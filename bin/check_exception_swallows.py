@@ -11,8 +11,7 @@ return a fallback, or record the error.
 Allowed:
 - narrow handlers (``except OSError: pass`` documents a specific, expected
   condition);
-- ``__del__`` bodies (interpreter-shutdown teardown races are idiomatic);
-- ``_jax_compat.py`` (the version-probing shims try/except by design).
+- ``__del__`` bodies (interpreter-shutdown teardown races are idiomatic).
 
 Usage: ``python bin/check_exception_swallows.py [root]`` — prints
 violations as ``path:line: message`` and exits nonzero if any. Enforced
@@ -27,8 +26,8 @@ import sys
 #: exception names whose silent swallow is banned
 BROAD = ("Exception", "BaseException")
 
-#: compat-shim files allowed to swallow (version probing by design)
-ALLOWED_FILES = ("_jax_compat.py",)
+#: files allowed to swallow (none)
+ALLOWED_FILES: tuple[str, ...] = ()
 
 #: enclosing function names where swallowing is idiomatic
 ALLOWED_FUNCS = ("__del__",)

@@ -18,7 +18,11 @@ check_reqtrace_events.py shape) enforces both directions across
   receiver-side comparison against a message type tag;
 - **every handled type is sent**: each string a dispatch comparison
   names must be constructed as a ``{"t": ...}`` literal somewhere (a
-  relay that forwards ``{**msg}`` rides the original literal).
+  relay that forwards ``{**msg}`` rides the original literal);
+- **pinned fields ride their message**: every literal of a type listed
+  in ``REQUIRED_FIELDS`` carries those keys (a ``ready`` that stops
+  naming the ``platform`` it runs on would let a worker serve from the
+  CPU on a machine with a chip, and nothing would say so).
 
 Comparison sites recognized as dispatch: ``Eq``/``NotEq``/``In``/
 ``NotIn`` compares where one side is the conventional tag expression —
@@ -45,6 +49,11 @@ TAG = "t"
 #: types legitimately one-sided (none today; additions need a reason)
 ALLOWED_UNHANDLED: set[str] = set()
 ALLOWED_UNSENT: set[str] = set()
+
+#: keys every literal of a message type must carry
+REQUIRED_FIELDS: dict[str, tuple[str, ...]] = {
+    "ready": ("platform", "device_kind"),
+}
 
 
 def _is_tag_expr(node: ast.AST) -> bool:
@@ -85,13 +94,22 @@ def scan_file(path: str) -> tuple[dict, dict, list[str]]:
             return {}, {}, [f"{path}:{e.lineno}: unparseable ({e.msg})"]
     sent: dict[str, str] = {}
     handled: dict[str, str] = {}
+    errors: list[str] = []
     for node in ast.walk(tree):
         if isinstance(node, ast.Dict):
+            keys = {k.value for k in node.keys
+                    if isinstance(k, ast.Constant)}
             for k, v in zip(node.keys, node.values):
                 if isinstance(k, ast.Constant) and k.value == TAG \
                         and isinstance(v, ast.Constant) \
                         and isinstance(v.value, str):
                     sent.setdefault(v.value, f"{path}:{node.lineno}")
+                    for need in REQUIRED_FIELDS.get(v.value, ()):
+                        if need not in keys:
+                            errors.append(
+                                f"{path}:{node.lineno}: protocol message "
+                                f"{v.value!r} is built without its "
+                                f"required field {need!r}")
         elif isinstance(node, ast.Compare) and len(node.ops) == 1 \
                 and isinstance(node.ops[0], (ast.Eq, ast.NotEq,
                                              ast.In, ast.NotIn)):
@@ -100,7 +118,7 @@ def scan_file(path: str) -> tuple[dict, dict, list[str]]:
                 for s in sides:
                     for val in _str_consts(s):
                         handled.setdefault(val, f"{path}:{node.lineno}")
-    return sent, handled, []
+    return sent, handled, errors
 
 
 def check_repo(root: str) -> list[str]:

@@ -10,7 +10,6 @@ Public API (mirrors /root/reference/deepspeed/__init__.py):
     initialize(...)      -> (engine, optimizer, dataloader, lr_scheduler)
     init_inference(...)  -> InferenceEngine
 """
-from . import _jax_compat  # noqa: F401  (must run before any jax API use)
 from .version import __version__  # noqa: F401
 
 from . import comm, models, zero  # noqa: F401

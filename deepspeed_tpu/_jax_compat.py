@@ -1,145 +1,21 @@
-"""Version shims for the small set of new jax APIs this package uses.
+"""The one JAX configuration helper the package and its tests share.
 
-The codebase targets current jax (``jax.shard_map`` with ``check_vma``,
-``jax.experimental.layout.Format``); container images occasionally pin an
-older jax where the same features live under earlier names
-(``jax.experimental.shard_map`` with ``check_rep``,
-``layout.DeviceLocalLayout``). Every shim is gated on ``hasattr`` so a
-current jax is untouched — importing this module there is a no-op.
+Written for the installed jax/jaxlib 0.9 (``pyproject.toml`` states the
+versions): ``jax.shard_map``, ``jax.lax.axis_size``,
+``jax.experimental.layout.Format`` and the partitionable threefry default
+are used directly where they are needed — nothing here shims an older
+JAX.
 """
 from __future__ import annotations
-
-import functools
-import os
 
 import jax
 
 
 def set_cpu_devices(n: int) -> None:
-    """Force the CPU platform with ``n`` virtual devices, across jax
-    versions. Must run before the first backend touch (``jax.devices()``
-    and friends) — a lazy backend has not read either knob yet. The ONE
-    place the ``jax_num_cpu_devices`` / ``--xla_force_host_platform_
-    device_count`` split lives; conftest, the dryrun entry, and the
-    subprocess test templates all call this."""
+    """Force the CPU platform with ``n`` virtual devices. Must run before
+    the first backend touch (``jax.devices()`` and friends) — a lazy
+    backend has not read either option yet. conftest, the dryrun entry,
+    ``chip_smoke.py --rehearse`` and the subprocess test templates all
+    call this."""
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-    except AttributeError:      # older jax: same knob, XLA flag spelling
-        import re
-
-        flag = f"--xla_force_host_platform_device_count={n}"
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" in flags:
-            # REPLACE a pre-existing count — keeping a stale value would
-            # silently give the caller the wrong device count
-            flags = re.sub(
-                r"--xla_force_host_platform_device_count=\d+", flag, flags)
-        else:
-            flags = (flags + " " + flag).strip()
-        os.environ["XLA_FLAGS"] = flags
-
-
-_PARTIAL_MANUAL_OK: bool | None = None
-#: True when _install() had to ADD jax.shard_map (old jax) — the probe
-#: short-circuit must not mistake the shim for the native API
-_SHIMMED_SHARD_MAP = False
-
-_PARTIAL_MANUAL_PROBE = """
-import os
-os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=4")
-import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax import shard_map
-    kw = {"axis_names": {"pipe"}}
-except ImportError:
-    from jax.experimental.shard_map import shard_map
-    kw = {"auto": frozenset({"data"}), "check_rep": False}
-mesh = Mesh(np.array(jax.devices()).reshape(2, 2), ("pipe", "data"))
-def body(x):
-    return jax.lax.ppermute(x, "pipe", [(i, (i + 1) % 2) for i in range(2)])
-out = jax.jit(shard_map(body, mesh=mesh, in_specs=P("pipe"),
-                        out_specs=P("pipe"), **kw))(jnp.ones((2, 4)))
-assert float(np.asarray(out).sum()) == 8.0
-"""
-
-
-def partial_manual_collectives_ok() -> bool:
-    """Whether this jax/jaxlib can run collectives (ppermute) inside a
-    shard_map that is manual over a strict SUBSET of mesh axes with other
-    axes of size > 1 left automatic — the ``spmd_pipeline`` idiom
-    (pipe × data/tensor). jaxlib 0.4.36's SPMD partitioner hits a FATAL
-    CHECK (``IsManualSubgroup``) on that pattern — a process abort, not an
-    exception — so the probe must run in a throwaway subprocess. Cached
-    per process."""
-    global _PARTIAL_MANUAL_OK
-    if _PARTIAL_MANUAL_OK is None and not _SHIMMED_SHARD_MAP:
-        # a NATIVE top-level shard_map API means current jax, whose
-        # partitioner has the pattern fixed — skip the multi-second
-        # subprocess probe. (hasattr(jax, "shard_map") alone would lie:
-        # _install() adds the attribute on old jax too.)
-        _PARTIAL_MANUAL_OK = True
-    if _PARTIAL_MANUAL_OK is None:
-        import subprocess
-        import sys
-
-        try:
-            _PARTIAL_MANUAL_OK = subprocess.run(
-                [sys.executable, "-c", _PARTIAL_MANUAL_PROBE],
-                capture_output=True, timeout=300).returncode == 0
-        except Exception:  # noqa: BLE001 — a broken probe means "no"
-            _PARTIAL_MANUAL_OK = False
-    return _PARTIAL_MANUAL_OK
-
-
-def _install() -> None:
-    if not jax.config.jax_threefry_partitionable:
-        # current jax defaults to the partitionable threefry, which makes
-        # random init MESH-INVARIANT; the old default generated different
-        # weights per mesh layout (measured: tensor2 x data2 init diverged
-        # from single-device init by up to 0.26 — every cross-mesh parity
-        # assumption in this codebase relies on the new default)
-        jax.config.update("jax_threefry_partitionable", True)
-
-    if not hasattr(jax, "shard_map"):
-        global _SHIMMED_SHARD_MAP
-        _SHIMMED_SHARD_MAP = True
-        from jax.experimental.shard_map import shard_map as _sm
-
-        @functools.wraps(_sm)
-        def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None,
-                      check_rep=None, axis_names=None, **kw):
-            if check_rep is None:
-                check_rep = bool(check_vma) if check_vma is not None else True
-            if axis_names is not None:
-                # new API names the MANUAL axes; old API names the
-                # complement ("auto" axes)
-                kw["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-            return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                       check_rep=check_rep, **kw)
-
-        jax.shard_map = shard_map
-
-    if not hasattr(jax.lax, "axis_size"):
-        from jax._src import core as _core
-
-        def axis_size(axis_name):
-            if isinstance(axis_name, (tuple, list)):
-                import math
-                return math.prod(_core.axis_frame(a) for a in axis_name)
-            return _core.axis_frame(axis_name)
-
-        jax.lax.axis_size = axis_size
-
-    import jax.experimental.layout as _layout
-    if not hasattr(_layout, "Format"):
-        # new API: Format(Layout(major_to_minor=...), sharding)
-        # old API: Layout(DeviceLocalLayout(major_to_minor=...), sharding)
-        _layout.Format = _layout.Layout
-        _layout.Layout = _layout.DeviceLocalLayout
-
-
-_install()
+    jax.config.update("jax_num_cpu_devices", n)

@@ -25,6 +25,7 @@ import subprocess
 import sys
 import time
 
+from ..utils.compile_cache import ENV_VAR as CACHE_ENV_VAR, default_cache_dir
 from ..utils.logging import logger
 
 
@@ -62,6 +63,10 @@ def build_child_env(base_env: dict, args, local_rank: int) -> dict:
     if world == 1:
         # single process needs no rendezvous; don't force jax.distributed
         env.pop("DS_TPU_COORDINATOR")
+    # the child is the user's own script: it gets the fixed compile-cache
+    # directory through the variable jax reads itself, unless the caller
+    # has already placed the cache (utils/compile_cache.py)
+    env.setdefault(CACHE_ENV_VAR, default_cache_dir())
     return env
 
 

@@ -34,7 +34,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import dot_product_attention
+from ..ops.attention import attention_formulation, dot_product_attention
 
 # Logical activation axis names (canonical home: parallel/axes.py);
 # re-exported here for back-compat.
@@ -277,6 +277,31 @@ def apply_rope(q: jax.Array, k: jax.Array, positions: jax.Array,
             jnp.concatenate([kr, k[..., d_rot:]], axis=-1))
 
 
+def _attn_impl(cfg: "ModelConfig") -> str:
+    """alibi's additive bias and sliding windows run XLA attention (no
+    flash kernel path); everything else follows ``cfg.attn_impl``."""
+    return "xla" if (cfg.position_embedding == "alibi"
+                     or cfg.sliding_window) else cfg.attn_impl
+
+
+def training_attention_formulation(cfg: "ModelConfig", batch: int,
+                                   seq: int) -> tuple[str, str]:
+    """``("pallas", "")`` or ``("xla", why_not)``: what :class:`Attention`
+    runs for a full-sequence ``[batch, seq]`` step (no KV cache, no
+    padding mask) on the devices of this process — the training engine
+    logs it once at build time so ``attn_impl="auto"`` never falls through
+    to XLA attention unannounced."""
+    q = jax.ShapeDtypeStruct((batch, seq, cfg.num_heads, cfg.head_dim),
+                             cfg.dtype)
+    kv = jax.ShapeDtypeStruct((batch, seq, cfg.kv_heads, cfg.head_dim),
+                              cfg.dtype)
+    # alibi's bias is built per call; its presence is all the gate reads
+    return attention_formulation(
+        q, kv, kv, causal=cfg.causal, window=cfg.sliding_window,
+        bias=True if cfg.position_embedding == "alibi" else None,
+        impl=cfg.attn_impl)
+
+
 class Attention(nn.Module):
     """Causal self-attention with GQA + optional RoPE + KV cache.
 
@@ -354,8 +379,7 @@ class Attention(nn.Module):
             mask=attn_mask,
             bias=alibi_bias,
             window=cfg.sliding_window,
-            impl="xla" if (alibi_bias is not None or cfg.sliding_window)
-            else cfg.attn_impl,
+            impl=_attn_impl(cfg),
         )
         # back to seq-sharded, heads full
         out = constrain(out, BATCH, SEQ, None, None)

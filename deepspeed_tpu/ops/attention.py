@@ -55,6 +55,29 @@ def _xla_attention(q, k, v, *, causal, positions, kv_len, mask, bias=None,
     return out
 
 
+def attention_formulation(q, k, v, *, causal: bool = True, positions=None,
+                          mask=None, bias=None, impl: str = "auto",
+                          window: int | None = None,
+                          allow_multi_device: bool = False
+                          ) -> tuple[str, str]:
+    """``("pallas", "")`` when :func:`dot_product_attention` runs the
+    flash kernel for these inputs, else ``("xla", why_not)``. Reads only
+    shapes and dtypes, so ``jax.ShapeDtypeStruct``s serve — the training
+    engine asks at build time and logs the answer, because ``auto``
+    falling through to XLA is otherwise silent."""
+    if impl == "xla":
+        return "xla", "attn_impl='xla' (config pin)"
+    if bias is not None or window:
+        return "xla", ("additive bias (alibi) / sliding window have no "
+                       "flash kernel path")
+    from .pallas.flash_attention import flash_attention_unusable_reason
+
+    why_not = flash_attention_unusable_reason(
+        q, k, v, causal=causal, positions=positions, mask=mask,
+        allow_multi_device=allow_multi_device)
+    return ("xla", why_not) if why_not else ("pallas", "")
+
+
 def dot_product_attention(q, k, v, *, causal: bool = True, positions=None,
                           kv_len=None, mask=None, bias=None, impl: str = "auto",
                           window: int | None = None,
@@ -72,20 +95,15 @@ def dot_product_attention(q, k, v, *, causal: bool = True, positions=None,
     if window and positions is None and not causal:
         raise ValueError("sliding_window requires causal attention "
                          "(bidirectional windows are not a thing here)")
-    if impl in ("auto", "pallas") and bias is None and not window:
-        try:
-            from .pallas.flash_attention import flash_attention_usable, flash_attention
+    chosen, why_not = attention_formulation(
+        q, k, v, causal=causal, positions=positions, mask=mask, bias=bias,
+        impl=impl, window=window, allow_multi_device=allow_multi_device)
+    if chosen == "pallas":
+        from .pallas.flash_attention import flash_attention
 
-            if flash_attention_usable(q, k, v, causal=causal, positions=positions,
-                                      mask=mask,
-                                      allow_multi_device=allow_multi_device):
-                return flash_attention(q, k, v, causal=causal)
-        except ImportError:
-            pass
-        if impl == "pallas":
-            raise ValueError("pallas flash attention not usable for these inputs")
-    elif impl == "pallas" and (bias is not None or window):
-        raise ValueError("pallas flash attention has no additive-bias or "
-                         "sliding-window path yet (these run XLA attention)")
+        return flash_attention(q, k, v, causal=causal)
+    if impl == "pallas":
+        raise ValueError(f"pallas flash attention not usable for these "
+                         f"inputs: {why_not}")
     return _xla_attention(q, k, v, causal=causal, positions=positions,
                           kv_len=kv_len, mask=mask, bias=bias, window=window)

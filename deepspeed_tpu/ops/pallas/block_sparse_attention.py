@@ -33,10 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-try:  # pragma: no cover
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -72,7 +69,7 @@ def layout_tables(layout: np.ndarray) -> tuple[np.ndarray, np.ndarray,
 
 def block_sparse_usable(layout: np.ndarray, block: int, S: int, D: int,
                         H: int, KV: int) -> bool:
-    if pltpu is None or block < MIN_BLOCK or block % 8 or S % block:
+    if block < MIN_BLOCK or block % 8 or S % block:
         return False
     if H != KV:                      # GQA head mapping not wired yet
         return False
@@ -334,7 +331,8 @@ def block_sparse_flash_attention(q, k, v, layout: np.ndarray, block: int,
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        from . import interpret_mode
+        interpret = interpret_mode()
     tbl_q, cnt_q, tbl_k, cnt_k = (jnp.asarray(t)
                                   for t in layout_tables(layout))
     qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))

@@ -35,10 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu importable everywhere jax is, but keep the guard for safety
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -129,12 +126,16 @@ def _pick_blocks(Sq: int, Skv: int, d: int, dtype_bytes: int,
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    from . import interpret_mode
+    return interpret_mode()
 
 
-def flash_attention_usable(q, k, v, *, causal: bool, positions=None,
-                           mask=None, allow_multi_device: bool = False) -> bool:
-    """Gate for the dispatcher: full-sequence self-attention only (the
+def flash_attention_unusable_reason(q, k, v, *, causal: bool,
+                                    positions=None, mask=None,
+                                    allow_multi_device: bool = False) -> str:
+    """Why the dispatcher must NOT claim the kernel for these inputs
+    (arrays or ``jax.ShapeDtypeStruct``s — only shapes and dtype are
+    read); ``""`` when it may. Full-sequence self-attention only (the
     decode/cached path has tiny q and is XLA's job).
 
     By default only claims the kernel when a single device is in play:
@@ -144,23 +145,34 @@ def flash_attention_usable(q, k, v, *, causal: bool, positions=None,
     parallel/sequence.py paths) and opt in with ``allow_multi_device=True``
     / explicit ``impl='pallas'``.
     """
+    del causal, v
     if not allow_multi_device and jax.device_count() > 1:
-        return False
+        return (f"{jax.device_count()} devices in this process and "
+                f"pallas_call has no GSPMD partitioning rule (only "
+                f"shard_map callers opt in)")
     if positions is not None or mask is not None:
-        return False
+        return "cached/masked attention (positions or mask given)"
     B, Sq, H, D = q.shape
     Skv, KV = k.shape[1], k.shape[2]
     if Sq != Skv:                      # prefill/training only
-        return False
+        return f"q length {Sq} != kv length {Skv}"
     if Sq < MIN_SEQ:                   # tiny: XLA is fast and cheap anyway
-        return False
-    if _pick_blocks(Sq, Skv, D, q.dtype.itemsize) is None:
-        return False
+        return f"sequence {Sq} < {MIN_SEQ}"
+    if _pick_blocks(Sq, Skv, D, jnp.dtype(q.dtype).itemsize) is None:
+        return f"sequence {Sq} has no block divisor in {_FAST_BLOCKS}"
     if H % KV != 0:
-        return False
+        return f"{H} query heads not divisible by {KV} kv heads"
     # head_dim should map onto MXU lanes; smaller dims are padded by Mosaic
     # but we only claim the kernel when it is profitable.
-    return D in (64, 128, 256)
+    if D not in (64, 128, 256):
+        return f"head_dim {D} not in (64, 128, 256)"
+    return ""
+
+
+def flash_attention_usable(q, k, v, **kw) -> bool:
+    """Gate for the dispatcher — see
+    :func:`flash_attention_unusable_reason`."""
+    return not flash_attention_unusable_reason(q, k, v, **kw)
 
 
 def _block_visible(causal: bool, q_start, k_start, block_q: int):

@@ -33,10 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pragma: no cover
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _pick(dim: int, want: int) -> int:
@@ -83,7 +80,8 @@ def _gmm_call(x, w, tile_expert, *, block_m: int, transpose_rhs: bool,
     bk = _pick(K, block_k or 2048)
     bn = _pick(N, block_n or 512)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        from . import interpret_mode
+        interpret = interpret_mode()
 
     grid = (Tp // block_m, N // bn, K // bk)
     if transpose_rhs:
@@ -158,7 +156,8 @@ def _dw_call(x, dy, tile_expert, n_exp: int, *, block_m: int,
     be = _pick(E, 512)
     bf = _pick(F, 512)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        from . import interpret_mode
+        interpret = interpret_mode()
     grid = (E // be, F // bf, Tp // block_m)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,

@@ -33,10 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pragma: no cover
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -44,8 +41,6 @@ NEG_INF = float(jnp.finfo(jnp.float32).min)
 def paged_attention_usable(num_heads: int, kv_heads: int, head_dim: int,
                            block_size: int) -> bool:
     """Gate: MXU-friendly head_dim, sublane-aligned pages, even GQA groups."""
-    if pltpu is None:
-        return False
     if num_heads % kv_heads:
         return False
     if block_size % 8:
@@ -233,8 +228,8 @@ def _ragged_attn_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
             if tree:
                 # tree nodes sit at root+depth, siblings SHARING a
                 # position — unrecoverable from the row ramp, so the
-                # positions ride a VMEM input ([1, TQB] rows t*G+g)
-                qpos = tpos_ref[0][None, :, None]
+                # positions ride a VMEM input ([1, 1, TQB] rows t*G+g)
+                qpos = tpos_ref[0, 0][None, :, None]
             else:
                 qpos = qstart + (tq * tqb + jax.lax.broadcasted_iota(
                     jnp.int32, scores.shape, 1)) // G
@@ -339,10 +334,10 @@ def _ragged_attn_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
             jnp.int32, scores.shape, 2)
         if tree:
             # stage rows are the candidate nodes themselves: visibility is
-            # the prebuilt ancestors-only mask ([1, TQB, srows] tile for
-            # this stage page), NOT position order — sibling nodes share a
-            # position but must not see each other
-            online_update(scores, ctx, tmask_ref[0][None] > 0, v,
+            # the prebuilt ancestors-only mask ([1, 1, TQB, srows] tile
+            # for this stage page), NOT position order — sibling nodes
+            # share a position but must not see each other
+            online_update(scores, ctx, tmask_ref[0, 0][None] > 0, v,
                           tree_cols=True)
         else:
             online_update(scores, ctx, ctx < seq_len, v)
@@ -412,7 +407,8 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        from . import interpret_mode
+        interpret = interpret_mode()
 
     # [S, T, KV, G, D] -> [S, KV, T*G, D], rows t*G + g
     qg = (q.reshape(S, T, KV, G, D).transpose(0, 2, 1, 3, 4)
@@ -478,16 +474,26 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
         # same way on rows and zero-pads columns out to the stage width
         # (padding columns are invisible — ancestor_mask already zeroes
         # past-tree columns, and zero mask == masked out)
+        #
+        # Mosaic wants each block's last two dims divisible by (8, 128)
+        # or equal to the array's, and the slot dim S is neither once a
+        # batch holds more than one slot — so both operands keep S (and
+        # the stage-page index) on LEADING axes and end in dims the block
+        # covers whole or in legal tiles: tpos [S, 1, TG] with block
+        # (1, 1, TQB), the mask [S, nsp, TG, srows] with block
+        # (1, 1, TQB, srows). Interpret mode never checks this.
         tpos = jnp.repeat(tree_positions.astype(jnp.int32), G, axis=1)
+        tpos = tpos.reshape(S, 1, TG)
         tmsk = jnp.repeat(tree_mask.astype(jnp.int32), G, axis=1)
         tmsk = jnp.pad(tmsk, ((0, 0), (0, 0), (0, Ts - T)))
+        tmsk = tmsk.reshape(S, TG, nsp, srows).transpose(0, 2, 1, 3)
         tree_ops = (tpos, tmsk)
         tree_specs = [
-            pl.BlockSpec((1, TQB),
-                         lambda s, tq, j, t, ln, qs, ss, lr: (s, tq)),
-            pl.BlockSpec((1, TQB, srows),
+            pl.BlockSpec((1, 1, TQB),
+                         lambda s, tq, j, t, ln, qs, ss, lr: (s, 0, tq)),
+            pl.BlockSpec((1, 1, TQB, srows),
                          lambda s, tq, j, t, ln, qs, ss, lr:
-                             (s, tq, jnp.maximum(j - n_grp, 0))),
+                             (s, jnp.maximum(j - n_grp, 0), tq, 0)),
         ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -574,7 +580,8 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, seq_lens,
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        from . import interpret_mode
+        interpret = interpret_mode()
 
     # [S, T, H, D] -> [S, KV, T*G, D], rows t*G + g
     qg = (q.reshape(S, T, KV, G, D).transpose(0, 2, 1, 3, 4)

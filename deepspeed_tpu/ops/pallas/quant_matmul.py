@@ -27,10 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pragma: no cover
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 class QuantLinear(NamedTuple):
@@ -325,13 +322,9 @@ def quant_matmul(x: jax.Array, qw: QuantLinear, *,
     if qw.bits in (8, "fp8") and (
             small_m_xla if small_m_xla is not None else M <= SMALL_M_XLA):
         return _xla_dequant_dot(x, qw, layer_index)
-    if pltpu is None:
-        # no Pallas TPU support in this jax build — XLA dequant fallback
-        if stacked:
-            qw = jax.tree.map(lambda a: a[layer_index], qw)
-        return (x @ dequantize_weight(qw).astype(x.dtype))
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        from . import interpret_mode
+        interpret = interpret_mode()
     G = qw.group_size
     bk = _pick(K, max(block_k, G))
     if bk % G:
@@ -579,14 +572,9 @@ def quant_grouped_matmul(x: jax.Array, qw: QuantGrouped,
     if stacked and qw.data.ndim != 4:
         raise ValueError("layer_index given but codes are not stacked "
                          f"(data {qw.data.shape})")
-    if pltpu is None:
-        if stacked:
-            qw = jax.tree.map(lambda a: a[layer_index], qw)
-        full = dequantize_grouped(qw).astype(x.dtype)      # [n, K, N]
-        te = jnp.repeat(tile_expert, block_m)
-        return jnp.einsum("tk,tkn->tn", x, full[te])[:, :N_logical]
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        from . import interpret_mode
+        interpret = interpret_mode()
     G = qw.group_size
     bk = _pick(K, max(block_k, G))
     if bk % G:

@@ -83,20 +83,6 @@ def spmd_pipeline(stage_fn: Callable[[Pytree, jax.Array, Pytree], jax.Array],
     """
     n = mesh.shape[axis]
     M = xs.shape[0]
-    if n > 1 and any(s > 1 for a, s in mesh.shape.items() if a != axis):
-        from .._jax_compat import partial_manual_collectives_ok
-
-        if not partial_manual_collectives_ok():
-            # old jaxlib: the SPMD partitioner hits a FATAL CHECK
-            # (IsManualSubgroup) on collectives inside a partial-manual
-            # shard_map — a process abort, not an exception. Refuse with
-            # a catchable error instead so callers (dryrun, tests) can
-            # skip pipeline × {data,tensor,expert} cleanly.
-            raise RuntimeError(
-                "this jaxlib cannot partition collectives inside a "
-                "partial-manual shard_map (pipe x non-trivial auto "
-                "axes); upgrade jax/jaxlib to run pipeline parallelism "
-                "combined with data/tensor/expert axes")
     base_fn = stage_fn if shared is not None else \
         (lambda p, x, a, _sh: stage_fn(p, x, a))
     fn = jax.checkpoint(base_fn) if remat else base_fn

@@ -107,8 +107,7 @@ class TPOverlapScope:
     ``token_specs`` names the mesh axes of the token (batch, seq) dims of
     activations at the projection sites — the engine's activation rules in
     mesh-axis form — so the ring shard_map can declare the full manual
-    partitioning (a *partial*-manual shard_map would abort this jaxlib's
-    partitioner on collectives, see _jax_compat)."""
+    partitioning."""
     mesh: Any
     axis: str = "tensor"
     token_specs: tuple = (("data", "expert", "fsdp"), "seq")

@@ -90,6 +90,11 @@ class ReplicaHandle:
         #: the replica's shared-memory page ring segment name (shm
         #: transport, serving/shm.py); None = relay-only peer
         self.shm: str | None = None
+        #: where this incarnation computes, from its ready message:
+        #: ``jax`` platform and device kind of an engine worker ("tpu",
+        #: "TPU v5 lite"), "host" for the toy backend; None until ready
+        self.platform: str | None = None
+        self.device_kind: str | None = None
         #: the weight version this incarnation serves
         #: (``{"id", "digest"}`` from ready/heartbeat; None until ready).
         #: Router-side MIRROR of the replica's authoritative
@@ -156,6 +161,7 @@ class ReplicaHandle:
 
             self.state = SPAWNING
             self.load = self.digest = self.tier_digest = self.shm = None
+            self.platform = self.device_kind = None
             self.wv = None
             self.rtt_s = self.clock_offset_s = None
             self.last_msg_t = time.monotonic()
@@ -169,8 +175,11 @@ class ReplicaHandle:
                 logger.warning(f"fleet: slot {self.slot} dial of "
                                f"{self.address} failed: {e}")
             return
+        # device placement is explicit: what FleetConfig.env says, and
+        # otherwise the worker inherits this process's environment — a
+        # default of JAX_PLATFORMS=cpu here would serve from the CPU on a
+        # machine with a chip and say nothing
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         # the worker must import THIS package tree regardless of the
         # router's cwd or install state
         import deepspeed_tpu as _pkg
@@ -198,6 +207,7 @@ class ReplicaHandle:
                                 self.proc.stdin.fileno(), own_fds=False)
         self.state = SPAWNING
         self.load = self.digest = self.tier_digest = self.shm = None
+        self.platform = self.device_kind = None
         self.wv = None
         self.rtt_s = self.clock_offset_s = None
         self.last_msg_t = time.monotonic()
@@ -421,6 +431,8 @@ class Fleet:
         r.max_live = int(msg.get("max_live", 1))
         r.block_size = int(msg.get("block_size", 0))
         r.shm = msg.get("shm") or None
+        r.platform = msg.get("platform")
+        r.device_kind = msg.get("device_kind")
         # r.wv is deliberately NOT set here: the router's _note_wv owns
         # every wv transition (gauge + sticky invalidation) and would
         # see an already-updated handle as "no change"
@@ -432,7 +444,8 @@ class Fleet:
             r.half_open = False
             r.deaths.clear()
         logger.info(f"fleet: slot {r.slot} epoch {r.epoch} ready "
-                    f"(max_live={r.max_live})")
+                    f"(max_live={r.max_live}, platform={r.platform}, "
+                    f"device_kind={r.device_kind})")
 
     def kill_replica(self, slot: int) -> None:
         """Chaos/bench hook: SIGKILL the slot's current incarnation (the

@@ -125,7 +125,14 @@ Message vocabulary (``t`` is the type tag)::
   replica -> router
     {"t":"ready","pid":int,"block_size":int,"max_live":int,"epoch":int,
      "role":"prefill"|"decode"|"mixed",
-     "wv":{"id":int,"digest":str}}          "wv" = the weight version
+     "platform":str,"device_kind":str,
+     "wv":{"id":int,"digest":str}}          "platform"/"device_kind" =
+                                            where the worker computes,
+                                            read after its engine is
+                                            built (jax's names, e.g.
+                                            "tpu"/"TPU v5 lite"; the toy
+                                            backend reports "host");
+                                            "wv" = the weight version
                                             this replica serves (id is
                                             the fleet-monotonic deploy
                                             id, digest the checkpoint

@@ -111,6 +111,9 @@ class ToyBackend:
         #: ALSO accepts fresh puts — the router's fallback when no
         #: prefill-capable slot is ready)
         self.role = str(cfg.get("role", "mixed"))
+        #: where this backend computes (the ready message reports it):
+        #: the toy touches no device
+        self.platform = self.device_kind = "host"
         #: the real radix trie — digest/match/publish are the production
         #: code paths (host-only; named ``radix`` because this backend
         #: OWNS its fake pool — StateManager's refcounted-API lint governs
@@ -900,6 +903,12 @@ class EngineBackend:
         self.block_size = self.eng.config.block_size
         self.max_live = self.eng.config.max_seqs
         self.role = str(cfg.get("role", "mixed"))
+        # the device the engine was actually built on — read AFTER the
+        # build so the ready message cannot name a platform it merely
+        # hoped for
+        dev = self.eng.topology.mesh.devices.flat[0]
+        self.platform = str(dev.platform)
+        self.device_kind = str(dev.device_kind)
         self._uids: dict[str, int] = {}
         self._next_uid = 1
         self._sent: dict[str, int] = {}          # rid -> tokens streamed
@@ -1695,6 +1704,8 @@ def serve(cfg: dict, chan: LineChannel,
     chan.send({"t": "ready", "pid": os.getpid(),
                "block_size": backend.block_size,
                "max_live": backend.max_live, "role": role,
+               "platform": backend.platform,
+               "device_kind": backend.device_kind,
                "shm": ring.name if ring is not None else None,
                "wv": dict(backend.weight_version),
                "epoch": int(cfg.get("epoch", 0))}, timeout=send_t)
@@ -2673,6 +2684,9 @@ def serve(cfg: dict, chan: LineChannel,
 def main(argv: list[str]) -> int:
     import json
 
+    from ..utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     args = list(argv[1:])
     listen = None
     if args and args[0] == "--listen":

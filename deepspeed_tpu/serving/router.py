@@ -83,7 +83,7 @@ GANG = "gang"
 
 class AdmissionError(RuntimeError):
     """Structured admission refusal: ``reason`` is machine-readable (the
-    shed taxonomy in the module docstring), the message is for humans."""
+    shed-reason table in the module docstring), the message is for humans."""
 
     def __init__(self, reason: str, detail: str = ""):
         super().__init__(f"request refused: {reason}"

@@ -147,3 +147,85 @@ def test_grads_merged_single_kv_block():
     for name, a, b in zip("qkv", gf, gx):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-3,
                                    err_msg=f"d{name}")
+
+
+# ---- which formulation, and why: nothing about the device is silent --------
+
+@pytest.mark.parametrize("platform,want", [
+    ("cpu", True), ("tpu", False), ("gpu", RuntimeError),
+    ("somebody_elses_plugin", RuntimeError)])
+def test_interpret_mode_is_decided_in_one_place(platform, want, monkeypatch):
+    """``ops.pallas.interpret_mode``: cpu interprets, tpu compiles, and any
+    other platform is an ERROR — never a quiet interpreter run on a
+    device nobody named."""
+    import deepspeed_tpu.ops.pallas as pallas_pkg
+
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    if want is RuntimeError:
+        with pytest.raises(RuntimeError, match=platform):
+            pallas_pkg.interpret_mode()
+    else:
+        assert pallas_pkg.interpret_mode() is want
+
+
+def _qkv(B=2, S=256, H=4, KV=4, D=64, Skv=None):
+    sds = jax.ShapeDtypeStruct
+    return (sds((B, S, H, D), jnp.bfloat16),
+            sds((B, Skv or S, KV, D), jnp.bfloat16),
+            sds((B, Skv or S, KV, D), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("qkv,kw,why", [
+    (_qkv(), {}, ""),
+    (_qkv(), {"allow_multi_device": False}, "devices in this process"),
+    (_qkv(), {"mask": object()}, "cached/masked"),
+    (_qkv(Skv=512), {}, "!= kv length"),
+    (_qkv(S=64), {}, "sequence 64 <"),
+    (_qkv(S=1280 + 8), {}, "no block divisor"),
+    (_qkv(H=6, KV=4), {}, "not divisible by 4 kv heads"),
+    (_qkv(D=16), {}, "head_dim 16"),
+])
+def test_flash_gate_names_its_reason(qkv, kw, why):
+    """``flash_attention_unusable_reason`` reads shapes only (abstract
+    values serve) and says WHY the kernel is not claimed; "" = usable.
+    (The suite runs on 8 virtual devices, hence allow_multi_device.)"""
+    from deepspeed_tpu.ops.pallas.flash_attention import \
+        flash_attention_unusable_reason
+
+    got = flash_attention_unusable_reason(
+        *qkv, causal=True, **{"allow_multi_device": True, **kw})
+    assert (got == "") if why == "" else (why in got), got
+    assert flash_attention_usable(
+        *qkv, causal=True, **{"allow_multi_device": True, **kw}) \
+        == (why == "")
+
+
+@pytest.mark.parametrize("kw,chosen,why", [
+    ({"allow_multi_device": True}, "pallas", ""),
+    ({"allow_multi_device": True, "impl": "xla"}, "xla", "config pin"),
+    ({"allow_multi_device": True, "bias": object()}, "xla", "alibi"),
+    ({"allow_multi_device": True, "window": 128}, "xla", "sliding window"),
+    ({}, "xla", "devices in this process"),
+])
+def test_attention_formulation_is_what_the_dispatcher_runs(kw, chosen, why):
+    from deepspeed_tpu.ops.attention import attention_formulation
+
+    got = attention_formulation(*_qkv(), causal=True, **kw)
+    assert got[0] == chosen and why in got[1]
+
+
+@pytest.mark.parametrize("preset,why", [
+    ("gpt2-350m", "devices in this process"),     # 8 virtual devices here
+    ("tiny-bloom", "alibi"),
+    ("mistral-7b", "sliding window"),
+])
+def test_training_engine_can_say_why_not_flash(preset, why):
+    """What the training engine logs at build time
+    (``engine.attention_formulation``)."""
+    from deepspeed_tpu.models import get_model_config
+    from deepspeed_tpu.models.transformer import \
+        training_attention_formulation
+
+    chosen, reason = training_attention_formulation(
+        get_model_config(preset), 8, 1024)
+    assert chosen == "xla" and why in reason

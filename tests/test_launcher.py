@@ -85,6 +85,17 @@ def test_build_child_env_singleproc_no_rendezvous():
     assert env["WORLD_SIZE"] == "1"
 
 
+@pytest.mark.parametrize("placed", [None, "/somewhere/else"])
+def test_build_child_env_places_the_compile_cache(placed):
+    """The launcher's child gets the fixed ``<checkout>/.jax_cache``
+    through ``JAX_COMPILATION_CACHE_DIR`` — unless the caller's
+    environment already places the cache, which is left alone."""
+    base = {} if placed is None else {"JAX_COMPILATION_CACHE_DIR": placed}
+    env = build_child_env(base, parse_args(["train.py"]), local_rank=0)
+    assert env["JAX_COMPILATION_CACHE_DIR"] == (
+        placed or os.path.join(REPO, ".jax_cache"))
+
+
 def test_launch_end_to_end(tmp_path):
     """Spawn 2 local workers through the real launcher; each checks its env."""
     script = tmp_path / "worker.py"

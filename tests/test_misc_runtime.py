@@ -298,7 +298,15 @@ def test_fused_head_loss_matches_dense():
 
 def test_fused_head_engine_training_matches_dense(monkeypatch):
     """DS_TPU_FUSED_HEAD_CHUNK routes the engine's default LM loss through
-    the fused head — training trajectory matches the dense path."""
+    the fused head — training trajectory matches the dense path.
+
+    Both engines also pin that the train step compiles ONCE: the state
+    starts with every leaf committed to its steady-state sharding, so
+    step 1 (fresh state) and step 2 (the step's own outputs) trace the
+    same module. An uncommitted step counter made them two modules and
+    compiled the whole step twice — 69 s more at step 2 for gpt2-350m on
+    a v5e (found by chip_smoke.py, PR 21)."""
+    import jax
     import numpy as np
 
     import deepspeed_tpu as ds
@@ -310,10 +318,13 @@ def test_fused_head_engine_training_matches_dense(monkeypatch):
             config={"train_micro_batch_size_per_gpu": 2,
                     "optimizer": {"type": "AdamW", "params": {"lr": 1e-2}},
                     "steps_per_print": 10_000})
+        assert all(leaf.committed for leaf in jax.tree.leaves(engine.state))
         rng = np.random.default_rng(0)
         batch = {"input_ids": rng.integers(
             0, 256, (engine.config.train_batch_size, 32)).astype(np.int32)}
-        return [float(engine.train_batch(batch)) for _ in range(3)]
+        out = [float(engine.train_batch(batch)) for _ in range(3)]
+        assert engine._train_step._cache_size() == 1
+        return out
 
     dense = losses()
     monkeypatch.setenv("DS_TPU_FUSED_HEAD_CHUNK", "96")

@@ -1,7 +1,7 @@
-"""Repo lint: no module-import-time jax device probes outside _jax_compat
-(bin/check_import_time_devices.py — the round-5 postmortem rule: the first
-``jax.devices()`` belongs behind a watchdog at CALL time, and import-time
-probes freeze the platform before set_cpu_devices can run), no silent
+"""Repo lint: no module-import-time jax device probes
+(bin/check_import_time_devices.py — importing the package must leave the
+chip free for the child that needs it, and import-time probes freeze the
+platform before set_cpu_devices can run), no silent
 ``except Exception: pass`` swallows (bin/check_exception_swallows.py —
 recovery paths must not eat the faults the resilience layer surfaces), and
 no emitted metric/span tag that can't sanitize to a valid Prometheus
@@ -561,6 +561,26 @@ def test_protocol_detector_recognizes_every_tag_idiom(tmp_path):
         "    if phase == 'xfer': pass\n"           # not a tag compare
         "    return a, b, c\n")
     assert protocol_lint.check_repo(str(tmp_path)) == []
+
+
+def test_protocol_detector_pins_ready_placement_fields(tmp_path):
+    """A worker's ``ready`` names where it computes (``platform``,
+    ``device_kind``): the router's only evidence that an engine worker
+    is on the chip and not quietly on the CPU. A literal that drops
+    either key is flagged; the repo's own sender carries both."""
+    serving = tmp_path / "deepspeed_tpu" / "serving"
+    serving.mkdir(parents=True)
+    (serving / "c.py").write_text(
+        "def hello(ch, t):\n"
+        "    ch.send({'t': 'ready', 'pid': 1, 'platform': 'tpu'})\n"
+        "    if t == 'ready': pass\n")
+    out = protocol_lint.check_repo(str(tmp_path))
+    assert len(out) == 1, "\n".join(out)
+    assert "'ready'" in out[0] and "'device_kind'" in out[0]
+    assert ":2:" in out[0]
+    sent, _, errs = protocol_lint.scan_file(os.path.join(
+        ROOT, "deepspeed_tpu", "serving", "replica.py"))
+    assert errs == [] and "ready" in sent
 
 
 def test_deadline_lint_covers_deploy_waits(tmp_path):

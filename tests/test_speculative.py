@@ -620,6 +620,57 @@ def test_tree_kernel_parity_matrix(kv_dtype, G, tol):
             assert err < tol, (kv_dtype, G, window, pg, err)
 
 
+def test_tree_kernel_parity_stage_spans_pages():
+    """More tree nodes than one page holds (T=20 at block_size 16 → a
+    32-row stage in 2 page-sized tiles): the ancestors mask reaches the
+    kernel as [S, stage_page, rows, page] so each grid step takes its
+    page's columns whole (the layout Mosaic accepts at S > 1 —
+    tests/test_chip_compile.py compiles it). Parity vs the gather
+    formulation pins that the re-layout kept node/column order."""
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.pallas.paged_attention import \
+        paged_ragged_attention
+
+    rng = np.random.default_rng(7)
+    S, T, KV, G, D, bs, nb, mp, Ts = 2, 20, 2, 2, 64, 16, 8, 4, 32
+    pool = jnp.asarray(rng.standard_normal((2, 2, KV, nb, bs, D)) * 0.3,
+                       jnp.float32)
+    q = jnp.asarray(rng.standard_normal((S, T, KV * G, D)) * 0.3,
+                    jnp.float32)
+    ks = jnp.asarray(rng.standard_normal((S, KV, Ts, D)) * 0.3, jnp.float32)
+    vs = jnp.asarray(rng.standard_normal((S, KV, Ts, D)) * 0.3, jnp.float32)
+    tables = np.stack([rng.permutation(np.arange(1, nb))[:mp]
+                       for _ in range(S)]).astype(np.int32)
+    # two interleaved chains under one root: node i's parent is i-2
+    parents = [-1, 0] + list(range(T - 2))
+    depth = [0] * T
+    for i in range(1, T):
+        depth[i] = depth[parents[i]] + 1
+    pos = np.zeros((S, T), np.int32)
+    mask = np.zeros((S, T, T), np.uint8)
+    lens = np.zeros((S,), np.int32)
+    sst = np.zeros((S,), np.int32)
+    for s in range(S):
+        root = 9 + 11 * s
+        pos[s] = [root + d for d in depth]
+        for i in range(T):
+            j = i
+            while j != -1:
+                mask[s, i, j] = 1
+                j = parents[j]
+        lens[s] = root + 1 + max(depth)
+        sst[s] = root
+    want = _tree_gather_ref(pool, q, ks, vs, tables, lens, sst, pos, mask,
+                            G)
+    got = paged_ragged_attention(
+        q, pool, ks, vs, jnp.asarray(tables), jnp.asarray(lens),
+        jnp.asarray(pos[:, 0].copy()), jnp.asarray(sst), block_size=bs,
+        layer_index=jnp.int32(1), tree_positions=jnp.asarray(pos),
+        tree_mask=jnp.asarray(mask), interpret=True)
+    assert np.abs(np.asarray(got) - want).max() < 2e-5
+
+
 def test_attn_registry_tree_gates():
     """select_attention's static gates: decode vs tree mode, the config
     pin reason, the tree-geometry gates (row tile, stage page tiling,
