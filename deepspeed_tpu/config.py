@@ -238,7 +238,9 @@ class TelemetryConfig:
     #: span ring-buffer capacity (most recent N spans retained)
     span_buffer: int = 4096
     #: mirror spans into jax.profiler Trace/StepTraceAnnotation so host
-    #: spans overlay the xplane device trace (profiling/trace.py)
+    #: spans share the xplane's clock with the device lines that
+    #: profiling/trace.py reads (jax.profiler.ProfileData; device scopes
+    #: are jax.named_scope metadata and need no switch)
     mirror_jax: bool = True
     #: serve /metrics + /healthz on this port (None = off; 0 = ephemeral)
     http_port: int | None = None

@@ -57,6 +57,8 @@ from ..models.transformer import (
 from ..parallel.tensor import (_ring_rs_core, allgather_matmul,
                                matmul_reduce_scatter, overlap_counters)
 from ..parallel.topology import MeshConfig, MeshTopology
+from ..profiling.trace import register_program
+from ..utils.annotations import device_scope
 from ..utils.logging import logger
 from ..ops.pallas.paged_attention import (paged_attention_usable,
                                           paged_ragged_attention)
@@ -1245,11 +1247,12 @@ class InferenceEngineV2:
                 return y.reshape(S, T, -1).astype(cfg.dtype)
             return jnp.einsum("sthd,hde->ste", o, w.astype(cfg.dtype))
 
-        x = params["embed"].astype(cfg.dtype)[token_ids]           # [S,T,E]
-        if m.position_embedding == "learned":
-            x = x + params["pos_embed"].astype(cfg.dtype)[positions]
-        if "ln_embed" in params:                                   # bloom
-            x = Norm(m).apply({"params": params["ln_embed"]}, x)
+        with device_scope("embed"):
+            x = params["embed"].astype(cfg.dtype)[token_ids]       # [S,T,E]
+            if m.position_embedding == "learned":
+                x = x + params["pos_embed"].astype(cfg.dtype)[positions]
+            if "ln_embed" in params:                               # bloom
+                x = Norm(m).apply({"params": params["ln_embed"]}, x)
         if rn:
             # token-sharded residual stream (Megatron-SP layout): norms and
             # residual adds run 1/tp-sized per chip; the projections put
@@ -1410,6 +1413,16 @@ class InferenceEngineV2:
             the read-only pool pages + the stage. Returns (o, stage_l')."""
             a = p["attn"]
             qli = li if qstack else None
+            with device_scope("attn_qkv"):
+                q, k, v = qkv(a, qli, h)
+            with device_scope("kv_stage"):
+                stage_l = stage(k, v, stage_l)
+            with device_scope("attn_core"):
+                o = core(li, q, stage_l)
+            with device_scope("attn_out"):
+                return out_proj(a, qli, o), stage_l
+
+        def qkv(a, qli, h):
             if rn:
                 # ONE bidirectional ring gathers the token-sharded hidden
                 # while all three projections consume each arriving shard
@@ -1440,7 +1453,10 @@ class InferenceEngineV2:
                 v = v + a["bv"].astype(cfg.dtype)
             if m.position_embedding == "rope":
                 q, k = apply_rope(q, k, positions, m.rope_theta, m.rotary_pct)
+            return q, k, v
 
+        def stage(k, v, stage_l):
+            """This step's K/V into the staged buffers."""
             k_t = k.transpose(0, 2, 1, 3).astype(cfg.dtype)  # [S,KV,T,D]
             v_t = v.transpose(0, 2, 1, 3).astype(cfg.dtype)
             if window_mode:
@@ -1453,8 +1469,12 @@ class InferenceEngineV2:
                 pad = [(0, 0), (0, 0), (0, Ts - T), (0, 0)]
                 k_st = jnp.pad(k_t, pad)
                 v_st = jnp.pad(v_t, pad)
-            stage_l = (k_st, v_st)
+            return k_st, v_st
 
+        def core(li, q, stage_l):
+            """Ragged attention over the pool pages + the stage: the Pallas
+            kernel, or the XLA gather fallback."""
+            k_st, v_st = stage_l
             # Sliding windows mask on every path; windowed models also
             # serve from a ROLLING block table (self._ring_tokens > 0) so
             # out-of-window KV blocks are reused instead of pinned.
@@ -1580,6 +1600,9 @@ class InferenceEngineV2:
                 scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
                 w = jax.nn.softmax(scores, axis=-1).astype(V.dtype)
                 o = jnp.einsum("shtc,schd->sthd", w, V)
+            return o
+
+        def out_proj(a, qli, o):
             if rn:
                 # row-parallel out-proj: partial outputs ring-accumulate
                 # toward their owner's token chunk instead of blocking on
@@ -1596,19 +1619,23 @@ class InferenceEngineV2:
                 o = proj_out(o, a["wo"], li=qli)
             if m.attn_out_bias:
                 o = o + a["bo"].astype(cfg.dtype)
-            return o, stage_l
+            return o
+
+        def norm(p_ln, x):
+            with device_scope("norm"):
+                return Norm(m).apply({"params": p_ln}, x)
 
         def layer(x, p, li, use_moe, stage_l):
             qli = li if qstack else None
-            h_attn = Norm(m).apply({"params": p["ln_attn"]}, x)
+            h_attn = norm(p["ln_attn"], x)
             o, stage_l = attention(p, li, h_attn, stage_l)
-            if m.parallel_block:
-                h_ffn = h_attn if m.parallel_block_norms == 1 else \
-                    Norm(m).apply({"params": p["ln_ffn"]}, x)
-                return x + o + ffn(p, h_ffn, use_moe, qli), stage_l
-            x = x + o
-            h_ffn = Norm(m).apply({"params": p["ln_ffn"]}, x)
-            return x + ffn(p, h_ffn, use_moe, qli), stage_l
+            if not m.parallel_block:
+                x = x + o
+            h_ffn = h_attn if m.parallel_block \
+                and m.parallel_block_norms == 1 else norm(p["ln_ffn"], x)
+            with device_scope("ffn"):
+                f = ffn(p, h_ffn, use_moe, qli)
+            return (x + o + f if m.parallel_block else x + f), stage_l
 
         # the pool stays read-only for the whole program: `attention`
         # closes over this alias, never the (later re-bound) kv_pool
@@ -1619,6 +1646,13 @@ class InferenceEngineV2:
             # pool never enters the carry — only the small staged KV does
             L = m.num_layers
             lidx = jnp.arange(L, dtype=jnp.int32)
+
+            def take(i):
+                with device_scope("weight_walk"):
+                    return jax.tree.map(
+                        lambda s: jax.lax.dynamic_index_in_dim(
+                            s, i, 0, keepdims=False), scanned_layers)
+
             if cfg.weight_prefetch and L > 1:
                 # double-buffered weight walk: layer i+1's parameter
                 # gather rides the scan CARRY and is issued before layer
@@ -1630,11 +1664,6 @@ class InferenceEngineV2:
                 # of weights resident. Quantized codes are NOT carried
                 # (stripped into qstack; the Pallas kernels stream them
                 # via scalar-prefetched layer indices).
-                def take(i):
-                    return jax.tree.map(
-                        lambda s: jax.lax.dynamic_index_in_dim(
-                            s, i, 0, keepdims=False), scanned_layers)
-
                 def body(carry, inp):
                     if window_mode:
                         li, stage_l = inp
@@ -1650,24 +1679,20 @@ class InferenceEngineV2:
                 xs = (lidx, kv_stage) if window_mode else lidx
                 (x, _), (k_ys, v_ys) = jax.lax.scan(body, (x, take(0)), xs)
             else:
+                # (the layer's slice is taken in the body, not by the
+                # scan: the same dynamic-slice, under a scope of its own)
                 def body(xc, inp):
                     if window_mode:
-                        p_i, li, stage_l = inp
+                        li, stage_l = inp
                     else:
-                        p_i, li = inp
+                        li = inp
                         stage_l = empty_stage
-                    x2, stage_l = layer(xc, p_i, li, is_moe_layer(m, 0),
-                                        stage_l)
+                    x2, stage_l = layer(xc, take(li), li,
+                                        is_moe_layer(m, 0), stage_l)
                     return x2, stage_l
 
-                if window_mode:
-                    k_buf, v_buf = kv_stage
-                    x, (k_ys, v_ys) = jax.lax.scan(
-                        body, x, (scanned_layers, lidx,
-                                  (k_buf, v_buf)))
-                else:
-                    x, (k_ys, v_ys) = jax.lax.scan(
-                        body, x, (scanned_layers, lidx))
+                xs = (lidx, kv_stage) if window_mode else lidx
+                x, (k_ys, v_ys) = jax.lax.scan(body, x, xs)
         else:
             k_list, v_list = [], []
             for i in range(m.num_layers):
@@ -1679,43 +1704,49 @@ class InferenceEngineV2:
                 k_list.append(stage_l[0])
                 v_list.append(stage_l[1])
             k_ys, v_ys = jnp.stack(k_list), jnp.stack(v_list)
-        x = Norm(m).apply({"params": params["ln_final"]}, x)
-        if tree_mode:
-            # the verify step samples at EVERY tree node: all-position
-            # logits ([S*T, E] rows through the same projection paths)
-            last = x.reshape(S * T, -1)
-        else:
-            last = jnp.take_along_axis(
-                x, sample_idx[:, None, None].astype(jnp.int32),
-                axis=1)[:, 0]                                      # [S,E]
-        if rn:
-            # leave the token-sharded stream: the logits projection reads
-            # S rows total — replicating them is noise next to the weight
-            last = jax.lax.with_sharding_constraint(
-                last, NamedSharding(mesh_t, P(None, None)))
-        if m.tie_embeddings:
-            if "logits_q" in params:
-                # tied models keep the embedding gather exact but project
-                # logits through an int8 COPY of the table — the decode
-                # step's single largest weight read (103MB bf16 on
-                # gpt2-350m, ~0.14ms/token). At M<=8 rows quant_matmul's
-                # small-M dispatch routes this through XLA's fused
-                # dequant-dot (convert+mul folded into the operand read:
-                # measured 122us vs 138 bf16 vs 271 for the Pallas tile
-                # kernel, whose whole-table dequant is VPU-bound at few
-                # rows); int4 keeps the Pallas kernel (XLA can't fuse the
-                # nibble unpack). Both single- and multi-device go
-                # through _qmm — per-shard, the same dispatch applies.
-                logits = self._qmm(last, params["logits_q"], "logits")
+
+        def head(x):
+            x = Norm(m).apply({"params": params["ln_final"]}, x)
+            if tree_mode:
+                # the verify step samples at EVERY tree node: all-position
+                # logits ([S*T, E] rows through the same projection paths)
+                last = x.reshape(S * T, -1)
             else:
-                logits = jnp.einsum("se,ve->sv", last,
-                                    params["embed"].astype(cfg.dtype))
-        elif isinstance(params["unembed"], QuantLinear):
-            logits = self._qmm(last, params["unembed"], "unembed")
-        else:
-            logits = jnp.einsum("se,ev->sv", last, params["unembed"].astype(cfg.dtype))
-        if m.unembed_bias:
-            logits = logits + params["unembed_b"].astype(cfg.dtype)
+                last = jnp.take_along_axis(
+                    x, sample_idx[:, None, None].astype(jnp.int32),
+                    axis=1)[:, 0]                                      # [S,E]
+            if rn:
+                # leave the token-sharded stream: the logits projection reads
+                # S rows total — replicating them is noise next to the weight
+                last = jax.lax.with_sharding_constraint(
+                    last, NamedSharding(mesh_t, P(None, None)))
+            if m.tie_embeddings:
+                if "logits_q" in params:
+                    # tied models keep the embedding gather exact but project
+                    # logits through an int8 COPY of the table — the decode
+                    # step's single largest weight read (103MB bf16 on
+                    # gpt2-350m, ~0.14ms/token). At M<=8 rows quant_matmul's
+                    # small-M dispatch routes this through XLA's fused
+                    # dequant-dot (convert+mul folded into the operand read:
+                    # measured 122us vs 138 bf16 vs 271 for the Pallas tile
+                    # kernel, whose whole-table dequant is VPU-bound at few
+                    # rows); int4 keeps the Pallas kernel (XLA can't fuse the
+                    # nibble unpack). Both single- and multi-device go
+                    # through _qmm — per-shard, the same dispatch applies.
+                    logits = self._qmm(last, params["logits_q"], "logits")
+                else:
+                    logits = jnp.einsum("se,ve->sv", last,
+                                        params["embed"].astype(cfg.dtype))
+            elif isinstance(params["unembed"], QuantLinear):
+                logits = self._qmm(last, params["unembed"], "unembed")
+            else:
+                logits = jnp.einsum("se,ev->sv", last, params["unembed"].astype(cfg.dtype))
+            if m.unembed_bias:
+                logits = logits + params["unembed_b"].astype(cfg.dtype)
+            return logits
+
+        with device_scope("head"):
+            logits = head(x)
         if tree_mode:
             # verify mode: NO pool write here — the caller merges only
             # the accepted path's staged rows (_spec_merge_program), so
@@ -1739,10 +1770,11 @@ class InferenceEngineV2:
         elif not self._ring_tokens and T % bs == 0:
             kv_pool = self._merge_pages(kv_pool, slot_map, k_ys, v_ys, T)
         else:
-            ks = (k_ys[:, :, :, :T, :].transpose(0, 1, 3, 2, 4)
-                  .reshape(L, S * T, KV, D))
-            vs = (v_ys[:, :, :, :T, :].transpose(0, 1, 3, 2, 4)
-                  .reshape(L, S * T, KV, D))
+            with device_scope("kv_commit"):
+                ks = (k_ys[:, :, :, :T, :].transpose(0, 1, 3, 2, 4)
+                      .reshape(L, S * T, KV, D))
+                vs = (v_ys[:, :, :, :T, :].transpose(0, 1, 3, 2, 4)
+                      .reshape(L, S * T, KV, D))
             kv_pool = self._merge_stage(kv_pool, slot_map.reshape(-1),
                                         ks, vs)
         return kv_pool, logits
@@ -1763,14 +1795,15 @@ class InferenceEngineV2:
         the layout-NEUTRAL dynamic-update-slice merges (``_merge_rows``,
         ``_merge_pages``) and fall back here only for configurations
         those can't express."""
-        bs = self.config.block_size
-        blk, off = flat_slots // bs, flat_slots % bs
-        liL = jnp.arange(kv_pool.shape[0])
-        kv_pool = kv_pool.at[liL[:, None], 0, :, blk[None, :],
-                             off[None, :]].set(ks.astype(kv_pool.dtype))
-        kv_pool = kv_pool.at[liL[:, None], 1, :, blk[None, :],
-                             off[None, :]].set(vs.astype(kv_pool.dtype))
-        return kv_pool
+        with device_scope("kv_commit"):
+            bs = self.config.block_size
+            blk, off = flat_slots // bs, flat_slots % bs
+            liL = jnp.arange(kv_pool.shape[0])
+            kv_pool = kv_pool.at[liL[:, None], 0, :, blk[None, :],
+                                 off[None, :]].set(ks.astype(kv_pool.dtype))
+            kv_pool = kv_pool.at[liL[:, None], 1, :, blk[None, :],
+                                 off[None, :]].set(vs.astype(kv_pool.dtype))
+            return kv_pool
 
     def _merge_rows(self, kv_pool, flat_slots, k_rows, v_rows):
         """Token-granular pool merge: one dynamic-update-slice per row
@@ -1779,15 +1812,16 @@ class InferenceEngineV2:
         granularity never clobbers neighbouring rows, so it is safe in
         ring (rolling-buffer) mode too. N is small by construction
         (decode plans: S; windows: W*S)."""
-        bs = self.config.block_size
-        kv_rows = jnp.stack([k_rows, v_rows], axis=1).astype(kv_pool.dtype)
-        z = jnp.int32(0)
-        for n in range(flat_slots.shape[0]):
-            upd = kv_rows[:, :, n][:, :, :, None, None, :]  # [L,2,KV,1,1,D]
-            kv_pool = jax.lax.dynamic_update_slice(
-                kv_pool, upd,
-                (z, z, z, flat_slots[n] // bs, flat_slots[n] % bs, z))
-        return kv_pool
+        with device_scope("kv_commit"):
+            bs = self.config.block_size
+            kv_rows = jnp.stack([k_rows, v_rows], axis=1).astype(kv_pool.dtype)
+            z = jnp.int32(0)
+            for n in range(flat_slots.shape[0]):
+                upd = kv_rows[:, :, n][:, :, :, None, None, :]  # [L,2,KV,1,1,D]
+                kv_pool = jax.lax.dynamic_update_slice(
+                    kv_pool, upd,
+                    (z, z, z, flat_slots[n] // bs, flat_slots[n] % bs, z))
+            return kv_pool
 
     def _merge_pages(self, kv_pool, slot_map, k_ys, v_ys, T):
         """Page-granular pool merge for SplitFuse chunk steps
@@ -1800,40 +1834,41 @@ class InferenceEngineV2:
         NOT page-write (their page holds live earlier rows): for those
         the page update degrades to a read-back of the current page, and
         a per-row token DUS writes the one real token."""
-        L, _, KV, nb, bs, D = kv_pool.shape
-        S = slot_map.shape[0]
-        z = jnp.int32(0)
-        n_real = (slot_map >= bs).sum(axis=1)          # trash slots < bs
-        for s in range(S):
-            # page-write only rows that really carry a chunk AND start on
-            # a page boundary (the scheduler advances kv_next in whole
-            # chunks so this holds today; the traced check pins the
-            # invariant rather than assuming it)
-            no_page = (n_real[s] <= 1) | (slot_map[s, 0] % bs != 0)
-            for pg in range(T // bs):
-                sl = pg * bs
-                page = jnp.stack(
-                    [k_ys[:, s, :, sl:sl + bs, :],
-                     v_ys[:, s, :, sl:sl + bs, :]],
-                    axis=1)[:, :, :, None].astype(kv_pool.dtype)
-                blk = slot_map[s, sl] // bs
-                if pg == 0:
-                    # read-modify-write: a single-token/misaligned row's
-                    # first page holds live earlier KV
-                    cur = jax.lax.dynamic_slice(
-                        kv_pool, (z, z, z, blk, z, z), (L, 2, KV, 1, bs, D))
-                    page = jnp.where(no_page, cur, page)
-                else:
-                    # later pages of degraded rows carry trash slots
-                    # (block 0) — writing garbage there is the existing
-                    # trash-block convention, no read-back needed
-                    blk = jnp.where(no_page, 0, blk)
-                kv_pool = jax.lax.dynamic_update_slice(
-                    kv_pool, page, (z, z, z, blk, z, z))
-        # every row's first token (covers degraded rows; for full chunks
-        # this rewrites the value the page already wrote)
-        return self._merge_rows(kv_pool, slot_map[:, 0],
-                                k_ys[:, :, :, 0, :], v_ys[:, :, :, 0, :])
+        with device_scope("kv_commit"):
+            L, _, KV, nb, bs, D = kv_pool.shape
+            S = slot_map.shape[0]
+            z = jnp.int32(0)
+            n_real = (slot_map >= bs).sum(axis=1)          # trash slots < bs
+            for s in range(S):
+                # page-write only rows that really carry a chunk AND start on
+                # a page boundary (the scheduler advances kv_next in whole
+                # chunks so this holds today; the traced check pins the
+                # invariant rather than assuming it)
+                no_page = (n_real[s] <= 1) | (slot_map[s, 0] % bs != 0)
+                for pg in range(T // bs):
+                    sl = pg * bs
+                    page = jnp.stack(
+                        [k_ys[:, s, :, sl:sl + bs, :],
+                         v_ys[:, s, :, sl:sl + bs, :]],
+                        axis=1)[:, :, :, None].astype(kv_pool.dtype)
+                    blk = slot_map[s, sl] // bs
+                    if pg == 0:
+                        # read-modify-write: a single-token/misaligned row's
+                        # first page holds live earlier KV
+                        cur = jax.lax.dynamic_slice(
+                            kv_pool, (z, z, z, blk, z, z), (L, 2, KV, 1, bs, D))
+                        page = jnp.where(no_page, cur, page)
+                    else:
+                        # later pages of degraded rows carry trash slots
+                        # (block 0) — writing garbage there is the existing
+                        # trash-block convention, no read-back needed
+                        blk = jnp.where(no_page, 0, blk)
+                    kv_pool = jax.lax.dynamic_update_slice(
+                        kv_pool, page, (z, z, z, blk, z, z))
+            # every row's first token (covers degraded rows; for full chunks
+            # this rewrites the value the page already wrote)
+            return self._merge_rows(kv_pool, slot_map[:, 0],
+                                    k_ys[:, :, :, 0, :], v_ys[:, :, :, 0, :])
 
     def _program(self, T: int, S_rows: int | None = None):
         """Step program for a [S_rows, T] plan. Packed prefill plans
@@ -1858,12 +1893,13 @@ class InferenceEngineV2:
                         params, kv_pool, token_ids, positions, slot_map,
                         block_tables, seq_lens, sample_idx)
                 cfg = self.config
-                toks = sample_logits(logits.astype(jnp.float32), rng,
-                                     temperature=cfg.temperature,
-                                     top_k=cfg.top_k, top_p=cfg.top_p,
-                                     greedy=cfg.greedy)
-                last_tok = last_tok.at[row_slots].set(
-                    jnp.where(do_sample.astype(bool), toks, row_last))
+                with device_scope("sample"):
+                    toks = sample_logits(logits.astype(jnp.float32), rng,
+                                         temperature=cfg.temperature,
+                                         top_k=cfg.top_k, top_p=cfg.top_p,
+                                         greedy=cfg.greedy)
+                    last_tok = last_tok.at[row_slots].set(
+                        jnp.where(do_sample.astype(bool), toks, row_last))
                 return kv_pool, last_tok, toks
 
             # distinct module names per kind: device traces attribute
@@ -1875,10 +1911,10 @@ class InferenceEngineV2:
             # intermediates, letting XLA choose (None) can shard last_tok's
             # output and break its donation alias (replicated input)
             repl = NamedSharding(self.topology.mesh, P())
-            self._programs[key] = jax.jit(
+            self._programs[key] = register_program(jax.jit(
                 step, donate_argnums=(1, 2),
                 in_shardings=(None, self._pool_format) + (None,) * 11,
-                out_shardings=(self._pool_format, repl, repl))
+                out_shardings=(self._pool_format, repl, repl)))
         return self._programs[key]
 
     def _window_program(self, W: int):
@@ -1943,17 +1979,20 @@ class InferenceEngineV2:
                             jnp.zeros_like(pos),
                             kv_stage=(kbuf, vbuf), stage_fill=i,
                             stage_starts=base)
-                    rng, sub = jax.random.split(rng)
-                    nxt = sample_logits(logits.astype(jnp.float32), sub,
-                                        temperature=cfg.temperature,
-                                        top_k=cfg.top_k, top_p=cfg.top_p,
-                                        greedy=cfg.greedy)
-                    out_tok = jnp.where(active, nxt, -1)
-                    # slots stop at their eos or when their budget is spent
-                    nxt_active = active & (nxt != eos_ids) & (i + 1 < rem)
-                    tok = jnp.where(active, nxt, tok)
-                    pos = jnp.where(active, pos + 1, pos)
-                    lens = jnp.where(active, lens + 1, lens)
+                    with device_scope("sample"):
+                        rng, sub = jax.random.split(rng)
+                        nxt = sample_logits(logits.astype(jnp.float32), sub,
+                                            temperature=cfg.temperature,
+                                            top_k=cfg.top_k, top_p=cfg.top_p,
+                                            greedy=cfg.greedy)
+                        out_tok = jnp.where(active, nxt, -1)
+                        # slots stop at their eos or when their budget is
+                        # spent
+                        nxt_active = active & (nxt != eos_ids) \
+                            & (i + 1 < rem)
+                        tok = jnp.where(active, nxt, tok)
+                        pos = jnp.where(active, pos + 1, pos)
+                        lens = jnp.where(active, lens + 1, lens)
                     return (out_tok, slot, tok, pos, lens, rng, nxt_active,
                             kbuf, vbuf)
 
@@ -2010,20 +2049,21 @@ class InferenceEngineV2:
                 # merge the WHOLE window's staged KV into the pool — the
                 # one pool write of this program (the pool stayed
                 # read-only through every iteration above)
-                ks = (kbuf[:, :, :, :W, :].transpose(0, 3, 1, 2, 4)
-                      .reshape(L, W * S, KV, D))
-                vs = (vbuf[:, :, :, :W, :].transpose(0, 3, 1, 2, 4)
-                      .reshape(L, W * S, KV, D))
-                kv_pool = self._merge_rows(kv_pool, slots.reshape(-1),
-                                           ks, vs)
+                with device_scope("kv_commit"):
+                    ks = (kbuf[:, :, :, :W, :].transpose(0, 3, 1, 2, 4)
+                          .reshape(L, W * S, KV, D))
+                    vs = (vbuf[:, :, :, :W, :].transpose(0, 3, 1, 2, 4)
+                          .reshape(L, W * S, KV, D))
+                    kv_pool = self._merge_rows(kv_pool, slots.reshape(-1),
+                                               ks, vs)
                 return kv_pool, tok, buf, i        # toks [W, S], iters run
 
             # non-pool outputs pinned replicated (see _program)
             repl = NamedSharding(self.topology.mesh, P())
-            self._programs[key] = jax.jit(
+            self._programs[key] = register_program(jax.jit(
                 run, donate_argnums=(1, 2),
                 in_shardings=(None, self._pool_format) + (None,) * 9,
-                out_shardings=(self._pool_format, repl, repl, repl))
+                out_shardings=(self._pool_format, repl, repl, repl)))
         return self._programs[key]
 
     def warm_decode_windows(self, sizes: list[int] | None = None,
@@ -2166,19 +2206,20 @@ class InferenceEngineV2:
                         block_tables, seq_lens,
                         jnp.zeros(token_ids.shape[0], jnp.int32),
                         tree_mask=tree_mask)
-                toks = sample_tree_logits(logits.astype(jnp.float32), rng,
-                                          temperature=cfg.temperature,
-                                          top_k=cfg.top_k, top_p=cfg.top_p,
-                                          greedy=cfg.greedy)
+                with device_scope("sample"):
+                    toks = sample_tree_logits(
+                        logits.astype(jnp.float32), rng,
+                        temperature=cfg.temperature, top_k=cfg.top_k,
+                        top_p=cfg.top_p, greedy=cfg.greedy)
                 return k_ys, v_ys, toks
 
             run.__name__ = "step_spec_verify"
             repl = NamedSharding(self.topology.mesh, P())
             # pool NOT donated: it stays live (unchanged) for the merge
             # program that runs after the host-side acceptance walk
-            self._programs[key] = jax.jit(
+            self._programs[key] = register_program(jax.jit(
                 run, in_shardings=(None, self._pool_format) + (None,) * 7,
-                out_shardings=(repl, repl, repl))
+                out_shardings=(repl, repl, repl)))
         return self._programs[key]
 
     def _spec_merge_program(self, T: int):
@@ -2193,17 +2234,18 @@ class InferenceEngineV2:
 
             def run(kv_pool, k_ys, v_ys, flat_slots):
                 L, S = k_ys.shape[0], k_ys.shape[1]
-                ks = (k_ys[:, :, :, :T, :].transpose(0, 1, 3, 2, 4)
-                      .reshape(L, S * T, m.kv_heads, m.head_dim))
-                vs = (v_ys[:, :, :, :T, :].transpose(0, 1, 3, 2, 4)
-                      .reshape(L, S * T, m.kv_heads, m.head_dim))
-                return self._merge_rows(kv_pool, flat_slots, ks, vs)
+                with device_scope("kv_commit"):
+                    ks = (k_ys[:, :, :, :T, :].transpose(0, 1, 3, 2, 4)
+                          .reshape(L, S * T, m.kv_heads, m.head_dim))
+                    vs = (v_ys[:, :, :, :T, :].transpose(0, 1, 3, 2, 4)
+                          .reshape(L, S * T, m.kv_heads, m.head_dim))
+                    return self._merge_rows(kv_pool, flat_slots, ks, vs)
 
             run.__name__ = "spec_merge"
-            self._programs[key] = jax.jit(
+            self._programs[key] = register_program(jax.jit(
                 run, donate_argnums=(0,),
                 in_shardings=(self._pool_format, None, None, None),
-                out_shardings=self._pool_format)
+                out_shardings=self._pool_format))
         return self._programs[key]
 
     def _try_dispatch_spec(self, prefill_pending: bool = False) -> bool:

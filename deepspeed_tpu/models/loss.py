@@ -13,6 +13,8 @@ import os
 import jax
 import jax.numpy as jnp
 
+from ..utils.annotations import device_scope
+
 IGNORE_INDEX = -100
 
 #: token rows per chunk of the streaming cross-entropy; 0 (default) =
@@ -341,12 +343,14 @@ def lm_loss_fn(model, params, batch, deterministic: bool = True):
             w, w_is_ve = params["unembed"].astype(cfg.dtype), False
         bias = params["unembed_b"] if getattr(cfg, "unembed_bias", False) \
             else None
-        loss = fused_lm_head_loss(hidden, w, labels, bias=bias,
-                                  w_is_ve=w_is_ve, vchunk=vchunk)
+        with device_scope("head_loss"):
+            loss = fused_lm_head_loss(hidden, w, labels, bias=bias,
+                                      w_is_ve=w_is_ve, vchunk=vchunk)
     else:
         out, variables = model.apply({"params": params}, input_ids,
                                      mutable=["losses"], **kwargs)
-        loss = cross_entropy_lm(out, labels)
+        with device_scope("head_loss"):
+            loss = cross_entropy_lm(out, labels)
     for leaf in jax.tree.leaves(variables.get("losses", {})):
         loss = loss + jnp.sum(leaf)
     return loss

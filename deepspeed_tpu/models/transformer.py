@@ -48,6 +48,7 @@ from ..parallel.axes import (  # noqa: E402
     constrain,
 )
 from ..parallel.tensor import current_tp_overlap, ring_row_matmul
+from ..utils.annotations import device_scope
 
 
 def default_activation_rules(topology) -> list[tuple[str, Any]]:
@@ -621,19 +622,22 @@ class TransformerLM(nn.Module):
         embed = self.param("embed", nn.with_partitioning(
             nn.initializers.normal(0.02), ("vocab", "embed")),
             (cfg.vocab_size, cfg.hidden_size), jnp.float32)
-        x = embed.astype(cfg.dtype)[input_ids]
+        with device_scope("embed"):
+            x = embed.astype(cfg.dtype)[input_ids]
         if cfg.position_embedding == "learned":
             pos_emb = self.param("pos_embed", nn.with_partitioning(
                 nn.initializers.normal(0.02), (None, "embed")),
                 (cfg.max_seq_len, cfg.hidden_size), jnp.float32)
-            x = x + pos_emb.astype(cfg.dtype)[positions]
+            with device_scope("embed"):
+                x = x + pos_emb.astype(cfg.dtype)[positions]
         if cfg.type_vocab_size:
             type_emb = self.param("type_embed", nn.with_partitioning(
                 nn.initializers.normal(0.02), (None, "embed")),
                 (cfg.type_vocab_size, cfg.hidden_size), jnp.float32)
             if token_type_ids is None:
                 token_type_ids = jnp.zeros_like(input_ids)
-            x = x + type_emb.astype(cfg.dtype)[token_type_ids]
+            with device_scope("embed"):
+                x = x + type_emb.astype(cfg.dtype)[token_type_ids]
         if cfg.embed_norm or not cfg.pre_norm:
             # bert: layernorm + dropout on the embedding sum; bloom:
             # word_embeddings_layernorm ahead of pre-norm blocks
@@ -670,17 +674,21 @@ class TransformerLM(nn.Module):
             # never built
             return x
         if cfg.tie_embeddings:
-            logits = jnp.einsum("bse,ve->bsv", x, embed.astype(cfg.dtype))
+            with device_scope("head_loss"):
+                logits = jnp.einsum("bse,ve->bsv", x, embed.astype(cfg.dtype))
         else:
             unembed = self.param("unembed", nn.with_partitioning(
                 nn.initializers.normal(0.02), ("embed", "vocab")),
                 (cfg.hidden_size, cfg.vocab_size), jnp.float32)
-            logits = jnp.einsum("bse,ev->bsv", x, unembed.astype(cfg.dtype))
+            with device_scope("head_loss"):
+                logits = jnp.einsum("bse,ev->bsv", x,
+                                    unembed.astype(cfg.dtype))
         if cfg.unembed_bias:
             ub = self.param("unembed_b", nn.with_partitioning(
                 nn.initializers.zeros, ("vocab",)),
                 (cfg.vocab_size,), jnp.float32)
-            logits = logits + ub.astype(cfg.dtype)
+            with device_scope("head_loss"):
+                logits = logits + ub.astype(cfg.dtype)
         logits = constrain(logits, BATCH, SEQ, None)
         if kv_caches is not None:
             return logits, new_caches

@@ -166,6 +166,7 @@ def _fwd(q, k, v, tbl_q, cnt_q, *, scale, causal, block, interpret):
             jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
             jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32),
         ],
+        name="block_sparse_fwd",
         interpret=interpret,
     )(tbl_q, cnt_q, q, k, v)
 
@@ -274,6 +275,7 @@ def _bwd(causal, scale, block, interpret, res, do):
             scratch_shapes=[pltpu.VMEM((block, D), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+        name="block_sparse_bwd_dq",
         interpret=interpret,
     )(tbl_q, cnt_q, q, k, v, do, lse, delta)
 
@@ -297,6 +299,7 @@ def _bwd(causal, scale, block, interpret, res, do):
         ),
         out_shape=[jax.ShapeDtypeStruct((B, H, S, D), k.dtype),
                    jax.ShapeDtypeStruct((B, H, S, D), v.dtype)],
+        name="block_sparse_bwd_dkv",
         interpret=interpret,
     )(tbl_k, cnt_k, q, k, v, do, lse, delta)
     return dq, dk, dv, None, None, None, None

@@ -275,6 +275,7 @@ def _fwd(q, k, v, *, causal: bool, scale: float,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
+        name="flash_attention_fwd",
         interpret=_interpret(),
     )(q, k, v)
     return out, lse
@@ -450,6 +451,7 @@ def _bwd_merged(causal, scale, block_q, block_k, res, do):
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
+        name="flash_attention_bwd_dqkv",
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
 
@@ -492,6 +494,7 @@ def _bwd(causal, scale, block_q, block_k, res, do):
                                lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        name="flash_attention_bwd_dq",
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
 
@@ -523,6 +526,7 @@ def _bwd(causal, scale, block_q, block_k, res, do):
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
+        name="flash_attention_bwd_dkv",
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
 

@@ -104,6 +104,7 @@ def _gmm_call(x, w, tile_expert, *, block_m: int, transpose_rhs: bool,
         functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Tp, N), x.dtype),
+        name="grouped_matmul_fwd",
         interpret=interpret,
     )(tile_expert.astype(jnp.int32), x, w)
 
@@ -173,6 +174,7 @@ def _dw_call(x, dy, tile_expert, n_exp: int, *, block_m: int,
         _dw_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_exp, E, F), jnp.float32),
+        name="grouped_matmul_bwd_dw",
         interpret=interpret,
     )(tile_expert.astype(jnp.int32), x, dy)
     # experts that own no tiles were never written — mask their garbage

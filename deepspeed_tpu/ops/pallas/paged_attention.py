@@ -529,6 +529,8 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
                           p_scale=p_scale, tree=tree),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, KV, TG, D), q.dtype),
+        name=("paged_attn_tree" if tree else "paged_attn_decode" if T == 1
+              else "paged_attn_prefill"),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
       q_starts.astype(jnp.int32), stage_starts.astype(jnp.int32),
@@ -616,6 +618,7 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, seq_lens,
         functools.partial(_paged_attn_kernel, **kw),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, KV, T * G, D), q.dtype),
+        name="paged_attn_slice_decode" if T == 1 else "paged_attn_slice_prefill",
         interpret=interpret,
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
       chunk_starts.astype(jnp.int32), qg, kp, vp)

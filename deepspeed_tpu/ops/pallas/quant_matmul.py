@@ -360,6 +360,7 @@ def quant_matmul(x: jax.Array, qw: QuantLinear, *,
             out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
             out_shape=jax.ShapeDtypeStruct((Mp, N), out_dtype),
+            name="quant_matmul",
             interpret=interpret,
         )(*((x,) if int8_like else (x[:, 0::2], x[:, 1::2])),
           qw.data, scale3)
@@ -386,6 +387,7 @@ def quant_matmul(x: jax.Array, qw: QuantLinear, *,
             functools.partial(kern, G=G, dtype=mm_dtype),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((Mp, N), out_dtype),
+            name="quant_matmul_stacked",
             interpret=interpret,
         )(jnp.asarray(layer_index, jnp.int32).reshape(1),
           *((x,) if int8_like else (x[:, 0::2], x[:, 1::2])),
@@ -606,6 +608,7 @@ def quant_grouped_matmul(x: jax.Array, qw: QuantGrouped,
             functools.partial(kern, G=G, dtype=mm_dtype),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((Tp, N), x.dtype),
+            name="quant_grouped_matmul",
             interpret=interpret,
         )(tile_expert.astype(jnp.int32), *x_ops, qw.data, scale4)
     else:
@@ -630,6 +633,7 @@ def quant_grouped_matmul(x: jax.Array, qw: QuantGrouped,
             functools.partial(kern, G=G, dtype=mm_dtype),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((Tp, N), x.dtype),
+            name="quant_grouped_matmul_stacked",
             interpret=interpret,
         )(tile_expert.astype(jnp.int32),
           jnp.asarray(layer_index, jnp.int32).reshape(1),

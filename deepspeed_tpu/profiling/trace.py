@@ -2,26 +2,38 @@
 
 Reference profiling surfaces kernel timelines via nsight/torch profiler;
 on TPU the equivalent is a ``jax.profiler`` trace whose xplane protobuf
-carries per-op device timings. The stock tensorboard converter is broken in
-some images, so this module parses the xplane directly (the recipe from
-.claude/skills/verify) and aggregates exclusive device time per op — the
-tool used to find this framework's own train-step bottlenecks.
+carries per-op device timings. This module reads it with
+``jax.profiler.ProfileData`` (no TensorFlow) and aggregates SELF device
+time per op (:func:`op_breakdown`) or per named scope
+(:func:`scope_breakdown`).
+
+The device trace names an op by its HLO instruction (``%fusion.12``) and
+carries no ``op_name`` metadata, so the op → scope table comes from the
+program: the engines hand every jitted program they create to
+:func:`register_program`, and :func:`program_scope_maps` lowers, fetches
+and parses the compiled HLO of the registered programs — only when a
+reader asks. Until then an entry is the jitted function and the abstract
+arguments of its first call.
 """
 from __future__ import annotations
 
+import bisect
 import collections
 import glob
 import os
 import re
+import weakref
 from contextlib import contextmanager
 
 import jax
+
+from ..utils.annotations import DEVICE_SCOPES, MODULE_SCOPES
 
 
 @contextmanager
 def trace(log_dir: str):
     """Capture a device trace: ``with trace(dir): run_steps()``. Pair with
-    :func:`op_breakdown` to read it back."""
+    :func:`op_breakdown` / :func:`scope_breakdown` to read it back."""
     jax.profiler.start_trace(log_dir)
     try:
         yield
@@ -30,6 +42,8 @@ def trace(log_dir: str):
 
 
 def _latest_xplane(log_dir: str) -> str:
+    if os.path.isfile(log_dir):
+        return log_dir
     paths = sorted(glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
                              recursive=True))
     if not paths:
@@ -38,37 +52,279 @@ def _latest_xplane(log_dir: str) -> str:
     return paths[-1]
 
 
+# ---- the scope map: compiled HLO text -> {instruction: op_name} ---------
+
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_LAYER_N = re.compile(r"^layer_\d+$")
+_WRAPPED = re.compile(r"^(?:\w+\()+|\)+$")
+_SCOPE_NAMES = frozenset(DEVICE_SCOPES) | frozenset(MODULE_SCOPES)
+UNSCOPED = "unscoped"
+AMBIGUOUS = "ambiguous"
+
+
+def scope_of(op_name: str | None) -> tuple[str, str]:
+    """(scope, direction) of one ``op_name`` path. The scope is the first
+    declared name on the path (``DEVICE_SCOPES`` or a flax module name;
+    ``layer_N`` folds to ``layer`` and takes the next name with it:
+    ``layer/attn``), or ``unscoped``. The direction needs no scope: the
+    path says ``transpose(`` for the backward pass and
+    ``rematted_computation`` for what remat runs again."""
+    parts = (op_name or "").split("/")
+    direction = ("recompute" if "rematted_computation" in parts else
+                 "bwd" if any(p.startswith("transpose(") for p in parts)
+                 else "fwd")
+    # a transform wraps the first scope it meets (``jvp(head_loss)``) and
+    # the path can carry that scope twice over:
+    # ``transpose(jvp(layer_1))/jvp(layer_1)/checkpoint/ffn/mul``
+    names: list[str] = []
+    for p in parts:
+        p = _WRAPPED.sub("", p)
+        n = "layer" if _LAYER_N.match(p) else p
+        if n in _SCOPE_NAMES and names[-1:] != [n]:
+            names.append(n)
+    if not names:
+        return UNSCOPED, direction
+    if names[0] == "layer" and len(names) > 1:
+        return f"layer/{names[1]}", direction
+    return names[0], direction
+
+
+def parse_hlo_scopes(text: str) -> tuple[str, dict[str, str]]:
+    """(module name, {instruction name: op_name path}) from the text of a
+    COMPILED module. A fusion (or call) takes its own ``op_name``; where
+    the compiler left none on it, that of the root of the computation it
+    calls, or failing that the op_name of the most common scope in there.
+    Instructions with no name at all map to ``""``."""
+    module = text.split(None, 2)[1].rstrip(",") if text.startswith(
+        "HloModule") else ""
+    own: dict[str, str] = {}
+    calls: dict[str, str] = {}
+    roots: dict[str, str] = {}
+    members: dict[str, list[str]] = collections.defaultdict(list)
+    comp = None
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            c = _COMPUTATION.match(line)
+            comp = c.group(1) if c else comp
+            continue
+        is_root, name = m.groups()
+        head = line[:line.find("backend_config=")] \
+            if "backend_config=" in line else line
+        op = _OP_NAME.search(line)
+        own[name] = op.group(1) if op else ""
+        called = _CALLS.search(head)
+        if called:
+            calls[name] = called.group(1)
+        if comp is not None:
+            members[comp].append(name)
+            if is_root:
+                roots[comp] = name
+    out = dict(own)
+    for name, callee in calls.items():
+        if own[name]:
+            continue
+        root = own.get(roots.get(callee, ""), "")
+        named = [own[i] for i in members.get(callee, ()) if own[i]]
+        if not root and named:
+            best = collections.Counter(map(scope_of, named)).most_common(1)
+            root = next(o for o in named if scope_of(o) == best[0][0])
+        out[name] = root
+    return module, out
+
+
+class RegisteredProgram:
+    """A jitted program as its engine holds it: calls go straight through;
+    the first one leaves the abstract arguments behind, from which the
+    compiled HLO can be had again later (jit's own cache answers). One
+    entry stands for ONE compiled program: the engines hold a jit of its
+    own for every shape; a jit that went on to compile for other shapes
+    too is read by its first."""
+    __slots__ = ("fn", "avals", "_parsed", "__weakref__")
+
+    def __init__(self, fn):
+        self.fn = fn
+        self.avals = None
+        self._parsed = None
+
+    def __call__(self, *args, **kwargs):
+        if self.avals is None:
+            self.avals = jax.tree.map(_abstract, (args, kwargs))
+        return self.fn(*args, **kwargs)
+
+    def __getattr__(self, name):
+        return getattr(self.fn, name)
+
+    @property
+    def module_name(self) -> str:
+        return "jit_" + re.sub(r"[^\w.\-]", "_", self.fn.__name__)
+
+    def scopes(self) -> dict:
+        """``{"module", "ops": {instruction: op_name}, "hlo_bytes"}`` —
+        lowered, compiled (or read from the compile cache) and parsed on
+        the first request, kept after."""
+        if self._parsed is None:
+            args, kwargs = self.avals
+            text = self.fn.lower(*args, **kwargs).compile().as_text()
+            module, ops = parse_hlo_scopes(text)
+            self._parsed = {"module": module, "ops": ops,
+                            "hlo_bytes": len(text)}
+        return self._parsed
+
+
+def _abstract(x):
+    if isinstance(x, jax.Array):
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=x.sharding if x.committed else None)
+    a = jax.api_util.shaped_abstractify(x)
+    return jax.ShapeDtypeStruct(a.shape, a.dtype, weak_type=a.weak_type)
+
+
+#: every live registered program of this process (weak: an engine that is
+#: dropped takes its programs along)
+_PROGRAMS: "weakref.WeakSet[RegisteredProgram]" = weakref.WeakSet()
+
+
+def register_program(jitted) -> RegisteredProgram:
+    """Called by an engine where it CREATES a jitted program; costs one
+    set insertion. Returns what the engine keeps and calls."""
+    prog = RegisteredProgram(jitted)
+    _PROGRAMS.add(prog)
+    return prog
+
+
+def registered_programs() -> list[RegisteredProgram]:
+    return list(_PROGRAMS)
+
+
+def merge_scope_maps(maps: list[dict[str, str]]) -> dict[str, str]:
+    """One map for several compiled programs of ONE module name (a window
+    program per size, a prefill step per shape: instruction names are
+    unique only inside one module). An instruction on whose (scope,
+    direction) they disagree maps to ``ambiguous`` — never to a guess."""
+    out: dict[str, str] = {}
+    folded: dict[str, tuple] = {}
+    for ops in maps:
+        for name, op in ops.items():
+            key = scope_of(op)
+            if name not in folded:
+                folded[name], out[name] = key, op
+            elif folded[name] != key:
+                out[name] = AMBIGUOUS
+    return out
+
+
+def program_scope_maps(names=None) -> dict[str, dict]:
+    """``{module name: {"ops": {instruction: op_name | "ambiguous"},
+    "programs": n, "hlo_bytes": largest text}}`` for the registered
+    programs that have run (optionally only the module ``names`` given).
+    THIS is where lowering, compiling and parsing happen."""
+    per: dict[str, list[dict]] = collections.defaultdict(list)
+    for prog in registered_programs():
+        if prog.avals is None or (names is not None
+                                  and prog.module_name not in names):
+            continue
+        parsed = prog.scopes()
+        per[parsed["module"] or prog.module_name].append(parsed)
+    return {mod: {"ops": merge_scope_maps([p["ops"] for p in ps]),
+                  "programs": len(ps),
+                  "hlo_bytes": max(p["hlo_bytes"] for p in ps)}
+            for mod, ps in per.items()}
+
+
+# ---- reading a trace ------------------------------------------------------
+
+def _instruction(event_name: str) -> str:
+    """``%fusion.12 = bf16[…] fusion(…)`` -> ``fusion.12``."""
+    return event_name.lstrip("%").split(" ", 1)[0]
+
+
+def _base_name(instr: str) -> str:
+    return re.sub(r"[.\d]+$", "", instr) or instr
+
+
+def _self_times(events):
+    """(name, start, self_ns) per event of one "XLA Ops" line: an op's time
+    minus that of the ops nested directly inside it (a ``while`` and its
+    body: a naive sum counts the body twice)."""
+    ev = sorted(events, key=lambda e: (e[1], -(e[2] - e[1])))
+    child = [0.0] * len(ev)
+    stack: list[int] = []
+    for i, (_, a, b) in enumerate(ev):
+        while stack and ev[stack[-1]][2] <= a:
+            stack.pop()
+        if stack:
+            child[stack[-1]] += b - a
+        stack.append(i)
+    return [(n, a, max(b - a - c, 0.0)) for (n, a, b), c in zip(ev, child)]
+
+
+def device_op_times(log_dir: str, device_substr: str = "TPU"):
+    """Per device plane of the newest trace under ``log_dir`` (or of that
+    ``.xplane.pb`` file): ``[(program, instruction, self_ns)]``, the
+    program being the run on the "XLA Modules" line that holds the op's
+    start (``"none"`` outside any). Device planes only — a CPU trace has
+    none and gives ``[]``."""
+    from jax.profiler import ProfileData
+
+    out = []
+    for plane in ProfileData.from_file(_latest_xplane(log_dir)).planes:
+        if not plane.name.startswith("/device:") \
+                or device_substr not in plane.name:
+            continue
+        lines = {ln.name: [(e.name, float(e.start_ns),
+                            float(e.start_ns) + float(e.duration_ns))
+                           for e in ln.events] for ln in plane.lines}
+        ops = lines.get("XLA Ops")
+        if not ops:
+            continue
+        runs = sorted((a, b, n.split("(")[0])
+                      for n, a, b in lines.get("XLA Modules", ()))
+        starts = [r[0] for r in runs]
+        rows = []
+        for name, a, self_ns in _self_times(ops):
+            j = bisect.bisect_right(starts, a) - 1
+            prog = runs[j][2] if j >= 0 and a < runs[j][1] else "none"
+            rows.append((prog, _instruction(name), self_ns))
+        out.append(rows)
+    return out
+
+
 def op_breakdown(log_dir: str, *, by_base_name: bool = True,
                  device_substr: str = "TPU") -> dict[str, float]:
-    """{op name: total device ms} from the newest trace under ``log_dir``.
-
-    ``by_base_name`` strips the ``%name.123`` instance suffix so repeated
-    ops (one per layer) aggregate. Requires the tensorflow profiler protos
-    (present in images that ship tensorflow); raises ImportError otherwise.
-    """
-    os.environ.setdefault("PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION", "python")
-    from tensorflow.tsl.profiler.protobuf import xplane_pb2
-
-    xs = xplane_pb2.XSpace()
-    with open(_latest_xplane(log_dir), "rb") as f:
-        xs.ParseFromString(f.read())
+    """{op name: SELF device ms} from the newest trace under ``log_dir``,
+    summed over every matching device plane. ``by_base_name`` strips the
+    ``.123`` instance suffix so repeated ops (one per layer) aggregate.
+    ``{}`` for a trace with no device plane (the CPU backend)."""
     totals: dict[str, float] = collections.Counter()
-    # aggregate over EVERY matching device plane (multi-chip hosts have one
-    # per device; runtime planes without an "XLA Ops" line contribute 0)
-    for plane in xs.planes:
-        if device_substr not in plane.name:
-            continue
-        meta = plane.event_metadata
-        for line in plane.lines:
-            if line.name != "XLA Ops":      # exclusive per-op timings
-                continue
-            for ev in line.events:
-                name = meta[ev.metadata_id].name
-                if by_base_name:
-                    name = re.sub(r"\.\d+$", "",
-                                  name.split(" = ")[0]).lstrip("%")
-                totals[name] += ev.duration_ps / 1e9
+    for rows in device_op_times(log_dir, device_substr):
+        for _, instr, self_ns in rows:
+            totals[_base_name(instr) if by_base_name else instr] \
+                += self_ns / 1e6
     return dict(totals)
+
+
+def scope_breakdown(log_dir: str, *, maps: dict | None = None,
+                    device_substr: str = "TPU") -> dict[str, dict]:
+    """``{program: {(scope, direction): SELF device ms}}`` from the newest
+    trace under ``log_dir``, summed over the device planes: every op joined
+    with the scope map of the program whose run holds it (``maps`` as
+    :func:`program_scope_maps` returns them — asked for here, for the
+    programs in the trace, when not given). Ops of a program without a
+    map, or unknown to it, count as ``unscoped``."""
+    planes = device_op_times(log_dir, device_substr)
+    if maps is None:
+        maps = program_scope_maps({p for rows in planes for p, _, _ in rows})
+    table: dict = collections.defaultdict(collections.Counter)
+    for rows in planes:
+        for prog, instr, self_ns in rows:
+            op = maps.get(prog, {}).get("ops", {}).get(instr)
+            key = (AMBIGUOUS, "fwd") if op == AMBIGUOUS else scope_of(op)
+            table[prog][key] += self_ns / 1e6
+    return {p: dict(t) for p, t in table.items()}
 
 
 #: HLO name fragments → collective kind (CommsLogger op names)
@@ -139,10 +395,20 @@ def overlap_breakdown(log_dir: str | None = None, *,
 
 
 def print_breakdown(log_dir: str, top: int = 20, steps: int = 1,
-                    device_substr: str = "TPU") -> str:
-    """Human-readable top-N op table (ms per step)."""
-    totals = op_breakdown(log_dir, device_substr=device_substr)
-    lines = [f"{'ms/step':>10}  op"]
+                    device_substr: str = "TPU", by_scope: bool = False,
+                    maps: dict | None = None) -> str:
+    """Human-readable top-N table (ms per step): by op, or with
+    ``by_scope`` by program, scope and direction (``maps`` as for
+    :func:`scope_breakdown`)."""
+    if by_scope:
+        totals = {f"{prog}  {scope}  {direction}": ms
+                  for prog, t in scope_breakdown(
+                      log_dir, maps=maps,
+                      device_substr=device_substr).items()
+                  for (scope, direction), ms in t.items()}
+    else:
+        totals = op_breakdown(log_dir, device_substr=device_substr)
+    lines = [f"{'ms/step':>10}  {'program  scope  direction' if by_scope else 'op'}"]
     for name, ms in sorted(totals.items(), key=lambda kv: -kv[1])[:top]:
         lines.append(f"{ms / max(steps, 1):10.3f}  {name}")
     text = "\n".join(lines)

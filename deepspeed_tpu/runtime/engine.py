@@ -39,6 +39,8 @@ from ..models.transformer import (ModelConfig, default_activation_rules,
                                   training_attention_formulation)
 from ..ops.optimizers import OptState, Optimizer, build_optimizer
 from ..parallel.topology import BATCH_AXES, MeshTopology
+from ..profiling.trace import register_program
+from ..utils.annotations import device_scope
 from ..utils.logging import log_dist, logger
 from ..utils.timer import (
     BACKWARD_GLOBAL_TIMER,
@@ -690,24 +692,29 @@ class DeepSpeedEngine:
         cfg = self.config
         lr = self.lr_schedule(state.opt_state.step)
         if cfg.gradient_clipping:
-            norm = _global_norm(grads)
-            clip = jnp.minimum(1.0, cfg.gradient_clipping / (norm + 1e-6))
-            grads = jax.tree.map(lambda g: g * clip, grads)
+            with device_scope("grad_check"):
+                norm = _global_norm(grads)
+                clip = jnp.minimum(1.0,
+                                   cfg.gradient_clipping / (norm + 1e-6))
+                grads = jax.tree.map(lambda g: g * clip, grads)
 
         master_in = state.master if state.master is not None else state.params
 
         def do_update(operand):
             m, opt = operand
-            new_master, new_opt = self.optimizer.update(grads, opt, m, lr=lr)
-            new_master = jax.lax.with_sharding_constraint(
-                new_master, self.plan.master_shardings)
+            with device_scope("optimizer"):
+                new_master, new_opt = self.optimizer.update(grads, opt, m,
+                                                            lr=lr)
+                new_master = jax.lax.with_sharding_constraint(
+                    new_master, self.plan.master_shardings)
             return new_master, new_opt
 
         guarded = state.scaler is not None or cfg.resilience.sentinel
         if guarded:
-            finite = fp16_mod.grads_finite(grads)
-            if loss_finite is not None:
-                finite = finite & loss_finite
+            with device_scope("grad_check"):
+                finite = fp16_mod.grads_finite(grads)
+                if loss_finite is not None:
+                    finite = finite & loss_finite
             new_master, new_opt = jax.lax.cond(
                 finite, do_update, lambda op: op, (master_in, state.opt_state))
         else:
@@ -716,13 +723,15 @@ class DeepSpeedEngine:
         new_scaler = None if state.scaler is None else \
             fp16_mod.update_scaler(state.scaler, finite, cfg.fp16)
 
-        if self.mixed_precision:
-            new_params = _cast_tree(new_master, self.compute_dtype)
-            master_out = new_master
-        else:
-            new_params = new_master
-            master_out = None
-        new_params = jax.lax.with_sharding_constraint(new_params, self.plan.param_shardings)
+        with device_scope("optimizer"):     # the cast back to compute dtype
+            if self.mixed_precision:
+                new_params = _cast_tree(new_master, self.compute_dtype)
+                master_out = new_master
+            else:
+                new_params = new_master
+                master_out = None
+            new_params = jax.lax.with_sharding_constraint(
+                new_params, self.plan.param_shardings)
         return TrainState(params=new_params, master=master_out, opt_state=new_opt,
                           scaler=new_scaler, global_step=state.global_step + 1), finite
 
@@ -841,11 +850,11 @@ class DeepSpeedEngine:
                                                   jnp.isfinite(loss))
             return new_state, (loss, finite)
 
-        self._train_step = jax.jit(
+        self._train_step = register_program(jax.jit(
             train_step,
             out_shardings=(ss, (repl, repl)),
             donate_argnums=(0,),
-        )
+        ))
 
     def _safe_manual_rules(self, manual_axes: tuple[str, ...]):
         """Logical-axis constraints on manual (shard_map) axes are illegal —
@@ -935,7 +944,8 @@ class DeepSpeedEngine:
                 return jnp.moveaxis(jax.lax.all_gather(
                     jnp.moveaxis(p, d, 0), "fsdp", tiled=True), 0, d)
 
-            full = jax.tree.map(gather, params, param_dims)
+            with device_scope("zero_gather"):
+                full = jax.tree.map(gather, params, param_dims)
 
             def reduce(g, d):
                 if d >= 0:
@@ -963,8 +973,9 @@ class DeepSpeedEngine:
                 loss_sum, acc = carry
                 loss, g = jax.value_and_grad(
                     lambda p: local_loss(p, mb, step))(full)
-                slabs = jax.tree.map(reduce, _cast_tree(g, jnp.float32),
-                                     grad_dims)
+                with device_scope("zero_reduce"):
+                    slabs = jax.tree.map(reduce, _cast_tree(g, jnp.float32),
+                                         grad_dims)
                 acc = jax.tree.map(jnp.add, acc, slabs)
                 return (loss_sum + loss, acc), None
 
@@ -987,9 +998,9 @@ class DeepSpeedEngine:
                                                   jnp.isfinite(loss))
             return new_state, (loss, finite)
 
-        self._train_step = jax.jit(train_step,
-                                   out_shardings=(ss, (repl, repl)),
-                                   donate_argnums=(0,))
+        self._train_step = register_program(jax.jit(
+            train_step, out_shardings=(ss, (repl, repl)),
+            donate_argnums=(0,)))
 
     def _use_onebit_comm(self) -> bool:
         """1-bit compressed gradient comm applies when the optimizer is a
@@ -1101,10 +1112,9 @@ class DeepSpeedEngine:
                              axis_names=set(dp_axes),
                              check_vma=False)(state, batch)
 
-        self._train_step = jax.jit(train_step,
-                                   out_shardings=(self._state_shardings,
-                                                  (repl, repl)),
-                                   donate_argnums=(0,))
+        self._train_step = register_program(jax.jit(
+            train_step, out_shardings=(self._state_shardings, (repl, repl)),
+            donate_argnums=(0,)))
 
     def _offload_apply(self, grads: Pytree) -> None:
         """Host optimizer step + device param refresh."""

@@ -2,7 +2,9 @@
 buffer, exportable as Chrome trace-event JSON.
 
 The device side of the story already exists — profiling/trace.py captures
-xplane device timelines. What was missing is the HOST timeline: where the
+xplane device timelines and reads them back with ``jax.profiler.
+ProfileData`` (device time by op, ``op_breakdown``, or by the named scope
+of the jitted programs, ``scope_breakdown``). What was missing is the HOST timeline: where the
 serving loop spent its time (plan building, dispatch, drain, commit), where
 the train step blocked, what the job was doing right before a hang. Spans
 are cheap enough to leave on in production (one perf_counter pair + one

@@ -12,6 +12,31 @@ import functools
 
 import jax
 
+#: Every scope the jitted programs give their own work, by name. A scope is
+#: metadata (``op_name`` in the compiled HLO): nothing runs at step time.
+#: ``profiling/trace.py`` joins these with a device trace;
+#: ``tests/test_device_scopes.py`` holds the tuple to the compiled programs
+#: in both directions (every name is used, no other name is).
+DEVICE_SCOPES = (
+    # serving forward (inference/engine_v2.py)
+    "embed", "weight_walk", "norm", "attn_qkv", "kv_stage", "attn_core",
+    "attn_out", "ffn", "head", "sample", "kv_commit",
+    # training (models/transformer.py, models/loss.py, runtime/engine.py)
+    "head_loss", "optimizer", "grad_check", "zero_gather", "zero_reduce",
+)
+#: flax's own module names in the training step (``layer_N`` folds to
+#: ``layer``), which the scope readers take as they are
+MODULE_SCOPES = ("layer", "attn", "ffn", "moe", "ln_attn", "ln_ffn",
+                 "ln_final", "ln_embed")
+
+
+def device_scope(name: str):
+    """``jax.named_scope`` for one of :data:`DEVICE_SCOPES` — for use INSIDE
+    a jitted function, where a host span would only time the tracing."""
+    if name not in DEVICE_SCOPES:
+        raise ValueError(f"{name!r} is not declared in DEVICE_SCOPES")
+    return jax.named_scope(name)
+
 
 def instrument_w_nvtx(fn=None, *, name: str | None = None):
     """Decorator: run ``fn`` under a named profiler range. Usable bare

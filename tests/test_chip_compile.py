@@ -207,3 +207,22 @@ def test_kernel_compiles_for_v5e(name, topo):
     fn, args, expect_kernel = CASES[name](topo.devices)
     compiled = jax.jit(fn).lower(*args).compile()
     assert ("tpu_custom_call" in compiled.as_text()) == expect_kernel
+
+
+@pytest.mark.parametrize("name, kernel", [
+    ("ragged_decode_bf16", "paged_attn_decode"),
+    ("ragged_chunk512_bf16", "paged_attn_prefill"),
+    ("ragged_tree_s8_bf16", "paged_attn_tree"),
+])
+def test_paged_kernel_instruction_is_named_by_form(name, kernel, topo):
+    """``name=`` on the ``pallas_call`` is what the compiled custom call's
+    HLO instruction is called — and so what a device trace's "XLA Ops" line
+    prints (unnamed, it took the innermost scope or ``closed_call``). The
+    benchmark's roofline readers match it by form."""
+    import re
+
+    fn, args, _ = CASES[name](topo.devices)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
+                       r'"tpu_custom_call"', text)
+    assert calls and {re.sub(r"[.\d]+$", "", c) for c in calls} == {kernel}

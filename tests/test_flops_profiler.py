@@ -1,6 +1,8 @@
 """Flops profiler + env report tests (reference
 tests/unit/profiling/flops_profiler/test_flops_profiler.py analogue)."""
 import io
+import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -89,17 +91,24 @@ def test_env_report_runs(capsys):
     assert "jax" in text
 
 
+#: a small scoped device trace recorded on a v5e
+#: (benchmark/tests/record_scope_fixture.py) and what the chip run wrote
+#: beside it: the program's scope maps and reduce_trace's own summary
+_SCOPED_TRACE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "benchmark", "tests", "data", "tpu_v5e_scopes.xplane.pb")
+
+
 def test_trace_capture_and_breakdown(tmp_path):
-    """profiling.trace: capture a device trace and read back per-op device
-    time (the xplane path nsight plays on GPU)."""
+    """profiling.trace: capture a trace (the machinery runs on any
+    backend; a CPU trace has no device plane and reads back empty), and
+    read per-op SELF device time back from a trace recorded on a TPU —
+    on ``jax.profiler.ProfileData``, no TensorFlow."""
     import jax
     import jax.numpy as jnp
-    import pytest as _pytest
 
-    from deepspeed_tpu.profiling.trace import op_breakdown, trace
-
-    _pytest.importorskip("tensorflow.tsl.profiler.protobuf.xplane_pb2",
-                         reason="xplane protos need tensorflow")
+    from deepspeed_tpu.profiling.trace import (op_breakdown,
+                                               print_breakdown, trace)
 
     @jax.jit
     def f(a, b):
@@ -109,10 +118,15 @@ def test_trace_capture_and_breakdown(tmp_path):
     jax.block_until_ready(f(a, b))          # compile outside the trace
     with trace(str(tmp_path)):
         jax.block_until_ready(f(a, b))
-    totals = op_breakdown(str(tmp_path), device_substr="TPU")
     if jax.default_backend() != "tpu":
-        # CPU xplanes carry host-thread lines, not the per-op device line
-        # this utility reads; the capture machinery is still exercised
-        _pytest.skip("per-op device lines are TPU-trace only")
-    assert totals, "no device ops captured"
-    assert all(ms >= 0 for ms in totals.values())
+        assert op_breakdown(str(tmp_path)) == {}
+
+    with open(_SCOPED_TRACE.replace(".xplane.pb", ".expected.json")) as fh:
+        expected = json.load(fh)
+    totals = op_breakdown(_SCOPED_TRACE)
+    assert totals and all(ms >= 0 for ms in totals.values())
+    # the same self times as the benchmark's reader gave on the chip, op by
+    # op, over the whole trace
+    assert totals == pytest.approx(expected["ops_ms_whole_trace"], rel=1e-9)
+    assert expected["kernel"] in totals
+    assert expected["kernel"] in print_breakdown(_SCOPED_TRACE, top=50)
