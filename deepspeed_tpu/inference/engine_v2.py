@@ -79,6 +79,30 @@ KIND_SPEC_3D = {"row": P(None, "tensor", None),
                 "rep": P(None, None, None)}
 
 
+def scan_layer_stack(stacked: Pytree, x, apply_layer, per_layer=None):
+    """``lax.scan`` of ``apply_layer(x, p, li, per_layer[li]) -> (x, ys)``
+    over the leading (depth) axis of ``stacked``; returns ``(x, stacked
+    ys)``. Layer ``li``'s weights are sliced out of the ``[L, ...]`` stack
+    INSIDE the body, so each slice is an operand of the op that consumes it
+    and XLA fuses it there: the matmuls read the stack in place. (Carrying
+    the slice of layer ``li + 1`` through the scan makes it a buffer, which
+    is a copy of every layer's weights every walk: 38 % of a decode
+    iteration on a v5e, ``PERF.md`` PR 24.) Module-level so that
+    ``tests/test_chip_compile.py`` can compile the walk alone."""
+    L = jax.tree.leaves(stacked)[0].shape[0]
+
+    def body(xc, inp):
+        li, extra = inp
+        with device_scope("weight_walk"):
+            p = jax.tree.map(
+                lambda s: jax.lax.dynamic_index_in_dim(
+                    s, li, 0, keepdims=False), stacked)
+        return apply_layer(xc, p, li, extra)
+
+    return jax.lax.scan(body, x,
+                        (jnp.arange(L, dtype=jnp.int32), per_layer))
+
+
 class WeightSwapError(RuntimeError):
     """A live weight swap was refused or failed verification. ``reason``
     is machine-readable (``integrity`` | ``shape_mismatch`` |
@@ -141,15 +165,6 @@ class RaggedInferenceConfig:
     #: exits early (the scheduler already sizes W to the largest
     #: remaining budget, so a full-length slot runs all W either way).
     decode_early_exit: bool = False
-    #: double-buffer the layer-scanned forward's weight reads: the scan
-    #: body carries layer i+1's parameter slice in the loop carry and
-    #: issues its gather BEFORE layer i's compute, so the next layer's
-    #: HBM weight reads overlap the current layer's matmuls instead of
-    #: serializing at the scan-iteration boundary. Costs one extra
-    #: layer's weights of HBM residency. Applies to the scanned (bf16)
-    #: leaves; quantized codes already stream tile-by-tile inside the
-    #: Pallas kernels via scalar-prefetched layer indices.
-    weight_prefetch: bool = True
     #: async pipeline depth: how many dispatched steps may await host
     #: readback before the engine blocks on the oldest. Dispatch never
     #: waits for sampled tokens (decode chains through a device-resident
@@ -1644,55 +1659,12 @@ class InferenceEngineV2:
         if "layers_stacked" in params:
             # scan over depth: ONE traced layer body regardless of L; the
             # pool never enters the carry — only the small staged KV does
-            L = m.num_layers
-            lidx = jnp.arange(L, dtype=jnp.int32)
+            def body(xc, p, li, stage_l):
+                return layer(xc, p, li, is_moe_layer(m, 0),
+                             stage_l if window_mode else empty_stage)
 
-            def take(i):
-                with device_scope("weight_walk"):
-                    return jax.tree.map(
-                        lambda s: jax.lax.dynamic_index_in_dim(
-                            s, i, 0, keepdims=False), scanned_layers)
-
-            if cfg.weight_prefetch and L > 1:
-                # double-buffered weight walk: layer i+1's parameter
-                # gather rides the scan CARRY and is issued before layer
-                # i's compute — it has no data dependence on this
-                # iteration's activations, so its HBM reads overlap the
-                # current layer's matmuls instead of serializing at the
-                # scan boundary (the decode window's per-iteration floor
-                # is exactly these weight reads). Costs one extra layer
-                # of weights resident. Quantized codes are NOT carried
-                # (stripped into qstack; the Pallas kernels stream them
-                # via scalar-prefetched layer indices).
-                def body(carry, inp):
-                    if window_mode:
-                        li, stage_l = inp
-                    else:
-                        li = inp
-                        stage_l = empty_stage
-                    xc, p_cur = carry
-                    p_next = take(jnp.minimum(li + 1, L - 1))
-                    x2, stage_l = layer(xc, p_cur, li, is_moe_layer(m, 0),
-                                        stage_l)
-                    return (x2, p_next), stage_l
-
-                xs = (lidx, kv_stage) if window_mode else lidx
-                (x, _), (k_ys, v_ys) = jax.lax.scan(body, (x, take(0)), xs)
-            else:
-                # (the layer's slice is taken in the body, not by the
-                # scan: the same dynamic-slice, under a scope of its own)
-                def body(xc, inp):
-                    if window_mode:
-                        li, stage_l = inp
-                    else:
-                        li = inp
-                        stage_l = empty_stage
-                    x2, stage_l = layer(xc, take(li), li,
-                                        is_moe_layer(m, 0), stage_l)
-                    return x2, stage_l
-
-                xs = (lidx, kv_stage) if window_mode else lidx
-                x, (k_ys, v_ys) = jax.lax.scan(body, x, xs)
+            x, (k_ys, v_ys) = scan_layer_stack(
+                scanned_layers, x, body, kv_stage if window_mode else None)
         else:
             k_list, v_list = [], []
             for i in range(m.num_layers):

@@ -226,3 +226,90 @@ def test_paged_kernel_instruction_is_named_by_form(name, kernel, topo):
     calls = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
                        r'"tpu_custom_call"', text)
     assert calls and {re.sub(r"[.\d]+$", "", c) for c in calls} == {kernel}
+
+
+def _mistral_walk(devs, S, T, L=4):
+    """``scan_layer_stack`` over an ``[L, …]`` stack of Mistral-7B layers
+    (bf16; the shapes are the model's own ``init``), applying the dense
+    layer ``engine_v2._ragged_forward`` applies less its paged attention:
+    the same ``Norm``/``DenseFFN`` modules and projection einsums. Returns
+    (fn, abstract args, shapes of one layer's weights)."""
+    import flax.linen as nn
+
+    from deepspeed_tpu.inference.engine_v2 import scan_layer_stack
+    from deepspeed_tpu.models import build_model
+    from deepspeed_tpu.models.transformer import (DenseFFN, Norm,
+                                                  dense_ffn_config)
+    from deepspeed_tpu.utils.annotations import device_scope
+
+    model = build_model("mistral-7b", num_layers=1)
+    m = model.config
+    layer0 = nn.unbox(jax.eval_shape(
+        lambda k: model.init(k, jnp.zeros((1, 8), jnp.int32)),
+        jax.random.PRNGKey(0))["params"]["layer_0"])
+    stack = jax.tree.map(lambda a: _sds(_one(devs), (L, *a.shape), BF16),
+                         layer0)
+
+    def layer(x, p, li, _):
+        a = p["attn"]
+        h = Norm(m).apply({"params": p["ln_attn"]}, x)
+        with device_scope("attn_qkv"):
+            q, k, v = (jnp.einsum("ste,ehd->sthd", h, a[n])
+                       for n in ("wq", "wk", "wv"))
+        o = q + jnp.repeat(k + v, m.num_heads // m.num_kv_heads, axis=2)
+        with device_scope("attn_out"):
+            x = x + jnp.einsum("sthd,hde->ste", o, a["wo"])
+        h = Norm(m).apply({"params": p["ln_ffn"]}, x)
+        with device_scope("ffn"):
+            f = DenseFFN(dense_ffn_config(m)).apply({"params": p["ffn"]}, h)
+        return x + f, None
+
+    def walk(stack, x):
+        return scan_layer_stack(stack, x, layer)[0]
+
+    x = _sds(_one(devs), (S, T, m.hidden_size), BF16)
+    return walk, (stack, x), [a.shape for a in jax.tree.leaves(layer0)]
+
+
+def _top_level_outputs(hlo_text):
+    """(op_name, dims, bytes) of every instruction OUTSIDE a fused
+    computation: what the program writes to a buffer of its own."""
+    import re
+
+    width = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
+    fused, out = False, []
+    for line in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            fused = "fused_computation" in head.group(1)
+            continue
+        ins = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]*)\]", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if fused or not ins or not name:
+            continue
+        dims = tuple(int(d) for d in ins.group(2).split(",") if d)
+        out.append((name.group(1), tuple(d for d in dims if d != 1),
+                    width.get(ins.group(1), 4) * int(np.prod(dims))))
+    return out
+
+
+@pytest.mark.parametrize("S, T", [(48, 1), (1, 128)],
+                         ids=["decode_48_slots", "prefill_chunk128"])
+def test_layer_walk_reads_the_stack_in_place(S, T, topo):
+    """The scanned layer walk holds no second copy of a layer: sliced
+    inside the scan body, a weight is an operand of the matmul fusion that
+    consumes it. (Carried through the scan, PR 24's parent, every leaf was
+    a ``dynamic-slice_bitcast_fusion`` under ``weight_walk`` — 38 % of a
+    decode iteration on the chip.) What stays a buffer is the projections
+    INTO heads (``[E, H, D]``: the matmul wants E in sublanes, the stack
+    has H there), a ninth of the layer."""
+    walk, args, leaves = _mistral_walk(topo.devices, S, T)
+    compiled = jax.jit(walk).lower(*args).compile()
+    layer_bytes = 2 * sum(int(np.prod(s)) for s in leaves)
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+    walked = [(dims, n) for name, dims, n in
+              _top_level_outputs(compiled.as_text())
+              if "weight_walk" in name and n >= 1 << 20]
+    ffn_shapes = {s for s in leaves if len(s) == 2}
+    assert not [w for w in walked if w[0] in ffn_shapes], walked
+    assert sum(n for _, n in walked) <= layer_bytes // 8, walked

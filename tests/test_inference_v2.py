@@ -903,23 +903,28 @@ def test_v2_decode_window_scan_matches_early_exit():
     assert es.flush(7) == ew.flush(7)
 
 
-def test_v2_weight_prefetch_matches_unprefetched():
-    """Scan-carried weight prefetch (double-buffered layer walk) is a
-    schedule change only: greedy chains must be identical with it off."""
+@pytest.mark.parametrize("decode_window", [4, 1],
+                         ids=["window", "single_step"])
+def test_v2_scanned_walk_matches_unrolled_layers(decode_window):
+    """The scanned walk over ``layers_stacked`` (each layer's weights sliced
+    out of the stack inside the scan body) against the Python loop over
+    ``layer_i`` trees: the same weights give the same greedy chains."""
     model = build_model("tiny-gpt2", hidden_size=256, num_heads=4)
-    rng = jax.random.PRNGKey(6)
     cfg = {"block_size": 8, "num_blocks": 64, "max_seqs": 2, "chunk": 8,
-           "max_seq_len": 128}
-    ep = InferenceEngineV2(model, config=cfg, rng=rng)   # prefetch (default)
-    en = InferenceEngineV2(model, config={**cfg, "weight_prefetch": False},
-                           rng=rng)
-    assert ep.config.weight_prefetch and not en.config.weight_prefetch
-    en.params = ep.params
+           "max_seq_len": 128, "decode_window": decode_window}
+    es = InferenceEngineV2(model, config=cfg, rng=jax.random.PRNGKey(6))
+    eu = InferenceEngineV2(model, config=cfg, rng=jax.random.PRNGKey(6))
+    stacked = es.params["layers_stacked"]
+    eu.params = {k: v for k, v in es.params.items() if k != "layers_stacked"}
+    for i in range(model.config.num_layers):
+        eu.params[f"layer_{i}"] = jax.tree.map(lambda a: a[i], stacked)
     rngnp = np.random.default_rng(2)
     prompts = [list(map(int, rngnp.integers(0, 256, (L,))))
                for L in (9, 14)]
-    assert ep.generate(prompts, max_new_tokens=8) == \
-        en.generate(prompts, max_new_tokens=8)
+    assert es.generate(prompts, max_new_tokens=8) == \
+        eu.generate(prompts, max_new_tokens=8)
+    assert (es.stats["windows"] > 0) == (decode_window > 1)
+    assert eu.stats["windows"] == es.stats["windows"]
 
 
 def test_v2_mixed_load_caps_decode_window():

@@ -100,7 +100,7 @@ def test_v2_quant_serving_matches_dequantized_weights(bits):
     # orders (in-tile f32 dequant vs bf16 round-tripped weights) and the
     # 4-bit step is coarse enough that XLA-version dot-order differences
     # move a few logits past 3e-2 (measured 0.047 max on jaxlib 0.4.36
-    # CPU, identical with and without weight prefetch)
+    # CPU)
     np.testing.assert_allclose(np.asarray(lq, np.float32)[0],
                                np.asarray(ld, np.float32)[0],
                                atol=5e-2 if bits == 4 else 3e-2)
