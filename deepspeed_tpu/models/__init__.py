@@ -147,6 +147,19 @@ PRESETS: dict[str, ModelConfig] = {
                                    moe=MoEConfig(
                                        num_experts=60, top_k=4,
                                        shared_expert_intermediate=5632)),
+    # OLMoE-1B-7B (allenai/OLMoE-1B-7B-0125-Instruct config.json): every
+    # layer sparse, 64 experts of width 1024 (``intermediate_size``), 8 a
+    # token with the softmax gates NOT renormalised, RMSNorm of the whole
+    # projected q and k before rope, no shared expert
+    "olmoe-1b-7b": ModelConfig(vocab_size=50304, hidden_size=2048,
+                               num_layers=16, num_heads=16, num_kv_heads=16,
+                               intermediate_size=1024, max_seq_len=4096,
+                               position_embedding="rope", rope_theta=1e4,
+                               norm="rmsnorm", norm_eps=1e-5,
+                               activation="silu_glu", qk_norm="full",
+                               tie_embeddings=False,
+                               moe=MoEConfig(num_experts=64, top_k=8,
+                                             normalize_gates=False)),
     # --- bert family: bidirectional post-norm encoders (reference
     # module_inject/containers/{bert,distil_bert}.py policies and the
     # csrc/transformer training kernels, whose target workload is BERT) ----
@@ -224,6 +237,15 @@ PRESETS: dict[str, ModelConfig] = {
                                   moe=MoEConfig(
                                       num_experts=4, top_k=2, min_capacity=4,
                                       shared_expert_intermediate=128)),
+    "tiny-olmoe": ModelConfig(vocab_size=256, hidden_size=64, num_layers=4,
+                              num_heads=4, num_kv_heads=4,
+                              intermediate_size=32, max_seq_len=128,
+                              position_embedding="rope", norm="rmsnorm",
+                              activation="silu_glu", qk_norm="full",
+                              tie_embeddings=False,
+                              moe=MoEConfig(num_experts=8, top_k=2,
+                                            min_capacity=4,
+                                            normalize_gates=False)),
 }
 
 

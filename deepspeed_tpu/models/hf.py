@@ -111,6 +111,33 @@ def _mixtral_tree(sd: dict, cfg: ModelConfig) -> dict:
     return t
 
 
+def _olmoe_tree(sd: dict, cfg: ModelConfig) -> dict:
+    """olmoe = llama attention + RMSNorm of the whole projected q and k
+    (``q_norm``/``k_norm`` [H*D]: elementwise scales, so they follow the
+    same half→interleaved permutation inside each head as ``wq``/``wk``;
+    the statistic itself is over the whole vector and blind to it) +
+    stacked-expert MoE FFN (``mlp.experts.K.{gate,up,down}_proj``, router
+    ``mlp.gate.weight`` [n, E])."""
+    H, KV, D = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+    perm = _interleave_perm(D)
+    t = _llama_tree_attn_only(sd, cfg)
+    n_exp = cfg.moe.num_experts
+    for i in range(cfg.num_layers):
+        p = f"model.layers.{i}."
+        a = t[f"layer_{i}"]["attn"]
+        a["q_norm"] = sd[p + "self_attn.q_norm.weight"].reshape(H, D)[:, perm]
+        a["k_norm"] = sd[p + "self_attn.k_norm.weight"].reshape(KV, D)[:, perm]
+        stack = lambda name: np.stack(
+            [sd[p + f"mlp.experts.{k}.{name}.weight"].T
+             for k in range(n_exp)])
+        t[f"layer_{i}"]["moe"] = {"moe_layer": {
+            "gate": {"wg": sd[p + "mlp.gate.weight"].T},        # [E, n_exp]
+            "experts": {"w_gate": stack("gate_proj"),           # [n, E, F]
+                        "w_up": stack("up_proj"),
+                        "w_down": stack("down_proj")}}}         # [n, F, E]
+    return t
+
+
 def _llama_tree_attn_only(sd: dict, cfg: ModelConfig) -> dict:
     """The llama embedding/attention/norm skeleton without the dense FFN
     (mixtral swaps in its MoE block)."""
@@ -407,7 +434,7 @@ _CONVERTERS = {"gpt2": _gpt2_tree, "llama": _llama_tree,
                "mixtral": _mixtral_tree, "falcon": _falcon_tree,
                "bloom": _bloom_tree, "opt": _opt_tree, "phi": _phi_tree,
                "phi3": _phi3_tree, "qwen": _qwen_tree,
-               "qwen2_moe": _qwen2_moe_tree}
+               "qwen2_moe": _qwen2_moe_tree, "olmoe": _olmoe_tree}
 
 
 def _reject_rope_scaling(hf_config) -> None:
@@ -662,6 +689,40 @@ def config_from_hf(hf_config) -> ModelConfig:
                 dense_ffn_intermediate=(hf_config.intermediate_size
                                         if moe_pattern is not None
                                         else None)))
+    if mt == "olmoe":
+        from .transformer import MoEConfig
+
+        if getattr(hf_config, "clip_qkv", None) is not None:
+            raise NotImplementedError(
+                f"olmoe clip_qkv={hf_config.clip_qkv} is not converted")
+        _reject_rope_scaling(hf_config)
+        return dataclasses.replace(
+            PRESETS["olmoe-1b-7b"],
+            vocab_size=hf_config.vocab_size,
+            hidden_size=hf_config.hidden_size,
+            num_layers=hf_config.num_hidden_layers,
+            num_heads=hf_config.num_attention_heads,
+            num_kv_heads=hf_config.num_key_value_heads,
+            # the config has no key of its own for one expert's width:
+            # intermediate_size IS that width (OlmoeMLP)
+            intermediate_size=hf_config.intermediate_size,
+            max_seq_len=hf_config.max_position_embeddings,
+            rope_theta=float(getattr(hf_config, "rope_theta", 10000.0)),
+            norm_eps=hf_config.rms_norm_eps,
+            qkv_bias=bool(getattr(hf_config, "attention_bias", False)),
+            tie_embeddings=bool(getattr(hf_config, "tie_word_embeddings",
+                                        False)),
+            moe=MoEConfig(
+                num_experts=hf_config.num_experts,
+                top_k=hf_config.num_experts_per_tok,
+                # HF routes every token (no capacity); eval capacity n/k
+                # guarantees the same
+                eval_capacity_factor=float(hf_config.num_experts)
+                / hf_config.num_experts_per_tok,
+                normalize_gates=bool(getattr(hf_config, "norm_topk_prob",
+                                             False)),
+                aux_loss_weight=float(getattr(
+                    hf_config, "router_aux_loss_coef", 0.01))))
     raise NotImplementedError(
         f"no converter for HF model_type '{mt}' (have: "
         f"{sorted(_CONVERTERS)})")

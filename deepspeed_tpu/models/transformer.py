@@ -121,6 +121,11 @@ class ModelConfig:
                                              # gelu_exact (erf) | relu |
                                              # silu_glu (SwiGLU)
     qkv_bias: bool = False                   # qwen-style projection biases
+    qk_norm: str | None = None               # None | "full": RMSNorm of the
+                                             # WHOLE projected q and k (all
+                                             # heads as one vector, OLMoE),
+                                             # before rope. (A per-head form
+                                             # would be another value here.)
     attn_out_bias: bool = False              # gpt2/bert-style out-proj bias
     parallel_block: bool = False             # falcon/gpt-j/phi: attn ∥ ffn
     parallel_block_norms: int = 1            # 2 = separate ln for ffn branch
@@ -179,6 +184,8 @@ class ModelConfig:
                 + 2 * self.kv_heads * self.head_dim
         if self.attn_out_bias:
             attn += h
+        if self.qk_norm:
+            attn += (self.num_heads + self.kv_heads) * self.head_dim
         per_norm = h if self.norm == "rmsnorm" else 2 * h
         # pre-norm: 2 per layer + ln_final; post-norm: 2 per layer + ln_embed
         norms = (2 * L + 1) * per_norm
@@ -278,6 +285,24 @@ def apply_rope(q: jax.Array, k: jax.Array, positions: jax.Array,
             jnp.concatenate([kr, k[..., d_rot:]], axis=-1))
 
 
+QK_NORMS = ("full",)
+
+
+def qk_norm(cfg: "ModelConfig", x: jax.Array, scale: jax.Array) -> jax.Array:
+    """``cfg.qk_norm`` on a projected q or k ``[..., heads, head_dim]`` with
+    its scale ``[heads, head_dim]`` — the one implementation shared by
+    training attention and the ragged inference forward. "full": RMS over
+    heads AND head_dim together (OLMoE normalises the projection before it
+    is split into heads). Statistics in float32, affine in the input dtype,
+    as :class:`Norm`."""
+    if cfg.qk_norm not in QK_NORMS:
+        raise ValueError(f"qk_norm {cfg.qk_norm!r} is not one of {QK_NORMS}")
+    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=(-2, -1),
+                   keepdims=True)
+    inv = jax.lax.rsqrt(var + cfg.norm_eps)
+    return x * inv.astype(x.dtype) * scale.astype(x.dtype)
+
+
 def _attn_impl(cfg: "ModelConfig") -> str:
     """alibi's additive bias and sliding windows run XLA attention (no
     flash kernel path); everything else follows ``cfg.attn_impl``."""
@@ -344,6 +369,13 @@ class Attention(nn.Module):
             q = q + bq.astype(cfg.dtype)
             k = k + bk.astype(cfg.dtype)
             v = v + bv.astype(cfg.dtype)
+        if cfg.qk_norm:
+            q = qk_norm(cfg, q, self.param("q_norm", nn.with_partitioning(
+                nn.initializers.ones, ("heads", "head_dim")), (H, D),
+                jnp.float32))
+            k = qk_norm(cfg, k, self.param("k_norm", nn.with_partitioning(
+                nn.initializers.ones, ("kv_heads", "head_dim")), (KV, D),
+                jnp.float32))
 
         if cfg.position_embedding == "rope":
             q, k = apply_rope(q, k, positions, cfg.rope_theta, cfg.rotary_pct)

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from ..parallel.axes import (BATCH, BATCH_NOEXP, EMBED, EXPERT, SEQ,
                              constrain as _constrain)
+from ..utils.annotations import device_scope
 from .sharded_moe import GateOutput, topk_dropless_gating, topkgating
 
 
@@ -66,24 +67,30 @@ def dropless_dispatch_combine(x2d: jax.Array, gates: jax.Array,
                               experts: jax.Array, num_experts: int, k: int,
                               block_m: int, gemm: Callable) -> jax.Array:
     """Shared megablocks-style dispatch/combine (used by the dropless
-    training path below AND the v2 quantized-expert serving path —
-    inference/engine_v2.py ``quant_moe`` — so routing fixes reach both).
+    training path below AND every routed-expert layer of the v2 serving
+    forward — inference/engine_v2.py ``routed_experts``, quantised or not
+    — so routing fixes reach all of them).
 
     Sort the [T, k] expert choices into a block-aligned buffer, run
     ``gemm(buf, sort) -> [Tp, F]`` (the only part that differs between
     callers: bf16 grouped GEMM vs quantized grouped GEMM), gather each
-    token's k rows back and combine with its normalized gates.
+    token's k rows back and combine with its gates (as the router gave
+    them: renormalised or not).
     """
     from ..ops.pallas.grouped_matmul import sort_tokens_by_expert
 
     T, E = x2d.shape
-    srt = sort_tokens_by_expert(experts.reshape(T, k), num_experts, block_m)
-    rows = jnp.repeat(x2d, k, axis=0)                      # [T*k, E]
-    buf = jnp.zeros((srt.Tp, E), x2d.dtype).at[srt.dst].set(rows)
-    out_buf = gemm(buf, srt)
-    rows_out = out_buf[srt.dst].reshape(T, k, -1)
-    return jnp.einsum("tk,tke->te",
-                      gates.reshape(T, k).astype(x2d.dtype), rows_out)
+    with device_scope("moe_dispatch"):
+        srt = sort_tokens_by_expert(experts.reshape(T, k), num_experts,
+                                    block_m)
+        rows = jnp.repeat(x2d, k, axis=0)                  # [T*k, E]
+        buf = jnp.zeros((srt.Tp, E), x2d.dtype).at[srt.dst].set(rows)
+    with device_scope("moe_experts"):
+        out_buf = gemm(buf, srt)
+    with device_scope("moe_combine"):
+        rows_out = out_buf[srt.dst].reshape(T, k, -1)
+        return jnp.einsum("tk,tke->te",
+                          gates.reshape(T, k).astype(x2d.dtype), rows_out)
 
 
 class Experts(nn.Module):

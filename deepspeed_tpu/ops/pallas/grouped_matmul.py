@@ -12,11 +12,23 @@ the TPU pipeline model:
   a scalar-prefetch argument; the weight BlockSpec's index_map reads it to
   DMA that expert's weight tile — the "grouped" part costs one SMEM lookup
   per tile instead of a gather.
-- Grid (token_tiles, n_tiles, k_tiles), k innermost; fp32 accumulation in
+- Grid (n_tiles, token_tiles, k_tiles), k innermost; fp32 accumulation in
   VMEM scratch, output written on the last k step (standard TPU matmul
-  schedule).
+  schedule). Token tiles run INSIDE an output-column tile: consecutive
+  tiles of one expert then ask for the same weight block (where K fits one
+  block) and Pallas skips the copy, so an expert's weights are read once
+  however many tiles its tokens fill; the small x tile is what is re-read.
 - Padding rows are zero → their outputs are zero and are never gathered
   back, so no masking is needed in the kernel.
+- The buffer is sized for the worst case (one partial tile an expert), so
+  its tail tiles hold no token at all. A caller that passes the sort's
+  ``n_tiles`` (the serving forward) has them skipped: no dot, and their
+  weight index repeats the last used block, so nothing is copied either.
+  At a decode step (a few rows an expert) that tail is a fifth to a half
+  of the grid. Their output rows are left unwritten and are never gathered.
+- ``layer_index`` selects a layer of a stacked ``[L, n, K, N]`` slab INSIDE
+  the kernel (scalar prefetch): a layer sliced out of the stack in XLA is a
+  copy of every expert's weights before the kernel reads them.
 
 ``grouped_matmul`` is differentiable: dx is the same kernel contracting
 the other weight axis (``transpose_rhs``); dw is a second Pallas kernel
@@ -45,34 +57,43 @@ def _pick(dim: int, want: int) -> int:
     return dim
 
 
-def _gmm_kernel(te_ref, x_ref, w_ref, o_ref, acc, *, transpose_rhs: bool):
-    k = pl.program_id(2)
+def _gmm_kernel(te_ref, nu_ref, li_ref, x_ref, w_ref, o_ref, acc, *,
+                transpose_rhs: bool, upcast: bool):
+    t, k = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
 
-    @pl.when(k == 0)
-    def _init():
-        acc[:] = jnp.zeros_like(acc)
+    @pl.when(t < nu_ref[0])
+    def _live():
+        @pl.when(k == 0)
+        def _init():
+            acc[:] = jnp.zeros_like(acc)
 
-    x = x_ref[...]                                   # [bm, bk]
-    w = w_ref[0]                                     # [bk, bn] | [bn, bk]
-    dims = (((1,), (1,)), ((), ())) if transpose_rhs \
-        else (((1,), (0,)), ((), ()))
-    acc[:] += jax.lax.dot_general(x, w, dims,
-                                  preferred_element_type=jnp.float32)
+        x = x_ref[...]                               # [bm, bk]
+        w = w_ref[0, 0]                              # [bk, bn] | [bn, bk]
+        if upcast:      # the CPU's dot thunk refuses bf16 x bf16 -> f32
+            x, w = x.astype(jnp.float32), w.astype(jnp.float32)
+        dims = (((1,), (1,)), ((), ())) if transpose_rhs \
+            else (((1,), (0,)), ((), ()))
+        acc[:] += jax.lax.dot_general(x, w, dims,
+                                      preferred_element_type=jnp.float32)
 
-    @pl.when(k == nk - 1)
-    def _finalize():
-        o_ref[...] = acc[:].astype(o_ref.dtype)
+        @pl.when(k == nk - 1)
+        def _finalize():
+            o_ref[...] = acc[:].astype(o_ref.dtype)
 
 
 def _gmm_call(x, w, tile_expert, *, block_m: int, transpose_rhs: bool,
               block_n: int | None, block_k: int | None,
-              interpret: bool | None):
+              interpret: bool | None, n_tiles=None, layer_index=None):
     Tp, E = x.shape
+    if layer_index is None:
+        w = w[None]                                  # one "layer": a bitcast
+    elif w.ndim != 4:
+        raise ValueError(f"layer_index given but w {w.shape} is not stacked")
     if transpose_rhs:
-        n_exp, N, K = w.shape                        # w [n, F, E], contract E
+        _, n_exp, N, K = w.shape                     # w [n, F, E], contract E
     else:
-        n_exp, K, N = w.shape                        # w [n, E, F], contract E
+        _, n_exp, K, N = w.shape                     # w [n, E, F], contract E
     if K != E:
         raise ValueError(f"contracting dims mismatch: x {x.shape} w {w.shape}")
     if Tp % block_m:
@@ -83,30 +104,56 @@ def _gmm_call(x, w, tile_expert, *, block_m: int, transpose_rhs: bool,
         from . import interpret_mode
         interpret = interpret_mode()
 
-    grid = (Tp // block_m, N // bn, K // bk)
+    n_all = Tp // block_m
+    nk = K // bk
+    grid = (N // bn, n_all, nk)
+
+    def w_k(t, k, nu):
+        # a tile past the used ones repeats the block of the step before it
+        return jnp.where(t < nu[0], k, nk - 1)
+
     if transpose_rhs:
-        w_spec = pl.BlockSpec((1, bn, bk),
-                              lambda t, f, k, te: (te[t], f, k))
+        w_spec = pl.BlockSpec(
+            (1, 1, bn, bk),
+            lambda f, t, k, te, nu, li: (li[0], te[t], f, w_k(t, k, nu)))
     else:
-        w_spec = pl.BlockSpec((1, bk, bn),
-                              lambda t, f, k, te: (te[t], k, f))
+        w_spec = pl.BlockSpec(
+            (1, 1, bk, bn),
+            lambda f, t, k, te, nu, li: (li[0], te[t], w_k(t, k, nu), f))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=3,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_m, bk), lambda t, f, k, te: (t, k)),
+            pl.BlockSpec((block_m, bk), lambda f, t, k, te, nu, li: (t, k)),
             w_spec,
         ],
-        out_specs=pl.BlockSpec((block_m, bn), lambda t, f, k, te: (t, f)),
+        out_specs=pl.BlockSpec((block_m, bn),
+                               lambda f, t, k, te, nu, li: (t, f)),
         scratch_shapes=[pltpu.VMEM((block_m, bn), jnp.float32)],
     )
+    one = lambda v, default: jnp.asarray(
+        default if v is None else v, jnp.int32).reshape(1)
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs),
+        functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs,
+                          upcast=bool(interpret)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Tp, N), x.dtype),
         name="grouped_matmul_fwd",
         interpret=interpret,
-    )(tile_expert.astype(jnp.int32), x, w)
+    )(tile_expert.astype(jnp.int32), one(n_tiles, n_all),
+      one(layer_index, 0), x, w)
+
+
+def grouped_matmul_layer(x, w, tile_expert, n_tiles, block_m: int,
+                         layer_index=None, interpret: bool | None = None):
+    """Forward-only form for serving: the sort's ``tile_expert`` and
+    ``n_tiles`` (the kernel skips the buffer's empty tail), and ``w`` may
+    be the depth-stacked ``[L, n, E, F]`` slab with ``layer_index`` picking
+    the layer inside the kernel."""
+    return _gmm_call(x, w, tile_expert, block_m=block_m,
+                     transpose_rhs=False, block_n=None, block_k=None,
+                     interpret=interpret, n_tiles=n_tiles,
+                     layer_index=layer_index)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -201,6 +248,8 @@ class ExpertSort(NamedTuple):
     dst: jax.Array          # [T*k] destination row per (token, choice)
     tile_expert: jax.Array  # [Tp // block_m] expert owning each token tile
     Tp: int                 # static padded buffer length
+    n_tiles: jax.Array      # scalar: tiles that hold a token (the rest of
+                            # the buffer is its worst-case tail)
 
 
 def sort_tokens_by_expert(expert_idx: jax.Array, num_experts: int,
@@ -229,7 +278,13 @@ def sort_tokens_by_expert(expert_idx: jax.Array, num_experts: int,
 
     Tp = ((Tk + block_m - 1) // block_m) * block_m + num_experts * block_m
     tile_starts = jnp.arange(Tp // block_m, dtype=jnp.int32) * block_m
+    # tiles past the last used one belong to the last used tile's expert:
+    # still nondecreasing (the dw kernel's invariant), and a kernel told
+    # ``n_tiles`` finds the weight block it already holds
+    n_tiles = (jnp.sum(aligned) // block_m).astype(jnp.int32)
+    tile_starts = jnp.minimum(tile_starts, (n_tiles - 1) * block_m)
     tile_expert = jnp.clip(
         jnp.searchsorted(starts, tile_starts, side="right") - 1,
         0, num_experts - 1).astype(jnp.int32)
-    return ExpertSort(dst=dst, tile_expert=tile_expert, Tp=Tp)
+    return ExpertSort(dst=dst, tile_expert=tile_expert, Tp=Tp,
+                      n_tiles=n_tiles)

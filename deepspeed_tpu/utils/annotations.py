@@ -21,6 +21,11 @@ DEVICE_SCOPES = (
     # serving forward (inference/engine_v2.py)
     "embed", "weight_walk", "norm", "attn_qkv", "kv_stage", "attn_core",
     "attn_out", "ffn", "head", "sample", "kv_commit",
+    # a routed-expert layer (moe/layer.py dropless_dispatch_combine and the
+    # serving forward's router), in place of ``ffn``: the router matmul and
+    # top-k; sort and scatter into the tile-aligned buffer; the grouped
+    # GEMMs and the activation; gather back and the gate-weighted sum
+    "moe_router", "moe_dispatch", "moe_experts", "moe_combine",
     # training (models/transformer.py, models/loss.py, runtime/engine.py)
     "head_loss", "optimizer", "grad_check", "zero_gather", "zero_reduce",
 )

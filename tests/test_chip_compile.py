@@ -161,6 +161,31 @@ def _grouped(backward):
     return build
 
 
+def _grouped_serving(tokens, down=False):
+    """The serving form at OLMoE-1B-7B's widths (64 experts, 8 a token,
+    hidden 2048, expert width 1024): the depth-stacked slab with the layer
+    picked inside the kernel, the sort's ``n_tiles`` skipping the buffer's
+    tail, at the tile height ``moe_tile_rows`` gives a step of ``tokens``
+    rows (48: a decode step, 16 rows a tile; 2048: a prefill step, 128)."""
+    def build(devs):
+        from deepspeed_tpu.inference.engine_v2 import (moe_padded_rows,
+                                                       moe_tile_rows)
+        from deepspeed_tpu.ops.pallas.grouped_matmul import \
+            grouped_matmul_layer
+        one = _one(devs)
+        L, n, k, E, F = 2, 64, 8, 2048, 1024
+        bm = moe_tile_rows(tokens, k, n)
+        Tp = moe_padded_rows(tokens, k, n, bm)
+        K, N = (F, E) if down else (E, F)
+
+        def fn(x, w, te, nt, li):
+            return grouped_matmul_layer(x, w, te, nt, bm, layer_index=li)
+        return fn, (_sds(one, (Tp, K), BF16), _sds(one, (L, n, K, N), BF16),
+                    _sds(one, (Tp // bm,), jnp.int32),
+                    _sds(one, (), jnp.int32), _sds(one, (), jnp.int32)), True
+    return build
+
+
 def _quant(bits, M, N=1024):
     def build(devs):
         from deepspeed_tpu.ops.pallas.quant_matmul import (SMALL_M_XLA,
@@ -191,6 +216,14 @@ CASES = {
     "ragged_tree_s8_t24_page16": _ragged(8, 24, BF16, tree=True, bs=16),
     "grouped_gemm_fwd": _grouped(False),
     "grouped_gemm_bwd": _grouped(True),
+    "grouped_gemm_olmoe_decode_up": _grouped_serving(48),
+    "grouped_gemm_olmoe_decode_down": _grouped_serving(48, down=True),
+    "grouped_gemm_olmoe_prefill_up": _grouped_serving(2048),
+    "grouped_gemm_olmoe_prefill_down": _grouped_serving(2048, down=True),
+    # OLMoE's attention geometry: one query head a KV head, head 128
+    "ragged_decode_h16_kv16_d128": _ragged(48, 1, BF16, D=128),
+    "ragged_chunk128_h16_kv16_d128": _ragged(4, 128, BF16, D=128),
+    "ragged_chunk1536_h16_kv16_d128": _ragged(1, 1536, BF16, D=128),
     "quant_int8_m8": _quant(8, 8),
     "quant_int8_m512": _quant(8, 512),
     "quant_int4_m8": _quant(4, 8),
@@ -213,6 +246,10 @@ def test_kernel_compiles_for_v5e(name, topo):
     ("ragged_decode_bf16", "paged_attn_decode"),
     ("ragged_chunk512_bf16", "paged_attn_prefill"),
     ("ragged_tree_s8_bf16", "paged_attn_tree"),
+    ("ragged_decode_h16_kv16_d128", "paged_attn_decode"),
+    ("ragged_chunk128_h16_kv16_d128", "paged_attn_prefill"),
+    ("grouped_gemm_olmoe_decode_up", "grouped_matmul_fwd"),
+    ("grouped_gemm_olmoe_prefill_down", "grouped_matmul_fwd"),
 ])
 def test_paged_kernel_instruction_is_named_by_form(name, kernel, topo):
     """``name=`` on the ``pallas_call`` is what the compiled custom call's

@@ -29,6 +29,8 @@ PKG = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                    "deepspeed_tpu")
 SERVING = ("embed", "weight_walk", "norm", "attn_qkv", "kv_stage",
            "attn_core", "attn_out", "ffn", "head", "sample", "kv_commit")
+#: a routed-expert layer's, in place of ``ffn`` (none in a dense program)
+MOE = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine")
 TRAINING = ("embed", "head_loss", "optimizer", "grad_check", "zero_gather",
             "zero_reduce")
 
@@ -113,7 +115,7 @@ def test_every_use_is_declared_and_every_declaration_used():
     assert not set(used) - set(DEVICE_SCOPES), \
         {k: v for k, v in used.items() if k not in DEVICE_SCOPES}
     assert not set(DEVICE_SCOPES) - set(used)
-    assert set(SERVING) | set(TRAINING) == set(DEVICE_SCOPES)
+    assert set(SERVING) | set(MOE) | set(TRAINING) == set(DEVICE_SCOPES)
 
 
 @pytest.mark.parametrize("name", SERVING)
@@ -136,6 +138,87 @@ def test_training_scope_lands_in_the_compiled_step(training, name):
     scopes = found["jit_train_step"]
     assert sum(n for (s, _), n in scopes.items() if s == name) > 0, \
         (name, dict(scopes))
+
+
+@pytest.fixture(scope="module")
+def moe_scopes():
+    """(scopes of the compiled programs of a tiny OLMoE engine, from its OWN
+    programs (the process-wide maps would fold them into the dense
+    engine's under the same module names), its counters): the engine is
+    dropped before the fixture returns."""
+    import gc
+
+    from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
+    from deepspeed_tpu.models import build_model
+
+    eng = InferenceEngineV2(
+        build_model("tiny-olmoe"), rng=jax.random.PRNGKey(6),
+        config={"block_size": 8, "num_blocks": 64, "max_seqs": 2, "chunk": 8,
+                "max_seq_len": 128})
+    eng.generate([[5, 6, 7, 8, 9, 10, 11, 12, 13]], max_new_tokens=8)
+    found: dict = collections.defaultdict(collections.Counter)
+    for prog in eng._programs.values():
+        if getattr(prog, "avals", None) is not None:
+            parsed = prog.scopes()
+            found[parsed["module"]].update(
+                ptrace.scope_of(op) for op in parsed["ops"].values())
+    stats = dict(eng.stats)
+    stats["qk_norm_in_attn_qkv"] = _rsqrt_under_attn_qkv(eng)
+    del eng, prog
+    gc.collect()
+    return found, stats
+
+
+def _rsqrt_under_attn_qkv(eng) -> int:
+    """rsqrt instructions of the decode window program whose ``op_name``
+    lies under ``attn_qkv``: the q/k normalisation, and nothing else."""
+    prog = next(p for k, p in eng._programs.items()
+                if isinstance(k, tuple) and k[0] == "win"
+                and getattr(p, "avals", None) is not None)
+    args, kwargs = prog.avals
+    text = prog.fn.lower(*args, **kwargs).compile().as_text()
+    return sum(1 for ln in text.splitlines()
+               if " rsqrt(" in ln and "/attn_qkv/" in ln)
+
+
+@pytest.mark.parametrize("name", MOE)
+def test_moe_scope_is_in_an_moe_program_and_in_no_dense_one(serving,
+                                                            moe_scopes, name):
+    found, _ = moe_scopes
+    assert {"jit_step_prefill", "jit_run"} <= set(found)
+    for mod in ("jit_step_prefill", "jit_run"):
+        assert found[mod][(name, "fwd")] > 0, (mod, dict(found[mod]))
+        # every layer of that stack is sparse and has no shared expert:
+        # nothing is left under ``ffn``
+        assert found[mod][("ffn", "fwd")] == 0
+    dense = _scopes_in(ptrace.program_scope_maps(
+        {"jit_step_prefill", "jit_step_decode", "jit_run"}))
+    for mod, scopes in dense.items():
+        assert scopes[(name, "fwd")] == 0, (mod, dict(scopes))
+
+
+def test_moe_counters_are_host_arithmetic(serving, moe_scopes):
+    """``moe_routed_rows`` / ``moe_padded_rows`` are booked from the plan's
+    shape at dispatch: no lowering, no compile, and nothing at all for a
+    dense model."""
+    eng, _, _ = serving
+    before = len(_EVENTS)
+    eng._count_moe(5, 8)
+    eng._count_moe(5, 8, iters=4)
+    assert len(_EVENTS) == before
+    assert eng.stats["moe_routed_rows"] == eng.stats["moe_padded_rows"] == 0
+    _, st = moe_scopes
+    assert 0 < st["moe_routed_rows"] < st["moe_padded_rows"]
+
+
+def test_qk_norm_is_booked_to_attn_qkv_and_absent_from_a_dense_program(
+        serving, moe_scopes):
+    """OLMoE's q/k normalisation adds its rsqrt under ``attn_qkv``; a model
+    without it compiles to a decode program with none there (the dense
+    cells' programs gain no instruction from PR 25)."""
+    eng, _, _ = serving
+    assert _rsqrt_under_attn_qkv(eng) == 0
+    assert moe_scopes[1]["qk_norm_in_attn_qkv"] >= 1
 
 
 def test_no_scope_outside_the_vocabulary_is_read(serving, training):

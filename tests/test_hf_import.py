@@ -281,6 +281,95 @@ def test_qwen2_moe_import_matches_torch_forward():
     np.testing.assert_allclose(got, ref, atol=3e-4)
 
 
+OLMOE = dict(vocab_size=128, hidden_size=64, intermediate_size=32,
+             num_hidden_layers=2, num_attention_heads=4,
+             num_key_value_heads=4, max_position_embeddings=64,
+             rms_norm_eps=1e-5, tie_word_embeddings=False, num_experts=8,
+             num_experts_per_tok=2, norm_topk_prob=False, rope_theta=10000.0)
+
+
+def _olmoe_state_dict(rng):
+    """A synthetic state dict under the checkpoint's own key names
+    (allenai/OLMoE-1B-7B: per-expert ``gate_proj/up_proj/down_proj``,
+    router ``mlp.gate.weight`` [n, E], ``q_norm``/``k_norm`` [H*D])."""
+    E, F, n, V = 64, 32, 8, 128
+    r = lambda *shape: rng.standard_normal(shape).astype(np.float32) * 0.1
+    sd = {"model.embed_tokens.weight": r(V, E), "lm_head.weight": r(V, E),
+          "model.norm.weight": 1 + r(E)}
+    for i in range(2):
+        p = f"model.layers.{i}."
+        sd.update({p + "input_layernorm.weight": 1 + r(E),
+                   p + "post_attention_layernorm.weight": 1 + r(E),
+                   p + "self_attn.q_norm.weight": 1 + 3 * r(E),
+                   p + "self_attn.k_norm.weight": 1 + 3 * r(E),
+                   p + "mlp.gate.weight": r(n, E)})
+        for name in ("q_proj", "k_proj", "v_proj", "o_proj"):
+            sd[p + f"self_attn.{name}.weight"] = r(E, E)
+        for k in range(n):
+            sd[p + f"mlp.experts.{k}.gate_proj.weight"] = r(F, E)
+            sd[p + f"mlp.experts.{k}.up_proj.weight"] = r(F, E)
+            sd[p + f"mlp.experts.{k}.down_proj.weight"] = r(E, F)
+    return sd
+
+
+def test_olmoe_tree_from_the_checkpoints_key_names():
+    """Shapes and placement from a synthetic state dict: experts stacked
+    to [n, E, F] / [n, F, E], the router transposed, and the q/k norm
+    scales permuted inside each head exactly as wq/wk's columns are."""
+    from types import SimpleNamespace
+
+    from deepspeed_tpu.models.hf import (_interleave_perm, _olmoe_tree,
+                                         config_from_hf)
+
+    cfg = config_from_hf(SimpleNamespace(model_type="olmoe", clip_qkv=None,
+                                         rope_scaling=None,
+                                         attention_bias=False, **OLMOE))
+    assert (cfg.qk_norm, cfg.moe.num_experts, cfg.moe.top_k,
+            cfg.moe.normalize_gates, cfg.ffn_size) == ("full", 8, 2, False, 32)
+    sd = _olmoe_state_dict(np.random.default_rng(0))
+    t = _olmoe_tree(sd, cfg)
+    l0 = t["layer_0"]
+    ex = l0["moe"]["moe_layer"]["experts"]
+    assert ex["w_gate"].shape == ex["w_up"].shape == (8, 64, 32)
+    assert ex["w_down"].shape == (8, 32, 64)
+    np.testing.assert_array_equal(
+        ex["w_up"][3], sd["model.layers.0.mlp.experts.3.up_proj.weight"].T)
+    np.testing.assert_array_equal(l0["moe"]["moe_layer"]["gate"]["wg"],
+                                  sd["model.layers.0.mlp.gate.weight"].T)
+    perm = _interleave_perm(16)
+    qn = sd["model.layers.0.self_attn.q_norm.weight"].reshape(4, 16)
+    np.testing.assert_array_equal(l0["attn"]["q_norm"], qn[:, perm])
+    # the scale of projection column c sits where column c went
+    wq = sd["model.layers.0.self_attn.q_proj.weight"].T.reshape(64, 4, 16)
+    np.testing.assert_array_equal(l0["attn"]["wq"], wq[:, :, perm])
+    with pytest.raises(NotImplementedError, match="clip_qkv"):
+        config_from_hf(SimpleNamespace(model_type="olmoe", clip_qkv=8.0,
+                                       rope_scaling=None, **OLMOE))
+
+
+def test_olmoe_import_matches_torch_forward():
+    """Against transformers' own OlmoeForCausalLM: whole-vector q/k norm
+    (with non-trivial scales), 8 experts top-2, gates NOT renormalised."""
+    if not hasattr(transformers, "OlmoeForCausalLM"):
+        pytest.skip("this transformers has no OlmoeForCausalLM")
+    from deepspeed_tpu.models.hf import from_hf_model
+
+    hf = transformers.OlmoeForCausalLM(transformers.OlmoeConfig(**OLMOE)).eval()
+    with torch.no_grad():        # the initialiser's ones would hide a
+        for name, p in hf.named_parameters():     # misplaced scale
+            if name.endswith(("q_norm.weight", "k_norm.weight")):
+                p.uniform_(0.5, 1.5)
+    model, params = from_hf_model(hf, dtype=jnp.float32)
+    assert model.config.qk_norm == "full"
+    assert model.config.moe.normalize_gates is False
+
+    ids = np.random.default_rng(9).integers(0, 128, (1, 16)).astype(np.int32)
+    with torch.no_grad():
+        ref = hf(torch.from_numpy(ids).long()).logits.numpy()
+    got = _logits_ours(model, params, ids)
+    np.testing.assert_allclose(got, ref, atol=3e-4)
+
+
 def test_qwen_v1_import_matches_torch_forward():
     """qwen v1 is a remote-code arch (no transformers class), so the
     oracle is a torch qwen2 model whose weights are RENAMED into the qwen
