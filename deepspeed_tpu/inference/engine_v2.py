@@ -62,7 +62,8 @@ from ..profiling.trace import register_program
 from ..utils.annotations import device_scope
 from ..utils.logging import logger
 from ..ops.pallas.paged_attention import (paged_attention_usable,
-                                          paged_ragged_attention)
+                                          paged_ragged_attention,
+                                          paged_step_counts, paged_work_list)
 from .ragged import StateManager, StepPlan
 from .sampling import sample_logits, sample_tree_logits
 from .scheduler import SpecAcceptTracker, SplitFuseScheduler
@@ -631,14 +632,13 @@ class InferenceEngineV2:
         # tree-verify stage width: _ragged_forward pads T nodes to
         # max(8, T) rows, rounded up to a page multiple past one page
         T_tree = max(cfg.spec_max_nodes, 1)
-        Ts_tree = max(8, T_tree)
-        if Ts_tree > cfg.block_size and Ts_tree % cfg.block_size:
-            Ts_tree += cfg.block_size - Ts_tree % cfg.block_size
+        Ts_tree = self._stage_rows(T_tree)
         sel_kw = dict(num_heads=m.num_heads, kv_heads=m.kv_heads,
                       head_dim=m.head_dim, block_size=cfg.block_size,
                       use_pallas=self._pallas_decode,
                       reason_not_usable=no_pallas)
         self._attn_decode_sel = select_attention(mode="decode", **sel_kw)
+        self._attn_paged = self._attn_decode_sel.is_pallas
         self._attn_tree_sel = select_attention(
             mode="tree", tree_nodes=T_tree, stage_rows=Ts_tree, **sel_kw)
         if cfg.spec_verify_pallas is False:
@@ -791,7 +791,11 @@ class InferenceEngineV2:
                       # layers) against the rows of the tile-aligned
                       # buffers the grouped GEMMs walk — host arithmetic
                       # from each dispatched plan's shape (``_count_moe``)
-                      "moe_routed_rows": 0, "moe_padded_rows": 0}
+                      "moe_routed_rows": 0, "moe_padded_rows": 0,
+                      # the paged kernel's grid steps that read a page
+                      # against the slots x table-width rectangle around
+                      # them (``_count_attn_steps``, host arithmetic too)
+                      "attn_steps_live": 0, "attn_steps_rect": 0}
         self._moe_layers = sum(is_moe_layer(m, i)
                                for i in range(m.num_layers))
         # measure the host<->device readback latency ONCE instead of
@@ -1213,6 +1217,14 @@ class InferenceEngineV2:
                          out_specs=os_, check_vma=False)(
             x2d, qw, tile_expert, lia)
 
+    def _stage_rows(self, n: int) -> int:
+        """Rows of the staged-KV buffer that holds ``n`` fresh tokens a
+        slot: sublane-aligned, and page-divisible when it spans pages (the
+        kernel tiles the stage in block_size rows)."""
+        bs = self.config.block_size
+        rows = max(8, n)
+        return rows if rows <= bs else -(-rows // bs) * bs
+
     # ------------------------------------------------------------------
     # ragged forward (reads the TransformerLM param tree directly;
     # reference model_implementations/inference_transformer_base.py:48)
@@ -1261,16 +1273,13 @@ class InferenceEngineV2:
         H, KV, D = m.num_heads, m.kv_heads, m.head_dim
         window_mode = kv_stage is not None
         tree_mode = tree_mask is not None
+        q_starts = positions[:, 0]
         if stage_starts is None:
-            stage_starts = positions[:, 0]
+            stage_starts = q_starts
         if window_mode:
             Ts = kv_stage[0].shape[3]
         else:
-            # sublane-aligned, and page-divisible when it spans pages (the
-            # kernel tiles the stage in block_size rows)
-            Ts = max(8, T)
-            if Ts > bs and Ts % bs:
-                Ts = -(-Ts // bs) * bs
+            Ts = self._stage_rows(T)
 
         # ring collective-matmul TP: static per program — the token-sharded
         # residual stream needs the row dim to divide the tensor axis
@@ -1589,12 +1598,6 @@ class InferenceEngineV2:
             win = m.sliding_window
             ring = self._ring_tokens
             li_dev = jnp.asarray(li, jnp.int32)
-            q_starts = positions[:, 0]
-            # kernel-vs-gather comes from the attention registry's static
-            # per-mode selection (attn_registry.py) — the ONLY dispatch
-            # decision point, pinned by check_attn_registry in
-            # bin/check_state_invariants.py
-            sel = self._attn_tree_sel if tree_mode else self._attn_decode_sel
             if sel.is_pallas:
                 # tree-verify stages ride two extra replicated operands:
                 # per-node absolute positions (root+depth) and the
@@ -1603,11 +1606,11 @@ class InferenceEngineV2:
                 t_specs = (P(None, None), P(None, None, None)) \
                     if tree_mode else ()
 
-                def _kernel(qq, pp, ks, vs, bt, sl, qs, ss, lr, *t):
+                def _kernel(qq, pp, ks, vs, bt, sl, qs, ss, lr, wl, nw, *t):
                     return paged_ragged_attention(
                         qq, pp, ks, vs, bt, sl, qs, ss,
                         block_size=bs, layer_index=lr, window=win,
-                        ring_tokens=ring,
+                        ring_tokens=ring, work=(wl, nw),
                         tree_positions=t[0] if t else None,
                         tree_mask=t[1] if t else None)
 
@@ -1625,15 +1628,15 @@ class InferenceEngineV2:
                                   P(None, "tensor", None, None),
                                   P(None, "tensor", None, None),
                                   P(None, None), P(None), P(None), P(None),
-                                  P(), *t_specs),
+                                  P(), P(None), P(), *t_specs),
                         out_specs=P(None, None, "tensor", None),
                         check_vma=False,
                     )(q, ro_pool, k_st, v_st, block_tables, seq_lens,
-                      q_starts, stage_starts, li_dev, *t_ops)
+                      q_starts, stage_starts, li_dev, *attn_work, *t_ops)
                 else:
                     o = _kernel(q, ro_pool, k_st, v_st, block_tables,
                                 seq_lens, q_starts, stage_starts, li_dev,
-                                *t_ops)
+                                *attn_work, *t_ops)
             else:
                 # fallback (alibi / odd geometries): gather each slot's
                 # pool pages (valid < stage_starts) and append the stage.
@@ -1751,6 +1754,21 @@ class InferenceEngineV2:
         # the pool stays read-only for the whole program: `attention`
         # closes over this alias, never the (later re-bound) kv_pool
         ro_pool = kv_pool
+        # kernel-vs-gather comes from the attention registry's static
+        # per-mode selection (attn_registry.py) — the ONLY dispatch
+        # decision point, pinned by check_attn_registry in
+        # bin/check_state_invariants.py
+        sel = self._attn_tree_sel if tree_mode else self._attn_decode_sel
+        # the paged kernel's steps, the same for every layer: built here,
+        # outside the layer loop (`core` closes over both)
+        attn_work = ()
+        if sel.is_pallas:
+            with device_scope("attn_core"):
+                attn_work = paged_work_list(
+                    seq_lens, q_starts, stage_starts, block_size=bs,
+                    max_pages=block_tables.shape[1], stage_rows=Ts,
+                    window=m.sliding_window, ring_tokens=self._ring_tokens,
+                    tree=tree_mode)
         empty_stage = (jnp.zeros((S, KV, Ts, D), cfg.dtype),) * 2
         if "layers_stacked" in params:
             # scan over depth: ONE traced layer body regardless of L; the
@@ -2017,9 +2035,7 @@ class InferenceEngineV2:
             cfg = self.config
             bs = cfg.block_size
             m = self.mcfg
-            Ws = max(8, W)          # stage rows (sublane-aligned)
-            if Ws > bs and Ws % bs:
-                Ws = -(-Ws // bs) * bs      # page-divisible past one page
+            Ws = self._stage_rows(W)
 
             def run(params, kv_pool, last_tok, tok_host, use_last, pos0,
                     lens0, block_tables, rem, eos_ids, rng):
@@ -2245,6 +2261,7 @@ class InferenceEngineV2:
         self.stats["dispatches"] += 1
         self.stats["windows"] += 1
         self._count_moe(int(rem.sum()), S, iters=W)
+        self._count_attn_steps(lens0, pos0, self._stage_rows(W), iters=W)
         if self._rt.enabled:
             for s in live:
                 self._rt.event(s.uid, "decode_window", W=W,
@@ -2527,6 +2544,23 @@ class InferenceEngineV2:
         self.stats["moe_padded_rows"] += iters * self._moe_layers * \
             moe_padded_rows(rows, mo.top_k, mo.num_experts, bm)
 
+    def _count_attn_steps(self, seq_lens, starts, stage_rows: int,
+                          iters: int = 1):
+        """Book the paged kernel's steps for one dispatch of a program
+        whose forward runs ``iters`` times (as ``_count_moe``: step plans
+        and decode windows). A window is counted as its first iteration
+        ``iters`` times over: its stage base is fixed, so the steps only
+        move where a sliding window slides or the stage outgrows a page."""
+        if not self._attn_paged:
+            return
+        live, rect = paged_step_counts(
+            seq_lens, starts, starts, block_size=self.config.block_size,
+            max_pages=self.state.max_blocks_per_seq, stage_rows=stage_rows,
+            window=self.mcfg.sliding_window, ring_tokens=self._ring_tokens)
+        n = iters * self.mcfg.num_layers
+        self.stats["attn_steps_live"] += n * live
+        self.stats["attn_steps_rect"] += n * rect
+
     def _dispatch_next(self) -> bool:
         """Dispatch the next scheduled step without blocking. Returns True
         if something was dispatched. Mixed prefill/decode load alternates
@@ -2586,6 +2620,8 @@ class InferenceEngineV2:
         self.stats["dispatches"] += 1
         n_tok = int(plan.active.sum())
         self._count_moe(n_tok, plan.token_ids.size)
+        self._count_attn_steps(plan.seq_lens, plan.positions[:, 0],
+                               self._stage_rows(T))
         if plan.kind == "prefill":
             self.stats["prefill_steps"] += 1
             self.stats["prefill_tokens"] += n_tok

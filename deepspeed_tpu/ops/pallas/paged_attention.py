@@ -1,29 +1,46 @@
-"""Paged (block-table) attention as a Pallas TPU kernel — decode + prefill.
+"""Paged (block-table) attention as Pallas TPU kernels — decode + prefill.
 
 TPU-native equivalent of the reference's blocked-flash ragged attention
 (/root/reference/deepspeed/inference/v2/kernels/ragged_ops/blocked_flash/
 blocked_flash.py:64, a flash-attn-2 variant reading K/V through a paged KV
-cache). Re-designed for the TPU pipeline model rather than translated:
+cache). Re-designed for the TPU pipeline model rather than translated.
 
-- The KV pool lives in HBM as [KV, num_blocks, block_size, D]. Each grid
-  step DMAs ONE page of ONE kv head into VMEM; the page index comes from a
-  scalar-prefetched block table (``pltpu.PrefetchScalarGridSpec``), so the
-  gather happens in the DMA engine — no [S, ctx, KV, D] materialization
-  like the XLA gather formulation in inference/engine_v2.py.
-- Grid (seqs, kv_heads, max_pages), pages innermost. Online-softmax state
-  (m, l, acc) is carried in VMEM scratch across the page steps of one
-  (seq, head); output is written on the last page step.
-- Pages wholly past ``seq_len`` are predicated off with ``@pl.when`` (their
-  DMA still lands on whatever the padded table entry points at — callers
-  pad tables with the trash block so it stays cache-friendly).
-- GQA: queries arrive as [S, KV, G, D] (G = H // KV query heads per kv
-  head); each grid step computes all G query heads of one kv head against
-  the page, so K/V are never repeated per query head.
+Two forms live here.
 
-Decode semantics: one new token per sequence whose K/V has already been
+**The ragged form — what the serving engine runs**
+(:func:`paged_ragged_attention`, kernels ``paged_attn_decode`` /
+``paged_attn_prefill`` / ``paged_attn_tree``):
+
+- The KV pool lives in HBM as ``[L, 2, KV, num_blocks, block_size, D]`` and
+  is READ-ONLY inside a program; this step's fresh K/V ride a small staged
+  buffer the kernel attends over after a slot's pool pages.
+- A grid step DMAs ONE page of ALL kv heads into VMEM; the page index
+  comes from a scalar-prefetched block table
+  (``pltpu.PrefetchScalarGridSpec``), so the gather happens in the DMA
+  engine — no ``[S, ctx, KV, D]`` materialization like the XLA gather
+  formulation in inference/engine_v2.py.
+- The iteration space is a LIST, not a rectangle: :func:`paged_work_list`
+  evaluates the kernel's own predicates (:func:`_live_steps`) over slots x
+  (table width + stage pages) in XLA, once a forward, and compacts the
+  steps that read a page; the grid is ``(q-tiles, n_items)`` with
+  ``n_items`` a dynamic bound and every index map reads its (slot, column)
+  from the prefetched list. An empty slot costs one finalize-only step, an
+  unused table column nothing.
+- Online-softmax state (m, l, acc) is carried in VMEM scratch across the
+  steps of one (q-tile, slot): initialised on the slot's first item,
+  written out on its last.
+- GQA: queries arrive as ``[S, KV, T*G, D]`` (G = H // KV query heads a kv
+  head); a step computes all G query heads of every kv head against the
+  page, so K/V are never repeated per query head.
+
+**The slice form** (:func:`paged_prefill_attention` /
+:func:`paged_decode_attention`, kernels ``paged_attn_slice_*``): one
+layer's ``[KV, P, D]`` pool slices, grid ``(seqs, kv_heads, max_pages)``
+with pages innermost and pages wholly past ``seq_len`` predicated off with
+``@pl.when``. Kept for direct kernel use; no engine path launches it.
+Decode there is one new token a sequence whose K/V has already been
 scattered into the pool; ``seq_lens`` counts valid context tokens
-*including* that token, so position ``p`` attends iff ``p < seq_len``
-(causality is implied — the query is the last token).
+*including* that token, so position ``p`` attends iff ``p < seq_len``.
 """
 from __future__ import annotations
 
@@ -31,6 +48,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
@@ -129,16 +147,164 @@ def _paged_attn_kernel(tables_ref, lens_ref, starts_ref, q_ref, k_ref, v_ref,
         o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
 
 
+def _live_steps(j, seq_len, qstart, sstart, *, block_size: int, window: int,
+                ring_tokens: int, page_group: int, n_pool: int, n_grp: int,
+                srows: int, tree: bool, xp=jnp):
+    """(run_pool, run_stage) of column ``j`` of a slot's walk: whether its
+    ``page_group`` pool pages hold a key some query row of the call can see,
+    and whether its stage page does. Columns ``j < n_grp`` are pool page
+    groups, the rest stage pages. THE rule of the ragged kernel's iteration
+    space: the kernel evaluates it on the scalars of one step,
+    :func:`paged_work_list` on the whole ``[S, n_grp + nsp]`` rectangle
+    (and :func:`paged_step_counts` on the host, ``xp=numpy``)."""
+    Gp = page_group
+    is_stage = j >= n_grp
+    if ring_tokens:
+        nwin = ring_tokens // block_size
+        b_latest = xp.maximum(sstart - 1, 0) // block_size
+        first_jj = j * Gp
+        run_pool = (sstart > 0) & (~is_stage) \
+            & (b_latest - (b_latest - first_jj) % nwin >= 0) \
+            & (first_jj < n_pool)           # jnp %: floor semantics
+    else:
+        group_start = j * Gp * block_size
+        run_pool = (group_start < sstart) & (~is_stage)
+        if window:
+            # earliest key any row of this call can see is qstart-window+1
+            run_pool &= (group_start + Gp * block_size
+                         > qstart - window + 1)
+    sp = xp.maximum(j - n_grp, 0)            # stage page index
+    if tree:
+        # every stage row is a candidate NODE — a branchy tree packs more
+        # nodes than its depth, so seq_len (root+1+max_depth) undercounts
+        # the live stage rows; the ancestors mask governs visibility, the
+        # gate only skips fully-empty slots
+        run_stage = is_stage & (seq_len > 0)
+    else:
+        run_stage = is_stage & (sstart + sp * srows < seq_len)
+    return run_pool, run_stage
+
+
+def _ragged_geometry(max_pages: int, stage_rows: int, block_size: int,
+                     page_group: int = 1):
+    """(n_grp, nsp, srows): pool page-group columns, stage pages, rows a
+    stage page — the rectangle's width is ``n_grp + nsp``."""
+    if stage_rows <= block_size:
+        srows, nsp = stage_rows, 1
+    else:
+        if stage_rows % block_size:
+            raise ValueError(f"stage rows {stage_rows} must be a multiple of "
+                             f"block_size {block_size} (or <= it)")
+        srows, nsp = block_size, stage_rows // block_size
+    return -(-max_pages // page_group), nsp, srows
+
+
+def _item_bits(nj: int) -> int:
+    return max(1, (nj - 1).bit_length())
+
+
+def _unpack_item(code, jbits: int):
+    """(slot, column, first-of-slot, last-of-slot) of one work-list item."""
+    return (code >> (2 + jbits), (code >> 2) & ((1 << jbits) - 1),
+            (code & 1) == 1, (code & 2) == 2)
+
+
+def _live_rectangle(seq_lens, q_starts, stage_starts, *, block_size: int,
+                    max_pages: int, stage_rows: int, window, ring_tokens,
+                    page_group: int, tree: bool, xp):
+    """:func:`_live_steps` over the whole rectangle: (live ``[S, n_grp +
+    nsp]`` bool, n_grp). ``xp`` is ``jnp`` (traced) or ``numpy`` (host)."""
+    n_grp, nsp, srows = _ragged_geometry(max_pages, stage_rows, block_size,
+                                         page_group)
+    col = lambda a: xp.asarray(a, xp.int32)[:, None]
+    run_pool, run_stage = _live_steps(
+        xp.arange(n_grp + nsp, dtype=xp.int32)[None, :], col(seq_lens),
+        col(q_starts), col(stage_starts), block_size=block_size,
+        window=int(window or 0), ring_tokens=int(ring_tokens or 0),
+        page_group=page_group, n_pool=max_pages, n_grp=n_grp, srows=srows,
+        tree=tree, xp=xp)
+    return run_pool | run_stage, n_grp
+
+
+def paged_work_list(seq_lens, q_starts, stage_starts, *, block_size: int,
+                    max_pages: int, stage_rows: int,
+                    window: int | None = None,
+                    ring_tokens: int | None = None, page_group: int = 1,
+                    tree: bool = False):
+    """The ragged kernel's iteration space as a list: the ``(slot, column)``
+    steps of the ``[S, n_grp + nsp]`` rectangle for which
+    :func:`_live_steps` is true, compacted in ``(slot, column)`` order, each
+    marked first-/last-of-slot. A slot with no live step gets ONE item that
+    only initialises and finalises (its rows must read zero, not stale
+    VMEM). Returns ``(items, n_items)``: int32 ``[S * (n_grp + nsp) + 1]``
+    packed ``slot | column | last | first`` (the rectangle is the largest a
+    list can get — prefix-shared pages sit in several tables, so no count of
+    pool blocks bounds it; one spare entry keeps the pipeline's look-ahead
+    past the last item in bounds), and how many of them are items.
+
+    Nothing here depends on the layer: a forward builds it once, outside
+    its layer loop, and hands it to every layer's kernel call.
+    ``page_group`` is the EFFECTIVE group (1 unless the caller of
+    :func:`paged_ragged_attention` asked for more)."""
+    live, n_grp = _live_rectangle(
+        seq_lens, q_starts, stage_starts, block_size=block_size,
+        max_pages=max_pages, stage_rows=stage_rows, window=window,
+        ring_tokens=ring_tokens, page_group=page_group, tree=tree, xp=jnp)
+    S, nj = live.shape
+    jbits = _item_bits(nj)
+    if 2 + jbits + max(1, (S - 1).bit_length()) > 31:
+        raise ValueError(f"{S} slots x {nj} columns do not pack into int32")
+    # lax, not jnp, from here on: every serving program traces this once,
+    # and each jnp wrapper (cumsum, where, sort, pad) is a nested jit to
+    # trace and lower — tens of ms a program, before its cache key exists
+    rank = jax.lax.cumsum(live.astype(jnp.int32), axis=1)    # 1-based
+    count = rank[:, -1:]
+    first = live & (rank == 1)
+    last = live & (rank == count)
+    # the finalize-only item of an empty slot sits on the first stage
+    # column: its pool refs map to the trash block
+    j = jax.lax.broadcasted_iota(jnp.int32, (S, nj), 1)
+    s = jax.lax.broadcasted_iota(jnp.int32, (S, nj), 0)
+    alone = (count == 0) & (j == n_grp)
+    code = ((s << jbits | j) << 2 | (last | alone).astype(jnp.int32) << 1
+            | (first | alone).astype(jnp.int32))
+    item = live | alone
+    # codes grow with (slot, column): one sort compacts them in order
+    big = jnp.full((S, nj), jnp.iinfo(jnp.int32).max, jnp.int32)
+    items = jax.lax.sort(jax.lax.select(item, code, big).reshape(-1),
+                         is_stable=False)
+    n_items = jnp.sum(item, dtype=jnp.int32)
+    keep = jax.lax.iota(jnp.int32, S * nj) < n_items
+    items = jax.lax.select(keep, items, jnp.zeros_like(items))
+    return jax.lax.pad(items, jnp.int32(0), [(0, 1, 0)]), n_items
+
+
+def paged_step_counts(seq_lens, q_starts, stage_starts, *, block_size: int,
+                      max_pages: int, stage_rows: int,
+                      window: int | None = None,
+                      ring_tokens: int | None = None, tree: bool = False):
+    """(live, rectangle) grid steps of ONE ragged-kernel call a q-tile, on
+    the host: the steps :func:`_live_steps` passes (what
+    :func:`paged_work_list` lists, less the finalize-only items of empty
+    slots) and the ``S x (n_grp + nsp)`` steps of the rectangle a grid
+    over slots and table width would walk. numpy in, ints out."""
+    live, _ = _live_rectangle(
+        seq_lens, q_starts, stage_starts, block_size=block_size,
+        max_pages=max_pages, stage_rows=stage_rows, window=window,
+        ring_tokens=ring_tokens, page_group=1, tree=tree, xp=np)
+    return int(live.sum()), live.size
+
+
 def _ragged_attn_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
-                        *refs, block_size: int,
+                        work_ref, *refs, block_size: int,
                         scale: float, G: int, window: int,
-                        ring_tokens: int, n_stage_pages: int,
-                        page_group: int, n_pool: int,
+                        ring_tokens: int, n_grp: int, srows: int,
+                        jbits: int, page_group: int, n_pool: int,
                         p_scale: float = 1.0, tree: bool = False):
     """Read-only-pool ragged attention, ALL kv heads per grid step.
 
-    Round-4 redesign of :func:`_paged_attn_kernel` driven by two measured
-    costs on real hardware:
+    What the measured costs on real hardware made of
+    :func:`_paged_attn_kernel`:
 
     1. Interleaving pool scatters with pallas reads inside the layer scan
        forced XLA to materialize pool-sized buffers (~280ms per decode
@@ -148,17 +314,31 @@ def _ragged_attn_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
        buffer and are merged into the pool ONCE per program by the
        caller.
     2. A (seqs, kv_heads, pages) grid ran ~200k grid steps per decode
-       iteration (~40ms of pure grid overhead). The grid is now
-       (seqs, page-groups+stage) with all KV heads batched into one
-       block-DMA and one batched MXU dot per step; the final grid steps
-       attend over the staged tokens instead of a pool page.
-    3. (round 5) Even at one-page-per-step the decode window spent ~60%
-       of device time in this kernel at ~94us/call — 136 grid steps of
-       ~0.5us fixed overhead each with one tiny dot. ``page_group`` pool
-       pages now ride ONE grid step through separate block-spec refs
-       (each with its own scalar-prefetched table index), cutting grid
-       steps ~page_group-fold; tail/invalid sub-pages map to the trash
-       block so the pipeline elides their re-fetch.
+       iteration. All KV heads ride one block-DMA and one batched MXU dot
+       per step, and a slot's walk is its pool page-groups, then its stage
+       pages (the staged tokens instead of a pool page).
+    3. The steps themselves are a LIST, not a rectangle (PR 26). The
+       rectangle slots x (page-groups + stage pages) is sized for every
+       slot live at the full table width; an interactive replica holds a
+       few short contexts. Measured on a v5e at 48 slots x (128 + 1)
+       columns, 32 heads over 8, the kernel alone: a predicated-off step
+       of the rectangle cost 0.16us, so a call with NO live slot took
+       0.98ms; with 7 slots of ~500 tokens live 1.00ms, of which 38 steps
+       read a page. The grid is now (q-tiles, n_items) with ``n_items`` a
+       DYNAMIC bound; step ``i`` reads its (slot, column, first-,
+       last-of-slot) from the scalar-prefetched work list
+       (:func:`paged_work_list`), so a call costs its live steps (~0.8us
+       each there: 512 KiB of K+V at ~630 GB/s) plus ~0.4us for each
+       empty slot's finalize-only step: the same call 0.054ms, all 48
+       slots at the full 16k tokens 5.05ms either way. A slot's pages come
+       in the same order as in the rectangle walk: the outputs are
+       bitwise the same, on the chip too.
+    4. ``page_group`` pool pages can ride ONE step through separate
+       block-spec refs (each with its own scalar-prefetched table index);
+       tail/invalid sub-pages map to the trash block so the pipeline
+       elides their re-fetch. Measured a loss on v5e where the call is
+       DMA-bound on its valid pages (see ``paged_ragged_attention``); off
+       by default.
 
     ``tree`` (the speculative-verify form): each query row is a
     candidate-tree NODE, not a token of a contiguous chunk. Two extra
@@ -171,7 +351,7 @@ def _ragged_attn_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
     positional mask — exactly the gather formulation's split in
     inference/engine_v2.py `_ragged_forward`.
 
-    Grid (S, q-tiles, ceil(n_pool/page_group) + n_stage_pages).
+    Grid (q-tiles, n_items).
     ``refs`` = (q, k_0..k_{Gp-1}, v_0..v_{Gp-1}, k_stage, v_stage,
     [tpos, tmask when tree,] o, m_scr, l_scr, acc_scr).
     """
@@ -186,13 +366,10 @@ def _ragged_attn_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
         o_ref, m_scr, l_scr, acc_scr = refs[5 + 2 * Gp:]
     else:
         o_ref, m_scr, l_scr, acc_scr = refs[3 + 2 * Gp:]
-    s = pl.program_id(0)
-    tq = pl.program_id(1)          # query-row tile (VMEM-bounds long chunks)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
-    n_grp = nj - n_stage_pages     # pool page-groups come first, then stage
+    tq = pl.program_id(0)          # query-row tile (VMEM-bounds long chunks)
+    s, j, first, last = _unpack_item(work_ref[pl.program_id(1)], jbits)
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -201,7 +378,6 @@ def _ragged_attn_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
     seq_len = lens_ref[s]
     qstart = qst_ref[s]
     sstart = sst_ref[s]            # pool holds positions < sstart
-    is_stage = j >= n_grp
     tqb = m_scr.shape[1]           # query rows per tile
 
     def online_update(scores, ctx, valid, v, tree_cols=False):
@@ -250,26 +426,19 @@ def _ragged_attn_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
             preferred_element_type=jnp.float32)            # [KV, TQB, D]
         m_scr[:] = m_new
 
+    # every item of the list is a live step by construction, but for the
+    # finalize-only item of an empty slot: there both are false
+    run_pool, run_stage = _live_steps(
+        j, seq_len, qstart, sstart, block_size=block_size, window=window,
+        ring_tokens=ring_tokens, page_group=Gp, n_pool=n_pool, n_grp=n_grp,
+        srows=srows, tree=tree)
+
     # ---- pool page step: page_group sub-pages, ONE online update --------
     # The serial cost of a grid step is its softmax/update CHAIN, not its
     # dot (measured r5: per-sub-page chains made grouping a net loss).
     # The Gp pages therefore concatenate in VMEM into one [KV, Gp*bs, D]
     # tile and run a single chain ~Gp x wider — vector ops grow by lane
     # count, chain length stays flat.
-    if ring_tokens:
-        nwin = ring_tokens // block_size
-        b_latest = jnp.maximum(sstart - 1, 0) // block_size
-        run_pool = (sstart > 0) & (~is_stage)
-        first_jj = j * Gp
-        run_pool &= (b_latest - (b_latest - first_jj) % nwin >= 0) \
-            & (first_jj < n_pool)
-    else:
-        group_start = j * Gp * block_size
-        run_pool = (group_start < sstart) & (~is_stage)
-        if window:
-            run_pool &= (group_start + Gp * block_size
-                         > qstart - window + 1)
-
     @pl.when(run_pool)
     def _pool_step():
         q = q_ref[0]                                       # [KV, TQB, D]
@@ -312,15 +481,6 @@ def _ragged_attn_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
 
     # ---- stage steps (this program's fresh tokens, page-sized tiles) -----
     sp = jnp.maximum(j - n_grp, 0)           # stage page index
-    srows = ks_ref.shape[2]                  # rows per stage page
-    if tree:
-        # every stage row is a candidate NODE — a branchy tree packs more
-        # nodes than its depth, so seq_len (root+1+max_depth) undercounts
-        # the live stage rows; the ancestors mask governs visibility, the
-        # gate only skips fully-empty slots
-        run_stage = is_stage & (seq_len > 0)
-    else:
-        run_stage = is_stage & (sstart + sp * srows < seq_len)
 
     @pl.when(run_stage)
     def _stage_step():
@@ -342,7 +502,7 @@ def _ragged_attn_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
         else:
             online_update(scores, ctx, ctx < seq_len, v)
 
-    @pl.when(j == nj - 1)
+    @pl.when(last)
     def _finalize():
         l = l_scr[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)               # empty slot → 0s
@@ -356,7 +516,7 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
                            window: int | None = None,
                            ring_tokens: int | None = None,
                            page_group: int | None = None,
-                           tree_positions=None, tree_mask=None,
+                           tree_positions=None, tree_mask=None, work=None,
                            interpret: bool | None = None):
     """Ragged attention over a READ-ONLY paged pool plus a staged tail.
 
@@ -371,6 +531,10 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
     block_tables: [S, max_pages] int32 (pad with the trash block 0)
     seq_lens:     [S] — total valid context incl. staged tokens
     layer_index:  scalar — which pool layer this call reads
+    work:         ``(items, n_items)`` of :func:`paged_work_list` for these
+                  ``seq_lens``/``q_starts``/``stage_starts`` — the same for
+                  every layer, so a forward builds it once outside its
+                  layer loop. Built here when not handed one.
 
     Tree-verify form (speculative decoding): pass ``tree_positions``
     [S, T] int32 (absolute position of each candidate node, root+depth —
@@ -424,20 +588,12 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
         TQB //= 2
     while TG % TQB:
         TQB //= 2
-    if Ts <= bs:
-        srows, nsp = Ts, 1
-    else:
-        if Ts % bs:
-            raise ValueError(f"stage rows {Ts} must be a multiple of "
-                             f"block_size {bs} (or <= it)")
-        srows, nsp = bs, Ts // bs
     n_pool = max_pages
     # sub-pages per grid step. Measured on v5e (520-token decode contexts,
     # 136-step baseline 84us/call): page_group 2 -> 95us, 4 -> 106-117us —
-    # the call is DMA-bound on its valid pages, per-grid-step overhead is
-    # already pipelined away, and the VMEM concat + wider chain only adds
-    # work. Default therefore 1; the grouped path stays for experiments
-    # on geometries where step count dominates (tiny pages, huge tables).
+    # the call is DMA-bound on its valid pages, and the VMEM concat + wider
+    # chain only adds work. Default therefore 1; the grouped path stays for
+    # experiments on geometries with tiny pages.
     page_b = KV * bs * D * 2            # one pool page in VMEM (bf16)
     score_b = KV * TQB * bs * 4         # f32 score tile per sub-page
     Gp = page_group if page_group else 1
@@ -446,7 +602,21 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
     # the [KV, TQB, Gp*bs] f32 score tile, inside ~16MB scoped VMEM
     while Gp > 1 and 6 * Gp * page_b + Gp * score_b > 8 * 2 ** 20:
         Gp //= 2
-    n_grp = -(-n_pool // Gp)
+    n_grp, nsp, srows = _ragged_geometry(n_pool, Ts, bs, Gp)
+    jbits = _item_bits(n_grp + nsp)
+    if work is None:
+        work = paged_work_list(
+            seq_lens, q_starts, stage_starts, block_size=bs,
+            max_pages=n_pool, stage_rows=Ts, window=window,
+            ring_tokens=ring_tokens, page_group=Gp, tree=tree)
+    items, n_items = work
+    if items.shape != (S * (n_grp + nsp) + 1,):
+        raise ValueError(f"work list {items.shape} was not built for {S} "
+                         f"slots x {n_grp + nsp} columns")
+
+    def item(wl, i):
+        s, j, _, _ = _unpack_item(wl[i], jbits)
+        return s, j
 
     def tbj(t, s, jj):
         # tail sub-pages of the last group and stage steps still need a
@@ -454,17 +624,22 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
         # re-fetch is elided when the previous index was already 0
         return jnp.where(jj < n_pool, t[s, jnp.minimum(jj, n_pool - 1)], 0)
 
-    def pool_spec(half, i):
-        return pl.BlockSpec(
-            (1, 1, KV, 1, bs, D),
-            lambda s, tq, j, t, ln, qs, ss, lr:
-                (lr[0], half, 0, tbj(t, s, j * Gp + i), 0, 0))
+    # index maps see (q-tile, item, *scalar-prefetch refs); the last of
+    # those is the work list, which says which (slot, column) item i is
+    def pool_spec(half, g):
+        def index(tq, i, t, ln, qs, ss, lr, wl):
+            s, j = item(wl, i)
+            return lr[0], half, 0, tbj(t, s, j * Gp + g), 0, 0
+        return pl.BlockSpec((1, 1, KV, 1, bs, D), index)
 
     def stage_spec():
-        return pl.BlockSpec(
-            (1, KV, srows, D),
-            lambda s, tq, j, t, ln, qs, ss, lr:
-                (s, 0, jnp.maximum(j - n_grp, 0), 0))
+        def index(tq, i, t, ln, qs, ss, lr, wl):
+            s, j = item(wl, i)
+            return s, 0, jnp.maximum(j - n_grp, 0), 0
+        return pl.BlockSpec((1, KV, srows, D), index)
+
+    def q_index(tq, i, t, ln, qs, ss, lr, wl):
+        return item(wl, i)[0], 0, tq, 0
 
     tree_ops = ()
     tree_specs = []
@@ -488,29 +663,29 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
         tmsk = jnp.pad(tmsk, ((0, 0), (0, 0), (0, Ts - T)))
         tmsk = tmsk.reshape(S, TG, nsp, srows).transpose(0, 2, 1, 3)
         tree_ops = (tpos, tmsk)
-        tree_specs = [
-            pl.BlockSpec((1, 1, TQB),
-                         lambda s, tq, j, t, ln, qs, ss, lr: (s, 0, tq)),
-            pl.BlockSpec((1, 1, TQB, srows),
-                         lambda s, tq, j, t, ln, qs, ss, lr:
-                             (s, jnp.maximum(j - n_grp, 0), tq, 0)),
-        ]
+
+        def tpos_index(tq, i, t, ln, qs, ss, lr, wl):
+            return item(wl, i)[0], 0, tq
+
+        def tmask_index(tq, i, t, ln, qs, ss, lr, wl):
+            s, j = item(wl, i)
+            return s, jnp.maximum(j - n_grp, 0), tq, 0
+
+        tree_specs = [pl.BlockSpec((1, 1, TQB), tpos_index),
+                      pl.BlockSpec((1, 1, TQB, srows), tmask_index)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(S, TG // TQB, n_grp + nsp),
+        num_scalar_prefetch=6,
+        grid=(TG // TQB, n_items),
         in_specs=[
-            pl.BlockSpec((1, KV, TQB, D),
-                         lambda s, tq, j, t, ln, qs, ss, lr: (s, 0, tq, 0)),
-            *[pool_spec(0, i) for i in range(Gp)],
-            *[pool_spec(1, i) for i in range(Gp)],
+            pl.BlockSpec((1, KV, TQB, D), q_index),
+            *[pool_spec(0, g) for g in range(Gp)],
+            *[pool_spec(1, g) for g in range(Gp)],
             stage_spec(),
             stage_spec(),
             *tree_specs,
         ],
-        out_specs=pl.BlockSpec((1, KV, TQB, D),
-                               lambda s, tq, j, t, ln, qs, ss, lr:
-                                   (s, 0, tq, 0)),
+        out_specs=pl.BlockSpec((1, KV, TQB, D), q_index),
         scratch_shapes=[
             pltpu.VMEM((KV, TQB, 1), jnp.float32),
             pltpu.VMEM((KV, TQB, 1), jnp.float32),
@@ -524,9 +699,9 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
     out = pl.pallas_call(
         functools.partial(_ragged_attn_kernel, block_size=block_size,
                           scale=float(scale), G=G, window=int(window or 0),
-                          ring_tokens=int(ring_tokens or 0),
-                          n_stage_pages=nsp, page_group=Gp, n_pool=n_pool,
-                          p_scale=p_scale, tree=tree),
+                          ring_tokens=int(ring_tokens or 0), n_grp=n_grp,
+                          srows=srows, jbits=jbits, page_group=Gp,
+                          n_pool=n_pool, p_scale=p_scale, tree=tree),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, KV, TG, D), q.dtype),
         name=("paged_attn_tree" if tree else "paged_attn_decode" if T == 1
@@ -534,7 +709,7 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
         interpret=interpret,
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
       q_starts.astype(jnp.int32), stage_starts.astype(jnp.int32),
-      jnp.asarray(layer_index, jnp.int32).reshape(1),
+      jnp.asarray(layer_index, jnp.int32).reshape(1), items,
       qg, *([pool] * Gp), *([pool] * Gp), k_stage, v_stage, *tree_ops)
     return (out.reshape(S, KV, T, G, D).transpose(0, 2, 1, 3, 4)
             .reshape(S, T, H, D))

@@ -91,7 +91,8 @@ def _ragged_args(mk, S, T, H, KV, D, bs, nb, pool_dtype, Ts=None,
             mk((S,), jnp.int32), mk((S,), jnp.int32))
 
 
-def _ragged(S, T, pool_dtype, tree=False, H=16, KV=16, D=64, bs=128):
+def _ragged(S, T, pool_dtype, tree=False, H=16, KV=16, D=64, bs=128,
+            max_pages=8):
     def build(devs):
         from deepspeed_tpu.ops.pallas.paged_attention import \
             paged_ragged_attention
@@ -99,7 +100,8 @@ def _ragged(S, T, pool_dtype, tree=False, H=16, KV=16, D=64, bs=128):
         Ts = max(8, T)
         if Ts > bs and Ts % bs:
             Ts = -(-Ts // bs) * bs
-        args = _ragged_args(mk, S, T, H, KV, D, bs, 64, pool_dtype, Ts=Ts)
+        args = _ragged_args(mk, S, T, H, KV, D, bs, 64, pool_dtype, Ts=Ts,
+                            max_pages=max_pages)
         if tree:
             args += (mk((S, T), jnp.int32), mk((S, T, T), jnp.uint8))
 
@@ -203,6 +205,9 @@ def _quant(bits, M, N=1024):
     return build
 
 
+MISTRAL = dict(H=32, KV=8, D=128, max_pages=128)
+OLMOE = dict(H=16, KV=16, D=128, max_pages=32)
+
 CASES = {
     "flash_fwd_bwd_b8_s1024_h16_d64": _flash(8, 1024, 16, 64),
     "flash_fwd_bwd_b1_s8192_h16_d64": _flash(1, 8192, 16, 64),
@@ -224,6 +229,16 @@ CASES = {
     "ragged_decode_h16_kv16_d128": _ragged(48, 1, BF16, D=128),
     "ragged_chunk128_h16_kv16_d128": _ragged(4, 128, BF16, D=128),
     "ragged_chunk1536_h16_kv16_d128": _ragged(1, 1536, BF16, D=128),
+    # the two geometries the benchmark serves, at their table widths: 48
+    # slots x 128 columns of Mistral's 32 heads over 8, 48 x 32 of OLMoE's
+    # 16 over 16 — the work list is sized by that rectangle
+    "ragged_decode_mistral_s48_p128": _ragged(48, 1, BF16, **MISTRAL),
+    "ragged_chunk128_mistral_s8_p128": _ragged(8, 128, BF16, **MISTRAL),
+    "ragged_tree_mistral_s48_p128": _ragged(48, 8, BF16, tree=True,
+                                            **MISTRAL),
+    "ragged_decode_olmoe_s48_p32": _ragged(48, 1, BF16, **OLMOE),
+    "ragged_chunk128_olmoe_s8_p32": _ragged(8, 128, BF16, **OLMOE),
+    "ragged_tree_olmoe_s48_p32": _ragged(48, 8, BF16, tree=True, **OLMOE),
     "quant_int8_m8": _quant(8, 8),
     "quant_int8_m512": _quant(8, 512),
     "quant_int4_m8": _quant(4, 8),
@@ -248,6 +263,12 @@ def test_kernel_compiles_for_v5e(name, topo):
     ("ragged_tree_s8_bf16", "paged_attn_tree"),
     ("ragged_decode_h16_kv16_d128", "paged_attn_decode"),
     ("ragged_chunk128_h16_kv16_d128", "paged_attn_prefill"),
+    ("ragged_decode_mistral_s48_p128", "paged_attn_decode"),
+    ("ragged_chunk128_mistral_s8_p128", "paged_attn_prefill"),
+    ("ragged_tree_mistral_s48_p128", "paged_attn_tree"),
+    ("ragged_decode_olmoe_s48_p32", "paged_attn_decode"),
+    ("ragged_chunk128_olmoe_s8_p32", "paged_attn_prefill"),
+    ("ragged_tree_olmoe_s48_p32", "paged_attn_tree"),
     ("grouped_gemm_olmoe_decode_up", "grouped_matmul_fwd"),
     ("grouped_gemm_olmoe_prefill_down", "grouped_matmul_fwd"),
 ])
@@ -263,6 +284,27 @@ def test_paged_kernel_instruction_is_named_by_form(name, kernel, topo):
     calls = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
                        r'"tpu_custom_call"', text)
     assert calls and {re.sub(r"[.\d]+$", "", c) for c in calls} == {kernel}
+
+
+def test_paged_kernel_scalar_prefetch_footprint():
+    """What the ragged kernel keeps in SMEM at the largest rectangle the
+    benchmark serves (48 slots x 128 table columns + 1 stage page): the
+    block tables, three vectors a slot, the layer index and the work list
+    — ONE packed int32 a rectangle step and one spare. Compiled for the
+    v5e by the case of that name above; held here under 64 KiB so that a
+    second list, or an unpacked one, is a decision and not an accident."""
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_work_list
+
+    S, pages, stage_rows = 48, MISTRAL["max_pages"], 8
+    vec = jax.ShapeDtypeStruct((S,), jnp.int32)
+    items, n_items = jax.eval_shape(
+        lambda a, b, c: paged_work_list(a, b, c, block_size=128,
+                                        max_pages=pages,
+                                        stage_rows=stage_rows), vec, vec, vec)
+    assert items.shape == (S * (pages + 1) + 1,) and n_items.shape == ()
+    assert items.dtype == jnp.int32
+    smem = 4 * (S * pages + 3 * S + 1 + items.shape[0])
+    assert smem == 49_928 and smem < 64 * 1024
 
 
 def _mistral_walk(devs, S, T, L=4):

@@ -211,6 +211,38 @@ def test_moe_counters_are_host_arithmetic(serving, moe_scopes):
     assert 0 < st["moe_routed_rows"] < st["moe_padded_rows"]
 
 
+def test_attn_step_counters_are_host_arithmetic(serving, monkeypatch):
+    """``attn_steps_live`` / ``attn_steps_rect`` are booked at dispatch from
+    the plan's lengths: the paged kernel's steps that read a page against
+    the slots x (table width + stage pages) rectangle, times iterations
+    and layers. Hand-counted for this engine's 8-token pages and 16-page
+    tables; no lowering, no compile, and nothing where the gather fallback
+    serves."""
+    eng, _, _ = serving
+    L = eng.mcfg.num_layers
+    assert eng.state.max_blocks_per_seq == 16 and eng.state.max_seqs == 2
+    st = eng.stats
+    assert 0 < st["attn_steps_live"] < st["attn_steps_rect"]
+    assert st["attn_steps_live"] % L == st["attn_steps_rect"] % L == 0
+    before, live, rect = len(_EVENTS), st["attn_steps_live"], \
+        st["attn_steps_rect"]
+    # a window of 4 iterations: slot 0 has 20 tokens in the pool (pages 0,
+    # 1, 2 hold a key below 20) and its staged token: 4 steps; slot 1 is
+    # empty; the rectangle is 2 x (16 + 1)
+    eng._count_attn_steps(np.array([21, 0]), np.array([20, 0]), 8, iters=4)
+    assert st["attn_steps_live"] - live == 4 * L * 4
+    assert st["attn_steps_rect"] - rect == 4 * L * 34
+    # a 24-token chunk (3 stage pages) behind 16 tokens (2 pool pages)
+    live, rect = st["attn_steps_live"], st["attn_steps_rect"]
+    eng._count_attn_steps(np.array([40, 0]), np.array([16, 0]), 24)
+    assert st["attn_steps_live"] - live == L * 5
+    assert st["attn_steps_rect"] - rect == L * 2 * 19
+    monkeypatch.setattr(eng, "_attn_paged", False)
+    eng._count_attn_steps(np.array([21, 0]), np.array([20, 0]), 8)
+    assert st["attn_steps_rect"] - rect == L * 2 * 19
+    assert len(_EVENTS) == before
+
+
 def test_qk_norm_is_booked_to_attn_qkv_and_absent_from_a_dense_program(
         serving, moe_scopes):
     """OLMoE's q/k normalisation adds its rsqrt under ``attn_qkv``; a model

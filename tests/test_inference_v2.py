@@ -961,3 +961,24 @@ def test_v2_mixed_load_caps_decode_window():
     # after the mix drained, full-size windows were dispatched again
     assert ("win", 8) in eng._programs
     eng.flush(1), eng.flush(2)
+
+
+def test_v2_attn_step_counters_match_a_hand_counted_run():
+    """``attn_steps_live`` / ``attn_steps_rect`` over a whole request, by
+    hand (8-token pages, tables of 8 pages, 2 layers). The 9-token prompt
+    rides ONE packed row of 16 (two stage pages, both hold a token below
+    9: 2 steps of the row's 8 + 2); the 8 tokens after the first come from
+    ONE window of 8 iterations over the 2 slots, the live one reading pool
+    pages 0 and 1 (a key below position 9) and its stage: 3 steps of the
+    2 x (8 + 1) rectangle an iteration."""
+    model = build_model("tiny-gpt2", hidden_size=256, num_heads=4)
+    eng = InferenceEngineV2(
+        model, config={"block_size": 8, "num_blocks": 32, "max_seqs": 2,
+                       "chunk": 8, "max_seq_len": 64, "decode_window": 8},
+        rng=jax.random.PRNGKey(0))
+    eng.generate([[5, 6, 7, 8, 9, 10, 11, 12, 13]], max_new_tokens=9)
+    L = model.config.num_layers
+    assert (eng.stats["prefill_steps"], eng.stats["windows"],
+            eng.stats["decode_steps"]) == (1, 1, 0)
+    assert eng.stats["attn_steps_live"] == L * (2 + 8 * 3)
+    assert eng.stats["attn_steps_rect"] == L * (10 + 8 * 18)
