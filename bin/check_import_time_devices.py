@@ -96,10 +96,9 @@ def check_repo(root: str) -> list[str]:
     for dirpath, _, files in os.walk(pkg):
         targets += [os.path.join(dirpath, f) for f in files
                     if f.endswith(".py") and f not in ALLOWED_FILES]
-    for extra in ("bench.py", "__graft_entry__.py"):
-        p = os.path.join(root, extra)
-        if os.path.exists(p):
-            targets.append(p)
+    entry = os.path.join(root, "__graft_entry__.py")
+    if os.path.exists(entry):
+        targets.append(entry)
     for path in sorted(targets):
         out += check_file(path)
     return out

@@ -291,10 +291,6 @@ def _targets(root: str) -> list[str]:
     for dirpath, _, files in os.walk(os.path.join(root, "deepspeed_tpu")):
         targets += [os.path.join(dirpath, f) for f in files
                     if f.endswith(".py")]
-    for extra in ("bench.py",):
-        p = os.path.join(root, extra)
-        if os.path.exists(p):
-            targets.append(p)
     return sorted(targets)
 
 
@@ -372,7 +368,7 @@ def render_metrics_doc(root: str) -> str:
         "# Metric-family reference (auto-generated)",
         "",
         "Every `serving_*` / `telemetry_*` family emitted with a literal",
-        "name in `deepspeed_tpu/` + `bench.py`. Regenerate with",
+        "name in `deepspeed_tpu/`. Regenerate with",
         "`python bin/check_metric_names.py --write-doc`;",
         "`tests/test_repo_lint.py` fails when an emitted family is",
         "missing here (or a documented one is no longer emitted).",

@@ -128,7 +128,7 @@ def feature_report() -> list[tuple[str, bool, str]]:
             "serving: multi-replica router", True,
             "serving.Router over N engine_v2 workers (prefix-cache-aware "
             "placement, retry-with-replay failover, SLO shedding, "
-            "circuit breaker; BENCH_MODE=router)"))
+            "circuit breaker; driven by tests/test_serving.py)"))
     except Exception as e:  # pragma: no cover — import breakage only
         feats.append(("serving: multi-replica router", False, str(e)))
 
@@ -143,7 +143,7 @@ def feature_report() -> list[tuple[str, bool, str]]:
             "FleetConfig roles=['prefill','decode',...] — KV page-bundle "
             "handoff through the router (pinned-until-ack, resumable, "
             "bit-identical greedy), remote replicas via --listen "
-            "sockets, scale-hint gauges; BENCH_MODE=disagg"))
+            "sockets, scale-hint gauges; driven by tests/test_disagg.py"))
     except Exception as e:  # pragma: no cover — import breakage only
         feats.append(("serving: disaggregated prefill/decode", False,
                       str(e)))
@@ -164,7 +164,7 @@ def feature_report() -> list[tuple[str, bool, str]]:
             "rebalancing; intra-host shm page ring "
             + ("available" if have_shm else
                "UNAVAILABLE (router relay only)")
-            + "; BENCH_MODE=disagg kv_pull scenario"))
+            + "; driven by tests/test_kv_pull.py"))
     except Exception as e:  # pragma: no cover — import breakage only
         feats.append(("serving: distributed prefix cache", False,
                       str(e)))
@@ -182,7 +182,7 @@ def feature_report() -> list[tuple[str, bool, str]]:
             "promote via adopt_prefix instead of recomputing; "
             f"probed RAM rate {rates['ram_bytes_s'] / 1e9:.1f} GB/s; "
             "engine kv_tier=True / replica cfg kv_tier={...}; "
-            "BENCH_MODE=kv_tier"))
+            "driven by tests/test_kvtier.py"))
     except Exception as e:  # pragma: no cover — import breakage only
         feats.append(("inference: KV tiering (HBM → host RAM → NVMe)",
                       False, str(e)))
@@ -200,7 +200,7 @@ def feature_report() -> list[tuple[str, bool, str]]:
             "transfers), promote_hint starts the two-phase tier "
             "extract concurrent with admission, and overlap promises "
             "prefill the suffix during the transfer with commit-or-"
-            "rollback settlement; BENCH_MODE=kv_push"))
+            "rollback settlement; driven by tests/test_kv_push.py"))
     except Exception as e:  # pragma: no cover — import breakage only
         feats.append(("serving: anticipatory KV movement (push/overlap)",
                       False, str(e)))
@@ -216,7 +216,7 @@ def feature_report() -> list[tuple[str, bool, str]]:
             "member-to-member over kind=\"prefix\" bundles, first "
             "token on the final member; cost-model gated, any failure "
             "collapses to single-replica (bit-identical); "
-            "BENCH_MODE=gang_prefill"))
+            "driven by tests/test_gang.py"))
     except Exception as e:  # pragma: no cover — import breakage only
         feats.append(("serving: gang prefill (fleet-sharded prompts)",
                       False, str(e)))
@@ -230,7 +230,7 @@ def feature_report() -> list[tuple[str, bool, str]]:
             "Router.deploy(ckpt) — verified-manifest rolling swap "
             "(canary + probe + health-gated soak, auto-rollback, "
             "version-skew-safe KV); engine_v2.swap_weights/save_weights; "
-            "BENCH_MODE=deploy"))
+            "driven by tests/test_deploy.py"))
     except Exception as e:  # pragma: no cover — import breakage only
         feats.append(("serving: zero-downtime weight deploys", False,
                       str(e)))
@@ -244,7 +244,7 @@ def feature_report() -> list[tuple[str, bool, str]]:
             "RouterConfig.journal_dir — crc'd segmented write-ahead log "
             "(fsync always|interval|none), restart replays + re-adopts "
             "daemon replicas via resync (streams re-attach, exactly-"
-            "once); BENCH_MODE=router router_restart scenario"))
+            "once); driven by tests/test_journal.py"))
     except Exception as e:  # pragma: no cover — import breakage only
         feats.append(("serving: crash-safe router (journal + resync)",
                       False, str(e)))
@@ -259,7 +259,7 @@ def feature_report() -> list[tuple[str, bool, str]]:
             "journaled deadline-bounded drain/retire (KV-tier flush), "
             "spawn with peer pre-warm, prefill<->decode re-role; "
             "SIGTERM / GCE maintenance preemption exits 83 (classified, "
-            "no breaker); BENCH_MODE=elastic"))
+            "no breaker); driven by tests/test_elastic.py"))
     except Exception as e:  # pragma: no cover — import breakage only
         feats.append(("serving: elastic fleet (drain/spawn/re-role)",
                       False, str(e)))

@@ -19,7 +19,7 @@ Architecture (TPU-first, round-4 async design):
   measured difference is ~280ms vs ~8.5ms per decode token-step.
 - Steps are cached jitted programs — a SplitFuse plan ([S, chunk] prompt
   chunks with decode rows fused in) or a multi-iteration decode window
-  (early-exiting ``lax.while_loop``) — built by inference/scheduler.py
+  (a fixed-trip ``lax.scan``) — built by inference/scheduler.py
   from a SPECULATIVE view of each sequence (dispatched-but-uncommitted).
 - Dispatch never waits: decode chains through a device-resident
   last-sampled-token array, sampled-token readbacks ride d2h in the
@@ -80,6 +80,10 @@ KIND_SPEC_3D = {"row": P(None, "tensor", None),
                 "col": P(None, None, "tensor"),
                 "rep": P(None, None, None)}
 
+
+#: ``tp_overlap`` in auto mode: the fewest token rows a ring chunk
+#: (S*T // tensor) must carry before a program rings
+TP_OVERLAP_MIN_ROWS = 64
 
 #: floors of the routed-expert tile height: a bf16 tile is 16 sublanes; the
 #: quantised grouped GEMM was validated (and rings its chunks) at 32
@@ -154,7 +158,8 @@ class RaggedInferenceConfig:
     #: KV page width. Wide pages feed the attention kernel full-lane MXU
     #: tiles and shrink the page grid — measured on v5e (gpt2-350m long
     #: mix): 6032/7459/9800 prompt tok/s at 32/64/128. 64 balances that
-    #: against per-sequence memory granularity; the bench runs 128.
+    #: against per-sequence memory granularity; the benchmark's
+    #: configurations (``benchmark/configs/``) set 128.
     block_size: int = 64
     num_blocks: int = 64
     max_seqs: int = 8                 # state_manager max_tracked_sequences
@@ -185,17 +190,6 @@ class RaggedInferenceConfig:
     #: windowing entirely. Pow2-floored like the window itself, so the
     #: compiled-program menu stays bounded. 0 disables the cap.
     decode_window_mixed_cap: int = 4
-    #: run the decode window body as an early-exiting ``lax.while_loop``
-    #: (True) instead of a fixed-trip ``lax.scan`` (False, default). The
-    #: while_loop stops the moment every slot is done, but its
-    #: data-dependent trip count blocks XLA from software-pipelining
-    #: across iterations — each iteration's weight reads start only after
-    #: the previous exit test. The scan unrolls to a known W iterations,
-    #: letting the scheduler overlap iteration i+1's first weight reads
-    #: with iteration i's tail; wasted work only arises when EVERY slot
-    #: exits early (the scheduler already sizes W to the largest
-    #: remaining budget, so a full-length slot runs all W either way).
-    decode_early_exit: bool = False
     #: async pipeline depth: how many dispatched steps may await host
     #: readback before the engine blocks on the oldest. Dispatch never
     #: waits for sampled tokens (decode chains through a device-resident
@@ -282,7 +276,7 @@ class RaggedInferenceConfig:
     #: (matmul⊗reduce-scatter) instead of blocking on the GSPMD
     #: all-reduce (parallel/tensor.py). None = auto: on whenever tensor>1,
     #: the model's head/ffn dims divide by the axis, AND the program
-    #: carries at least ``tp_overlap_min_rows`` token rows per ring chunk
+    #: carries at least ``TP_OVERLAP_MIN_ROWS`` token rows per ring chunk
     #: — prefill/training-shaped M; decode windows (M = max_seqs) stay on
     #: the blocking path by default because each ring step re-reads the
     #: weight shard, and at HBM-roofline decode sizes n× weight traffic
@@ -292,17 +286,6 @@ class RaggedInferenceConfig:
     #: True = require: ring EVERY divisible program including decode, and
     #: raise when the geometry can't ring.
     tp_overlap: bool | None = None
-    #: auto-mode gate: minimum token rows per ring chunk (S*T // tp)
-    #: before a program rings — see ``tp_overlap``
-    tp_overlap_min_rows: int = 64
-    #: int8/fp8 weight matmul dispatch for few-row calls: None (auto)
-    #: routes M <= quant_matmul.SMALL_M_XLA rows through XLA's fused
-    #: dequant-dot — at decode the Pallas tile kernel is VPU-bound on the
-    #: whole-weight dequant while XLA folds convert+multiply into the
-    #: dot's operand read (the halved HBM traffic actually lands).
-    #: True/False forces the choice for every quantized dense matmul
-    #: (profiling escape hatch; int4 always keeps the Pallas kernel).
-    quant_small_m_xla: bool | None = None
     #: speculative decoding (inference/speculative.py): None = off;
     #: "ngram" = self-speculative prompt-lookup proposer (no extra
     #: weights — candidates come from the sequence's own history);
@@ -1099,9 +1082,8 @@ class InferenceEngineV2:
         from ..ops.pallas.quant_matmul import quant_matmul
 
         mesh = self.topology.mesh
-        sm = self.config.quant_small_m_xla
         if mesh.size == 1:
-            return quant_matmul(x2d, qw, layer_index=li, small_m_xla=sm)
+            return quant_matmul(x2d, qw, layer_index=li)
         kind = self._qkind[name]
         ws = KIND_SPEC_2D[kind]
         if li is not None:
@@ -1111,8 +1093,7 @@ class InferenceEngineV2:
 
         def fn(xl, ql, lil):
             y = quant_matmul(xl, ql, layer_index=(None if li is None
-                                                  else lil),
-                             small_m_xla=sm)
+                                                  else lil))
             return jax.lax.psum(y, "tensor") if kind == "row" else y
 
         lia = jnp.zeros((), jnp.int32) if li is None else li
@@ -1286,13 +1267,13 @@ class InferenceEngineV2:
         # (exact-k packed prefill plans with odd row counts fall back to
         # the blocking einsum path, counted per compiled program), and the
         # auto mode additionally requires ring chunks of at least
-        # tp_overlap_min_rows rows (decode-sized programs would pay n×
+        # TP_OVERLAP_MIN_ROWS rows (decode-sized programs would pay n×
         # weight re-reads for a tiny hidden collective; tp_overlap=True
         # overrides for measurement)
         rn = self._tp_ring_n
         if rn and (tree_mode or S % rn or not (
                 self._tp_ring_force
-                or (S * T) // rn >= self.config.tp_overlap_min_rows)):
+                or (S * T) // rn >= TP_OVERLAP_MIN_ROWS)):
             overlap_counters.fallback()
             rn = 0
         mesh_t = self.topology.mesh
@@ -1476,20 +1457,17 @@ class InferenceEngineV2:
                 # intermediate size — ring only when it divides the axis
                 if isinstance(wu, QuantLinear) or wu.shape[1] % rn == 0:
                     h2 = h.reshape(S * T, -1)
-                    sm = cfg.quant_small_m_xla
                     if m.activation == "silu_glu":
                         g2, u2 = allgather_matmul(
-                            h2, (fwr("w_gate"), wu), mesh_t,
-                            layer_index=li, small_m_xla=sm)
+                            h2, (fwr("w_gate"), wu), mesh_t, layer_index=li)
                         z = jax.nn.silu(g2) * u2
                     else:
-                        u2 = allgather_matmul(h2, wu, mesh_t,
-                                              layer_index=li, small_m_xla=sm)
+                        u2 = allgather_matmul(h2, wu, mesh_t, layer_index=li)
                         z = _ACTS[m.activation](
                             u2 + f["b_up"].astype(u2.dtype))
                     y2 = matmul_reduce_scatter(
                         z.astype(cfg.dtype), fwr("w_down"), mesh_t,
-                        layer_index=li, small_m_xla=sm)
+                        layer_index=li)
                     out = y2.reshape(S, T, -1).astype(cfg.dtype)
                     if m.activation != "silu_glu":
                         out = out + f["b_down"].astype(cfg.dtype)
@@ -1552,8 +1530,7 @@ class InferenceEngineV2:
                     return w2.reshape(w2.shape[0], -1)
                 q2, k2, v2 = allgather_matmul(
                     h.reshape(S * T, -1), (aw("wq"), aw("wk"), aw("wv")),
-                    mesh_t, layer_index=qli,
-                    small_m_xla=cfg.quant_small_m_xla)
+                    mesh_t, layer_index=qli)
                 q = q2.reshape(S, T, H, -1).astype(cfg.dtype)
                 k = k2.reshape(S, T, KV, -1).astype(cfg.dtype)
                 v = v2.reshape(S, T, KV, -1).astype(cfg.dtype)
@@ -1723,8 +1700,7 @@ class InferenceEngineV2:
                 if not isinstance(wo, QuantLinear):
                     wo = wo.astype(cfg.dtype).reshape(-1, wo.shape[-1])
                 o2 = matmul_reduce_scatter(
-                    o.reshape(S * T, -1), wo, mesh_t, layer_index=qli,
-                    small_m_xla=cfg.quant_small_m_xla)
+                    o.reshape(S * T, -1), wo, mesh_t, layer_index=qli)
                 o = o2.reshape(S, T, -1).astype(cfg.dtype)
             else:
                 o = proj_out(o, a["wo"], li=qli)
@@ -2022,14 +1998,12 @@ class InferenceEngineV2:
         device-resident last-sample array when the host value is still
         in flight (``use_last``).
 
-        Loop form (round-6): default is a FIXED-trip ``lax.scan`` — a
-        known trip count lets XLA software-pipeline across iterations
-        (iteration i+1's first weight reads overlap iteration i's tail),
-        which a data-dependent ``while_loop`` exit test forbids. The
-        while_loop form survives behind ``decode_early_exit=True``; its
-        only win is skipping iterations after EVERY slot exits early
-        (eos), since the scheduler already sizes W to the largest
-        remaining budget."""
+        The window is a FIXED-trip ``lax.scan``: a known trip count lets
+        XLA software-pipeline across iterations (iteration i+1's first
+        weight reads overlap iteration i's tail), which a data-dependent
+        exit test forbids. Work is wasted only when EVERY slot exits early
+        (eos): the scheduler already sizes W to the largest remaining
+        budget."""
         key = ("win", W)
         if key not in self._programs:
             cfg = self.config
@@ -2080,48 +2054,21 @@ class InferenceEngineV2:
                     return (out_tok, slot, tok, pos, lens, rng, nxt_active,
                             kbuf, vbuf)
 
-                if cfg.decode_early_exit:
-                    def cond(carry):
-                        i, active = carry[0], carry[6]
-                        return (i < W) & jnp.any(active)
+                def body(carry, i):
+                    tok, pos, lens, rng, active, kbuf, vbuf = carry
+                    (out_tok, slot, tok, pos, lens, rng, active, kbuf,
+                     vbuf) = _iter(i, tok, pos, lens, rng, active, kbuf,
+                                   vbuf)
+                    return ((tok, pos, lens, rng, active, kbuf, vbuf),
+                            (out_tok, slot))
 
-                    def body(carry):
-                        (i, tok, pos, lens, rng, buf, active, kbuf, vbuf,
-                         slots) = carry
-                        (out_tok, slot, tok, pos, lens, rng, active, kbuf,
-                         vbuf) = _iter(i, tok, pos, lens, rng, active,
-                                       kbuf, vbuf)
-                        buf = buf.at[i].set(out_tok)
-                        slots = slots.at[i].set(slot)
-                        return (i + 1, tok, pos, lens, rng, buf, active,
-                                kbuf, vbuf, slots)
-
-                    buf0 = jnp.full((W, S), -1, jnp.int32)
-                    slots0 = jnp.zeros((W, S), jnp.int32)
-                    (i, tok, _, _, _, buf, _, kbuf, vbuf,
-                     slots) = jax.lax.while_loop(
-                        cond, body,
-                        (jnp.int32(0), tok0, pos0, lens0, rng, buf0,
-                         active0, stage0, stage0, slots0))
-                else:
-                    def body(carry, i):
-                        tok, pos, lens, rng, active, kbuf, vbuf = carry
-                        (out_tok, slot, tok, pos, lens, rng, active, kbuf,
-                         vbuf) = _iter(i, tok, pos, lens, rng, active,
-                                       kbuf, vbuf)
-                        return ((tok, pos, lens, rng, active, kbuf, vbuf),
-                                (out_tok, slot))
-
-                    ((tok, _, _, _, _, kbuf, vbuf),
-                     (buf, slots)) = jax.lax.scan(
-                        body, (tok0, pos0, lens0, rng, active0, stage0,
-                               stage0),
-                        jnp.arange(W, dtype=jnp.int32))
-                    # useful-iteration count for stats parity with the
-                    # early-exit form: iterations past the last active
-                    # slot emit all -1
-                    i = jnp.sum(jnp.any(buf >= 0, axis=1),
-                                dtype=jnp.int32)
+                ((tok, _, _, _, _, kbuf, vbuf),
+                 (buf, slots)) = jax.lax.scan(
+                    body, (tok0, pos0, lens0, rng, active0, stage0, stage0),
+                    jnp.arange(W, dtype=jnp.int32))
+                # useful-iteration count: iterations past the last active
+                # slot emit all -1
+                i = jnp.sum(jnp.any(buf >= 0, axis=1), dtype=jnp.int32)
                 # only window PARTICIPANTS may update the device-resident
                 # last token: slots outside the window (empty/sched_done)
                 # carry tok0 = 0, and clobbering their last_tok would make
@@ -2846,8 +2793,8 @@ class InferenceEngineV2:
         """Lifetime shared-prefix cache counters — cached/referenced page
         counts, hit/lookup tokens, insert/dedup/evict totals (None when
         the cache is disabled). The per-run view lives in ``stats``
-        (``prefix_hit_tokens`` / ``prefix_hit_rate``), which the bench
-        zeroes per measured phase."""
+        (``prefix_hit_tokens`` / ``prefix_hit_rate``), which a measuring
+        caller zeroes per phase."""
         return None if self._prefix_cache is None \
             else self._prefix_cache.stats()
 
@@ -3606,7 +3553,7 @@ class InferenceEngineV2:
         """Commit-side SLOs: TTFT (admission → first committed token) and
         observed per-token time-between-tokens — a window committing n
         tokens dt after the previous commit contributes n samples of dt/n
-        (the bench's amortized-burst convention, live)."""
+        (the amortized-burst convention, live)."""
         now = time.perf_counter()
         reg = self._telem.registry
         rt = self._rt
@@ -3673,8 +3620,8 @@ class InferenceEngineV2:
         process-wide in parallel/tensor.py) into this engine's stats.
 
         INCREMENTAL (+= new-since-last-refresh, base rebased each call)
-        rather than since-init values: callers like bench's serve() zero
-        the stats dict per measured run, and an absolute-delta overwrite
+        rather than since-init values: a measuring caller zeroes the
+        stats dict per run, and an absolute-delta overwrite
         would silently clobber that reset with cumulative numbers. A
         snapshot BELOW the base means someone reset the process-wide
         counters — rebase to zero instead of emitting negative deltas.

@@ -168,9 +168,9 @@ class SplitFuseScheduler:
     def program_shape_menu(self) -> list[tuple[int, int]]:
         """Every (T, n_rows) prefill-plan shape :meth:`next_step` can emit
         under the current packing config — THE warm list for anything that
-        must never compile mid-serve (the bench probe pre-compiles these;
-        a hand-kept copy drifted once and cost a 4.5s recompile inside an
-        SLA-scored run). Mirrors the packing math below by construction."""
+        must never compile mid-serve (a hand-kept copy drifted once and
+        cost a 4.5s recompile inside an SLA-scored run). Mirrors the
+        packing math below by construction."""
         S_max = self.state.max_seqs
         shapes = {(self.chunk, S_max)}
         if not self.pack:
@@ -297,7 +297,7 @@ class SplitFuseScheduler:
             # pool-throttled steady state entirely — measured 54%
             # occupancy on the long mix), and each row's chunk grows by
             # the pow2 budget multiplier. One compiled program per
-            # (rows, chunk) pair, ~4s each, warmed by the bench probe.
+            # (rows, chunk) pair, ~4s each: warm ``program_shape_menu()``.
             k = min(len(prefill), st.max_seqs)
             n_rows = st.max_seqs
             T = self.chunk

@@ -27,9 +27,7 @@ PRESETS: dict[str, ModelConfig] = {
                              activation="gelu"),
     # gpt2-large geometry: the largest preset that stays HBM-resident on a
     # 16GB chip with fp32 master+opt state (16 B/param ~ 12.4GB + remat
-    # activations) — the model-scale bench entry for hosts whose
-    # host-device link is too slow for ZeRO-Offload at 1.3b (VERDICT r03
-    # weak #2)
+    # activations)
     "gpt2-774m": ModelConfig(vocab_size=50257, hidden_size=1280, num_layers=36,
                              num_heads=20, max_seq_len=1024,
                              position_embedding="learned", qkv_bias=True, attn_out_bias=True,

@@ -148,32 +148,28 @@ def _paged_attn_kernel(tables_ref, lens_ref, starts_ref, q_ref, k_ref, v_ref,
 
 
 def _live_steps(j, seq_len, qstart, sstart, *, block_size: int, window: int,
-                ring_tokens: int, page_group: int, n_pool: int, n_grp: int,
-                srows: int, tree: bool, xp=jnp):
+                ring_tokens: int, n_pool: int, srows: int, tree: bool,
+                xp=jnp):
     """(run_pool, run_stage) of column ``j`` of a slot's walk: whether its
-    ``page_group`` pool pages hold a key some query row of the call can see,
-    and whether its stage page does. Columns ``j < n_grp`` are pool page
-    groups, the rest stage pages. THE rule of the ragged kernel's iteration
-    space: the kernel evaluates it on the scalars of one step,
-    :func:`paged_work_list` on the whole ``[S, n_grp + nsp]`` rectangle
+    pool page holds a key some query row of the call can see, and whether
+    its stage page does. Columns ``j < n_pool`` are the table's pool pages,
+    the rest stage pages. THE rule of the ragged kernel's iteration space:
+    the kernel evaluates it on the scalars of one step,
+    :func:`paged_work_list` on the whole ``[S, n_pool + nsp]`` rectangle
     (and :func:`paged_step_counts` on the host, ``xp=numpy``)."""
-    Gp = page_group
-    is_stage = j >= n_grp
+    is_stage = j >= n_pool
     if ring_tokens:
         nwin = ring_tokens // block_size
         b_latest = xp.maximum(sstart - 1, 0) // block_size
-        first_jj = j * Gp
         run_pool = (sstart > 0) & (~is_stage) \
-            & (b_latest - (b_latest - first_jj) % nwin >= 0) \
-            & (first_jj < n_pool)           # jnp %: floor semantics
+            & (b_latest - (b_latest - j) % nwin >= 0)   # jnp %: floor semantics
     else:
-        group_start = j * Gp * block_size
-        run_pool = (group_start < sstart) & (~is_stage)
+        page_start = j * block_size
+        run_pool = (page_start < sstart) & (~is_stage)
         if window:
             # earliest key any row of this call can see is qstart-window+1
-            run_pool &= (group_start + Gp * block_size
-                         > qstart - window + 1)
-    sp = xp.maximum(j - n_grp, 0)            # stage page index
+            run_pool &= page_start + block_size > qstart - window + 1
+    sp = xp.maximum(j - n_pool, 0)           # stage page index
     if tree:
         # every stage row is a candidate NODE — a branchy tree packs more
         # nodes than its depth, so seq_len (root+1+max_depth) undercounts
@@ -185,18 +181,15 @@ def _live_steps(j, seq_len, qstart, sstart, *, block_size: int, window: int,
     return run_pool, run_stage
 
 
-def _ragged_geometry(max_pages: int, stage_rows: int, block_size: int,
-                     page_group: int = 1):
-    """(n_grp, nsp, srows): pool page-group columns, stage pages, rows a
-    stage page — the rectangle's width is ``n_grp + nsp``."""
+def _ragged_geometry(stage_rows: int, block_size: int):
+    """(nsp, srows): stage pages and rows a stage page — the rectangle's
+    width is the table's ``max_pages`` pool columns + ``nsp``."""
     if stage_rows <= block_size:
-        srows, nsp = stage_rows, 1
-    else:
-        if stage_rows % block_size:
-            raise ValueError(f"stage rows {stage_rows} must be a multiple of "
-                             f"block_size {block_size} (or <= it)")
-        srows, nsp = block_size, stage_rows // block_size
-    return -(-max_pages // page_group), nsp, srows
+        return 1, stage_rows
+    if stage_rows % block_size:
+        raise ValueError(f"stage rows {stage_rows} must be a multiple of "
+                         f"block_size {block_size} (or <= it)")
+    return stage_rows // block_size, block_size
 
 
 def _item_bits(nj: int) -> int:
@@ -211,45 +204,40 @@ def _unpack_item(code, jbits: int):
 
 def _live_rectangle(seq_lens, q_starts, stage_starts, *, block_size: int,
                     max_pages: int, stage_rows: int, window, ring_tokens,
-                    page_group: int, tree: bool, xp):
-    """:func:`_live_steps` over the whole rectangle: (live ``[S, n_grp +
-    nsp]`` bool, n_grp). ``xp`` is ``jnp`` (traced) or ``numpy`` (host)."""
-    n_grp, nsp, srows = _ragged_geometry(max_pages, stage_rows, block_size,
-                                         page_group)
+                    tree: bool, xp):
+    """:func:`_live_steps` over the whole rectangle: live ``[S, max_pages +
+    nsp]`` bool. ``xp`` is ``jnp`` (traced) or ``numpy`` (host)."""
+    nsp, srows = _ragged_geometry(stage_rows, block_size)
     col = lambda a: xp.asarray(a, xp.int32)[:, None]
     run_pool, run_stage = _live_steps(
-        xp.arange(n_grp + nsp, dtype=xp.int32)[None, :], col(seq_lens),
+        xp.arange(max_pages + nsp, dtype=xp.int32)[None, :], col(seq_lens),
         col(q_starts), col(stage_starts), block_size=block_size,
         window=int(window or 0), ring_tokens=int(ring_tokens or 0),
-        page_group=page_group, n_pool=max_pages, n_grp=n_grp, srows=srows,
-        tree=tree, xp=xp)
-    return run_pool | run_stage, n_grp
+        n_pool=max_pages, srows=srows, tree=tree, xp=xp)
+    return run_pool | run_stage
 
 
 def paged_work_list(seq_lens, q_starts, stage_starts, *, block_size: int,
                     max_pages: int, stage_rows: int,
                     window: int | None = None,
-                    ring_tokens: int | None = None, page_group: int = 1,
-                    tree: bool = False):
+                    ring_tokens: int | None = None, tree: bool = False):
     """The ragged kernel's iteration space as a list: the ``(slot, column)``
-    steps of the ``[S, n_grp + nsp]`` rectangle for which
+    steps of the ``[S, max_pages + nsp]`` rectangle for which
     :func:`_live_steps` is true, compacted in ``(slot, column)`` order, each
     marked first-/last-of-slot. A slot with no live step gets ONE item that
     only initialises and finalises (its rows must read zero, not stale
-    VMEM). Returns ``(items, n_items)``: int32 ``[S * (n_grp + nsp) + 1]``
+    VMEM). Returns ``(items, n_items)``: int32 ``[S * (max_pages + nsp) + 1]``
     packed ``slot | column | last | first`` (the rectangle is the largest a
     list can get — prefix-shared pages sit in several tables, so no count of
     pool blocks bounds it; one spare entry keeps the pipeline's look-ahead
     past the last item in bounds), and how many of them are items.
 
     Nothing here depends on the layer: a forward builds it once, outside
-    its layer loop, and hands it to every layer's kernel call.
-    ``page_group`` is the EFFECTIVE group (1 unless the caller of
-    :func:`paged_ragged_attention` asked for more)."""
-    live, n_grp = _live_rectangle(
+    its layer loop, and hands it to every layer's kernel call."""
+    live = _live_rectangle(
         seq_lens, q_starts, stage_starts, block_size=block_size,
         max_pages=max_pages, stage_rows=stage_rows, window=window,
-        ring_tokens=ring_tokens, page_group=page_group, tree=tree, xp=jnp)
+        ring_tokens=ring_tokens, tree=tree, xp=jnp)
     S, nj = live.shape
     jbits = _item_bits(nj)
     if 2 + jbits + max(1, (S - 1).bit_length()) > 31:
@@ -265,7 +253,7 @@ def paged_work_list(seq_lens, q_starts, stage_starts, *, block_size: int,
     # column: its pool refs map to the trash block
     j = jax.lax.broadcasted_iota(jnp.int32, (S, nj), 1)
     s = jax.lax.broadcasted_iota(jnp.int32, (S, nj), 0)
-    alone = (count == 0) & (j == n_grp)
+    alone = (count == 0) & (j == max_pages)
     code = ((s << jbits | j) << 2 | (last | alone).astype(jnp.int32) << 1
             | (first | alone).astype(jnp.int32))
     item = live | alone
@@ -286,21 +274,21 @@ def paged_step_counts(seq_lens, q_starts, stage_starts, *, block_size: int,
     """(live, rectangle) grid steps of ONE ragged-kernel call a q-tile, on
     the host: the steps :func:`_live_steps` passes (what
     :func:`paged_work_list` lists, less the finalize-only items of empty
-    slots) and the ``S x (n_grp + nsp)`` steps of the rectangle a grid
+    slots) and the ``S x (max_pages + nsp)`` steps of the rectangle a grid
     over slots and table width would walk. numpy in, ints out."""
-    live, _ = _live_rectangle(
+    live = _live_rectangle(
         seq_lens, q_starts, stage_starts, block_size=block_size,
         max_pages=max_pages, stage_rows=stage_rows, window=window,
-        ring_tokens=ring_tokens, page_group=1, tree=tree, xp=np)
+        ring_tokens=ring_tokens, tree=tree, xp=np)
     return int(live.sum()), live.size
 
 
 def _ragged_attn_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
-                        work_ref, *refs, block_size: int,
-                        scale: float, G: int, window: int,
-                        ring_tokens: int, n_grp: int, srows: int,
-                        jbits: int, page_group: int, n_pool: int,
-                        p_scale: float = 1.0, tree: bool = False):
+                        work_ref, q_ref, kp_ref, vp_ref, ks_ref, vs_ref,
+                        *refs, block_size: int, scale: float, G: int,
+                        window: int, ring_tokens: int, srows: int,
+                        jbits: int, n_pool: int, p_scale: float = 1.0,
+                        tree: bool = False):
     """Read-only-pool ragged attention, ALL kv heads per grid step.
 
     What the measured costs on real hardware made of
@@ -315,10 +303,10 @@ def _ragged_attn_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
        caller.
     2. A (seqs, kv_heads, pages) grid ran ~200k grid steps per decode
        iteration. All KV heads ride one block-DMA and one batched MXU dot
-       per step, and a slot's walk is its pool page-groups, then its stage
-       pages (the staged tokens instead of a pool page).
+       per step, and a slot's walk is its pool pages, ONE a step, then its
+       stage pages (the staged tokens instead of a pool page).
     3. The steps themselves are a LIST, not a rectangle (PR 26). The
-       rectangle slots x (page-groups + stage pages) is sized for every
+       rectangle slots x (table width + stage pages) is sized for every
        slot live at the full table width; an interactive replica holds a
        few short contexts. Measured on a v5e at 48 slots x (128 + 1)
        columns, 32 heads over 8, the kernel alone: a predicated-off step
@@ -333,12 +321,6 @@ def _ragged_attn_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
        slots at the full 16k tokens 5.05ms either way. A slot's pages come
        in the same order as in the rectangle walk: the outputs are
        bitwise the same, on the chip too.
-    4. ``page_group`` pool pages can ride ONE step through separate
-       block-spec refs (each with its own scalar-prefetched table index);
-       tail/invalid sub-pages map to the trash block so the pipeline
-       elides their re-fetch. Measured a loss on v5e where the call is
-       DMA-bound on its valid pages (see ``paged_ragged_attention``); off
-       by default.
 
     ``tree`` (the speculative-verify form): each query row is a
     candidate-tree NODE, not a token of a contiguous chunk. Two extra
@@ -352,20 +334,12 @@ def _ragged_attn_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
     inference/engine_v2.py `_ragged_forward`.
 
     Grid (q-tiles, n_items).
-    ``refs`` = (q, k_0..k_{Gp-1}, v_0..v_{Gp-1}, k_stage, v_stage,
-    [tpos, tmask when tree,] o, m_scr, l_scr, acc_scr).
+    ``refs`` = ([tpos, tmask when tree,] o, m_scr, l_scr, acc_scr).
     """
     del layer_ref
-    Gp = page_group
-    q_ref = refs[0]
-    kp_refs = refs[1:1 + Gp]
-    vp_refs = refs[1 + Gp:1 + 2 * Gp]
-    ks_ref, vs_ref = refs[1 + 2 * Gp:3 + 2 * Gp]
     if tree:
-        tpos_ref, tmask_ref = refs[3 + 2 * Gp:5 + 2 * Gp]
-        o_ref, m_scr, l_scr, acc_scr = refs[5 + 2 * Gp:]
-    else:
-        o_ref, m_scr, l_scr, acc_scr = refs[3 + 2 * Gp:]
+        tpos_ref, tmask_ref, *refs = refs
+    o_ref, m_scr, l_scr, acc_scr = refs
     tq = pl.program_id(0)          # query-row tile (VMEM-bounds long chunks)
     s, j, first, last = _unpack_item(work_ref[pl.program_id(1)], jbits)
 
@@ -430,24 +404,14 @@ def _ragged_attn_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
     # finalize-only item of an empty slot: there both are false
     run_pool, run_stage = _live_steps(
         j, seq_len, qstart, sstart, block_size=block_size, window=window,
-        ring_tokens=ring_tokens, page_group=Gp, n_pool=n_pool, n_grp=n_grp,
-        srows=srows, tree=tree)
+        ring_tokens=ring_tokens, n_pool=n_pool, srows=srows, tree=tree)
 
-    # ---- pool page step: page_group sub-pages, ONE online update --------
-    # The serial cost of a grid step is its softmax/update CHAIN, not its
-    # dot (measured r5: per-sub-page chains made grouping a net loss).
-    # The Gp pages therefore concatenate in VMEM into one [KV, Gp*bs, D]
-    # tile and run a single chain ~Gp x wider — vector ops grow by lane
-    # count, chain length stays flat.
+    # ---- pool page step --------------------------------------------------
     @pl.when(run_pool)
     def _pool_step():
         q = q_ref[0]                                       # [KV, TQB, D]
-        if Gp == 1:
-            k = kp_refs[0][0, 0, :, 0]                     # [KV, bs, D]
-            v = vp_refs[0][0, 0, :, 0]
-        else:
-            k = jnp.concatenate([r[0, 0, :, 0] for r in kp_refs], axis=1)
-            v = jnp.concatenate([r[0, 0, :, 0] for r in vp_refs], axis=1)
+        k = kp_ref[0, 0, :, 0]                             # [KV, bs, D]
+        v = vp_ref[0, 0, :, 0]
         if k.dtype != q.dtype:
             # fp8 KV pool: converting the PAGE up costs ~10us/page in
             # Mosaic (element-wise + sublane relayout); converting the
@@ -464,23 +428,26 @@ def _ragged_attn_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
             q = q.astype(k.dtype)
         scores = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale  # [KV,TQB,Gp*bs]
+            preferred_element_type=jnp.float32) * scale    # [KV, TQB, bs]
         off = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 2)
         if ring_tokens:
             nwin = ring_tokens // block_size
             b_latest = jnp.maximum(sstart - 1, 0) // block_size
-            jj = j * Gp + off // block_size    # per-element pool page idx
-            b_j = b_latest - (b_latest - jj) % nwin
-            raw = b_j * block_size + off % block_size
+            # per element, from the broadcast page index: derived on the
+            # step's scalars, the remainder sits ahead of every vector op
+            # of the step — measured on a v5e over an fp8 pool (20 slots
+            # live in a 34-page ring), 425 us a call against 413
+            b_j = b_latest - (b_latest - jnp.full_like(off, j)) % nwin
+            raw = b_j * block_size + off
             ctx = jnp.where(raw < sstart, raw, raw - ring_tokens)
-            valid = (ctx >= 0) & (b_j >= 0) & (jj < n_pool)
+            valid = (ctx >= 0) & (b_j >= 0)
         else:
-            ctx = j * Gp * block_size + off
-            valid = ctx < sstart               # jj >= n_pool ⇒ ctx >= sstart
+            ctx = j * block_size + off
+            valid = ctx < sstart
         online_update(scores, ctx, valid, v)
 
     # ---- stage steps (this program's fresh tokens, page-sized tiles) -----
-    sp = jnp.maximum(j - n_grp, 0)           # stage page index
+    sp = jnp.maximum(j - n_pool, 0)          # stage page index
 
     @pl.when(run_stage)
     def _stage_step():
@@ -515,7 +482,6 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
                            scale: float | None = None,
                            window: int | None = None,
                            ring_tokens: int | None = None,
-                           page_group: int | None = None,
                            tree_positions=None, tree_mask=None, work=None,
                            interpret: bool | None = None):
     """Ragged attention over a READ-ONLY paged pool plus a staged tail.
@@ -589,53 +555,38 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
     while TG % TQB:
         TQB //= 2
     n_pool = max_pages
-    # sub-pages per grid step. Measured on v5e (520-token decode contexts,
-    # 136-step baseline 84us/call): page_group 2 -> 95us, 4 -> 106-117us —
-    # the call is DMA-bound on its valid pages, and the VMEM concat + wider
-    # chain only adds work. Default therefore 1; the grouped path stays for
-    # experiments on geometries with tiny pages.
-    page_b = KV * bs * D * 2            # one pool page in VMEM (bf16)
-    score_b = KV * TQB * bs * 4         # f32 score tile per sub-page
-    Gp = page_group if page_group else 1
-    Gp = max(1, min(Gp, n_pool))
-    # budget: 2*Gp pool refs double-buffered + the k/v concat tiles +
-    # the [KV, TQB, Gp*bs] f32 score tile, inside ~16MB scoped VMEM
-    while Gp > 1 and 6 * Gp * page_b + Gp * score_b > 8 * 2 ** 20:
-        Gp //= 2
-    n_grp, nsp, srows = _ragged_geometry(n_pool, Ts, bs, Gp)
-    jbits = _item_bits(n_grp + nsp)
+    nsp, srows = _ragged_geometry(Ts, bs)
+    jbits = _item_bits(n_pool + nsp)
     if work is None:
         work = paged_work_list(
             seq_lens, q_starts, stage_starts, block_size=bs,
             max_pages=n_pool, stage_rows=Ts, window=window,
-            ring_tokens=ring_tokens, page_group=Gp, tree=tree)
+            ring_tokens=ring_tokens, tree=tree)
     items, n_items = work
-    if items.shape != (S * (n_grp + nsp) + 1,):
+    if items.shape != (S * (n_pool + nsp) + 1,):
         raise ValueError(f"work list {items.shape} was not built for {S} "
-                         f"slots x {n_grp + nsp} columns")
+                         f"slots x {n_pool + nsp} columns")
 
     def item(wl, i):
         s, j, _, _ = _unpack_item(wl[i], jbits)
         return s, j
 
-    def tbj(t, s, jj):
-        # tail sub-pages of the last group and stage steps still need a
-        # legal page index — map them to the trash block (0); their
-        # re-fetch is elided when the previous index was already 0
-        return jnp.where(jj < n_pool, t[s, jnp.minimum(jj, n_pool - 1)], 0)
-
     # index maps see (q-tile, item, *scalar-prefetch refs); the last of
     # those is the work list, which says which (slot, column) item i is
-    def pool_spec(half, g):
+    def pool_spec(half):
         def index(tq, i, t, ln, qs, ss, lr, wl):
             s, j = item(wl, i)
-            return lr[0], half, 0, tbj(t, s, j * Gp + g), 0, 0
+            # a stage step still needs a legal page index: the trash block
+            # (0), whose re-fetch is elided when the previous index was 0
+            return (lr[0], half, 0,
+                    jnp.where(j < n_pool, t[s, jnp.minimum(j, n_pool - 1)], 0),
+                    0, 0)
         return pl.BlockSpec((1, 1, KV, 1, bs, D), index)
 
     def stage_spec():
         def index(tq, i, t, ln, qs, ss, lr, wl):
             s, j = item(wl, i)
-            return s, 0, jnp.maximum(j - n_grp, 0), 0
+            return s, 0, jnp.maximum(j - n_pool, 0), 0
         return pl.BlockSpec((1, KV, srows, D), index)
 
     def q_index(tq, i, t, ln, qs, ss, lr, wl):
@@ -669,7 +620,7 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
 
         def tmask_index(tq, i, t, ln, qs, ss, lr, wl):
             s, j = item(wl, i)
-            return s, jnp.maximum(j - n_grp, 0), tq, 0
+            return s, jnp.maximum(j - n_pool, 0), tq, 0
 
         tree_specs = [pl.BlockSpec((1, 1, TQB), tpos_index),
                       pl.BlockSpec((1, 1, TQB, srows), tmask_index)]
@@ -679,8 +630,8 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
         grid=(TG // TQB, n_items),
         in_specs=[
             pl.BlockSpec((1, KV, TQB, D), q_index),
-            *[pool_spec(0, g) for g in range(Gp)],
-            *[pool_spec(1, g) for g in range(Gp)],
+            pool_spec(0),
+            pool_spec(1),
             stage_spec(),
             stage_spec(),
             *tree_specs,
@@ -699,9 +650,9 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
     out = pl.pallas_call(
         functools.partial(_ragged_attn_kernel, block_size=block_size,
                           scale=float(scale), G=G, window=int(window or 0),
-                          ring_tokens=int(ring_tokens or 0), n_grp=n_grp,
-                          srows=srows, jbits=jbits, page_group=Gp,
-                          n_pool=n_pool, p_scale=p_scale, tree=tree),
+                          ring_tokens=int(ring_tokens or 0), srows=srows,
+                          jbits=jbits, n_pool=n_pool, p_scale=p_scale,
+                          tree=tree),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, KV, TG, D), q.dtype),
         name=("paged_attn_tree" if tree else "paged_attn_decode" if T == 1
@@ -710,7 +661,7 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
       q_starts.astype(jnp.int32), stage_starts.astype(jnp.int32),
       jnp.asarray(layer_index, jnp.int32).reshape(1), items,
-      qg, *([pool] * Gp), *([pool] * Gp), k_stage, v_stage, *tree_ops)
+      qg, pool, pool, k_stage, v_stage, *tree_ops)
     return (out.reshape(S, KV, T, G, D).transpose(0, 2, 1, 3, 4)
             .reshape(S, T, H, D))
 

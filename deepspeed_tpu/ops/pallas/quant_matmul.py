@@ -264,8 +264,8 @@ def _xla_dequant_dot(x: jax.Array, qw, layer_index) -> jax.Array:
     return (x @ w)[:, :N_logical]
 
 
-def local_matmul(x: jax.Array, w, *, layer_index: jax.Array | None = None,
-                 small_m_xla: bool | None = None) -> jax.Array:
+def local_matmul(x: jax.Array, w, *,
+                 layer_index: jax.Array | None = None) -> jax.Array:
     """Per-shard 2D matmul dispatch by weight type: ``QuantLinear`` routes
     through :func:`quant_matmul` (in-tile dequant Pallas kernel or the
     fused-XLA small-M dispatch — never a whole-shard dequantize), plain
@@ -273,8 +273,7 @@ def local_matmul(x: jax.Array, w, *, layer_index: jax.Array | None = None,
     the ring collective-matmul bodies (parallel/tensor.py) use, so
     dtype/quant routing decisions stay next to the kernels."""
     if isinstance(w, QuantLinear):
-        return quant_matmul(x, w, layer_index=layer_index,
-                            small_m_xla=small_m_xla)
+        return quant_matmul(x, w, layer_index=layer_index)
     wl = w
     if layer_index is not None and w.ndim == 3:
         wl = w[layer_index]

@@ -165,7 +165,7 @@ def _flatten_w(w, base_spec: P, stacked: bool):
     return ([w], [spec], ("d",))
 
 
-def _rebuild_dots(recipes, leaves, li, stacked, small_m_xla):
+def _rebuild_dots(recipes, leaves, li, stacked):
     """Per-weight local-dot closures from the flattened shard_map args."""
     dots, i = [], 0
     for r in recipes:
@@ -174,8 +174,7 @@ def _rebuild_dots(recipes, leaves, li, stacked, small_m_xla):
                              r[4])
             i += 2
             dots.append(lambda c, qw=qw: local_matmul(
-                c, qw, layer_index=(li if stacked else None),
-                small_m_xla=small_m_xla))
+                c, qw, layer_index=(li if stacked else None)))
         else:
             wl = leaves[i]
             i += 1
@@ -281,7 +280,7 @@ def _li_arg(layer_index):
 
 
 def allgather_matmul(x, w, mesh, *, axis: str = "tensor",
-                     layer_index=None, small_m_xla: bool | None = None):
+                     layer_index=None):
     """``<all-gather x over axis> @ w``, ring-overlapped.
 
     x: [M, K] with rows (M) sharded over ``axis``; w: [K, N] with output
@@ -318,8 +317,8 @@ def allgather_matmul(x, w, mesh, *, axis: str = "tensor",
                 f"allgather_matmul: w output dim {data_cols} not divisible "
                 f"by '{axis}' axis size {n}")
     if n == 1:
-        outs = tuple(local_matmul(x, wi, layer_index=layer_index,
-                                  small_m_xla=small_m_xla) for wi in ws)
+        outs = tuple(local_matmul(x, wi, layer_index=layer_index)
+                     for wi in ws)
         return outs[0] if single else outs
 
     leaves, specs, recipes = [], [], []
@@ -330,7 +329,7 @@ def allgather_matmul(x, w, mesh, *, axis: str = "tensor",
         recipes.append(r)
 
     def body(x_loc, li_l, *wl):
-        dots = _rebuild_dots(recipes, wl, li_l, stacked, small_m_xla)
+        dots = _rebuild_dots(recipes, wl, li_l, stacked)
         return tuple(_ring_ag_core(x_loc, dots, n, axis))
 
     fn = shard_map(body, mesh=mesh,
@@ -343,8 +342,7 @@ def allgather_matmul(x, w, mesh, *, axis: str = "tensor",
 
 
 def matmul_reduce_scatter(x, w, mesh, *, axis: str = "tensor",
-                          layer_index=None,
-                          small_m_xla: bool | None = None):
+                          layer_index=None):
     """``reduce-scatter(x @ w) over axis``, ring-overlapped.
 
     x: [M, K] with the contraction (K) sharded over ``axis``; w: [K, N]
@@ -371,13 +369,12 @@ def matmul_reduce_scatter(x, w, mesh, *, axis: str = "tensor",
             f"matmul_reduce_scatter: output rows {M} not divisible by "
             f"'{axis}' axis size {n} — pad the token dim or fall back")
     if n == 1:
-        return local_matmul(x, w, layer_index=layer_index,
-                            small_m_xla=small_m_xla)
+        return local_matmul(x, w, layer_index=layer_index)
     stacked = layer_index is not None
     leaves, specs, recipe = _flatten_w(w, P(axis, None), stacked)
 
     def body(x_loc, li_l, *wl):
-        dots = _rebuild_dots([recipe], wl, li_l, stacked, small_m_xla)
+        dots = _rebuild_dots([recipe], wl, li_l, stacked)
         return _ring_rs_core(x_loc, lambda rows, _s: dots[0](rows), n,
                              axis, x.dtype)
 
@@ -392,7 +389,7 @@ def matmul_reduce_scatter(x, w, mesh, *, axis: str = "tensor",
 
 def ring_row_matmul(x, w, mesh, *, axis: str = "tensor",
                     lead_specs: Sequence | None = None,
-                    layer_index=None, small_m_xla: bool | None = None):
+                    layer_index=None):
     """Replicated-output row-parallel matmul for the GSPMD model zoo.
 
     x: [*lead, K] (K forced ``axis``-sharded at the shard_map boundary —
@@ -445,7 +442,7 @@ def ring_row_matmul(x, w, mesh, *, axis: str = "tensor",
     leaves, specs, recipe = _flatten_w(w, P(axis, None), stacked)
 
     def body(x_loc, li_l, *wl):
-        dots = _rebuild_dots([recipe], wl, li_l, stacked, small_m_xla)
+        dots = _rebuild_dots([recipe], wl, li_l, stacked)
         x2 = x_loc.reshape(-1, x_loc.shape[-1])
         y_c = _ring_rs_core(x2, lambda rows, _s: dots[0](rows), n, axis,
                             x.dtype)

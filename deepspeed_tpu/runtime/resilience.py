@@ -162,7 +162,11 @@ class FaultInjector:
                                              while heartbeats CONTINUE (the
                                              wedged-engine shape; un-stalled
                                              late delivery drills dedup)...
-      ``replica_stall_stream_s`` (float)     ...for this long (default 1.0)
+      ``replica_stall_stream_s`` (float)     ...for at least this long
+                                             (default 1.0) AND until the
+                                             router flushes a stalled
+                                             request: the late delivery is
+                                             stale whatever the host's pace
 
     Weight-swap points (serving/deploy.py rolling deploys; armed per-slot
     via ``FleetConfig.per_slot`` like the rest of the chaos matrix):

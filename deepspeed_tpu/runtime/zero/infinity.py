@@ -162,7 +162,7 @@ class LayerStreamTrainer:
         # peak_staged_bytes counts staged PARAMS; peak_hbm_bytes adds the
         # grad queue (≤ lookahead+1 layer-grad trees) — the honest total
         self.peak_hbm_bytes = 0
-        # read-ahead effectiveness (surfaced by the bench artifact): a hit
+        # read-ahead effectiveness: a hit
         # = the group's NVMe reads were already in flight when the walk
         # needed it; a miss = the fetch had to be issued synchronously
         self.nvme_prefetch_hits = 0

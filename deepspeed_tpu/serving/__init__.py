@@ -15,7 +15,7 @@ hand each sequence's KV pages off to a decode-capable replica through
 the router (chunked, resumable, pinned-until-ack — the KV-page migration
 primitive in inference/migration.py), and per-role autoscale hint gauges
 ride the router's existing load signals. workload.py generates the
-seeded multi-tenant traces the bench and chaos suites replay.
+seeded multi-tenant traces the serving test suites replay.
 Fleet-wide distributed tracing (telemetry/fleettrace.py,
 ``RouterConfig(fleet_trace=True)``) assembles router + replica timelines
 into clock-aligned per-request views with black-box postmortem dumps

@@ -417,7 +417,7 @@ class DeployManager:
 
 
 # --------------------------------------------------------------------------
-# Toy checkpoints — the deploy suite's (and bench's) swap targets. Real
+# Toy checkpoints — the deploy suite's swap targets. Real
 # engine fleets publish via InferenceEngineV2.save_weights; the toy
 # format carries no tensors, but it exercises the REAL contract: meta +
 # state + size/crc32 manifest + atomic 'latest', verified by the same

@@ -1,8 +1,7 @@
 """Disaggregated prefill/decode serving: roles, handoffs, scale hints.
 
 Prefill and decode have opposite roofline profiles (compute-bound vs
-HBM-bound — bench ``device_probe``/``time_split`` shows it on this very
-engine), so production systems split them onto separate pools and ship
+HBM-bound), so production systems split them onto separate pools and ship
 the KV cache across (Splitwise ISCA'24, DistServe OSDI'24). This module
 is the serving-tier half of that split over the KV-page migration
 primitive (``inference/migration.py``):

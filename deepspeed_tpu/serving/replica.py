@@ -103,7 +103,7 @@ class ToyBackend:
         self.tokens_per_step = int(cfg.get("tokens_per_step", 4))
         self.decode_delay_s = float(cfg.get("decode_delay_s", 0.0))
         #: simulated per-prefill-step device time: what a cache hit (or
-        #: a pulled chain) SKIPS — the kv_pull bench's compute model
+        #: a pulled chain) SKIPS — the kv_pull tests' compute model
         self.prefill_delay_s = float(cfg.get("prefill_delay_s", 0.0))
         #: disaggregated serving role (serving/disagg.py): "prefill"
         #: freezes each sequence after its first sampled token and hands
@@ -1725,8 +1725,12 @@ def serve(cfg: dict, chan: LineChannel,
     digest_ver_sent = -1                 # first heartbeat always ships it
     tier_ver_sent = -1                   # KV-tier residency, same scheme
     tier_stat_marks: dict = {}           # telemetry delta-sync marks
-    stall_until = 0.0
-    stalled: list[dict] = []             # stream msgs queued during a stall
+    # the stream stall (fault injection): while ``stall_until`` is set,
+    # stream messages queue here; they go out late once the router has
+    # given up on a stalled request (its ``flush``) AND the time is past
+    stall_until: float | None = None
+    stall_given_up = False
+    stalled: list[dict] = []
     # fleet tracing (telemetry/fleettrace.py): record per-request
     # timeline segments (both clocks) and ship them to the router on the
     # line protocol — bounded per request AND per process, drop-counted.
@@ -1838,7 +1842,7 @@ def serve(cfg: dict, chan: LineChannel,
                           [int(x) for x in msg.get("toks", ())])
         elif t in ("done", "failed"):
             st.note_term(str(msg["id"]), msg)
-        if time.monotonic() < stall_until:
+        if stall_until is not None:
             stalled.append(msg)
             return
         _send(msg)
@@ -2107,6 +2111,8 @@ def serve(cfg: dict, chan: LineChannel,
                 _trace_ev(rid, "flush")
                 _trace_ship(rid)
                 st.drop_request(rid)     # pulls/exports/buffers + cancel
+                if any(str(m.get("id")) == rid for m in stalled):
+                    stall_given_up = True
             elif t == "mig_begin":
                 # a migrated-in sequence is arriving (decode role): claim
                 # capacity BEFORE the first payload chunk
@@ -2527,6 +2533,7 @@ def serve(cfg: dict, chan: LineChannel,
                 if inj.countdown("replica_stall_stream_after_chunks"):
                     stall_until = time.monotonic() + float(
                         inj.value("replica_stall_stream_s") or 1.0)
+                    stall_given_up = False
                 _trace_ev(rid, "chunk", n=len(toks), off=off)
                 _stream({"t": "chunk", "id": rid, "a": a, "off": off,
                          "toks": toks})
@@ -2633,12 +2640,15 @@ def serve(cfg: dict, chan: LineChannel,
             _cleanup_shm(ring, readers)
             return 0
 
-        if stalled and time.monotonic() >= stall_until:
-            # stall expired: deliver the queued stream late — the router
-            # has usually reassigned by now and must drop these as stale
+        if stall_until is not None and stall_given_up \
+                and time.monotonic() >= stall_until:
+            # stall over: deliver the queued stream late — the router has
+            # replayed at least the request it flushed, and must drop that
+            # request's messages as stale
             for m in stalled:
                 _send(m)
             stalled.clear()
+            stall_until = None
 
         now = time.monotonic()
         if now - last_hb >= hb_interval:

@@ -12,7 +12,7 @@ returns a shared null object, nothing buffers, no server binds. Enable via
   (the training engine calls :func:`configure` from its config section),
 - engine_v2: ``RaggedInferenceConfig(telemetry=True)``,
 - env: ``DS_TPU_TELEMETRY=1`` (+ ``DS_TPU_TELEMETRY_PORT`` for the HTTP
-  endpoint) — the bench/driver path, no config edit needed.
+  endpoint) — a driver's path, no config edit needed.
 
 ``configure()`` mutates the default instance IN PLACE so references cached
 by already-constructed engines stay live.
@@ -38,8 +38,8 @@ from .timeseries import StoreSampler, TimeSeriesStore
 from .alerts import AlertManager, AlertRule, default_fleet_rules
 
 #: metric-name prefix of every router-side series (serving/router.py) —
-#: the registry-zeroing scopes the bench and the router harness use to
-#: coexist in one process registry (Telemetry.reset_metrics)
+#: the registry-zeroing scopes an engine harness and a router harness use
+#: to coexist in one process registry (Telemetry.reset_metrics)
 SERVING_ROUTER_PREFIX = "serving_router_"
 #: families the ROUTER harness owns per measured scenario: its own
 #: counters plus the per-tenant attribution it emits in the PR-7 format
@@ -239,8 +239,8 @@ class Telemetry:
     def reset_metrics(self, prefix: str | tuple[str, ...] | None = None,
                       keep: tuple[str, ...] = ()) -> None:
         """THE registry-zeroing entry point for per-run measurement scopes
-        (bench phases, router bench scenarios). Components co-resident in
-        one process zero only their own families: the bench-driven engine
+        (a measured phase, a router scenario). Components co-resident in
+        one process zero only their own families: a harness-driven engine
         resets with ``keep=(SERVING_ROUTER_PREFIX,)`` and the router
         harness resets with ``prefix=ROUTER_RUN_PREFIXES`` — an inline
         ``registry.reset()`` at either site would clobber the other
@@ -344,7 +344,7 @@ class Telemetry:
             # merge label series under the family for the summary view;
             # series created with DIFFERENT buckets (the registry allows
             # it per label set) cannot fold — skip them rather than
-            # mis-bin or crash the bench artifact assembly
+            # mis-bin or crash the summary
             for s in fam["series"]:
                 if tuple(s["bounds"]) != h.bounds:
                     continue

@@ -15,11 +15,13 @@ from __future__ import annotations
 
 from ..utils.logging import logger
 
-#: dense bf16 peak TFLOPs per chip, by device_kind substring (public specs)
+#: dense bf16 peak TFLOPs per chip, by device_kind substring (public
+#: specs); the first fragment found wins, so a v5e (``"TPU v5 lite"``) is
+#: told from a v5p, whose device_kind is the bare ``"TPU v5"``
 PEAK_TFLOPS_BY_KIND = (
     ("v6e", 918.0), ("v6", 918.0),
-    ("v5p", 459.0),
     ("v5e", 197.0), ("v5 lite", 197.0), ("v5litepod", 197.0),
+    ("v5", 459.0),
     ("v4", 275.0),
     ("v3", 123.0),
     ("v2", 45.0),

@@ -10,7 +10,7 @@ path derived from where this package is checked out, never from
 ``tempfile``, a pid or the clock.
 
 Entry points call :func:`enable_compile_cache` before their first
-compile: ``chip_smoke.py``'s children, ``bench.py`` and the replica
+compile: ``chip_smoke.py``'s children, the runners of ``benchmark/`` and the replica
 worker's ``main``. The launcher hands its children the same directory
 through their environment (:func:`default_cache_dir`).
 """

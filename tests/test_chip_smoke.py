@@ -1,8 +1,8 @@
 """``chip_smoke.py`` among the tests: it must refuse a host without a
 chip in seconds (fast tier), its whole flow must run on the CPU at tiny
 sizes with the same parent and children (slow tier — it compiles two
-engines), and the compile-cache helper it shares with bench.py and the
-replica worker must place the cache where the contract says.
+engines), and the compile-cache helper it shares with the benchmark and
+the replica worker must place the cache where the contract says.
 """
 from __future__ import annotations
 
@@ -104,31 +104,3 @@ def test_compile_cache_dir_is_fixed_or_placed_from_outside(tmp_path,
     before = jax.config.jax_compilation_cache_dir
     assert enable_compile_cache() == placed
     assert jax.config.jax_compilation_cache_dir == before
-
-
-# ---- bench.py: no peak for a device it does not know ----------------------
-
-@pytest.mark.parametrize("kind,peak", [("TPU v5 lite", 197.0),
-                                       ("TPU v5", 459.0), ("cpu", None),
-                                       ("Some Future Chip", None)])
-def test_bench_refuses_a_utilization_on_an_unknown_device(kind, peak,
-                                                          monkeypatch):
-    """``bench.py`` computes a utilization only against a peak it has a
-    source for: an unknown ``device_kind`` (the CPU included — there is no
-    ``cpu: 1.0`` entry any more) raises where the peak is asked for."""
-    import importlib.util
-    import types
-
-    import jax
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_under_test", os.path.join(ROOT, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    monkeypatch.setattr(
-        jax, "devices", lambda: [types.SimpleNamespace(device_kind=kind)])
-    if peak is None:
-        with pytest.raises(RuntimeError, match="no bf16 peak known"):
-            bench._peak_tflops()
-    else:
-        assert bench._peak_tflops() == peak

@@ -482,6 +482,11 @@ def test_router_restart_mid_drain_resumes_retire(tmp_path):
         # the journaled drain-phase action was adopted, not restarted
         assert (b._elastic.action or {}).get("kind") == "retire"
         assert b._elastic.action["slot"] == slot
+        # freeze the advisor as router a's was: the replayed backlog on one
+        # replica is an organic UP hint, and on a slow host the retire
+        # settles inside start() and the hint revives the parked slot
+        # before the state is read
+        b._scale.update = lambda *a, **k: None
         b.start(min_ready=1)
         assert poll_until(
             b, lambda: b._elastic.actions_total.get("retire:ok"),

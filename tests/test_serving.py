@@ -12,6 +12,7 @@ to the no-fault run" is asserted against closed-form truth, not a second
 (possibly equally wrong) run.
 """
 import collections
+import contextlib
 import os
 import time
 
@@ -324,9 +325,12 @@ CHAOS_CASES = {
     "hang_during_decode": (
         {"replica_hang_after_chunks": 3, "replica_hang_s": 30.0},
         {"hb_timeout_s": 0.4}),
+    # the stall lasts until the router has timed a stalled request out
+    # (the replica sees its flush), so the late delivery is stale by
+    # construction; the floor only keeps the stall from ending at once
     "stalled_stream_stale_delivery": (
         {"replica_stall_stream_after_chunks": 2,
-         "replica_stall_stream_s": 1.0},
+         "replica_stall_stream_s": 0.1},
         {"request_timeout_s": 0.35}),
     "dropped_completion_reply": (
         {"replica_drop_done": 1}, {"request_timeout_s": 0.5}),
@@ -347,10 +351,16 @@ def test_chaos_matrix_exactly_once_bit_identical(case):
     router = make_router(per_slot={"0": {"faults": faults}},
                         replica={"tokens_per_step": 2},
                         log_tag=f"chaos_{case}", **over)
-    with router:
+    with contextlib.closing(router):
+        # BOTH replicas serving before the trace arrives: with one, the
+        # survivor alone could finish the trace while slot 0 still spawns,
+        # and the fault would never fire
+        router.start(min_ready=2)
         tids = submit_trace(router, trace)
         res = router.run(deadline_s=60)
         assert_exactly_once(router, res)
+        assert any(0 in res[t]["placed"] for t in tids), \
+            "slot 0 got no request: its fault cannot have fired"
         n_done = 0
         for rec, tid in zip(trace, tids):
             if res[tid]["status"] == "done":

@@ -590,11 +590,11 @@ def _tree_gather_ref(pool, q, ks, vs, tables, lens, sst, pos, mask, G,
 ])
 def test_tree_kernel_parity_matrix(kv_dtype, G, tol):
     """Interpret-mode CPU parity, Pallas tree-verify vs the gather
-    formulation: storage dtype x GQA x grouped pages x sliding window on
-    a branchy SpecTree with an empty slot riding along. Reduced-precision
-    pools compare against the round-tripped values so the tolerance
-    isolates the kernel's fused q/p casts (the fp8 bound matches the
-    long-context p-prescale test in test_paged_attention_groups.py).
+    formulation: storage dtype x GQA x sliding window on a branchy
+    SpecTree with an empty slot riding along. Reduced-precision pools
+    compare against the round-tripped values so the tolerance isolates the
+    kernel's fused q/p casts (the fp8 bound matches the long-context
+    p-prescale test in test_paged_work_list.py).
     Ring mode is absent by design: the engine refuses spec decode in
     rolling-ring mode, so tree x ring is unreachable."""
     import jax.numpy as jnp
@@ -610,14 +610,12 @@ def test_tree_kernel_parity_matrix(kv_dtype, G, tol):
     for window in (None, 7):
         want = _tree_gather_ref(ref_pool, q, ks, vs, tables, lens, sst,
                                 pos, mask, G, window=window)
-        for pg in (1, 2):
-            got = paged_ragged_attention(
-                q, pool, ks, vs, tables, lens, qst, sst, block_size=16,
-                layer_index=jnp.int32(1), window=window, page_group=pg,
-                tree_positions=pos, tree_mask=mask, interpret=True)
-            err = np.abs(np.asarray(got, np.float32)[live]
-                         - want[live]).max()
-            assert err < tol, (kv_dtype, G, window, pg, err)
+        got = paged_ragged_attention(
+            q, pool, ks, vs, tables, lens, qst, sst, block_size=16,
+            layer_index=jnp.int32(1), window=window,
+            tree_positions=pos, tree_mask=mask, interpret=True)
+        err = np.abs(np.asarray(got, np.float32)[live] - want[live]).max()
+        assert err < tol, (kv_dtype, G, window, err)
 
 
 def test_tree_kernel_parity_stage_spans_pages():

@@ -193,6 +193,20 @@ def test_peak_flops_probe_unknown_backend_is_none():
     assert T.device_peak_flops() is None
 
 
+@pytest.mark.parametrize("kind,peak", [("TPU v5 lite", 197e12),
+                                       ("TPU v5", 459e12), ("cpu", None),
+                                       ("Some Future Chip", None)])
+def test_peak_flops_by_device_kind(kind, peak, monkeypatch):
+    """A utilization is computed only against a peak with a source: a v5e
+    reports ``"TPU v5 lite"``, a v5p the bare ``"TPU v5"``; the CPU and a
+    kind the table does not know have none."""
+    import jax
+
+    monkeypatch.setattr(
+        jax, "devices", lambda: [types.SimpleNamespace(device_kind=kind)])
+    assert T.device_peak_flops() == peak
+
+
 # --------------------------------------------------------------------------
 # Prometheus exposition
 # --------------------------------------------------------------------------

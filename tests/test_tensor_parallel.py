@@ -247,7 +247,7 @@ def test_engine_v2_identical_tokens_tp_overlap_on_off(quant):
         return out, dict(eng.stats)
 
     # True forces the ring on EVERY divisible program incl. decode-sized
-    # M (the auto mode's tp_overlap_min_rows gate keeps decode blocking
+    # M (the auto mode's TP_OVERLAP_MIN_ROWS gate keeps decode blocking
     # by default pending real-slice measurement)
     on, stats_on = run(True)
     off, stats_off = run(False)
