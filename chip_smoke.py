@@ -87,9 +87,10 @@ REHEARSAL = {
 }
 #: serve_parity checks every PARITY_STRIDE-th step of each served stream
 PARITY_STRIDE = 2
-#: four chips: global batch 4 (two per data-parallel rank). The one-device
-#: side runs XLA attention too (the flash gate is per process), whose full
-#: gpt2-350m step at micro-batch 8 needs 19 GiB of the chip's 15.75
+#: four chips: global batch 4 (two per data-parallel rank), sized when
+#: both sides ran XLA attention (a full gpt2-350m step at micro-batch 8
+#: needed 19 GiB of the chip's 15.75). Since PR 29 both sides run the flash
+#: kernel: the one-device mesh directly, the sharded one per shard
 SHARDED_GLOBAL_BATCH = 4
 
 

@@ -34,7 +34,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import attention_formulation, dot_product_attention
+from ..ops.attention import (AttentionSharding, attention_formulation,
+                             dot_product_attention)
 
 # Logical activation axis names (canonical home: parallel/axes.py);
 # re-exported here for back-compat.
@@ -46,6 +47,7 @@ from ..parallel.axes import (  # noqa: E402
     MLP,
     SEQ,
     constrain,
+    mesh_specs,
 )
 from ..parallel.tensor import current_tp_overlap, ring_row_matmul
 from ..utils.annotations import device_scope
@@ -310,13 +312,29 @@ def _attn_impl(cfg: "ModelConfig") -> str:
                      or cfg.sliding_window) else cfg.attn_impl
 
 
-def training_attention_formulation(cfg: "ModelConfig", batch: int,
-                                   seq: int) -> tuple[str, str]:
+def attention_axis_names(cfg: "ModelConfig") -> tuple[tuple, tuple]:
+    """The logical axes :class:`Attention` states for q and for k/v at the
+    attention call (GQA leaves the K/V heads whole)."""
+    kv_heads = HEADS if cfg.kv_heads == cfg.num_heads else None
+    return (BATCH, None, HEADS, None), (BATCH, None, kv_heads, None)
+
+
+def attention_sharding(cfg: "ModelConfig") -> AttentionSharding | None:
+    """Those names on the mesh the engine traces the model under, for the
+    dispatcher's per-shard kernel path; None where no engine scoped one."""
+    found = mesh_specs(*attention_axis_names(cfg))
+    return None if found is None else AttentionSharding(*found)
+
+
+def training_attention_formulation(cfg: "ModelConfig", batch: int, seq: int,
+                                   manual_axes=None) -> tuple[str, str]:
     """``("pallas", "")`` or ``("xla", why_not)``: what :class:`Attention`
     runs for a full-sequence ``[batch, seq]`` step (no KV cache, no
-    padding mask) on the devices of this process — the training engine
-    logs it once at build time so ``attn_impl="auto"`` never falls through
-    to XLA attention unannounced."""
+    padding mask) under the rules and mesh in scope HERE — the training
+    engine asks once at build time, inside what it traces the loss under,
+    so ``attn_impl="auto"`` never falls through to XLA attention
+    unannounced. ``manual_axes``: the mesh axes the step's own
+    ``shard_map`` will have made manual (``batch`` is then a shard's)."""
     q = jax.ShapeDtypeStruct((batch, seq, cfg.num_heads, cfg.head_dim),
                              cfg.dtype)
     kv = jax.ShapeDtypeStruct((batch, seq, cfg.kv_heads, cfg.head_dim),
@@ -325,7 +343,8 @@ def training_attention_formulation(cfg: "ModelConfig", batch: int,
     return attention_formulation(
         q, kv, kv, causal=cfg.causal, window=cfg.sliding_window,
         bias=True if cfg.position_embedding == "alibi" else None,
-        impl=cfg.attn_impl)
+        impl=cfg.attn_impl, sharding=attention_sharding(cfg),
+        manual_axes=manual_axes)
 
 
 class Attention(nn.Module):
@@ -390,9 +409,10 @@ class Attention(nn.Module):
             new_cache = (ck, cv, cache_len + S)
 
         # Ulysses resharding: seq→full, heads→sharded over ('tensor','seq')
-        q = constrain(q, BATCH, None, HEADS, None)
-        k = constrain(k, BATCH, None, HEADS if KV == H else None, None)
-        v = constrain(v, BATCH, None, HEADS if KV == H else None, None)
+        q_names, kv_names = attention_axis_names(cfg)
+        q = constrain(q, *q_names)
+        k = constrain(k, *kv_names)
+        v = constrain(v, *kv_names)
 
         alibi_bias = None
         if cfg.position_embedding == "alibi":
@@ -413,6 +433,7 @@ class Attention(nn.Module):
             bias=alibi_bias,
             window=cfg.sliding_window,
             impl=_attn_impl(cfg),
+            sharding=attention_sharding(cfg),
         )
         # back to seq-sharded, heads full
         out = constrain(out, BATCH, SEQ, None, None)

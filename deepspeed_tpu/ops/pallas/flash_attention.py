@@ -131,25 +131,19 @@ def _interpret() -> bool:
 
 
 def flash_attention_unusable_reason(q, k, v, *, causal: bool,
-                                    positions=None, mask=None,
-                                    allow_multi_device: bool = False) -> str:
-    """Why the dispatcher must NOT claim the kernel for these inputs
-    (arrays or ``jax.ShapeDtypeStruct``s — only shapes and dtype are
-    read); ``""`` when it may. Full-sequence self-attention only (the
-    decode/cached path has tiny q and is XLA's job).
+                                    positions=None, mask=None) -> str:
+    """Why the kernel cannot run these inputs (arrays or
+    ``jax.ShapeDtypeStruct``s — only shapes and dtype are read); ``""``
+    when it can. Full-sequence self-attention only (the decode/cached path
+    has tiny q and is XLA's job).
 
-    By default only claims the kernel when a single device is in play:
-    ``pallas_call`` has no GSPMD partitioning rule, so inside a pjit-sharded
-    model on a multi-device mesh it would force replication of q/k/v.
-    Multi-device callers run it per-shard (inside shard_map, e.g.
-    parallel/sequence.py paths) and opt in with ``allow_multi_device=True``
-    / explicit ``impl='pallas'``.
+    The shapes are the ones ONE kernel call sees: ``pallas_call`` has no
+    GSPMD partitioning rule, so on a mesh the dispatcher
+    (``ops/attention.py``) asks with the PER-SHARD shapes and runs the
+    kernel inside ``shard_map``; whether a call is per shard is its
+    question, not this gate's.
     """
     del causal, v
-    if not allow_multi_device and jax.device_count() > 1:
-        return (f"{jax.device_count()} devices in this process and "
-                f"pallas_call has no GSPMD partitioning rule (only "
-                f"shard_map callers opt in)")
     if positions is not None or mask is not None:
         return "cached/masked attention (positions or mask given)"
     B, Sq, H, D = q.shape

@@ -66,10 +66,10 @@ def ulysses_attention(q, k, v, mesh, *, axis: str = "seq",
     if attn_fn is None:
         from ..ops.attention import dot_product_attention
 
-        # per-shard inside shard_map → safe (and intended) to use the
-        # Pallas flash kernel even on a multi-device mesh
-        attn_fn = functools.partial(dot_product_attention, causal=causal,
-                                    allow_multi_device=True)
+        # traced inside the shard_map below, where every mesh axis is
+        # manual: the dispatcher sees per-shard shapes and may claim the
+        # Pallas flash kernel for them
+        attn_fn = functools.partial(dot_product_attention, causal=causal)
     n = mesh.shape[axis]
     if q.shape[2] % n or k.shape[2] % n:
         raise ValueError(

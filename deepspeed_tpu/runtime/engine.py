@@ -24,6 +24,7 @@ API parity:
 from __future__ import annotations
 
 import dataclasses
+from contextlib import contextmanager, nullcontext
 from functools import partial
 from typing import Any, Callable, NamedTuple
 
@@ -323,18 +324,32 @@ class DeepSpeedEngine:
     def _announce_attention(self) -> tuple[str, str] | None:
         """Log ONCE which attention formulation the train step will trace
         and, when it is not the flash kernel, why — ``attn_impl="auto"``
-        otherwise falls through to XLA attention silently (e.g. on every
-        multi-device mesh: pallas_call has no GSPMD rule). Returns
-        ``(formulation, reason)``; None for models that are not the
-        zoo's TransformerLM (custom loss_fn / foreign modules)."""
+        otherwise falls through to XLA attention silently. Asked inside
+        what the step traces the model under (:meth:`_model_scope`; the
+        ZeRO++ and 1-bit steps make the DP axes manual and hand the model
+        a shard's rows). Returns ``(formulation, reason)``; None for
+        models that are not the zoo's TransformerLM (custom loss_fn /
+        foreign modules)."""
         mcfg = getattr(self.model, "config", None)
         if self._custom_loss_fn or not isinstance(mcfg, ModelConfig):
             return None
-        batch, seq = self._sample_batch["input_ids"].shape[:2]
-        chosen, why_not = training_attention_formulation(mcfg, batch, seq)
+        topo = self.topology
+        rows = self.config.train_micro_batch_size_per_gpu
+        manual = tuple(a for a in BATCH_AXES if topo.size(a) > 1) \
+            if self._use_zeropp_comm() or self._use_onebit_comm() else ()
+        if not manual:
+            rows *= topo.dp_world_size
+        seq = self._sample_batch["input_ids"].shape[1]
+        # the ZeRO-Infinity layer streamer traces its blocks under neither
+        # rules nor mesh (runtime/zero/infinity.py)
+        with nullcontext() if self._param_stream is not None \
+                else self._model_scope(manual):
+            chosen, why_not = training_attention_formulation(
+                mcfg, rows, seq, manual_axes=manual)
         logger.info(f"attention: attn_impl={mcfg.attn_impl!r} runs " + (
-            "the Pallas flash kernel" if chosen == "pallas"
-            else f"XLA attention — {why_not}"))
+            "the Pallas flash kernel" + (
+                " per shard" if topo.mesh.size > 1 else "")
+            if chosen == "pallas" else f"XLA attention — {why_not}"))
         return chosen, why_not
 
     # ------------------------------------------------------------------
@@ -629,6 +644,24 @@ class DeepSpeedEngine:
         )
 
     # ------------------------------------------------------------------
+    @contextmanager
+    def _model_scope(self, manual_axes: tuple[str, ...] = ()):
+        """Everything the model is traced under, in one place: the
+        activation rules (less those on axes the step's own ``shard_map``
+        has made manual — a constraint on a manual axis is illegal), the
+        mesh they resolve onto (the attention dispatcher maps its kernel
+        over it) and, on the GSPMD path, the TP ring-overlap scope."""
+        from ..parallel.axes import model_mesh_scope
+        from ..parallel.tensor import tp_overlap_scope
+
+        rules = self._safe_manual_rules(manual_axes) if manual_axes \
+            else self._rules
+        with nn.logical_axis_rules(rules), \
+                model_mesh_scope(self.topology.mesh), \
+                (tp_overlap_scope(self.topology.mesh)
+                 if self._tp_overlap and not manual_axes else nullcontext()):
+            yield
+
     def _loss_with_rules(self, params, batch, step=None):
         """``step`` present → training call: a per-step PRNG key rides into
         the batch under '_train_rng' so stochastic layers (bert dropout,
@@ -643,13 +676,7 @@ class DeepSpeedEngine:
             batch = dict(batch)
             batch["_train_rng"] = jax.random.fold_in(self._train_rng_base,
                                                      step)
-        from contextlib import nullcontext
-
-        from ..parallel.tensor import tp_overlap_scope
-
-        ctx = tp_overlap_scope(self.topology.mesh) if self._tp_overlap \
-            else nullcontext()
-        with nn.logical_axis_rules(self._rules), ctx:
+        with self._model_scope():
             loss = self._raw_loss_fn(params, batch)
         if fault_scale is not None:
             # fault-injection rail (resilience.FaultInjector.nan_scale):
@@ -907,7 +934,6 @@ class DeepSpeedEngine:
         qw = z.zero_quantized_weights
         dp_axes = tuple(a for a in BATCH_AXES if topo.size(a) > 1)
         data_axes = tuple(a for a in dp_axes if a != "fsdp")
-        safe_rules = self._safe_manual_rules(dp_axes)
         is_p = lambda x: isinstance(x, P)
 
         def fsdp_dim(spec):
@@ -929,7 +955,7 @@ class DeepSpeedEngine:
             mb = dict(mb)
             fault_scale = mb.pop("_fault_scale", None)
             mb["_train_rng"] = jax.random.fold_in(self._train_rng_base, step)
-            with nn.logical_axis_rules(safe_rules):
+            with self._model_scope(dp_axes):
                 loss = self._raw_loss_fn(p, mb)
             if fault_scale is not None:
                 loss = loss * jnp.mean(fault_scale)
@@ -1047,13 +1073,12 @@ class DeepSpeedEngine:
             logger.warning("gradient_clipping is ignored on the 1-bit "
                            "compressed path (error feedback and clipping "
                            "don't compose; the reference behaves the same)")
-        safe_rules = self._safe_manual_rules(dp_axes)
 
         def local_loss(p, mb, step):
             mb = dict(mb)
             fault_scale = mb.pop("_fault_scale", None)
             mb["_train_rng"] = jax.random.fold_in(self._train_rng_base, step)
-            with nn.logical_axis_rules(safe_rules):
+            with self._model_scope(dp_axes):
                 loss = self._raw_loss_fn(p, mb)
             if fault_scale is not None:
                 loss = loss * jnp.mean(fault_scale)

@@ -145,6 +145,77 @@ def _ragged_tp4(devs):
     return fn, args, True
 
 
+def _flash_train_mesh(devs):
+    """The attention of the benchmark's train step (``mistral7b-zero3-sft``:
+    micro-batch 2 a chip, sequence 2048, 32 heads over 8, head 128, bf16,
+    forward and backward) as the dispatcher places it on mesh ``{fsdp: 4}``
+    of the described chips: the flash kernel per shard inside a
+    ``shard_map``, specs from the model's own logical names under the
+    engine's rules. A Mosaic refusal shows here, before a four-chip call."""
+    import flax.linen as nn
+
+    from deepspeed_tpu.models import get_model_config
+    from deepspeed_tpu.models.transformer import (attention_sharding,
+                                                  default_activation_rules)
+    from deepspeed_tpu.ops.attention import (attention_formulation,
+                                             dot_product_attention)
+    from deepspeed_tpu.parallel.axes import model_mesh_scope
+    from deepspeed_tpu.parallel.topology import MeshTopology
+
+    topo = MeshTopology({"fsdp": 4}, devices=list(devs))
+    m = get_model_config("mistral-7b", sliding_window=None)
+    with nn.logical_axis_rules(default_activation_rules(topo)), \
+            model_mesh_scope(topo.mesh):
+        sharding = attention_sharding(m)
+    B, S = 2 * 4, 2048
+    q = _sds(NamedSharding(topo.mesh, sharding.q_spec),
+             (B, S, m.num_heads, m.head_dim), BF16)
+    kv = _sds(NamedSharding(topo.mesh, sharding.kv_spec),
+              (B, S, m.kv_heads, m.head_dim), BF16)
+    assert attention_formulation(q, kv, kv, sharding=sharding) \
+        == ("pallas", "")
+
+    def loss(q, k, v):
+        return dot_product_attention(
+            q, k, v, causal=True, sharding=sharding).astype(jnp.float32).sum()
+    return jax.value_and_grad(loss, argnums=(0, 1, 2)), (q, kv, kv), True
+
+
+def _flash_inside_manual_dp(devs):
+    """The same attention where the step's own ``shard_map`` has already
+    made the DP axis manual (ZeRO++, 1-bit Adam: ``axis_names={fsdp}``, the
+    other five axes of the engine's mesh automatic, all of size one).
+    Mosaic lowers a kernel only where EVERY mesh axis is manual, so the
+    dispatcher maps the rest itself; interpret mode never sees that rule."""
+    from deepspeed_tpu.models import get_model_config
+    from deepspeed_tpu.ops.attention import (AttentionSharding,
+                                             dot_product_attention)
+    from deepspeed_tpu.parallel.topology import MeshTopology
+
+    mesh = MeshTopology({"fsdp": 4}, devices=list(devs)).mesh
+    m = get_model_config("mistral-7b", sliding_window=None)
+    # the rules the engine keeps inside that region (the BATCH rule names
+    # the manual axis and is dropped): heads over ('tensor', 'seq')
+    heads = P(None, None, ("tensor", "seq"), None)
+    sharding = AttentionSharding(mesh, heads, P(None, None, None, None))
+    rows = P("fsdp", None, None, None)
+
+    def loss(q, k, v):
+        def shard(q, k, v):
+            return dot_product_attention(q, k, v, causal=True,
+                                         sharding=sharding)
+        out = jax.shard_map(shard, mesh=mesh, axis_names={"fsdp"},
+                            in_specs=(rows, rows, rows), out_specs=rows,
+                            check_vma=False)(q, k, v)
+        return out.astype(jnp.float32).sum()
+
+    q = _sds(NamedSharding(mesh, rows), (8, 2048, m.num_heads, m.head_dim),
+             BF16)
+    kv = _sds(NamedSharding(mesh, rows), (8, 2048, m.kv_heads, m.head_dim),
+              BF16)
+    return jax.value_and_grad(loss, argnums=(0, 1, 2)), (q, kv, kv), True
+
+
 def _grouped(backward):
     def build(devs):
         from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
@@ -247,6 +318,8 @@ CASES = {
     "quant_fp8_m512": _quant("fp8", 512),
     "quant_int8_m512_vocab50257": _quant(8, 512, N=50257),
     "ragged_decode_shard_map_tp4": _ragged_tp4,
+    "flash_fwd_bwd_train_mesh_fsdp4": _flash_train_mesh,
+    "flash_fwd_bwd_inside_manual_fsdp4": _flash_inside_manual_dp,
 }
 
 
@@ -271,6 +344,11 @@ def test_kernel_compiles_for_v5e(name, topo):
     ("ragged_tree_olmoe_s48_p32", "paged_attn_tree"),
     ("grouped_gemm_olmoe_decode_up", "grouped_matmul_fwd"),
     ("grouped_gemm_olmoe_prefill_down", "grouped_matmul_fwd"),
+    # sequence 2048 > one KV block: the split backward pair (what
+    # ``train_flash_attn_mfu`` matches in the benchmark's train cell)
+    ("flash_fwd_bwd_train_mesh_fsdp4",
+     {"flash_attention_fwd", "flash_attention_bwd_dq",
+      "flash_attention_bwd_dkv"}),
 ])
 def test_paged_kernel_instruction_is_named_by_form(name, kernel, topo):
     """``name=`` on the ``pallas_call`` is what the compiled custom call's
@@ -283,7 +361,8 @@ def test_paged_kernel_instruction_is_named_by_form(name, kernel, topo):
     text = jax.jit(fn).lower(*args).compile().as_text()
     calls = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
                        r'"tpu_custom_call"', text)
-    assert calls and {re.sub(r"[.\d]+$", "", c) for c in calls} == {kernel}
+    assert calls and {re.sub(r"[.\d]+$", "", c) for c in calls} \
+        == (kernel if isinstance(kernel, set) else {kernel})
 
 
 def test_paged_kernel_scalar_prefetch_footprint():
