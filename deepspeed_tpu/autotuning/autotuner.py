@@ -25,6 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import Config
+from ..runtime.activation_checkpointing import (device_memory_limit,
+                                                step_memory)
 from ..utils.logging import logger
 from .tuner import TUNERS, ModelBasedTuner
 
@@ -67,8 +69,7 @@ class Autotuner:
         self.stages = stages
         dev = jax.devices()[0]
         if hbm_budget_bytes is None:
-            stats = getattr(dev, "memory_stats", lambda: None)()
-            hbm_budget_bytes = (stats or {}).get("bytes_limit", 16 << 30)
+            hbm_budget_bytes = device_memory_limit() or 16 << 30
         self.hbm_budget = int(hbm_budget_bytes)
         kind = getattr(dev, "device_kind", "cpu")
         self.peak_flops, self.hbm_bw = CHIP_SPECS.get(kind, CHIP_SPECS["cpu"])
@@ -126,9 +127,7 @@ class Autotuner:
             batch = engine._shard_batch(engine._reshape_for_gas(batch),
                                         with_gas_dim=True)
             compiled = engine._train_step.lower(engine.state, batch).compile()
-            mem = compiled.memory_analysis()
-            peak = int(mem.argument_size_in_bytes + mem.temp_size_in_bytes
-                       + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+            peak = step_memory(compiled)["step_bytes"]
             costs = compiled.cost_analysis()
             if isinstance(costs, (list, tuple)):
                 costs = costs[0] if costs else {}

@@ -163,8 +163,13 @@ class ActivationCheckpointingConfig:
     partition_activations: bool = False  # maps to activation sharding over 'seq'
     cpu_checkpointing: bool = False      # maps to the 'offload' remat policy
     number_checkpoints: int | None = None
-    # TPU extension: jax.checkpoint policy name (ops/remat.py registry)
-    policy: str = "none"  # none|full|dots_saveable|nothing_saveable|dots_with_no_batch_dims_saveable|offload
+    # TPU extension: jax.checkpoint policy name (ops/remat.py:POLICIES).
+    # none | full = nothing_saveable | dots_saveable |
+    # dots_with_no_batch_dims_saveable | everything_saveable |
+    # save_matmul_products | save_attn_products | offload — each PINS what a
+    # rematted block keeps; "auto" has the engine judge it from the compiled
+    # step's memory (ModelConfig.remat_policy's default, engine.remat_plan)
+    policy: str = "none"
 
     _IGNORED_KEYS = ("contiguous_memory_optimization",
                      "synchronize_checkpoint_boundary", "profile")

@@ -33,6 +33,7 @@ from typing import Any, Callable
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.attention import (AttentionSharding, attention_formulation,
                              dot_product_attention)
@@ -146,7 +147,14 @@ class ModelConfig:
     moe: MoEConfig | None = None
     dtype: Any = jnp.bfloat16                # compute dtype
     remat: bool = False                      # rematerialize each block
-    remat_policy: str = "nothing_saveable"   # runtime/activation_checkpointing.py
+    remat_policy: str = "auto"               # what a rematted block keeps
+                                             # (registry: ops/remat.py).
+                                             # "auto": the matmul products
+                                             # tagged below, stepped down by
+                                             # the engine where the compiled
+                                             # step's memory says so
+                                             # (engine.remat_plan); any
+                                             # other name pins that policy
     attn_impl: str = "auto"                  # auto | pallas | xla
 
     @property
@@ -413,6 +421,11 @@ class Attention(nn.Module):
         q = constrain(q, *q_names)
         k = constrain(k, *kv_names)
         v = constrain(v, *kv_names)
+        # what a rematted block may keep (ops/remat.py ATTN_PRODUCTS): a tag
+        # is metadata, read by a names policy alone
+        q = checkpoint_name(q, "attn_q")
+        k = checkpoint_name(k, "attn_k")
+        v = checkpoint_name(v, "attn_v")
 
         alibi_bias = None
         if cfg.position_embedding == "alibi":
@@ -452,7 +465,7 @@ class Attention(nn.Module):
             jnp.einsum("bshd,hde->bse", out, wo.astype(cfg.dtype))
         if bo is not None:
             out = out + bo.astype(cfg.dtype)
-        out = constrain(out, BATCH, SEQ, EMBED)
+        out = checkpoint_name(constrain(out, BATCH, SEQ, EMBED), "attn_proj")
         if new_cache is not None:
             return out, new_cache
         return out
@@ -482,7 +495,10 @@ class DenseFFN(nn.Module):
                             (cfg.hidden_size, F), jnp.float32)
             wd = self.param("w_down", nn.with_partitioning(_dense_init(), ("mlp", "embed")),
                             (F, cfg.hidden_size), jnp.float32)
-            h = jax.nn.silu(x @ wg.astype(cfg.dtype)) * (x @ wu.astype(cfg.dtype))
+            # ops/remat.py FFN_PRODUCTS: kept by the names policies
+            h = jax.nn.silu(
+                checkpoint_name(x @ wg.astype(cfg.dtype), "ffn_gate")) \
+                * checkpoint_name(x @ wu.astype(cfg.dtype), "ffn_up")
         else:
             wu = self.param("w_up", nn.with_partitioning(_dense_init(), ("embed", "mlp")),
                             (cfg.hidden_size, F), jnp.float32)
@@ -493,7 +509,8 @@ class DenseFFN(nn.Module):
             bd = self.param("b_down", nn.with_partitioning(nn.initializers.zeros, ("embed",)),
                             (cfg.hidden_size,), jnp.float32)
             act = _ACTS[cfg.activation]
-            h = act(x @ wu.astype(cfg.dtype) + bu.astype(cfg.dtype))
+            h = act(checkpoint_name(
+                x @ wu.astype(cfg.dtype) + bu.astype(cfg.dtype), "ffn_up"))
         h = constrain(h, BATCH, SEQ, MLP)
         # row-parallel down-proj via ring matmul⊗reduce-scatter when a
         # tp_overlap scope is active (see Attention); falls back to the
@@ -703,7 +720,8 @@ class TransformerLM(nn.Module):
         if cfg.remat:
             from ..ops.remat import remat_module
 
-            # remat=True always checkpoints; 'none' would contradict it
+            # remat=True always checkpoints; 'none' would contradict it.
+            # "auto" outside an engine is the ladder's first rung
             policy = cfg.remat_policy if cfg.remat_policy != "none" else "full"
             block_cls = remat_module(Block, policy=policy, static_argnums=(4,))
 

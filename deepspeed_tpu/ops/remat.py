@@ -13,6 +13,16 @@ from ..utils.logging import logger
 
 _cp = jax.checkpoint_policies
 
+#: what models/transformer.py tags with ``checkpoint_name`` because it is
+#: dear to make again and cheap to hold: a matmul's product, one hidden- or
+#: FFN-wide row a token. Attention's q, k, v are tagged as they enter
+#: ``dot_product_attention`` (after bias, q/k norm and rope) with the output
+#: projection's result; the dense FFN's gate and up products (the up
+#: product alone in a two-matrix FFN). The flash kernel's own ``(out, lse)``
+#: and a routed expert's products carry no tag: they are made again.
+ATTN_PRODUCTS = ("attn_q", "attn_k", "attn_v", "attn_proj")
+FFN_PRODUCTS = ("ffn_gate", "ffn_up")
+
 #: name → jax.checkpoint policy ("full" remat saves nothing; "none" disables)
 POLICIES: dict[str, Any] = {
     "none": None,
@@ -23,7 +33,21 @@ POLICIES: dict[str, Any] = {
     "dots_with_no_batch_dims_saveable": _cp.dots_with_no_batch_dims_saveable,
     "checkpoint_dots_with_no_batch_dims": _cp.dots_with_no_batch_dims_saveable,
     "everything_saveable": _cp.everything_saveable,
+    # names policies, not dots_saveable: that one also keeps the down
+    # projection's output (the next block's saved input once more), the
+    # router's float32 einsum and an MoE block's capacity einsums
+    "save_matmul_products": _cp.save_only_these_names(*ATTN_PRODUCTS,
+                                                      *FFN_PRODUCTS),
+    "save_attn_products": _cp.save_only_these_names(*ATTN_PRODUCTS),
 }
+
+#: ``remat_policy="auto"`` (the default): the rungs a rematted block's
+#: saved set steps down, dearest first. The training engine judges each
+#: against the compiled step's memory (runtime/engine.py ``remat_plan``); a
+#: model traced outside an engine has no step to judge and takes the first.
+AUTO = "auto"
+REMAT_LADDER = ("save_matmul_products", "save_attn_products",
+                "nothing_saveable")
 
 
 def make_policy(name: str):
@@ -33,12 +57,14 @@ def make_policy(name: str):
     (checkpointing.py:472): matmul outputs are kept on device, everything
     else saved is offloaded to pinned host memory instead of recomputed.
     """
+    if name == AUTO:
+        name = REMAT_LADDER[0]
     if name in POLICIES:
         return POLICIES[name]
     if name in ("cpu", "offload", "offload_dots"):
         return _offload_policy()
     raise ValueError(f"unknown activation checkpointing policy '{name}'; "
-                     f"one of {sorted(POLICIES)} or 'offload'")
+                     f"one of {sorted(POLICIES)}, 'auto' or 'offload'")
 
 
 @functools.cache

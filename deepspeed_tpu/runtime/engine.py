@@ -39,8 +39,9 @@ from ..models.loss import lm_loss_fn
 from ..models.transformer import (ModelConfig, default_activation_rules,
                                   training_attention_formulation)
 from ..ops.optimizers import OptState, Optimizer, build_optimizer
+from ..ops.remat import AUTO as REMAT_AUTO, REMAT_LADDER
 from ..parallel.topology import BATCH_AXES, MeshTopology
-from ..profiling.trace import register_program
+from ..profiling.trace import _abstract, register_program
 from ..utils.annotations import device_scope
 from ..utils.logging import log_dist, logger
 from ..utils.timer import (
@@ -54,6 +55,7 @@ from ..utils.timer import (
     SynchronizedWallClockTimer,
     ThroughputTimer,
 )
+from . import activation_checkpointing as _ac_mod
 from . import fp16 as fp16_mod
 from .fp16 import ScalerState
 from .lr_schedules import Schedule, build_scheduler, constant_lr
@@ -131,8 +133,6 @@ class DeepSpeedEngine:
         if ac.partition_activations and self.topology.size("seq") <= 1:
             logger.warning("partition_activations=True but the mesh has no "
                            "'seq' axis — activations stay unpartitioned")
-        from . import activation_checkpointing as _ac_mod
-
         _ac_mod.configure(ac)
 
         self._custom_loss_fn = loss_fn is not None
@@ -305,7 +305,7 @@ class DeepSpeedEngine:
 
         # ---- state bring-up (reference _configure_distributed_model :1137)
         self._init_state(params, sample_batch, rng)
-        self._build_programs()
+        self.remat_plan = self._build_judged_programs()
         self.attention_formulation = self._announce_attention()
 
         # imperative-API grad buffer (forward/backward/step triplet)
@@ -320,6 +320,115 @@ class DeepSpeedEngine:
             f"micro_bs={config.train_micro_batch_size_per_gpu} "
             f"gas={config.gradient_accumulation_steps} "
             f"global_bs={config.train_batch_size} mesh={self.topology.axis_sizes}")
+
+    def _build_judged_programs(self) -> dict | None:
+        """Build the step programs and decide what a rematted block SAVES
+        for its backward pass. ``remat=True`` with ``remat_policy="auto"``
+        (the default) walks ``ops/remat.py:REMAT_LADDER`` from the top: the
+        step is built with a rung, lowered and compiled from abstract
+        arguments — the executable the first ``train_batch`` then finds in
+        jit's cache, so judging adds no lowering — and kept if its
+        arguments, temporaries and code fit the device's limit less
+        ``activation_checkpointing.STEP_HEADROOM_BYTES``; otherwise one
+        rung down. Returns the plan (``engine.remat_plan``): the policy
+        chosen and why, and each rung tried with the compiled step's bytes
+        and the limit it was held to. None where nothing is rematted (or
+        the model is not the zoo's); a pinned policy is never judged."""
+        mcfg = getattr(self.model, "config", None)
+        if self._custom_loss_fn or not isinstance(mcfg, ModelConfig) \
+                or not mcfg.remat:
+            self._build_programs()
+            return None
+        plan = {"chosen": mcfg.remat_policy, "rung": None, "why": "",
+                "limit_bytes": None, "tried": []}
+        # the rungs to walk; the last of them is taken without a judgement
+        limit = None
+        if mcfg.remat_policy != REMAT_AUTO:
+            ladder = (mcfg.remat_policy,)
+            unjudged = "pinned by remat_policy: not judged"
+        elif self._param_stream is not None or self._offload_opt is not None:
+            # paths that exist because memory is short, with no one compiled
+            # train step to judge: what remat=True kept before the ladder
+            ladder = REMAT_LADDER[-1:]
+            unjudged = "host-offload path (no compiled train step): not judged"
+        else:
+            limit = plan["limit_bytes"] = _ac_mod.device_memory_limit()
+            ladder = REMAT_LADDER if limit is not None else REMAT_LADDER[:1]
+            unjudged = ("last rung (what remat=True kept before the ladder): "
+                        "not judged" if limit is not None else
+                        "the backend reports no memory limit: first rung, "
+                        "not judged")
+        headroom = _ac_mod.STEP_HEADROOM_BYTES
+        for policy in ladder:
+            self._pin_remat(policy)
+            self._build_programs()
+            if policy == ladder[-1]:
+                plan.update(chosen=policy, why=unjudged)
+                break
+            tried = {"policy": policy, "limit_bytes": limit,
+                     "headroom_bytes": headroom}
+            plan["tried"].append(tried)
+            try:
+                compiled = self._train_step.lower(
+                    *self._abstract_step_args()).compile()
+            except jax.errors.JaxRuntimeError as e:
+                if "RESOURCE_EXHAUSTED" not in str(e):
+                    raise
+                # XLA refuses to compile a step that cannot fit
+                tried.update(fits=False, why="the compiler ran out of "
+                             "memory: " + str(e).splitlines()[0][:200])
+                continue
+            tried.update(_ac_mod.step_memory(compiled))
+            tried["fits"] = tried["step_bytes"] <= limit - headroom
+            verdict = (f"step {tried['step_bytes']} B (arguments "
+                       f"{tried['argument_bytes']}, temporaries "
+                       f"{tried['temp_bytes']}) %s the limit {limit} B less "
+                       f"{headroom}")
+            if tried["fits"]:
+                plan.update(chosen=policy, why=verdict % "fits")
+                break
+            tried["why"] = verdict % "over"
+        if mcfg.remat_policy == REMAT_AUTO:
+            plan["rung"] = REMAT_LADDER.index(plan["chosen"])
+        return self._announce_remat(plan)
+
+    def _pin_remat(self, policy: str) -> None:
+        """Trace the model with ``policy`` from here on (the engine's own
+        clone: the caller's module is left as it was)."""
+        self.model = self.model.clone(config=dataclasses.replace(
+            self.model.config, remat_policy=policy))
+        self._raw_loss_fn = partial(lm_loss_fn, self.model)
+
+    def _abstract_step_args(self) -> tuple:
+        """``(state, batch)`` as ``train_batch`` hands them to the jitted
+        step, abstract: committed leaves with their sharding, the batch
+        with the sample batch's trailing dims under the gas dim."""
+        cfg = self.config
+        gas = cfg.gradient_accumulation_steps
+        lead = (gas, cfg.train_batch_size // gas)
+        batch = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            lead + tuple(x.shape[1:]), jax.dtypes.canonicalize_dtype(x.dtype),
+            sharding=self._batch_sharding(1 + x.ndim, with_gas_dim=True)),
+            self._sample_batch)
+        return jax.tree.map(_abstract, self.state), batch
+
+    def _announce_remat(self, plan: dict) -> dict:
+        """One log line and, under telemetry, two gauges: the rung's index
+        in the ladder (−1: pinned) and the chosen step's temporary bytes
+        (0 where it was not compiled to be judged)."""
+        judged = [t for t in plan["tried"] if t["policy"] == plan["chosen"]]
+        logger.info(
+            f"remat: every block checkpointed, keeping "
+            f"{plan['chosen']!r} — {plan['why']}" + "".join(
+                f"; {t['policy']!r} refused: {t['why']}"
+                for t in plan["tried"] if not t["fits"]))
+        if self._telem.enabled:
+            reg = self._telem.registry
+            reg.gauge("train_remat_rung").set(
+                -1 if plan["rung"] is None else plan["rung"])
+            reg.gauge("train_step_temp_bytes").set(
+                judged[0]["temp_bytes"] if judged else 0)
+        return plan
 
     def _announce_attention(self) -> tuple[str, str] | None:
         """Log ONCE which attention formulation the train step will trace
@@ -1157,25 +1266,22 @@ class DeepSpeedEngine:
     def _shard_batch(self, batch: dict, with_gas_dim: bool) -> dict:
         """Device_put the host batch with [*(gas), global_batch, seq] dims
         sharded over the DP axes (+ seq axis)."""
-        topo = self.topology
-
         def put(x):
             x = jnp.asarray(x) if not isinstance(x, jax.Array) else x
-            ndim = x.ndim
-            if with_gas_dim:
-                entries: list[Any] = [None] * ndim
-                if ndim >= 2:
-                    entries[1] = BATCH_AXES
-                if ndim >= 3 and topo.size("seq") > 1:
-                    entries[2] = "seq"
-            else:
-                entries = [None] * ndim
-                entries[0] = BATCH_AXES
-                if ndim >= 2 and topo.size("seq") > 1:
-                    entries[1] = "seq"
-            return jax.device_put(x, NamedSharding(topo.mesh, P(*entries)))
+            return jax.device_put(x, self._batch_sharding(x.ndim,
+                                                          with_gas_dim))
 
         return jax.tree.map(put, batch)
+
+    def _batch_sharding(self, ndim: int, with_gas_dim: bool) -> NamedSharding:
+        topo = self.topology
+        entries: list[Any] = [None] * ndim
+        b = 1 if with_gas_dim else 0
+        if ndim > b:
+            entries[b] = BATCH_AXES
+        if ndim > b + 1 and topo.size("seq") > 1:
+            entries[b + 1] = "seq"
+        return NamedSharding(topo.mesh, P(*entries))
 
     def _apply_curriculum(self, batch: dict) -> dict:
         """Seqlen curriculum: truncate [B, S] leaves to the current

@@ -355,14 +355,75 @@ def test_paged_kernel_instruction_is_named_by_form(name, kernel, topo):
     HLO instruction is called — and so what a device trace's "XLA Ops" line
     prints (unnamed, it took the innermost scope or ``closed_call``). The
     benchmark's roofline readers match it by form."""
+    fn, args, _ = CASES[name](topo.devices)
+    calls = _kernel_calls(jax.jit(fn).lower(*args).compile().as_text())
+    assert calls and set(calls) \
+        == (kernel if isinstance(kernel, set) else {kernel})
+
+
+def _kernel_calls(hlo_text):
+    """The Pallas custom calls of a compiled program by the kernel's
+    ``name=`` (the instruction's name less its numeric suffix)."""
     import re
 
-    fn, args, _ = CASES[name](topo.devices)
-    text = jax.jit(fn).lower(*args).compile().as_text()
-    calls = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
-                       r'"tpu_custom_call"', text)
-    assert calls and {re.sub(r"[.\d]+$", "", c) for c in calls} \
-        == (kernel if isinstance(kernel, set) else {kernel})
+    return [re.sub(r"[.\d]+$", "", c) for c in re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        hlo_text)]
+
+
+def _mistral_grad_step(devs, layers, remat_policy):
+    """Loss and gradients of ``layers`` Mistral-7B blocks (the benchmark's
+    train cell: 2 rows x 2048 a chip, bf16, every block rematted) on mesh
+    ``{fsdp: 4}`` of the described chips, parameters abstract and sharded
+    over ``fsdp`` on their first dimension."""
+    import flax.linen as nn
+
+    from deepspeed_tpu.models import build_model
+    from deepspeed_tpu.models.loss import lm_loss_fn
+    from deepspeed_tpu.models.transformer import default_activation_rules
+    from deepspeed_tpu.parallel.axes import model_mesh_scope
+    from deepspeed_tpu.parallel.topology import BATCH_AXES, MeshTopology
+
+    topo = MeshTopology({"fsdp": 4}, devices=list(devs))
+    model = build_model("mistral-7b", sliding_window=None, vocab_size=32768,
+                        num_layers=layers, max_seq_len=2048, remat=True,
+                        remat_policy=remat_policy)
+    ids = _sds(NamedSharding(topo.mesh, P(BATCH_AXES)), (8, 2048), jnp.int32)
+    params = jax.tree.map(
+        lambda b: _sds(NamedSharding(topo.mesh, P("fsdp")), b.value.shape,
+                       BF16),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0), ids)["params"],
+        is_leaf=lambda l: hasattr(l, "names"))
+
+    def grad_step(p, ids):
+        with nn.logical_axis_rules(default_activation_rules(topo)), \
+                model_mesh_scope(topo.mesh):
+            return jax.value_and_grad(
+                lambda p: lm_loss_fn(model, p, {"input_ids": ids}))(p)
+    return jax.jit(grad_step).lower(params, ids).compile()
+
+
+def test_first_remat_rung_keeps_the_kernel_count_and_drops_matmuls(topo):
+    """What ``remat_policy="auto"`` keeps first (``save_matmul_products``)
+    against ``nothing_saveable``, compiled: still two ``flash_attention_fwd``
+    calls a layer — the kernel's ``(out, lse)`` carry no tag, and the
+    benchmark's ``train_flash_attn_mfu`` counts two — and fewer matmuls,
+    for more temporaries."""
+    import re
+
+    layers, got = 2, {}
+    for policy in ("save_matmul_products", "nothing_saveable"):
+        compiled = _mistral_grad_step(topo.devices, layers, policy)
+        text = compiled.as_text()
+        got[policy] = (_kernel_calls(text).count("flash_attention_fwd"),
+                       len(re.findall(r"= \S+ convolution\(", text)),
+                       compiled.memory_analysis().temp_size_in_bytes)
+        print(policy, "flash forwards, matmuls, temporaries:", got[policy])
+    top, bottom = got["save_matmul_products"], got["nothing_saveable"]
+    assert top[0] == bottom[0] == 2 * layers
+    # q, k, v, the output projection, gate and up: not made again
+    assert bottom[1] - top[1] >= 2 * layers
+    assert top[2] > bottom[2]
 
 
 def test_paged_kernel_scalar_prefetch_footprint():
