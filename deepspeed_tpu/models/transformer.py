@@ -35,7 +35,8 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ..ops.attention import (AttentionSharding, attention_formulation,
+from ..ops.attention import (AttentionSharding, attention_flash_plan,
+                             attention_formulation,
                              dot_product_attention)
 
 # Logical activation axis names (canonical home: parallel/axes.py);
@@ -334,6 +335,23 @@ def attention_sharding(cfg: "ModelConfig") -> AttentionSharding | None:
     return None if found is None else AttentionSharding(*found)
 
 
+def _training_attention_call(cfg: "ModelConfig", batch: int, seq: int,
+                             manual_axes=None) -> tuple[tuple, dict]:
+    """The abstract q, k, v and keywords of the attention call
+    :class:`Attention` makes for a full-sequence ``[batch, seq]`` step (no
+    KV cache, no padding mask) under the rules and mesh in scope HERE."""
+    q = jax.ShapeDtypeStruct((batch, seq, cfg.num_heads, cfg.head_dim),
+                             cfg.dtype)
+    kv = jax.ShapeDtypeStruct((batch, seq, cfg.kv_heads, cfg.head_dim),
+                              cfg.dtype)
+    # alibi's bias is built per call; its presence is all the gate reads
+    return (q, kv, kv), dict(
+        causal=cfg.causal, window=cfg.sliding_window,
+        bias=True if cfg.position_embedding == "alibi" else None,
+        impl=cfg.attn_impl, sharding=attention_sharding(cfg),
+        manual_axes=manual_axes)
+
+
 def training_attention_formulation(cfg: "ModelConfig", batch: int, seq: int,
                                    manual_axes=None) -> tuple[str, str]:
     """``("pallas", "")`` or ``("xla", why_not)``: what :class:`Attention`
@@ -343,16 +361,17 @@ def training_attention_formulation(cfg: "ModelConfig", batch: int, seq: int,
     so ``attn_impl="auto"`` never falls through to XLA attention
     unannounced. ``manual_axes``: the mesh axes the step's own
     ``shard_map`` will have made manual (``batch`` is then a shard's)."""
-    q = jax.ShapeDtypeStruct((batch, seq, cfg.num_heads, cfg.head_dim),
-                             cfg.dtype)
-    kv = jax.ShapeDtypeStruct((batch, seq, cfg.kv_heads, cfg.head_dim),
-                              cfg.dtype)
-    # alibi's bias is built per call; its presence is all the gate reads
-    return attention_formulation(
-        q, kv, kv, causal=cfg.causal, window=cfg.sliding_window,
-        bias=True if cfg.position_embedding == "alibi" else None,
-        impl=cfg.attn_impl, sharding=attention_sharding(cfg),
-        manual_axes=manual_axes)
+    qkv, kw = _training_attention_call(cfg, batch, seq, manual_axes)
+    return attention_formulation(*qkv, **kw)
+
+
+def training_flash_plan(cfg: "ModelConfig", batch: int, seq: int,
+                        manual_axes=None):
+    """The flash kernel's plan for that call (``ops/pallas/
+    flash_attention.py:FlashPlan``: blocks, compute tile, backward form,
+    tiles computed) — None where XLA attention runs."""
+    qkv, kw = _training_attention_call(cfg, batch, seq, manual_axes)
+    return attention_flash_plan(*qkv, **kw)
 
 
 class Attention(nn.Module):

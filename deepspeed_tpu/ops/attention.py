@@ -204,6 +204,27 @@ def attention_formulation(q, k, v, *, causal: bool = True, positions=None,
                       manual_axes=manual_axes)[:2]
 
 
+def attention_flash_plan(q, k, v, *, causal: bool = True, positions=None,
+                         mask=None, bias=None, impl: str = "auto",
+                         window: int | None = None,
+                         sharding: AttentionSharding | None = None,
+                         manual_axes=None):
+    """The ``FlashPlan`` (``ops/pallas/flash_attention.py``: blocks, compute
+    tile, backward form, tiles computed) of the ONE kernel call
+    :func:`dot_product_attention` makes for these inputs — a shard's shapes
+    under a mesh, and the launcher's own plan, since the launcher makes it
+    from the same shapes. None where XLA attention runs. Takes what
+    :func:`attention_formulation` takes."""
+    chosen, _, place = _formulate(
+        q, k, v, causal=causal, positions=positions, mask=mask, bias=bias,
+        impl=impl, window=window, sharding=sharding, manual_axes=manual_axes)
+    if chosen != "pallas":
+        return None
+    from .pallas.flash_attention import flash_plan
+
+    return flash_plan(place.q_shape, place.kv_shape, q.dtype, causal)
+
+
 def dot_product_attention(q, k, v, *, causal: bool = True, positions=None,
                           kv_len=None, mask=None, bias=None, impl: str = "auto",
                           window: int | None = None,

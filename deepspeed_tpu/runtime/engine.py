@@ -37,7 +37,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..config import Config
 from ..models.loss import lm_loss_fn
 from ..models.transformer import (ModelConfig, default_activation_rules,
-                                  training_attention_formulation)
+                                  training_attention_formulation,
+                                  training_flash_plan)
 from ..ops.optimizers import OptState, Optimizer, build_optimizer
 from ..ops.remat import AUTO as REMAT_AUTO, REMAT_LADDER
 from ..parallel.topology import BATCH_AXES, MeshTopology
@@ -436,10 +437,15 @@ class DeepSpeedEngine:
         otherwise falls through to XLA attention silently. Asked inside
         what the step traces the model under (:meth:`_model_scope`; the
         ZeRO++ and 1-bit steps make the DP axes manual and hand the model
-        a shard's rows). Returns ``(formulation, reason)``; None for
-        models that are not the zoo's TransformerLM (custom loss_fn /
-        foreign modules)."""
+        a shard's rows). Where it is the flash kernel, one ``flash:`` line
+        more says what the kernel does with a shard's shapes
+        (``engine.flash_plan``, the launcher's own ``FlashPlan``: blocks,
+        compute tile, backward form, the share of the score square
+        computed). Returns ``(formulation, reason)``; None for models
+        that are not the zoo's TransformerLM (custom loss_fn / foreign
+        modules)."""
         mcfg = getattr(self.model, "config", None)
+        self.flash_plan = None
         if self._custom_loss_fn or not isinstance(mcfg, ModelConfig):
             return None
         topo = self.topology
@@ -455,10 +461,14 @@ class DeepSpeedEngine:
                 else self._model_scope(manual):
             chosen, why_not = training_attention_formulation(
                 mcfg, rows, seq, manual_axes=manual)
+            self.flash_plan = training_flash_plan(
+                mcfg, rows, seq, manual_axes=manual)
         logger.info(f"attention: attn_impl={mcfg.attn_impl!r} runs " + (
             "the Pallas flash kernel" + (
                 " per shard" if topo.mesh.size > 1 else "")
             if chosen == "pallas" else f"XLA attention — {why_not}"))
+        if self.flash_plan is not None:
+            logger.info(f"flash: {self.flash_plan.describe()}")
         return chosen, why_not
 
     # ------------------------------------------------------------------

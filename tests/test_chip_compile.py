@@ -282,6 +282,7 @@ OLMOE = dict(H=16, KV=16, D=128, max_pages=32)
 CASES = {
     "flash_fwd_bwd_b8_s1024_h16_d64": _flash(8, 1024, 16, 64),
     "flash_fwd_bwd_b1_s8192_h16_d64": _flash(1, 8192, 16, 64),
+    "flash_fwd_bwd_b1_s16384_h16_d64": _flash(1, 16384, 16, 64),
     "ragged_decode_bf16": _ragged(8, 1, BF16),
     "ragged_decode_fp8": _ragged(8, 1, FP8),
     "ragged_chunk512_bf16": _ragged(1, 512, BF16),
@@ -344,11 +345,11 @@ def test_kernel_compiles_for_v5e(name, topo):
     ("ragged_tree_olmoe_s48_p32", "paged_attn_tree"),
     ("grouped_gemm_olmoe_decode_up", "grouped_matmul_fwd"),
     ("grouped_gemm_olmoe_prefill_down", "grouped_matmul_fwd"),
-    # sequence 2048 > one KV block: the split backward pair (what
-    # ``train_flash_attn_mfu`` matches in the benchmark's train cell)
+    # the whole K and V of a (row, kv head) at sequence 2048, head 128 stay
+    # resident: ONE merged backward kernel (what ``train_flash_attn_mfu``
+    # matches in the benchmark's train cell since PR 35)
     ("flash_fwd_bwd_train_mesh_fsdp4",
-     {"flash_attention_fwd", "flash_attention_bwd_dq",
-      "flash_attention_bwd_dkv"}),
+     {"flash_attention_fwd", "flash_attention_bwd_dqkv"}),
 ])
 def test_paged_kernel_instruction_is_named_by_form(name, kernel, topo):
     """``name=`` on the ``pallas_call`` is what the compiled custom call's
@@ -359,6 +360,37 @@ def test_paged_kernel_instruction_is_named_by_form(name, kernel, topo):
     calls = _kernel_calls(jax.jit(fn).lower(*args).compile().as_text())
     assert calls and set(calls) \
         == (kernel if isinstance(kernel, set) else {kernel})
+
+
+@pytest.mark.parametrize("name, shape, backward, kernels", [
+    # gpt2-350m's rows, the shape the old block policy was measured on
+    ("flash_fwd_bwd_b8_s1024_h16_d64", (8, 1024, 16, 64), "merged",
+     {"fwd", "bwd_dqkv"}),
+    # sequence 8192 at head 64: K, V (2 x 1 MiB, 2 x 2 MiB lane-padded), the
+    # dk/dv blocks and their fp32 scratch still fit the plan's budget and
+    # stay resident: merged; at 16384 they do not: keys come a block a grid
+    # step and the backward is the split pair
+    ("flash_fwd_bwd_b1_s8192_h16_d64", (1, 8192, 16, 64), "merged",
+     {"fwd", "bwd_dqkv"}),
+    ("flash_fwd_bwd_b1_s16384_h16_d64", (1, 16384, 16, 64), "split",
+     {"fwd", "bwd_dq", "bwd_dkv"}),
+])
+def test_flash_backward_form_is_the_plans(name, shape, backward, kernels,
+                                          topo):
+    """``flash_plan`` says which backward a shape takes, and the compiled
+    program launches exactly those kernels (outside a ``shard_map`` the
+    instruction carries the transform's prefix: ``jvp_…``)."""
+    import re
+
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_plan
+
+    plan = flash_plan(shape, shape, BF16, True)
+    assert plan.backward == backward
+    assert plan.resident == (backward == "merged")
+    fn, args, _ = CASES[name](topo.devices)
+    calls = _kernel_calls(jax.jit(fn).lower(*args).compile().as_text())
+    assert {re.search(r"flash_attention_(fwd|bwd_[a-z]+)", c).group(1)
+            for c in calls} == kernels
 
 
 def _kernel_calls(hlo_text):

@@ -1,5 +1,7 @@
 """Pallas flash-attention numerics vs the XLA oracle (role of reference
 tests/unit/ops/transformer/ kernel tests). Runs in interpret mode on CPU."""
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,7 +10,10 @@ from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.ops.attention import _xla_attention
 from deepspeed_tpu.ops.pallas.flash_attention import (
-    flash_attention, flash_attention_usable)
+    flash_attention, flash_attention_usable, flash_plan)
+
+#: the module itself (the package re-exports the function under its name)
+FLASH = sys.modules[flash_attention.__module__]
 
 
 def _rand(shape, key, dtype=jnp.float32):
@@ -119,8 +124,8 @@ def test_shape_validation():
 
 
 def test_grads_merged_single_kv_block():
-    """Default blocks with S <= 1024 route the backward through the merged
-    single-launch dQ/dK/dV kernel — the path production training takes.
+    """Default blocks route the backward through the merged single-launch
+    dQ/dK/dV kernel — the path production training takes.
     Check grads vs the XLA oracle, incl. GQA head-group summing."""
     import jax
     import jax.numpy as jnp
@@ -135,7 +140,7 @@ def test_grads_merged_single_kv_block():
     k = jnp.asarray(r.standard_normal((B, S, KV, D)), jnp.float32)
     v = jnp.asarray(r.standard_normal((B, S, KV, D)), jnp.float32)
 
-    def loss_flash(q, k, v):   # default blocks → Skv == block_k → merged
+    def loss_flash(q, k, v):   # default blocks → K, V resident → merged
         return jnp.sum(flash_attention(q, k, v, causal=True) ** 2)
 
     def loss_xla(q, k, v):
@@ -147,6 +152,146 @@ def test_grads_merged_single_kv_block():
     for name, a, b in zip("qkv", gf, gx):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-3,
                                    err_msg=f"d{name}")
+
+
+# ---- compute tiles inside a block, and the backward's two forms (PR 35) -----
+
+def _loss_and_grads(attn, q, k, v):
+    return jax.value_and_grad(
+        lambda q, k, v: jnp.sum(attn(q, k, v) ** 2), argnums=(0, 1, 2))(
+            q, k, v)
+
+
+def _xla(causal):
+    return lambda q, k, v: _xla_attention(
+        q, k, v, causal=causal, positions=None, kv_len=None, mask=None)
+
+
+def _qkv_arrays(S, H, KV, D, seed=0, B=1):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (_rand((B, S, H, D), ks[0]), _rand((B, S, KV, D), ks[1]),
+            _rand((B, S, KV, D), ks[2]))
+
+
+@pytest.mark.parametrize("tiles,H,KV,D,causal", [
+    ((128, 128), 4, 1, 64, True),       # MQA
+    ((128, 256), 8, 2, 128, True),      # GQA 4
+    ((128, 128), 2, 2, 128, True),      # group 1
+    ((128, 128), 2, 2, 64, False),
+    ((128, 256), 4, 1, 128, False),
+    ((256, 128), 8, 2, 64, True),
+    ((None, None), 4, 2, 64, True),     # the plan's own tiles
+], ids=["mqa-128x128-d64", "gqa4-128x256-d128", "group1-128x128-d128",
+        "group1-128x128-d64-full", "mqa-128x256-d128-full",
+        "gqa4-256x128-d64", "default-tiles"])
+def test_many_tiles_under_one_block_match_xla(tiles, H, KV, D, causal):
+    """Sequence 512 is ONE block; pinned tiles make the block the diagonal
+    crosses a walk of several compute tiles (widths 128..512). Forward and
+    all three gradients against XLA attention."""
+    q, k, v = _qkv_arrays(512, H, KV, D)
+    plan = flash_plan(q.shape, k.shape, q.dtype, causal, *tiles)
+    assert (plan.block_q, plan.block_k, plan.backward) == (512, 512, "merged")
+    if tiles[0] and causal:
+        assert plan.tiles_computed < plan.tiles_in_square
+    got, g_got = _loss_and_grads(
+        lambda q, k, v: flash_attention(q, k, v, causal=causal,
+                                        block_q=tiles[0], block_k=tiles[1]),
+        q, k, v)
+    want, g_want = _loss_and_grads(_xla(causal), q, k, v)
+    np.testing.assert_allclose(got, want, rtol=2e-5)
+    for a, b, name in zip(g_got, g_want, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4,
+                                   rtol=5e-4, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("KV,causal", [(1, True), (2, True), (4, False)],
+                         ids=["mqa", "gqa2", "group1-full"])
+def test_split_backward_pair_equals_the_merged_kernel(KV, causal,
+                                                      monkeypatch):
+    """A sequence whose K, V and dk/dv scratch do not fit the budget takes
+    the split dq + dk/dv pair over key blocks — forced here by patching the
+    budget under what the merged kernel needs (and the block menu down, so
+    that sequence 512 is four blocks of the square). Same inputs, same
+    gradients as the merged kernel, and as XLA attention."""
+    q, k, v = _qkv_arrays(512, 4, KV, 64, seed=3)
+    monkeypatch.setattr(FLASH, "_FAST_BLOCKS", (256, 128))
+    merged = flash_plan(q.shape, k.shape, q.dtype, causal)
+    assert (merged.block_q, merged.block_k, merged.backward) \
+        == (256, 256, "merged")
+    flash = lambda q, k, v: flash_attention(q, k, v, causal=causal)
+    got_m, g_m = _loss_and_grads(flash, q, k, v)
+
+    need = FLASH._vmem_merged(256, 256, 512, 64, 4)
+    assert FLASH._vmem_split(256, 256, 64, 4) < need
+    monkeypatch.setattr(FLASH, "VMEM_BUDGET_BYTES", need - 1)
+    split = flash_plan(q.shape, k.shape, q.dtype, causal)
+    assert split.backward == "split" and not split.resident
+    assert split[:4] == merged[:4]
+    got_s, g_s = _loss_and_grads(flash, q, k, v)
+
+    want, g_want = _loss_and_grads(_xla(causal), q, k, v)
+    np.testing.assert_allclose(got_s, got_m, rtol=1e-6)
+    for a, b, c, name in zip(g_s, g_m, g_want, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5,
+                                   rtol=1e-5, err_msg=f"d{name} split/merged")
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c), atol=5e-4,
+                                   rtol=5e-4, err_msg=f"d{name} split/xla")
+
+
+def test_budget_decides_the_backward_form(monkeypatch):
+    """Beyond the budget no block size is refused a shape it fits: the
+    split pair's blocks shrink; pinned tiles are the caller's tradeoff."""
+    shape = (1, 2048, 8, 256)
+    assert flash_plan(shape, shape, jnp.float32, True).backward == "merged"
+    monkeypatch.setattr(FLASH, "VMEM_BUDGET_BYTES", 12 * 1024 * 1024)
+    plan = flash_plan(shape, shape, jnp.float32, True)
+    assert plan.backward == "split" and plan.block_q * plan.block_k \
+        < 1024 * 1024
+    assert FLASH._vmem_split(plan.block_q, plan.block_k, 256, 4) \
+        <= FLASH.VMEM_BUDGET_BYTES
+    pinned = flash_plan(shape, shape, jnp.float32, True, 256, 256)
+    assert (pinned.block_q, pinned.block_k, pinned.tile_q) \
+        == (1024, 1024, 256)
+    monkeypatch.setattr(FLASH, "VMEM_BUDGET_BYTES", 1024 * 1024)
+    assert flash_plan(shape, shape, jnp.float32, True) is None
+
+
+@pytest.mark.parametrize("S,D,causal,tiles", [
+    (2048, 128, True, (None, None)),    # the train cell's shard
+    (1024, 64, True, (None, None)),     # gpt2-350m's rows
+    (8192, 64, True, (None, None)),
+    (1536, 128, True, (None, None)),    # blocks of 512
+    (512, 64, True, (128, 128)),
+    (512, 64, True, (128, 256)),
+    (1024, 128, True, (256, 128)),
+    (384, 64, True, (None, None)),
+    (2048, 128, False, (None, None)),
+])
+def test_plan_counts_the_tiles_the_mask_leaves(S, D, causal, tiles):
+    """``tiles_computed`` (backward) and ``fwd_tiles_computed`` against a
+    count by brute force over the mask: a ``tile_q x tile_k`` tile is
+    computed iff some (query, key) pair of it is unmasked — or, where the
+    forward walks whole rows of a block (``fwd_tile_q``), iff some pair of
+    its ``fwd_tile_q x tile_k`` stripe is."""
+    shape = (1, S, 4, D)
+    plan = flash_plan(shape, shape, jnp.bfloat16, causal, *tiles)
+    allow = np.tril(np.ones((S, S), bool)) if causal \
+        else np.ones((S, S), bool)
+
+    def brute(rows):
+        seen = allow.reshape(S // rows, rows, S // plan.tile_k,
+                             plan.tile_k).any(axis=(1, 3))
+        return int(seen.sum()) * rows // plan.tile_q
+
+    assert plan.tiles_in_square == (S // plan.tile_q) * (S // plan.tile_k)
+    assert plan.tiles_computed == brute(plan.tile_q)
+    assert plan.fwd_tiles_computed == brute(plan.fwd_tile_q)
+    assert abs(plan.causal_need - allow.mean()) < 1e-12
+    assert plan.causal_need <= plan.computed_share <= 1.0
+    if (S, D, tiles) == (2048, 128, (None, None)) and causal:
+        assert plan.backward == "merged" and plan.computed_share <= 0.625 \
+            and plan.fwd_tile_q == plan.block_q
+        assert "backward merged" in plan.describe()
 
 
 # ---- which formulation, and why: nothing about the device is silent --------
@@ -184,6 +329,11 @@ def _qkv(B=2, S=256, H=4, KV=4, D=64, Skv=None):
     (_qkv(S=1280 + 8), {}, "no block divisor"),
     (_qkv(H=6, KV=4), {}, "not divisible by 4 kv heads"),
     (_qkv(D=16), {}, "head_dim 16"),
+    # long rows: K and V resident at 8192, key blocks and the split pair
+    # at 16384 — admitted either way, as before PR 35
+    (_qkv(B=1, S=8192, H=16, KV=16), {}, ""),
+    (_qkv(B=1, S=16384, H=16, KV=16), {}, ""),
+    (_qkv(B=1, S=4096, H=8, KV=8, D=256), {}, ""),
 ])
 def test_flash_gate_names_its_reason(qkv, kw, why):
     """``flash_attention_unusable_reason`` reads the shapes ONE kernel call
@@ -291,14 +441,21 @@ def test_training_engine_can_say_why_not_flash(preset, over, mesh, chosen,
 
     from deepspeed_tpu.models import get_model_config
     from deepspeed_tpu.models.transformer import (
-        default_activation_rules, training_attention_formulation)
+        default_activation_rules, training_attention_formulation,
+        training_flash_plan)
     from deepspeed_tpu.parallel.axes import model_mesh_scope
 
     with nn.logical_axis_rules(default_activation_rules(None)), \
             model_mesh_scope(_mesh(**mesh)) if mesh else nullcontext():
         got = training_attention_formulation(
             get_model_config(preset, **over), 8, 1024)
+        plan = training_flash_plan(get_model_config(preset, **over), 8, 1024)
     assert got[0] == chosen and why in got[1], got
+    # the ``flash:`` line's plan exists exactly where the kernel runs, and
+    # is the launcher's: made from a shard's shapes
+    assert (plan is not None) == (chosen == "pallas")
+    if plan is not None:
+        assert plan.backward == "merged" and plan.block_k == 1024
 
 
 # ---- the kernel under a mesh: one step, per shard, against XLA attention ---
@@ -340,6 +497,11 @@ def _one_step(mesh, impl, heads, kv_heads, zero):
     loss = float(engine.train_batch(batch))
     grads = jax.tree.map(lambda a, b: a - np.asarray(b), before,
                          engine.state.params)
+    # one ``flash:`` log line, from the launcher's own plan of a shard
+    assert (engine.flash_plan is not None) \
+        == (engine.attention_formulation[0] == "pallas")
+    if engine.flash_plan is not None:
+        assert engine.flash_plan[:5] == (128, 128, 128, 128, "merged")
     return engine.attention_formulation, calls, loss, grads
 
 
