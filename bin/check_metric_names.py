@@ -362,6 +362,43 @@ def collect_metric_families(root: str) -> dict[str, dict]:
     return fams
 
 
+#: the serving engine's host loop, written by hand (spans and
+#: ``engine.stats`` keys are no registry families: nothing collects them
+#: statically); ``render_metrics_doc`` appends it as it stands
+ENGINE_LOOP_DOC = """\
+## The serving engine's loop: spans and `engine.stats`
+
+Spans (`telemetry/spans.py`, only with telemetry on; each is mirrored into
+`jax.profiler.TraceAnnotation` under its bare name, its scalar arguments as
+the event's stats). `seq` numbers the entries appended to the engine's
+pipeline (`InferenceEngineV2._inflight`), one counter bumped at the append:
+the spans of one entry share it.
+
+| span | arguments | what it covers |
+|---|---|---|
+| `plan` | `kind` (`window` \\| `step`), `seq` (of the entry it plans) | the scheduler's next step, or a decode window's host arrays: what `plan_s` times |
+| `dispatch` | `kind` (`window` \\| `prefill` \\| `decode` \\| `spec_verify`), `W` (a window's iterations) or `T` (a step's tokens a row), `seq` | the enqueue of the entry's program: inside `dispatch_s` |
+| `drain_block` | `kind` (`window` \\| `plan`), `seq` | the host blocked on the oldest entry's readback: what `drain_block_s` times |
+| `commit` | `seq`, `depth` (entries in flight when it joined) | the entry's tokens into the sequences: what `commit_s` times |
+| `replica_step` | none | `EngineBackend.step`'s own work after `engine.step()` returned (events packed, finished requests flushed): not round the engine's step, or every idle gap of a device trace would take its name |
+| `admit` | `prompt` | `StateManager.admit` inside `put` |
+
+`engine.stats`, the pipeline entry by entry (always on, counters only):
+
+| key | what it counts |
+|---|---|
+| `entries_dispatched`, `inflight_depth_sum` | entries appended, and the sum over them of the entries already in flight: the mean is how far the host runs ahead of the device |
+| `entries_committed`, `inflight_residence_s` | entries committed, and the sum of (end of commit - append): what a dispatched step spends in the pipeline |
+| `prefill_entries_committed`, `prefill_residence_s` | the same two for prefill plans alone |
+| `decode_tokens` | tokens generated by decode steps (booked at dispatch) and decode windows (booked at commit) |
+| `replica_step_s`, `engine_step_s` | booked by `EngineBackend.step`: its own wall time, and `engine.step()`'s inside it |
+
+A replica worker that leaves logs one line from them: `pipeline: depth ...
+residence ... ms over ... entries (prefill ... ms over ...); replica step
+... % outside the engine`.
+"""
+
+
 def render_metrics_doc(root: str) -> str:
     fams = collect_metric_families(root)
     lines = [
@@ -381,7 +418,7 @@ def render_metrics_doc(root: str) -> str:
         help_s = " ".join(e["help"].split()).replace("|", "\\|")
         lines.append(f"| `{name}` | {e['type']} | {help_s} "
                      f"| {e['file']} |")
-    lines.append("")
+    lines += ["", ENGINE_LOOP_DOC]
     return "\n".join(lines)
 
 

@@ -673,6 +673,9 @@ class InferenceEngineV2:
         # riding d2h; committed lazily (see _drain)
         from collections import deque
         self._inflight: deque = deque()
+        # the number the next entry appended to ``_inflight`` takes; the
+        # spans of its dispatch, blocked drain and commit carry it
+        self._entry_seq = 0
         # serving SLO instruments (telemetry/) — all no-ops when disabled
         from .. import telemetry as _telemetry
         if cfg.reqtrace and cfg.telemetry is False:
@@ -731,9 +734,17 @@ class InferenceEngineV2:
         self.stats = {"plan_s": 0.0, "dispatch_s": 0.0, "drain_block_s": 0.0,
                       "commit_s": 0.0, "dispatches": 0, "prefill_steps": 0,
                       "decode_steps": 0, "windows": 0, "window_iters": 0,
-                      "window_iters_max": 0, "forced_drains": 0,
-                      "opportunistic_drains": 0, "prefill_budget_tokens": 0,
+                      "forced_drains": 0, "opportunistic_drains": 0,
                       "prefill_tokens": 0, "decode_tokens": 0,
+                      # the pipeline, entry by entry (``_enqueue`` /
+                      # ``_drain``): entries appended and the depth each
+                      # joined; entries committed and the time from their
+                      # append to the end of their commit; the same two
+                      # for prefill plans alone
+                      "entries_dispatched": 0, "inflight_depth_sum": 0,
+                      "entries_committed": 0, "inflight_residence_s": 0.0,
+                      "prefill_entries_committed": 0,
+                      "prefill_residence_s": 0.0,
                       # shared-prefix KV cache (prefix_cache.py): prompt
                       # tokens served from the trie vs looked up, per-run
                       # (bench zeroes these with the rest of the dict)
@@ -2161,33 +2172,35 @@ class InferenceEngineV2:
         t0 = time.perf_counter()
         S = self.state.max_seqs
         mb = self.state.max_blocks_per_seq
-        tok0 = np.zeros((S,), np.int32)
-        use_last = np.zeros((S,), np.uint8)
-        pos0 = np.zeros((S,), np.int32)
-        lens0 = np.zeros((S,), np.int32)
-        tables = np.zeros((S, mb), np.int32)
-        rem = np.zeros((S,), np.int32)
-        eos = np.full((S,), -1, np.int32)
-        sched: dict[int, tuple[int, int]] = {}   # uid -> (slot, n scheduled)
-        for s in live:
-            sl = s.slot
-            if s.n_inflight:
-                use_last[sl] = 1                 # value only on device
-            else:
-                tok0[sl] = s.tokens[-1]
-            pos0[sl] = s.len_sched - 1
-            lens0[sl] = s.len_sched
-            tables[sl, :len(s.blocks)] = s.blocks
-            n = min(s.gen_remaining_sched, W)
-            rem[sl] = n
-            if s.eos_id is not None:
-                eos[sl] = s.eos_id
-            sched[s.uid] = (sl, n)
+        with self._telem.span("plan", kind="window", seq=self._entry_seq):
+            tok0 = np.zeros((S,), np.int32)
+            use_last = np.zeros((S,), np.uint8)
+            pos0 = np.zeros((S,), np.int32)
+            lens0 = np.zeros((S,), np.int32)
+            tables = np.zeros((S, mb), np.int32)
+            rem = np.zeros((S,), np.int32)
+            eos = np.full((S,), -1, np.int32)
+            sched: dict[int, tuple[int, int]] = {}   # uid -> (slot, n sched)
+            for s in live:
+                sl = s.slot
+                if s.n_inflight:
+                    use_last[sl] = 1             # value only on device
+                else:
+                    tok0[sl] = s.tokens[-1]
+                pos0[sl] = s.len_sched - 1
+                lens0[sl] = s.len_sched
+                tables[sl, :len(s.blocks)] = s.blocks
+                n = min(s.gen_remaining_sched, W)
+                rem[sl] = n
+                if s.eos_id is not None:
+                    eos[sl] = s.eos_id
+                sched[s.uid] = (sl, n)
         self.stats["plan_s"] += time.perf_counter() - t0
 
         t0 = time.perf_counter()
         self._emit_attn_kernel("decode")
-        with self._telem.span("dispatch", kind="window", W=W):
+        with self._telem.span("dispatch", kind="window", W=W,
+                              seq=self._entry_seq):
             fn = self._window_program(W)
             self._rng, sub = jax.random.split(self._rng)
             self.kv_pool, self._last_tok, toks, iters = fn(
@@ -2201,9 +2214,8 @@ class InferenceEngineV2:
             s.n_inflight += n
         toks.copy_to_host_async()
         iters.copy_to_host_async()
-        self._inflight.append({"kind": "window", "sched": sched,
-                               "toks": toks, "iters": iters,
-                               "t": time.perf_counter()})
+        self._enqueue({"kind": "window", "sched": sched, "toks": toks,
+                       "iters": iters, "t": time.perf_counter()})
         self.stats["dispatch_s"] += time.perf_counter() - t0
         self.stats["dispatches"] += 1
         self.stats["windows"] += 1
@@ -2528,8 +2540,9 @@ class InferenceEngineV2:
             self._serve_toggle = False
             return True
         t0 = time.perf_counter()
-        plan = self.scheduler.next_step(
-            prefer="decode" if want_decode else None)
+        with self._telem.span("plan", kind="step", seq=self._entry_seq):
+            plan = self.scheduler.next_step(
+                prefer="decode" if want_decode else None)
         self.stats["plan_s"] += time.perf_counter() - t0
         if plan is None:
             return False
@@ -2551,7 +2564,8 @@ class InferenceEngineV2:
                     f"chunks starting page-misaligned (slot_map col 0 = "
                     f"{plan.slot_map[bad, 0].tolist()}, block_size {bs})")
         t0 = time.perf_counter()
-        with self._telem.span("dispatch", kind=plan.kind):
+        with self._telem.span("dispatch", kind=plan.kind, T=T,
+                              seq=self._entry_seq):
             fn = self._program(T, plan.token_ids.shape[0])
             self._rng, sub = jax.random.split(self._rng)
             self.kv_pool, self._last_tok, toks = fn(
@@ -2561,8 +2575,8 @@ class InferenceEngineV2:
                 plan.do_sample, plan.use_last, plan.row_slots, sub)
         self.scheduler.mark_dispatched(plan)
         toks.copy_to_host_async()
-        self._inflight.append({"kind": "plan", "plan": plan, "toks": toks,
-                               "t": time.perf_counter()})
+        self._enqueue({"kind": "plan", "plan": plan, "toks": toks,
+                       "t": time.perf_counter()})
         self.stats["dispatch_s"] += time.perf_counter() - t0
         self.stats["dispatches"] += 1
         n_tok = int(plan.active.sum())
@@ -2572,11 +2586,6 @@ class InferenceEngineV2:
         if plan.kind == "prefill":
             self.stats["prefill_steps"] += 1
             self.stats["prefill_tokens"] += n_tok
-            # occupancy denominator: padded token BUDGET this step paid
-            # for, rows x T (the honest prefill-MFU accounting divides
-            # useful tokens by these)
-            self.stats["prefill_budget_tokens"] += int(
-                np.prod(plan.token_ids.shape))
         else:
             self.stats["decode_steps"] += 1
             self.stats["decode_tokens"] += n_tok
@@ -2586,6 +2595,18 @@ class InferenceEngineV2:
                 plan.kind, n_tok, int(np.prod(plan.token_ids.shape)),
                 plan.uids)
         return True
+
+    def _enqueue(self, entry: dict) -> None:
+        """Append a dispatched entry to the pipeline under a number of its
+        own (the one its ``dispatch`` span already carries) and book the
+        depth of the pipeline it joins: how far the host runs ahead of the
+        device when it dispatches."""
+        depth = len(self._inflight)
+        entry["seq"], entry["depth"] = self._entry_seq, depth
+        self._entry_seq += 1
+        self.stats["entries_dispatched"] += 1
+        self.stats["inflight_depth_sum"] += depth
+        self._inflight.append(entry)
 
     def _drain(self, force: bool = False, drain_all: bool = False) -> dict:
         """Commit completed in-flight steps. Non-forced drains only take
@@ -2607,7 +2628,8 @@ class InferenceEngineV2:
             if not ready:
                 self.stats["forced_drains"] += 1
                 t0 = time.perf_counter()
-                with self._telem.span("drain_block", kind=entry["kind"]):
+                with self._telem.span("drain_block", kind=entry["kind"],
+                                      seq=entry["seq"]):
                     toks_h = np.asarray(entry["toks"])
                 self.stats["drain_block_s"] += time.perf_counter() - t0
             else:
@@ -2616,8 +2638,20 @@ class InferenceEngineV2:
             self._inflight.popleft()
             force = False
             t0 = time.perf_counter()
-            self._commit_entry(entry, toks_h, emitted)
-            self.stats["commit_s"] += time.perf_counter() - t0
+            with self._telem.span("commit", seq=entry["seq"],
+                                  depth=entry["depth"]):
+                self._commit_entry(entry, toks_h, emitted)
+            t1 = time.perf_counter()
+            st = self.stats
+            st["commit_s"] += t1 - t0
+            # residence: from the append (``entry["t"]``) to the end of
+            # the commit — what a dispatched step spends in the pipeline
+            residence = t1 - entry["t"]
+            st["entries_committed"] += 1
+            st["inflight_residence_s"] += residence
+            if entry["kind"] == "plan" and entry["plan"].kind == "prefill":
+                st["prefill_entries_committed"] += 1
+                st["prefill_residence_s"] += residence
         if emitted and self._telem.enabled:
             self._record_commit_telemetry(emitted)
         return emitted
@@ -2626,7 +2660,6 @@ class InferenceEngineV2:
                       emitted: dict) -> None:
         if entry["kind"] == "window":
             self.stats["window_iters"] += int(np.asarray(entry["iters"]))
-            self.stats["window_iters_max"] += toks_h.shape[0]
             for uid, (sl, n) in entry["sched"].items():
                 seq = self.state.seqs.get(uid)
                 if seq is None:
@@ -2636,6 +2669,9 @@ class InferenceEngineV2:
                 vals = [int(t) for t in col[col >= 0]]  # active prefix
                 new = seq.commit_generated(vals, len(vals))
                 if new:
+                    # a decode step books its token at dispatch; a window
+                    # knows how many it made only here
+                    self.stats["decode_tokens"] += len(new)
                     self._results[uid].extend(new)
                     emitted.setdefault(uid, []).extend(new)
                     if self._rt.enabled:

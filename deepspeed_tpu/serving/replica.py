@@ -900,6 +900,9 @@ class EngineBackend:
         self.eng = InferenceEngineV2(
             model, rng=jax.random.PRNGKey(int(cfg.get("seed", 0))),
             config=ecfg)
+        # ``step`` books its own wall time and the engine's inside it
+        # beside the engine's counters
+        self.eng.stats.update(replica_step_s=0.0, engine_step_s=0.0)
         self.block_size = self.eng.config.block_size
         self.max_live = self.eng.config.max_seqs
         self.role = str(cfg.get("role", "mixed"))
@@ -1008,32 +1011,61 @@ class EngineBackend:
     def step(self, inj: FaultInjector) -> list[tuple]:
         if not self.has_work():
             return []
+        t0 = time.perf_counter()
         if self._in_prefill() \
                 and inj.countdown("replica_crash_during_prefill"):
             inj.crash_now("replica_crash_during_prefill", "engine prefill")
         if self._degrade_s:
             time.sleep(self._degrade_s)
+        t1 = time.perf_counter()
         emitted = self.eng.step()
-        events: list[tuple] = []
-        by_uid = {uid: rid for rid, uid in self._uids.items()}
-        for uid, toks in emitted.items():
-            rid = by_uid.get(uid)
-            if rid is None or not toks:
-                continue
-            events.append((rid, "chunk", [int(t) for t in toks],
-                           self._sent[rid]))
-            self._sent[rid] += len(toks)
-        for rid, uid in list(self._uids.items()):
-            seq = self.eng.state.seqs.get(uid)
-            if seq is not None and seq.done and not seq.frozen \
-                    and not self.eng._uid_inflight(uid):
-                toks = [int(t) for t in self.eng.flush(uid)]
-                del self._uids[rid]
-                self._sent.pop(rid, None)
-                self._tenants.pop(rid, None)
-                self._resumed.discard(rid)
-                events.append((rid, "done", toks, 0))
+        t2 = time.perf_counter()
+        # the span covers the loop's own work and not the engine's step
+        # inside it: a reader of the device trace names an idle gap after
+        # the span that covers most of it, and a span round the whole call
+        # would take that name from every span of the engine's
+        with self.eng._telem.span("replica_step"):
+            events: list[tuple] = []
+            by_uid = {uid: rid for rid, uid in self._uids.items()}
+            for uid, toks in emitted.items():
+                rid = by_uid.get(uid)
+                if rid is None or not toks:
+                    continue
+                events.append((rid, "chunk", [int(t) for t in toks],
+                               self._sent[rid]))
+                self._sent[rid] += len(toks)
+            for rid, uid in list(self._uids.items()):
+                seq = self.eng.state.seqs.get(uid)
+                if seq is not None and seq.done and not seq.frozen \
+                        and not self.eng._uid_inflight(uid):
+                    toks = [int(t) for t in self.eng.flush(uid)]
+                    del self._uids[rid]
+                    self._sent.pop(rid, None)
+                    self._tenants.pop(rid, None)
+                    self._resumed.discard(rid)
+                    events.append((rid, "done", toks, 0))
+        # the replica loop's own host time a step is what this call took
+        # less the engine's step inside it
+        st = self.eng.stats
+        st["replica_step_s"] += time.perf_counter() - t0
+        st["engine_step_s"] += t2 - t1
         return events
+
+    def pipeline_line(self) -> str:
+        """The engine's pipeline over this backend's life, from its
+        counters: what a worker logs when it leaves."""
+        st = self.eng.stats
+        n_in, n_out = st["entries_dispatched"], st["entries_committed"]
+        n_pre, step_s = st["prefill_entries_committed"], st["replica_step_s"]
+        return (
+            f"pipeline: depth {st['inflight_depth_sum'] / max(n_in, 1):.2f} "
+            f"residence "
+            f"{1e3 * st['inflight_residence_s'] / max(n_out, 1):.1f} ms "
+            f"over {n_out} entries (prefill "
+            f"{1e3 * st['prefill_residence_s'] / max(n_pre, 1):.1f} ms over "
+            f"{n_pre}); replica step "
+            f"{100 * (step_s - st['engine_step_s']) / max(step_s, 1e-9):.2f}"
+            f" % outside the engine")
 
     # -- KV-page migration (disaggregated serving) -----------------------
     def request_handoff(self, rid: str) -> bool:
@@ -1654,6 +1686,14 @@ class DaemonState:
         return out
 
 
+def _log_pipeline(backend) -> None:
+    """A leaving worker's one line on its engine's pipeline (the toy
+    backend has none)."""
+    line = getattr(backend, "pipeline_line", None)
+    if line is not None:
+        logger.info(line())
+
+
 def _drain_flush(backend, inj) -> int:
     """Elastic drain-flush: push every unpinned cached chain into the
     KV tier — block-at-a-time eviction WITH demotion drives the
@@ -1663,6 +1703,7 @@ def _drain_flush(backend, inj) -> int:
     SIGKILL mid-flush leaves at most a torn tail record, which the
     tier's scan gate skips on the next open. Returns blocks flushed."""
     n = 0
+    _log_pipeline(backend)
     radix = getattr(backend, "radix", None)
     tier = getattr(backend, "kv_tier", None)
     if radix is not None and tier is not None:
@@ -2514,6 +2555,7 @@ def serve(cfg: dict, chan: LineChannel,
                     chan.send({"t": "bye"}, timeout=1.0)
                 except (ChannelClosed, ChannelTimeout):
                     pass                 # router already gone: exit anyway
+                _log_pipeline(backend)
                 tier = getattr(backend, "kv_tier", None)
                 if tier is not None:
                     # graceful exit: spill the RAM ring so a restarted

@@ -10,8 +10,8 @@ the train step blocked, what the job was doing right before a hang. Spans
 are cheap enough to leave on in production (one perf_counter pair + one
 ring-buffer slot per span; no allocation growth past the buffer capacity)
 and every completed span is mirrored into ``jax.profiler.TraceAnnotation``
-when a device trace is active, so host spans overlay the xplane timeline in
-the same viewer.
+(its scalar arguments as the event's stats) when a device trace is active,
+so host spans overlay the xplane timeline in the same viewer.
 
 Lock discipline: the ring buffer is written with GIL-atomic operations only
 (index bump + slot store) — "lock-free-ish" — because spans wrap latency-
@@ -124,7 +124,14 @@ class SpanTracer:
         self._jax_profiler = None         # lazy; import failure logged once
 
     # -- recording -------------------------------------------------------
-    def _annotation(self, name: str, step: int | None):
+    def _annotation(self, name: str, step: int | None,
+                    args: dict | None = None):
+        """The span's mirror in the profiler's own trace. Its NAME stays
+        bare (readers of an xplane group events by name: an idle gap is
+        named after the span that covers it); the span's scalar arguments
+        travel as the event's stats, so a device trace can tell a prefill
+        dispatch from a window and pair the spans of one entry by its
+        ``seq``."""
         if not self.mirror_jax:
             return None
         prof = self._jax_profiler
@@ -138,14 +145,17 @@ class SpanTracer:
             self._jax_profiler = prof
         if step is not None:
             return prof.StepTraceAnnotation(name, step_num=step)
-        return prof.TraceAnnotation(name)
+        return prof.TraceAnnotation(name, **{
+            k: v for k, v in (args or {}).items()
+            if isinstance(v, (int, float, str, bool))})
 
     def span(self, name: str, **args):
         """``with tracer.span("dispatch", kind="prefill"): ...`` — records
         a completed span on exit; no-op (shared null) when disabled."""
         if not self.enabled:
             return NULL_SPAN
-        return _Span(self, name, args or None, self._annotation(name, None))
+        return _Span(self, name, args or None,
+                     self._annotation(name, None, args))
 
     def step_span(self, name: str, step: int, **args):
         """A span mirrored as ``jax.profiler.StepTraceAnnotation`` so a

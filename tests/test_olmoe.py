@@ -307,9 +307,9 @@ def test_moe_counters_follow_the_plans():
                             config={**ENGINE, "decode_window": 4})
     eng.generate([list(range(1, 20))], max_new_tokens=9)
     st, mo = eng.stats, model.config.moe
+    assert st["decode_tokens"] == 8        # 9 new: 1 by the prefill, 2 x 4
     tokens = st["prefill_tokens"] + st["decode_tokens"]
-    window_tokens = 8                      # 9 new: 1 by the prefill, 2 x 4
-    assert st["moe_routed_rows"] == (tokens + window_tokens) * mo.top_k * 4
+    assert st["moe_routed_rows"] == tokens * mo.top_k * 4
     assert st["moe_padded_rows"] > st["moe_routed_rows"]
     # the rule: twice the mean rows an expert, a power of two, inside
     # [floor, 128]

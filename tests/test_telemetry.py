@@ -63,6 +63,39 @@ def test_span_nesting_depths_and_args():
     assert inner["t0"] + inner["dur"] <= outer["t0"] + outer["dur"] + 1e-6
 
 
+def test_span_arguments_reach_the_profiler_under_a_bare_name():
+    """The mirror of a span in the profiler's trace is named as the span
+    is — readers of a device trace group events by name — and carries the
+    span's scalar arguments as the event's stats."""
+    class Profiler:
+        made = []
+
+        class TraceAnnotation:
+            def __init__(self, name, **kwargs):
+                Profiler.made.append((name, kwargs))
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+    t = Telemetry(enabled=True, span_buffer=8)
+    t.tracer._jax_profiler = Profiler
+    with t.span("dispatch", kind="window", W=8, seq=41, rows=[1, 2]):
+        pass
+    with t.span("commit"):
+        pass
+    assert Profiler.made == [
+        ("dispatch", {"kind": "window", "W": 8, "seq": 41}), ("commit", {})]
+    # the ring keeps every argument, scalar or not
+    assert t.tracer.events()[0]["args"]["rows"] == [1, 2]
+    t.tracer.mirror_jax = False
+    with t.span("dispatch", seq=42):
+        pass
+    assert len(Profiler.made) == 2
+
+
 def test_span_ring_buffer_wraparound():
     t = Telemetry(enabled=True, span_buffer=8)
     for i in range(20):
