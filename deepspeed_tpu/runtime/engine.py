@@ -32,6 +32,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..config import Config
@@ -805,7 +806,13 @@ class DeepSpeedEngine:
 
     def _compute_grads(self, state: TrainState, batch: dict) -> tuple[jax.Array, Pytree]:
         """One microbatch forward+backward; grads constrained per plan
-        (stage ≥2 → reduce-scatter; else all-reduce)."""
+        (stage ≥2 → reduce-scatter; else all-reduce) in the dtype the
+        backward made them, and only then read as float32 (unscaled, under
+        fp16). The cast is no pass of its own: whoever consumes the
+        gradients inside the same program — the GAS scan's accumulator, or
+        with nothing to accumulate the update itself — does it in registers.
+        Only a program that RETURNS them (``grad_step``, the imperative
+        API) writes float32 gradients to memory, as it must."""
         mgr = getattr(self, "compression_manager", None)
 
         def scaled_loss(p):
@@ -820,56 +827,82 @@ class DeepSpeedEngine:
             return loss
 
         loss, grads = jax.value_and_grad(scaled_loss)(state.params)
-        grads = _cast_tree(grads, jnp.float32)
+        grads = _cast_tree(self._constrain_grads(grads), jnp.float32)
         if state.scaler is not None:
             loss = loss / state.scaler.scale
             grads = jax.tree.map(lambda g: g / state.scaler.scale, grads)
-        grads = jax.lax.with_sharding_constraint(grads, self.plan.grad_shardings)
         return loss, grads
+
+    def _constrain_grads(self, grads: Pytree) -> Pytree:
+        """The backward's gradients as the rest of the step takes them:
+        sharded per plan, and laid out as the state is (row-major). A weight
+        gradient leaves its matmul in whatever layout suits the matmul
+        (``wq``'s ``[E, H, D]`` comes out D-major); left so, the compiler
+        gives the fused update's OUTPUTS the gradient's layout and copies
+        params, master and both moments back — four copies a leaf where
+        re-laying the one bf16 gradient is one."""
+        grads = jax.lax.with_sharding_constraint(grads, self.plan.grad_shardings)
+        return jax.tree.map(
+            lambda g: with_layout_constraint(
+                g, Layout(major_to_minor=tuple(range(g.ndim)))), grads)
 
     def _apply_grads(self, state: TrainState, grads: Pytree,
                      loss_finite: jax.Array | None = None
                      ) -> tuple[TrainState, jax.Array]:
-        """Optimizer update; returns ``(new_state, finite_flag)``. Under the
-        fp16 scaler OR the resilience sentinel (bf16/fp32 included) a
-        non-finite step skips the update in-program — ``global_step`` still
-        advances, so ``skipped_steps`` counts the skips host-side with no
-        extra sync."""
+        """The tail of the step: optimizer update and the cast back to the
+        compute dtype; returns ``(new_state, finite_flag)``.
+
+        Written so that it compiles to ONE fused pass over each leaf of the
+        shard. First a pre-pass (scope ``grad_check``) reads each gradient
+        once for the global scalars the update waits on: the finite flag
+        and, with ``gradient_clipping``, the norm. The clip factor is a
+        scalar multiplied inside the update — no clipped tree is written —
+        and so is a gradient's float32 cast where it arrives in the
+        backward's dtype. Then (scope ``optimizer``) ``optimizer.update``,
+        the skip and the cast back are one fusion a leaf with the new
+        params, master and moments as its outputs.
+
+        Under the fp16 scaler OR the resilience sentinel (bf16/fp32
+        included) a non-finite step changes nothing: the update is computed
+        unconditionally and each leaf of master, moments and the optimizer's
+        step SELECTS old or new by the flag (a ``lax.cond`` round the update
+        would be a fusion barrier: its operands and results are buffers, so
+        the gradient cast, the check, the update and the cast back were four
+        passes). A skipped step therefore costs a normal step's bytes
+        instead of none; skips are rare — an fp16 overflow every
+        ``loss_scale_window`` steps, a bf16 divergence never in a sound
+        run. ``global_step`` still advances, so ``skipped_steps`` counts the
+        skips host-side with no extra sync."""
         cfg = self.config
         lr = self.lr_schedule(state.opt_state.step)
-        if cfg.gradient_clipping:
-            with device_scope("grad_check"):
+        guarded = state.scaler is not None or cfg.resilience.sentinel
+        with device_scope("grad_check"):
+            if guarded:
+                finite = fp16_mod.grads_finite(grads)
+                if loss_finite is not None:
+                    finite = finite & loss_finite
+            else:
+                finite = jnp.asarray(True)
+            if cfg.gradient_clipping:
+                # a non-finite gradient stays non-finite clipped (inf x 0 is
+                # NaN), so checking the unclipped tree decides the same and
+                # lets the check and the norm share one read
                 norm = _global_norm(grads)
                 clip = jnp.minimum(1.0,
                                    cfg.gradient_clipping / (norm + 1e-6))
                 grads = jax.tree.map(lambda g: g * clip, grads)
 
         master_in = state.master if state.master is not None else state.params
-
-        def do_update(operand):
-            m, opt = operand
-            with device_scope("optimizer"):
-                new_master, new_opt = self.optimizer.update(grads, opt, m,
-                                                            lr=lr)
-                new_master = jax.lax.with_sharding_constraint(
-                    new_master, self.plan.master_shardings)
-            return new_master, new_opt
-
-        guarded = state.scaler is not None or cfg.resilience.sentinel
-        if guarded:
-            with device_scope("grad_check"):
-                finite = fp16_mod.grads_finite(grads)
-                if loss_finite is not None:
-                    finite = finite & loss_finite
-            new_master, new_opt = jax.lax.cond(
-                finite, do_update, lambda op: op, (master_in, state.opt_state))
-        else:
-            finite = jnp.asarray(True)
-            new_master, new_opt = do_update((master_in, state.opt_state))
-        new_scaler = None if state.scaler is None else \
-            fp16_mod.update_scaler(state.scaler, finite, cfg.fp16)
-
-        with device_scope("optimizer"):     # the cast back to compute dtype
+        with device_scope("optimizer"):
+            new_master, new_opt = self.optimizer.update(
+                grads, state.opt_state, master_in, lr=lr)
+            if guarded:
+                def keep(new, old):
+                    return jnp.where(finite, new, old)
+                new_master = jax.tree.map(keep, new_master, master_in)
+                new_opt = jax.tree.map(keep, new_opt, state.opt_state)
+            new_master = jax.lax.with_sharding_constraint(
+                new_master, self.plan.master_shardings)
             if self.mixed_precision:
                 new_params = _cast_tree(new_master, self.compute_dtype)
                 master_out = new_master
@@ -878,6 +911,8 @@ class DeepSpeedEngine:
                 master_out = None
             new_params = jax.lax.with_sharding_constraint(
                 new_params, self.plan.param_shardings)
+        new_scaler = None if state.scaler is None else \
+            fp16_mod.update_scaler(state.scaler, finite, cfg.fp16)
         return TrainState(params=new_params, master=master_out, opt_state=new_opt,
                           scaler=new_scaler, global_step=state.global_step + 1), finite
 
@@ -897,11 +932,21 @@ class DeepSpeedEngine:
         repl = NamedSharding(topo.mesh, P())
 
         def make_gas_grads(compute, constrain: bool):
-            """GAS scan factory: fp32 grad accumulation over microbatches
+            """GAS factory: fp32 grad accumulation over microbatches
             (reference engine.py:1838/:1977 forward/backward loop).
             ``compute(state, mb) -> (loss, grads)``; constrain=False inside
-            shard_map regions where sharding constraints are illegal."""
+            shard_map regions where sharding constraints are illegal.
+            With ``gradient_accumulation_steps`` 1 there is nothing to
+            accumulate: no float32 zero tree, no scan, no ``/ gas`` — the
+            one microbatch's gradients go to the update as ``compute``
+            hands them. Otherwise the float32 accumulator is the scan's
+            carry, and feeds the same update."""
             def gas_grads(state: TrainState, batch: dict):
+                if gas == 1:
+                    loss, grads = compute(
+                        state, jax.tree.map(lambda x: x[0], batch))
+                    return loss.astype(jnp.float32), grads
+
                 def micro(carry, mb):
                     loss_sum, grad_acc = carry
                     loss, grads = compute(state, mb)

@@ -403,11 +403,12 @@ def _kernel_calls(hlo_text):
         hlo_text)]
 
 
-def _mistral_grad_step(devs, layers, remat_policy):
-    """Loss and gradients of ``layers`` Mistral-7B blocks (the benchmark's
-    train cell: 2 rows x 2048 a chip, bf16, every block rematted) on mesh
-    ``{fsdp: 4}`` of the described chips, parameters abstract and sharded
-    over ``fsdp`` on their first dimension."""
+def _mistral_cell(devs, layers, remat_policy):
+    """``layers`` Mistral-7B blocks as the benchmark's train cell runs them
+    (2 rows x 2048 a chip, bf16, every block rematted) on mesh
+    ``{fsdp: 4}`` of the described chips. Returns the topology, the abstract
+    parameters still in their flax boxes, the abstract ``input_ids`` and
+    ``grad_step(params, ids) -> (loss, grads)``."""
     import flax.linen as nn
 
     from deepspeed_tpu.models import build_model
@@ -421,17 +422,24 @@ def _mistral_grad_step(devs, layers, remat_policy):
                         num_layers=layers, max_seq_len=2048, remat=True,
                         remat_policy=remat_policy)
     ids = _sds(NamedSharding(topo.mesh, P(BATCH_AXES)), (8, 2048), jnp.int32)
-    params = jax.tree.map(
-        lambda b: _sds(NamedSharding(topo.mesh, P("fsdp")), b.value.shape,
-                       BF16),
-        jax.eval_shape(model.init, jax.random.PRNGKey(0), ids)["params"],
-        is_leaf=lambda l: hasattr(l, "names"))
+    boxed = jax.eval_shape(model.init, jax.random.PRNGKey(0), ids)["params"]
 
     def grad_step(p, ids):
         with nn.logical_axis_rules(default_activation_rules(topo)), \
                 model_mesh_scope(topo.mesh):
             return jax.value_and_grad(
                 lambda p: lm_loss_fn(model, p, {"input_ids": ids}))(p)
+    return topo, boxed, ids, grad_step
+
+
+def _mistral_grad_step(devs, layers, remat_policy):
+    """Loss and gradients of the cell's blocks, compiled: parameters
+    abstract and sharded over ``fsdp`` on their first dimension."""
+    topo, boxed, ids, grad_step = _mistral_cell(devs, layers, remat_policy)
+    params = jax.tree.map(
+        lambda b: _sds(NamedSharding(topo.mesh, P("fsdp")), b.value.shape,
+                       BF16),
+        boxed, is_leaf=lambda l: hasattr(l, "names"))
     return jax.jit(grad_step).lower(params, ids).compile()
 
 
@@ -456,6 +464,157 @@ def test_first_remat_rung_keeps_the_kernel_count_and_drops_matmuls(topo):
     # q, k, v, the output projection, gate and up: not made again
     assert bottom[1] - top[1] >= 2 * layers
     assert top[2] > bottom[2]
+
+
+def _engine_tail(topo, boxed):
+    """What ``DeepSpeedEngine._apply_grads`` reads of an engine, for the
+    benchmark's train cell: its config (bf16, AdamW, ZeRO-3: the sentinel
+    guards the step), optimizer, constant lr and the plan of these
+    parameters — so that the engine's OWN method is what compiles (an
+    engine places real arrays; a described chip holds none). Returns the
+    stand-in and the abstract ``TrainState``."""
+    import json
+    import types
+    from pathlib import Path
+
+    from deepspeed_tpu.config import Config
+    from deepspeed_tpu.ops.optimizers import OptState, build_optimizer
+    from deepspeed_tpu.runtime.engine import TrainState
+    from deepspeed_tpu.runtime.lr_schedules import constant_lr
+    from deepspeed_tpu.runtime.zero.planner import build_plan
+
+    cell = json.loads((Path(__file__).parent.parent / "benchmark" / "configs"
+                       / "mistral-7b-v0.3-train-l8.json").read_text())
+    cfg = Config.from_dict(cell["deepspeed"])
+    assert cfg.resilience.sentinel and not cfg.gradient_clipping
+    plan = build_plan(topo, cfg.zero_optimization, boxed)
+    engine = types.SimpleNamespace(
+        config=cfg, plan=plan, mixed_precision=True, compute_dtype=BF16,
+        optimizer=build_optimizer(cfg.optimizer.type, cfg.optimizer.params),
+        lr_schedule=constant_lr(cfg.optimizer.params["lr"]))
+
+    def tree(shardings, dtype):
+        return jax.tree.map(lambda b, s: _sds(s, b.value.shape, dtype), boxed,
+                            shardings, is_leaf=lambda l: hasattr(l, "names"))
+    scalar = _sds(NamedSharding(topo.mesh, P()), (), jnp.int32)
+    f32 = lambda: tree(plan.master_shardings, jnp.float32)
+    state = TrainState(params=tree(plan.param_shardings, BF16), master=f32(),
+                       opt_state=OptState(step=scalar, mu=f32(), nu=f32()),
+                       scaler=None, global_step=scalar)
+    return engine, state
+
+
+def _entry_instructions(hlo_text):
+    """(name, result type, opcode) of the entry computation's own
+    instructions — what runs at top level, a fusion counted once."""
+    import re
+
+    entry = hlo_text[hlo_text.index("\nENTRY "):]
+    return re.findall(r"^\s+(?:ROOT )?%?([\w.\-]+) = (.+?) ([\w\-]+)\(",
+                      entry, re.M)
+
+
+def _shapes_of(result_type):
+    """Every ``dtype[dims]`` of a result type, a tuple's in order:
+    ``bf16[1024,14336]{1,0:T(8,128)(2,1)}`` -> ``[("bf16", (1024, 14336))]``."""
+    import re
+
+    return [(dtype, tuple(int(d) for d in dims.split(",") if d))
+            for dtype, dims in re.findall(r"(\w+)\[([\d,]*)\]", result_type)]
+
+
+def test_train_step_tail_is_one_fused_pass_a_leaf(topo):
+    """The tail of the train step — everything after the last gradient
+    reduction — compiled for the described v5e:2x2 through the engine's own
+    ``_apply_grads``: NO ``conditional`` (the skip is a select inside the
+    update; a conditional's operands and results are buffers), NO top-level
+    ``convert`` of a parameter shard's shape (neither the gradients'
+    bf16 -> f32 nor the new master's f32 -> bf16 is a pass of its own),
+    ONE fusion a leaf whose outputs are the new params (bf16), master and
+    both moments (f32), and no float32 shard copied from one layout to
+    another. Then the tail alone, against the parent's form
+    written out (float32 gradients into a ``lax.cond`` round the update, the
+    cast back after it): the float32 gradient shards are no temporaries any
+    more."""
+    from deepspeed_tpu.runtime import fp16 as fp16_mod
+    from deepspeed_tpu.runtime.engine import DeepSpeedEngine, _cast_tree
+
+    layers = 2
+    cell_topo, boxed, ids, grad_step = _mistral_cell(
+        topo.devices, layers, "save_matmul_products")
+    engine, state = _engine_tail(cell_topo, boxed)
+    # the master's shards: a leaf too small to shard its params (a norm's
+    # scale) still updates a quarter of itself on each chip
+    shard_shapes = {s.sharding.shard_shape(s.shape)
+                    for s in jax.tree.leaves(state.master)}
+    n_leaves = len(jax.tree.leaves(state.params))
+    shardings = jax.tree.map(lambda s: s.sharding, state)
+    repl = NamedSharding(cell_topo.mesh, P())
+
+    def tail(state, grads, loss_finite):
+        # the gradients as ``engine._compute_grads`` hands them on
+        grads = DeepSpeedEngine._constrain_grads(engine, grads)
+        return DeepSpeedEngine._apply_grads(
+            engine, state, _cast_tree(grads, jnp.float32), loss_finite)
+
+    def train_step(state, ids):
+        loss, grads = grad_step(state.params, ids)
+        new_state, finite = tail(state, grads, jnp.isfinite(loss))
+        return new_state, (loss, finite)
+
+    compiled = jax.jit(train_step, out_shardings=(shardings, (repl, repl)),
+                       donate_argnums=(0,)).lower(state, ids).compile()
+    top = _entry_instructions(compiled.as_text())
+    assert top and not [n for n, _, op in top if op == "conditional"]
+    converts = [(n, t) for n, t, op in top if op == "convert"
+                and _shapes_of(t)[0][1] in shard_shapes]
+    assert not converts, converts
+
+    def is_update(result_type):
+        dtypes, shapes = zip(*_shapes_of(result_type) or [((), ())])
+        return dtypes == ("bf16", "f32", "f32", "f32") \
+            and len(set(shapes)) == 1 and shapes[0] in shard_shapes
+    updates = [t for _, t, op in top if op == "fusion" and is_update(t)]
+    assert len(updates) == n_leaves, (len(updates), n_leaves)
+    # ... written in the state's layout: no float32 shard is copied to
+    # another one (``wq``'s gradient leaves its matmul D-major, and an
+    # update that took that layout copied master and both moments back)
+    relaid = [(n, t) for n, t, op in top if op == "copy"
+              and _shapes_of(t)[0][0] == "f32"
+              and _shapes_of(t)[0][1] in shard_shapes]
+    assert not relaid, relaid
+
+    # the tail alone: this PR's against the parent's arithmetic
+    def parent_tail(state, grads, loss_finite):
+        grads = _cast_tree(grads, jnp.float32)
+        grads = jax.lax.with_sharding_constraint(
+            grads, engine.plan.grad_shardings)
+        lr = engine.lr_schedule(state.opt_state.step)
+        finite = fp16_mod.grads_finite(grads) & loss_finite
+        new_master, new_opt = jax.lax.cond(
+            finite,
+            lambda op: engine.optimizer.update(grads, op[1], op[0], lr=lr),
+            lambda op: op, (state.master, state.opt_state))
+        return state._replace(
+            params=_cast_tree(new_master, BF16), master=new_master,
+            opt_state=new_opt, global_step=state.global_step + 1), finite
+
+    flag = _sds(repl, (), jnp.bool_)
+    temps = {}
+    for name, fn in (("change", tail), ("parent", parent_tail)):
+        alone = jax.jit(fn, out_shardings=(shardings, repl),
+                        donate_argnums=(0,)).lower(
+            state, state.params, flag).compile()
+        temps[name] = alone.memory_analysis().temp_size_in_bytes
+        has_cond = " conditional(" in alone.as_text()
+        assert has_cond == (name == "parent")
+    print("tail temporaries, bytes:", temps)
+    f32_shards = sum(4 * int(np.prod(s.sharding.shard_shape(s.shape)))
+                     for s in jax.tree.leaves(state.master))
+    # the parent holds float32 gradient shards between its passes (not all
+    # at once: the scheduler casts a leaf near its use); the fused tail none
+    assert temps["parent"] > f32_shards // 2
+    assert temps["change"] < f32_shards // 100
 
 
 def test_paged_kernel_scalar_prefetch_footprint():

@@ -296,6 +296,117 @@ def test_imperative_step_sentinel_observes():
         eng2.step()
 
 
+def _exact_batch(step, B):
+    """Small integers only, so that every sum the loss and its gradient
+    take is exact in bf16, fp16 and float32 in ANY order — the sharded
+    program and the test's plain arithmetic then agree bit for bit."""
+    rng = np.random.default_rng(77 + step)
+    return {"x": rng.integers(-1, 2, (B, W_DIM)).astype(np.float32),
+            "y": rng.integers(-2, 3, (B,)).astype(np.float32)}
+
+
+def _bits(tree):
+    import jax
+
+    return [np.asarray(l).tobytes() for l in jax.tree.leaves(tree)]
+
+
+GUARDS = {
+    "bf16-sentinel": {"bf16": {"enabled": True}},
+    "fp16-scaler": {"fp16": {"enabled": True, "initial_scale_power": 8},
+                    "bf16": {"enabled": False}},
+    "fp32-sentinel": {"bf16": {"enabled": False}},
+}
+
+
+@pytest.mark.parametrize("clip", [0.0, 1.0], ids=["noclip", "clip"])
+@pytest.mark.parametrize("gas", [1, 2], ids=["gas1", "gas2"])
+@pytest.mark.parametrize("guard", list(GUARDS))
+def test_guarded_step_updates_exactly_and_skips_bit_for_bit(guard, gas, clip):
+    """The fused step's tail (``engine._apply_grads``): the skip is a select
+    inside the update, the gradients arrive in the backward's dtype when
+    there is nothing to accumulate. On a finite batch the new state equals
+    the arithmetic written out here — cast to float32, unscale, accumulate
+    from zero, ``/ gas``, clip by the global norm, ``optimizer.update``, cast
+    back — EXACTLY; with the fault rail's ``nan_scale`` armed, params,
+    master, moments and the optimizer's step are bit for bit the old state,
+    ``finite`` is False, ``global_step`` advanced and ``skipped_steps``
+    counts it; the next finite step trains on."""
+    import jax
+    import jax.numpy as jnp
+
+    eng = tiny_engine(resilience={"fault_injection": {"nan_grads_step": 1},
+                                  "max_consecutive_bad": 3},
+                      gradient_accumulation_steps=gas,
+                      gradient_clipping=clip, **GUARDS[guard])
+    flags = []
+    observe = eng.resilience.observe_step
+    eng.resilience.observe_step = lambda loss, finite: (
+        flags.append(bool(finite)), observe(loss, finite))[1]
+    B = eng.config.train_batch_size
+    assert B == 16 * gas
+    scale = eng.get_loss_scale()
+    assert scale == (256.0 if guard == "fp16-scaler" else 1.0)
+    mixed = guard != "fp32-sentinel"
+    old = jax.device_get(eng.state)
+    assert (old.master is not None) == mixed
+
+    @jax.jit
+    def expected(params, master, opt_state, batch):
+        acc = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
+        for mb in range(gas):
+            micro = {k: v[mb * 16:(mb + 1) * 16] for k, v in batch.items()}
+            g = jax.grad(lambda p: _loss_fn(p, micro) * scale)(params)
+            acc = jax.tree.map(
+                lambda a, g: a + g.astype(jnp.float32) / scale, acc, g)
+        grads = jax.tree.map(lambda a: a / gas, acc)
+        if clip:
+            norm = jnp.sqrt(sum(jnp.sum(jnp.square(g))
+                                for g in jax.tree.leaves(grads)))
+            factor = jnp.minimum(1.0, clip / (norm + 1e-6))
+            grads = jax.tree.map(lambda g: g * factor, grads)
+        new_master, new_opt = eng.optimizer.update(
+            grads, opt_state, master,
+            lr=eng.lr_schedule(opt_state.step))
+        return jax.tree.map(lambda m, p: m.astype(p.dtype), new_master,
+                            params), new_master, new_opt
+
+    want_p, want_m, want_o = expected(
+        old.params, old.master if mixed else old.params, old.opt_state,
+        _exact_batch(0, B))
+
+    # step 0, finite: the update, exactly
+    assert np.isfinite(float(eng.train_batch(_exact_batch(0, B))))
+    got = jax.device_get(eng.state)
+    assert got.params["w"].dtype == {"bf16-sentinel": jnp.bfloat16,
+                                     "fp16-scaler": jnp.float16,
+                                     "fp32-sentinel": jnp.float32}[guard]
+    assert np.any(np.asarray(got.params["w"], np.float32) != 0)
+    assert _bits(got.params) == _bits(want_p)
+    if mixed:
+        assert _bits(got.master) == _bits(want_m)
+    assert _bits(got.opt_state) == _bits(want_o)
+    assert int(got.opt_state.step) == 1 and flags == [True]
+
+    # step 1, poisoned: nothing but global_step (and the scaler) moves
+    assert np.isnan(float(eng.train_batch(_exact_batch(1, B))))
+    skipped = jax.device_get(eng.state)
+    for part in ("params", "master", "opt_state"):
+        assert _bits(getattr(skipped, part)) == _bits(getattr(got, part)), part
+    assert flags == [True, False]
+    assert int(skipped.global_step) == eng.global_steps == 2
+    assert eng.skipped_steps == 1
+    assert eng.get_loss_scale() == scale         # hysteresis 2: not yet halved
+    if guard != "fp16-scaler":  # an fp16 overflow is the scaler's, not a bad step
+        assert eng.resilience_counters["skipped_steps"] == 1
+
+    # step 2, finite again: training goes on from the untouched state
+    assert np.isfinite(float(eng.train_batch(_exact_batch(2, B))))
+    assert flags == [True, False, True]
+    assert int(eng.state.opt_state.step) == 2 and eng.skipped_steps == 1
+    assert _bits(jax.device_get(eng.state.params)) != _bits(got.params)
+
+
 def test_divergence_abort_without_checkpoint():
     eng = tiny_engine(resilience={"fault_injection": {"nan_grads_step": 1},
                                   "max_consecutive_bad": 1})
