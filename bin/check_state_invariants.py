@@ -58,9 +58,12 @@ STATE_FILE = "deepspeed_tpu/inference/ragged.py"
 
 #: (rule, function name) pairs allowed inside STATE_FILE
 ALLOWED = {
+    #: _reserve_more/_free_more: the tables of a model's further kinds of
+    #: layer (a window kind's ring beside the primary's growing table),
+    #: reserved in every kind or in none and freed with the sequence
     "allocator": {"_alloc", "release", "migrate_in_begin",
                   "import_commit", "abort_import", "adopt_prefix",
-                  "flush_prefix_cache"},
+                  "flush_prefix_cache", "_reserve_more", "_free_more"},
     #: snapshot_prefix/release_prefix/adopt_prefix are the cross-replica
     #: radix-pull surface (placement-time distributed cache): the export
     #: leg's gather-scoped pin and the import leg's unreferenced adopt

@@ -45,14 +45,17 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.transformer import (
     _ACTS,
+    GLU_ACTS,
     DenseFFN,
     ModelConfig,
     Norm,
     TransformerLM,
     apply_rope,
+    cache_kind,
     default_activation_rules,
     dense_ffn_config,
     is_moe_layer,
+    kind_ropes,
     qk_norm,
 )
 from ..parallel.tensor import (_ring_rs_core, allgather_matmul,
@@ -136,6 +139,80 @@ def scan_layer_stack(stacked: Pytree, x, apply_layer, per_layer=None):
 
     return jax.lax.scan(body, x,
                         (jnp.arange(L, dtype=jnp.int32), per_layer))
+
+
+def scan_layer_periods(stacked: Pytree, x, apply_layer, period: int,
+                       per_layer=None):
+    """:func:`scan_layer_stack` for a stack whose layers come in PERIODS of
+    ``period`` kinds: one scan step walks a whole period, so the body knows
+    each layer's place ``j`` in the period — its kind — statically, and
+    still slices layer ``li``'s weights out of the ``[L, ...]`` stack in
+    place. ``apply_layer(x, p, li, per_layer[j][pi], j) -> (x, ys)``;
+    ``per_layer`` is None or one pytree a place, each with a leading axis
+    of ``L // period``. Returns ``(x, ys)`` with ``ys`` a tuple over ``j``
+    of that place's outputs stacked over the periods."""
+    L = jax.tree.leaves(stacked)[0].shape[0]
+
+    def body(xc, inp):
+        pi, extra = inp
+        ys = []
+        for j in range(period):
+            li = pi * period + j
+            with device_scope("weight_walk"):
+                p = jax.tree.map(
+                    lambda s: jax.lax.dynamic_index_in_dim(
+                        s, li, 0, keepdims=False), stacked)
+            xc, y = apply_layer(xc, p, li,
+                                None if extra is None else extra[j], j)
+            ys.append(y)
+        return xc, tuple(ys)
+
+    return jax.lax.scan(
+        body, x, (jnp.arange(L // period, dtype=jnp.int32), per_layer))
+
+
+@dataclass(frozen=True)
+class CacheKind:
+    """One kind of layer's KV cache as the engine holds it: which layers
+    write it, the mask they attend under, and the geometry of a sequence's
+    block table in its pool (``StateManager.kinds`` holds the allocator).
+    ``ring_tokens`` > 0: the table is a ring of that many token slots,
+    reused in place (a window kind narrower than a whole context)."""
+    name: str                      # "full" | "window"
+    layers: tuple[int, ...]        # the model's layers of this kind
+    window: int | None             # sliding-window mask (None: full)
+    max_blocks: int                # block-table width of a sequence
+    ring_tokens: int               # 0 = a table that grows
+    num_blocks: int                # blocks of its pool
+
+
+def cache_kinds(m: ModelConfig, cfg: "RaggedInferenceConfig"
+                ) -> tuple[CacheKind, ...]:
+    """The caches a model's layers need, the PRIMARY first ("full" where
+    the model has full layers). A window kind keeps a ring of
+    ceil((W + step) / block) + 1 blocks a sequence where that is narrower
+    than a whole context — the mistral rolling buffer: only the last window
+    (+ the step being written) stays resident. The primary's pool is
+    ``num_blocks``; a further kind's is every slot's whole ring
+    (``max_seqs`` x ring + the trash block), so it never refuses."""
+    bs = cfg.block_size
+    whole = -(-cfg.max_seq_len // bs)
+    of = [cache_kind(m.layer_kind(i)) for i in range(m.num_layers)]
+    names = sorted(set(of))                     # "full" < "window"
+    out = []
+    for name in names:
+        width, ring, W = whole, 0, None
+        if name == "window":
+            W = m.sliding_window
+            step_max = max(cfg.chunk, max(cfg.decode_window, 1))
+            nwin = -(-(W + step_max) // bs) + 1
+            if nwin < whole or len(names) > 1:
+                width = min(nwin, whole)
+                ring = width * bs
+        out.append(CacheKind(
+            name, tuple(i for i, k in enumerate(of) if k == name), W, width,
+            ring, cfg.num_blocks if not out else cfg.max_seqs * width + 1))
+    return tuple(out)
 
 
 class WeightSwapError(RuntimeError):
@@ -385,26 +462,24 @@ class InferenceEngineV2:
         self.topology = topology
         self._rules = default_activation_rules(topology)
 
-        max_blocks_per_seq = -(-cfg.max_seq_len // cfg.block_size)
-        # mistral rolling KV buffer: a sliding-window model only ever needs
-        # the last window (+ the step being written) resident, so the block
-        # table shrinks to a ring of nwin slots and long sequences stop
-        # pinning whole-context KV (reference mistral rolling cache)
-        self._ring_tokens = 0
-        W = model.config.sliding_window
-        if W and W < cfg.max_seq_len:
-            step_max = max(cfg.chunk, max(cfg.decode_window, 1))
-            nwin = -(-(W + step_max) // cfg.block_size) + 1
-            if nwin < max_blocks_per_seq:
-                max_blocks_per_seq = nwin
-                self._ring_tokens = nwin * cfg.block_size
-        self.state = StateManager(cfg.num_blocks, cfg.block_size, cfg.max_seqs,
-                                  max_blocks_per_seq)
-        # packing is off in ring mode: the rolling-buffer table is sized
-        # for chunk-at-most steps, and a grown chunk would overrun it
+        # one KV cache (allocator, pool, block table a sequence) for each
+        # KIND of layer the model has: a table that grows for full layers,
+        # a bounded ring for window layers (``cache_kinds``)
+        self._kinds = cache_kinds(model.config, cfg)
+        k0 = self._kinds[0]
+        self.state = StateManager(
+            k0.num_blocks, cfg.block_size, cfg.max_seqs, k0.max_blocks,
+            kind=k0.name, ring=bool(k0.ring_tokens),
+            more_kinds={k.name: (k.num_blocks, k.max_blocks,
+                                 bool(k.ring_tokens))
+                        for k in self._kinds[1:]})
+        has_ring = self.state.has_ring
+        # where a kind keeps a ring, plans pack ROWS only: the rolling table
+        # is sized for chunk-at-most steps, and a grown chunk would overrun
+        # it
         self.scheduler = SplitFuseScheduler(
-            self.state, cfg.chunk,
-            pack=cfg.prefill_pack and not self._ring_tokens)
+            self.state, cfg.chunk, pack=cfg.prefill_pack,
+            grow_chunk=not has_ring)
 
         # --- shared-prefix KV cache (radix reuse over the pool) ----------
         use_pc = cfg.prefix_cache
@@ -415,8 +490,8 @@ class InferenceEngineV2:
             # suffix-divergence parity test (tests/test_inference_v2.py::
             # test_v2_fp8_kv_prefix_cache_cross_request_parity) pins warm
             # == cold greedy streams at e4m3 granularity
-            use_pc = self.scheduler.pack and not self._ring_tokens
-        if use_pc and self._ring_tokens:
+            use_pc = self.scheduler.pack and not has_ring
+        if use_pc and has_ring:
             raise ValueError(
                 "prefix_cache=True cannot combine with a sliding-window "
                 "rolling KV ring: ring tables reuse page slots in place, "
@@ -564,10 +639,23 @@ class InferenceEngineV2:
         self._kv_dtype = jnp.float8_e4m3fn \
             if cfg.kv_cache_dtype == "fp8" else cfg.dtype
         self._guard_pinned_layout_against_cache()
-        self.kv_pool = jax.device_put(
-            jnp.zeros((m.num_layers, 2, m.kv_heads, cfg.num_blocks,
+        # one pool a kind: ``kv_pool`` is the array itself for a model of
+        # one kind, a tuple of arrays (``self._kinds``' order) for more
+        pools = tuple(jax.device_put(
+            jnp.zeros((len(k.layers), 2, m.kv_heads, k.num_blocks,
                        cfg.block_size, m.head_dim),
                       self._kv_dtype), self._pool_format)
+            for k in self._kinds)
+        self.kv_pool = pools[0] if len(pools) == 1 else pools
+        #: a jitted program's sharding of its ``kv_pool`` argument
+        self._pool_formats = self._pool_format if len(pools) == 1 \
+            else (self._pool_format,) * len(pools)
+        logger.info("cache: " + "; ".join(
+            f"{k.name}: {len(k.layers)} layer(s), pool {k.num_blocks} "
+            f"blocks of {cfg.block_size}, table {k.max_blocks} a sequence"
+            + (f" (a ring of {k.ring_tokens} tokens, window {k.window})"
+               if k.ring_tokens else " (grows with the context)")
+            for k in self._kinds))
 
         # alibi needs a positional bias inside the kernel — XLA path only.
         # pallas_call has no GSPMD rule, so multi-device meshes run the
@@ -789,7 +877,20 @@ class InferenceEngineV2:
                       # the paged kernel's grid steps that read a page
                       # against the slots x table-width rectangle around
                       # them (``_count_attn_steps``, host arithmetic too)
-                      "attn_steps_live": 0, "attn_steps_rect": 0}
+                      "attn_steps_live": 0, "attn_steps_rect": 0,
+                      # a ring slot overwritten in place (window kinds)
+                      "ring_blocks_reused": 0,
+                      # pages a full table would have walked in window
+                      # layers, and those of them the window kind did not
+                      "attn_pages_unclipped": 0, "attn_pages_clipped": 0}
+        for k in self._kinds:
+            # by kind of layer: blocks live sequences hold (sampled after
+            # every dispatch, and the run's peak), and the paged kernel's
+            # steps as above
+            self.stats.update({f"kv_blocks_live_{k.name}": 0,
+                               f"kv_blocks_peak_{k.name}": 0,
+                               f"attn_steps_live_{k.name}": 0,
+                               f"attn_steps_rect_{k.name}": 0})
         self._moe_layers = sum(is_moe_layer(m, i)
                                for i in range(m.num_layers))
         # measure the host<->device readback latency ONCE instead of
@@ -828,7 +929,8 @@ class InferenceEngineV2:
             self._spec.reqtrace = self._rt
         logger.info(
             f"engine_v2 up: blocks={cfg.num_blocks}x{cfg.block_size} "
-            f"pool={self.kv_pool.nbytes / 1e6:.0f}MB max_seqs={cfg.max_seqs} "
+            f"pool={sum(p.nbytes for p in pools) / 1e6:.0f}MB "
+            f"max_seqs={cfg.max_seqs} "
             f"chunk={cfg.chunk} tp={topology.size('tensor')}")
         # the chosen attention formulation and, for the gather fallback,
         # the reason — said once here so it is never a silent choice
@@ -884,7 +986,7 @@ class InferenceEngineV2:
         if cfg.spec_decode not in ("ngram", "draft"):
             raise ValueError(f"spec_decode must be None, 'ngram' or "
                              f"'draft', got {cfg.spec_decode!r}")
-        if self._ring_tokens:
+        if self.state.has_ring:
             raise ValueError(
                 "spec_decode cannot combine with a sliding-window rolling "
                 "KV ring: provisional verify slots past the committed tail "
@@ -1261,15 +1363,27 @@ class InferenceEngineV2:
         cfg = self.config
         S, T = token_ids.shape
         bs = cfg.block_size
-        ctx = self.state.max_blocks_per_seq * bs
         H, KV, D = m.num_heads, m.kv_heads, m.head_dim
         window_mode = kv_stage is not None
+        # everything a KIND of layer owns comes as the thing itself for a
+        # model of one kind and as a tuple (``self._kinds``' order) for
+        # more: pool, slot map, block table, staged buffers
+        kinds = self._kinds
+        many = len(kinds) > 1
+        per_kind, one = self._each_kind, self._per_kind
+        pools, slot_maps = per_kind(kv_pool), per_kind(slot_map)
+        tables = per_kind(block_tables)
+        #: the cache (an index into ``kinds``) of a layer kind
+        cache_of = {name: [k.name for k in kinds].index(cache_kind(name))
+                    for name in set(m.kinds_period)}
+        period = m.kinds_period
         tree_mode = tree_mask is not None
         q_starts = positions[:, 0]
         if stage_starts is None:
             stage_starts = q_starts
         if window_mode:
-            Ts = kv_stage[0].shape[3]
+            kbufs, vbufs = per_kind(kv_stage[0]), per_kind(kv_stage[1])
+            Ts = kbufs[0].shape[3]
         else:
             Ts = self._stage_rows(T)
 
@@ -1366,7 +1480,7 @@ class InferenceEngineV2:
             x = jax.lax.with_sharding_constraint(
                 x, NamedSharding(mesh_t, P("tensor", None, None)))
 
-        def routed_experts(ml, h, li):
+        def routed_experts(ml, h, li, h_router=None):
             """THE routed-expert layer of serving, quantised or not: router
             -> dropless top-k (every token reaches its k experts; generation
             must not drop a routed token — the FastGen v2 MoE contract) ->
@@ -1386,8 +1500,12 @@ class InferenceEngineV2:
             mo = m.moe
             Tt, E = S * T, h.shape[-1]
             flat = h.reshape(Tt, E).astype(cfg.dtype)
+            # what the router reads, where that is not what the experts
+            # read (``MoEConfig.router_input``)
+            routed = flat if h_router is None \
+                else h_router.reshape(Tt, E).astype(cfg.dtype)
             with device_scope("moe_router"):
-                logits = jnp.einsum("te,en->tn", flat.astype(jnp.float32),
+                logits = jnp.einsum("te,en->tn", routed.astype(jnp.float32),
                                     ml["gate"]["wg"].astype(jnp.float32))
                 gate = topk_dropless_gating(
                     logits[None], mo.top_k,
@@ -1414,8 +1532,8 @@ class InferenceEngineV2:
                                      else w.astype(cfg.dtype), srt, kind, bm,
                                      li=wli)
 
-                if m.activation == "silu_glu":
-                    z = jax.nn.silu(mm(buf, "w_gate", "col")) \
+                if m.activation in GLU_ACTS:
+                    z = GLU_ACTS[m.activation](mm(buf, "w_gate", "col")) \
                         * mm(buf, "w_up", "col")
                 else:
                     z = _ACTS[m.activation](mm(buf, "w_up", "col"))
@@ -1426,7 +1544,7 @@ class InferenceEngineV2:
                 mo.top_k, bm, gemm)
             return out.reshape(S, T, E).astype(cfg.dtype)
 
-        def ffn(p, h, use_moe: bool, li=None):
+        def ffn(p, h, use_moe: bool, li=None, h_router=None):
             if use_moe and rn:
                 # routing needs the full token set (gate + expert sort over
                 # all tokens): gather the token-sharded stream once and run
@@ -1436,8 +1554,11 @@ class InferenceEngineV2:
                 overlap_counters.fallback()
                 h = jax.lax.with_sharding_constraint(
                     h, NamedSharding(mesh_t, P(None, None, None)))
+                if h_router is not None:
+                    h_router = jax.lax.with_sharding_constraint(
+                        h_router, NamedSharding(mesh_t, P(None, None, None)))
             if use_moe:
-                out = routed_experts(p["moe"]["moe_layer"], h, li)
+                out = routed_experts(p["moe"]["moe_layer"], h, li, h_router)
                 se = m.moe.shared_expert_intermediate
                 if se:   # qwen2-moe sigmoid-gated shared expert
                     with device_scope("ffn"):
@@ -1468,10 +1589,10 @@ class InferenceEngineV2:
                 # intermediate size — ring only when it divides the axis
                 if isinstance(wu, QuantLinear) or wu.shape[1] % rn == 0:
                     h2 = h.reshape(S * T, -1)
-                    if m.activation == "silu_glu":
+                    if m.activation in GLU_ACTS:
                         g2, u2 = allgather_matmul(
                             h2, (fwr("w_gate"), wu), mesh_t, layer_index=li)
-                        z = jax.nn.silu(g2) * u2
+                        z = GLU_ACTS[m.activation](g2) * u2
                     else:
                         u2 = allgather_matmul(h2, wu, mesh_t, layer_index=li)
                         z = _ACTS[m.activation](
@@ -1480,7 +1601,7 @@ class InferenceEngineV2:
                         z.astype(cfg.dtype), fwr("w_down"), mesh_t,
                         layer_index=li)
                     out = y2.reshape(S, T, -1).astype(cfg.dtype)
-                    if m.activation != "silu_glu":
+                    if m.activation not in GLU_ACTS:
                         out = out + f["b_down"].astype(cfg.dtype)
                     return out
                 overlap_counters.fallback()
@@ -1495,9 +1616,9 @@ class InferenceEngineV2:
                         else qstack[f"ffn/{k}"]
 
                 h2d = h.reshape(-1, h.shape[-1])
-                if m.activation == "silu_glu":
-                    z = jax.nn.silu(self._qmm(h2d, fw("w_gate"), "w_gate",
-                                              li=li)) \
+                if m.activation in GLU_ACTS:
+                    z = GLU_ACTS[m.activation](self._qmm(
+                        h2d, fw("w_gate"), "w_gate", li=li)) \
                         * self._qmm(h2d, fw("w_up"), "w_up", li=li)
                     out = self._qmm(z.astype(cfg.dtype), fw("w_down"),
                                     "w_down", li=li)
@@ -1511,21 +1632,28 @@ class InferenceEngineV2:
                 return out.reshape(h.shape).astype(cfg.dtype)
             return DenseFFN(dense_ffn_config(m)).apply({"params": f}, h)
 
-        def attention(p, li, h, stage_l):
+        def attention(p, li, h, stage_l, kind, c, lk):
             """QKV → write into the STAGED buffer → ragged attention over
-            the read-only pool pages + the stage. Returns (o, stage_l')."""
+            the read-only pool pages + the stage. Returns (o, stage_l').
+            ``kind``: the layer's kind (static); ``c`` its cache among
+            ``kinds``; ``lk`` the layer's index inside that cache's pool."""
             a = p["attn"]
             qli = li if qstack else None
             with device_scope("attn_qkv"):
-                q, k, v = qkv(a, qli, h)
+                q, k, v = qkv(a, qli, h, kind)
             with device_scope("kv_stage"):
                 stage_l = stage(k, v, stage_l)
             with device_scope("attn_core"):
-                o = core(li, q, stage_l)
+                if many:     # window and global layers told apart, inside
+                    with (device_scope("attn_window") if kinds[c].window
+                          else device_scope("attn_full")):
+                        o = core(c, lk, q, stage_l)
+                else:
+                    o = core(c, lk, q, stage_l)
             with device_scope("attn_out"):
                 return out_proj(a, qli, o), stage_l
 
-        def qkv(a, qli, h):
+        def qkv(a, qli, h, kind):
             if rn:
                 # ONE bidirectional ring gathers the token-sharded hidden
                 # while all three projections consume each arriving shard
@@ -1556,7 +1684,7 @@ class InferenceEngineV2:
             if m.qk_norm:
                 q = qk_norm(m, q, a["q_norm"])
                 k = qk_norm(m, k, a["k_norm"])
-            if m.position_embedding == "rope":
+            if kind_ropes(m, kind):
                 q, k = apply_rope(q, k, positions, m.rope_theta, m.rotary_pct)
             return q, k, v
 
@@ -1576,16 +1704,20 @@ class InferenceEngineV2:
                 v_st = jnp.pad(v_t, pad)
             return k_st, v_st
 
-        def core(li, q, stage_l):
+        def core(c, lk, q, stage_l):
             """Ragged attention over the pool pages + the stage: the Pallas
-            kernel, or the XLA gather fallback."""
+            kernel, or the XLA gather fallback — over cache ``c``'s pool
+            and block table, at layer ``lk`` of that pool."""
             k_st, v_st = stage_l
-            # Sliding windows mask on every path; windowed models also
-            # serve from a ROLLING block table (self._ring_tokens > 0) so
-            # out-of-window KV blocks are reused instead of pinned.
-            win = m.sliding_window
-            ring = self._ring_tokens
-            li_dev = jnp.asarray(li, jnp.int32)
+            # Sliding windows mask on every path; a window kind also serves
+            # from a ROLLING block table (ring_tokens > 0) so out-of-window
+            # KV blocks are reused instead of pinned.
+            win = kinds[c].window
+            ring = kinds[c].ring_tokens
+            ro_pool, block_tables = pools[c], tables[c]
+            attn_work = attn_works[c]
+            ctx = block_tables.shape[1] * bs
+            li_dev = jnp.asarray(lk, jnp.int32)
             if sel.is_pallas:
                 # tree-verify stages ride two extra replicated operands:
                 # per-node absolute positions (root+depth) and the
@@ -1644,17 +1776,17 @@ class InferenceEngineV2:
                 scores = jnp.einsum("sthd,schd->shtc", q, K).astype(jnp.float32)
                 scores = scores / (D ** 0.5)
                 sstart = stage_starts[:, None]
-                if self._ring_tokens:
+                if ring:
                     # rolling buffer: recover each gathered offset's
                     # absolute position (same algebra as the kernel);
                     # pool-latest is the token BEFORE the stage
-                    nwin = self._ring_tokens // bs
+                    nwin = ring // bs
                     b_latest = jnp.maximum(sstart - 1, 0) // bs
                     jidx = (jnp.arange(ctx) // bs)[None, :]
                     b_j = b_latest - (b_latest - jidx) % nwin
                     raw = b_j * bs + (jnp.arange(ctx) % bs)[None, :]
                     cpos_pool = jnp.where(raw < sstart, raw,
-                                          raw - self._ring_tokens)  # [S,ctx]
+                                          raw - ring)           # [S,ctx]
                     valid_pool = cpos_pool >= 0
                 else:
                     # pages are position-ordered: context index j IS
@@ -1723,24 +1855,25 @@ class InferenceEngineV2:
             with device_scope("norm"):
                 return Norm(m).apply({"params": p_ln}, x)
 
-        def layer(x, p, li, use_moe, stage_l):
+        def layer(x, p, li, use_moe, stage_l, kind, lk):
             qli = li if qstack else None
             h_attn = norm(p["ln_attn"], x)
-            o, stage_l = attention(p, li, h_attn, stage_l)
+            o, stage_l = attention(p, li, h_attn, stage_l, kind,
+                                   cache_of[kind], lk)
             if not m.parallel_block:
                 x = x + o
             h_ffn = h_attn if m.parallel_block \
                 and m.parallel_block_norms == 1 else norm(p["ln_ffn"], x)
             if use_moe:     # its own scopes: router, dispatch, experts...
-                f = ffn(p, h_ffn, True, li)
+                f = ffn(p, h_ffn, True, li,
+                        h_attn if m.moe.router_input == "attn" else None)
             else:
                 with device_scope("ffn"):
                     f = ffn(p, h_ffn, False, qli)
             return (x + o + f if m.parallel_block else x + f), stage_l
 
-        # the pool stays read-only for the whole program: `attention`
-        # closes over this alias, never the (later re-bound) kv_pool
-        ro_pool = kv_pool
+        # the pools stay read-only for the whole program: `core` reads
+        # ``pools``, never the (later re-bound) kv_pool
         # kernel-vs-gather comes from the attention registry's static
         # per-mode selection (attn_registry.py) — the ONLY dispatch
         # decision point, pinned by check_attn_registry in
@@ -1748,35 +1881,73 @@ class InferenceEngineV2:
         sel = self._attn_tree_sel if tree_mode else self._attn_decode_sel
         # the paged kernel's steps, the same for every layer: built here,
         # outside the layer loop (`core` closes over both)
-        attn_work = ()
+        # (one list a kind of layer: a window kind's is bounded by its
+        # window, over its own table)
+        attn_works = [()] * len(kinds)
         if sel.is_pallas:
             with device_scope("attn_core"):
-                attn_work = paged_work_list(
+                attn_works = [paged_work_list(
                     seq_lens, q_starts, stage_starts, block_size=bs,
-                    max_pages=block_tables.shape[1], stage_rows=Ts,
-                    window=m.sliding_window, ring_tokens=self._ring_tokens,
-                    tree=tree_mode)
+                    max_pages=tables[c].shape[1], stage_rows=Ts,
+                    window=k.window, ring_tokens=k.ring_tokens,
+                    tree=tree_mode) for c, k in enumerate(kinds)]
         empty_stage = (jnp.zeros((S, KV, Ts, D), cfg.dtype),) * 2
-        if "layers_stacked" in params:
+        P_ = len(period)
+        if "layers_stacked" in params and not many:
             # scan over depth: ONE traced layer body regardless of L; the
             # pool never enters the carry — only the small staged KV does
             def body(xc, p, li, stage_l):
                 return layer(xc, p, li, is_moe_layer(m, 0),
-                             stage_l if window_mode else empty_stage)
+                             stage_l if window_mode else empty_stage,
+                             period[0], li)
 
             x, (k_ys, v_ys) = scan_layer_stack(
                 scanned_layers, x, body, kv_stage if window_mode else None)
+            k_ys, v_ys = (k_ys,), (v_ys,)
+        elif "layers_stacked" in params:
+            # a scan over PERIODS: place j of a period fixes the layer's
+            # kind, its cache c and its rank r among that cache's layers of
+            # the period — layer pi * P + j is layer pi * n_c + r of pool c
+            place = []
+            for j, kind in enumerate(period):
+                c = cache_of[kind]
+                place.append((c, sum(cache_of[kk] == c
+                                     for kk in period[:j])))
+            n_in = [sum(cc == c for cc, _ in place)
+                    for c in range(len(kinds))]
+            xs = None
+            if window_mode:
+                split = lambda b, c: b.reshape(-1, n_in[c], *b.shape[1:])
+                xs = [(split(kbufs[c], c)[:, r], split(vbufs[c], c)[:, r])
+                      for c, r in place]
+
+            def body(xc, p, li, stage_l, j):
+                c, r = place[j]
+                return layer(xc, p, li, is_moe_layer(m, 0),
+                             stage_l if window_mode else empty_stage,
+                             period[j], (li // P_) * n_in[c] + r)
+
+            x, ys = scan_layer_periods(scanned_layers, x, body, P_, xs)
+            k_ys, v_ys = [], []
+            for c in range(len(kinds)):
+                for out, half in ((k_ys, 0), (v_ys, 1)):
+                    y = jnp.stack([ys[j][half] for j in range(P_)
+                                   if place[j][0] == c], axis=1)
+                    out.append(y.reshape(-1, *y.shape[2:]))
         else:
-            k_list, v_list = [], []
+            lists = [([], []) for _ in kinds]
             for i in range(m.num_layers):
                 use_moe = is_moe_layer(m, i)
-                stage_l = (kv_stage[0][i], kv_stage[1][i]) if window_mode \
+                c = cache_of[m.layer_kind(i)]
+                lk = kinds[c].layers.index(i)
+                stage_l = (kbufs[c][lk], vbufs[c][lk]) if window_mode \
                     else empty_stage
                 x, stage_l = layer(x, params[f"layer_{i}"], i, use_moe,
-                                   stage_l)
-                k_list.append(stage_l[0])
-                v_list.append(stage_l[1])
-            k_ys, v_ys = jnp.stack(k_list), jnp.stack(v_list)
+                                   stage_l, m.layer_kind(i), lk)
+                lists[c][0].append(stage_l[0])
+                lists[c][1].append(stage_l[1])
+            k_ys = [jnp.stack(ks) for ks, _ in lists]
+            v_ys = [jnp.stack(vs) for _, vs in lists]
 
         def head(x):
             x = Norm(m).apply({"params": params["ln_final"]}, x)
@@ -1824,33 +1995,42 @@ class InferenceEngineV2:
             # verify mode: NO pool write here — the caller merges only
             # the accepted path's staged rows (_spec_merge_program), so
             # rejected candidates never touch the pool
-            return (k_ys, v_ys), logits.reshape(S, T, -1)
+            return (one(k_ys), one(v_ys)), logits.reshape(S, T, -1)
         if window_mode:
             # the window loop keeps accumulating into the staged buffers;
             # the caller merges them into the pool once, after the loop
-            return (k_ys, v_ys), logits
+            return (one(k_ys), one(v_ys)), logits
 
         # ---- the ONE pool write of this program -------------------------
         # every layer's fresh K/V lands at its (block, offset) slot;
         # padded tokens carry trash-block slots (block 0) by construction.
-        # DUS merges avoid the scatter layout war (see _merge_stage);
-        # ring mode and page-misaligned chunks keep the scatter.
-        L = m.num_layers
-        if T == 1:
-            kv_pool = self._merge_rows(
-                kv_pool, slot_map[:, 0],
-                k_ys[:, :, :, 0, :], v_ys[:, :, :, 0, :])
-        elif not self._ring_tokens and T % bs == 0:
-            kv_pool = self._merge_pages(kv_pool, slot_map, k_ys, v_ys, T)
-        else:
-            with device_scope("kv_commit"):
-                ks = (k_ys[:, :, :, :T, :].transpose(0, 1, 3, 2, 4)
-                      .reshape(L, S * T, KV, D))
-                vs = (v_ys[:, :, :, :T, :].transpose(0, 1, 3, 2, 4)
-                      .reshape(L, S * T, KV, D))
-            kv_pool = self._merge_stage(kv_pool, slot_map.reshape(-1),
-                                        ks, vs)
-        return kv_pool, logits
+        # DUS merges avoid the scatter layout war (see _merge_stage: at
+        # this PR's cell the scatter held a copy of the window layers'
+        # whole 1.3 GiB pool as a temporary of every prefill step);
+        # page-misaligned chunks keep the scatter.
+        merged = []
+        for k, pool, slots, kc, vc in zip(kinds, pools, slot_maps, k_ys,
+                                          v_ys):
+            L = len(k.layers)
+            if T == 1:
+                pool = self._merge_rows(
+                    pool, slots[:, 0],
+                    kc[:, :, :, 0, :], vc[:, :, :, 0, :])
+            elif T % bs == 0:
+                # (a ring too: the slot a whole page lands in held a page
+                # more than a window + a step older, dead to every query
+                # from this chunk on, and the rows past the chunk's real
+                # tokens read as that older wrap: masked by the window)
+                pool = self._merge_pages(pool, slots, kc, vc, T)
+            else:
+                with device_scope("kv_commit"):
+                    ks = (kc[:, :, :, :T, :].transpose(0, 1, 3, 2, 4)
+                          .reshape(L, S * T, KV, D))
+                    vs = (vc[:, :, :, :T, :].transpose(0, 1, 3, 2, 4)
+                          .reshape(L, S * T, KV, D))
+                pool = self._merge_stage(pool, slots.reshape(-1), ks, vs)
+            merged.append(pool)
+        return one(merged), logits
 
     def _merge_stage(self, kv_pool, flat_slots, ks, vs):
         """THE pool write: scatter staged K/V rows (``[L, N, KV, D]``,
@@ -1986,8 +2166,8 @@ class InferenceEngineV2:
             repl = NamedSharding(self.topology.mesh, P())
             self._programs[key] = register_program(jax.jit(
                 step, donate_argnums=(1, 2),
-                in_shardings=(None, self._pool_format) + (None,) * 11,
-                out_shardings=(self._pool_format, repl, repl)))
+                in_shardings=(None, self._pool_formats) + (None,) * 11,
+                out_shardings=(self._pool_formats, repl, repl)))
         return self._programs[key]
 
     def _window_program(self, W: int):
@@ -2025,26 +2205,35 @@ class InferenceEngineV2:
             def run(params, kv_pool, last_tok, tok_host, use_last, pos0,
                     lens0, block_tables, rem, eos_ids, rng):
                 S = tok_host.shape[0]
-                KV, D, L = m.kv_heads, m.head_dim, m.num_layers
+                KV, D = m.kv_heads, m.head_dim
+                kinds = self._kinds
+                # (per kind of layer, as ``_ragged_forward`` takes them)
+                per_kind, one = self._each_kind, self._per_kind
                 tok0 = jnp.where(use_last.astype(bool), last_tok, tok_host)
                 active0 = rem > 0
-                stage0 = jnp.zeros((L, S, KV, Ws, D), cfg.dtype)
+                stage0 = one([jnp.zeros((len(k.layers), S, KV, Ws, D),
+                                        cfg.dtype) for k in kinds])
                 base = pos0          # stage base position, fixed per window
 
                 def _iter(i, tok, pos, lens, rng, active, kbuf, vbuf):
                     """One fully-fused decode iteration; returns this
                     iteration's emitted tokens/slots plus the advanced
                     state."""
-                    mb = self.state.max_blocks_per_seq
-                    blk = jnp.take_along_axis(
-                        block_tables, ((pos // bs) % mb)[:, None],
-                        axis=1)[:, 0]      # ring slot (mod no-op linear)
-                    # inactive slots' staged rows merge into the trash block
-                    slot = jnp.where(active, blk * bs + pos % bs, 0)
+                    slots = []
+                    for k, table in zip(kinds, per_kind(block_tables)):
+                        blk = jnp.take_along_axis(
+                            table, ((pos // bs) % k.max_blocks)[:, None],
+                            axis=1)[:, 0]  # ring slot (mod no-op linear)
+                        # inactive slots' staged rows merge into the trash
+                        # block
+                        slots.append(jnp.where(active,
+                                               blk * bs + pos % bs, 0))
+                    slot = one(slots)
                     with nn.logical_axis_rules(self._rules):
                         (kbuf, vbuf), logits = self._ragged_forward(
                             params, kv_pool, tok[:, None], pos[:, None],
-                            slot[:, None], block_tables, lens,
+                            one([sl[:, None] for sl in slots]),
+                            block_tables, lens,
                             jnp.zeros_like(pos),
                             kv_stage=(kbuf, vbuf), stage_fill=i,
                             stage_starts=base)
@@ -2091,21 +2280,27 @@ class InferenceEngineV2:
                 # merge the WHOLE window's staged KV into the pool — the
                 # one pool write of this program (the pool stayed
                 # read-only through every iteration above)
-                with device_scope("kv_commit"):
-                    ks = (kbuf[:, :, :, :W, :].transpose(0, 3, 1, 2, 4)
-                          .reshape(L, W * S, KV, D))
-                    vs = (vbuf[:, :, :, :W, :].transpose(0, 3, 1, 2, 4)
-                          .reshape(L, W * S, KV, D))
-                    kv_pool = self._merge_rows(kv_pool, slots.reshape(-1),
-                                               ks, vs)
+                merged = []
+                for k, pool, kb, vb, sl in zip(
+                        kinds, per_kind(kv_pool), per_kind(kbuf),
+                        per_kind(vbuf), per_kind(slots)):
+                    L = len(k.layers)
+                    with device_scope("kv_commit"):
+                        ks = (kb[:, :, :, :W, :].transpose(0, 3, 1, 2, 4)
+                              .reshape(L, W * S, KV, D))
+                        vs = (vb[:, :, :, :W, :].transpose(0, 3, 1, 2, 4)
+                              .reshape(L, W * S, KV, D))
+                        merged.append(self._merge_rows(
+                            pool, sl.reshape(-1), ks, vs))
+                kv_pool = one(merged)
                 return kv_pool, tok, buf, i        # toks [W, S], iters run
 
             # non-pool outputs pinned replicated (see _program)
             repl = NamedSharding(self.topology.mesh, P())
             self._programs[key] = register_program(jax.jit(
                 run, donate_argnums=(1, 2),
-                in_shardings=(None, self._pool_format) + (None,) * 9,
-                out_shardings=(self._pool_format, repl, repl, repl)))
+                in_shardings=(None, self._pool_formats) + (None,) * 9,
+                out_shardings=(self._pool_formats, repl, repl, repl)))
         return self._programs[key]
 
     def warm_decode_windows(self, sizes: list[int] | None = None,
@@ -2129,8 +2324,8 @@ class InferenceEngineV2:
                 sizes.append(W)
                 W //= 2
         S = self.state.max_seqs
-        mb = self.state.max_blocks_per_seq
         z = lambda *s: np.zeros(s, np.int32)
+        tables = self._per_kind([z(S, k.max_blocks) for k in self._kinds])
         for W in sizes:
             if W <= 1 or (skip_existing and ("win", W) in self._programs):
                 continue
@@ -2138,7 +2333,7 @@ class InferenceEngineV2:
             self._rng, sub = jax.random.split(self._rng)
             self.kv_pool, self._last_tok, _, _ = fn(
                 self.params, self.kv_pool, self._last_tok, z(S),
-                np.zeros(S, np.uint8), z(S), z(S), z(S, mb), z(S),
+                np.zeros(S, np.uint8), z(S), z(S), tables, z(S),
                 np.full(S, -1, np.int32), sub)
         jax.block_until_ready(self.kv_pool)
 
@@ -2171,13 +2366,13 @@ class InferenceEngineV2:
 
         t0 = time.perf_counter()
         S = self.state.max_seqs
-        mb = self.state.max_blocks_per_seq
         with self._telem.span("plan", kind="window", seq=self._entry_seq):
             tok0 = np.zeros((S,), np.int32)
             use_last = np.zeros((S,), np.uint8)
             pos0 = np.zeros((S,), np.int32)
             lens0 = np.zeros((S,), np.int32)
-            tables = np.zeros((S, mb), np.int32)
+            tables = [np.zeros((S, k.max_blocks), np.int32)
+                      for k in self._kinds]
             rem = np.zeros((S,), np.int32)
             eos = np.full((S,), -1, np.int32)
             sched: dict[int, tuple[int, int]] = {}   # uid -> (slot, n sched)
@@ -2189,7 +2384,9 @@ class InferenceEngineV2:
                     tok0[sl] = s.tokens[-1]
                 pos0[sl] = s.len_sched - 1
                 lens0[sl] = s.len_sched
-                tables[sl, :len(s.blocks)] = s.blocks
+                for k, table in zip(self._kinds, tables):
+                    blocks = self.state.blocks_of(s, k.name)
+                    table[sl, :len(blocks)] = blocks
                 n = min(s.gen_remaining_sched, W)
                 rem[sl] = n
                 if s.eos_id is not None:
@@ -2205,11 +2402,12 @@ class InferenceEngineV2:
             self._rng, sub = jax.random.split(self._rng)
             self.kv_pool, self._last_tok, toks, iters = fn(
                 self.params, self.kv_pool, self._last_tok, tok0, use_last,
-                pos0, lens0, tables, rem, eos, sub)
+                pos0, lens0, self._per_kind(tables), rem, eos, sub)
         # dispatch-time speculative advance: KV for positions up to
         # len_sched-1+n-1 is now scheduled, n new samples are in flight
         for s in live:
             _, n = sched[s.uid]
+            self.state.note_written(s, s.len_sched - 1, s.len_sched - 1 + n)
             s.n_sched = s.len_sched - 1 + n
             s.n_inflight += n
         toks.copy_to_host_async()
@@ -2263,7 +2461,7 @@ class InferenceEngineV2:
             # pool NOT donated: it stays live (unchanged) for the merge
             # program that runs after the host-side acceptance walk
             self._programs[key] = register_program(jax.jit(
-                run, in_shardings=(None, self._pool_format) + (None,) * 7,
+                run, in_shardings=(None, self._pool_formats) + (None,) * 7,
                 out_shardings=(repl, repl, repl)))
         return self._programs[key]
 
@@ -2512,13 +2710,27 @@ class InferenceEngineV2:
         move where a sliding window slides or the stage outgrows a page."""
         if not self._attn_paged:
             return
-        live, rect = paged_step_counts(
-            seq_lens, starts, starts, block_size=self.config.block_size,
-            max_pages=self.state.max_blocks_per_seq, stage_rows=stage_rows,
-            window=self.mcfg.sliding_window, ring_tokens=self._ring_tokens)
-        n = iters * self.mcfg.num_layers
-        self.stats["attn_steps_live"] += n * live
-        self.stats["attn_steps_rect"] += n * rect
+        bs = self.config.block_size
+        st = self.stats
+        for k in self._kinds:
+            live, rect = paged_step_counts(
+                seq_lens, starts, starts, block_size=bs,
+                max_pages=k.max_blocks, stage_rows=stage_rows,
+                window=k.window, ring_tokens=k.ring_tokens)
+            n = iters * len(k.layers)
+            st["attn_steps_live"] += n * live
+            st["attn_steps_rect"] += n * rect
+            st[f"attn_steps_live_{k.name}"] += n * live
+            st[f"attn_steps_rect_{k.name}"] += n * rect
+            if k.window:
+                # pool pages a table that grew with the context would have
+                # walked for the same rows, against what the window left
+                whole = -(-self.config.max_seq_len // bs)
+                full, _ = paged_step_counts(
+                    seq_lens, starts, starts, block_size=bs,
+                    max_pages=whole, stage_rows=stage_rows)
+                st["attn_pages_unclipped"] += n * full
+                st["attn_pages_clipped"] += n * (full - live)
 
     def _dispatch_next(self) -> bool:
         """Dispatch the next scheduled step without blocking. Returns True
@@ -2548,7 +2760,7 @@ class InferenceEngineV2:
             return False
         self._serve_toggle = plan.kind == "prefill"
         T, bs = plan.token_ids.shape[1], self.config.block_size
-        if T > 1 and not self._ring_tokens and T % bs == 0:
+        if T > 1 and T % bs == 0:
             # page-merge invariant (advisor r04): the compiled program
             # whole-page-writes any row carrying >1 real token, assuming
             # its chunk starts page-aligned. The scheduler advances
@@ -2568,10 +2780,13 @@ class InferenceEngineV2:
                               seq=self._entry_seq):
             fn = self._program(T, plan.token_ids.shape[0])
             self._rng, sub = jax.random.split(self._rng)
+            more = [plan.more[k.name] for k in self._kinds[1:]]
             self.kv_pool, self._last_tok, toks = fn(
                 self.params, self.kv_pool, self._last_tok,
-                plan.token_ids, plan.positions, plan.slot_map,
-                plan.block_tables, plan.seq_lens, plan.sample_idx,
+                plan.token_ids, plan.positions,
+                self._per_kind([plan.slot_map] + [a for a, _ in more]),
+                self._per_kind([plan.block_tables] + [b for _, b in more]),
+                plan.seq_lens, plan.sample_idx,
                 plan.do_sample, plan.use_last, plan.row_slots, sub)
         self.scheduler.mark_dispatched(plan)
         toks.copy_to_host_async()
@@ -2596,11 +2811,27 @@ class InferenceEngineV2:
                 plan.uids)
         return True
 
+    def _per_kind(self, per_kind):
+        """What a program takes for each kind of layer, from one value a
+        kind: the thing itself for a model of one kind, a tuple for more."""
+        return per_kind[0] if len(self._kinds) == 1 else tuple(per_kind)
+
+    def _each_kind(self, value) -> tuple:
+        """The inverse: one value a kind, in ``self._kinds``' order."""
+        return (value,) if len(self._kinds) == 1 else tuple(value)
+
     def _enqueue(self, entry: dict) -> None:
         """Append a dispatched entry to the pipeline under a number of its
         own (the one its ``dispatch`` span already carries) and book the
         depth of the pipeline it joins: how far the host runs ahead of the
-        device when it dispatches."""
+        device when it dispatches; and, by kind of layer, the KV blocks
+        live sequences hold now."""
+        for name, n in self.state.sample().items():
+            self.stats[f"kv_blocks_live_{name}"] = n
+            self.stats[f"kv_blocks_peak_{name}"] = \
+                self.state.kinds[name].blocks_peak
+        self.stats["ring_blocks_reused"] = sum(
+            k.blocks_reused for k in self.state.kinds.values())
         depth = len(self._inflight)
         entry["seq"], entry["depth"] = self._entry_seq, depth
         self._entry_seq += 1
@@ -2890,7 +3121,7 @@ class InferenceEngineV2:
     def can_import(self, n_tokens: int, remaining_gen: int) -> bool:
         """Would ``import_reserve`` succeed right now? (The serving
         replica's admission check before it acks a migration begin.)"""
-        if self._ring_tokens:
+        if self.state.has_ring:
             return False
         return self.state.can_admit(n_tokens, remaining_gen)
 
@@ -2905,7 +3136,7 @@ class InferenceEngineV2:
         from .migration import PageBundle
         from .prefix_cache import chain_hashes
 
-        if self._ring_tokens:
+        if self.state.has_ring:
             raise RuntimeError(
                 "page migration requires linear block tables "
                 "(rolling-ring mode reuses page slots in place)")
@@ -2986,7 +3217,7 @@ class InferenceEngineV2:
         from .migration import MigrationError, PageBundle
 
         shell = PageBundle.from_meta(meta)
-        if self._ring_tokens:
+        if self.state.has_ring:
             raise MigrationError("rolling-ring pools cannot import "
                                  "page chains")
         if shell.block_size != self.config.block_size:
@@ -3094,7 +3325,7 @@ class InferenceEngineV2:
         fallback and the puller recomputes)."""
         from .migration import MigrationError, PageBundle
 
-        if self._prefix_cache is None or self._ring_tokens:
+        if self._prefix_cache is None or self.state.has_ring:
             raise MigrationError("no shareable prefix cache on this pool")
         snap = self.state.snapshot_prefix(tokens, trace=trace_id or None)
         if snap is None:
@@ -3142,7 +3373,7 @@ class InferenceEngineV2:
                 f"version_skew: chain computed under "
                 f"{bundle.weight_version}, pool serves "
                 f"{self._weight_version}")
-        if self._prefix_cache is None or self._ring_tokens:
+        if self._prefix_cache is None or self.state.has_ring:
             raise MigrationError("no shareable prefix cache on this pool")
         if bundle.block_size != self.config.block_size:
             raise MigrationError(

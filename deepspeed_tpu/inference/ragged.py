@@ -48,6 +48,25 @@ class BlockedAllocator:
 
 
 @dataclass
+class KindCache:
+    """The KV cache of ONE kind of layer: its own block allocator (its own
+    pool on the device) and the width of a sequence's block table there.
+    "full": the table grows with the context. "window": a ring of
+    ``max_blocks_per_seq`` slots — absolute position ``p`` lives in slot
+    ``(p // block_size) % max_blocks_per_seq``, so a sequence never holds
+    more than its ring (``ring`` says whether the table is narrower than a
+    whole context, i.e. whether slots are ever reused in place)."""
+    name: str
+    allocator: BlockedAllocator
+    max_blocks_per_seq: int
+    ring: bool = False
+    #: ring slots overwritten in place (a page past the window reused)
+    blocks_reused: int = 0
+    #: most blocks live sequences held at once (``StateManager.sample``)
+    blocks_peak: int = 0
+
+
+@dataclass
 class SequenceDescriptor:
     """Per-uid state (reference sequence_descriptor.py DSSequenceDescriptor).
 
@@ -73,7 +92,11 @@ class SequenceDescriptor:
     tokens: list[int]                 # full token history (prompt + generated)
     slot: int = -1                    # batch slot while scheduled
     n_computed: int = 0               # tokens whose KV is already in the pool
-    blocks: list[int] = field(default_factory=list)
+    blocks: list[int] = field(default_factory=list)   # the PRIMARY kind's
+    #: block tables of the model's further kinds of layer (kind -> blocks):
+    #: a model of window AND full layers holds its growing table in
+    #: ``blocks`` and its ring here, under "window"
+    kind_blocks: dict = field(default_factory=dict)
     max_new_tokens: int = 0
     n_generated: int = 0
     done: bool = False
@@ -191,19 +214,27 @@ class StateManager:
     dispatched-but-uncommitted steps before release runs)."""
 
     def __init__(self, num_blocks: int, block_size: int, max_seqs: int,
-                 max_blocks_per_seq: int):
-        self.allocator = BlockedAllocator(num_blocks)
+                 max_blocks_per_seq: int, kind: str = "full",
+                 ring: bool = False, more_kinds: dict | None = None):
+        """One allocator and one block table a sequence for each kind of
+        layer the model has. The PRIMARY kind is the positional arguments'
+        (``state.allocator``, ``state.max_blocks_per_seq``, ``seq.blocks``
+        — all a one-kind model has); ``more_kinds`` maps each further kind
+        to ``(num_blocks, max_blocks_per_seq, ring)`` and its tables live
+        in ``seq.kind_blocks``. Static table widths → step programs never
+        recompile. A window kind's width is the engine's ROLLING buffer
+        (ceil((window + step) / bs) + 1 slots): the physical slot of
+        absolute position p is (p // bs) % width, so a sequence never pins
+        more than one window of KV there (the mistral rolling cache). A
+        full kind's is the same formula — the mod never fires."""
         self.block_size = block_size
         self.max_seqs = max_seqs
-        # static block-table width → step programs never recompile. For
-        # sliding-window models the engine sizes this to the ROLLING
-        # buffer (ceil((window + step) / bs) + 1 slots): physical slot for
-        # absolute position p is (p // bs) % max_blocks_per_seq, so a
-        # sequence never pins more than one window of KV (the mistral
-        # rolling cache; reference mistral model impl). Linear mode is the
-        # same formula — the mod never fires because p // bs stays below
-        # the table width.
-        self.max_blocks_per_seq = max_blocks_per_seq
+        self.kinds: dict[str, KindCache] = {kind: KindCache(
+            kind, BlockedAllocator(num_blocks), max_blocks_per_seq, ring)}
+        for name, (nb, width, is_ring) in (more_kinds or {}).items():
+            self.kinds[name] = KindCache(name, BlockedAllocator(nb), width,
+                                         is_ring)
+        self.primary = kind
         self.seqs: dict[int, SequenceDescriptor] = {}
         self._free_slots = list(range(max_seqs))
         #: shared-prefix trie (attach_prefix_cache); None = no sharing
@@ -225,6 +256,78 @@ class StateManager:
         # release_prefix), counted by audit() alongside sequence shares
         self._pull_pins: dict[int, list] = {}
         self._pull_ctr = 0
+
+    @property
+    def allocator(self) -> BlockedAllocator:
+        return self.kinds[self.primary].allocator
+
+    @property
+    def max_blocks_per_seq(self) -> int:
+        return self.kinds[self.primary].max_blocks_per_seq
+
+    @property
+    def has_ring(self) -> bool:
+        """Some kind reuses page slots in place: what a ring cannot do
+        (share pages through the prefix trie, speculate past its tail,
+        export a linear page chain) is refused for the whole sequence."""
+        return any(k.ring for k in self.kinds.values())
+
+    def blocks_of(self, seq: SequenceDescriptor, kind: str) -> list[int]:
+        return seq.blocks if kind == self.primary else seq.kind_blocks[kind]
+
+    def _more_need(self, n_tokens: int) -> dict[str, int]:
+        """Blocks ``n_tokens`` of context need in each kind but the
+        primary."""
+        return {n: min(-(-n_tokens // self.block_size), k.max_blocks_per_seq)
+                for n, k in self.kinds.items() if n != self.primary}
+
+    def _reserve_more(self, seq: SequenceDescriptor, n_tokens: int,
+                      fresh: list[int]) -> None:
+        """Reserve the further kinds' tables for ``seq`` — in every kind or
+        in none: a kind that cannot give its blocks hands back what the
+        kinds before it gave AND ``fresh``, the blocks the primary kind
+        just gave, so that a refused admission leaves no kind
+        half-reserved."""
+        got: dict[str, list[int]] = {}
+        try:
+            for name, n in self._more_need(n_tokens).items():
+                got[name] = self.kinds[name].allocator.allocate(n)
+        except RuntimeError:
+            for name, blocks in got.items():
+                self.kinds[name].allocator.free(blocks)
+            if fresh:
+                self.allocator.free(fresh)
+            raise
+        seq.kind_blocks = got
+
+    def _free_more(self, seq: SequenceDescriptor) -> None:
+        for name, blocks in seq.kind_blocks.items():
+            if blocks:
+                self.kinds[name].allocator.free(blocks)
+        seq.kind_blocks = {}
+
+    def sample(self) -> dict[str, int]:
+        """Blocks live sequences hold, by kind, and each kind's running
+        peak (the engine samples after every dispatch)."""
+        live = {n: 0 for n in self.kinds}
+        for seq in self.seqs.values():
+            live[self.primary] += len(seq.blocks)
+            for n, blocks in seq.kind_blocks.items():
+                live[n] += len(blocks)
+        for n, k in self.kinds.items():
+            k.blocks_peak = max(k.blocks_peak, live[n])
+        return live
+
+    def note_written(self, seq: SequenceDescriptor, start: int,
+                     end: int) -> None:
+        """Book the ring slots that writing positions ``[start, end)``
+        overwrites in place (a page whose slot held an earlier page)."""
+        bs = self.block_size
+        for k in self.kinds.values():
+            if k.ring and end > start:
+                w = k.max_blocks_per_seq
+                first = max(-(-start // bs), w)     # pages starting in range
+                k.blocks_reused += max(0, (end - 1) // bs - first + 1)
 
     def attach_prefix_cache(self, cache) -> None:
         """Enable shared-prefix serving (engine init, linear tables only —
@@ -291,6 +394,9 @@ class StateManager:
                     > self.max_blocks_per_seq:
                 return False
             avail += self.prefix_cache.evictable_blocks
+        if any(self.kinds[n].allocator.free_blocks < k for n, k in
+               self._more_need(prompt_len + max_new_tokens).items()):
+            return False
         return bool(self._free_slots) and avail >= need
 
     def admit(self, uid: int, tokens: list[int], max_new_tokens: int,
@@ -335,7 +441,9 @@ class StateManager:
         n_need = self._blocks_for(len(tokens) + max_new_tokens)
         try:
             fresh = self._alloc(n_need - len(shared_nodes))
+            self._reserve_more(seq, len(tokens) + max_new_tokens, fresh)
         except RuntimeError:
+            # refused WHOLE: no kind is left half-reserved
             if shared_nodes:
                 self.prefix_cache.release(shared_nodes)
             self._free_slots.insert(0, seq.slot)
@@ -405,6 +513,7 @@ class StateManager:
                     self.allocator.free(to_free)
         elif seq.blocks:
             self.allocator.free(seq.blocks)
+        self._free_more(seq)
         if seq.slot >= 0:
             self._free_slots.append(seq.slot)
             self._free_slots.sort()
@@ -671,6 +780,7 @@ class StateManager:
                                  slot=self._free_slots.pop(0))
         try:
             fresh = self._alloc(self._blocks_for(len(tokens) + remaining))
+            self._reserve_more(seq, len(tokens) + remaining, fresh)
         except RuntimeError:
             self._free_slots.insert(0, seq.slot)
             raise
@@ -737,6 +847,7 @@ class StateManager:
         if seq.blocks:
             self.allocator.free(seq.blocks)
         seq.blocks = []
+        self._free_more(seq)
         if seq.slot >= 0:
             self._free_slots.append(seq.slot)
             self._free_slots.sort()
@@ -902,6 +1013,21 @@ class StateManager:
             missing = set(range(1, self.allocator.num_blocks)) - set(owners)
             raise AssertionError(f"leaked blocks (owned by nobody): "
                                  f"{sorted(missing)}")
+        # the further kinds share nothing: free list + owned tables
+        for name, k in self.kinds.items():
+            if name == self.primary:
+                continue
+            held = list(k.allocator._free)
+            for uid, seq in self.seqs.items():
+                mine = seq.kind_blocks.get(name, [])
+                if len(mine) > k.max_blocks_per_seq:
+                    raise AssertionError(
+                        f"uid {uid} holds {len(mine)} {name} blocks, more "
+                        f"than its table of {k.max_blocks_per_seq}")
+                held.extend(mine)
+            if sorted(held) != list(range(1, k.allocator.num_blocks)):
+                raise AssertionError(
+                    f"{name} pool: blocks leaked or owned twice")
 
 
 @dataclass
@@ -926,3 +1052,7 @@ class StepPlan:
     #                                   than max_seqs; row==slot when full)
     uids: list[int] = field(default_factory=list)   # uid per row (-1 = empty)
     dispatched: bool = False          # mark_dispatched ran (async pipeline)
+    #: the same two arrays for each further kind of layer (kind ->
+    #: (slot_map [S, T], block_tables [S, that kind's width])); empty for
+    #: a model of one kind
+    more: dict = field(default_factory=dict)

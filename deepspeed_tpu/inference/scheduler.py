@@ -23,9 +23,16 @@ from .ragged import SequenceDescriptor, StateManager, StepPlan
 
 
 class SplitFuseScheduler:
-    def __init__(self, state: StateManager, chunk: int, pack: bool = False):
+    def __init__(self, state: StateManager, chunk: int, pack: bool = False,
+                 grow_chunk: bool = True):
         self.state = state
         self.chunk = chunk
+        #: packed plans may carry a chunk LONGER than ``chunk`` (see
+        #: ``pack``). False where a kind of layer keeps a ring: the ring is
+        #: sized for chunk-at-most steps and a grown chunk would overrun
+        #: it — such a model packs ROWS only (exactly the rows that have
+        #: work, ``chunk`` tokens each)
+        self.grow_chunk = grow_chunk
         # process-wide telemetry (telemetry/); configure() mutates the
         # instance in place, so caching the reference here stays live
         self._telem = get_telemetry()
@@ -95,6 +102,22 @@ class SplitFuseScheduler:
                 plan.seq_lens[s] = start_pos + n
                 plan.sample_idx[s] = n - 1
                 plan.do_sample[s] = sample
+        # the model's further kinds of layer: the same two arrays from
+        # each kind's own table (a window kind's ring: slot (pos // bs) %
+        # width of ITS width)
+        for name, k in self.state.kinds.items():
+            if name == self.state.primary:
+                continue
+            w = k.max_blocks_per_seq
+            slots = np.zeros((S, T), np.int32)
+            tables = np.zeros((S, w), np.int32)
+            for seq, toks, start_pos, _ in entries:
+                r = row_of[seq.slot]
+                blocks = np.asarray(seq.kind_blocks[name], np.int32)
+                pos = np.arange(start_pos, start_pos + len(toks))
+                slots[r, :len(toks)] = blocks[(pos // bs) % w] * bs + pos % bs
+                tables[r, :len(blocks)] = blocks
+            plan.more[name] = (slots, tables)
         for seq, *_ in entries:
             r = row_of[seq.slot]
             plan.uids[r] = seq.uid
@@ -199,7 +222,7 @@ class SplitFuseScheduler:
         page-merge program would fail the alignment invariant)."""
         bs = self.state.block_size
         out = [self.chunk]
-        if self.chunk % bs == 0:
+        if self.grow_chunk and self.chunk % bs == 0:
             T = self.chunk * (self.state.max_seqs // n_rows)
             while T >= self.chunk and T % bs == 0:
                 out.append(T)
@@ -344,6 +367,7 @@ class SplitFuseScheduler:
                 continue
             seq = self.state.seqs[uid]
             n = int(plan.active[s].sum())
+            self.state.note_written(seq, seq.kv_next, seq.kv_next + n)
             seq.n_sched = seq.kv_next + n
             if plan.do_sample[s]:
                 seq.n_inflight += 1

@@ -158,6 +158,22 @@ PRESETS: dict[str, ModelConfig] = {
                                tie_embeddings=False,
                                moe=MoEConfig(num_experts=64, top_k=8,
                                              normalize_gates=False)),
+    # SmallThinker-21B-A3B (PowerInfer/SmallThinker-21BA3B-Instruct
+    # config.json): 28 heads x 128 over hidden 2560; of every four layers
+    # the first is full causal attention with NO position embedding and
+    # the next three a 4096-token window with rope; every layer sparse, 64
+    # ReGLU experts of width 768, 6 a token, softmax over the chosen six,
+    # routed from the layer's INPUT norm output (before attention)
+    "smallthinker-21b-a3b": ModelConfig(
+        vocab_size=151936, hidden_size=2560, num_layers=52, num_heads=28,
+        num_kv_heads=4, head_size=128, intermediate_size=768,
+        max_seq_len=16384, position_embedding="rope", rope_theta=1.5e6,
+        norm="rmsnorm", norm_eps=1e-6, activation="relu_glu",
+        sliding_window=4096,
+        layer_kinds=("full_nope", "window", "window", "window"),
+        tie_embeddings=False,
+        moe=MoEConfig(num_experts=64, top_k=6, normalize_gates=True,
+                      router_input="attn")),
     # --- bert family: bidirectional post-norm encoders (reference
     # module_inject/containers/{bert,distil_bert}.py policies and the
     # csrc/transformer training kernels, whose target workload is BERT) ----
@@ -244,6 +260,17 @@ PRESETS: dict[str, ModelConfig] = {
                               moe=MoEConfig(num_experts=8, top_k=2,
                                             min_capacity=4,
                                             normalize_gates=False)),
+    # two periods; window 16 so that a ring of 8-token blocks wraps in a
+    # CPU test; heads x head_size (128) wider than hidden (64), as published
+    "tiny-smallthinker": ModelConfig(
+        vocab_size=256, hidden_size=64, num_layers=8, num_heads=4,
+        num_kv_heads=2, head_size=32, intermediate_size=32, max_seq_len=256,
+        position_embedding="rope", rope_theta=1e4, norm="rmsnorm",
+        norm_eps=1e-6, activation="relu_glu", sliding_window=16,
+        layer_kinds=("full_nope", "window", "window", "window"),
+        tie_embeddings=False,
+        moe=MoEConfig(num_experts=8, top_k=2, min_capacity=4,
+                      normalize_gates=True, router_input="attn")),
 }
 
 

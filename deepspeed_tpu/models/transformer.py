@@ -105,6 +105,20 @@ class MoEConfig:
     # False = use the raw softmax probabilities (qwen2-moe's
     # norm_topk_prob=False default)
     normalize_gates: bool = True
+    # what the router reads: "ffn" — the tensor the experts read (the
+    # post-attention norm's output, every preset before SmallThinker) — or
+    # "attn": the layer's INPUT norm output, before attention
+    # (SmallThinker: "router placed before attention")
+    router_input: str = "ffn"
+
+
+#: what a kind of layer fixes: (sliding-window mask, position embedding).
+#: "full" and "window" carry the model's ``position_embedding``;
+#: "full_nope" is full causal attention with NO position embedding
+#: (SmallThinker's global layers). The serving engine keeps one KV
+#: allocator a MASK ("full" / "window"): see ``cache_kind``.
+LAYER_KINDS = {"full": (False, True), "window": (True, True),
+               "full_nope": (False, False)}
 
 
 @dataclass(frozen=True)
@@ -123,7 +137,8 @@ class ModelConfig:
     norm_eps: float = 1e-5
     activation: str = "gelu"                 # gelu (tanh approx) |
                                              # gelu_exact (erf) | relu |
-                                             # silu_glu (SwiGLU)
+                                             # silu_glu (SwiGLU) |
+                                             # relu_glu (ReGLU)
     qkv_bias: bool = False                   # qwen-style projection biases
     qk_norm: str | None = None               # None | "full": RMSNorm of the
                                              # WHOLE projected q and k (all
@@ -137,6 +152,14 @@ class ModelConfig:
     causal: bool = True                      # False → bidirectional encoder
                                              # (bert family)
     sliding_window: int | None = None        # mistral: attend last W tokens
+    head_size: int | None = None             # width of one head; None →
+                                             # hidden_size // num_heads
+    layer_kinds: tuple[str, ...] | None = None   # LAYER_KINDS names, one
+                                             # PERIOD repeated over the
+                                             # depth; None → every layer
+                                             # "window" where
+                                             # sliding_window is set, else
+                                             # "full"
     pre_norm: bool = True                    # False → post-norm residuals
                                              # (original BERT layout)
     embed_norm: bool = False                 # bloom: LayerNorm right after
@@ -164,13 +187,33 @@ class ModelConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+        return self.head_size or self.hidden_size // self.num_heads
+
+    @property
+    def kinds_period(self) -> tuple[str, ...]:
+        """One period of layer kinds (``layer_kinds``, or the one kind
+        every layer has)."""
+        period = self.layer_kinds or (
+            ("window",) if self.sliding_window else ("full",))
+        bad = [k for k in period if k not in LAYER_KINDS]
+        if bad or self.num_layers % len(period):
+            raise ValueError(
+                f"layer_kinds {period!r}: names must be of "
+                f"{sorted(LAYER_KINDS)} and the period must divide "
+                f"num_layers {self.num_layers}")
+        if "window" in period and not self.sliding_window:
+            raise ValueError("a 'window' layer kind needs sliding_window")
+        return period
+
+    def layer_kind(self, i: int) -> str:
+        period = self.kinds_period
+        return period[i % len(period)]
 
     @property
     def ffn_size(self) -> int:
         if self.intermediate_size:
             return self.intermediate_size
-        if self.activation == "silu_glu":
+        if self.activation in GLU_ACTS:
             return int(8 * self.hidden_size / 3 // 128 + 1) * 128
         return 4 * self.hidden_size
 
@@ -180,7 +223,7 @@ class ModelConfig:
         f = self.ffn_size
         attn = h * self.num_heads * self.head_dim + 2 * h * self.kv_heads * self.head_dim \
             + self.num_heads * self.head_dim * h
-        if self.activation == "silu_glu":
+        if self.activation in GLU_ACTS:
             ffn_dense = 3 * h * f
         else:
             ffn_dense = 2 * h * f + f + h  # + biases
@@ -314,11 +357,27 @@ def qk_norm(cfg: "ModelConfig", x: jax.Array, scale: jax.Array) -> jax.Array:
     return x * inv.astype(x.dtype) * scale.astype(x.dtype)
 
 
-def _attn_impl(cfg: "ModelConfig") -> str:
+def kind_window(cfg: "ModelConfig", kind: str) -> int | None:
+    """The sliding window a layer of ``kind`` masks with (None: full)."""
+    return cfg.sliding_window if LAYER_KINDS[kind][0] else None
+
+
+def kind_ropes(cfg: "ModelConfig", kind: str) -> bool:
+    """Whether a layer of ``kind`` rotates q and k."""
+    return cfg.position_embedding == "rope" and LAYER_KINDS[kind][1]
+
+
+def cache_kind(kind: str) -> str:
+    """The KV cache a layer of ``kind`` keeps: "window" (a bounded ring)
+    or "full" (a table that grows with the context)."""
+    return "window" if LAYER_KINDS[kind][0] else "full"
+
+
+def _attn_impl(cfg: "ModelConfig", kind: str) -> str:
     """alibi's additive bias and sliding windows run XLA attention (no
     flash kernel path); everything else follows ``cfg.attn_impl``."""
     return "xla" if (cfg.position_embedding == "alibi"
-                     or cfg.sliding_window) else cfg.attn_impl
+                     or kind_window(cfg, kind)) else cfg.attn_impl
 
 
 def attention_axis_names(cfg: "ModelConfig") -> tuple[tuple, tuple]:
@@ -381,10 +440,15 @@ class Attention(nn.Module):
     'seq' for the attention itself (all-to-all inserted by XLA).
     """
     config: ModelConfig
+    kind: str = ""        # a LAYER_KINDS name; "" → the model's one kind
 
     @nn.compact
     def __call__(self, x, positions, kv_cache=None, attn_mask=None):
         cfg = self.config
+        if not self.kind and len(set(cfg.kinds_period)) > 1:
+            raise ValueError("a model of several layer kinds needs each "
+                             "Attention told its own (Block(kind=...))")
+        kind = self.kind or cfg.layer_kind(0)
         B, S, _ = x.shape
         H, KV, D = cfg.num_heads, cfg.kv_heads, cfg.head_dim
 
@@ -423,7 +487,7 @@ class Attention(nn.Module):
                 nn.initializers.ones, ("kv_heads", "head_dim")), (KV, D),
                 jnp.float32))
 
-        if cfg.position_embedding == "rope":
+        if kind_ropes(cfg, kind):
             q, k = apply_rope(q, k, positions, cfg.rope_theta, cfg.rotary_pct)
 
         new_cache = None
@@ -463,8 +527,8 @@ class Attention(nn.Module):
             kv_len=(kv_cache[2] + S) if kv_cache is not None else None,
             mask=attn_mask,
             bias=alibi_bias,
-            window=cfg.sliding_window,
-            impl=_attn_impl(cfg),
+            window=kind_window(cfg, kind),
+            impl=_attn_impl(cfg, kind),
             sharding=attention_sharding(cfg),
         )
         # back to seq-sharded, heads full
@@ -498,6 +562,8 @@ _ACTS = {
     "gelu_exact": lambda x: jax.nn.gelu(x, approximate=False),
     "relu": jax.nn.relu,
 }
+#: three-matrix (gated) FFNs, ``down(act(gate x) * up x)``: SwiGLU, ReGLU
+GLU_ACTS = {"silu_glu": jax.nn.silu, "relu_glu": jax.nn.relu}
 
 
 class DenseFFN(nn.Module):
@@ -507,7 +573,7 @@ class DenseFFN(nn.Module):
     def __call__(self, x):
         cfg = self.config
         F = cfg.ffn_size
-        if cfg.activation == "silu_glu":
+        if cfg.activation in GLU_ACTS:
             wg = self.param("w_gate", nn.with_partitioning(_dense_init(), ("embed", "mlp")),
                             (cfg.hidden_size, F), jnp.float32)
             wu = self.param("w_up", nn.with_partitioning(_dense_init(), ("embed", "mlp")),
@@ -515,7 +581,7 @@ class DenseFFN(nn.Module):
             wd = self.param("w_down", nn.with_partitioning(_dense_init(), ("mlp", "embed")),
                             (F, cfg.hidden_size), jnp.float32)
             # ops/remat.py FFN_PRODUCTS: kept by the names policies
-            h = jax.nn.silu(
+            h = GLU_ACTS[cfg.activation](
                 checkpoint_name(x @ wg.astype(cfg.dtype), "ffn_gate")) \
                 * checkpoint_name(x @ wu.astype(cfg.dtype), "ffn_up")
         else:
@@ -542,7 +608,7 @@ class DenseFFN(nn.Module):
                                   lead_specs=scope.token_specs)
         if out is None:
             out = h @ wd.astype(cfg.dtype)
-        if cfg.activation != "silu_glu":
+        if cfg.activation not in GLU_ACTS:
             out = out + bd.astype(cfg.dtype)
         return constrain(out, BATCH, SEQ, EMBED)
 
@@ -605,11 +671,12 @@ class MoEFFN(nn.Module):
     config: ModelConfig
 
     @nn.compact
-    def __call__(self, x, deterministic: bool = True):
+    def __call__(self, x, deterministic: bool = True, router_x=None):
         from ..moe.layer import MoE
 
         cfg = self.config
-        out = MoE(**moe_layer_kwargs(cfg), name="moe_layer")(x, deterministic)
+        out = MoE(**moe_layer_kwargs(cfg), name="moe_layer")(
+            x, deterministic, router_x=router_x)
         se = cfg.moe.shared_expert_intermediate
         if se:
             shared_cfg = dataclasses.replace(cfg, intermediate_size=se)
@@ -626,6 +693,7 @@ class MoEFFN(nn.Module):
 class Block(nn.Module):
     config: ModelConfig
     use_moe: bool = False
+    kind: str = ""        # a LAYER_KINDS name; "" → the model's one kind
 
     @nn.compact
     def __call__(self, x, positions, kv_cache=None, attn_mask=None, deterministic=True):
@@ -635,7 +703,7 @@ class Block(nn.Module):
             # gpt-neox/falcon-40b keep separate norms per branch
             # (parallel_block_norms=2) — reference falcon/gptneox containers
             h = Norm(cfg, name="ln_attn")(x)
-            attn_out = Attention(cfg, name="attn")(h, positions,
+            attn_out = Attention(cfg, self.kind, name="attn")(h, positions,
                                                    kv_cache=kv_cache,
                                                    attn_mask=attn_mask)
             if kv_cache is not None:
@@ -658,7 +726,7 @@ class Block(nn.Module):
         if not cfg.pre_norm:
             # post-norm residuals (original BERT layout; the reference's
             # DeepSpeedTransformerConfig pre_layer_norm=False mode)
-            attn_out = Attention(cfg, name="attn")(x, positions,
+            attn_out = Attention(cfg, self.kind, name="attn")(x, positions,
                                                    kv_cache=kv_cache,
                                                    attn_mask=attn_mask)
             if kv_cache is not None:
@@ -675,8 +743,9 @@ class Block(nn.Module):
                 return x, new_cache
             return x
 
-        attn_out = Attention(cfg, name="attn")(Norm(cfg, name="ln_attn")(x), positions,
-                                               kv_cache=kv_cache, attn_mask=attn_mask)
+        h_in = Norm(cfg, name="ln_attn")(x)
+        attn_out = Attention(cfg, self.kind, name="attn")(
+            h_in, positions, kv_cache=kv_cache, attn_mask=attn_mask)
         if kv_cache is not None:
             attn_out, new_cache = attn_out
         else:
@@ -684,7 +753,9 @@ class Block(nn.Module):
         x = x + drop(attn_out)
         h = Norm(cfg, name="ln_ffn")(x)
         if self.use_moe:
-            ffn_out = MoEFFN(cfg, name="moe")(h, deterministic=deterministic)
+            ffn_out = MoEFFN(cfg, name="moe")(
+                h, deterministic=deterministic,
+                router_x=h_in if cfg.moe.router_input == "attn" else None)
         else:
             ffn_out = DenseFFN(dense_ffn_config(cfg), name="ffn")(h)
         x = x + drop(ffn_out)
@@ -748,7 +819,8 @@ class TransformerLM(nn.Module):
         for i in range(cfg.num_layers):
             use_moe = is_moe_layer(cfg, i)
             cache = kv_caches[i] if kv_caches is not None else None
-            out = block_cls(cfg, use_moe=use_moe, name=f"layer_{i}")(
+            out = block_cls(cfg, use_moe=use_moe, kind=cfg.layer_kind(i),
+                            name=f"layer_{i}")(
                 x, positions, cache, attn_mask, deterministic)
             if kv_caches is not None:
                 x, c = out

@@ -114,7 +114,9 @@ class Experts(nn.Module):
         E, F, n = self.hidden_size, self.ffn_size, self.num_experts
         init = nn.initializers.variance_scaling(1.0, "fan_in", "normal")
         dtype = x.dtype
-        glu = self.activation == "silu_glu"
+        from ..models.transformer import _ACTS, GLU_ACTS
+
+        glu = self.activation in GLU_ACTS
         if glu:
             wg = self.param("w_gate", nn.with_partitioning(
                 init, ("expert", "embed", "expert_mlp")), (n, E, F), jnp.float32)
@@ -123,23 +125,21 @@ class Experts(nn.Module):
         wd = self.param("w_down", nn.with_partitioning(
             init, ("expert", "expert_mlp", "embed")), (n, F, E), jnp.float32)
 
-        from ..models.transformer import _ACTS
-
-        act = _ACTS[self.activation] if not glu else None
+        act = GLU_ACTS[self.activation] if glu else _ACTS[self.activation]
         if sort is not None:
             from ..ops.pallas.grouped_matmul import grouped_matmul
 
             te = sort.tile_expert
             if glu:
-                h = jax.nn.silu(grouped_matmul(x, wg.astype(dtype), te,
-                                               block_m)) * \
+                h = act(grouped_matmul(x, wg.astype(dtype), te,
+                                       block_m)) * \
                     grouped_matmul(x, wu.astype(dtype), te, block_m)
             else:
                 h = act(grouped_matmul(x, wu.astype(dtype), te, block_m))
             return grouped_matmul(h, wd.astype(dtype), te, block_m)
 
         if glu:
-            h = jax.nn.silu(jnp.einsum("ngce,nef->ngcf", x, wg.astype(dtype))) * \
+            h = act(jnp.einsum("ngce,nef->ngcf", x, wg.astype(dtype))) * \
                 jnp.einsum("ngce,nef->ngcf", x, wu.astype(dtype))
         else:
             h = act(jnp.einsum("ngce,nef->ngcf", x, wu.astype(dtype)))
@@ -173,7 +173,10 @@ class MoE(nn.Module):
     normalize_gates: bool = True
 
     @nn.compact
-    def __call__(self, x: jax.Array, deterministic: bool = True) -> jax.Array:
+    def __call__(self, x: jax.Array, deterministic: bool = True,
+                 router_x: jax.Array | None = None) -> jax.Array:
+        """``router_x``: what the router reads where that is not what the
+        experts read (``MoEConfig.router_input``); None → ``x``."""
         B, S, E = x.shape
         dtype = x.dtype
         gate = TopKGate(
@@ -184,7 +187,7 @@ class MoE(nn.Module):
             noisy_gate_policy=self.noisy_gate_policy,
             drop_tokens=self.drop_tokens, dropless=self.dropless,
             normalize_gates=self.normalize_gates,
-            name="gate")(x, deterministic)
+            name="gate")(x if router_x is None else router_x, deterministic)
 
         self.sow("losses", "moe_aux_loss",
                  gate.aux_loss * self.aux_loss_weight +

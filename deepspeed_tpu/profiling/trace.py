@@ -92,6 +92,22 @@ def scope_of(op_name: str | None) -> tuple[str, str]:
     return names[0], direction
 
 
+#: scopes declared INSIDE another (``attn_core/attn_window``): which kind
+#: of layer an attention core belongs to
+SUB_SCOPES = frozenset({"attn_full", "attn_window"})
+
+
+def sub_scope_of(op_name: str | None) -> str | None:
+    """The declared sub-scope on one ``op_name`` path, or None.
+    :func:`scope_of` names the FIRST declared scope on a path
+    (``attn_core``), so that a table by scope still sums the kinds; this
+    names the kind of layer inside it."""
+    for p in (op_name or "").split("/"):
+        if _WRAPPED.sub("", p) in SUB_SCOPES:
+            return _WRAPPED.sub("", p)
+    return None
+
+
 def parse_hlo_scopes(text: str) -> tuple[str, dict[str, str]]:
     """(module name, {instruction name: op_name path}) from the text of a
     COMPILED module. A fusion (or call) takes its own ``op_name``; where

@@ -21,6 +21,9 @@ DEVICE_SCOPES = (
     # serving forward (inference/engine_v2.py)
     "embed", "weight_walk", "norm", "attn_qkv", "kv_stage", "attn_core",
     "attn_out", "ffn", "head", "sample", "kv_commit",
+    # inside ``attn_core``, in a model of window AND full layers: which kind
+    # of layer the attention core belongs to (one KV cache a kind)
+    "attn_full", "attn_window",
     # a routed-expert layer (moe/layer.py dropless_dispatch_combine and the
     # serving forward's router), in place of ``ffn``: the router matmul and
     # top-k; sort and scatter into the tile-aligned buffer; the grouped

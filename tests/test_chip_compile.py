@@ -92,7 +92,7 @@ def _ragged_args(mk, S, T, H, KV, D, bs, nb, pool_dtype, Ts=None,
 
 
 def _ragged(S, T, pool_dtype, tree=False, H=16, KV=16, D=64, bs=128,
-            max_pages=8):
+            max_pages=8, window=None, ring=False):
     def build(devs):
         from deepspeed_tpu.ops.pallas.paged_attention import \
             paged_ragged_attention
@@ -108,7 +108,8 @@ def _ragged(S, T, pool_dtype, tree=False, H=16, KV=16, D=64, bs=128,
         def fn(q, pool, ks, vs, bt, sl, qs, ss, *t):
             return paged_ragged_attention(
                 q, pool, ks, vs, bt, sl, qs, ss, block_size=bs,
-                layer_index=1,
+                layer_index=1, window=window,
+                ring_tokens=max_pages * bs if ring else None,
                 tree_positions=t[0] if t else None,
                 tree_mask=t[1] if t else None)
         return fn, args, True
@@ -234,7 +235,7 @@ def _grouped(backward):
     return build
 
 
-def _grouped_serving(tokens, down=False):
+def _grouped_serving(tokens, down=False, k=8, E=2048, F=1024):
     """The serving form at OLMoE-1B-7B's widths (64 experts, 8 a token,
     hidden 2048, expert width 1024): the depth-stacked slab with the layer
     picked inside the kernel, the sort's ``n_tiles`` skipping the buffer's
@@ -246,7 +247,7 @@ def _grouped_serving(tokens, down=False):
         from deepspeed_tpu.ops.pallas.grouped_matmul import \
             grouped_matmul_layer
         one = _one(devs)
-        L, n, k, E, F = 2, 64, 8, 2048, 1024
+        L, n = 2, 64
         bm = moe_tile_rows(tokens, k, n)
         Tp = moe_padded_rows(tokens, k, n, bm)
         K, N = (F, E) if down else (E, F)
@@ -277,6 +278,12 @@ def _quant(bits, M, N=1024):
 
 
 MISTRAL = dict(H=32, KV=8, D=128, max_pages=128)
+#: SmallThinker-21B-A3B: 28 query heads over 4 (groups of SEVEN), head 128;
+#: a global layer's table of 16384 / 128 pages and a window layer's ring of
+#: ceil((4096 + 512) / 128) + 1 slots; 64 experts of 768, 6 a token
+THINKER = dict(H=28, KV=4, D=128)
+THINKER_RING = dict(max_pages=37, window=4096, ring=True, **THINKER)
+THINKER_MOE = dict(k=6, E=2560, F=768)
 OLMOE = dict(H=16, KV=16, D=128, max_pages=32)
 
 CASES = {
@@ -311,6 +318,17 @@ CASES = {
     "ragged_decode_olmoe_s48_p32": _ragged(48, 1, BF16, **OLMOE),
     "ragged_chunk128_olmoe_s8_p32": _ragged(8, 128, BF16, **OLMOE),
     "ragged_tree_olmoe_s48_p32": _ragged(48, 8, BF16, tree=True, **OLMOE),
+    "ragged_decode_thinker_global_s48_p128": _ragged(
+        48, 1, BF16, max_pages=128, **THINKER),
+    "ragged_decode_thinker_ring_s48_p37": _ragged(48, 1, BF16,
+                                                  **THINKER_RING),
+    "ragged_chunk512_thinker_global_s6_p128": _ragged(
+        6, 512, BF16, max_pages=128, **THINKER),
+    "ragged_chunk512_thinker_ring_s6_p37": _ragged(6, 512, BF16,
+                                                   **THINKER_RING),
+    "grouped_gemm_thinker_decode_up": _grouped_serving(48, **THINKER_MOE),
+    "grouped_gemm_thinker_prefill_down": _grouped_serving(
+        512, down=True, **THINKER_MOE),
     "quant_int8_m8": _quant(8, 8),
     "quant_int8_m512": _quant(8, 512),
     "quant_int4_m8": _quant(4, 8),
@@ -638,17 +656,21 @@ def test_paged_kernel_scalar_prefetch_footprint():
     assert smem == 49_928 and smem < 64 * 1024
 
 
-def _mistral_walk(devs, S, T, L=4):
-    """``scan_layer_stack`` over an ``[L, …]`` stack of Mistral-7B layers
+def _mistral_walk(devs, S, T, L=4, period=1):
+    """``scan_layer_stack`` (``period`` 1) or ``scan_layer_periods`` (the
+    walk of a model of several layer kinds: place ``j`` of a period ropes
+    or not) over an ``[L, …]`` stack of Mistral-7B layers
     (bf16; the shapes are the model's own ``init``), applying the dense
     layer ``engine_v2._ragged_forward`` applies less its paged attention:
     the same ``Norm``/``DenseFFN`` modules and projection einsums. Returns
     (fn, abstract args, shapes of one layer's weights)."""
     import flax.linen as nn
 
-    from deepspeed_tpu.inference.engine_v2 import scan_layer_stack
+    from deepspeed_tpu.inference.engine_v2 import (scan_layer_periods,
+                                                   scan_layer_stack)
     from deepspeed_tpu.models import build_model
     from deepspeed_tpu.models.transformer import (DenseFFN, Norm,
+                                                  apply_rope,
                                                   dense_ffn_config)
     from deepspeed_tpu.utils.annotations import device_scope
 
@@ -660,12 +682,15 @@ def _mistral_walk(devs, S, T, L=4):
     stack = jax.tree.map(lambda a: _sds(_one(devs), (L, *a.shape), BF16),
                          layer0)
 
-    def layer(x, p, li, _):
+    def layer(x, p, li, _, j=0):
         a = p["attn"]
         h = Norm(m).apply({"params": p["ln_attn"]}, x)
         with device_scope("attn_qkv"):
             q, k, v = (jnp.einsum("ste,ehd->sthd", h, a[n])
                        for n in ("wq", "wk", "wv"))
+            if j:       # the kind is static: only these places rope
+                pos = jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
+                q, k = apply_rope(q, k, pos, m.rope_theta)
         o = q + jnp.repeat(k + v, m.num_heads // m.num_kv_heads, axis=2)
         with device_scope("attn_out"):
             x = x + jnp.einsum("sthd,hde->ste", o, a["wo"])
@@ -675,6 +700,8 @@ def _mistral_walk(devs, S, T, L=4):
         return x + f, None
 
     def walk(stack, x):
+        if period > 1:
+            return scan_layer_periods(stack, x, layer, period)[0]
         return scan_layer_stack(stack, x, layer)[0]
 
     x = _sds(_one(devs), (S, T, m.hidden_size), BF16)
@@ -703,9 +730,11 @@ def _top_level_outputs(hlo_text):
     return out
 
 
-@pytest.mark.parametrize("S, T", [(48, 1), (1, 128)],
-                         ids=["decode_48_slots", "prefill_chunk128"])
-def test_layer_walk_reads_the_stack_in_place(S, T, topo):
+@pytest.mark.parametrize("S, T, period", [(48, 1, 1), (1, 128, 1),
+                                          (48, 1, 4)],
+                         ids=["decode_48_slots", "prefill_chunk128",
+                              "decode_two_kinds_period4"])
+def test_layer_walk_reads_the_stack_in_place(S, T, period, topo):
     """The scanned layer walk holds no second copy of a layer: sliced
     inside the scan body, a weight is an operand of the matmul fusion that
     consumes it. (Carried through the scan, PR 24's parent, every leaf was
@@ -713,13 +742,17 @@ def test_layer_walk_reads_the_stack_in_place(S, T, topo):
     decode iteration on the chip.) What stays a buffer is the projections
     INTO heads (``[E, H, D]``: the matmul wants E in sublanes, the stack
     has H there), a ninth of the layer."""
-    walk, args, leaves = _mistral_walk(topo.devices, S, T)
+    walk, args, leaves = _mistral_walk(topo.devices, S, T,
+                                       L=4 * period, period=period)
     compiled = jax.jit(walk).lower(*args).compile()
     layer_bytes = 2 * sum(int(np.prod(s)) for s in leaves)
-    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+    # (a period's body holds ``period`` layers: each may keep its
+    # projections into heads)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < layer_bytes * period
     walked = [(dims, n) for name, dims, n in
               _top_level_outputs(compiled.as_text())
               if "weight_walk" in name and n >= 1 << 20]
     ffn_shapes = {s for s in leaves if len(s) == 2}
     assert not [w for w in walked if w[0] in ffn_shapes], walked
-    assert sum(n for _, n in walked) <= layer_bytes // 8, walked
+    assert sum(n for _, n in walked) <= period * layer_bytes // 8, walked

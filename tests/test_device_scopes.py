@@ -31,6 +31,9 @@ SERVING = ("embed", "weight_walk", "norm", "attn_qkv", "kv_stage",
            "attn_core", "attn_out", "ffn", "head", "sample", "kv_commit")
 #: a routed-expert layer's, in place of ``ffn`` (none in a dense program)
 MOE = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine")
+#: inside ``attn_core`` in a model of window AND full layers (held to its
+#: compiled programs by tests/test_smallthinker.py)
+KINDS = ("attn_full", "attn_window")
 TRAINING = ("embed", "head_loss", "optimizer", "grad_check", "zero_gather",
             "zero_reduce")
 
@@ -115,7 +118,8 @@ def test_every_use_is_declared_and_every_declaration_used():
     assert not set(used) - set(DEVICE_SCOPES), \
         {k: v for k, v in used.items() if k not in DEVICE_SCOPES}
     assert not set(DEVICE_SCOPES) - set(used)
-    assert set(SERVING) | set(MOE) | set(TRAINING) == set(DEVICE_SCOPES)
+    assert set(SERVING) | set(MOE) | set(TRAINING) | set(KINDS) \
+        == set(DEVICE_SCOPES)
 
 
 @pytest.mark.parametrize("name", SERVING)

@@ -412,7 +412,7 @@ def test_v2_rolling_window_kv_wraps_and_matches_v1():
         eng = InferenceEngineV2(model, params=v1.params,
                                 config={**cfg, "use_pallas_decode": pallas},
                                 rng=rng)
-        assert eng._ring_tokens > 0
+        assert eng._kinds[0].ring_tokens > 0
         nwin = eng.state.max_blocks_per_seq
         assert nwin * 8 < 256 and nwin * 8 >= 8 + 8
 
@@ -762,7 +762,7 @@ def test_v2_fp8_kv_with_rolling_window_ring():
                        "kv_cache_dtype": "fp8"},
         rng=jax.random.PRNGKey(3), topology=MeshTopology({"tensor": 1,
                                                           "data": 1}))
-    assert eng._ring_tokens > 0          # rolling buffer active
+    assert eng._kinds[0].ring_tokens > 0          # rolling buffer active
     assert not eng.scheduler.pack        # packing off in ring mode
     assert eng.kv_pool.dtype == jnp.float8_e4m3fn
     prompt = list(range(40))             # > window: the ring must wrap

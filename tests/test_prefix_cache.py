@@ -836,7 +836,7 @@ def test_v2_prefix_cache_config_gates():
     windowed = build_model("tiny-gpt2", hidden_size=256, num_heads=4,
                            sliding_window=24)
     ring = InferenceEngineV2(windowed, config=base, rng=rng, topology=topo)
-    assert ring._ring_tokens and ring._prefix_cache is None
+    assert ring._kinds[0].ring_tokens and ring._prefix_cache is None
     with pytest.raises(ValueError, match="rolling"):
         InferenceEngineV2(windowed, config={**base, "prefix_cache": True},
                           rng=rng, topology=topo)
