@@ -745,6 +745,9 @@ class InferenceEngineV2:
             self.scheduler.row_multiple = self._tp_ring_n
 
         self._programs: dict[int, Any] = {}
+        #: the grouped GEMM's weight blocks, one entry a distinct expert
+        #: shape and block (``_gmm``; the ``gmm:`` log lines)
+        self.gmm_plans: dict[tuple, Any] = {}
         self._rng = jax.random.PRNGKey(17)
         self._results: dict[int, list[int]] = {}
         # device-resident last sampled token per slot: decode steps read it
@@ -1218,16 +1221,27 @@ class InferenceEngineV2:
         ``[n, K, N]`` or, with ``li``, the depth-stacked ``[L, n, K, N]``
         (the kernel picks the layer). On a mesh the expert width is the
         tensor-sharded dim, as for a dense FFN: ``kind`` "col" (gate/up)
-        keeps the output sharded, "row" (down) sums the partial products."""
+        keeps the output sharded, "row" (down) sums the partial products.
+        The kernel's weight block is ``gmm_plan``'s for the shapes the
+        launch sees (a shard's, under a mesh); each distinct one is logged
+        once, as a ``gmm:`` line, while the programs are traced, and kept
+        in ``self.gmm_plans``."""
         from jax import shard_map
 
-        from ..ops.pallas.grouped_matmul import grouped_matmul_layer
+        from ..ops.pallas.grouped_matmul import gmm_plan, grouped_matmul_layer
+
+        def launch(xl, wl, te, nt, lil):
+            plan = gmm_plan(wl.shape[-2], wl.shape[-1], block_m, xl.dtype)
+            seen = self.gmm_plans.setdefault(plan._replace(block_m=0), plan)
+            if seen is plan:
+                logger.info(f"gmm: {plan.describe()}")
+            return grouped_matmul_layer(xl, wl, te, nt, block_m,
+                                        layer_index=lil)
 
         mesh = self.topology.mesh
         ntp = self.topology.size("tensor")
         if mesh.size == 1:
-            return grouped_matmul_layer(x2d, w, srt.tile_expert, srt.n_tiles,
-                                        block_m, layer_index=li)
+            return launch(x2d, w, srt.tile_expert, srt.n_tiles, li)
         width = w.shape[-1] if kind == "col" else w.shape[-2]
         if ntp <= 1 or width % ntp:
             kind = "rep"
@@ -1237,9 +1251,7 @@ class InferenceEngineV2:
         os_ = P(None, "tensor") if kind == "col" else P(None, None)
 
         def fn(xl, wl, te, nt, lil):
-            y = grouped_matmul_layer(
-                xl, wl, te, nt, block_m,
-                layer_index=None if li is None else lil)
+            y = launch(xl, wl, te, nt, None if li is None else lil)
             return jax.lax.psum(y, "tensor") if kind == "row" else y
 
         lia = jnp.zeros((), jnp.int32) if li is None else li

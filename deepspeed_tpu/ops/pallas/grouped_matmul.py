@@ -12,12 +12,28 @@ the TPU pipeline model:
   a scalar-prefetch argument; the weight BlockSpec's index_map reads it to
   DMA that expert's weight tile — the "grouped" part costs one SMEM lookup
   per tile instead of a gather.
-- Grid (n_tiles, token_tiles, k_tiles), k innermost; fp32 accumulation in
-  VMEM scratch, output written on the last k step (standard TPU matmul
-  schedule). Token tiles run INSIDE an output-column tile: consecutive
-  tiles of one expert then ask for the same weight block (where K fits one
-  block) and Pallas skips the copy, so an expert's weights are read once
-  however many tiles its tokens fill; the small x tile is what is re-read.
+- Grid (column_tiles, token_tiles, k_tiles), k innermost. The weight block
+  ``[bk, bn]`` comes from ONE plan (:func:`gmm_plan`), a pure function of
+  the call's ``(K, N, block_m, dtype)`` and :data:`VMEM_BUDGET_BYTES`, never
+  a caller's: K WHOLE wherever a ``[K, bn]`` block fits the budget
+  double-buffered beside the x tile, the output tile and the dot's fp32
+  result (at a ``bn`` of :data:`MIN_BLOCK_N` or N: no 128-wide strips),
+  the column tile the widest multiple of 128 dividing N that still fits
+  (N whole included), and K split — into its largest such divisor — only
+  where it must be (Mixtral's 14336-deep down projection), never because
+  K is not a power of two. What the plan guarantees wherever K is
+  whole (every expert shape the benchmark serves: OLMoE 2048 x 1024,
+  SmallThinker 2560 x 768, both ways round): ONE dot a step straight into
+  the output tile (no accumulator scratch, no partial sums); token tiles
+  run INSIDE a column tile, so consecutive tiles of one expert ask for the
+  same weight block and Pallas skips the copy — an expert's weights are
+  read once however many tiles its tokens fill, the small x tile is what is
+  re-read; and a tile of the buffer's empty tail costs ``N / bn`` grid
+  steps, one where N is whole. With K split the accumulator is fp32 VMEM
+  scratch, written out on the last k step, and a second tile of an expert
+  reads its weights again. The serving engine logs the plan of every
+  distinct expert shape once, as a ``gmm:`` line, when it builds its
+  programs (``InferenceEngineV2._gmm``; :meth:`GmmPlan.describe`).
 - Padding rows are zero → their outputs are zero and are never gathered
   back, so no masking is needed in the kernel.
 - The buffer is sized for the worst case (one partial tile an expert), so
@@ -57,34 +73,127 @@ def _pick(dim: int, want: int) -> int:
     return dim
 
 
-def _gmm_kernel(te_ref, nu_ref, li_ref, x_ref, w_ref, o_ref, acc, *,
+#: what :meth:`GmmPlan.vmem_bytes` may come to: the buffers a launch states
+#: (weight block, x tile and output tile, each double-buffered), the dot's
+#: fp32 result and, where K is split, the fp32 accumulator. Compiled for a
+#: described v5e each shape below takes a limit within 1 MiB of its
+#: estimate. 12 MiB holds every expert matrix the benchmark serves
+#: whole at a 128-row prefill tile (OLMoE 2048 x 1024: 10.0 / 10.5 MiB,
+#: SmallThinker 2560 x 768: 9.5 / 10.4) and refuses the next size up.
+VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+#: the scoped-VMEM limit handed to Mosaic with every launch: the budget and
+#: half again for what Mosaic adds itself (as ``flash_attention.py`` does;
+#: a v5e core has 128 MiB, the compiler's default is 16)
+VMEM_LIMIT_BYTES = 18 * 1024 * 1024
+#: the narrowest column tile K is kept whole for: 1 KiB of bf16 contiguous
+#: in HBM a weight row. Under it a deeper block is a strip of short rows
+MIN_BLOCK_N = 512
+
+
+class GmmPlan(NamedTuple):
+    """The weight block of one grouped-GEMM call — :func:`gmm_plan` makes
+    it, ``_gmm_call`` reads it and the serving engine logs it."""
+    K: int                  # contracting dim (x's columns)
+    N: int                  # output columns
+    block_m: int            # token rows a tile (the sort's alignment)
+    bk: int                 # the weight block is [bk, bn] ...
+    bn: int
+    itemsize: int           # ... of elements this wide
+
+    @property
+    def nk(self) -> int:
+        return self.K // self.bk
+
+    @property
+    def steps_per_tile(self) -> int:
+        """Grid steps a token tile costs, a tile of the empty tail too."""
+        return (self.N // self.bn) * self.nk
+
+    @property
+    def vmem_bytes(self) -> int:
+        return _vmem_bytes(self.block_m, self.K, self.bk, self.bn,
+                           self.itemsize)
+
+    def describe(self) -> str:
+        return (f"K {self.K} x N {self.N}: weight block {self.bk} x "
+                f"{self.bn} ({self.bk * self.bn * self.itemsize / 2**20:.2f}"
+                f" MiB), nk {self.nk}"
+                + ("" if self.nk > 1 else
+                   " (K whole: one dot a step, an expert's weights read "
+                   "once however many tiles)")
+                + f", {self.steps_per_tile} grid step"
+                f"{'s' * (self.steps_per_tile > 1)} a tile of "
+                f"{self.block_m} rows; VMEM {self.vmem_bytes / 2**20:.2f} "
+                f"of {VMEM_BUDGET_BYTES / 2**20:.0f} MiB")
+
+
+def _vmem_bytes(block_m: int, K: int, bk: int, bn: int, itemsize: int) -> int:
+    blocks = bk * bn + block_m * bk + block_m * bn
+    return 2 * blocks * itemsize + block_m * bn * 4 * (1 + (bk < K))
+
+
+def _blocks(dim: int) -> list[int]:
+    """Block sizes Mosaic takes along ``dim``, widest first: the dim
+    itself, then its divisors that are multiples of 128."""
+    return [dim] + [d for d in range((dim - 1) // 128 * 128, 0, -128)
+                    if dim % d == 0]
+
+
+def gmm_plan(K: int, N: int, block_m: int, dtype) -> GmmPlan:
+    """The weight block for ``[Tp, K] x [n, K, N]`` at ``block_m`` rows a
+    tile: the deepest ``bk`` — K whole first — whose block fits
+    :data:`VMEM_BUDGET_BYTES` at the widest column tile not over
+    :data:`MIN_BLOCK_N`, and then the widest ``bn`` that fits beside it. The
+    LOCAL shape decides: under a tensor mesh N (or K) is the shard's. A dim
+    with no divisor that is a multiple of 128 is taken whole whatever it
+    comes to."""
+    itemsize = jnp.dtype(dtype).itemsize
+    fits = lambda bk, bn: _vmem_bytes(block_m, K, bk, bn, itemsize) \
+        <= VMEM_BUDGET_BYTES
+    bks, bns = _blocks(K), _blocks(N)
+    floor = next((b for b in bns if b <= MIN_BLOCK_N), bns[-1])
+    bk = next((b for b in bks if fits(b, floor)), bks[-1])
+    bn = next((b for b in bns if fits(bk, b)), bns[-1])
+    return GmmPlan(K=K, N=N, block_m=block_m, bk=bk, bn=bn,
+                   itemsize=itemsize)
+
+
+def _gmm_kernel(te_ref, nu_ref, li_ref, x_ref, w_ref, o_ref, *acc,
                 transpose_rhs: bool, upcast: bool):
     t, k = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
 
     @pl.when(t < nu_ref[0])
     def _live():
-        @pl.when(k == 0)
-        def _init():
-            acc[:] = jnp.zeros_like(acc)
-
         x = x_ref[...]                               # [bm, bk]
         w = w_ref[0, 0]                              # [bk, bn] | [bn, bk]
         if upcast:      # the CPU's dot thunk refuses bf16 x bf16 -> f32
             x, w = x.astype(jnp.float32), w.astype(jnp.float32)
         dims = (((1,), (1,)), ((), ())) if transpose_rhs \
             else (((1,), (0,)), ((), ()))
-        acc[:] += jax.lax.dot_general(x, w, dims,
-                                      preferred_element_type=jnp.float32)
+        prod = jax.lax.dot_general(x, w, dims,
+                                   preferred_element_type=jnp.float32)
+        if not acc:                                  # K whole: one dot
+            o_ref[...] = prod.astype(o_ref.dtype)
+            return
+        acc_ref, = acc
+
+        @pl.when(k == 0)
+        def _init():
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+
+        acc_ref[:] += prod
 
         @pl.when(k == nk - 1)
         def _finalize():
-            o_ref[...] = acc[:].astype(o_ref.dtype)
+            o_ref[...] = acc_ref[:].astype(o_ref.dtype)
 
 
 def _gmm_call(x, w, tile_expert, *, block_m: int, transpose_rhs: bool,
-              block_n: int | None, block_k: int | None,
               interpret: bool | None, n_tiles=None, layer_index=None):
+    """One ``grouped_matmul_fwd`` launch; its weight block is
+    :func:`gmm_plan`'s for the shapes it is handed (a shard's, under a
+    mesh) and nobody else's."""
     Tp, E = x.shape
     if layer_index is None:
         w = w[None]                                  # one "layer": a bitcast
@@ -98,14 +207,13 @@ def _gmm_call(x, w, tile_expert, *, block_m: int, transpose_rhs: bool,
         raise ValueError(f"contracting dims mismatch: x {x.shape} w {w.shape}")
     if Tp % block_m:
         raise ValueError(f"tokens {Tp} not a multiple of block_m {block_m}")
-    bk = _pick(K, block_k or 2048)
-    bn = _pick(N, block_n or 512)
+    plan = gmm_plan(K, N, block_m, x.dtype)
+    bk, bn, nk = plan.bk, plan.bn, plan.nk
     if interpret is None:
         from . import interpret_mode
         interpret = interpret_mode()
 
     n_all = Tp // block_m
-    nk = K // bk
     grid = (N // bn, n_all, nk)
 
     def w_k(t, k, nu):
@@ -129,7 +237,8 @@ def _gmm_call(x, w, tile_expert, *, block_m: int, transpose_rhs: bool,
         ],
         out_specs=pl.BlockSpec((block_m, bn),
                                lambda f, t, k, te, nu, li: (t, f)),
-        scratch_shapes=[pltpu.VMEM((block_m, bn), jnp.float32)],
+        # K whole: the dot goes straight to the output tile
+        scratch_shapes=[pltpu.VMEM((block_m, bn), jnp.float32)] * (nk > 1),
     )
     one = lambda v, default: jnp.asarray(
         default if v is None else v, jnp.int32).reshape(1)
@@ -139,6 +248,8 @@ def _gmm_call(x, w, tile_expert, *, block_m: int, transpose_rhs: bool,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Tp, N), x.dtype),
         name="grouped_matmul_fwd",
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(tile_expert.astype(jnp.int32), one(n_tiles, n_all),
       one(layer_index, 0), x, w)
@@ -151,25 +262,23 @@ def grouped_matmul_layer(x, w, tile_expert, n_tiles, block_m: int,
     be the depth-stacked ``[L, n, E, F]`` slab with ``layer_index`` picking
     the layer inside the kernel."""
     return _gmm_call(x, w, tile_expert, block_m=block_m,
-                     transpose_rhs=False, block_n=None, block_k=None,
-                     interpret=interpret, n_tiles=n_tiles,
-                     layer_index=layer_index)
+                     transpose_rhs=False, interpret=interpret,
+                     n_tiles=n_tiles, layer_index=layer_index)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def grouped_matmul(x, w, tile_expert, block_m: int = 128,
-                   block_n: int | None = None, block_k: int | None = None,
                    interpret: bool | None = None):
     """x: [Tp, E] expert-sorted+aligned tokens; w: [n_exp, E, F];
     tile_expert: [Tp // block_m] int32 — expert owning each token tile.
     Returns [Tp, F]."""
     return _gmm_call(x, w, tile_expert, block_m=block_m, transpose_rhs=False,
-                     block_n=block_n, block_k=block_k, interpret=interpret)
+                     interpret=interpret)
 
 
-def _gmm_fwd(x, w, tile_expert, block_m, block_n, block_k, interpret):
+def _gmm_fwd(x, w, tile_expert, block_m, interpret):
     out = _gmm_call(x, w, tile_expert, block_m=block_m, transpose_rhs=False,
-                    block_n=block_n, block_k=block_k, interpret=interpret)
+                    interpret=interpret)
     return out, (x, w, tile_expert)
 
 
@@ -229,12 +338,12 @@ def _dw_call(x, dy, tile_expert, n_exp: int, *, block_m: int,
     return jnp.where(has[:, None, None], dw, 0.0)
 
 
-def _gmm_bwd(block_m, block_n, block_k, interpret, res, dy):
+def _gmm_bwd(block_m, interpret, res, dy):
     x, w, tile_expert = res
     n_exp = w.shape[0]
     # dx[t] = dy[t] @ w[e_t]^T — same kernel, contracting w's F axis
     dx = _gmm_call(dy, w, tile_expert, block_m=block_m, transpose_rhs=True,
-                   block_n=block_n, block_k=block_k, interpret=interpret)
+                   interpret=interpret)
     dw = _dw_call(x, dy, tile_expert, n_exp, block_m=block_m,
                   interpret=interpret).astype(w.dtype)
     return dx.astype(x.dtype), dw, None

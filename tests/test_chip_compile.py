@@ -327,6 +327,9 @@ CASES = {
     "ragged_chunk512_thinker_ring_s6_p37": _ragged(6, 512, BF16,
                                                    **THINKER_RING),
     "grouped_gemm_thinker_decode_up": _grouped_serving(48, **THINKER_MOE),
+    "grouped_gemm_thinker_decode_down": _grouped_serving(
+        48, down=True, **THINKER_MOE),
+    "grouped_gemm_thinker_prefill_up": _grouped_serving(512, **THINKER_MOE),
     "grouped_gemm_thinker_prefill_down": _grouped_serving(
         512, down=True, **THINKER_MOE),
     "quant_int8_m8": _quant(8, 8),
@@ -347,6 +350,27 @@ def test_kernel_compiles_for_v5e(name, topo):
     fn, args, expect_kernel = CASES[name](topo.devices)
     compiled = jax.jit(fn).lower(*args).compile()
     assert ("tpu_custom_call" in compiled.as_text()) == expect_kernel
+
+
+@pytest.mark.parametrize("name", [n for n in CASES if n.startswith(
+    ("grouped_gemm_olmoe_", "grouped_gemm_thinker_"))])
+def test_grouped_gemm_block_fits_the_plans_budget(name, topo, monkeypatch):
+    """The decode form (16 rows a tile) and the 128-row prefill form at the
+    published OLMoE and SmallThinker widths take their expert matrix in ONE
+    block (``gmm_plan``: K and N whole), and what Mosaic needs for it is
+    inside the plan's own estimate: the launch compiles with the scoped
+    VMEM limit pulled down from ``VMEM_LIMIT_BYTES`` to the budget."""
+    import deepspeed_tpu.ops.pallas.grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "VMEM_LIMIT_BYTES", gm.VMEM_BUDGET_BYTES)
+    fn, args, _ = CASES[name](topo.devices)
+    x, w = args[0], args[1]
+    plan = gm.gmm_plan(w.shape[-2], w.shape[-1],
+                       x.shape[0] // args[2].shape[0], x.dtype)
+    assert (plan.bk, plan.bn, plan.steps_per_tile) == (*w.shape[-2:], 1)
+    assert plan.vmem_bytes <= gm.VMEM_BUDGET_BYTES
+    assert _kernel_calls(jax.jit(fn).lower(*args).compile().as_text()) \
+        == ["grouped_matmul_fwd"]
 
 
 @pytest.mark.parametrize("name, kernel", [

@@ -319,3 +319,37 @@ def test_moe_counters_follow_the_plans():
     assert moe_tile_rows(4096, 8, 64) == 128
     assert moe_tile_rows(48, 8, 64, quantised=True) == 32
     assert moe_padded_rows(48, 8, 64, 16) == 384 + 64 * 16
+
+
+def test_engine_logs_one_gmm_line_a_shape():
+    """The grouped GEMM's block plan is the program's own record of what
+    ran: one ``gmm:`` line a distinct expert shape (gate and up share
+    theirs; down is the other), however many programs — prefill steps,
+    one-step decode, windows, each at its own tile height — launch it."""
+    import logging
+
+    from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
+    from deepspeed_tpu.models import build_model
+    from deepspeed_tpu.utils.logging import logger
+
+    lines = []
+    handler = logging.Handler()
+    handler.emit = lambda rec: lines.append(rec.getMessage())
+    level = logger.level            # (conftest.py quiets it to warnings)
+    logger.addHandler(handler)
+    logger.setLevel(logging.INFO)
+    try:
+        model = build_model("tiny-olmoe")
+        eng = InferenceEngineV2(model, rng=jax.random.PRNGKey(0),
+                                config={**ENGINE, "decode_window": 4})
+        eng.generate([list(range(1, 20))], max_new_tokens=9)
+    finally:
+        logger.setLevel(level)
+        logger.removeHandler(handler)
+    m = model.config
+    shapes = {(p.K, p.N, p.bk, p.bn) for p in eng.gmm_plans.values()}
+    assert shapes == {(m.hidden_size, m.ffn_size) * 2,
+                      (m.ffn_size, m.hidden_size) * 2}      # K and N whole
+    gmm = [ln for ln in lines if ln.startswith("gmm: ")]
+    assert len(gmm) == 2 and len(eng._programs) >= 2
+    assert all("nk 1" in ln for ln in gmm)

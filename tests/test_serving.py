@@ -391,15 +391,18 @@ def test_crash_loop_opens_breaker_survivor_serves():
                                     max_new_tokens=8, vocab=VOCAB))
     router = make_router(
         per_slot={"0": {"faults": {"replica_crash_on_start": True}}},
-        breaker_max_restarts=2, breaker_window_s=30.0,
+        breaker_max_restarts=2, breaker_window_s=180.0,
         breaker_cooloff_s=120.0, log_tag="breaker", telemetry=True)
     with router:
         tids = submit_trace(router, trace)
         res = router.run(deadline_s=60)
         assert_exactly_once(router, res)
         assert all(res[t]["status"] == "done" for t in tids)
-        # drive maintenance until the breaker verdict lands
-        deadline = time.monotonic() + 20
+        # drive maintenance until the breaker verdict lands: three deaths,
+        # and on a loaded host (the whole suite on six workers) one
+        # incarnation takes 13 s from spawn to death, so window and wait
+        # are sized for that; an idle host leaves the loop in a second
+        deadline = time.monotonic() + 120
         while router.fleet.breaker_opens_total == 0 \
                 and time.monotonic() < deadline:
             router.poll()
