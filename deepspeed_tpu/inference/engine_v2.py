@@ -1496,10 +1496,12 @@ class InferenceEngineV2:
             """THE routed-expert layer of serving, quantised or not: router
             -> dropless top-k (every token reaches its k experts; generation
             must not drop a routed token — the FastGen v2 MoE contract) ->
-            sort into a tile-aligned buffer -> grouped GEMMs -> gather and
-            gate-weighted sum. Only the GEMM differs: the bf16 Pallas
-            grouped matmul, or its in-tile-dequant twin over QuantGrouped
-            slabs (reference cutlass_ops/moe_gemm with mixed_gemm). The
+            sort, and gather the rows into a tile-aligned buffer (no
+            scatter: a one-hot matmul at a step's few rows, a row gather
+            at many) -> grouped GEMMs -> gather back and gate-weighted
+            sum. Only the GEMM differs: the bf16 Pallas grouped matmul, or
+            its in-tile-dequant twin over QuantGrouped slabs (reference
+            cutlass_ops/moe_gemm with mixed_gemm). The
             dispatch/combine algebra is shared with the training dropless
             path (moe/layer.py ``dropless_dispatch_combine``). NB this
             diverges from the v1/training forward exactly when eval

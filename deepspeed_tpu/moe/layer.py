@@ -71,24 +71,28 @@ def dropless_dispatch_combine(x2d: jax.Array, gates: jax.Array,
     forward — inference/engine_v2.py ``routed_experts``, quantised or not
     — so routing fixes reach all of them).
 
-    Sort the [T, k] expert choices into a block-aligned buffer, run
-    ``gemm(buf, sort) -> [Tp, F]`` (the only part that differs between
-    callers: bf16 grouped GEMM vs quantized grouped GEMM), gather each
-    token's k rows back and combine with its gates (as the router gave
-    them: renormalised or not).
+    Sort the [T, k] expert choices into the layout of a block-aligned
+    buffer and GATHER each buffer row's token into it (padding rows zero;
+    no scatter on the way in, forward or backward: ``ExpertSort``,
+    ``gather_expert_rows``), run ``gemm(buf, sort) -> [Tp, F]`` (the only
+    part that differs between callers: bf16 grouped GEMM vs quantized
+    grouped GEMM), gather each token's k rows back and combine with its
+    gates (as the router gave them: renormalised or not).
     """
-    from ..ops.pallas.grouped_matmul import sort_tokens_by_expert
+    from ..ops.pallas.grouped_matmul import (gather_expert_rows,
+                                             gather_token_rows,
+                                             sort_tokens_by_expert)
 
-    T, E = x2d.shape
+    T = x2d.shape[0]
     with device_scope("moe_dispatch"):
         srt = sort_tokens_by_expert(experts.reshape(T, k), num_experts,
                                     block_m)
-        rows = jnp.repeat(x2d, k, axis=0)                  # [T*k, E]
-        buf = jnp.zeros((srt.Tp, E), x2d.dtype).at[srt.dst].set(rows)
+        buf = gather_expert_rows(x2d, srt.src, srt.dst)    # [Tp, E]
     with device_scope("moe_experts"):
         out_buf = gemm(buf, srt)
     with device_scope("moe_combine"):
-        rows_out = out_buf[srt.dst].reshape(T, k, -1)
+        rows_out = gather_token_rows(out_buf, srt.src,
+                                     srt.dst).reshape(T, k, -1)
         return jnp.einsum("tk,tke->te",
                           gates.reshape(T, k).astype(x2d.dtype), rows_out)
 

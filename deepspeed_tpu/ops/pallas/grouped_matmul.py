@@ -35,7 +35,10 @@ the TPU pipeline model:
   distinct expert shape once, as a ``gmm:`` line, when it builds its
   programs (``InferenceEngineV2._gmm``; :meth:`GmmPlan.describe`).
 - Padding rows are zero → their outputs are zero and are never gathered
-  back, so no masking is needed in the kernel.
+  back, so no masking is needed in the kernel. The buffer is FILLED without
+  a scatter (``gather_expert_rows``: a one-hot matmul for a step of few
+  tokens, a row gather for many; a TPU walks a scatter one update at a
+  time), and the sort holds none either.
 - The buffer is sized for the worst case (one partial tile an expert), so
   its tail tiles hold no token at all. A caller that passes the sort's
   ``n_tiles`` (the serving forward) has them skipped: no dot, and their
@@ -334,7 +337,7 @@ def _dw_call(x, dy, tile_expert, n_exp: int, *, block_m: int,
         interpret=interpret,
     )(tile_expert.astype(jnp.int32), x, dy)
     # experts that own no tiles were never written — mask their garbage
-    has = jnp.zeros((n_exp,), bool).at[tile_expert].set(True)
+    has = jnp.any(tile_expert[:, None] == jnp.arange(n_exp)[None], axis=0)
     return jnp.where(has[:, None, None], dw, 0.0)
 
 
@@ -353,37 +356,62 @@ grouped_matmul.defvjp(_gmm_fwd, _gmm_bwd)
 
 
 class ExpertSort(NamedTuple):
-    """In-jit dropless dispatch layout (static shapes throughout)."""
-    dst: jax.Array          # [T*k] destination row per (token, choice)
+    """In-jit dropless dispatch layout (static shapes throughout). The way
+    into the buffer is a GATHER, not a scatter: ``src`` names, for every
+    buffer row, what it holds; ``dst`` names, for every (token, choice),
+    its row — the way back, and what the dense fill compares against."""
+    dst: jax.Array          # [T*k] buffer row of each (token, choice)
     tile_expert: jax.Array  # [Tp // block_m] expert owning each token tile
     Tp: int                 # static padded buffer length
     n_tiles: jax.Array      # scalar: tiles that hold a token (the rest of
                             # the buffer is its worst-case tail)
+    src: jax.Array          # [Tp] the (token, choice) entry ``t * k + c``
+                            # each buffer row holds; T*k where it holds none
+
+
+def _lookup(table: jax.Array, idx: jax.Array) -> jax.Array:
+    """``table[idx]`` for a table of ``num_experts`` entries, as a
+    comparison and a sum: a gather costs ~8 ns an INDEX on a v5e however
+    small the table (``PERF.md`` PR 46), this a few vector ops in all."""
+    n = table.shape[0]
+    return jnp.sum(jnp.where(idx[..., None] == jnp.arange(n, dtype=idx.dtype),
+                             table, 0), axis=-1, dtype=table.dtype)
 
 
 def sort_tokens_by_expert(expert_idx: jax.Array, num_experts: int,
                           block_m: int = 128) -> ExpertSort:
-    """Compute the expert-sorted, block-aligned destination of every
-    (token, choice) pair. ``expert_idx``: [T, k] int32 from top-k routing.
+    """Compute the expert-sorted, block-aligned buffer row of every
+    (token, choice) pair, and for every buffer row the entry to gather into
+    it. ``expert_idx``: [T, k] int32 from top-k routing.
+
+    No scatter anywhere (a TPU walks a scatter one update at a time, ~0.17
+    us a row: ``PERF.md`` PR 46): the counts are a comparison against
+    ``arange(n)`` summed, the order ONE stable sort of the expert ids, its
+    inverse permutation (``dst``) a second sort keyed on that order, and
+    ``src`` index arithmetic — buffer row ``p`` of a tile of expert ``e``
+    has rank ``r = p - starts[e]``, is live iff ``r < counts[e]`` and holds
+    entry ``order[cum_counts[e] + r]`` (token ``... // k``).
 
     Static buffer bound: T*k rounded up to block_m, plus one block_m of
     alignment padding per expert (each expert wastes < block_m rows).
     """
     T, k = expert_idx.shape
     Tk = T * k
-    e_flat = expert_idx.reshape(-1)
-    counts = jnp.bincount(e_flat, length=num_experts)              # [n]
+    e_flat = expert_idx.reshape(-1).astype(jnp.int32)
+    counts = jnp.sum(
+        e_flat[:, None] == jnp.arange(num_experts, dtype=jnp.int32)[None],
+        axis=0, dtype=jnp.int32)                                   # [n]
     aligned = ((counts + block_m - 1) // block_m) * block_m
     starts = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                               jnp.cumsum(aligned)[:-1].astype(jnp.int32)])
     cum_counts = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                                   jnp.cumsum(counts)[:-1].astype(jnp.int32)])
 
-    order = jnp.argsort(e_flat, stable=True)                       # [Tk]
-    sorted_e = e_flat[order]
-    rank = jnp.arange(Tk, dtype=jnp.int32) - cum_counts[sorted_e]
-    dst_sorted = starts[sorted_e] + rank
-    dst = jnp.zeros((Tk,), jnp.int32).at[order].set(dst_sorted)
+    iota = jnp.arange(Tk, dtype=jnp.int32)
+    sorted_e, order = jax.lax.sort((e_flat, iota), num_keys=1,
+                                   is_stable=True)                 # [Tk]
+    dst_sorted = _lookup(starts - cum_counts, sorted_e) + iota
+    _, dst = jax.lax.sort((order, dst_sorted), num_keys=1, is_stable=False)
 
     Tp = ((Tk + block_m - 1) // block_m) * block_m + num_experts * block_m
     tile_starts = jnp.arange(Tp // block_m, dtype=jnp.int32) * block_m
@@ -391,9 +419,105 @@ def sort_tokens_by_expert(expert_idx: jax.Array, num_experts: int,
     # still nondecreasing (the dw kernel's invariant), and a kernel told
     # ``n_tiles`` finds the weight block it already holds
     n_tiles = (jnp.sum(aligned) // block_m).astype(jnp.int32)
-    tile_starts = jnp.minimum(tile_starts, (n_tiles - 1) * block_m)
+    clamped = jnp.minimum(tile_starts, (n_tiles - 1) * block_m)
+    # the last expert whose rows start at or before the tile
+    # (``searchsorted(starts, clamped, side="right") - 1``, densely)
     tile_expert = jnp.clip(
-        jnp.searchsorted(starts, tile_starts, side="right") - 1,
-        0, num_experts - 1).astype(jnp.int32)
+        jnp.sum(starts[None] <= clamped[:, None], axis=1, dtype=jnp.int32) - 1,
+        0, num_experts - 1)
+
+    # a tail tile lies past its (clamped) expert's aligned rows: not live
+    p = tile_starts[:, None] + jnp.arange(block_m, dtype=jnp.int32)[None]
+    live = p < _lookup(starts + counts, tile_expert)[:, None]      # [tiles, bm]
+    entry = order[jnp.clip(
+        p + _lookup(cum_counts - starts, tile_expert)[:, None], 0, Tk - 1)]
+    src = jnp.where(live, entry, Tk).reshape(Tp)
     return ExpertSort(dst=dst, tile_expert=tile_expert, Tp=Tp,
-                      n_tiles=n_tiles)
+                      n_tiles=n_tiles, src=src)
+
+
+#: The fill is a one-hot matmul where ``tokens x width`` is at most this,
+#: a row gather above it (512 tokens of SmallThinker's 2560): the matmul
+#: costs ``2 * Tp * T * E`` operations at the MXU's pace, the gather 6-18 ns
+#: a buffer row plus 7 for its index; on a v5e they cross between 600 and
+#: 900 tokens at widths 2048-2560 (``PERF.md`` PR 46, call 2). At a 48-row
+#: decode step the matmul is 5 us where the gather walks 1,312 rows in 34.
+DENSE_FILL_MAX_ELEMS = 512 * 2560
+
+
+def _rows_or_zero(a: jax.Array, idx: jax.Array) -> jax.Array:
+    """``a[idx]``, and a zero row wherever ``idx`` is one past the end (the
+    zero row is appended: a select after the gather is one more pass over
+    the buffer)."""
+    return jnp.concatenate([a, jnp.zeros((1,) + a.shape[1:], a.dtype)])[idx]
+
+
+def _fill_dense(x2d: jax.Array, dst: jax.Array, Tp: int) -> jax.Array:
+    """The buffer as ``hit [Tp, T] @ x2d``, ``hit[p, t]`` set where one of
+    token ``t``'s k rows is ``p``: no index is walked at all. A sum of one
+    value and zeros is that value, so rows come out as they went in
+    (``-0.0`` as ``+0.0``) and padding rows zero. ``0 * inf`` is NaN, so a
+    non-finite value is kept out of the matmul — it would reach every row —
+    and its token's rows read NaN instead."""
+    T = x2d.shape[0]
+    hit = jnp.any(dst.reshape(1, T, -1)
+                  == jnp.arange(Tp, dtype=dst.dtype)[:, None, None],
+                  axis=-1).astype(x2d.dtype)
+    pick = functools.partial(jnp.dot, hit, precision=jax.lax.Precision.HIGHEST,
+                             preferred_element_type=jnp.float32)
+    finite = jnp.isfinite(x2d)
+    buf = pick(jnp.where(finite, x2d, jnp.zeros((), x2d.dtype)))
+    poisoned = pick(jnp.any(~finite, axis=-1, keepdims=True)
+                    .astype(x2d.dtype)) > 0                        # [Tp, 1]
+    return jnp.where(poisoned, jnp.nan, buf).astype(x2d.dtype)
+
+
+@jax.custom_vjp
+def gather_expert_rows(x2d: jax.Array, src: jax.Array,
+                       dst: jax.Array) -> jax.Array:
+    """The expert buffer ``[Tp, E]`` of ``x2d [T, E]`` under an
+    :class:`ExpertSort`: row ``p`` is token ``src[p] // k``'s, or zero where
+    the row holds none — what ``zeros.at[dst].set(repeat(x2d, k))`` made,
+    bit for bit, with no scatter: a one-hot matmul for few tokens
+    (:data:`DENSE_FILL_MAX_ELEMS`; the form depends on the shape alone), a
+    row gather for many. The backward is a gather as well (the transpose
+    of the scatter this replaces, not of the gather, which would be a
+    scatter-add of Tp rows): ``d_x2d[t] = sum_k d_buf[dst[t, k]]``."""
+    T, E = x2d.shape
+    if T * E <= DENSE_FILL_MAX_ELEMS:
+        return _fill_dense(x2d, dst, src.shape[0])
+    return _rows_or_zero(x2d, src // (dst.shape[0] // T))
+
+
+def _rows_in_fwd(x2d, src, dst):
+    return gather_expert_rows(x2d, src, dst), (dst, x2d.shape[0])
+
+
+def _rows_in_bwd(res, d_buf):
+    dst, T = res
+    d_rows = d_buf[dst].reshape(T, dst.shape[0] // T, d_buf.shape[-1])
+    return jax.lax.reduce_sum(d_rows, axes=(1,)), None, None
+
+
+gather_expert_rows.defvjp(_rows_in_fwd, _rows_in_bwd)
+
+
+@jax.custom_vjp
+def gather_token_rows(out_buf: jax.Array, src: jax.Array,
+                      dst: jax.Array) -> jax.Array:
+    """The way back: ``out_buf[dst]``, the ``[T*k, F]`` rows of every
+    (token, choice). Its backward fills a buffer again — a gather by
+    ``src``, padding rows zero — where the transpose of ``out_buf[dst]``
+    is a scatter-add of T*k rows."""
+    return out_buf[dst]
+
+
+def _rows_out_fwd(out_buf, src, dst):
+    return out_buf[dst], src
+
+
+def _rows_out_bwd(src, d_rows):
+    return _rows_or_zero(d_rows, src), None, None
+
+
+gather_token_rows.defvjp(_rows_out_fwd, _rows_out_bwd)

@@ -26,7 +26,7 @@ DEVICE_SCOPES = (
     "attn_full", "attn_window",
     # a routed-expert layer (moe/layer.py dropless_dispatch_combine and the
     # serving forward's router), in place of ``ffn``: the router matmul and
-    # top-k; sort and scatter into the tile-aligned buffer; the grouped
+    # top-k; sort and gather into the tile-aligned buffer; the grouped
     # GEMMs and the activation; gather back and the gate-weighted sum
     "moe_router", "moe_dispatch", "moe_experts", "moe_combine",
     # training (models/transformer.py, models/loss.py, runtime/engine.py)

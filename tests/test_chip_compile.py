@@ -260,6 +260,34 @@ def _grouped_serving(tokens, down=False, k=8, E=2048, F=1024):
     return build
 
 
+def _routed_layer(tokens, k=8, E=2048, F=1024):
+    """One routed-expert layer of serving as ``moe/layer.py`` builds it
+    (sort, fill, three grouped GEMMs, combine) at OLMoE's or SmallThinker's
+    widths and the tile height of a step of ``tokens`` rows."""
+    def build(devs):
+        from deepspeed_tpu.inference.engine_v2 import moe_tile_rows
+        from deepspeed_tpu.moe.layer import dropless_dispatch_combine
+        from deepspeed_tpu.ops.pallas.grouped_matmul import \
+            grouped_matmul_layer
+        one = _one(devs)
+        n = 64
+        bm = moe_tile_rows(tokens, k, n)
+
+        def fn(x, gates, experts, wg, wu, wd):
+            def gemm(buf, srt):
+                mm = lambda a, w: grouped_matmul_layer(
+                    a, w, srt.tile_expert, srt.n_tiles, bm)
+                return mm(jax.nn.silu(mm(buf, wg)) * mm(buf, wu), wd)
+            return dropless_dispatch_combine(x, gates, experts, n, k, bm,
+                                             gemm)
+        return fn, (_sds(one, (tokens, E), BF16),
+                    _sds(one, (tokens, k), jnp.float32),
+                    _sds(one, (tokens, k), jnp.int32),
+                    _sds(one, (n, E, F), BF16), _sds(one, (n, E, F), BF16),
+                    _sds(one, (n, F, E), BF16)), True
+    return build
+
+
 def _quant(bits, M, N=1024):
     def build(devs):
         from deepspeed_tpu.ops.pallas.quant_matmul import (SMALL_M_XLA,
@@ -343,6 +371,40 @@ CASES = {
     "flash_fwd_bwd_train_mesh_fsdp4": _flash_train_mesh,
     "flash_fwd_bwd_inside_manual_fsdp4": _flash_inside_manual_dp,
 }
+
+
+#: a routed layer whole: (tokens of the step, widths) -> the fill's form
+ROUTED_LAYERS = {
+    "routed_layer_thinker_decode": ((48, THINKER_MOE), "dense"),
+    "routed_layer_thinker_chunk512": ((512, THINKER_MOE), "dense"),
+    "routed_layer_olmoe_decode": ((48, {}), "dense"),
+    "routed_layer_olmoe_chunk128": ((128, {}), "dense"),
+    "routed_layer_olmoe_rows2048": ((2048, {}), "gather"),
+}
+
+
+@pytest.mark.parametrize("name", list(ROUTED_LAYERS))
+def test_routed_layer_compiles_with_no_scatter(name, topo):
+    """The compiled layer holds no ``scatter`` instruction (the parent's
+    held three: the fill, the destinations, bincount's add; a TPU walks
+    each one update at a time), its fill is the form the step's shape
+    picks — a convolution under ``moe_dispatch`` for few tokens, a gather
+    for many — and the three grouped GEMMs are still the kernel."""
+    import re
+
+    import deepspeed_tpu.ops.pallas.grouped_matmul as gm
+
+    (tokens, widths), form = ROUTED_LAYERS[name]
+    fn, args, _ = _routed_layer(tokens, **widths)(topo.devices)
+    assert (tokens * args[0].shape[1] <= gm.DENSE_FILL_MAX_ELEMS) \
+        == (form == "dense")
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert not re.findall(r"= \S+ scatter\(", text)
+    assert _kernel_calls(text) == ["grouped_matmul_fwd"] * 3
+    dispatch = [ln for ln in text.splitlines() if "/moe_dispatch/" in ln]
+    assert any(" convolution(" in ln for ln in dispatch) == (form == "dense")
+    assert any(re.search(r" gather\(.*slice_sizes=\{1,\d{4}\}", ln)
+               for ln in dispatch) == (form == "gather")
 
 
 @pytest.mark.parametrize("name", list(CASES))

@@ -246,3 +246,50 @@ def test_moe_dropless_grads_flow():
     g = unbox_params(jax.jit(jax.grad(loss))(params))
     assert np.abs(np.asarray(g["gate"]["wg"])).sum() > 0
     assert np.abs(np.asarray(g["experts"]["w_up"])).sum() > 0
+
+
+@pytest.mark.parametrize("max_elems", [1 << 40, 0], ids=["dense", "gather"])
+def test_moe_dropless_layer_holds_no_scatter_and_keeps_its_grads(
+        monkeypatch, max_elems):
+    """The dropless training layer in either form of the fill (a one-hot
+    matmul for few tokens, a row gather for many): no ``scatter*`` primitive
+    in its forward, one in its gradient (the router's), and output and
+    gradients those of the per-token formulation."""
+    import deepspeed_tpu.ops.pallas.grouped_matmul as gm
+    from deepspeed_tpu.moe.sharded_moe import topk_dropless_gating
+    from deepspeed_tpu.runtime.zero.planner import unbox_params
+    from tests.test_grouped_matmul import _scatters as scatters
+
+    monkeypatch.setattr(gm, "DENSE_FILL_MAX_ELEMS", max_elems)
+    m = MoE(hidden_size=16, num_experts=4, ffn_size=32, k=2,
+            dropless=True, dropless_block_m=8, aux_loss_weight=0.0,
+            z_loss_weight=0.0)
+    x = jnp.asarray(np.random.default_rng(7).standard_normal((2, 8, 16)),
+                    jnp.float32)
+    params = unbox_params(m.init(jax.random.PRNGKey(0), x)["params"])
+
+    def loss(p, x):
+        return jnp.sum(jnp.sin(m.apply({"params": p}, x)))
+
+    def loss_ref(p, x):
+        g = topk_dropless_gating(
+            jnp.einsum("gse,en->gsn", x, p["gate"]["wg"]), 2)
+        ex = p["experts"]
+        h = jax.nn.silu(jnp.einsum("gse,gskef->gskf", x,
+                                   ex["w_gate"][g.experts])) \
+            * jnp.einsum("gse,gskef->gskf", x, ex["w_up"][g.experts])
+        y = jnp.einsum("gskf,gskfe->gske", h, ex["w_down"][g.experts])
+        return jnp.sum(jnp.sin(jnp.einsum("gsk,gske->gse", g.gates, y)))
+
+    # (the gradient of the ROUTER's top-k is a scatter-add onto [tokens, n]
+    # and stays: tests/test_grouped_matmul.py holds the dispatch's own
+    # gradient to none)
+    assert scatters(jax.make_jaxpr(loss)(params, x).jaxpr) == []
+    assert scatters(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(
+        params, x).jaxpr) == ["scatter-add"]
+    np.testing.assert_allclose(float(loss(params, x)),
+                               float(loss_ref(params, x)), rtol=1e-5)
+    got = jax.jit(jax.grad(loss, argnums=(0, 1)))(params, x)
+    want = jax.grad(loss_ref, argnums=(0, 1))(params, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
