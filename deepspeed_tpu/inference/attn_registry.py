@@ -28,11 +28,15 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-from ..ops.pallas.paged_attention import paged_attention_usable
+from ..ops.pallas.paged_attention import (ONE_TILE_ROWS,
+                                          paged_attention_usable)
 
-#: widest query-row tile paged_ragged_attention will run (its TQB cap) —
-#: tree nodes × GQA group must fit one tile
-QUERY_TILE_ROWS = 128
+#: the TREE form's one-tile limit: tree nodes × GQA group must ride ONE
+#: query tile of paged_ragged_attention (the per-node positions and the
+#: ancestors mask tile with it), and up to this many rows a KV head every
+#: call does (``paged_plan``; a prefill chunk's tile is planned from its
+#: shape and may be taller)
+QUERY_TILE_ROWS = ONE_TILE_ROWS
 
 #: int32 ancestors-mask bytes the tree q-tile may bind in VMEM. The
 #: decode kernel already budgets ~2MB for its f32 score tile; the mask

@@ -64,7 +64,7 @@ from ..parallel.topology import MeshConfig, MeshTopology
 from ..profiling.trace import register_program
 from ..utils.annotations import device_scope
 from ..utils.logging import logger
-from ..ops.pallas.paged_attention import (paged_attention_usable,
+from ..ops.pallas.paged_attention import (paged_attention_usable, paged_plan,
                                           paged_ragged_attention,
                                           paged_step_counts, paged_work_list)
 from .ragged import StateManager, StepPlan
@@ -710,6 +710,19 @@ class InferenceEngineV2:
                       reason_not_usable=no_pallas)
         self._attn_decode_sel = select_attention(mode="decode", **sel_kw)
         self._attn_paged = self._attn_decode_sel.is_pallas
+        #: the paged kernel's query tile for a whole prefill chunk, one
+        #: entry a kind of layer: ``paged_plan`` — what the kernel itself
+        #: calls — at the head counts a launch sees (a shard's, under a
+        #: mesh); the ``paged:`` log lines. A decode program's rows (one
+        #: token's query heads) ride one tile
+        self.paged_plans: dict[str, Any] = {}
+        if self._attn_paged:
+            plan = paged_plan(cfg.chunk * (m.num_heads // m.kv_heads),
+                              m.kv_heads // tp, cfg.block_size, cfg.dtype)
+            self.paged_plans = {k.name: plan for k in self._kinds}
+            for k in self._kinds:
+                logger.info(f"paged: {k.name}: a chunk of {cfg.chunk} "
+                            f"tokens is {plan.describe()}")
         self._attn_tree_sel = select_attention(
             mode="tree", tree_nodes=T_tree, stage_rows=Ts_tree, **sel_kw)
         if cfg.spec_verify_pallas is False:

@@ -45,6 +45,7 @@ scattered into the pool; ``seq_lens`` counts valid context tokens
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -190,6 +191,74 @@ def _ragged_geometry(stage_rows: int, block_size: int):
         raise ValueError(f"stage rows {stage_rows} must be a multiple of "
                          f"block_size {block_size} (or <= it)")
     return stage_rows // block_size, block_size
+
+
+#: rows a KV head up to which a call rides ONE query tile whatever its
+#: shape: every decode program (T = 1), the tree form (the node positions
+#: ride one tile: ``attn_registry.QUERY_TILE_ROWS``) and a short chunk
+ONE_TILE_ROWS = 128
+#: most bytes of a grid step's f32 score tile ``[KV, TQB, bs]`` (its lanes
+#: padded to 128, as VMEM holds them): what a tile's height is cut by
+SCORE_TILE_BYTES = 2 ** 21
+#: scoped VMEM a call with a tile taller than :data:`ONE_TILE_ROWS` may use
+#: (the compiler's default is 16 MiB of a v5e's 128). Beside the score tile
+#: a step holds the q and output blocks twice, the f32 accumulator, m and l
+#: (a lane each, padded to 128) and ~4 score tiles of temporaries: found by
+#: bisection on the compiler, 17 MiB at SmallThinker's 896 rows over 4 KV
+#: heads, 19 at its 1,024, 16 at Mistral's 512 over 8, 22 at 256 over 16
+VMEM_LIMIT_BYTES = 40 * 1024 * 1024
+
+
+class PagedPlan(NamedTuple):
+    """The query tile of one ragged-kernel call — :func:`paged_plan` makes
+    it, :func:`paged_ragged_attention` reads it and the serving engine logs
+    it (``paged:``)."""
+    TG: int                 # query rows a KV head: T tokens x G query heads
+    KV: int
+    block_size: int
+    tqb: int                # rows a query tile
+
+    @property
+    def n_tiles(self) -> int:
+        """Query tiles a call: EACH walks every live page of a slot."""
+        return self.TG // self.tqb
+
+    @property
+    def score_tile_bytes(self) -> int:
+        return self.KV * self.tqb * max(self.block_size, 128) * 4
+
+    def describe(self) -> str:
+        return (f"{self.TG} query rows a KV head ({self.KV} KV heads, page "
+                f"{self.block_size}): {self.n_tiles} query tile"
+                f"{'s' * (self.n_tiles > 1)} of {self.tqb} rows a call, "
+                f"each walks the slot's pages once; score tile "
+                f"{self.score_tile_bytes / 2**20:.2f} of "
+                f"{SCORE_TILE_BYTES / 2**20:.0f} MiB")
+
+
+def paged_plan(TG: int, KV: int, block_size: int, dtype,
+               tree: bool = False) -> PagedPlan:
+    """The query tile for ``TG`` rows a KV head: the TALLEST divisor of
+    ``TG`` that is a multiple of ``dtype``'s sublane tile and keeps the f32
+    score tile within :data:`SCORE_TILE_BYTES` — the grid is (query tiles,
+    work list), so a call walks its pages once a tile (SmallThinker's
+    512-token chunk, 3,584 rows: 4 tiles of 896 where a cap of 128 rows
+    made 28). The LOCAL shape decides: under a tensor mesh ``TG`` and
+    ``KV`` are the shard's. Where no such divisor is taller than
+    :data:`ONE_TILE_ROWS` (always for ``TG`` within it, and for the tree
+    form, whose per-row operands tile by 128 lanes) the tile is that many
+    rows, halved until it fits — never under 8 — and divides ``TG``."""
+    plan = lambda t: PagedPlan(TG, KV, block_size, t)
+    fits = lambda t: plan(t).score_tile_bytes <= SCORE_TILE_BYTES
+    if TG > ONE_TILE_ROWS and not tree:
+        sub = 32 // jnp.dtype(dtype).itemsize
+        tall = max((t for t in range(sub, TG + 1, sub)
+                    if TG % t == 0 and fits(t)), default=0)
+        if tall > ONE_TILE_ROWS:
+            return plan(tall)
+    halves = (min(TG, ONE_TILE_ROWS) >> i for i in range(8))
+    return plan(next(t for t in halves
+                     if TG % t == 0 and (t <= 8 or fits(t))))
 
 
 def _item_bits(nj: int) -> int:
@@ -545,15 +614,8 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
           .reshape(S, KV, T * G, D))
     TG = T * G
     # query-row tiles bound VMEM for long prefill chunks; stage pages
-    # bound it on the key side (uniform page-sized score tiles). Large
-    # pages widen the f32 score tile [KV, TQB, bs], so shrink TQB to
-    # keep it ~2MB (a 256-token page at TQB=128 overflows the 16MB
-    # scoped-vmem budget)
-    TQB = TG if TG <= 128 else 128
-    while TQB > 8 and KV * TQB * bs * 4 > 2 ** 21:
-        TQB //= 2
-    while TG % TQB:
-        TQB //= 2
+    # bound it on the key side (uniform page-sized score tiles)
+    TQB = paged_plan(TG, KV, bs, q.dtype, tree).tqb
     n_pool = max_pages
     nsp, srows = _ragged_geometry(Ts, bs)
     jbits = _item_bits(n_pool + nsp)
@@ -657,6 +719,11 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
         out_shape=jax.ShapeDtypeStruct((S, KV, TG, D), q.dtype),
         name=("paged_attn_tree" if tree else "paged_attn_decode" if T == 1
               else "paged_attn_prefill"),
+        # a call of one tile (every decode and tree program) is compiled as
+        # it always was; a taller tile states what it may use
+        compiler_params=(pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES)
+            if TQB > ONE_TILE_ROWS else None),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
       q_starts.astype(jnp.int32), stage_starts.astype(jnp.int32),

@@ -354,6 +354,14 @@ CASES = {
         6, 512, BF16, max_pages=128, **THINKER),
     "ragged_chunk512_thinker_ring_s6_p37": _ragged(6, 512, BF16,
                                                    **THINKER_RING),
+    # the cell's own prefill programs: one or two rows of a 512-token chunk,
+    # or one row of two (a packed plan), 3,584 / 7,168 query rows a KV head
+    # in tiles of 896 / 1,024 (``paged_plan``) — a VMEM refusal shows here
+    **{f"ragged_chunk{T}_thinker_{kind}_s{S}_p{geo['max_pages']}":
+       _ragged(S, T, BF16, **geo)
+       for T in (512, 1024) for S in (1, 2) for kind, geo in (
+           ("global", dict(max_pages=128, **THINKER)),
+           ("ring", THINKER_RING))},
     "grouped_gemm_thinker_decode_up": _grouped_serving(48, **THINKER_MOE),
     "grouped_gemm_thinker_decode_down": _grouped_serving(
         48, down=True, **THINKER_MOE),
@@ -447,6 +455,11 @@ def test_grouped_gemm_block_fits_the_plans_budget(name, topo, monkeypatch):
     ("ragged_decode_olmoe_s48_p32", "paged_attn_decode"),
     ("ragged_chunk128_olmoe_s8_p32", "paged_attn_prefill"),
     ("ragged_tree_olmoe_s48_p32", "paged_attn_tree"),
+    # a planned query tile (896 / 1,024 rows, PR 47) is the same form
+    ("ragged_chunk512_thinker_global_s1_p128", "paged_attn_prefill"),
+    ("ragged_chunk512_thinker_ring_s2_p37", "paged_attn_prefill"),
+    ("ragged_chunk1024_thinker_global_s2_p128", "paged_attn_prefill"),
+    ("ragged_chunk1024_thinker_ring_s1_p37", "paged_attn_prefill"),
     ("grouped_gemm_olmoe_decode_up", "grouped_matmul_fwd"),
     ("grouped_gemm_olmoe_prefill_down", "grouped_matmul_fwd"),
     # the whole K and V of a (row, kv head) at sequence 2048, head 128 stay

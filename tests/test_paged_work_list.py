@@ -387,3 +387,145 @@ def test_a_handed_list_is_the_list_the_kernel_builds():
                            block_size=8, max_pages=8, stage_rows=8)
     np.testing.assert_array_equal(np.asarray(_attend(a, work=work)),
                                   np.asarray(_attend(a)))
+
+
+# ---- (d) the query tile is planned from the shape (PR 47) -------------------
+
+def _capped_tile(TG, KV, bs):
+    """The tile as it was before ``paged_plan``: a cap of 128 rows, halved
+    to a 2 MiB score tile and to a divisor (transcribed, not shared)."""
+    TQB = TG if TG <= 128 else 128
+    while TQB > 8 and KV * TQB * bs * 4 > 2 ** 21:
+        TQB //= 2
+    while TG % TQB:
+        TQB //= 2
+    return TQB
+
+
+# (rows a KV head, KV heads, page) -> the tile under the 2 MiB budget
+PLANS = {
+    # T = 1: one token's query heads
+    "decode_smallthinker_28_over_4": ((7, 4, 128), 7),
+    "decode_mistral_32_over_8": ((4, 8, 128), 4),
+    "decode_olmoe_16_over_16": ((1, 16, 128), 1),
+    # OLMoE's whole prefill chunk is one tile already
+    "chunk128_olmoe": ((128, 16, 128), 128),
+    "chunk16_smallthinker": ((112, 4, 128), 112),
+    # 512 and 1,024 tokens x 7 query heads a KV head
+    "chunk512_smallthinker": ((3584, 4, 128), 896),
+    "chunk1024_smallthinker": ((7168, 4, 128), 1024),
+    "chunk256_smallthinker": ((1792, 4, 128), 896),
+    # 128 and 256 tokens x 4
+    "chunk128_mistral": ((512, 8, 128), 512),
+    "chunk256_mistral": ((1024, 8, 128), 512),
+    # under tensor: 4 a shard holds 2 of Mistral's KV heads
+    "chunk256_mistral_a_tensor_shard": ((1024, 2, 128), 1024),
+    "chunk1536_olmoe": ((1536, 16, 128), 256),
+    # a narrow page's score tile is lane-padded: no taller than at 128
+    "page_of_32": ((4096, 4, 32), 1024),
+    # no admissible divisor above 128: the capped tile stays
+    "wide_page_many_heads": ((3584, 16, 256), 128),
+    "thirty_two_kv_heads": ((512, 32, 128), 128),
+    "rows_with_no_aligned_divisor": ((262, 2, 16), 2),
+    "rows_whose_aligned_divisors_are_short": ((2 * 3 * 5 * 7 * 11, 2, 16), 2),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PLANS))
+def test_query_tile_is_planned_from_the_shape(name):
+    (TG, KV, bs), want = PLANS[name]
+    plan = pa.paged_plan(TG, KV, bs, jnp.bfloat16)
+    assert plan.tqb == want and plan.n_tiles * plan.tqb == TG
+    capped = _capped_tile(TG, KV, bs)
+    if want <= 128:
+        assert want == capped           # the parent's tile, to the row
+    else:
+        assert want % 16 == 0 and want > capped
+        assert plan.score_tile_bytes <= pa.SCORE_TILE_BYTES
+    # the tree form rides the capped tile whatever the shape
+    assert pa.paged_plan(TG, KV, bs, jnp.bfloat16, tree=True).tqb == capped
+    assert f"{plan.n_tiles} query tile" in plan.describe()
+
+
+def test_query_tile_over_a_sweep_of_shapes():
+    """Every call of at most 128 rows a KV head keeps the capped tile (all
+    decode and tree programs); a taller tile divides the rows, is sublane
+    aligned and never holds a score tile over the budget; the tallest
+    admissible divisor is the one taken."""
+    for KV in (1, 2, 4, 8, 16, 32):
+        for bs in (16, 32, 64, 128, 256):
+            for G in (1, 2, 4, 7, 8):
+                for T in (1, 2, 8, 16, 24, 64, 128, 256, 512, 1024, 1536):
+                    TG = T * G
+                    capped = _capped_tile(TG, KV, bs)
+                    for dt, sub in ((jnp.float32, 8), (jnp.bfloat16, 16)):
+                        tqb = pa.paged_plan(TG, KV, bs, dt).tqb
+                        assert TG % tqb == 0
+                        if TG <= 128 or tqb <= 128:
+                            assert tqb == capped, (TG, KV, bs)
+                            continue
+                        room = pa.SCORE_TILE_BYTES // (KV * max(bs, 128) * 4)
+                        assert tqb % sub == 0 and tqb <= room
+                        assert not any(TG % t == 0 for t in range(
+                            tqb + sub, min(room, TG) + 1, sub))
+
+
+def _chunk_cases():
+    g = dict(KV=2, G=7, D=64, bs=16, nb=40, max_pages=16, T=64)
+    return {
+        "full_causal": dict(S=1, ctx=[100], **g),
+        "a_first_chunk": dict(S=1, ctx=[0], **g),
+        # the window (three pages) slides off the first pages of slot 0
+        "sliding_window": dict(S=2, ctx=[150, 20], window=48, **g),
+        # a ring of 8 table slots under a window of 48: slot 0 has wrapped
+        # twice, slot 1 has not, slot 2 is a first chunk
+        "wrapped_ring": dict(S=3, ctx=[300, 40, 0], window=48, ring=True,
+                             **{**g, "max_pages": 8}),
+        # 37 + 50 fresh tokens: starts and ends inside a page, and the
+        # chunk's last 14 rows are padding
+        "chunk_ends_mid_page": dict(S=2, ctx=[37, 129], fresh=[50, 64], **g),
+        "an_empty_slot": dict(S=3, ctx=[100, 0, 55], fresh=[64, 0, 64], **g),
+        "all_slots_empty": dict(S=2, ctx=[0, 0], fresh=[0, 0], **g),
+        "fp8_pool": dict(S=2, ctx=[100, 37], kv_dtype=jnp.float8_e4m3fn,
+                         **g),
+        "ring_over_fp8_pool": dict(
+            S=2, ctx=[300, 40], window=48, ring=True,
+            kv_dtype=jnp.float8_e4m3fn, **{**g, "max_pages": 8}),
+        "four_slots_at_four_depths": dict(S=4, ctx=[0, 16, 100, 191], **g),
+        "mistral_groups_of_four": dict(
+            S=2, ctx=[100, 37], **{**g, "G": 4, "T": 128}),
+    }
+
+
+CHUNKS = _chunk_cases()
+
+
+@pytest.mark.parametrize("tiles", ["one_tall_tile", "two_tall_tiles"])
+@pytest.mark.parametrize("name", sorted(CHUNKS))
+def test_a_tall_query_tile_is_bitwise_the_128_row_tile(name, tiles,
+                                                       monkeypatch):
+    """A row's online softmax sees its pages in the same order and never
+    another row: the planned tile (all 448 rows a KV head, or two tiles of
+    224 under a budget pulled down) gives the capped tile's output bit for
+    bit (seven tiles of 64; at G = 4, 512 rows: four of 128)."""
+    c = CHUNKS[name]
+    a = _case(np.random.default_rng(47), **c)
+    TG, KV = c["T"] * c["G"], c["KV"]
+    if tiles == "two_tall_tiles":
+        monkeypatch.setattr(pa, "SCORE_TILE_BYTES", KV * (TG // 2) * 128 * 4)
+    plan = pa.paged_plan(TG, KV, c["bs"], jnp.float32)
+    assert plan.tqb > 128
+    assert plan.n_tiles == (1 if tiles == "one_tall_tile" else 2)
+    got = _attend(a)
+    capped = _capped_tile(TG, KV, c["bs"])
+    assert capped <= 128
+    monkeypatch.setattr(pa, "paged_plan", lambda *_a, **_k: pa.PagedPlan(
+        TG, KV, c["bs"], capped))
+    want = _attend(a)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert not np.isnan(np.asarray(got, np.float32)).any()
+    empty = np.asarray(a["seq_lens"]) == 0
+    assert not np.asarray(got)[empty].any()
+    tol = 8e-2 if a["pool"].dtype == jnp.float8_e4m3fn else 2e-5
+    np.testing.assert_allclose(np.asarray(got), _plain_softmax(a),
+                               rtol=tol, atol=tol)
