@@ -300,21 +300,24 @@ def check_file(path: str) -> list[str]:
 #: selection, consulted in exactly ONE forward dispatch site. History:
 #: per-call-site `if self._pallas_decode` conditionals are how the
 #: tree-verify path silently pinned the gather formulation — this check
-#: makes that regression structural.
+#: makes that regression structural. The boundary is a MODULE: the serving
+#: forward (inference/forward.py) is the only module of the package that
+#: imports the paged kernel, so no other can dispatch it.
 ENGINE_FILE = "deepspeed_tpu/inference/engine_v2.py"
-#: where the kernel entrypoint may be CALLED inside the engine
-ATTN_KERNEL_CALL_ALLOWED = {"_ragged_forward"}
-#: where the registry selections may be READ (dispatch + the counter +
-#: the init-time config-pin composition)
-ATTN_SEL_READ_ALLOWED = {"_ragged_forward", "_emit_attn_kernel", "__init__"}
-#: where they may be ASSIGNED / computed
+FORWARD_FILE = "deepspeed_tpu/inference/forward.py"
+#: the paged kernel's entry points, and the package that defines them
+ATTN_KERNEL_NAMES = {"paged_ragged_attention", "paged_work_list"}
+ATTN_KERNEL_HOME = "deepspeed_tpu/ops/pallas/"
+#: where the engine may READ the selections it computed (the counter + the
+#: init-time config-pin composition) and where it computes them
+ATTN_SEL_READ_ALLOWED = {"_emit_attn_kernel", "__init__"}
 ATTN_SEL_WRITE_ALLOWED = {"__init__"}
 
 
 class _AttnVisitor(ast.NodeVisitor):
-    """Engine-file walk for the registry pin: flags ad-hoc second
-    dispatch sites (kernel calls or selection reads outside the
-    allowlisted functions) and stray selection rebinds."""
+    """Engine-file walk for the registry pin: flags selection reads
+    outside the allowlisted functions (an ad-hoc second dispatch site)
+    and stray selection rebinds."""
 
     def __init__(self, path: str):
         self.path = path
@@ -335,14 +338,7 @@ class _AttnVisitor(ast.NodeVisitor):
     def visit_Call(self, node: ast.Call):
         name = node.func.attr if isinstance(node.func, ast.Attribute) \
             else node.func.id if isinstance(node.func, ast.Name) else ""
-        if name == "paged_ragged_attention" \
-                and not self._in(ATTN_KERNEL_CALL_ALLOWED):
-            self.violations.append(
-                f"{self.path}:{node.lineno}: paged_ragged_attention() "
-                f"called outside {sorted(ATTN_KERNEL_CALL_ALLOWED)} — "
-                f"the registry-routed forward is the ONLY kernel "
-                f"dispatch site")
-        elif name == "select_attention" \
+        if name == "select_attention" \
                 and not self._in(ATTN_SEL_WRITE_ALLOWED):
             self.violations.append(
                 f"{self.path}:{node.lineno}: select_attention() called "
@@ -364,33 +360,64 @@ class _AttnVisitor(ast.NodeVisitor):
                 self.violations.append(
                     f"{self.path}:{node.lineno}: {node.attr} read "
                     f"outside {sorted(ATTN_SEL_READ_ALLOWED)} — no "
-                    f"ad-hoc second dispatch site; route through "
-                    f"_ragged_forward / _emit_attn_kernel")
+                    f"ad-hoc second dispatch site; the forward "
+                    f"({FORWARD_FILE}) dispatches, _emit_attn_kernel "
+                    f"counts")
         self.generic_visit(node)
 
 
-def check_attn_registry(root: str) -> list[str]:
-    """Pin engine_v2's kernel-vs-gather routing to the attention
-    registry (see _AttnVisitor). Also requires the tree branch to
-    actually consult the registry: a forward that reads NEITHER
-    selection would mean dispatch regressed to an inline conditional."""
-    path = os.path.join(root, *ENGINE_FILE.split("/"))
-    if not os.path.exists(path):
-        return []
+def _kernel_imports(path: str) -> list[str]:
+    """Imports of the paged kernel's entry points in one file."""
     with open(path, encoding="utf-8") as f:
         src = f.read()
     try:
         tree = ast.parse(src, filename=path)
     except SyntaxError as e:
         return [f"{path}:{e.lineno}: unparseable ({e.msg})"]
+    return [
+        f"{path}:{node.lineno}: imports {a.name} — only {FORWARD_FILE} "
+        f"may: the registry-routed forward is the ONLY kernel dispatch "
+        f"site"
+        for node in ast.walk(tree) if isinstance(node, ast.ImportFrom)
+        for a in node.names if a.name in ATTN_KERNEL_NAMES]
+
+
+def check_attn_registry(root: str) -> list[str]:
+    """Pin the kernel-vs-gather routing to the attention registry: of the
+    package, inference/forward.py alone imports the paged kernel; it
+    consults BOTH selections (a forward that reads neither would mean
+    dispatch regressed to an inline conditional); engine_v2 computes them
+    once and reads them only to count (see _AttnVisitor)."""
+    path = os.path.join(root, *ENGINE_FILE.split("/"))
+    if not os.path.exists(path):
+        return []
+    out: list[str] = []
+    for dirpath, _, files in os.walk(os.path.join(root, "deepspeed_tpu")):
+        for f in sorted(files):
+            full = os.path.join(dirpath, f)
+            norm = full.replace(os.sep, "/")
+            if f.endswith(".py") and ATTN_KERNEL_HOME not in norm \
+                    and not norm.endswith(FORWARD_FILE):
+                out += _kernel_imports(full)
+    with open(path, encoding="utf-8") as f:
+        src = f.read()
+    try:
+        tree = ast.parse(src, filename=path)
+    except SyntaxError as e:
+        return out + [f"{path}:{e.lineno}: unparseable ({e.msg})"]
     v = _AttnVisitor(path)
     v.visit(tree)
-    out = v.violations
-    if "_attn_tree_sel" not in src or "_attn_decode_sel" not in src:
+    out += v.violations
+    fwd = os.path.join(root, *FORWARD_FILE.split("/"))
+    fwd_src = ""
+    if os.path.exists(fwd):
+        with open(fwd, encoding="utf-8") as f:
+            fwd_src = f.read()
+    if "attn_tree_sel" not in fwd_src or "attn_decode_sel" not in fwd_src:
         out.append(
-            f"{path}:1: _ragged_forward no longer consults the "
-            f"attention registry selections (_attn_decode_sel/"
-            f"_attn_tree_sel) — kernel-vs-gather must route through "
+            f"{fwd}:1: the forward no longer consults the attention "
+            f"registry selections (attn_decode_sel/attn_tree_sel) — "
+            f"kernel-vs-gather must route through "
             f"inference/attn_registry.py")
     return out
 

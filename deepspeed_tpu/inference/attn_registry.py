@@ -3,7 +3,7 @@
 The serving engine has two formulations of paged attention: the Pallas
 kernel (ops/pallas/paged_attention.py — block-table DMA gather, online
 softmax, no [S, ctx, KV, D] materialization) and the XLA gather fallback
-inside ``engine_v2._ragged_forward``. Historically each dispatch site
+inside ``inference/forward.py``. Historically each dispatch site
 carried its own ``if self._pallas_decode and ...`` conditional, which is
 how the tree-verify path silently pinned the gather formulation for a
 year of PRs. This module centralizes the decision:
@@ -12,11 +12,13 @@ year of PRs. This module centralizes the decision:
   returning an :class:`AttnSelection` — the chosen path plus a
   human-readable reason whenever the gather fallback wins. The engine
   computes one selection per mode at init (the inputs are all static),
-  routes ``_ragged_forward`` through it, surfaces it in ``ds_report``,
+  hands both to its forward (``forward.RaggedForward``), which routes
+  through them, surfaces it in ``ds_report``,
   and counts every dispatch against it
   (``serving_attn_kernel_total{path,mode}``).
 - A repo lint (bin/check_state_invariants.py::check_attn_registry) pins
-  that the engine has no ad-hoc second dispatch site.
+  that the engine has no ad-hoc second dispatch site: the forward's module
+  alone imports the kernel.
 
 Tree mode adds geometry gates on top of :func:`paged_attention_usable`:
 the T candidate nodes must fit ONE query-row tile (the kernel's

@@ -7,14 +7,17 @@ engine_v2.py:30 with ``put`` :107 / ``query`` :158 / ``can_schedule`` :184 /
 kernels kernels/ragged_ops/).
 
 Architecture (TPU-first, round-4 async design):
-- KV lives in ONE block-granular pool per model:
+- KV lives in ONE block-granular pool per KIND of layer (``kv_pool``: a
+  tuple, ``forward.cache_kinds``' order, for every model):
   [L, 2, KV, num_blocks, block_size, D], sharded over ``tensor`` on the
   KV-head dim. Sequences own block lists (host-side allocator,
-  inference/ragged.py). The pool is READ-ONLY inside every compiled
-  step: fresh K/V rides a small staged buffer through
-  ``paged_ragged_attention`` (ops/pallas/paged_attention.py — pool pages
-  + stage in one online softmax, all KV heads per grid step) and ONE
-  scatter per program merges it. Interleaving pool writes with the
+  inference/ragged.py). The pools are READ-ONLY inside the forward
+  (inference/forward.py — the model's ragged step, the only caller of the
+  serving path's Pallas kernels): fresh K/V rides a small staged buffer
+  through ``paged_ragged_attention`` (ops/pallas/paged_attention.py — pool
+  pages + stage in one online softmax, all KV heads per grid step), the
+  forward returns it, and ONE merge per program writes it, after the
+  forward and inside the same ``jit``. Interleaving pool writes with the
   attention custom call makes XLA materialize pool-sized copies — the
   measured difference is ~280ms vs ~8.5ms per decode token-step.
 - Steps are cached jitted programs — a SplitFuse plan ([S, chunk] prompt
@@ -30,11 +33,9 @@ Architecture (TPU-first, round-4 async design):
 """
 from __future__ import annotations
 
-import dataclasses
 import os
 import time
 from dataclasses import dataclass
-from functools import partial
 from typing import Any
 
 import flax.linen as nn
@@ -43,176 +44,29 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..models.transformer import (
-    _ACTS,
-    GLU_ACTS,
-    DenseFFN,
-    ModelConfig,
-    Norm,
-    TransformerLM,
-    apply_rope,
-    cache_kind,
-    default_activation_rules,
-    dense_ffn_config,
-    is_moe_layer,
-    kind_ropes,
-    qk_norm,
-)
-from ..parallel.tensor import (_ring_rs_core, allgather_matmul,
-                               matmul_reduce_scatter, overlap_counters)
+from ..models.transformer import (ModelConfig, TransformerLM,
+                                  default_activation_rules, is_moe_layer)
+from ..parallel.tensor import overlap_counters
 from ..parallel.topology import MeshConfig, MeshTopology
 from ..profiling.trace import register_program
 from ..utils.annotations import device_scope
 from ..utils.logging import logger
 from ..ops.pallas.paged_attention import (paged_attention_usable, paged_plan,
-                                          paged_ragged_attention,
-                                          paged_step_counts, paged_work_list)
+                                          paged_step_counts)
+# (``cache_kinds``, ``moe_tile_rows`` and ``moe_padded_rows`` are imported
+# from here by the benchmark's tools too)
+from .forward import (KIND_SPEC_2D, KIND_SPEC_3D, RaggedForward, cache_kinds,
+                      merge_rows, merge_step, moe_padded_rows, moe_tile_rows,
+                      stage_rows)
 from .ragged import StateManager, StepPlan
 from .sampling import sample_logits, sample_tree_logits
 from .scheduler import SpecAcceptTracker, SplitFuseScheduler
+from .speculative import (SPEC_BRANCHES, SPEC_DEPTH_MIXED_CAP, SPEC_NGRAM_MAX,
+                          SPEC_NGRAM_MIN, DraftModelProposer, NGramProposer,
+                          accept_walk)
 from .weights import load_tp_params
 
 Pytree = Any
-
-#: TP kind -> weight PartitionSpec, the single source for quantize-time
-#: sharding, matmul-time shard_map specs, and stacked-layer shardings.
-#: 2D = dense [K, N] QuantLinear; 3D = grouped [n, K, N] QuantGrouped.
-KIND_SPEC_2D = {"row": P("tensor", None), "col": P(None, "tensor"),
-                "rep": P(None, None)}
-KIND_SPEC_3D = {"row": P(None, "tensor", None),
-                "col": P(None, None, "tensor"),
-                "rep": P(None, None, None)}
-
-
-#: ``tp_overlap`` in auto mode: the fewest token rows a ring chunk
-#: (S*T // tensor) must carry before a program rings
-TP_OVERLAP_MIN_ROWS = 64
-
-#: floors of the routed-expert tile height: a bf16 tile is 16 sublanes; the
-#: quantised grouped GEMM was validated (and rings its chunks) at 32
-MOE_TILE_FLOOR = {False: 16, True: 32}
-
-
-def moe_tile_rows(tokens: int, top_k: int, num_experts: int,
-                  quantised: bool = False) -> int:
-    """Tile height of the routed-expert buffer of a step that carries
-    ``tokens`` rows (a static shape of the program): TWICE the mean number
-    of rows an expert gets, rounded up to a power of two, inside [floor,
-    128]. Twice, so that an expert's rows fill one tile with room for the
-    spread around the mean: a second tile for the same expert is one more
-    pass over its rows' column blocks. Chip timings behind the rule (one
-    OLMoE layer alone on a v5e, ``benchmark/tools/time_moe_layer.py``,
-    ``PERF.md`` PR 25): at 48 rows every height costs the same (1.30-1.33
-    ms; the kernel skips the buffer's empty tail), at 128 rows 32 wins
-    (1.37 ms against 1.55 at 16), at 512 and 2048 rows 128 wins (1.77 ms
-    against 2.75 at 16; 4.43 against 6.48)."""
-    mean2 = -(-2 * tokens * top_k // num_experts)
-    return min(128, max(MOE_TILE_FLOOR[bool(quantised)],
-                        1 << (mean2 - 1).bit_length()))
-
-
-def moe_padded_rows(tokens: int, top_k: int, num_experts: int,
-                    block_m: int) -> int:
-    """Rows of the tile-aligned buffer ``sort_tokens_by_expert`` makes."""
-    return -(-tokens * top_k // block_m) * block_m + num_experts * block_m
-
-
-def scan_layer_stack(stacked: Pytree, x, apply_layer, per_layer=None):
-    """``lax.scan`` of ``apply_layer(x, p, li, per_layer[li]) -> (x, ys)``
-    over the leading (depth) axis of ``stacked``; returns ``(x, stacked
-    ys)``. Layer ``li``'s weights are sliced out of the ``[L, ...]`` stack
-    INSIDE the body, so each slice is an operand of the op that consumes it
-    and XLA fuses it there: the matmuls read the stack in place. (Carrying
-    the slice of layer ``li + 1`` through the scan makes it a buffer, which
-    is a copy of every layer's weights every walk: 38 % of a decode
-    iteration on a v5e, ``PERF.md`` PR 24.) Module-level so that
-    ``tests/test_chip_compile.py`` can compile the walk alone."""
-    L = jax.tree.leaves(stacked)[0].shape[0]
-
-    def body(xc, inp):
-        li, extra = inp
-        with device_scope("weight_walk"):
-            p = jax.tree.map(
-                lambda s: jax.lax.dynamic_index_in_dim(
-                    s, li, 0, keepdims=False), stacked)
-        return apply_layer(xc, p, li, extra)
-
-    return jax.lax.scan(body, x,
-                        (jnp.arange(L, dtype=jnp.int32), per_layer))
-
-
-def scan_layer_periods(stacked: Pytree, x, apply_layer, period: int,
-                       per_layer=None):
-    """:func:`scan_layer_stack` for a stack whose layers come in PERIODS of
-    ``period`` kinds: one scan step walks a whole period, so the body knows
-    each layer's place ``j`` in the period — its kind — statically, and
-    still slices layer ``li``'s weights out of the ``[L, ...]`` stack in
-    place. ``apply_layer(x, p, li, per_layer[j][pi], j) -> (x, ys)``;
-    ``per_layer`` is None or one pytree a place, each with a leading axis
-    of ``L // period``. Returns ``(x, ys)`` with ``ys`` a tuple over ``j``
-    of that place's outputs stacked over the periods."""
-    L = jax.tree.leaves(stacked)[0].shape[0]
-
-    def body(xc, inp):
-        pi, extra = inp
-        ys = []
-        for j in range(period):
-            li = pi * period + j
-            with device_scope("weight_walk"):
-                p = jax.tree.map(
-                    lambda s: jax.lax.dynamic_index_in_dim(
-                        s, li, 0, keepdims=False), stacked)
-            xc, y = apply_layer(xc, p, li,
-                                None if extra is None else extra[j], j)
-            ys.append(y)
-        return xc, tuple(ys)
-
-    return jax.lax.scan(
-        body, x, (jnp.arange(L // period, dtype=jnp.int32), per_layer))
-
-
-@dataclass(frozen=True)
-class CacheKind:
-    """One kind of layer's KV cache as the engine holds it: which layers
-    write it, the mask they attend under, and the geometry of a sequence's
-    block table in its pool (``StateManager.kinds`` holds the allocator).
-    ``ring_tokens`` > 0: the table is a ring of that many token slots,
-    reused in place (a window kind narrower than a whole context)."""
-    name: str                      # "full" | "window"
-    layers: tuple[int, ...]        # the model's layers of this kind
-    window: int | None             # sliding-window mask (None: full)
-    max_blocks: int                # block-table width of a sequence
-    ring_tokens: int               # 0 = a table that grows
-    num_blocks: int                # blocks of its pool
-
-
-def cache_kinds(m: ModelConfig, cfg: "RaggedInferenceConfig"
-                ) -> tuple[CacheKind, ...]:
-    """The caches a model's layers need, the PRIMARY first ("full" where
-    the model has full layers). A window kind keeps a ring of
-    ceil((W + step) / block) + 1 blocks a sequence where that is narrower
-    than a whole context — the mistral rolling buffer: only the last window
-    (+ the step being written) stays resident. The primary's pool is
-    ``num_blocks``; a further kind's is every slot's whole ring
-    (``max_seqs`` x ring + the trash block), so it never refuses."""
-    bs = cfg.block_size
-    whole = -(-cfg.max_seq_len // bs)
-    of = [cache_kind(m.layer_kind(i)) for i in range(m.num_layers)]
-    names = sorted(set(of))                     # "full" < "window"
-    out = []
-    for name in names:
-        width, ring, W = whole, 0, None
-        if name == "window":
-            W = m.sliding_window
-            step_max = max(cfg.chunk, max(cfg.decode_window, 1))
-            nwin = -(-(W + step_max) // bs) + 1
-            if nwin < whole or len(names) > 1:
-                width = min(nwin, whole)
-                ring = width * bs
-        out.append(CacheKind(
-            name, tuple(i for i, k in enumerate(of) if k == name), W, width,
-            ring, cfg.num_blocks if not out else cfg.max_seqs * width + 1))
-    return tuple(out)
 
 
 class WeightSwapError(RuntimeError):
@@ -378,24 +232,15 @@ class RaggedInferenceConfig:
     #: pages) and under forced-ring tp_overlap (the verify forward runs
     #: all-position logits, which the token-sharded stream doesn't carry).
     spec_decode: str | None = None
-    #: max candidate chain depth per proposal round (adapted per tenant —
-    #: see spec_adapt); also bounds the draft mirror's decode budget
+    #: max candidate chain depth per proposal round (adapted per tenant
+    #: from the acceptance-rate EMA, scheduler.SpecAcceptTracker); also
+    #: bounds the draft mirror's decode budget. The n-gram proposer's
+    #: branches and n-gram lengths and the depth cap while prefill is
+    #: pending are constants of inference/speculative.py.
     spec_depth: int = 4
     #: candidate-tree node budget per sequence (root included); branchy
     #: n-gram proposals are truncated here so the verify width is bounded
     spec_max_nodes: int = 8
-    #: n-gram proposer: distinct candidate branches per tree
-    spec_branches: int = 2
-    #: n-gram proposer: longest/shortest history n-gram matched
-    spec_ngram_max: int = 3
-    spec_ngram_min: int = 1
-    #: cap on draft depth while prefill chunks are PENDING (the
-    #: decode_window_mixed_cap idea: a waiting first chunk must not sit
-    #: behind a max-depth verify round). 0 disables the cap.
-    spec_depth_mixed_cap: int = 2
-    #: adapt per-tenant draft depth from the acceptance-rate EMA
-    #: (scheduler.SpecAcceptTracker); False pins spec_depth for everyone
-    spec_adapt: bool = True
     #: speculative VERIFY attention formulation. None = auto (the kernel
     #: registry picks Pallas whenever the geometry allows — see
     #: attn_registry.select_attention). False pins the XLA gather
@@ -438,12 +283,6 @@ class RaggedInferenceConfig:
 
 
 class InferenceEngineV2:
-    #: least token-tile height of the quantised grouped GEMM (and
-    #: ``_qgmm``'s default): the sort alignment and the kernel must use the
-    #: SAME value for the tile→expert map to mean anything, so both take
-    #: ``moe_tile_rows`` of the step, which never goes below this
-    _MOE_GEMM_BLOCK_M = MOE_TILE_FLOOR[True]
-
     def __init__(self, model: TransformerLM, params: Pytree | None = None,
                  config: RaggedInferenceConfig | dict | None = None,
                  topology: MeshTopology | None = None,
@@ -553,6 +392,8 @@ class InferenceEngineV2:
         # --- weights: same tree as the trainer, TP-sharded ---------------
         self.params, plan = load_tp_params(model, params, rng, topology,
                                            cfg.dtype)
+        #: a quantised weight's TP kind, by weight name
+        self._qkind: dict[str, str] = {}
         if cfg.quant_bits:
             if cfg.quant_bits not in (4, 8, "fp8"):
                 raise ValueError(f"quant_bits must be 4, 8 or 'fp8', got "
@@ -618,9 +459,9 @@ class InferenceEngineV2:
         # kernel's per-page DMA ([KV, block_size, D] with the layer/half
         # offset folded into the index map) needs no reshape, and the
         # once-per-program stage merge scatters at (block, offset). The
-        # pool is READ-ONLY inside every compiled step (see
-        # _ragged_forward) — fresh KV rides a small staged buffer and is
-        # merged here exactly once per dispatch.
+        # pool is READ-ONLY inside the forward (inference/forward.py) —
+        # fresh KV rides a small staged buffer and is merged exactly once
+        # per dispatch.
         tp = max(topology.size("tensor"), 1)
         kv_spec = P(None, None, "tensor", None, None, None) \
             if m.kv_heads % tp == 0 else \
@@ -639,17 +480,15 @@ class InferenceEngineV2:
         self._kv_dtype = jnp.float8_e4m3fn \
             if cfg.kv_cache_dtype == "fp8" else cfg.dtype
         self._guard_pinned_layout_against_cache()
-        # one pool a kind: ``kv_pool`` is the array itself for a model of
-        # one kind, a tuple of arrays (``self._kinds``' order) for more
-        pools = tuple(jax.device_put(
+        #: one pool a kind of layer: a tuple in ``self._kinds``' order, for
+        #: every model
+        self.kv_pool = tuple(jax.device_put(
             jnp.zeros((len(k.layers), 2, m.kv_heads, k.num_blocks,
                        cfg.block_size, m.head_dim),
                       self._kv_dtype), self._pool_format)
             for k in self._kinds)
-        self.kv_pool = pools[0] if len(pools) == 1 else pools
         #: a jitted program's sharding of its ``kv_pool`` argument
-        self._pool_formats = self._pool_format if len(pools) == 1 \
-            else (self._pool_format,) * len(pools)
+        self._pool_formats = (self._pool_format,) * len(self._kinds)
         logger.info("cache: " + "; ".join(
             f"{k.name}: {len(k.layers)} layer(s), pool {k.num_blocks} "
             f"blocks of {cfg.block_size}, table {k.max_blocks} a sequence"
@@ -700,10 +539,10 @@ class InferenceEngineV2:
             no_pallas = ("kernel-unusable geometry (needs head_dim in "
                          "{64,128,256}, block_size % 8 == 0 and even "
                          "GQA groups)")
-        # tree-verify stage width: _ragged_forward pads T nodes to
-        # max(8, T) rows, rounded up to a page multiple past one page
+        # tree-verify stage width: the forward pads T nodes to max(8, T)
+        # rows, rounded up to a page multiple past one page
         T_tree = max(cfg.spec_max_nodes, 1)
-        Ts_tree = self._stage_rows(T_tree)
+        Ts_tree = stage_rows(T_tree, cfg.block_size)
         sel_kw = dict(num_heads=m.num_heads, kv_heads=m.kv_heads,
                       head_dim=m.head_dim, block_size=cfg.block_size,
                       use_pallas=self._pallas_decode,
@@ -738,7 +577,7 @@ class InferenceEngineV2:
 
         # ---- ring collective-matmul TP (latency-hiding overlap) ----------
         # static geometry gate; programs whose row count doesn't divide the
-        # axis additionally fall back per-program inside _ragged_forward
+        # axis additionally fall back per-program inside the forward
         ring_geom = (tp > 1 and m.num_heads % tp == 0
                      and m.kv_heads % tp == 0 and m.ffn_size % tp == 0)
         if cfg.tp_overlap and not ring_geom:
@@ -759,8 +598,16 @@ class InferenceEngineV2:
 
         self._programs: dict[int, Any] = {}
         #: the grouped GEMM's weight blocks, one entry a distinct expert
-        #: shape and block (``_gmm``; the ``gmm:`` log lines)
+        #: shape and block (``RaggedForward.gmm``; the ``gmm:`` log lines)
         self.gmm_plans: dict[tuple, Any] = {}
+        #: THE serving forward (inference/forward.py): everything it reads
+        #: of this engine, handed over once
+        self._forward = RaggedForward(
+            mcfg=m, config=cfg, kinds=self._kinds, topology=topology,
+            tp_ring_n=self._tp_ring_n, tp_ring_force=self._tp_ring_force,
+            attn_decode_sel=self._attn_decode_sel,
+            attn_tree_sel=self._attn_tree_sel, qkind=self._qkind,
+            gmm_plans=self.gmm_plans)
         self._rng = jax.random.PRNGKey(17)
         self._results: dict[int, list[int]] = {}
         # device-resident last sampled token per slot: decode steps read it
@@ -945,7 +792,7 @@ class InferenceEngineV2:
             self._spec.reqtrace = self._rt
         logger.info(
             f"engine_v2 up: blocks={cfg.num_blocks}x{cfg.block_size} "
-            f"pool={sum(p.nbytes for p in pools) / 1e6:.0f}MB "
+            f"pool={sum(p.nbytes for p in self.kv_pool) / 1e6:.0f}MB "
             f"max_seqs={cfg.max_seqs} "
             f"chunk={cfg.chunk} tp={topology.size('tensor')}")
         # the chosen attention formulation and, for the gather fallback,
@@ -997,8 +844,6 @@ class InferenceEngineV2:
         the proposer decodes exactly ``depth`` tokens per round and a
         window would run past them into the mirror's budget)."""
         cfg = self.config
-        from .speculative import DraftModelProposer, NGramProposer
-
         if cfg.spec_decode not in ("ngram", "draft"):
             raise ValueError(f"spec_decode must be None, 'ngram' or "
                              f"'draft', got {cfg.spec_decode!r}")
@@ -1025,8 +870,8 @@ class InferenceEngineV2:
         self._spec_tracker = SpecAcceptTracker(base_depth)
         if cfg.spec_decode == "ngram":
             self._spec = NGramProposer(
-                base_depth, ngram_max=cfg.spec_ngram_max,
-                ngram_min=cfg.spec_ngram_min, branches=cfg.spec_branches,
+                base_depth, ngram_max=SPEC_NGRAM_MAX,
+                ngram_min=SPEC_NGRAM_MIN, branches=SPEC_BRANCHES,
                 max_nodes=cfg.spec_max_nodes)
             return
         if draft_model is None:
@@ -1085,7 +930,8 @@ class InferenceEngineV2:
         multi-device mesh each tensor shard quantizes ITS slice inside a
         shard_map, so group boundaries live within shards and the codes/
         scales carry the same tensor-axis sharding as the bf16 weights
-        they replace. The matmuls then run per-shard via ``_qmm``.
+        they replace. The matmuls then run per-shard via
+        ``RaggedForward.qmm``.
         MoE routed-expert weights quantize into QuantGrouped slabs served
         by the grouped in-tile-dequant GEMM (reference cutlass_ops/
         moe_gemm/) — the gate and the qwen2-moe shared expert stay exact
@@ -1100,7 +946,6 @@ class InferenceEngineV2:
         m = self.mcfg
         mesh = self.topology.mesh
         tp = self.topology.size("tensor")
-        self._qkind: dict[str, str] = {}
         spec0 = plan.param_specs.get("layer_0", {})
 
         # one jitted per-shard quantize program per (kind, grouped): the
@@ -1195,961 +1040,6 @@ class InferenceEngineV2:
         logger.info(f"engine_v2 int{bits} weights: "
                     f"{before / 1e6:.0f}MB -> {after / 1e6:.0f}MB")
 
-    def _qmm(self, x2d, qw, name: str, li=None):
-        """Quantized matmul dispatch: single device runs the Pallas kernel
-        directly; on a mesh it runs per-shard through shard_map with specs
-        from the weight's TP kind (pallas_call has no GSPMD rule). ``row``
-        weights contract a sharded K, so the partial products psum over
-        the tensor axis — the same collective GSPMD inserts for the dense
-        einsum. ``li`` (a traced layer index) selects a layer of a
-        STACKED [L, ...] QuantLinear inside the kernel — the layer-scan
-        path passes the whole stack so no per-layer code copies are
-        materialized (measured r5: scan slices of int8 codes cost
-        ~0.57ms per decode iteration)."""
-        from jax import shard_map
-
-        from ..ops.pallas.quant_matmul import quant_matmul
-
-        mesh = self.topology.mesh
-        if mesh.size == 1:
-            return quant_matmul(x2d, qw, layer_index=li)
-        kind = self._qkind[name]
-        ws = KIND_SPEC_2D[kind]
-        if li is not None:
-            ws = P(None, *ws)       # stacked leaves carry a layer dim
-        xs = P(None, "tensor") if kind == "row" else P(None, None)
-        os_ = P(None, "tensor") if kind == "col" else P(None, None)
-
-        def fn(xl, ql, lil):
-            y = quant_matmul(xl, ql, layer_index=(None if li is None
-                                                  else lil))
-            return jax.lax.psum(y, "tensor") if kind == "row" else y
-
-        lia = jnp.zeros((), jnp.int32) if li is None else li
-        return shard_map(fn, mesh=mesh, in_specs=(xs, ws, P()),
-                         out_specs=os_, check_vma=False)(x2d, qw, lia)
-
-    def _gmm(self, x2d, w, srt, kind: str, block_m: int, li=None):
-        """Grouped (per-expert) bf16 matmul: ``w`` is one layer's
-        ``[n, K, N]`` or, with ``li``, the depth-stacked ``[L, n, K, N]``
-        (the kernel picks the layer). On a mesh the expert width is the
-        tensor-sharded dim, as for a dense FFN: ``kind`` "col" (gate/up)
-        keeps the output sharded, "row" (down) sums the partial products.
-        The kernel's weight block is ``gmm_plan``'s for the shapes the
-        launch sees (a shard's, under a mesh); each distinct one is logged
-        once, as a ``gmm:`` line, while the programs are traced, and kept
-        in ``self.gmm_plans``."""
-        from jax import shard_map
-
-        from ..ops.pallas.grouped_matmul import gmm_plan, grouped_matmul_layer
-
-        def launch(xl, wl, te, nt, lil):
-            plan = gmm_plan(wl.shape[-2], wl.shape[-1], block_m, xl.dtype)
-            seen = self.gmm_plans.setdefault(plan._replace(block_m=0), plan)
-            if seen is plan:
-                logger.info(f"gmm: {plan.describe()}")
-            return grouped_matmul_layer(xl, wl, te, nt, block_m,
-                                        layer_index=lil)
-
-        mesh = self.topology.mesh
-        ntp = self.topology.size("tensor")
-        if mesh.size == 1:
-            return launch(x2d, w, srt.tile_expert, srt.n_tiles, li)
-        width = w.shape[-1] if kind == "col" else w.shape[-2]
-        if ntp <= 1 or width % ntp:
-            kind = "rep"
-        lead = (None,) * (w.ndim - 3)
-        ws = P(*lead, *KIND_SPEC_3D[kind])
-        xs = P(None, "tensor") if kind == "row" else P(None, None)
-        os_ = P(None, "tensor") if kind == "col" else P(None, None)
-
-        def fn(xl, wl, te, nt, lil):
-            y = launch(xl, wl, te, nt, None if li is None else lil)
-            return jax.lax.psum(y, "tensor") if kind == "row" else y
-
-        lia = jnp.zeros((), jnp.int32) if li is None else li
-        return shard_map(fn, mesh=mesh,
-                         in_specs=(xs, ws, P(None), P(), P()),
-                         out_specs=os_, check_vma=False)(
-            x2d, w, srt.tile_expert, srt.n_tiles, lia)
-
-    def _qgmm(self, x2d, qw, tile_expert, name: str, li=None,
-              block_m: int | None = None):
-        """Grouped (per-expert) quantized matmul dispatch — the MoE
-        analogue of ``_qmm``; the tile→expert map is replicated.
-        ``block_m`` is the sort's tile height (``moe_tile_rows``)."""
-        from functools import partial
-
-        from jax import shard_map
-
-        from ..ops.pallas.quant_matmul import quant_grouped_matmul
-
-        bm = block_m or self._MOE_GEMM_BLOCK_M
-        gmm = partial(quant_grouped_matmul, block_m=bm)
-        mesh = self.topology.mesh
-        if mesh.size == 1:
-            return gmm(x2d, qw, tile_expert, layer_index=li)
-        kind = self._qkind[name]
-        ws = KIND_SPEC_3D[kind]
-        if li is not None:
-            ws = P(None, *ws)
-        xs = P(None, "tensor") if kind == "row" else P(None, None)
-        os_ = P(None, "tensor") if kind == "col" else P(None, None)
-        # grouped ring steps (tp_overlap): a row-kind expert GEMM's psum
-        # becomes a ring accumulation over token-TILE chunks — each step's
-        # partial grouped GEMM (chunk rows + matching tile→expert slice)
-        # overlaps the traveling accumulator's ppermute; chunks stay
-        # tile-aligned so the tile ownership invariant holds
-        ntp = self.topology.size("tensor")
-        ring = (kind == "row" and self._tp_ring_n and ntp > 1
-                and x2d.shape[0] % (ntp * bm) == 0)
-        if kind == "row" and self._tp_ring_n and not ring:
-            overlap_counters.fallback()
-
-        def fn(xl, ql, te, lil):
-            liA = None if li is None else lil
-            if not ring:
-                y = gmm(xl, ql, te, layer_index=liA)
-                return jax.lax.psum(y, "tensor") if kind == "row" else y
-
-            def dot(rows, start):
-                # the chunk's tile→expert slice rides the traced row
-                # offset; chunks are whole tiles by the ring gate above
-                tec = jax.lax.dynamic_slice(te, (start // bm,),
-                                            (rows.shape[0] // bm,))
-                return gmm(rows, ql, tec, layer_index=liA)
-
-            # unidirectional: the bidirectional half-chunk split need not
-            # stay tile-aligned
-            y_c = _ring_rs_core(xl, dot, ntp, "tensor", x2d.dtype,
-                                bidir=False)
-            return jax.lax.all_gather(y_c, "tensor", axis=0, tiled=True)
-
-        if ring:
-            n_out = qw.shape[-1]
-            overlap_counters.ring(
-                steps=ntp - 1,
-                bytes_permuted=(ntp - 1) * x2d.shape[0] * n_out * 4)
-
-        lia = jnp.zeros((), jnp.int32) if li is None else li
-        return shard_map(fn, mesh=mesh, in_specs=(xs, ws, P(None), P()),
-                         out_specs=os_, check_vma=False)(
-            x2d, qw, tile_expert, lia)
-
-    def _stage_rows(self, n: int) -> int:
-        """Rows of the staged-KV buffer that holds ``n`` fresh tokens a
-        slot: sublane-aligned, and page-divisible when it spans pages (the
-        kernel tiles the stage in block_size rows)."""
-        bs = self.config.block_size
-        rows = max(8, n)
-        return rows if rows <= bs else -(-rows // bs) * bs
-
-    # ------------------------------------------------------------------
-    # ragged forward (reads the TransformerLM param tree directly;
-    # reference model_implementations/inference_transformer_base.py:48)
-    # ------------------------------------------------------------------
-    def _ragged_forward(self, params, kv_pool, token_ids, positions, slot_map,
-                        block_tables, seq_lens, sample_idx,
-                        kv_stage=None, stage_fill=None, stage_starts=None,
-                        tree_mask=None):
-        """One ragged forward over a READ-ONLY pool.
-
-        The pool holds only ALREADY-MERGED tokens (positions
-        < stage_starts); this call's fresh K/V ride a small staged buffer
-        that attention overlays on the paged context. Measured round-4
-        rationale: interleaving pool scatters with the attention kernel
-        inside the layer scan forced XLA into pool-sized copies (~280ms
-        per decode step on a 1.6GB pool); with the pool read-only and ONE
-        merge per compiled program the same step is HBM-bound.
-
-        Default mode (``kv_stage`` None): stages are this step's tokens,
-        the merge happens HERE, returns (merged_pool, logits).
-        Window mode (``kv_stage`` = (k_buf, v_buf) [L, S, KV, Ws, D],
-        ``stage_fill`` = this iteration's row): writes row ``stage_fill``,
-        attends over rows < this iteration's length, returns
-        ((k_buf, v_buf), logits) and the CALLER merges after the loop.
-        Tree mode (``tree_mask`` [S, T, T] uint8): the speculative VERIFY
-        forward — row t of a sequence is a candidate-tree node whose
-        position is root + depth and whose visibility over the staged
-        fresh KV is ancestors-only (siblings share a POSITION, which
-        positional-causal masking cannot tell apart, hence the explicit
-        mask; the paged pool below the root stays position-causal).
-        Returns ((k_ys, v_ys), logits[S, T, V]) — ALL-node logits, no
-        pool merge: the caller merges only the ACCEPTED path's staged
-        rows, so rejected candidates never reach the pool. The Pallas
-        kernel serves tree mode too (per-node stage positions + the
-        ancestors mask ride into the kernel) whenever the registry's
-        tree selection picks it (attn_registry.select_attention —
-        geometry gates on top of the decode gate); the XLA gather
-        formulation is the counted fallback. Tree mode never rings
-        (all-position logits need the full residual stream).
-        """
-        m = self.mcfg
-        cfg = self.config
-        S, T = token_ids.shape
-        bs = cfg.block_size
-        H, KV, D = m.num_heads, m.kv_heads, m.head_dim
-        window_mode = kv_stage is not None
-        # everything a KIND of layer owns comes as the thing itself for a
-        # model of one kind and as a tuple (``self._kinds``' order) for
-        # more: pool, slot map, block table, staged buffers
-        kinds = self._kinds
-        many = len(kinds) > 1
-        per_kind, one = self._each_kind, self._per_kind
-        pools, slot_maps = per_kind(kv_pool), per_kind(slot_map)
-        tables = per_kind(block_tables)
-        #: the cache (an index into ``kinds``) of a layer kind
-        cache_of = {name: [k.name for k in kinds].index(cache_kind(name))
-                    for name in set(m.kinds_period)}
-        period = m.kinds_period
-        tree_mode = tree_mask is not None
-        q_starts = positions[:, 0]
-        if stage_starts is None:
-            stage_starts = q_starts
-        if window_mode:
-            kbufs, vbufs = per_kind(kv_stage[0]), per_kind(kv_stage[1])
-            Ts = kbufs[0].shape[3]
-        else:
-            Ts = self._stage_rows(T)
-
-        # ring collective-matmul TP: static per program — the token-sharded
-        # residual stream needs the row dim to divide the tensor axis
-        # (exact-k packed prefill plans with odd row counts fall back to
-        # the blocking einsum path, counted per compiled program), and the
-        # auto mode additionally requires ring chunks of at least
-        # TP_OVERLAP_MIN_ROWS rows (decode-sized programs would pay n×
-        # weight re-reads for a tiny hidden collective; tp_overlap=True
-        # overrides for measurement)
-        rn = self._tp_ring_n
-        if rn and (tree_mode or S % rn or not (
-                self._tp_ring_force
-                or (S * T) // rn >= TP_OVERLAP_MIN_ROWS)):
-            overlap_counters.fallback()
-            rn = 0
-        mesh_t = self.topology.mesh
-
-        from ..ops.pallas.quant_matmul import (QuantGrouped, QuantLinear,
-                                               quant_matmul)
-
-        # Layer-scanned quantized weights do NOT ride the scan xs: a
-        # scanned pallas operand forces a dynamic-slice COPY of the codes
-        # every iteration (~0.57ms per decode step measured on v5e).
-        # Instead the stacked QuantLinear/QuantGrouped leaves are stripped
-        # out here, closed over whole, and the kernels select the layer
-        # via a scalar-prefetched index (quant_matmul layer_index).
-        qstack: dict[str, Any] = {}
-        scanned_layers = params.get("layers_stacked")
-        if scanned_layers is not None and cfg.quant_bits:
-            from jax.tree_util import DictKey, tree_map_with_path
-
-            def _strip(path, leaf):
-                if isinstance(leaf, (QuantLinear, QuantGrouped)):
-                    key = "/".join(p.key for p in path
-                                   if isinstance(p, DictKey))
-                    qstack[key] = leaf
-                    return None
-                return leaf
-
-            is_q = lambda l: isinstance(l, (QuantLinear, QuantGrouped))
-            scanned_layers = tree_map_with_path(_strip, scanned_layers,
-                                                is_leaf=is_q)
-
-        # The same for the bf16 routed-expert slabs of an all-MoE stack: they
-        # are nearly all of a layer's bytes, and the grouped GEMM is a
-        # Pallas call — a slice of the stack would be copied before it
-        # reads a byte. Closed over whole; the kernel picks the layer.
-        xstack: dict[str, Any] = {}
-        if scanned_layers is not None and "moe" in scanned_layers:
-            ml0 = scanned_layers["moe"]["moe_layer"]
-            if all(w is not None and not isinstance(w, QuantGrouped)
-                   for w in ml0["experts"].values()):
-                xstack = dict(ml0["experts"])
-                scanned_layers = {
-                    **scanned_layers, "moe": {
-                        **scanned_layers["moe"], "moe_layer": {
-                            **ml0, "experts": {k: None for k in xstack}}}}
-
-        def proj_in(h, w, nh, name, li=None):
-            """[S,T,E] @ [E,(nh,D)] -> [S,T,nh,D]; QuantLinear weights run
-            the in-tile-dequant Pallas GEMM (per-shard under TP); ``w``
-            None means the weight lives in ``qstack`` (stacked quant)."""
-            if w is None:
-                w, nm = qstack[f"attn/{name}"], name
-                y = self._qmm(h.reshape(-1, h.shape[-1]), w, nm, li=li)
-                return y.reshape(S, T, nh, -1).astype(cfg.dtype)
-            if isinstance(w, QuantLinear):
-                y = self._qmm(h.reshape(-1, h.shape[-1]), w, name)
-                return y.reshape(S, T, nh, -1).astype(cfg.dtype)
-            return jnp.einsum("ste,ehd->sthd", h, w.astype(cfg.dtype))
-
-        def proj_out(o, w, li=None):
-            if w is None:
-                y = self._qmm(o.reshape(S * T, -1), qstack["attn/wo"],
-                              "wo", li=li)
-                return y.reshape(S, T, -1).astype(cfg.dtype)
-            if isinstance(w, QuantLinear):
-                y = self._qmm(o.reshape(S * T, -1), w, "wo")
-                return y.reshape(S, T, -1).astype(cfg.dtype)
-            return jnp.einsum("sthd,hde->ste", o, w.astype(cfg.dtype))
-
-        with device_scope("embed"):
-            x = params["embed"].astype(cfg.dtype)[token_ids]       # [S,T,E]
-            if m.position_embedding == "learned":
-                x = x + params["pos_embed"].astype(cfg.dtype)[positions]
-            if "ln_embed" in params:                               # bloom
-                x = Norm(m).apply({"params": params["ln_embed"]}, x)
-        if rn:
-            # token-sharded residual stream (Megatron-SP layout): norms and
-            # residual adds run 1/tp-sized per chip; the projections put
-            # the gather/scatter back via overlapped ring primitives
-            x = jax.lax.with_sharding_constraint(
-                x, NamedSharding(mesh_t, P("tensor", None, None)))
-
-        def routed_experts(ml, h, li, h_router=None):
-            """THE routed-expert layer of serving, quantised or not: router
-            -> dropless top-k (every token reaches its k experts; generation
-            must not drop a routed token — the FastGen v2 MoE contract) ->
-            sort, and gather the rows into a tile-aligned buffer (no
-            scatter: a one-hot matmul at a step's few rows, a row gather
-            at many) -> grouped GEMMs -> gather back and gate-weighted
-            sum. Only the GEMM differs: the bf16 Pallas grouped matmul, or
-            its in-tile-dequant twin over QuantGrouped slabs (reference
-            cutlass_ops/moe_gemm with mixed_gemm). The
-            dispatch/combine algebra is shared with the training dropless
-            path (moe/layer.py ``dropless_dispatch_combine``). NB this
-            diverges from the v1/training forward exactly when eval
-            capacity would bind — there v1 drops overflow tokens, v2
-            doesn't (tests/test_moe.py::
-            test_capacity_divergence_v1_drops_v2_routes_all)."""
-            from ..moe.layer import dropless_dispatch_combine
-            from ..moe.sharded_moe import topk_dropless_gating
-
-            mo = m.moe
-            Tt, E = S * T, h.shape[-1]
-            flat = h.reshape(Tt, E).astype(cfg.dtype)
-            # what the router reads, where that is not what the experts
-            # read (``MoEConfig.router_input``)
-            routed = flat if h_router is None \
-                else h_router.reshape(Tt, E).astype(cfg.dtype)
-            with device_scope("moe_router"):
-                logits = jnp.einsum("te,en->tn", routed.astype(jnp.float32),
-                                    ml["gate"]["wg"].astype(jnp.float32))
-                gate = topk_dropless_gating(
-                    logits[None], mo.top_k,
-                    normalize_gates=mo.normalize_gates)
-
-            def exw(k):      # stripped (stacked) slabs are closed over
-                w = ml["experts"].get(k)
-                if w is not None:
-                    return w, None
-                if k in xstack:
-                    return xstack[k], li
-                return qstack[f"moe/moe_layer/experts/{k}"], li
-
-            quantised = isinstance(exw("w_up")[0], QuantGrouped)
-            bm = moe_tile_rows(Tt, mo.top_k, mo.num_experts, quantised)
-
-            def gemm(buf, srt):
-                def mm(x, k, kind):
-                    w, wli = exw(k)
-                    if quantised:
-                        return self._qgmm(x, w, srt.tile_expert, f"moe_{k}",
-                                          li=wli, block_m=bm)
-                    return self._gmm(x, w if wli is not None
-                                     else w.astype(cfg.dtype), srt, kind, bm,
-                                     li=wli)
-
-                if m.activation in GLU_ACTS:
-                    z = GLU_ACTS[m.activation](mm(buf, "w_gate", "col")) \
-                        * mm(buf, "w_up", "col")
-                else:
-                    z = _ACTS[m.activation](mm(buf, "w_up", "col"))
-                return mm(z.astype(cfg.dtype), "w_down", "row")
-
-            out = dropless_dispatch_combine(
-                flat, gate.gates[0], gate.experts[0], mo.num_experts,
-                mo.top_k, bm, gemm)
-            return out.reshape(S, T, E).astype(cfg.dtype)
-
-        def ffn(p, h, use_moe: bool, li=None, h_router=None):
-            if use_moe and rn:
-                # routing needs the full token set (gate + expert sort over
-                # all tokens): gather the token-sharded stream once and run
-                # the MoE path replicated; the expert GEMMs themselves ring
-                # via _qgmm's grouped ring steps when the contraction is
-                # tensor-sharded
-                overlap_counters.fallback()
-                h = jax.lax.with_sharding_constraint(
-                    h, NamedSharding(mesh_t, P(None, None, None)))
-                if h_router is not None:
-                    h_router = jax.lax.with_sharding_constraint(
-                        h_router, NamedSharding(mesh_t, P(None, None, None)))
-            if use_moe:
-                out = routed_experts(p["moe"]["moe_layer"], h, li, h_router)
-                se = m.moe.shared_expert_intermediate
-                if se:   # qwen2-moe sigmoid-gated shared expert
-                    with device_scope("ffn"):
-                        shared_cfg = dataclasses.replace(
-                            m, intermediate_size=se)
-                        shared = DenseFFN(shared_cfg).apply(
-                            {"params": p["moe"]["shared_expert"]}, h)
-                        g = jax.nn.sigmoid(jnp.einsum(
-                            "ste,eo->sto", h.astype(jnp.float32),
-                            p["moe"]["shared_gate"].astype(jnp.float32)))
-                        out = out + g.astype(out.dtype) * shared
-                return out
-            f = p["ffn"]
-            if rn:
-                # ring FFN pair: gate/up share ONE all-gather⊗matmul ring,
-                # down is matmul⊗reduce-scatter back into the token-sharded
-                # stream. Mirrors DenseFFN.__call__ / the quant branch below
-                # — keep activations/biases in sync across the three.
-                def fwr(k):
-                    wv = f.get(k)
-                    if wv is None and f"ffn/{k}" in qstack:
-                        return qstack[f"ffn/{k}"]
-                    return wv if isinstance(wv, QuantLinear) \
-                        else wv.astype(cfg.dtype)
-
-                wu = fwr("w_up")
-                # dense layers of a mixed MoE stack may carry their own
-                # intermediate size — ring only when it divides the axis
-                if isinstance(wu, QuantLinear) or wu.shape[1] % rn == 0:
-                    h2 = h.reshape(S * T, -1)
-                    if m.activation in GLU_ACTS:
-                        g2, u2 = allgather_matmul(
-                            h2, (fwr("w_gate"), wu), mesh_t, layer_index=li)
-                        z = GLU_ACTS[m.activation](g2) * u2
-                    else:
-                        u2 = allgather_matmul(h2, wu, mesh_t, layer_index=li)
-                        z = _ACTS[m.activation](
-                            u2 + f["b_up"].astype(u2.dtype))
-                    y2 = matmul_reduce_scatter(
-                        z.astype(cfg.dtype), fwr("w_down"), mesh_t,
-                        layer_index=li)
-                    out = y2.reshape(S, T, -1).astype(cfg.dtype)
-                    if m.activation not in GLU_ACTS:
-                        out = out + f["b_down"].astype(cfg.dtype)
-                    return out
-                overlap_counters.fallback()
-            quant_ffn = isinstance(f.get("w_up"), QuantLinear) or (
-                "w_up" in f and f["w_up"] is None and "ffn/w_up" in qstack)
-            if quant_ffn:
-                # NB: mirrors DenseFFN.__call__ (models/transformer.py) with
-                # the matmuls swapped for quant_matmul — keep the two in
-                # sync when touching activations/biases
-                def fw(k):
-                    return f[k] if f.get(k) is not None \
-                        else qstack[f"ffn/{k}"]
-
-                h2d = h.reshape(-1, h.shape[-1])
-                if m.activation in GLU_ACTS:
-                    z = GLU_ACTS[m.activation](self._qmm(
-                        h2d, fw("w_gate"), "w_gate", li=li)) \
-                        * self._qmm(h2d, fw("w_up"), "w_up", li=li)
-                    out = self._qmm(z.astype(cfg.dtype), fw("w_down"),
-                                    "w_down", li=li)
-                else:
-                    z = self._qmm(h2d, fw("w_up"), "w_up", li=li) \
-                        + f["b_up"].astype(cfg.dtype)
-                    act = _ACTS[m.activation]
-                    out = self._qmm(act(z).astype(cfg.dtype),
-                                    fw("w_down"), "w_down", li=li) \
-                        + f["b_down"].astype(cfg.dtype)
-                return out.reshape(h.shape).astype(cfg.dtype)
-            return DenseFFN(dense_ffn_config(m)).apply({"params": f}, h)
-
-        def attention(p, li, h, stage_l, kind, c, lk):
-            """QKV → write into the STAGED buffer → ragged attention over
-            the read-only pool pages + the stage. Returns (o, stage_l').
-            ``kind``: the layer's kind (static); ``c`` its cache among
-            ``kinds``; ``lk`` the layer's index inside that cache's pool."""
-            a = p["attn"]
-            qli = li if qstack else None
-            with device_scope("attn_qkv"):
-                q, k, v = qkv(a, qli, h, kind)
-            with device_scope("kv_stage"):
-                stage_l = stage(k, v, stage_l)
-            with device_scope("attn_core"):
-                if many:     # window and global layers told apart, inside
-                    with (device_scope("attn_window") if kinds[c].window
-                          else device_scope("attn_full")):
-                        o = core(c, lk, q, stage_l)
-                else:
-                    o = core(c, lk, q, stage_l)
-            with device_scope("attn_out"):
-                return out_proj(a, qli, o), stage_l
-
-        def qkv(a, qli, h, kind):
-            if rn:
-                # ONE bidirectional ring gathers the token-sharded hidden
-                # while all three projections consume each arriving shard
-                # (fused QKV collective-matmul); quantized weights run
-                # quant_matmul per ring step, never a whole-shard dequant
-                def aw(name):
-                    wv = a[name]
-                    if wv is None:
-                        return qstack[f"attn/{name}"]
-                    if isinstance(wv, QuantLinear):
-                        return wv
-                    w2 = wv.astype(cfg.dtype)
-                    return w2.reshape(w2.shape[0], -1)
-                q2, k2, v2 = allgather_matmul(
-                    h.reshape(S * T, -1), (aw("wq"), aw("wk"), aw("wv")),
-                    mesh_t, layer_index=qli)
-                q = q2.reshape(S, T, H, -1).astype(cfg.dtype)
-                k = k2.reshape(S, T, KV, -1).astype(cfg.dtype)
-                v = v2.reshape(S, T, KV, -1).astype(cfg.dtype)
-            else:
-                q = proj_in(h, a["wq"], H, "wq", li=qli)
-                k = proj_in(h, a["wk"], KV, "wk", li=qli)
-                v = proj_in(h, a["wv"], KV, "wv", li=qli)
-            if m.qkv_bias:
-                q = q + a["bq"].astype(cfg.dtype)
-                k = k + a["bk"].astype(cfg.dtype)
-                v = v + a["bv"].astype(cfg.dtype)
-            if m.qk_norm:
-                q = qk_norm(m, q, a["q_norm"])
-                k = qk_norm(m, k, a["k_norm"])
-            if kind_ropes(m, kind):
-                q, k = apply_rope(q, k, positions, m.rope_theta, m.rotary_pct)
-            return q, k, v
-
-        def stage(k, v, stage_l):
-            """This step's K/V into the staged buffers."""
-            k_t = k.transpose(0, 2, 1, 3).astype(cfg.dtype)  # [S,KV,T,D]
-            v_t = v.transpose(0, 2, 1, 3).astype(cfg.dtype)
-            if window_mode:
-                k_st, v_st = stage_l
-                k_st = jax.lax.dynamic_update_slice(
-                    k_st, k_t, (0, 0, stage_fill, 0))
-                v_st = jax.lax.dynamic_update_slice(
-                    v_st, v_t, (0, 0, stage_fill, 0))
-            else:
-                pad = [(0, 0), (0, 0), (0, Ts - T), (0, 0)]
-                k_st = jnp.pad(k_t, pad)
-                v_st = jnp.pad(v_t, pad)
-            return k_st, v_st
-
-        def core(c, lk, q, stage_l):
-            """Ragged attention over the pool pages + the stage: the Pallas
-            kernel, or the XLA gather fallback — over cache ``c``'s pool
-            and block table, at layer ``lk`` of that pool."""
-            k_st, v_st = stage_l
-            # Sliding windows mask on every path; a window kind also serves
-            # from a ROLLING block table (ring_tokens > 0) so out-of-window
-            # KV blocks are reused instead of pinned.
-            win = kinds[c].window
-            ring = kinds[c].ring_tokens
-            ro_pool, block_tables = pools[c], tables[c]
-            attn_work = attn_works[c]
-            ctx = block_tables.shape[1] * bs
-            li_dev = jnp.asarray(lk, jnp.int32)
-            if sel.is_pallas:
-                # tree-verify stages ride two extra replicated operands:
-                # per-node absolute positions (root+depth) and the
-                # ancestors-only mask over the stage columns
-                t_ops = (positions, tree_mask) if tree_mode else ()
-                t_specs = (P(None, None), P(None, None, None)) \
-                    if tree_mode else ()
-
-                def _kernel(qq, pp, ks, vs, bt, sl, qs, ss, lr, wl, nw, *t):
-                    return paged_ragged_attention(
-                        qq, pp, ks, vs, bt, sl, qs, ss,
-                        block_size=bs, layer_index=lr, window=win,
-                        ring_tokens=ring, work=(wl, nw),
-                        tree_positions=t[0] if t else None,
-                        tree_mask=t[1] if t else None)
-
-                mesh = self.topology.mesh
-                if mesh.size > 1:
-                    # per-shard over the tensor axis: q on query heads, the
-                    # pool/stage on kv heads (the weight TP slicing)
-                    from jax import shard_map
-
-                    o = shard_map(
-                        _kernel,
-                        mesh=mesh,
-                        in_specs=(P(None, None, "tensor", None),
-                                  P(None, None, "tensor", None, None, None),
-                                  P(None, "tensor", None, None),
-                                  P(None, "tensor", None, None),
-                                  P(None, None), P(None), P(None), P(None),
-                                  P(), P(None), P(), *t_specs),
-                        out_specs=P(None, None, "tensor", None),
-                        check_vma=False,
-                    )(q, ro_pool, k_st, v_st, block_tables, seq_lens,
-                      q_starts, stage_starts, li_dev, *attn_work, *t_ops)
-                else:
-                    o = _kernel(q, ro_pool, k_st, v_st, block_tables,
-                                seq_lens, q_starts, stage_starts, li_dev,
-                                *attn_work, *t_ops)
-            else:
-                # fallback (alibi / odd geometries): gather each slot's
-                # pool pages (valid < stage_starts) and append the stage.
-                pool = ro_pool
-                blocks = jnp.repeat(block_tables, bs, axis=1)    # [S,ctx]
-                offs = jnp.tile(jnp.arange(bs), block_tables.shape[1])
-                K = pool[li_dev, 0, :, blocks, offs[None, :]]   # [S,ctx,KV,D]
-                V = pool[li_dev, 1, :, blocks, offs[None, :]]
-                K = jnp.concatenate([K.astype(cfg.dtype),
-                                     k_st.transpose(0, 2, 1, 3)], axis=1)
-                V = jnp.concatenate([V.astype(cfg.dtype),
-                                     v_st.transpose(0, 2, 1, 3)], axis=1)
-                if KV != H:
-                    K = jnp.repeat(K, H // KV, axis=2)
-                    V = jnp.repeat(V, H // KV, axis=2)
-
-                scores = jnp.einsum("sthd,schd->shtc", q, K).astype(jnp.float32)
-                scores = scores / (D ** 0.5)
-                sstart = stage_starts[:, None]
-                if ring:
-                    # rolling buffer: recover each gathered offset's
-                    # absolute position (same algebra as the kernel);
-                    # pool-latest is the token BEFORE the stage
-                    nwin = ring // bs
-                    b_latest = jnp.maximum(sstart - 1, 0) // bs
-                    jidx = (jnp.arange(ctx) // bs)[None, :]
-                    b_j = b_latest - (b_latest - jidx) % nwin
-                    raw = b_j * bs + (jnp.arange(ctx) % bs)[None, :]
-                    cpos_pool = jnp.where(raw < sstart, raw,
-                                          raw - ring)           # [S,ctx]
-                    valid_pool = cpos_pool >= 0
-                else:
-                    # pages are position-ordered: context index j IS
-                    # absolute position j, valid while before the stage
-                    cpos_pool = jnp.broadcast_to(jnp.arange(ctx)[None, :],
-                                                 (S, ctx))
-                    valid_pool = cpos_pool < sstart
-                if tree_mode:
-                    # stage entries are tree nodes: their ABSOLUTE
-                    # positions come from the positions array (root +
-                    # depth; siblings share one), not a contiguous ramp —
-                    # alibi's relative bias below reads these; validity/
-                    # causality over the stage is the ancestors-only mask
-                    cpos_st = jnp.pad(positions, ((0, 0), (0, Ts - T)))
-                else:
-                    cpos_st = sstart + jnp.arange(Ts)[None, :]   # [S,Ts]
-                cpos = jnp.concatenate([cpos_pool, cpos_st], axis=1)
-                valid = jnp.concatenate(
-                    [valid_pool, cpos_st < seq_lens[:, None]], axis=1)
-                valid = valid[:, None, None, :]
-                if m.position_embedding == "alibi":
-                    from ..models.transformer import alibi_slopes
-
-                    slopes = alibi_slopes(H)                       # [H]
-                    rel = (cpos.astype(jnp.float32)[:, None, None, :]
-                           - positions[:, None, :, None].astype(jnp.float32))
-                    scores = scores + slopes[None, :, None, None] * rel
-                causal = cpos[:, None, :] <= positions[:, :, None]
-                if win:
-                    causal &= cpos[:, None, :] > positions[:, :, None] - win
-                mask = valid & causal[:, None, :, :]
-                if tree_mode:
-                    # stage columns: ancestors-only visibility replaces
-                    # the positional mask entirely (padding nodes carry
-                    # all-zero mask rows except their self-bit, set by
-                    # the caller); pool columns keep the causal mask —
-                    # every node descends from the committed context
-                    tm = jnp.pad(tree_mask.astype(bool),
-                                 ((0, 0), (0, 0), (0, Ts - T)))
-                    mask = jnp.concatenate(
-                        [mask[..., :ctx], tm[:, None, :, :]], axis=-1)
-                scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
-                w = jax.nn.softmax(scores, axis=-1).astype(V.dtype)
-                o = jnp.einsum("shtc,schd->sthd", w, V)
-            return o
-
-        def out_proj(a, qli, o):
-            if rn:
-                # row-parallel out-proj: partial outputs ring-accumulate
-                # toward their owner's token chunk instead of blocking on
-                # the GSPMD all-reduce; output rejoins the token-sharded
-                # residual stream directly
-                wo = a["wo"] if a["wo"] is not None else qstack["attn/wo"]
-                if not isinstance(wo, QuantLinear):
-                    wo = wo.astype(cfg.dtype).reshape(-1, wo.shape[-1])
-                o2 = matmul_reduce_scatter(
-                    o.reshape(S * T, -1), wo, mesh_t, layer_index=qli)
-                o = o2.reshape(S, T, -1).astype(cfg.dtype)
-            else:
-                o = proj_out(o, a["wo"], li=qli)
-            if m.attn_out_bias:
-                o = o + a["bo"].astype(cfg.dtype)
-            return o
-
-        def norm(p_ln, x):
-            with device_scope("norm"):
-                return Norm(m).apply({"params": p_ln}, x)
-
-        def layer(x, p, li, use_moe, stage_l, kind, lk):
-            qli = li if qstack else None
-            h_attn = norm(p["ln_attn"], x)
-            o, stage_l = attention(p, li, h_attn, stage_l, kind,
-                                   cache_of[kind], lk)
-            if not m.parallel_block:
-                x = x + o
-            h_ffn = h_attn if m.parallel_block \
-                and m.parallel_block_norms == 1 else norm(p["ln_ffn"], x)
-            if use_moe:     # its own scopes: router, dispatch, experts...
-                f = ffn(p, h_ffn, True, li,
-                        h_attn if m.moe.router_input == "attn" else None)
-            else:
-                with device_scope("ffn"):
-                    f = ffn(p, h_ffn, False, qli)
-            return (x + o + f if m.parallel_block else x + f), stage_l
-
-        # the pools stay read-only for the whole program: `core` reads
-        # ``pools``, never the (later re-bound) kv_pool
-        # kernel-vs-gather comes from the attention registry's static
-        # per-mode selection (attn_registry.py) — the ONLY dispatch
-        # decision point, pinned by check_attn_registry in
-        # bin/check_state_invariants.py
-        sel = self._attn_tree_sel if tree_mode else self._attn_decode_sel
-        # the paged kernel's steps, the same for every layer: built here,
-        # outside the layer loop (`core` closes over both)
-        # (one list a kind of layer: a window kind's is bounded by its
-        # window, over its own table)
-        attn_works = [()] * len(kinds)
-        if sel.is_pallas:
-            with device_scope("attn_core"):
-                attn_works = [paged_work_list(
-                    seq_lens, q_starts, stage_starts, block_size=bs,
-                    max_pages=tables[c].shape[1], stage_rows=Ts,
-                    window=k.window, ring_tokens=k.ring_tokens,
-                    tree=tree_mode) for c, k in enumerate(kinds)]
-        empty_stage = (jnp.zeros((S, KV, Ts, D), cfg.dtype),) * 2
-        P_ = len(period)
-        if "layers_stacked" in params and not many:
-            # scan over depth: ONE traced layer body regardless of L; the
-            # pool never enters the carry — only the small staged KV does
-            def body(xc, p, li, stage_l):
-                return layer(xc, p, li, is_moe_layer(m, 0),
-                             stage_l if window_mode else empty_stage,
-                             period[0], li)
-
-            x, (k_ys, v_ys) = scan_layer_stack(
-                scanned_layers, x, body, kv_stage if window_mode else None)
-            k_ys, v_ys = (k_ys,), (v_ys,)
-        elif "layers_stacked" in params:
-            # a scan over PERIODS: place j of a period fixes the layer's
-            # kind, its cache c and its rank r among that cache's layers of
-            # the period — layer pi * P + j is layer pi * n_c + r of pool c
-            place = []
-            for j, kind in enumerate(period):
-                c = cache_of[kind]
-                place.append((c, sum(cache_of[kk] == c
-                                     for kk in period[:j])))
-            n_in = [sum(cc == c for cc, _ in place)
-                    for c in range(len(kinds))]
-            xs = None
-            if window_mode:
-                split = lambda b, c: b.reshape(-1, n_in[c], *b.shape[1:])
-                xs = [(split(kbufs[c], c)[:, r], split(vbufs[c], c)[:, r])
-                      for c, r in place]
-
-            def body(xc, p, li, stage_l, j):
-                c, r = place[j]
-                return layer(xc, p, li, is_moe_layer(m, 0),
-                             stage_l if window_mode else empty_stage,
-                             period[j], (li // P_) * n_in[c] + r)
-
-            x, ys = scan_layer_periods(scanned_layers, x, body, P_, xs)
-            k_ys, v_ys = [], []
-            for c in range(len(kinds)):
-                for out, half in ((k_ys, 0), (v_ys, 1)):
-                    y = jnp.stack([ys[j][half] for j in range(P_)
-                                   if place[j][0] == c], axis=1)
-                    out.append(y.reshape(-1, *y.shape[2:]))
-        else:
-            lists = [([], []) for _ in kinds]
-            for i in range(m.num_layers):
-                use_moe = is_moe_layer(m, i)
-                c = cache_of[m.layer_kind(i)]
-                lk = kinds[c].layers.index(i)
-                stage_l = (kbufs[c][lk], vbufs[c][lk]) if window_mode \
-                    else empty_stage
-                x, stage_l = layer(x, params[f"layer_{i}"], i, use_moe,
-                                   stage_l, m.layer_kind(i), lk)
-                lists[c][0].append(stage_l[0])
-                lists[c][1].append(stage_l[1])
-            k_ys = [jnp.stack(ks) for ks, _ in lists]
-            v_ys = [jnp.stack(vs) for _, vs in lists]
-
-        def head(x):
-            x = Norm(m).apply({"params": params["ln_final"]}, x)
-            if tree_mode:
-                # the verify step samples at EVERY tree node: all-position
-                # logits ([S*T, E] rows through the same projection paths)
-                last = x.reshape(S * T, -1)
-            else:
-                last = jnp.take_along_axis(
-                    x, sample_idx[:, None, None].astype(jnp.int32),
-                    axis=1)[:, 0]                                      # [S,E]
-            if rn:
-                # leave the token-sharded stream: the logits projection reads
-                # S rows total — replicating them is noise next to the weight
-                last = jax.lax.with_sharding_constraint(
-                    last, NamedSharding(mesh_t, P(None, None)))
-            if m.tie_embeddings:
-                if "logits_q" in params:
-                    # tied models keep the embedding gather exact but project
-                    # logits through an int8 COPY of the table — the decode
-                    # step's single largest weight read (103MB bf16 on
-                    # gpt2-350m, ~0.14ms/token). At M<=8 rows quant_matmul's
-                    # small-M dispatch routes this through XLA's fused
-                    # dequant-dot (convert+mul folded into the operand read:
-                    # measured 122us vs 138 bf16 vs 271 for the Pallas tile
-                    # kernel, whose whole-table dequant is VPU-bound at few
-                    # rows); int4 keeps the Pallas kernel (XLA can't fuse the
-                    # nibble unpack). Both single- and multi-device go
-                    # through _qmm — per-shard, the same dispatch applies.
-                    logits = self._qmm(last, params["logits_q"], "logits")
-                else:
-                    logits = jnp.einsum("se,ve->sv", last,
-                                        params["embed"].astype(cfg.dtype))
-            elif isinstance(params["unembed"], QuantLinear):
-                logits = self._qmm(last, params["unembed"], "unembed")
-            else:
-                logits = jnp.einsum("se,ev->sv", last, params["unembed"].astype(cfg.dtype))
-            if m.unembed_bias:
-                logits = logits + params["unembed_b"].astype(cfg.dtype)
-            return logits
-
-        with device_scope("head"):
-            logits = head(x)
-        if tree_mode:
-            # verify mode: NO pool write here — the caller merges only
-            # the accepted path's staged rows (_spec_merge_program), so
-            # rejected candidates never touch the pool
-            return (one(k_ys), one(v_ys)), logits.reshape(S, T, -1)
-        if window_mode:
-            # the window loop keeps accumulating into the staged buffers;
-            # the caller merges them into the pool once, after the loop
-            return (one(k_ys), one(v_ys)), logits
-
-        # ---- the ONE pool write of this program -------------------------
-        # every layer's fresh K/V lands at its (block, offset) slot;
-        # padded tokens carry trash-block slots (block 0) by construction.
-        # DUS merges avoid the scatter layout war (see _merge_stage: at
-        # this PR's cell the scatter held a copy of the window layers'
-        # whole 1.3 GiB pool as a temporary of every prefill step);
-        # page-misaligned chunks keep the scatter.
-        merged = []
-        for k, pool, slots, kc, vc in zip(kinds, pools, slot_maps, k_ys,
-                                          v_ys):
-            L = len(k.layers)
-            if T == 1:
-                pool = self._merge_rows(
-                    pool, slots[:, 0],
-                    kc[:, :, :, 0, :], vc[:, :, :, 0, :])
-            elif T % bs == 0:
-                # (a ring too: the slot a whole page lands in held a page
-                # more than a window + a step older, dead to every query
-                # from this chunk on, and the rows past the chunk's real
-                # tokens read as that older wrap: masked by the window)
-                pool = self._merge_pages(pool, slots, kc, vc, T)
-            else:
-                with device_scope("kv_commit"):
-                    ks = (kc[:, :, :, :T, :].transpose(0, 1, 3, 2, 4)
-                          .reshape(L, S * T, KV, D))
-                    vs = (vc[:, :, :, :T, :].transpose(0, 1, 3, 2, 4)
-                          .reshape(L, S * T, KV, D))
-                pool = self._merge_stage(pool, slots.reshape(-1), ks, vs)
-            merged.append(pool)
-        return one(merged), logits
-
-    def _merge_stage(self, kv_pool, flat_slots, ks, vs):
-        """THE pool write: scatter staged K/V rows (``[L, N, KV, D]``,
-        row n ↔ flat pool slot ``flat_slots[n]``) into the block-granular
-        pool. Shared by the per-step program (stage = this step's tokens)
-        and the window program (stage = the whole window) — the
-        [L, 2, KV, nb, bs, D] indexing convention lives HERE only.
-
-        NB on layout: an XLA scatter layout-assigns the pool to a
-        scatter-friendly permutation while the pallas reads need
-        row-major, costing full-pool layout-permute copies per compiled
-        step (~23ms/window on a 1.6GB pool; a flat [rows, D] scatter is
-        WORSE — column-major preference; layout_constraint pins don't
-        override scatter's mandatory layout). Callers therefore prefer
-        the layout-NEUTRAL dynamic-update-slice merges (``_merge_rows``,
-        ``_merge_pages``) and fall back here only for configurations
-        those can't express."""
-        with device_scope("kv_commit"):
-            bs = self.config.block_size
-            blk, off = flat_slots // bs, flat_slots % bs
-            liL = jnp.arange(kv_pool.shape[0])
-            kv_pool = kv_pool.at[liL[:, None], 0, :, blk[None, :],
-                                 off[None, :]].set(ks.astype(kv_pool.dtype))
-            kv_pool = kv_pool.at[liL[:, None], 1, :, blk[None, :],
-                                 off[None, :]].set(vs.astype(kv_pool.dtype))
-            return kv_pool
-
-    def _merge_rows(self, kv_pool, flat_slots, k_rows, v_rows):
-        """Token-granular pool merge: one dynamic-update-slice per row
-        (``k_rows/v_rows`` [L, N, KV, D], row n ↔ flat slot n). DUS is
-        layout-neutral and in-place — no scatter layout war — and row
-        granularity never clobbers neighbouring rows, so it is safe in
-        ring (rolling-buffer) mode too. N is small by construction
-        (decode plans: S; windows: W*S)."""
-        with device_scope("kv_commit"):
-            bs = self.config.block_size
-            kv_rows = jnp.stack([k_rows, v_rows], axis=1).astype(kv_pool.dtype)
-            z = jnp.int32(0)
-            for n in range(flat_slots.shape[0]):
-                upd = kv_rows[:, :, n][:, :, :, None, None, :]  # [L,2,KV,1,1,D]
-                kv_pool = jax.lax.dynamic_update_slice(
-                    kv_pool, upd,
-                    (z, z, z, flat_slots[n] // bs, flat_slots[n] % bs, z))
-            return kv_pool
-
-    def _merge_pages(self, kv_pool, slot_map, k_ys, v_ys, T):
-        """Page-granular pool merge for SplitFuse chunk steps
-        (``k_ys/v_ys`` [L, S, KV, Ts, D], token t of row s ↔
-        ``slot_map[s, t]``). Chunk starts are page-aligned whenever
-        chunk %% block_size == 0, so each page of a prefill row is one
-        whole-page DUS (rows past the chunk's real tokens land in the
-        not-yet-valid region — harmless). Rows carrying a single token
-        (fused decode rows, 1-token final chunks, inactive padding) must
-        NOT page-write (their page holds live earlier rows): for those
-        the page update degrades to a read-back of the current page, and
-        a per-row token DUS writes the one real token."""
-        with device_scope("kv_commit"):
-            L, _, KV, nb, bs, D = kv_pool.shape
-            S = slot_map.shape[0]
-            z = jnp.int32(0)
-            n_real = (slot_map >= bs).sum(axis=1)          # trash slots < bs
-            for s in range(S):
-                # page-write only rows that really carry a chunk AND start on
-                # a page boundary (the scheduler advances kv_next in whole
-                # chunks so this holds today; the traced check pins the
-                # invariant rather than assuming it)
-                no_page = (n_real[s] <= 1) | (slot_map[s, 0] % bs != 0)
-                for pg in range(T // bs):
-                    sl = pg * bs
-                    page = jnp.stack(
-                        [k_ys[:, s, :, sl:sl + bs, :],
-                         v_ys[:, s, :, sl:sl + bs, :]],
-                        axis=1)[:, :, :, None].astype(kv_pool.dtype)
-                    blk = slot_map[s, sl] // bs
-                    if pg == 0:
-                        # read-modify-write: a single-token/misaligned row's
-                        # first page holds live earlier KV
-                        cur = jax.lax.dynamic_slice(
-                            kv_pool, (z, z, z, blk, z, z), (L, 2, KV, 1, bs, D))
-                        page = jnp.where(no_page, cur, page)
-                    else:
-                        # later pages of degraded rows carry trash slots
-                        # (block 0) — writing garbage there is the existing
-                        # trash-block convention, no read-back needed
-                        blk = jnp.where(no_page, 0, blk)
-                    kv_pool = jax.lax.dynamic_update_slice(
-                        kv_pool, page, (z, z, z, blk, z, z))
-            # every row's first token (covers degraded rows; for full chunks
-            # this rewrites the value the page already wrote)
-            return self._merge_rows(kv_pool, slot_map[:, 0],
-                                    k_ys[:, :, :, 0, :], v_ys[:, :, :, 0, :])
-
     def _program(self, T: int, S_rows: int | None = None):
         """Step program for a [S_rows, T] plan. Packed prefill plans
         (S_rows < max_seqs) carry fewer, wider rows — the token-budget
@@ -2169,9 +1059,11 @@ class InferenceEngineV2:
                     jnp.where(use_last.astype(bool), row_last,
                               token_ids[:, 0]))
                 with nn.logical_axis_rules(self._rules):
-                    kv_pool, logits = self._ragged_forward(
-                        params, kv_pool, token_ids, positions, slot_map,
+                    (k_ys, v_ys), logits = self._forward(
+                        params, kv_pool, token_ids, positions,
                         block_tables, seq_lens, sample_idx)
+                    # the ONE pool write of this program
+                    kv_pool = merge_step(kv_pool, slot_map, k_ys, v_ys, T)
                 cfg = self.config
                 with device_scope("sample"):
                     toks = sample_logits(logits.astype(jnp.float32), rng,
@@ -2227,19 +1119,18 @@ class InferenceEngineV2:
             cfg = self.config
             bs = cfg.block_size
             m = self.mcfg
-            Ws = self._stage_rows(W)
+            Ws = stage_rows(W, bs)
 
             def run(params, kv_pool, last_tok, tok_host, use_last, pos0,
                     lens0, block_tables, rem, eos_ids, rng):
                 S = tok_host.shape[0]
                 KV, D = m.kv_heads, m.head_dim
                 kinds = self._kinds
-                # (per kind of layer, as ``_ragged_forward`` takes them)
-                per_kind, one = self._each_kind, self._per_kind
                 tok0 = jnp.where(use_last.astype(bool), last_tok, tok_host)
                 active0 = rem > 0
-                stage0 = one([jnp.zeros((len(k.layers), S, KV, Ws, D),
-                                        cfg.dtype) for k in kinds])
+                # (a tuple a kind of layer, as the forward takes them)
+                stage0 = tuple(jnp.zeros((len(k.layers), S, KV, Ws, D),
+                                         cfg.dtype) for k in kinds)
                 base = pos0          # stage base position, fixed per window
 
                 def _iter(i, tok, pos, lens, rng, active, kbuf, vbuf):
@@ -2247,7 +1138,7 @@ class InferenceEngineV2:
                     iteration's emitted tokens/slots plus the advanced
                     state."""
                     slots = []
-                    for k, table in zip(kinds, per_kind(block_tables)):
+                    for k, table in zip(kinds, block_tables):
                         blk = jnp.take_along_axis(
                             table, ((pos // bs) % k.max_blocks)[:, None],
                             axis=1)[:, 0]  # ring slot (mod no-op linear)
@@ -2255,13 +1146,11 @@ class InferenceEngineV2:
                         # block
                         slots.append(jnp.where(active,
                                                blk * bs + pos % bs, 0))
-                    slot = one(slots)
+                    slot = tuple(slots)
                     with nn.logical_axis_rules(self._rules):
-                        (kbuf, vbuf), logits = self._ragged_forward(
+                        (kbuf, vbuf), logits = self._forward(
                             params, kv_pool, tok[:, None], pos[:, None],
-                            one([sl[:, None] for sl in slots]),
-                            block_tables, lens,
-                            jnp.zeros_like(pos),
+                            block_tables, lens, jnp.zeros_like(pos),
                             kv_stage=(kbuf, vbuf), stage_fill=i,
                             stage_starts=base)
                     with device_scope("sample"):
@@ -2308,19 +1197,18 @@ class InferenceEngineV2:
                 # one pool write of this program (the pool stayed
                 # read-only through every iteration above)
                 merged = []
-                for k, pool, kb, vb, sl in zip(
-                        kinds, per_kind(kv_pool), per_kind(kbuf),
-                        per_kind(vbuf), per_kind(slots)):
+                for k, pool, kb, vb, sl in zip(kinds, kv_pool, kbuf, vbuf,
+                                               slots):
                     L = len(k.layers)
                     with device_scope("kv_commit"):
                         ks = (kb[:, :, :, :W, :].transpose(0, 3, 1, 2, 4)
                               .reshape(L, W * S, KV, D))
                         vs = (vb[:, :, :, :W, :].transpose(0, 3, 1, 2, 4)
                               .reshape(L, W * S, KV, D))
-                        merged.append(self._merge_rows(
+                        merged.append(merge_rows(
                             pool, sl.reshape(-1), ks, vs))
-                kv_pool = one(merged)
-                return kv_pool, tok, buf, i        # toks [W, S], iters run
+                # toks [W, S], iters run
+                return tuple(merged), tok, buf, i
 
             # non-pool outputs pinned replicated (see _program)
             repl = NamedSharding(self.topology.mesh, P())
@@ -2352,7 +1240,7 @@ class InferenceEngineV2:
                 W //= 2
         S = self.state.max_seqs
         z = lambda *s: np.zeros(s, np.int32)
-        tables = self._per_kind([z(S, k.max_blocks) for k in self._kinds])
+        tables = tuple(z(S, k.max_blocks) for k in self._kinds)
         for W in sizes:
             if W <= 1 or (skip_existing and ("win", W) in self._programs):
                 continue
@@ -2398,8 +1286,8 @@ class InferenceEngineV2:
             use_last = np.zeros((S,), np.uint8)
             pos0 = np.zeros((S,), np.int32)
             lens0 = np.zeros((S,), np.int32)
-            tables = [np.zeros((S, k.max_blocks), np.int32)
-                      for k in self._kinds]
+            tables = tuple(np.zeros((S, k.max_blocks), np.int32)
+                           for k in self._kinds)
             rem = np.zeros((S,), np.int32)
             eos = np.full((S,), -1, np.int32)
             sched: dict[int, tuple[int, int]] = {}   # uid -> (slot, n sched)
@@ -2429,7 +1317,7 @@ class InferenceEngineV2:
             self._rng, sub = jax.random.split(self._rng)
             self.kv_pool, self._last_tok, toks, iters = fn(
                 self.params, self.kv_pool, self._last_tok, tok0, use_last,
-                pos0, lens0, self._per_kind(tables), rem, eos, sub)
+                pos0, lens0, tables, rem, eos, sub)
         # dispatch-time speculative advance: KV for positions up to
         # len_sched-1+n-1 is now scheduled, n new samples are in flight
         for s in live:
@@ -2445,7 +1333,9 @@ class InferenceEngineV2:
         self.stats["dispatches"] += 1
         self.stats["windows"] += 1
         self._count_moe(int(rem.sum()), S, iters=W)
-        self._count_attn_steps(lens0, pos0, self._stage_rows(W), iters=W)
+        self._count_attn_steps(lens0, pos0,
+                               stage_rows(W, self.config.block_size),
+                               iters=W)
         if self._rt.enabled:
             for s in live:
                 self._rt.event(s.uid, "decode_window", W=W,
@@ -2468,11 +1358,11 @@ class InferenceEngineV2:
         if key not in self._programs:
             cfg = self.config
 
-            def run(params, kv_pool, token_ids, positions, slot_map,
-                    block_tables, seq_lens, tree_mask, rng):
+            def run(params, kv_pool, token_ids, positions, block_tables,
+                    seq_lens, tree_mask, rng):
                 with nn.logical_axis_rules(self._rules):
-                    (k_ys, v_ys), logits = self._ragged_forward(
-                        params, kv_pool, token_ids, positions, slot_map,
+                    (k_ys, v_ys), logits = self._forward(
+                        params, kv_pool, token_ids, positions,
                         block_tables, seq_lens,
                         jnp.zeros(token_ids.shape[0], jnp.int32),
                         tree_mask=tree_mask)
@@ -2488,13 +1378,15 @@ class InferenceEngineV2:
             # pool NOT donated: it stays live (unchanged) for the merge
             # program that runs after the host-side acceptance walk
             self._programs[key] = register_program(jax.jit(
-                run, in_shardings=(None, self._pool_formats) + (None,) * 7,
+                run, in_shardings=(None, self._pool_formats) + (None,) * 6,
                 out_shardings=(repl, repl, repl)))
         return self._programs[key]
 
     def _spec_merge_program(self, T: int):
         """THE pool write of a spec round: fold the verify step's staged
-        KV rows into the paged pool, row n ↔ ``flat_slots[n]`` (host-built
+        KV rows into the paged pool (the ONE pool of a ring-free model:
+        ``_init_speculative`` refuses the rest), row n ↔ ``flat_slots[n]``
+        (host-built
         AFTER the acceptance walk — accepted-path nodes get their
         sequence's tail-page slots, every rejected/padding node points at
         the trash block, so unaccepted KV never lands in a real page)."""
@@ -2509,7 +1401,7 @@ class InferenceEngineV2:
                           .reshape(L, S * T, m.kv_heads, m.head_dim))
                     vs = (v_ys[:, :, :, :T, :].transpose(0, 1, 3, 2, 4)
                           .reshape(L, S * T, m.kv_heads, m.head_dim))
-                    return self._merge_rows(kv_pool, flat_slots, ks, vs)
+                    return merge_rows(kv_pool, flat_slots, ks, vs)
 
             run.__name__ = "spec_merge"
             self._programs[key] = register_program(jax.jit(
@@ -2555,7 +1447,7 @@ class InferenceEngineV2:
                     continue
                 d = self._spec_tracker.depth(
                     s.uid, prefill_pending=prefill_pending,
-                    mixed_cap=cfg.spec_depth_mixed_cap)
+                    mixed_cap=SPEC_DEPTH_MIXED_CAP)
                 d = min(d, s.gen_remaining_sched - 1)
                 if d >= 1:
                     probe[s.uid] = (s.tokens, d)
@@ -2576,7 +1468,7 @@ class InferenceEngineV2:
         for s in live:
             d = self._spec_tracker.depth(
                 s.uid, prefill_pending=prefill_pending,
-                mixed_cap=cfg.spec_depth_mixed_cap)
+                mixed_cap=SPEC_DEPTH_MIXED_CAP)
             # the commit may emit depth+1 tokens (accepted chain + bonus):
             # cap one short of the remaining budget so provision() and the
             # block reservation are honoured by construction
@@ -2586,8 +1478,6 @@ class InferenceEngineV2:
         if all(t.n_candidates == 0 for t in trees.values()):
             self.stats["plan_s"] += time.perf_counter() - t0
             return False     # nothing to verify — plain decode is cheaper
-
-        from .speculative import accept_walk
 
         S = self.state.max_seqs
         mb = self.state.max_blocks_per_seq
@@ -2626,8 +1516,7 @@ class InferenceEngineV2:
                 fn = self._spec_program(T)
                 self._rng, sub = jax.random.split(self._rng)
                 k_ys, v_ys, toks = fn(self.params, self.kv_pool, tok, pos,
-                                      np.zeros((S, T), np.int32), tables,
-                                      lens, mask, sub)
+                                      (tables,), lens, mask, sub)
                 toks_h = np.asarray(toks)
 
             # exact acceptance on the host, then ONE merge of exactly the
@@ -2644,8 +1533,8 @@ class InferenceEngineV2:
                     flat[sl * T + node] = \
                         seq.blocks[(p // bs) % mb] * bs + p % bs
                 accepts[uid] = accepted
-            self.kv_pool = self._spec_merge_program(T)(
-                self.kv_pool, k_ys, v_ys, flat)
+            self.kv_pool = (self._spec_merge_program(T)(
+                self.kv_pool[0], k_ys[0], v_ys[0], flat),)
         except Exception:
             # failed dispatch: no provisional marker may outlive the round
             for uid in meta:
@@ -2670,7 +1559,7 @@ class InferenceEngineV2:
                 self._results[uid].extend(out)
                 self._spec_emit.setdefault(uid, []).extend(out)
                 emitted[uid] = out
-            if cfg.spec_adapt and tree.n_candidates:
+            if tree.n_candidates:
                 ev = self._spec_tracker.observe(uid, tree.n_candidates,
                                                 n_acc)
                 if ev is not None:
@@ -2807,12 +1696,15 @@ class InferenceEngineV2:
                               seq=self._entry_seq):
             fn = self._program(T, plan.token_ids.shape[0])
             self._rng, sub = jax.random.split(self._rng)
-            more = [plan.more[k.name] for k in self._kinds[1:]]
+            # THE site that turns a plan into a tuple a kind of layer
+            # (``self._kinds``' order): the plan holds the primary's slot
+            # map and table itself and every further kind's in ``more``
+            of_kind = {self._kinds[0].name: (plan.slot_map,
+                                             plan.block_tables), **plan.more}
+            slot_maps, tables = zip(*(of_kind[k.name] for k in self._kinds))
             self.kv_pool, self._last_tok, toks = fn(
                 self.params, self.kv_pool, self._last_tok,
-                plan.token_ids, plan.positions,
-                self._per_kind([plan.slot_map] + [a for a, _ in more]),
-                self._per_kind([plan.block_tables] + [b for _, b in more]),
+                plan.token_ids, plan.positions, slot_maps, tables,
                 plan.seq_lens, plan.sample_idx,
                 plan.do_sample, plan.use_last, plan.row_slots, sub)
         self.scheduler.mark_dispatched(plan)
@@ -2824,7 +1716,7 @@ class InferenceEngineV2:
         n_tok = int(plan.active.sum())
         self._count_moe(n_tok, plan.token_ids.size)
         self._count_attn_steps(plan.seq_lens, plan.positions[:, 0],
-                               self._stage_rows(T))
+                               stage_rows(T, bs))
         if plan.kind == "prefill":
             self.stats["prefill_steps"] += 1
             self.stats["prefill_tokens"] += n_tok
@@ -2837,15 +1729,6 @@ class InferenceEngineV2:
                 plan.kind, n_tok, int(np.prod(plan.token_ids.shape)),
                 plan.uids)
         return True
-
-    def _per_kind(self, per_kind):
-        """What a program takes for each kind of layer, from one value a
-        kind: the thing itself for a model of one kind, a tuple for more."""
-        return per_kind[0] if len(self._kinds) == 1 else tuple(per_kind)
-
-    def _each_kind(self, value) -> tuple:
-        """The inverse: one value a kind, in ``self._kinds``' order."""
-        return (value,) if len(self._kinds) == 1 else tuple(value)
 
     def _enqueue(self, entry: dict) -> None:
         """Append a dispatched entry to the pipeline under a number of its
@@ -3173,19 +2056,12 @@ class InferenceEngineV2:
         bs = self.config.block_size
         n_full = len(snap["page_blocks"])
         with self._telem.span("migrate_out", pages=n_full):
-            if n_full:
-                # one device gather + one transfer for every full page
-                pages_h = np.asarray(self.kv_pool[:, :, :, np.asarray(
-                    snap["page_blocks"], np.int32)])
+            page_blobs = self._read_pages(snap["page_blocks"])
             tail = None
             if snap["tail_rows"]:
                 tail = np.asarray(
-                    self.kv_pool[:, :, :, snap["tail_block"],
-                                 :snap["tail_rows"]]).tobytes()
-        page_blobs = [pages_h[:, :, :, j].tobytes() for j in range(n_full)]
-        m = self.mcfg
-        page_bytes = (m.num_layers * 2 * m.kv_heads * bs * m.head_dim
-                      * np.dtype(self._kv_dtype).itemsize)
+                    self.kv_pool[0][:, :, :, snap["tail_block"],
+                                    :snap["tail_rows"]]).tobytes()
         bundle = PageBundle(
             trace_id=trace_id,
             tokens=snap["tokens"],
@@ -3196,7 +2072,7 @@ class InferenceEngineV2:
             eos_id=snap["eos_id"], tenant=tenant,
             block_size=bs,
             kv_dtype=np.dtype(self._kv_dtype).name,
-            page_bytes=page_bytes,
+            page_bytes=self._page_bytes,
             tail_rows=snap["tail_rows"],
             tail_bytes=len(tail or b""),
             # the engine's fp8-KV pool is scale-free e4m3 (no side-car
@@ -3223,17 +2099,40 @@ class InferenceEngineV2:
         again and resumes locally exactly where it stopped."""
         self.state.export_abort(uid)
 
-    def _import_page_fn(self):
-        """One-page pool scatter, compiled once: (pool, block, page) ->
-        pool with that block replaced. Donated + layout-pinned like the
-        step programs, so an import never copies the pool."""
+    # migration, prefix pulls and the tier move pages of THE pool of a
+    # ring-free model — one kind of layer: every caller refuses a ring
+    @property
+    def _page_shape(self) -> tuple[int, ...]:
+        m = self.mcfg
+        return (m.num_layers, 2, m.kv_heads, self.config.block_size,
+                m.head_dim)
+
+    @property
+    def _page_bytes(self) -> int:
+        return int(np.prod(self._page_shape)) \
+            * np.dtype(self._kv_dtype).itemsize
+
+    def _read_pages(self, blocks) -> list[bytes]:
+        """Pages ``blocks`` as host bytes, a ``_page_shape`` each: one
+        device gather + one transfer for all of them."""
+        if not len(blocks):
+            return []
+        pages_h = np.asarray(
+            self.kv_pool[0][:, :, :, np.asarray(blocks, np.int32)])
+        return [pages_h[:, :, :, j].tobytes() for j in range(len(blocks))]
+
+    def _write_page(self, block: int, page: np.ndarray) -> None:
+        """One-page pool scatter, compiled once: that block replaced.
+        Donated + layout-pinned like the step programs, so an import
+        never copies the pool."""
         if getattr(self, "_import_page_jit", None) is None:
             self._import_page_jit = jax.jit(
                 lambda pool, idx, page: pool.at[:, :, :, idx].set(page),
                 donate_argnums=(0,),
                 in_shardings=(self._pool_format, None, None),
                 out_shardings=self._pool_format)
-        return self._import_page_jit
+        self.kv_pool = (self._import_page_jit(
+            self.kv_pool[0], np.int32(block), page),)
 
     def import_reserve(self, uid: int, meta: dict) -> None:
         """Claim capacity for an arriving bundle BEFORE its first payload
@@ -3255,9 +2154,7 @@ class InferenceEngineV2:
             raise MigrationError(
                 f"kv dtype mismatch: bundle {shell.kv_dtype}, pool "
                 f"{np.dtype(self._kv_dtype).name}")
-        m = self.mcfg
-        want = (m.num_layers * 2 * m.kv_heads * self.config.block_size
-                * m.head_dim * np.dtype(self._kv_dtype).itemsize)
+        want = self._page_bytes
         if shell.page_bytes != want:
             raise MigrationError(
                 f"page geometry mismatch: bundle pages are "
@@ -3299,26 +2196,18 @@ class InferenceEngineV2:
                 f"version_skew: bundle weights "
                 f"{bundle.weight_version} vs pool {self._weight_version}")
         seq = self.state.seqs[uid]
-        bs = self.config.block_size
-        m = self.mcfg
-        page_shape = (m.num_layers, 2, m.kv_heads, bs, m.head_dim)
+        page_shape = self._page_shape
         dt = np.dtype(self._kv_dtype)
-        fn = self._import_page_fn()
         with self._telem.span("migrate_in", pages=bundle.n_full):
             for j in range(bundle.n_full):
-                page = np.frombuffer(bundle.pages[j],
-                                     dtype=dt).reshape(page_shape)
-                self.kv_pool = fn(self.kv_pool,
-                                  np.int32(seq.blocks[j]), page)
+                self._write_page(seq.blocks[j], np.frombuffer(
+                    bundle.pages[j], dtype=dt).reshape(page_shape))
             if bundle.tail_rows:
                 rows = np.frombuffer(bundle.tail, dtype=dt).reshape(
-                    (m.num_layers, 2, m.kv_heads, bundle.tail_rows,
-                     m.head_dim))
+                    (*page_shape[:3], bundle.tail_rows, page_shape[4]))
                 page = np.zeros(page_shape, dt)
                 page[:, :, :, :bundle.tail_rows] = rows
-                self.kv_pool = fn(
-                    self.kv_pool, np.int32(seq.blocks[bundle.n_full]),
-                    page)
+                self._write_page(seq.blocks[bundle.n_full], page)
         self.state.import_commit(uid)
         if self._spec is not None:
             # the proposer sees the full imported history as its
@@ -3361,18 +2250,12 @@ class InferenceEngineV2:
             bs = self.config.block_size
             with self._telem.span("kv_pull_export",
                                   pages=len(snap["blocks"])):
-                pages_h = np.asarray(self.kv_pool[:, :, :, np.asarray(
-                    snap["blocks"], np.int32)])
-            blobs = [pages_h[:, :, :, j].tobytes()
-                     for j in range(len(snap["blocks"]))]
+                blobs = self._read_pages(snap["blocks"])
         finally:
             self.state.release_prefix(snap["handle"])
-        m = self.mcfg
-        page_bytes = (m.num_layers * 2 * m.kv_heads * bs * m.head_dim
-                      * np.dtype(self._kv_dtype).itemsize)
         bundle = PageBundle.prefix(
             trace_id, [int(t) for t in tokens[:snap["n_tokens"]]], bs,
-            np.dtype(self._kv_dtype).name, page_bytes, blobs,
+            np.dtype(self._kv_dtype).name, self._page_bytes, blobs,
             weight_version=dict(self._weight_version))
         bundle.validate()
         self.stats["kv_pull_bytes_out"] = self.stats.get(
@@ -3410,24 +2293,18 @@ class InferenceEngineV2:
             raise MigrationError(
                 f"kv dtype mismatch: bundle {bundle.kv_dtype}, pool "
                 f"{np.dtype(self._kv_dtype).name}")
-        m = self.mcfg
-        bs = self.config.block_size
-        want = (m.num_layers * 2 * m.kv_heads * bs * m.head_dim
-                * np.dtype(self._kv_dtype).itemsize)
+        want = self._page_bytes
         if bundle.page_bytes != want:
             raise MigrationError(
                 f"page geometry mismatch: bundle pages are "
                 f"{bundle.page_bytes}B, this pool's are {want}B")
         fresh = self.state.adopt_prefix(bundle.tokens, bundle.n_computed,
                                         trace=bundle.trace_id or None)
-        page_shape = (m.num_layers, 2, m.kv_heads, bs, m.head_dim)
         dt = np.dtype(self._kv_dtype)
-        fn = self._import_page_fn()
         with self._telem.span("kv_pull_import", pages=len(fresh)):
             for j, block in fresh:
-                page = np.frombuffer(bundle.pages[j],
-                                     dtype=dt).reshape(page_shape)
-                self.kv_pool = fn(self.kv_pool, np.int32(block), page)
+                self._write_page(block, np.frombuffer(
+                    bundle.pages[j], dtype=dt).reshape(self._page_shape))
         key = f"kv_{source}_bytes_in"
         self.stats[key] = self.stats.get(key, 0) + bundle.payload_bytes
         return bundle.n_full
@@ -3483,22 +2360,16 @@ class InferenceEngineV2:
         if tier is None:
             return
         bs = self.config.block_size
-        m = self.mcfg
-        page_bytes = (m.num_layers * 2 * m.kv_heads * bs * m.head_dim
-                      * np.dtype(self._kv_dtype).itemsize)
         demoted = 0
         for tokens, blocks in chains:
             chain = chain_hashes(tokens, bs)
             if not chain or tier.has(chain[-1]):
                 continue
             with self._telem.span("kv_tier_demote", pages=len(blocks)):
-                pages_h = np.asarray(self.kv_pool[:, :, :, np.asarray(
-                    blocks, np.int32)])
-            blobs = [pages_h[:, :, :, j].tobytes()
-                     for j in range(len(blocks))]
+                blobs = self._read_pages(blocks)
             bundle = PageBundle.prefix(
                 "", [int(t) for t in tokens], bs,
-                np.dtype(self._kv_dtype).name, page_bytes, blobs,
+                np.dtype(self._kv_dtype).name, self._page_bytes, blobs,
                 weight_version=dict(self._weight_version))
             demoted += tier.absorb(bundle)
         if demoted:

@@ -143,6 +143,17 @@ def accept_walk(tree: SpecTree, samples) -> tuple[list[int], list[int]]:
     return accepted, visited
 
 
+#: what the engine builds its n-gram proposer with: distinct candidate
+#: branches a tree, and the longest / shortest history n-gram matched
+SPEC_BRANCHES = 2
+SPEC_NGRAM_MAX = 3
+SPEC_NGRAM_MIN = 1
+#: cap on the draft depth while prefill chunks are PENDING (the
+#: ``decode_window_mixed_cap`` idea: a waiting first chunk must not sit
+#: behind a max-depth verify round)
+SPEC_DEPTH_MIXED_CAP = 2
+
+
 class NGramProposer:
     """Self-speculative prompt-lookup proposer (PLD / LLMA-style): no
     extra weights, no extra forward — candidates come from the sequence's

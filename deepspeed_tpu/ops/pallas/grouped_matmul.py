@@ -33,7 +33,8 @@ the TPU pipeline model:
   scratch, written out on the last k step, and a second tile of an expert
   reads its weights again. The serving engine logs the plan of every
   distinct expert shape once, as a ``gmm:`` line, when it builds its
-  programs (``InferenceEngineV2._gmm``; :meth:`GmmPlan.describe`).
+  programs (``inference/forward.py:RaggedForward.gmm``;
+  :meth:`GmmPlan.describe`).
 - Padding rows are zero → their outputs are zero and are never gathered
   back, so no masking is needed in the kernel. The buffer is FILLED without
   a scatter (``gather_expert_rows``: a one-hot matmul for a step of few

@@ -400,7 +400,7 @@ def _ragged_attn_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
     committed context, with positions read from the input instead of
     the ramp); stage columns take the tree mask VERBATIM, replacing the
     positional mask — exactly the gather formulation's split in
-    inference/engine_v2.py `_ragged_forward`.
+    inference/forward.py (`RaggedForward`).
 
     Grid (q-tiles, n_items).
     ``refs`` = ([tpos, tmask when tree,] o, m_scr, l_scr, acc_scr).

@@ -117,7 +117,7 @@ def _ragged(S, T, pool_dtype, tree=False, H=16, KV=16, D=64, bs=128,
 
 
 def _ragged_tp4(devs):
-    """The ``tensor: 4`` serving layout (engine_v2 ``_ragged_forward``):
+    """The ``tensor: 4`` serving layout (``inference/forward.py``):
     the kernel per shard under shard_map, heads split four ways over a
     Mesh of the described devices."""
     from deepspeed_tpu.ops.pallas.paged_attention import \
@@ -756,17 +756,16 @@ def test_paged_kernel_scalar_prefetch_footprint():
 
 
 def _mistral_walk(devs, S, T, L=4, period=1):
-    """``scan_layer_stack`` (``period`` 1) or ``scan_layer_periods`` (the
-    walk of a model of several layer kinds: place ``j`` of a period ropes
-    or not) over an ``[L, …]`` stack of Mistral-7B layers
+    """``scan_layers`` — THE walk of a stacked model: a scan over depth
+    (``period`` 1) or over periods of several layer kinds (place ``j`` of a
+    period ropes or not) — over an ``[L, …]`` stack of Mistral-7B layers
     (bf16; the shapes are the model's own ``init``), applying the dense
-    layer ``engine_v2._ragged_forward`` applies less its paged attention:
+    layer ``inference/forward.py`` applies less its paged attention:
     the same ``Norm``/``DenseFFN`` modules and projection einsums. Returns
     (fn, abstract args, shapes of one layer's weights)."""
     import flax.linen as nn
 
-    from deepspeed_tpu.inference.engine_v2 import (scan_layer_periods,
-                                                   scan_layer_stack)
+    from deepspeed_tpu.inference.forward import scan_layers
     from deepspeed_tpu.models import build_model
     from deepspeed_tpu.models.transformer import (DenseFFN, Norm,
                                                   apply_rope,
@@ -799,9 +798,7 @@ def _mistral_walk(devs, S, T, L=4, period=1):
         return x + f, None
 
     def walk(stack, x):
-        if period > 1:
-            return scan_layer_periods(stack, x, layer, period)[0]
-        return scan_layer_stack(stack, x, layer)[0]
+        return scan_layers(stack, x, layer, period)[0]
 
     x = _sds(_one(devs), (S, T, m.hidden_size), BF16)
     return walk, (stack, x), [a.shape for a in jax.tree.leaves(layer0)]

@@ -14,8 +14,31 @@ from deepspeed_tpu.inference import (
     InferenceEngineV2,
     StateManager,
 )
+from deepspeed_tpu.inference.forward import merge_step
 from deepspeed_tpu.inference.scheduler import SplitFuseScheduler
 from deepspeed_tpu.models import build_model
+
+
+def fwd_args(plan):
+    """A plan's arrays as ``RaggedForward`` takes them (a model of one kind
+    of layer: one block table)."""
+    return (jnp.asarray(plan.token_ids), jnp.asarray(plan.positions),
+            (jnp.asarray(plan.block_tables),), jnp.asarray(plan.seq_lens),
+            jnp.asarray(plan.sample_idx))
+
+
+def forward_then_merge(eng):
+    """A step program less its sampling: the forward, then the ONE pool
+    write — ``(pools, plan) -> (pools, logits)``."""
+    def step(params, pools, slot_map, tok, pos, tables, lens, sample_idx):
+        (k_ys, v_ys), logits = eng._forward(params, pools, tok, pos, tables,
+                                            lens, sample_idx)
+        return merge_step(pools, (slot_map,), k_ys, v_ys,
+                          tok.shape[1]), logits
+
+    fn = jax.jit(step)
+    return lambda pools, plan: fn(eng.params, pools,
+                                  jnp.asarray(plan.slot_map), *fwd_args(plan))
 
 
 def test_allocator_roundtrip():
@@ -270,11 +293,9 @@ def test_v2_pallas_decode_under_tensor_parallel():
         eng.step()          # prefill chunk 2 (samples first token)
     plan = ex.scheduler.next_step()
     assert plan.kind == "decode"
-    args = (jnp.asarray(plan.token_ids), jnp.asarray(plan.positions),
-            jnp.asarray(plan.slot_map), jnp.asarray(plan.block_tables),
-            jnp.asarray(plan.seq_lens), jnp.asarray(plan.sample_idx))
-    _, lx = jax.jit(ex._ragged_forward)(ex.params, ex.kv_pool, *args)
-    _, lp = jax.jit(ep._ragged_forward)(ep.params, ep.kv_pool, *args)
+    args = fwd_args(plan)
+    _, lx = jax.jit(ex._forward)(ex.params, ex.kv_pool, *args)
+    _, lp = jax.jit(ep._forward)(ep.params, ep.kv_pool, *args)
     # engines compute in bf16: paths agree to a bf16 ulp (~8e-3 at |x|~1)
     np.testing.assert_allclose(np.asarray(lx, np.float32)[0],
                                np.asarray(lp, np.float32)[0], atol=2e-2)
@@ -310,11 +331,9 @@ def test_v2_pallas_prefill_matches_xla():
             eng.put(uid, p, max_new_tokens=4)
     plan = ex.scheduler.next_step()
     assert plan.kind == "prefill" and plan.token_ids.shape[1] > 1
-    args = (jnp.asarray(plan.token_ids), jnp.asarray(plan.positions),
-            jnp.asarray(plan.slot_map), jnp.asarray(plan.block_tables),
-            jnp.asarray(plan.seq_lens), jnp.asarray(plan.sample_idx))
-    _, lx = jax.jit(ex._ragged_forward)(ex.params, ex.kv_pool, *args)
-    _, lp = jax.jit(ep._ragged_forward)(ep.params, ep.kv_pool, *args)
+    args = fwd_args(plan)
+    _, lx = jax.jit(ex._forward)(ex.params, ex.kv_pool, *args)
+    _, lp = jax.jit(ep._forward)(ep.params, ep.kv_pool, *args)
     live = np.asarray(plan.seq_lens) > 0   # empty slots emit garbage on
     np.testing.assert_allclose(           # BOTH paths (uniform vs zeros)
         np.asarray(lx, np.float32)[live],
@@ -346,11 +365,9 @@ def test_v2_pallas_prefill_under_tensor_parallel():
         eng.put(1, prompt, max_new_tokens=3)
     plan = ex.scheduler.next_step()
     assert plan.kind == "prefill"
-    args = (jnp.asarray(plan.token_ids), jnp.asarray(plan.positions),
-            jnp.asarray(plan.slot_map), jnp.asarray(plan.block_tables),
-            jnp.asarray(plan.seq_lens), jnp.asarray(plan.sample_idx))
-    _, lx = jax.jit(ex._ragged_forward)(ex.params, ex.kv_pool, *args)
-    _, lp = jax.jit(ep._ragged_forward)(ep.params, ep.kv_pool, *args)
+    args = fwd_args(plan)
+    _, lx = jax.jit(ex._forward)(ex.params, ex.kv_pool, *args)
+    _, lp = jax.jit(ep._forward)(ep.params, ep.kv_pool, *args)
     live = np.asarray(plan.seq_lens) > 0
     np.testing.assert_allclose(np.asarray(lx, np.float32)[live],
                                np.asarray(lp, np.float32)[live], atol=2e-2)
@@ -420,14 +437,10 @@ def test_v2_rolling_window_kv_wraps_and_matches_v1():
         prompt = list(map(int, rngnp.integers(0, 256, (11,))))
         eng.put(1, prompt, max_new_tokens=60)
         checked = 0
-        fwd = jax.jit(eng._ragged_forward)   # one wrapper, 2 shape compiles
+        step = forward_then_merge(eng)   # one wrapper, 2 shape compiles
         while not eng.query(1).get("done", False):
             plan = eng.scheduler.next_step()
-            args = (jnp.asarray(plan.token_ids), jnp.asarray(plan.positions),
-                    jnp.asarray(plan.slot_map),
-                    jnp.asarray(plan.block_tables),
-                    jnp.asarray(plan.seq_lens), jnp.asarray(plan.sample_idx))
-            eng.kv_pool, logits = fwd(eng.params, eng.kv_pool, *args)
+            eng.kv_pool, logits = step(eng.kv_pool, plan)
             sampled = {}
             if plan.do_sample[0]:
                 toks = eng.state.seqs[1].tokens
@@ -474,15 +487,11 @@ def test_v2_pallas_kernels_on_mixed_data_tensor_mesh():
     for eng in (ex, ep):
         eng.put(1, prompt, max_new_tokens=4)
     # prefill chunk parity, then decode-step parity, through both paths
+    step_x, step_p = forward_then_merge(ex), forward_then_merge(ep)
     for _ in range(3):
         plan = ex.scheduler.next_step()
-        args = (jnp.asarray(plan.token_ids), jnp.asarray(plan.positions),
-                jnp.asarray(plan.slot_map), jnp.asarray(plan.block_tables),
-                jnp.asarray(plan.seq_lens), jnp.asarray(plan.sample_idx))
-        ex.kv_pool, lx = jax.jit(ex._ragged_forward)(ex.params, ex.kv_pool,
-                                                     *args)
-        ep.kv_pool, lp = jax.jit(ep._ragged_forward)(ep.params, ep.kv_pool,
-                                                     *args)
+        ex.kv_pool, lx = step_x(ex.kv_pool, plan)
+        ep.kv_pool, lp = step_p(ep.kv_pool, plan)
         np.testing.assert_allclose(np.asarray(lx, np.float32)[0],
                                    np.asarray(lp, np.float32)[0], atol=2e-2)
         tok = int(np.argmax(np.asarray(lx, np.float32)[0]))
@@ -583,8 +592,8 @@ def test_v2_fp8_kv_cache_serves_close_to_bf16():
     e16 = InferenceEngineV2(model, config=cfg, rng=rng, topology=topo)
     ef8 = InferenceEngineV2(model, config={**cfg, "kv_cache_dtype": "fp8"},
                             rng=rng, topology=topo)
-    assert ef8.kv_pool.dtype == jnp.float8_e4m3fn
-    assert ef8.kv_pool.nbytes == e16.kv_pool.nbytes // 2
+    assert ef8.kv_pool[0].dtype == jnp.float8_e4m3fn
+    assert ef8.kv_pool[0].nbytes == e16.kv_pool[0].nbytes // 2
 
     # longer than the single-row chunk chain's largest T (chunk *
     # max_seqs = 16): the PR-1 chunk growth let a 12-token prompt prefill
@@ -605,14 +614,8 @@ def test_v2_fp8_kv_cache_serves_close_to_bf16():
     pf8 = ef8.scheduler.next_step()
     assert p16.kind == pf8.kind == "prefill"     # same tokens, via the pool
     assert (p16.token_ids == pf8.token_ids).all()
-    args16 = (jnp.asarray(p16.token_ids), jnp.asarray(p16.positions),
-              jnp.asarray(p16.slot_map), jnp.asarray(p16.block_tables),
-              jnp.asarray(p16.seq_lens), jnp.asarray(p16.sample_idx))
-    argsf8 = (jnp.asarray(pf8.token_ids), jnp.asarray(pf8.positions),
-              jnp.asarray(pf8.slot_map), jnp.asarray(pf8.block_tables),
-              jnp.asarray(pf8.seq_lens), jnp.asarray(pf8.sample_idx))
-    _, l16 = jax.jit(e16._ragged_forward)(e16.params, e16.kv_pool, *args16)
-    _, lf8 = jax.jit(ef8._ragged_forward)(ef8.params, ef8.kv_pool, *argsf8)
+    _, l16 = jax.jit(e16._forward)(e16.params, e16.kv_pool, *fwd_args(p16))
+    _, lf8 = jax.jit(ef8._forward)(ef8.params, ef8.kv_pool, *fwd_args(pf8))
     a, b = np.asarray(l16, np.float32)[0], np.asarray(lf8, np.float32)[0]
     # fp8 KV quantization noise, not divergence: logits stay close on the
     # softmax scale
@@ -633,7 +636,7 @@ def test_v2_fp8_kv_combines_with_quant_weights():
                        "chunk": 8, "max_seq_len": 128, "quant_bits": 8,
                        "kv_cache_dtype": "fp8"},
         rng=jax.random.PRNGKey(7))
-    assert eng.kv_pool.dtype == jnp.float8_e4m3fn
+    assert eng.kv_pool[0].dtype == jnp.float8_e4m3fn
     eng.put(1, [5, 9, 2, 7, 1, 3], max_new_tokens=5)
     eng.put(2, [4, 4, 8], max_new_tokens=3)
     while not (eng.query(1).get("done", False)
@@ -764,7 +767,7 @@ def test_v2_fp8_kv_with_rolling_window_ring():
                                                           "data": 1}))
     assert eng._kinds[0].ring_tokens > 0          # rolling buffer active
     assert not eng.scheduler.pack        # packing off in ring mode
-    assert eng.kv_pool.dtype == jnp.float8_e4m3fn
+    assert eng.kv_pool[0].dtype == jnp.float8_e4m3fn
     prompt = list(range(40))             # > window: the ring must wrap
     eng.put(1, prompt, max_new_tokens=6)
     while not eng.query(1).get("done", False):
@@ -792,7 +795,7 @@ def test_v2_fp8_kv_long_context_logits_parity():
     e16 = InferenceEngineV2(model, config=cfg, rng=rng, topology=topo)
     ef8 = InferenceEngineV2(model, config={**cfg, "kv_cache_dtype": "fp8"},
                             rng=rng, topology=topo)
-    assert ef8.kv_pool.dtype == jnp.float8_e4m3fn
+    assert ef8.kv_pool[0].dtype == jnp.float8_e4m3fn
 
     rngnp = np.random.default_rng(9)
     prompt = list(map(int, rngnp.integers(0, 256, (300,))))
@@ -808,14 +811,8 @@ def test_v2_fp8_kv_long_context_logits_parity():
     p16 = e16.scheduler.next_step()
     pf8 = ef8.scheduler.next_step()
     assert int(p16.seq_lens[0]) >= 280   # long context actually reached
-    args16 = (jnp.asarray(p16.token_ids), jnp.asarray(p16.positions),
-              jnp.asarray(p16.slot_map), jnp.asarray(p16.block_tables),
-              jnp.asarray(p16.seq_lens), jnp.asarray(p16.sample_idx))
-    argsf8 = (jnp.asarray(pf8.token_ids), jnp.asarray(pf8.positions),
-              jnp.asarray(pf8.slot_map), jnp.asarray(pf8.block_tables),
-              jnp.asarray(pf8.seq_lens), jnp.asarray(pf8.sample_idx))
-    _, l16 = jax.jit(e16._ragged_forward)(e16.params, e16.kv_pool, *args16)
-    _, lf8 = jax.jit(ef8._ragged_forward)(ef8.params, ef8.kv_pool, *argsf8)
+    _, l16 = jax.jit(e16._forward)(e16.params, e16.kv_pool, *fwd_args(p16))
+    _, lf8 = jax.jit(ef8._forward)(ef8.params, ef8.kv_pool, *fwd_args(pf8))
     a = np.asarray(l16, np.float32)[0]
     b = np.asarray(lf8, np.float32)[0]
     # same bound shape as the short-context test: quantization noise on
@@ -847,7 +844,7 @@ def test_v2_fp8_kv_prefix_cache_cross_request_parity():
            "max_seq_len": 160, "kv_cache_dtype": "fp8"}
     warm = InferenceEngineV2(model, config=cfg, rng=rng, topology=topo)
     assert warm._prefix_cache is not None      # the flipped auto gate
-    assert warm.kv_pool.dtype == jnp.float8_e4m3fn
+    assert warm.kv_pool[0].dtype == jnp.float8_e4m3fn
     # same model + same init rng = identical weights (a built engine's
     # params are layer-stacked in place and cannot be handed over)
     cold = InferenceEngineV2(model, config={**cfg, "prefix_cache": False},

@@ -199,6 +199,7 @@ def serve_logits(model, params, prompt, dtype, n_step=4, n_window=4):
     Returns {form: [(tokens so far, logits row)]}, teacher-forced on the
     engine's own argmax."""
     from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
+    from deepspeed_tpu.inference.forward import merge_step
 
     # (a copy: the engine donates the per-layer leaves to their stack)
     eng = InferenceEngineV2(model, params=jax.tree.map(jnp.copy, params),
@@ -206,15 +207,22 @@ def serve_logits(model, params, prompt, dtype, n_step=4, n_window=4):
                             rng=jax.random.PRNGKey(0))
     assert "layers_stacked" in eng.params          # the scanned walk
     eng.put(1, prompt, max_new_tokens=n_step + n_window + 2)
-    fwd = jax.jit(eng._ragged_forward)
+    def step(params, pools, slot_maps, tok, pos, tables, lens, sample_idx):
+        # a step program less its sampling: the forward, then the ONE pool
+        # write
+        (k_ys, v_ys), logits = eng._forward(params, pools, tok, pos, tables,
+                                            lens, sample_idx)
+        return merge_step(pools, slot_maps, k_ys, v_ys, tok.shape[1]), logits
+
+    fwd = jax.jit(step)
     out = {"prefill": [], "step": [], "window": []}
     seq = eng.state.seqs[1]
     chunks = 0
     while len(out["step"]) < n_step:
         plan = eng.scheduler.next_step()
-        args = [jnp.asarray(a) for a in (
-            plan.token_ids, plan.positions, plan.slot_map, plan.block_tables,
-            plan.seq_lens, plan.sample_idx)]
+        args = [(jnp.asarray(plan.slot_map),), jnp.asarray(plan.token_ids),
+                jnp.asarray(plan.positions), (jnp.asarray(plan.block_tables),),
+                jnp.asarray(plan.seq_lens), jnp.asarray(plan.sample_idx)]
         eng.kv_pool, logits = fwd(eng.params, eng.kv_pool, *args)
         chunks += plan.kind == "prefill"
         sampled = {}
@@ -230,16 +238,17 @@ def serve_logits(model, params, prompt, dtype, n_step=4, n_window=4):
     S, bs, Ws = cfg.max_seqs, cfg.block_size, 8
     tables = np.zeros((S, eng.state.max_blocks_per_seq), np.int32)
     tables[seq.slot, :len(seq.blocks)] = seq.blocks
-    tables = jnp.asarray(tables)
-    stage = jnp.zeros((m.num_layers, S, m.kv_heads, Ws, m.head_dim), dtype)
-    kbuf = vbuf = stage
+    tables = (jnp.asarray(tables),)
+    # (a tuple a kind of layer, as the window program's: one kind here)
+    kbuf = vbuf = (jnp.zeros((m.num_layers, S, m.kv_heads, Ws, m.head_dim),
+                             dtype),)
     toks = list(seq.tokens)
     base = np.zeros(S, np.int32)
     base[seq.slot] = len(toks) - 1
-    win = jax.jit(lambda p, pool, tok, pos, slot, lens, kb, vb, i, b:
-                  eng._ragged_forward(p, pool, tok, pos, slot, tables, lens,
-                                      jnp.zeros_like(lens), kv_stage=(kb, vb),
-                                      stage_fill=i, stage_starts=b))
+    win = jax.jit(lambda p, pool, tok, pos, lens, kb, vb, i, b:
+                  eng._forward(p, pool, tok, pos, tables, lens,
+                               jnp.zeros_like(lens), kv_stage=(kb, vb),
+                               stage_fill=i, stage_starts=b))
     for i in range(n_window):
         tok = np.zeros(S, np.int32)
         pos = np.zeros(S, np.int32)
@@ -248,8 +257,8 @@ def serve_logits(model, params, prompt, dtype, n_step=4, n_window=4):
             toks[-1], len(toks) - 1, len(toks)
         (kbuf, vbuf), logits = win(
             eng.params, eng.kv_pool, jnp.asarray(tok)[:, None],
-            jnp.asarray(pos)[:, None], jnp.zeros((S, 1), jnp.int32),
-            jnp.asarray(lens), kbuf, vbuf, jnp.int32(i), jnp.asarray(base))
+            jnp.asarray(pos)[:, None], jnp.asarray(lens), kbuf, vbuf,
+            jnp.int32(i), jnp.asarray(base))
         row = np.asarray(logits, np.float32)[seq.slot]
         out["window"].append((list(toks), row))
         toks.append(int(np.argmax(row)))

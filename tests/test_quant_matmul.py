@@ -92,10 +92,10 @@ def test_v2_quant_serving_matches_dequantized_weights(bits):
         eng.put(1, prompt, max_new_tokens=6)
     plan = eq.scheduler.next_step()
     args = (jnp.asarray(plan.token_ids), jnp.asarray(plan.positions),
-            jnp.asarray(plan.slot_map), jnp.asarray(plan.block_tables),
+            (jnp.asarray(plan.block_tables),),
             jnp.asarray(plan.seq_lens), jnp.asarray(plan.sample_idx))
-    _, lq = jax.jit(eq._ragged_forward)(eq.params, eq.kv_pool, *args)
-    _, ld = jax.jit(ed._ragged_forward)(ed.params, ed.kv_pool, *args)
+    _, lq = jax.jit(eq._forward)(eq.params, eq.kv_pool, *args)
+    _, ld = jax.jit(ed._forward)(ed.params, ed.kv_pool, *args)
     # int4 gets a little headroom: the engines contract in different
     # orders (in-tile f32 dequant vs bf16 round-tripped weights) and the
     # 4-bit step is coarse enough that XLA-version dot-order differences
@@ -154,10 +154,10 @@ def test_v2_quant_serving_under_tensor_parallel(mesh_cfg):
         eng.put(1, prompt, max_new_tokens=6)
     plan = e1.scheduler.next_step()
     args = (jnp.asarray(plan.token_ids), jnp.asarray(plan.positions),
-            jnp.asarray(plan.slot_map), jnp.asarray(plan.block_tables),
+            (jnp.asarray(plan.block_tables),),
             jnp.asarray(plan.seq_lens), jnp.asarray(plan.sample_idx))
-    _, l1 = jax.jit(e1._ragged_forward)(e1.params, e1.kv_pool, *args)
-    _, ltp = jax.jit(etp._ragged_forward)(etp.params, etp.kv_pool, *args)
+    _, l1 = jax.jit(e1._forward)(e1.params, e1.kv_pool, *args)
+    _, ltp = jax.jit(etp._forward)(etp.params, etp.kv_pool, *args)
     # same quantization function per shard; activations run bf16 so paths
     # agree to a bf16 ulp + psum reduction-order noise
     np.testing.assert_allclose(np.asarray(l1, np.float32)[0],
@@ -246,10 +246,10 @@ def test_v2_quant_moe_serving(tensor):
         eng.put(1, prompt, max_new_tokens=6)
     plan = eq.scheduler.next_step()
     args = (jnp.asarray(plan.token_ids), jnp.asarray(plan.positions),
-            jnp.asarray(plan.slot_map), jnp.asarray(plan.block_tables),
+            (jnp.asarray(plan.block_tables),),
             jnp.asarray(plan.seq_lens), jnp.asarray(plan.sample_idx))
-    _, lq = jax.jit(eq._ragged_forward)(eq.params, eq.kv_pool, *args)
-    _, ld = jax.jit(ed._ragged_forward)(ed.params, ed.kv_pool, *args)
+    _, lq = jax.jit(eq._forward)(eq.params, eq.kv_pool, *args)
+    _, ld = jax.jit(ed._forward)(ed.params, ed.kv_pool, *args)
     np.testing.assert_allclose(np.asarray(lq, np.float32)[0],
                                np.asarray(ld, np.float32)[0], atol=3e-2)
     # quantized MoE engine generates to completion through its own path

@@ -720,46 +720,74 @@ def test_state_invariant_detector_pins_evict_sink_attach(tmp_path):
 def test_repo_attn_dispatch_routes_through_registry():
     """Tree-verify dispatch pin: the kernel-vs-gather decision for BOTH
     decode and tree modes is attn_registry's static per-engine selection,
-    consulted in exactly one forward site. Ad-hoc conditionals are how
-    the tree branch silently pinned the gather formulation for 10 PRs."""
+    consulted in exactly one forward site — the one module that imports
+    the kernel. Ad-hoc conditionals are how the tree branch silently
+    pinned the gather formulation for 10 PRs."""
     violations = state_lint.check_attn_registry(ROOT)
     assert violations == [], "\n".join(violations)
 
 
+#: the blessed shape: the forward imports the kernel and consults both
+#: selections; the engine computes them once and reads them to count
+_FORWARD_OK = (
+    "from ..ops.pallas.paged_attention import (paged_ragged_attention,\n"
+    "                                          paged_work_list)\n"
+    "class RaggedForward:\n"
+    "    def __call__(self, tree_mode):\n"
+    "        sel = self.attn_tree_sel if tree_mode else self.attn_decode_sel\n"
+    "        if sel.is_pallas:\n"
+    "            return paged_ragged_attention()\n")
+_ENGINE_OK = (
+    "from .forward import RaggedForward\n"
+    "class Engine:\n"
+    "    def __init__(self):\n"
+    "        self._attn_decode_sel = select_attention(mode='x')\n"
+    "        self._attn_tree_sel = select_attention(mode='y')\n"
+    "        if self._attn_tree_sel.is_pallas:\n"   # init pin compose
+    "            pass\n"
+    "    def _emit_attn_kernel(self, mode):\n"
+    "        return self._attn_decode_sel.path\n")
+
+
+def _plant(tmp_path, engine: str, forward: str):
+    inf = tmp_path / "deepspeed_tpu" / "inference"
+    inf.mkdir(parents=True, exist_ok=True)
+    (inf / "engine_v2.py").write_text(engine)
+    (inf / "forward.py").write_text(forward)
+    return inf
+
+
 def test_attn_registry_detector_flags_adhoc_dispatch(tmp_path):
-    eng = tmp_path / "deepspeed_tpu" / "inference" / "engine_v2.py"
-    eng.parent.mkdir(parents=True)
-    eng.write_text(
-        "class Engine:\n"
-        "    def __init__(self):\n"
-        "        self._attn_decode_sel = select_attention(mode='x')\n"
-        "        self._attn_tree_sel = select_attention(mode='y')\n"
+    """A planted import of the kernel outside the forward's module, and a
+    second site that reads or rebinds the selections, are flagged."""
+    inf = _plant(
+        tmp_path,
+        "from ..ops.pallas.paged_attention import paged_ragged_attention\n"
+        + _ENGINE_OK +
         "    def _sneaky(self):\n"
         "        self._attn_tree_sel = select_attention(mode='z')\n"  # call + store
         "        if self._attn_decode_sel.is_pallas:\n"              # read
-        "            return paged_ragged_attention()\n")             # kernel call
+        "            return paged_ragged_attention()\n",
+        _FORWARD_OK)
     out = state_lint.check_attn_registry(str(tmp_path))
     assert len(out) == 4, "\n".join(out)
-    assert ":6:" in out[0] and "_attn_tree_sel" in out[0] \
-        and "assigned" in out[0]
-    assert ":6:" in out[1] and "select_attention()" in out[1]
-    assert ":7:" in out[2] and "_attn_decode_sel" in out[2] \
-        and "read" in out[2]
-    assert ":8:" in out[3] and "paged_ragged_attention()" in out[3]
+    assert "engine_v2.py:1:" in out[0] \
+        and "imports paged_ragged_attention" in out[0]
+    assert ":12:" in out[1] and "_attn_tree_sel" in out[1] \
+        and "assigned" in out[1]
+    assert ":12:" in out[2] and "select_attention()" in out[2]
+    assert ":13:" in out[3] and "_attn_decode_sel" in out[3] \
+        and "read" in out[3]
+    # an import inside a function of any other module is one too
+    (inf / "other.py").write_text(
+        "def f():\n"
+        "    from ..ops.pallas.paged_attention import paged_work_list\n")
+    out = state_lint.check_attn_registry(str(tmp_path))
+    assert len(out) == 5 and any(
+        "other.py:2:" in v and "paged_work_list" in v for v in out)
+    (inf / "other.py").unlink()
     # the blessed shape is clean
-    eng.write_text(
-        "class Engine:\n"
-        "    def __init__(self):\n"
-        "        self._attn_decode_sel = select_attention(mode='x')\n"
-        "        self._attn_tree_sel = select_attention(mode='y')\n"
-        "        if self._attn_tree_sel.is_pallas:\n"   # init pin compose
-        "            pass\n"
-        "    def _ragged_forward(self):\n"
-        "        sel = self._attn_tree_sel\n"
-        "        if sel.is_pallas:\n"
-        "            return paged_ragged_attention()\n"
-        "    def _emit_attn_kernel(self, mode):\n"
-        "        return self._attn_decode_sel.path\n")
+    _plant(tmp_path, _ENGINE_OK, _FORWARD_OK)
     assert state_lint.check_attn_registry(str(tmp_path)) == []
     # no engine file at all (foreign checkout): not this lint's problem
     assert state_lint.check_attn_registry(str(tmp_path / "nope")) == []
@@ -768,12 +796,12 @@ def test_attn_registry_detector_flags_adhoc_dispatch(tmp_path):
 def test_attn_registry_detector_requires_selection_reads(tmp_path):
     """A forward that consults NEITHER selection means dispatch regressed
     to an inline conditional — flagged even with zero other violations."""
-    eng = tmp_path / "deepspeed_tpu" / "inference" / "engine_v2.py"
-    eng.parent.mkdir(parents=True)
-    eng.write_text(
-        "class Engine:\n"
-        "    def _ragged_forward(self):\n"
-        "        if self._use_pallas:\n"
+    _plant(
+        tmp_path, _ENGINE_OK,
+        "from ..ops.pallas.paged_attention import paged_ragged_attention\n"
+        "class RaggedForward:\n"
+        "    def __call__(self):\n"
+        "        if self.use_pallas:\n"
         "            return paged_ragged_attention()\n")
     out = state_lint.check_attn_registry(str(tmp_path))
     assert len(out) == 1, "\n".join(out)

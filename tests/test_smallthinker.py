@@ -182,6 +182,7 @@ def serve_logits(model, params, prompt, dtype, n_step=4, n_window=4):
     Returns {form: [(tokens so far, logits row)]}, teacher-forced on the
     engine's own argmax."""
     from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
+    from deepspeed_tpu.inference.forward import merge_step
 
     # (a copy: the engine donates the per-layer leaves to their stack)
     eng = InferenceEngineV2(model, params=jax.tree.map(jnp.copy, params),
@@ -190,15 +191,22 @@ def serve_logits(model, params, prompt, dtype, n_step=4, n_window=4):
     assert "layers_stacked" in eng.params          # the scanned walk
     assert [k.name for k in eng._kinds] == ["full", "window"]
     eng.put(1, prompt, max_new_tokens=n_step + n_window + 2)
-    fwd = jax.jit(eng._ragged_forward)
+    def step(params, pools, slot_maps, tok, pos, tables, lens, sample_idx):
+        # a step program less its sampling: the forward, then the ONE pool
+        # write
+        (k_ys, v_ys), logits = eng._forward(params, pools, tok, pos, tables,
+                                            lens, sample_idx)
+        return merge_step(pools, slot_maps, k_ys, v_ys, tok.shape[1]), logits
+
+    fwd = jax.jit(step)
     out = {"prefill": [], "step": [], "window": []}
     seq = eng.state.seqs[1]
     chunks = 0
     while len(out["step"]) < n_step:
         plan = eng.scheduler.next_step()
         slots, tables = plan.more["window"]
-        args = [jnp.asarray(plan.token_ids), jnp.asarray(plan.positions),
-                (jnp.asarray(plan.slot_map), jnp.asarray(slots)),
+        args = [(jnp.asarray(plan.slot_map), jnp.asarray(slots)),
+                jnp.asarray(plan.token_ids), jnp.asarray(plan.positions),
                 (jnp.asarray(plan.block_tables), jnp.asarray(tables)),
                 jnp.asarray(plan.seq_lens), jnp.asarray(plan.sample_idx)]
         eng.kv_pool, logits = fwd(eng.params, eng.kv_pool, *args)
@@ -228,12 +236,10 @@ def serve_logits(model, params, prompt, dtype, n_step=4, n_window=4):
     toks = list(seq.tokens)
     base = np.zeros(S, np.int32)
     base[seq.slot] = len(toks) - 1
-    zero_slots = (jnp.zeros((S, 1), jnp.int32),) * 2
     win = jax.jit(lambda p, pool, tok, pos, lens, kb, vb, i, b:
-                  eng._ragged_forward(p, pool, tok, pos, zero_slots, tables,
-                                      lens, jnp.zeros_like(lens),
-                                      kv_stage=(kb, vb), stage_fill=i,
-                                      stage_starts=b))
+                  eng._forward(p, pool, tok, pos, tables, lens,
+                               jnp.zeros_like(lens), kv_stage=(kb, vb),
+                               stage_fill=i, stage_starts=b))
     for i in range(n_window):
         tok = np.zeros(S, np.int32)
         pos = np.zeros(S, np.int32)

@@ -307,12 +307,15 @@ def test_engine_v2_odd_row_packed_prefill_rings_tp2():
 
 @pytest.mark.slow
 def test_qgmm_grouped_ring_matches_psum():
-    """The MoE expert-GEMM grouped ring (engine_v2._qgmm row kind under
-    tp_overlap: per-destination token-tile chunks + tile→expert slices
+    """The MoE expert-GEMM grouped ring (``RaggedForward.qgmm``, row kind
+    under tp_overlap: per-destination token-tile chunks + tile→expert slices
     ring-accumulating over the tensor axis) matches the blocking
     psum formulation on the same per-shard-quantized expert slabs."""
+    import dataclasses
+
     from deepspeed_tpu.inference.engine_v2 import (InferenceEngineV2,
                                                    RaggedInferenceConfig)
+    from deepspeed_tpu.inference.forward import MOE_TILE_FLOOR
     from deepspeed_tpu.models.transformer import (ModelConfig, MoEConfig,
                                                   TransformerLM)
     from deepspeed_tpu.ops.pallas.quant_matmul import QuantGrouped
@@ -333,14 +336,16 @@ def test_qgmm_grouped_ring_matches_psum():
     qw = eng.params["layer_0"]["moe"]["moe_layer"]["experts"]["w_down"]
     assert isinstance(qw, QuantGrouped)
     F = mcfg.ffn_size
-    rows = 4 * eng._MOE_GEMM_BLOCK_M          # tile-aligned, % (tp*bm) == 0
+    bm = MOE_TILE_FLOOR[True]                 # the quantised GEMM's least tile
+    rows = 4 * bm                             # tile-aligned, % (tp*bm) == 0
     x2d = jax.random.normal(jax.random.PRNGKey(2), (rows, F), jnp.float32)
     te = jnp.array([0, 2, 1, 3], jnp.int32)   # one expert per tile
 
-    assert eng._tp_ring_n == 2                # ring path engages
-    y_ring = eng._qgmm(x2d, qw, te, "moe_w_down")
-    eng._tp_ring_n = 0                        # blocking psum path
-    y_psum = eng._qgmm(x2d, qw, te, "moe_w_down")
+    fwd = eng._forward
+    assert fwd.tp_ring_n == 2                 # ring path engages
+    y_ring = fwd.qgmm(x2d, qw, te, "moe_w_down", bm)
+    y_psum = dataclasses.replace(fwd, tp_ring_n=0).qgmm(   # blocking psum
+        x2d, qw, te, "moe_w_down", bm)
     np.testing.assert_allclose(np.asarray(y_ring), np.asarray(y_psum),
                                rtol=1e-3, atol=1e-3)
 
