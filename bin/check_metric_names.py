@@ -392,6 +392,13 @@ the spans of one entry share it.
 | `prefill_entries_committed`, `prefill_residence_s` | the same two for prefill plans alone |
 | `decode_tokens` | tokens generated by decode steps (booked at dispatch) and decode windows (booked at commit) |
 | `replica_step_s`, `engine_step_s` | booked by `EngineBackend.step`: its own wall time, and `engine.step()`'s inside it |
+| `kv_blocks_live_<kind>`, `kv_blocks_peak_<kind>` | by PAGED kind of layer (`full`: a table that grows; `window`: a bounded ring): KV blocks live sequences hold, sampled after every dispatch (`StateManager.sample`), and the run's peak |
+| `ring_blocks_reused` | ring slots a page past the window overwrote in place (`StateManager.note_written`, booked at dispatch) |
+| `attn_steps_live_<kind>`, `attn_steps_rect_<kind>` | `attn_steps_live` / `attn_steps_rect` split by kind of layer (each kind walks its own table) |
+| `attn_pages_unclipped`, `attn_pages_clipped` | pool pages a table that grew with the context would have walked in the window layers, and those of them the window kind did not walk |
+| `state_records_live`, `state_records_peak` | a RECORD kind's state (`conv`: the last `conv_taps - 1` inputs of a short convolution, one record a layer and slot, no pages): records live — one a sequence in a slot — sampled after every dispatch, and the run's peak; present only where the model has such layers |
+| `conv_chunks`, `conv_chunks_carried` | prefill rows dispatched, and those of them whose first position is past 0: they started from the record their sequence's last chunk left, not from zeros (booked on the host at dispatch) |
+| `moe_routed_rows`, `moe_padded_rows`, `attn_steps_*` | booked with the layers that HAVE the mechanism — the expert layers, the layers of each paged kind — not `num_layers` (a stack of unlike layers) |
 
 A replica worker that leaves logs one line from them: `pipeline: depth ...
 residence ... ms over ... entries (prefill ... ms over ...); replica step

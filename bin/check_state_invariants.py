@@ -40,6 +40,15 @@ in ``deepspeed_tpu/`` outside the allowlisted ``StateManager`` methods:
   stop). The router-side heartbeat MIRROR deliberately uses a different
   attribute name (``ReplicaHandle.wv``) so it stays writable.
 
+- calls of ``merge_records`` (the write of a RECORD kind's per-slot
+  state — a "conv" layer's last inputs, addressed by the slot a live
+  sequence holds, with no allocator behind it): legal ONLY where a program
+  writes its records ONCE, for the rows live in it (``forward.merge_step``
+  for a step plan, ``engine_v2._window_program`` after the window's scan).
+  A second write inside a program — or one from the host — could land a
+  decode window's record over a half-prefilled sequence's, which no
+  ``audit()`` of slots can see.
+
 Reads (``allocator.free_blocks``, ``prefix_cache.stats()``, iterating
 ``seq.blocks``) are fine anywhere.
 
@@ -140,6 +149,11 @@ EVICT_SINK_ALLOWED = {
     ("replica.py", "__init__"),
 }
 
+#: the one write of a program's records (a record kind's state a slot):
+#: (file basename, function) pairs
+RECORD_WRITE_ALLOWED = {("forward.py", "merge_step"),
+                        ("engine_v2.py", "_window_program")}
+
 #: mutating list-method names (on a ``.blocks`` attribute)
 LIST_MUTATORS = {"append", "extend", "insert", "pop", "remove", "clear",
                  "sort", "reverse"}
@@ -191,6 +205,18 @@ class _Visitor(ast.NodeVisitor):
                 f"{ok}) — route through admit/release")
 
     def visit_Call(self, node: ast.Call):
+        name = node.func.id if isinstance(node.func, ast.Name) \
+            else getattr(node.func, "attr", "")
+        if name == "merge_records" and not any(
+                (self.fname, f) in RECORD_WRITE_ALLOWED
+                for f in self._func_stack):
+            ok = ", ".join(sorted(f"{f}:{fn}"
+                                  for f, fn in RECORD_WRITE_ALLOWED))
+            self.violations.append(
+                f"{self.path}:{node.lineno}: merge_records() called outside "
+                f"the one record write of a program (allowed only in {ok}) "
+                f"— a record kind's state is written once a program, for "
+                f"the rows live in it")
         if isinstance(node.func, ast.Attribute):
             chain = _chain(node.func)
             if len(chain) >= 2:

@@ -56,8 +56,8 @@ from ..ops.pallas.paged_attention import (paged_attention_usable, paged_plan,
 # (``cache_kinds``, ``moe_tile_rows`` and ``moe_padded_rows`` are imported
 # from here by the benchmark's tools too)
 from .forward import (KIND_SPEC_2D, KIND_SPEC_3D, RaggedForward, cache_kinds,
-                      merge_rows, merge_step, moe_padded_rows, moe_tile_rows,
-                      stage_rows)
+                      kv_pack, merge_records, merge_rows, merge_step, moe_padded_rows,
+                      moe_tile_rows, stage_rows)
 from .ragged import StateManager, StepPlan
 from .sampling import sample_logits, sample_tree_logits
 from .scheduler import SpecAcceptTracker, SplitFuseScheduler
@@ -149,6 +149,13 @@ class RaggedInferenceConfig:
     #: ``SplitFuseScheduler.program_shape_menu``); off in rolling-window
     #: mode.
     prefill_pack: bool = True
+    #: the most sequences ONE packed prefill plan carries (0 = as many as
+    #: have work, up to ``max_seqs``). A model whose plans pack ROWS only
+    #: (a ring or a record: the chunk cannot grow) has one program a row
+    #: count, ``max_seqs`` of them; a cap bounds that menu — and a step's
+    #: tokens, rows x ``chunk`` — at what a deployment warms. Sequences
+    #: past the cap keep their place and ride the next prefill plan.
+    prefill_max_rows: int = 0
     #: content-addressed shared-prefix KV cache over the paged pool
     #: (vLLM PagedAttention block sharing + SGLang RadixAttention, TPU
     #: formulation — inference/prefix_cache.py): full KV pages are keyed
@@ -304,6 +311,8 @@ class InferenceEngineV2:
         # one KV cache (allocator, pool, block table a sequence) for each
         # KIND of layer the model has: a table that grows for full layers,
         # a bounded ring for window layers (``cache_kinds``)
+        # — and one RECORD a slot for layers whose state is no pages at all
+        # (a "conv" kind: no allocator, no table)
         self._kinds = cache_kinds(model.config, cfg)
         k0 = self._kinds[0]
         self.state = StateManager(
@@ -311,14 +320,17 @@ class InferenceEngineV2:
             kind=k0.name, ring=bool(k0.ring_tokens),
             more_kinds={k.name: (k.num_blocks, k.max_blocks,
                                  bool(k.ring_tokens))
-                        for k in self._kinds[1:]})
-        has_ring = self.state.has_ring
-        # where a kind keeps a ring, plans pack ROWS only: the rolling table
-        # is sized for chunk-at-most steps, and a grown chunk would overrun
-        # it
+                        for k in self._kinds[1:] if not k.is_record},
+            records={k.name: k.rows for k in self._kinds if k.is_record})
+        # what only a linear chain of pages can do is refused below where
+        # a kind keeps a ring or a record, with this reason
+        not_pages = self.state.not_a_page_chain
+        # there plans pack ROWS only: a rolling table is sized for
+        # chunk-at-most steps and a grown chunk would overrun it; a record
+        # hands over at chunk boundaries the warmed programs know
         self.scheduler = SplitFuseScheduler(
             self.state, cfg.chunk, pack=cfg.prefill_pack,
-            grow_chunk=not has_ring)
+            grow_chunk=not not_pages, max_rows=cfg.prefill_max_rows)
 
         # --- shared-prefix KV cache (radix reuse over the pool) ----------
         use_pc = cfg.prefix_cache
@@ -329,13 +341,13 @@ class InferenceEngineV2:
             # suffix-divergence parity test (tests/test_inference_v2.py::
             # test_v2_fp8_kv_prefix_cache_cross_request_parity) pins warm
             # == cold greedy streams at e4m3 granularity
-            use_pc = self.scheduler.pack and not has_ring
-        if use_pc and has_ring:
+            use_pc = self.scheduler.pack and not not_pages
+        if use_pc and not_pages:
             raise ValueError(
-                "prefix_cache=True cannot combine with a sliding-window "
-                "rolling KV ring: ring tables reuse page slots in place, "
-                "so a published page's content would change under a "
-                "reader (serve linear or set prefix_cache=False)")
+                f"prefix_cache=True needs a sequence's state to be a "
+                f"linear chain of pages: {not_pages} (a rolling KV ring's "
+                f"published page would change under a reader; a record "
+                f"has no page to publish). Set prefix_cache=False")
         self._prefix_cache = None
         if use_pc:
             from .prefix_cache import PrefixCache
@@ -407,8 +419,11 @@ class InferenceEngineV2:
         # unrolled loop.
         m = self.mcfg
         moe_flags = [is_moe_layer(m, i) for i in range(m.num_layers)]
+        # ... and so does a stack whose layers differ in OPERATOR (a
+        # "conv" kind among attention layers)
         self._scan_layers = (m.num_layers > 1 and
-                             (all(moe_flags) or not any(moe_flags)))
+                             (all(moe_flags) or not any(moe_flags))
+                             and not any(k.is_record for k in self._kinds))
         if self._scan_layers:
             layers = [self.params.pop(f"layer_{i}")
                       for i in range(m.num_layers)]
@@ -463,8 +478,14 @@ class InferenceEngineV2:
         # fresh KV rides a small staged buffer and is merged exactly once
         # per dispatch.
         tp = max(topology.size("tensor"), 1)
+        #: the pool's own head geometry: ``kv_pack`` KV heads side by side
+        #: in a page row (2 where heads are 64 wide: ``forward.kv_pack``),
+        #: so ``[.., KV / pack, nb, block, pack * head_dim]``
+        self._kv_pack = kv_pack(m, tp)
+        self._kv_geom = (m.kv_heads // self._kv_pack,
+                         m.head_dim * self._kv_pack)
         kv_spec = P(None, None, "tensor", None, None, None) \
-            if m.kv_heads % tp == 0 else \
+            if self._kv_geom[0] % tp == 0 else \
             P(None, None, None, None, None, None)
         self._pool_sharding = NamedSharding(topology.mesh, kv_spec)
         # pin the pool's jit entry/exit layout to row-major: with the
@@ -482,19 +503,32 @@ class InferenceEngineV2:
         self._guard_pinned_layout_against_cache()
         #: one pool a kind of layer: a tuple in ``self._kinds``' order, for
         #: every model
-        self.kv_pool = tuple(jax.device_put(
-            jnp.zeros((len(k.layers), 2, m.kv_heads, k.num_blocks,
-                       cfg.block_size, m.head_dim),
-                      self._kv_dtype), self._pool_format)
+        # every model; a record kind's entry is its records ``[layers,
+        # max_seqs + 1, rows, width]`` (the last one the trash record),
+        # replicated, in the compute dtype: donated and returned like a pool
+        repl = NamedSharding(topology.mesh, P())
+        self.kv_pool = tuple(
+            jax.device_put(jnp.zeros((len(k.layers), k.num_blocks, k.rows,
+                                      k.width), cfg.dtype), repl)
+            if k.is_record else
+            jax.device_put(
+                jnp.zeros((len(k.layers), 2, self._kv_geom[0], k.num_blocks,
+                           cfg.block_size, self._kv_geom[1]),
+                          self._kv_dtype), self._pool_format)
             for k in self._kinds)
         #: a jitted program's sharding of its ``kv_pool`` argument
-        self._pool_formats = (self._pool_format,) * len(self._kinds)
+        self._pool_formats = tuple(repl if k.is_record else self._pool_format
+                                   for k in self._kinds)
         logger.info("cache: " + "; ".join(
+            f"{k.name}: {len(k.layers)} layer(s), a record of {k.rows} x "
+            f"{k.width} a slot, {k.num_blocks} slots, "
+            f"{self.kv_pool[c].nbytes} bytes (no pages: addressed by the "
+            f"sequence's slot)" if k.is_record else
             f"{k.name}: {len(k.layers)} layer(s), pool {k.num_blocks} "
             f"blocks of {cfg.block_size}, table {k.max_blocks} a sequence"
             + (f" (a ring of {k.ring_tokens} tokens, window {k.window})"
                if k.ring_tokens else " (grows with the context)")
-            for k in self._kinds))
+            for c, k in enumerate(self._kinds)))
 
         # alibi needs a positional bias inside the kernel — XLA path only.
         # pallas_call has no GSPMD rule, so multi-device meshes run the
@@ -505,8 +539,8 @@ class InferenceEngineV2:
         # serving state across non-tensor axes (each data member computes
         # the same thing, which is the multi-replica serving layout).
         tp_ok = (m.num_heads % tp == 0 and m.kv_heads % tp == 0)
-        pallas_ok = (paged_attention_usable(m.num_heads, m.kv_heads,
-                                            m.head_dim, cfg.block_size)
+        pallas_ok = (paged_attention_usable(m.num_heads, *self._kv_geom,
+                                            cfg.block_size)
                      and m.position_embedding != "alibi"
                      and (topology.mesh.size == 1 or tp_ok))
         if cfg.use_pallas_decode and not pallas_ok:
@@ -543,8 +577,8 @@ class InferenceEngineV2:
         # rows, rounded up to a page multiple past one page
         T_tree = max(cfg.spec_max_nodes, 1)
         Ts_tree = stage_rows(T_tree, cfg.block_size)
-        sel_kw = dict(num_heads=m.num_heads, kv_heads=m.kv_heads,
-                      head_dim=m.head_dim, block_size=cfg.block_size,
+        sel_kw = dict(num_heads=m.num_heads, kv_heads=self._kv_geom[0],
+                      head_dim=self._kv_geom[1], block_size=cfg.block_size,
                       use_pallas=self._pallas_decode,
                       reason_not_usable=no_pallas)
         self._attn_decode_sel = select_attention(mode="decode", **sel_kw)
@@ -556,11 +590,13 @@ class InferenceEngineV2:
         #: token's query heads) ride one tile
         self.paged_plans: dict[str, Any] = {}
         if self._attn_paged:
-            plan = paged_plan(cfg.chunk * (m.num_heads // m.kv_heads),
-                              m.kv_heads // tp, cfg.block_size, cfg.dtype)
-            self.paged_plans = {k.name: plan for k in self._kinds}
-            for k in self._kinds:
-                logger.info(f"paged: {k.name}: a chunk of {cfg.chunk} "
+            plan = paged_plan(cfg.chunk * (m.num_heads // self._kv_geom[0]),
+                              self._kv_geom[0] // tp, cfg.block_size,
+                              cfg.dtype)
+            self.paged_plans = {k.name: plan for k in self._kinds
+                                if not k.is_record}
+            for k in self.paged_plans:
+                logger.info(f"paged: {k}: a chunk of {cfg.chunk} "
                             f"tokens is {plan.describe()}")
         self._attn_tree_sel = select_attention(
             mode="tree", tree_nodes=T_tree, stage_rows=Ts_tree, **sel_kw)
@@ -607,7 +643,7 @@ class InferenceEngineV2:
             tp_ring_n=self._tp_ring_n, tp_ring_force=self._tp_ring_force,
             attn_decode_sel=self._attn_decode_sel,
             attn_tree_sel=self._attn_tree_sel, qkind=self._qkind,
-            gmm_plans=self.gmm_plans)
+            gmm_plans=self.gmm_plans, kv_pack=self._kv_pack)
         self._rng = jax.random.PRNGKey(17)
         self._results: dict[int, list[int]] = {}
         # device-resident last sampled token per slot: decode steps read it
@@ -746,7 +782,16 @@ class InferenceEngineV2:
                       # pages a full table would have walked in window
                       # layers, and those of them the window kind did not
                       "attn_pages_unclipped": 0, "attn_pages_clipped": 0}
+        if any(k.is_record for k in self._kinds):
+            # a record kind: records live (one a sequence in a slot) and
+            # the run's peak; prefill rows dispatched, and those of them
+            # that started from a record their last chunk left (not zeros)
+            self.stats.update({"state_records_live": 0,
+                               "state_records_peak": 0,
+                               "conv_chunks": 0, "conv_chunks_carried": 0})
         for k in self._kinds:
+            if k.is_record:
+                continue
             # by kind of layer: blocks live sequences hold (sampled after
             # every dispatch, and the run's peak), and the paged kernel's
             # steps as above
@@ -816,12 +861,28 @@ class InferenceEngineV2:
         programs must never come from the cache — and jax's cache switch
         is process-wide, so the process serves without it (its programs
         compile in seconds: the layer stack is scanned). Where the pin IS
-        the default (CPU; head width >= 128) nothing changes."""
-        cfg, m = self.config, self.mcfg
-        default = jnp.zeros((2, 2, 2, 2, cfg.block_size, m.head_dim),
-                            self._kv_dtype).format.layout.major_to_minor
-        if tuple(default) == (0, 1, 2, 3, 4, 5) \
-                or not jax.config.jax_enable_compilation_cache:
+        the default (CPU; page rows 128 lanes wide: heads of 128, and heads
+        of 64 since PR 50 holds two of them side by side in a page row,
+        ``forward.kv_pack``) nothing changes. Presets that can still reach
+        it: ``falcon-7b`` (ONE KV head of 64: nothing to pair it with),
+        ``phi-2`` (heads of 80), ``phi-3-mini`` and ``gpt-neox-20b`` (96);
+        no cell of the benchmark does. The fault still stands (PR
+        50, calls 1 and 3: a head-64 engine with the guard off died on its
+        warm start with the message above). The default is asked of the
+        pool's REAL shape — it depends on all of it: ``[2, 2, 8, 16, 64,
+        64]`` defaults to row-major, ``[1, 2, 8, 8192, 64, 64]`` to ``(0,
+        1, 2, 4, 5, 3)`` (same calls) — by compiling an identity for that
+        shape: nothing of its size is allocated."""
+        cfg = self.config
+        if not jax.config.jax_enable_compilation_cache:
+            return
+        k0 = self._kinds[0]
+        shape = (len(k0.layers), 2, self._kv_geom[0], k0.num_blocks,
+                 cfg.block_size, self._kv_geom[1])
+        default = jax.jit(lambda x: x).lower(jax.ShapeDtypeStruct(
+            shape, self._kv_dtype, sharding=self._pool_sharding)).compile(
+            ).input_formats[0][0].layout.major_to_minor
+        if tuple(default) == (0, 1, 2, 3, 4, 5):
             return
         from jax.experimental.compilation_cache import \
             compilation_cache as cc
@@ -830,10 +891,10 @@ class InferenceEngineV2:
         cc.reset_cache()
         logger.warning(
             f"engine_v2: persistent compilation cache turned OFF for this "
-            f"process — the KV pool is pinned row-major but this device's "
-            f"default layout for it is {tuple(default)}, and executables "
-            f"read back from the cache lose pinned output layouts "
-            f"(jax {jax.__version__})")
+            f"process — the KV pool {shape} is pinned row-major but this "
+            f"device's default layout for it is {tuple(default)}, and "
+            f"executables read back from the cache lose pinned output "
+            f"layouts (jax {jax.__version__})")
 
     def _init_speculative(self, draft_model, draft_params, draft_rng) -> None:
         """Bring up the configured proposer backend + the per-tenant
@@ -847,12 +908,13 @@ class InferenceEngineV2:
         if cfg.spec_decode not in ("ngram", "draft"):
             raise ValueError(f"spec_decode must be None, 'ngram' or "
                              f"'draft', got {cfg.spec_decode!r}")
-        if self.state.has_ring:
+        if self.state.not_a_page_chain:
             raise ValueError(
-                "spec_decode cannot combine with a sliding-window rolling "
-                "KV ring: provisional verify slots past the committed tail "
-                "would alias live ring pages (serve linear or disable "
-                "spec_decode)")
+                f"spec_decode needs a sequence's state to be a linear "
+                f"chain of pages: {self.state.not_a_page_chain} "
+                f"(provisional verify slots past the committed tail would "
+                f"alias live pages of a rolling KV ring; a record cannot "
+                f"take back a rejected token). Disable spec_decode")
         if self._tp_ring_force:
             raise ValueError(
                 "spec_decode cannot combine with tp_overlap=True: the "
@@ -1001,20 +1063,25 @@ class InferenceEngineV2:
         E = m.hidden_size
         for i in range(m.num_layers):
             layer = self.params[f"layer_{i}"]
-            a = layer["attn"]
-            sa = spec0.get("attn", {})
-            for k in ("wq", "wk", "wv"):
-                a[k] = q2d(a[k], E, k, sa.get(k))         # [E, (H|KV)*D]
-            a["wo"] = q2d(a["wo"], m.num_heads * m.head_dim, "wo",
-                          sa.get("wo"))
+            # (a "conv" layer's operator stays exact: 2.7 % of an LFM2
+            # expert layer's bytes, as the router and a shared expert do)
+            if "attn" in layer:
+                a = layer["attn"]
+                sa = spec0.get("attn", {})
+                for k in ("wq", "wk", "wv"):
+                    a[k] = q2d(a[k], E, k, sa.get(k))     # [E, (H|KV)*D]
+                a["wo"] = q2d(a["wo"], m.num_heads * m.head_dim, "wo",
+                              sa.get("wo"))
             if "ffn" in layer:
                 f = layer["ffn"]
                 sf = spec0.get("ffn", {})
                 for k in ("w_gate", "w_up"):
                     if k in f:
                         f[k] = q2d(f[k], E, k, sf.get(k))
-                f["w_down"] = q2d(f["w_down"], m.ffn_size, "w_down",
-                                  sf.get("w_down"))
+                # (its own width: a mixed stack's dense layers are not
+                # the experts' ``ffn_size``)
+                f["w_down"] = q2d(f["w_down"], f["w_down"].shape[0],
+                                  "w_down", sf.get("w_down"))
             if "moe" in layer:
                 ex = layer["moe"]["moe_layer"]["experts"]
                 se = (spec0.get("moe", {}).get("moe_layer", {})
@@ -1124,13 +1191,18 @@ class InferenceEngineV2:
             def run(params, kv_pool, last_tok, tok_host, use_last, pos0,
                     lens0, block_tables, rem, eos_ids, rng):
                 S = tok_host.shape[0]
-                KV, D = m.kv_heads, m.head_dim
+                KV, D = self._kv_geom
                 kinds = self._kinds
                 tok0 = jnp.where(use_last.astype(bool), last_tok, tok_host)
                 active0 = rem > 0
-                # (a tuple a kind of layer, as the forward takes them)
-                stage0 = tuple(jnp.zeros((len(k.layers), S, KV, Ws, D),
-                                         cfg.dtype) for k in kinds)
+                # (a tuple a kind of layer, as the forward takes them; a
+                # record kind's running record starts as its slots' records
+                # — a window's row IS its slot — and has no V half)
+                stage0 = tuple(
+                    (pool[:, :S], None) if k.is_record else
+                    (jnp.zeros((len(k.layers), S, KV, Ws, D), cfg.dtype),) * 2
+                    for k, pool in zip(kinds, kv_pool))
+                stage0 = tuple(zip(*stage0))     # (k halves, v halves)
                 base = pos0          # stage base position, fixed per window
 
                 def _iter(i, tok, pos, lens, rng, active, kbuf, vbuf):
@@ -1139,6 +1211,9 @@ class InferenceEngineV2:
                     state."""
                     slots = []
                     for k, table in zip(kinds, block_tables):
+                        if k.is_record:      # written once, by slot, below
+                            slots.append(None)
+                            continue
                         blk = jnp.take_along_axis(
                             table, ((pos // bs) % k.max_blocks)[:, None],
                             axis=1)[:, 0]  # ring slot (mod no-op linear)
@@ -1148,11 +1223,18 @@ class InferenceEngineV2:
                                                blk * bs + pos % bs, 0))
                     slot = tuple(slots)
                     with nn.logical_axis_rules(self._rules):
-                        (kbuf, vbuf), logits = self._forward(
+                        (kb_new, vbuf), logits = self._forward(
                             params, kv_pool, tok[:, None], pos[:, None],
                             block_tables, lens, jnp.zeros_like(pos),
                             kv_stage=(kbuf, vbuf), stage_fill=i,
                             stage_starts=base)
+                    # a row that is not live in this iteration keeps the
+                    # record it has
+                    kbuf = tuple(
+                        jnp.where(active[None, :, None, None],
+                                  new.astype(old.dtype), old)
+                        if k.is_record else new
+                        for k, new, old in zip(kinds, kb_new, kbuf))
                     with device_scope("sample"):
                         rng, sub = jax.random.split(rng)
                         nxt = sample_logits(logits.astype(jnp.float32), sub,
@@ -1180,7 +1262,7 @@ class InferenceEngineV2:
 
                 ((tok, _, _, _, _, kbuf, vbuf),
                  (buf, slots)) = jax.lax.scan(
-                    body, (tok0, pos0, lens0, rng, active0, stage0, stage0),
+                    body, (tok0, pos0, lens0, rng, active0, *stage0),
                     jnp.arange(W, dtype=jnp.int32))
                 # useful-iteration count: iterations past the last active
                 # slot emit all -1
@@ -1199,6 +1281,12 @@ class InferenceEngineV2:
                 merged = []
                 for k, pool, kb, vb, sl in zip(kinds, kv_pool, kbuf, vbuf,
                                                slots):
+                    if k.is_record:
+                        # the window's last records, for the rows that
+                        # took part in it; the rest land in the trash one
+                        merged.append(merge_records(
+                            pool, jnp.where(active0, jnp.arange(S), S), kb))
+                        continue
                     L = len(k.layers)
                     with device_scope("kv_commit"):
                         ks = (kb[:, :, :, :W, :].transpose(0, 3, 1, 2, 4)
@@ -1240,7 +1328,8 @@ class InferenceEngineV2:
                 W //= 2
         S = self.state.max_seqs
         z = lambda *s: np.zeros(s, np.int32)
-        tables = tuple(z(S, k.max_blocks) for k in self._kinds)
+        tables = tuple(z(S) if k.is_record else z(S, k.max_blocks)
+                       for k in self._kinds)
         for W in sizes:
             if W <= 1 or (skip_existing and ("win", W) in self._programs):
                 continue
@@ -1286,7 +1375,9 @@ class InferenceEngineV2:
             use_last = np.zeros((S,), np.uint8)
             pos0 = np.zeros((S,), np.int32)
             lens0 = np.zeros((S,), np.int32)
-            tables = tuple(np.zeros((S, k.max_blocks), np.int32)
+            # (a record kind has no table: a window's row is its slot)
+            tables = tuple(np.zeros((S,) if k.is_record
+                                    else (S, k.max_blocks), np.int32)
                            for k in self._kinds)
             rem = np.zeros((S,), np.int32)
             eos = np.full((S,), -1, np.int32)
@@ -1300,8 +1391,9 @@ class InferenceEngineV2:
                 pos0[sl] = s.len_sched - 1
                 lens0[sl] = s.len_sched
                 for k, table in zip(self._kinds, tables):
-                    blocks = self.state.blocks_of(s, k.name)
-                    table[sl, :len(blocks)] = blocks
+                    if not k.is_record:
+                        blocks = self.state.blocks_of(s, k.name)
+                        table[sl, :len(blocks)] = blocks
                 n = min(s.gen_remaining_sched, W)
                 rem[sl] = n
                 if s.eos_id is not None:
@@ -1398,9 +1490,9 @@ class InferenceEngineV2:
                 L, S = k_ys.shape[0], k_ys.shape[1]
                 with device_scope("kv_commit"):
                     ks = (k_ys[:, :, :, :T, :].transpose(0, 1, 3, 2, 4)
-                          .reshape(L, S * T, m.kv_heads, m.head_dim))
+                          .reshape(L, S * T, *self._kv_geom))
                     vs = (v_ys[:, :, :, :T, :].transpose(0, 1, 3, 2, 4)
-                          .reshape(L, S * T, m.kv_heads, m.head_dim))
+                          .reshape(L, S * T, *self._kv_geom))
                     return merge_rows(kv_pool, flat_slots, ks, vs)
 
             run.__name__ = "spec_merge"
@@ -1629,6 +1721,8 @@ class InferenceEngineV2:
         bs = self.config.block_size
         st = self.stats
         for k in self._kinds:
+            if k.is_record:
+                continue
             live, rect = paged_step_counts(
                 seq_lens, starts, starts, block_size=bs,
                 max_pages=k.max_blocks, stage_rows=stage_rows,
@@ -1720,6 +1814,11 @@ class InferenceEngineV2:
         if plan.kind == "prefill":
             self.stats["prefill_steps"] += 1
             self.stats["prefill_tokens"] += n_tok
+            if "conv_chunks" in self.stats:
+                live = np.asarray(plan.uids) >= 0
+                self.stats["conv_chunks"] += int(live.sum())
+                self.stats["conv_chunks_carried"] += int(
+                    (live & (plan.positions[:, 0] > 0)).sum())
         else:
             self.stats["decode_steps"] += 1
             self.stats["decode_tokens"] += n_tok
@@ -1737,9 +1836,13 @@ class InferenceEngineV2:
         device when it dispatches; and, by kind of layer, the KV blocks
         live sequences hold now."""
         for name, n in self.state.sample().items():
+            k = self.state.kinds[name]
+            if k.record_rows:
+                self.stats["state_records_live"] = n
+                self.stats["state_records_peak"] = k.blocks_peak
+                continue
             self.stats[f"kv_blocks_live_{name}"] = n
-            self.stats[f"kv_blocks_peak_{name}"] = \
-                self.state.kinds[name].blocks_peak
+            self.stats[f"kv_blocks_peak_{name}"] = k.blocks_peak
         self.stats["ring_blocks_reused"] = sum(
             k.blocks_reused for k in self.state.kinds.values())
         depth = len(self._inflight)
@@ -2031,7 +2134,7 @@ class InferenceEngineV2:
     def can_import(self, n_tokens: int, remaining_gen: int) -> bool:
         """Would ``import_reserve`` succeed right now? (The serving
         replica's admission check before it acks a migration begin.)"""
-        if self.state.has_ring:
+        if self.state.not_a_page_chain:
             return False
         return self.state.can_admit(n_tokens, remaining_gen)
 
@@ -2046,10 +2149,10 @@ class InferenceEngineV2:
         from .migration import PageBundle
         from .prefix_cache import chain_hashes
 
-        if self.state.has_ring:
+        if self.state.not_a_page_chain:
             raise RuntimeError(
-                "page migration requires linear block tables "
-                "(rolling-ring mode reuses page slots in place)")
+                f"page migration requires linear block tables: "
+                f"{self.state.not_a_page_chain}")
         while self._inflight and self._uid_inflight(uid):
             self._drain(force=True)
         snap = self.state.migrate_out(uid, trace=trace_id or None)
@@ -2104,8 +2207,8 @@ class InferenceEngineV2:
     @property
     def _page_shape(self) -> tuple[int, ...]:
         m = self.mcfg
-        return (m.num_layers, 2, m.kv_heads, self.config.block_size,
-                m.head_dim)
+        return (len(self._kinds[0].layers), 2, self._kv_geom[0],
+                self.config.block_size, self._kv_geom[1])
 
     @property
     def _page_bytes(self) -> int:
@@ -2143,9 +2246,9 @@ class InferenceEngineV2:
         from .migration import MigrationError, PageBundle
 
         shell = PageBundle.from_meta(meta)
-        if self.state.has_ring:
-            raise MigrationError("rolling-ring pools cannot import "
-                                 "page chains")
+        if self.state.not_a_page_chain:
+            raise MigrationError(f"this pool cannot import page chains: "
+                                 f"{self.state.not_a_page_chain}")
         if shell.block_size != self.config.block_size:
             raise MigrationError(
                 f"block_size mismatch: bundle {shell.block_size}, "
@@ -2241,8 +2344,11 @@ class InferenceEngineV2:
         fallback and the puller recomputes)."""
         from .migration import MigrationError, PageBundle
 
-        if self._prefix_cache is None or self.state.has_ring:
-            raise MigrationError("no shareable prefix cache on this pool")
+        if self._prefix_cache is None:
+            raise MigrationError(
+                "no shareable prefix cache on this pool"
+                + (f": {self.state.not_a_page_chain}"
+                   if self.state.not_a_page_chain else ""))
         snap = self.state.snapshot_prefix(tokens, trace=trace_id or None)
         if snap is None:
             raise MigrationError("prefix chain not cached")
@@ -2283,8 +2389,11 @@ class InferenceEngineV2:
                 f"version_skew: chain computed under "
                 f"{bundle.weight_version}, pool serves "
                 f"{self._weight_version}")
-        if self._prefix_cache is None or self.state.has_ring:
-            raise MigrationError("no shareable prefix cache on this pool")
+        if self._prefix_cache is None:
+            raise MigrationError(
+                "no shareable prefix cache on this pool"
+                + (f": {self.state.not_a_page_chain}"
+                   if self.state.not_a_page_chain else ""))
         if bundle.block_size != self.config.block_size:
             raise MigrationError(
                 f"block_size mismatch: bundle {bundle.block_size}, "

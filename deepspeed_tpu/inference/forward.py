@@ -5,7 +5,12 @@ model:
 
 - **A tuple a kind of layer, always** (:func:`cache_kinds`' order): the pools,
   the block tables, the staged buffers and the forward's fresh K/V are tuples
-  of ``len(kinds)`` entries, for a model of one kind too.
+  of ``len(kinds)`` entries, for a model of one kind too. A kind whose state
+  is a RECORD a slot and not pages ("conv": the last inputs of a short
+  convolution) is one more entry of the same tuples: its "pool" is the
+  records ``[layers, max_seqs + 1, rows, width]``, its "table" each row's
+  slot, its fresh state each row's new record; a model without such layers
+  carries no such entry.
 - **One walk over a stacked model** (:func:`scan_layers`: a scan over periods
   of layer kinds; a period of one is the plain scan over depth). Layers that
   cannot be stacked (MoE on some layers only) take the unrolled loop.
@@ -36,6 +41,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.transformer import (
     _ACTS,
+    CONV,
     GLU_ACTS,
     DenseFFN,
     ModelConfig,
@@ -43,6 +49,7 @@ from ..models.transformer import (
     alibi_slopes,
     apply_rope,
     cache_kind,
+    conv_mix,
     dense_ffn_config,
     is_moe_layer,
     kind_ropes,
@@ -144,32 +151,50 @@ def scan_layers(stacked: Pytree, x, apply_layer, period: int = 1,
 
 @dataclass(frozen=True)
 class CacheKind:
-    """One kind of layer's KV cache as the engine holds it: which layers
-    write it, the mask they attend under, and the geometry of a sequence's
-    block table in its pool (``StateManager.kinds`` holds the allocator).
+    """One kind of layer's cache as the engine holds it: which layers write
+    it and its geometry. A PAGED kind ("full" | "window": keys and values):
+    the mask its layers attend under and the width of a sequence's block
+    table in its pool (``StateManager.kinds`` holds the allocator);
     ``ring_tokens`` > 0: the table is a ring of that many token slots,
-    reused in place (a window kind narrower than a whole context)."""
-    name: str                      # "full" | "window"
+    reused in place (a window kind narrower than a whole context). A RECORD
+    kind ("conv", ``rows`` > 0): ``rows`` x ``width`` values a layer and
+    SLOT, addressed by the slot a live sequence already holds — no
+    allocator, no table, nothing to reserve."""
+    name: str                      # "full" | "window" | "conv"
     layers: tuple[int, ...]        # the model's layers of this kind
     window: int | None             # sliding-window mask (None: full)
     max_blocks: int                # block-table width of a sequence
     ring_tokens: int               # 0 = a table that grows
-    num_blocks: int                # blocks of its pool
+    num_blocks: int                # blocks of its pool (records: slots + 1)
+    rows: int = 0                  # > 0: a record kind, rows of a record
+    width: int = 0                 # values a row of a record
+
+    @property
+    def is_record(self) -> bool:
+        return self.rows > 0
 
 
 def cache_kinds(m: ModelConfig, cfg: "RaggedInferenceConfig"
                 ) -> tuple[CacheKind, ...]:
-    """The caches a model's layers need, the PRIMARY first ("full" where
-    the model has full layers). A window kind keeps a ring of
+    """The caches a model's layers need: the PAGED kinds first, each with
+    the layers that HAVE keys and values only, the PRIMARY of them first
+    ("full" where the model has full layers), then the record kind where
+    the model has "conv" layers. A window kind keeps a ring of
     ceil((W + step) / block) + 1 blocks a sequence where that is narrower
     than a whole context — the mistral rolling buffer: only the last window
     (+ the step being written) stays resident. The primary's pool is
-    ``num_blocks``; a further kind's is every slot's whole ring
-    (``max_seqs`` x ring + the trash block), so it never refuses."""
+    ``num_blocks``; a further paged kind's is every slot's whole ring
+    (``max_seqs`` x ring + the trash block), so it never refuses. The
+    record kind "conv" holds ``conv_taps - 1`` rows of ``hidden_size`` a
+    layer and slot, ``max_seqs`` records and one more for rows that are not
+    live (the trash record, as a pool's trash block)."""
     bs = cfg.block_size
     whole = -(-cfg.max_seq_len // bs)
-    of = [cache_kind(m.layer_kind(i)) for i in range(m.num_layers)]
-    names = sorted(set(of))                     # "full" < "window"
+    of = [cache_kind(k) for k in m.kinds]
+    names = sorted(set(of) - {CONV})            # "full" < "window"
+    if not names:
+        raise ValueError("a model needs at least one attention layer: the "
+                         "engine's primary cache is a paged one")
     out = []
     for name in names:
         width, ring, W = whole, 0, None
@@ -183,7 +208,61 @@ def cache_kinds(m: ModelConfig, cfg: "RaggedInferenceConfig"
         out.append(CacheKind(
             name, tuple(i for i, k in enumerate(of) if k == name), W, width,
             ring, cfg.num_blocks if not out else cfg.max_seqs * width + 1))
+    if CONV in of:
+        out.append(CacheKind(
+            CONV, tuple(i for i, k in enumerate(of) if k == CONV), None, 0,
+            0, cfg.max_seqs + 1, rows=m.conv_taps - 1, width=m.hidden_size))
     return tuple(out)
+
+
+def kv_pack(m: ModelConfig, tp: int = 1) -> int:
+    """How many KV heads share one page row of the pool: 2 where a head is
+    64 wide (and the KV heads pair up, on every tensor shard), else 1.
+
+    A pool ``[L, 2, KV, nb, block, 64]`` pinned row-major is NOT the
+    device's default layout on a v5e at a serving size — ``[1, 2, 8, 8192,
+    64, 64]`` defaults to ``(0, 1, 2, 4, 5, 3)``, ``[.., 128, 64]`` swaps
+    the page and head dims (PR 50, calls 1 and 3) — and an executable read
+    back from the persistent compile cache hands its outputs back in the
+    DEFAULT layout whatever it pinned (jax 0.9.0 / libtpu 0.0.34, PR 21;
+    seen again in both calls), so such an engine had to serve with the
+    cache off. Two KV heads side by side make the pool ``[L, 2, KV/2, nb,
+    block, 128]``: the same bytes, row-major BY default, no lane padding
+    (a 64-wide row is stored in a 128-lane tile: twice its bytes). The
+    paged kernel then sees a model of KV/2 heads of 128: a query carries
+    zeros in its partner's 64 lanes (its scores are its own head's; the
+    scale stays 1/sqrt(64)) and keeps its own half of the output. The
+    kernel is untouched and reads the same K and V bytes; the 128-deep
+    contraction the MXU does anyway runs over the partner's lanes times
+    zero, and the P.V product is twice as wide. Heads of 128 and more are
+    left as they were."""
+    return 2 if (m.head_dim == 64 and m.kv_heads % (2 * max(tp, 1)) == 0) \
+        else 1
+
+
+def pack_heads(q, k, v, pack: int):
+    """``q`` ``[S, T, H, D]`` and ``k``, ``v`` ``[S, T, KV, D]`` as the
+    kernel sees them over a pool of ``pack`` KV heads a page row: ``[S, T,
+    H, pack * D]`` with zeros outside the lanes of the query's own KV head,
+    and ``[S, T, KV / pack, pack * D]`` (a reshape)."""
+    S, T, H, D = q.shape
+    KV = k.shape[2]
+    q6 = q.reshape(S, T, KV // pack, pack, H // KV, D)
+    q = jnp.stack([jnp.pad(q6[:, :, :, j], [(0, 0)] * 4
+                           + [(j * D, (pack - 1 - j) * D)])
+                   for j in range(pack)], axis=3).reshape(S, T, H, pack * D)
+    shape = (S, T, KV // pack, pack * D)
+    return q, k.reshape(shape), v.reshape(shape)
+
+
+def unpack_heads(o, kv_heads: int, pack: int):
+    """The attention output ``[S, T, H, pack * D]`` over a packed pool back
+    to ``[S, T, H, D]``: each head keeps the lanes of its own KV head."""
+    S, T, H, Dp = o.shape
+    D = Dp // pack
+    o7 = o.reshape(S, T, kv_heads // pack, pack, H // kv_heads, pack, D)
+    return jnp.stack([o7[:, :, :, j, :, j] for j in range(pack)],
+                     axis=3).reshape(S, T, H, D)
 
 
 def _io_specs(kind: str) -> tuple[P, P]:
@@ -228,6 +307,9 @@ class RaggedForward:
     qkind: Mapping[str, str]
     #: the engine's ``gmm_plans``: ``gmm`` books each distinct block there
     gmm_plans: dict
+    #: KV heads held side by side in one page row (:func:`kv_pack`): 1, or
+    #: 2 where heads are 64 wide
+    kv_pack: int = 1
 
     def qmm(self, x2d, qw, name: str, li=None):
         """Quantized matmul dispatch: single device runs the Pallas kernel
@@ -363,7 +445,17 @@ class RaggedForward:
         layer in ``self.kinds``' order, and so are ``k_ys`` / ``v_ys``: this
         call's fresh K/V, ``[layers of the kind, S, KV, Ts, D]``, for the
         CALLER to merge inside its own program (:func:`merge_step`,
-        :func:`merge_rows`).
+        :func:`merge_rows`). A record kind's entries: ``kv_pools[c]`` the
+        records ``[layers, slots + 1, rows, width]``, ``block_tables[c]``
+        each row's slot ``[S]``, ``k_ys[c]`` each row's NEW record
+        ``[layers, S, rows, width]`` (``v_ys[c]`` None) for the caller to
+        write for the rows that are live (:func:`merge_records`). A row
+        whose first position is 0 starts from zeros, whatever its slot's
+        record holds; a row's new record is that of its VALID tokens
+        (``seq_lens`` less its first position: a chunk's padding does not
+        count). In window mode ``kv_stage[0][c]`` is the running record
+        ``[layers, S, rows, width]``, read instead of the records and
+        returned advanced by this iteration's token.
 
         The pools hold only ALREADY-MERGED tokens (positions
         < stage_starts); the fresh K/V ride a small staged buffer that
@@ -396,10 +488,13 @@ class RaggedForward:
         S, T = token_ids.shape
         bs = cfg.block_size
         H, KV, D = m.num_heads, m.kv_heads, m.head_dim
+        #: the pool's own head geometry (``kv_pack`` heads a page row)
+        pk = self.kv_pack
+        KVp, Dp = KV // pk, D * pk
         window_mode = kv_stage is not None
         #: the cache (an index into ``kinds``) of a layer kind
         cache_of = {name: [k.name for k in kinds].index(cache_kind(name))
-                    for name in set(m.kinds_period)}
+                    for name in set(m.kinds)}
         period = m.kinds_period
         tree_mode = tree_mask is not None
         q_starts = positions[:, 0]
@@ -410,6 +505,12 @@ class RaggedForward:
             Ts = kbufs[0].shape[3]
         else:
             Ts = stage_rows(T, bs)
+        if CONV in cache_of:
+            #: a record kind's rows: tokens of this call that count, and
+            #: whether the row starts its sequence (no past: zeros)
+            n_valid = jnp.ones_like(seq_lens) if window_mode \
+                else seq_lens - q_starts
+            fresh_row = (q_starts == 0)[:, None, None]
 
         # ring collective-matmul TP: static per program — the token-sharded
         # residual stream needs the row dim to divide the tensor axis
@@ -524,7 +625,8 @@ class RaggedForward:
                                     ml["gate"]["wg"].astype(jnp.float32))
                 gate = topk_dropless_gating(
                     logits[None], mo.top_k,
-                    normalize_gates=mo.normalize_gates)
+                    normalize_gates=mo.normalize_gates,
+                    score=mo.router_score, bias=ml["gate"].get("bias"))
 
             def exw(k):      # stripped (stacked) slabs are closed over
                 w = ml["experts"].get(k)
@@ -654,6 +756,8 @@ class RaggedForward:
             qli = li if qstack else None
             with device_scope("attn_qkv"):
                 q, k, v = qkv(a, qli, h, kind)
+                if pk > 1:
+                    q, k, v = pack_heads(q, k, v, pk)
             with device_scope("kv_stage"):
                 stage_l = stage(k, v, stage_l)
             # window and global layers told apart, inside ``attn_core``,
@@ -666,6 +770,8 @@ class RaggedForward:
             with device_scope("attn_core"), sub:
                 o = core(c, lk, q, stage_l)
             with device_scope("attn_out"):
+                if pk > 1:
+                    o = unpack_heads(o, KV, pk)
                 return out_proj(a, qli, o), stage_l
 
         def qkv(a, qli, h, kind):
@@ -746,6 +852,7 @@ class RaggedForward:
                         qq, pp, ks, vs, bt, sl, qs, ss,
                         block_size=bs, layer_index=lr, window=win,
                         ring_tokens=ring, work=(wl, nw),
+                        scale=None if pk == 1 else D ** -0.5,
                         tree_positions=t[0] if t else None,
                         tree_mask=t[1] if t else None)
 
@@ -781,9 +888,9 @@ class RaggedForward:
                                      k_st.transpose(0, 2, 1, 3)], axis=1)
                 V = jnp.concatenate([V.astype(cfg.dtype),
                                      v_st.transpose(0, 2, 1, 3)], axis=1)
-                if KV != H:
-                    K = jnp.repeat(K, H // KV, axis=2)
-                    V = jnp.repeat(V, H // KV, axis=2)
+                if KVp != H:
+                    K = jnp.repeat(K, H // KVp, axis=2)
+                    V = jnp.repeat(V, H // KVp, axis=2)
 
                 scores = jnp.einsum("sthd,schd->shtc", q, K).astype(jnp.float32)
                 scores = scores / (D ** 0.5)
@@ -866,10 +973,19 @@ class RaggedForward:
                 return Norm(m).apply({"params": p_ln}, x)
 
         def layer(x, p, li, use_moe, stage_l, kind, lk):
+            """``stage_l``: the layer's staged K/V — or, for a "conv"
+            layer, the record it starts from; returned advanced."""
             qli = li if qstack else None
             h_attn = norm(p["ln_attn"], x)
-            o, stage_l = attention(p, li, h_attn, stage_l, kind,
-                                   cache_of[kind], lk)
+            if kind == CONV:
+                with device_scope("conv_mix"):
+                    o, stage_l = conv_mix(
+                        m, p["conv"], h_attn,
+                        jnp.where(fresh_row, 0, stage_l).astype(cfg.dtype),
+                        n_valid)
+            else:
+                o, stage_l = attention(p, li, h_attn, stage_l, kind,
+                                       cache_of[kind], lk)
             if not m.parallel_block:
                 x = x + o
             h_ffn = h_attn if m.parallel_block \
@@ -894,14 +1010,17 @@ class RaggedForward:
         attn_works = [()] * len(kinds)
         if sel.is_pallas:
             with device_scope("attn_core"):
-                attn_works = [paged_work_list(
+                attn_works = [() if k.is_record else paged_work_list(
                     seq_lens, q_starts, stage_starts, block_size=bs,
                     max_pages=block_tables[c].shape[1], stage_rows=Ts,
                     window=k.window, ring_tokens=k.ring_tokens,
                     tree=tree_mode) for c, k in enumerate(kinds)]
-        empty_stage = (jnp.zeros((S, KV, Ts, D), cfg.dtype),) * 2
+        empty_stage = (jnp.zeros((S, KVp, Ts, Dp), cfg.dtype),) * 2
         P_ = len(period)
         if "layers_stacked" in params:
+            if CONV in cache_of:
+                raise ValueError("a model with 'conv' layers is walked "
+                                 "unrolled, not stacked")
             # a scan over PERIODS (of one layer, for a model of one kind):
             # one traced body a place, whatever the depth; the pools never
             # enter the carry — only the small staged KV does. Place j of a
@@ -940,6 +1059,15 @@ class RaggedForward:
                 use_moe = is_moe_layer(m, i)
                 c = cache_of[m.layer_kind(i)]
                 lk = kinds[c].layers.index(i)
+                if kinds[c].is_record:
+                    # the record the row starts from: the running one of a
+                    # window, else its slot's (``block_tables[c]``: slots)
+                    rec = kbufs[c][lk] if window_mode \
+                        else kv_pools[c][lk][block_tables[c]]
+                    x, rec = layer(x, params[f"layer_{i}"], i, use_moe,
+                                   rec, CONV, lk)
+                    lists[c][0].append(rec)
+                    continue
                 stage_l = (kbufs[c][lk], vbufs[c][lk]) if window_mode \
                     else empty_stage
                 x, stage_l = layer(x, params[f"layer_{i}"], i, use_moe,
@@ -947,7 +1075,7 @@ class RaggedForward:
                 lists[c][0].append(stage_l[0])
                 lists[c][1].append(stage_l[1])
             k_ys = [jnp.stack(ks) for ks, _ in lists]
-            v_ys = [jnp.stack(vs) for _, vs in lists]
+            v_ys = [jnp.stack(vs) if vs else None for _, vs in lists]
 
         def head(x):
             x = Norm(m).apply({"params": params["ln_final"]}, x)
@@ -996,6 +1124,19 @@ class RaggedForward:
             (logits.reshape(S, T, -1) if tree_mode else logits)
 
 
+def merge_records(records, write_slots, new):
+    """THE one write of a program's records (a record kind: ``records``
+    ``[layers, slots + 1, rows, width]``): row ``s``'s new record
+    (``new`` ``[layers, S, rows, width]``, :class:`RaggedForward`'s
+    ``k_ys`` entry of the kind) lands at ``write_slots[s]`` — the row's
+    slot where the row is live in this step, the last (trash) record where
+    it is not: a sequence half-way through its prompt sits in a slot that
+    a decode program also spans, and must find its record as its last
+    chunk left it."""
+    with device_scope("state_commit"):
+        return records.at[:, write_slots].set(new.astype(records.dtype))
+
+
 def merge_step(kv_pools, slot_maps, k_ys, v_ys, T: int):
     """THE one pool write of a step program: every kind's fresh K/V of a
     ``[S, T]`` plan (:class:`RaggedForward`'s ``k_ys`` / ``v_ys``) lands at
@@ -1004,9 +1145,14 @@ def merge_step(kv_pools, slot_maps, k_ys, v_ys, T: int):
     (block 0) by construction. DUS merges avoid the scatter layout war (see
     :func:`merge_stage`: at SmallThinker's cell the scatter held a copy of
     the window layers' whole 1.3 GiB pool as a temporary of every prefill
-    step); page-misaligned chunks keep the scatter."""
+    step); page-misaligned chunks keep the scatter. A record kind (its
+    ``v_ys`` entry is None, its ``slot_maps`` entry each row's write slot
+    ``[S]``) is written by :func:`merge_records`."""
     merged = []
     for pool, slots, kc, vc in zip(kv_pools, slot_maps, k_ys, v_ys):
+        if vc is None:
+            merged.append(merge_records(pool, slots, kc))
+            continue
         L, _, KV, _, bs, D = pool.shape
         if T == 1:
             pool = merge_rows(pool, slots[:, 0],

@@ -49,17 +49,23 @@ class BlockedAllocator:
 
 @dataclass
 class KindCache:
-    """The KV cache of ONE kind of layer: its own block allocator (its own
-    pool on the device) and the width of a sequence's block table there.
-    "full": the table grows with the context. "window": a ring of
-    ``max_blocks_per_seq`` slots — absolute position ``p`` lives in slot
-    ``(p // block_size) % max_blocks_per_seq``, so a sequence never holds
-    more than its ring (``ring`` says whether the table is narrower than a
-    whole context, i.e. whether slots are ever reused in place)."""
+    """The cache of ONE kind of layer. A PAGED kind: its own block
+    allocator (its own pool on the device) and the width of a sequence's
+    block table there. "full": the table grows with the context. "window":
+    a ring of ``max_blocks_per_seq`` slots — absolute position ``p`` lives
+    in slot ``(p // block_size) % max_blocks_per_seq``, so a sequence never
+    holds more than its ring (``ring`` says whether the table is narrower
+    than a whole context, i.e. whether slots are ever reused in place). A
+    RECORD kind ("conv", ``record_rows`` > 0): a fixed record a layer and
+    SLOT on the device, addressed by the slot a live sequence already
+    holds — no allocator (None), no table, nothing to reserve: it never
+    refuses an admission. ``blocks_peak`` counts its records live."""
     name: str
-    allocator: BlockedAllocator
+    allocator: BlockedAllocator | None
     max_blocks_per_seq: int
     ring: bool = False
+    #: > 0: a record kind, rows of one record
+    record_rows: int = 0
     #: ring slots overwritten in place (a page past the window reused)
     blocks_reused: int = 0
     #: most blocks live sequences held at once (``StateManager.sample``)
@@ -215,7 +221,8 @@ class StateManager:
 
     def __init__(self, num_blocks: int, block_size: int, max_seqs: int,
                  max_blocks_per_seq: int, kind: str = "full",
-                 ring: bool = False, more_kinds: dict | None = None):
+                 ring: bool = False, more_kinds: dict | None = None,
+                 records: dict | None = None):
         """One allocator and one block table a sequence for each kind of
         layer the model has. The PRIMARY kind is the positional arguments'
         (``state.allocator``, ``state.max_blocks_per_seq``, ``seq.blocks``
@@ -226,7 +233,9 @@ class StateManager:
         (ceil((window + step) / bs) + 1 slots): the physical slot of
         absolute position p is (p // bs) % width, so a sequence never pins
         more than one window of KV there (the mistral rolling cache). A
-        full kind's is the same formula — the mod never fires."""
+        full kind's is the same formula — the mod never fires. ``records``
+        maps each RECORD kind to the rows of its record: listed in
+        ``kinds`` (``sample``, ``audit``) with nothing to reserve."""
         self.block_size = block_size
         self.max_seqs = max_seqs
         self.kinds: dict[str, KindCache] = {kind: KindCache(
@@ -234,6 +243,8 @@ class StateManager:
         for name, (nb, width, is_ring) in (more_kinds or {}).items():
             self.kinds[name] = KindCache(name, BlockedAllocator(nb), width,
                                          is_ring)
+        for name, rows in (records or {}).items():
+            self.kinds[name] = KindCache(name, None, 0, record_rows=rows)
         self.primary = kind
         self.seqs: dict[int, SequenceDescriptor] = {}
         self._free_slots = list(range(max_seqs))
@@ -266,11 +277,22 @@ class StateManager:
         return self.kinds[self.primary].max_blocks_per_seq
 
     @property
-    def has_ring(self) -> bool:
-        """Some kind reuses page slots in place: what a ring cannot do
-        (share pages through the prefix trie, speculate past its tail,
-        export a linear page chain) is refused for the whole sequence."""
-        return any(k.ring for k in self.kinds.values())
+    def not_a_page_chain(self) -> str:
+        """Why a sequence's state here is NOT a linear chain of pages
+        ("" where it is): some kind reuses page slots in place (a ring) or
+        keeps a record that is no page at all. What only a page chain can
+        do — grow a chunk past the ring, share pages through the prefix
+        trie, speculate past the tail, export, import or rewind pages — is
+        refused for the whole sequence, with this reason."""
+        for k in self.kinds.values():
+            if k.ring:
+                return (f"kind {k.name!r} keeps a rolling ring: page slots "
+                        f"are reused in place")
+            if k.record_rows:
+                return (f"kind {k.name!r} keeps a record a slot "
+                        f"({k.record_rows} rows), not pages: it cannot be "
+                        f"shared, exported or rolled back")
+        return ""
 
     def blocks_of(self, seq: SequenceDescriptor, kind: str) -> list[int]:
         return seq.blocks if kind == self.primary else seq.kind_blocks[kind]
@@ -279,7 +301,8 @@ class StateManager:
         """Blocks ``n_tokens`` of context need in each kind but the
         primary."""
         return {n: min(-(-n_tokens // self.block_size), k.max_blocks_per_seq)
-                for n, k in self.kinds.items() if n != self.primary}
+                for n, k in self.kinds.items()
+                if n != self.primary and not k.record_rows}
 
     def _reserve_more(self, seq: SequenceDescriptor, n_tokens: int,
                       fresh: list[int]) -> None:
@@ -307,13 +330,17 @@ class StateManager:
         seq.kind_blocks = {}
 
     def sample(self) -> dict[str, int]:
-        """Blocks live sequences hold, by kind, and each kind's running
-        peak (the engine samples after every dispatch)."""
+        """Blocks live sequences hold, by kind (a record kind: records
+        live, one a sequence in a slot), and each kind's running peak (the
+        engine samples after every dispatch)."""
         live = {n: 0 for n in self.kinds}
         for seq in self.seqs.values():
             live[self.primary] += len(seq.blocks)
             for n, blocks in seq.kind_blocks.items():
                 live[n] += len(blocks)
+            for n, k in self.kinds.items():
+                if k.record_rows and seq.slot >= 0:
+                    live[n] += 1
         for n, k in self.kinds.items():
             k.blocks_peak = max(k.blocks_peak, live[n])
         return live
@@ -611,6 +638,10 @@ class StateManager:
         seq = self.seqs[uid]
         if not tokens:
             raise ValueError("cannot rewind to an empty history")
+        if self.not_a_page_chain:
+            raise RuntimeError(
+                f"uid {uid}: rewind needs page chains: "
+                f"{self.not_a_page_chain}")
         if seq.n_shared_blocks:
             shared = seq.n_shared_blocks * self.block_size
             if (len(tokens) <= shared
@@ -1016,6 +1047,22 @@ class StateManager:
         # the further kinds share nothing: free list + owned tables
         for name, k in self.kinds.items():
             if name == self.primary:
+                continue
+            if k.record_rows:
+                # a record is addressed by the slot: every live sequence
+                # in a slot of its own, the rest free, none out of range
+                slots = [s.slot for s in self.seqs.values() if s.slot >= 0]
+                if sorted(slots + self._free_slots) \
+                        != list(range(self.max_seqs)):
+                    raise AssertionError(
+                        f"{name} records: slots held twice, leaked or out "
+                        f"of range (live {sorted(slots)}, free "
+                        f"{sorted(self._free_slots)})")
+                if seq_blocks := [u for u, s in self.seqs.items()
+                                  if name in s.kind_blocks]:
+                    raise AssertionError(
+                        f"{name} is a record kind but uid(s) {seq_blocks} "
+                        f"hold blocks of it")
                 continue
             held = list(k.allocator._free)
             for uid, seq in self.seqs.items():

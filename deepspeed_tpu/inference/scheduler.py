@@ -24,9 +24,12 @@ from .ragged import SequenceDescriptor, StateManager, StepPlan
 
 class SplitFuseScheduler:
     def __init__(self, state: StateManager, chunk: int, pack: bool = False,
-                 grow_chunk: bool = True):
+                 grow_chunk: bool = True, max_rows: int = 0):
         self.state = state
         self.chunk = chunk
+        #: the most sequences one packed prefill plan carries (0: all that
+        #: have work); the rest keep their place for the next plan
+        self.max_rows = max_rows if 0 < max_rows < state.max_seqs else 0
         #: packed plans may carry a chunk LONGER than ``chunk`` (see
         #: ``pack``). False where a kind of layer keeps a ring: the ring is
         #: sized for chunk-at-most steps and a grown chunk would overrun
@@ -106,7 +109,7 @@ class SplitFuseScheduler:
         # each kind's own table (a window kind's ring: slot (pos // bs) %
         # width of ITS width)
         for name, k in self.state.kinds.items():
-            if name == self.state.primary:
+            if name == self.state.primary or k.record_rows:
                 continue
             w = k.max_blocks_per_seq
             slots = np.zeros((S, T), np.int32)
@@ -132,6 +135,16 @@ class SplitFuseScheduler:
             for r in range(S):
                 if plan.uids[r] < 0:
                     plan.row_slots[r] = next(free)
+        # a record kind is addressed by the row's SLOT: read there; written
+        # there for the rows live in this step, at the trash record (the
+        # last) for the rest (in ``slot_map``'s and ``block_tables``' place)
+        for name, k in self.state.kinds.items():
+            if k.record_rows:
+                live = np.asarray(plan.uids) >= 0
+                plan.more[name] = (
+                    np.where(live, plan.row_slots,
+                             self.state.max_seqs).astype(np.int32),
+                    plan.row_slots.copy())
         return plan
 
     def _native_build(self, plan: StepPlan, T: int, entries,
@@ -198,7 +211,10 @@ class SplitFuseScheduler:
         shapes = {(self.chunk, S_max)}
         if not self.pack:
             return sorted(shapes)
-        for k in range(1, S_max):
+        if self.max_rows:
+            # a capped plan is never the full-width one
+            shapes.clear()
+        for k in range(1, (self.max_rows or S_max - 1) + 1):
             n_rows = self._pad_rows(k)
             for T in self._chunk_chain(n_rows):
                 shapes.add((T, n_rows))
@@ -322,6 +338,8 @@ class SplitFuseScheduler:
             # the pow2 budget multiplier. One compiled program per
             # (rows, chunk) pair, ~4s each: warm ``program_shape_menu()``.
             k = min(len(prefill), st.max_seqs)
+            if self.pack and self.max_rows:
+                k = min(k, self.max_rows)
             n_rows = st.max_seqs
             T = self.chunk
             if self.pack and k < st.max_seqs:

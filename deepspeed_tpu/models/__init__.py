@@ -271,15 +271,62 @@ PRESETS: dict[str, ModelConfig] = {
         tie_embeddings=False,
         moe=MoEConfig(num_experts=8, top_k=2, min_capacity=4,
                       normalize_gates=True, router_input="attn")),
+    # LFM2-24B-A2B (LiquidAI/LFM2-24B-A2B config.json, ``lfm2_moe``): 30 of
+    # 40 layers mix the sequence by a gated short convolution (3 taps a
+    # channel, no keys and values), every fourth (i % 4 == 2) by attention
+    # over 8 KV heads of 64 with q and k RMS-normalised per head; the first
+    # two layers carry a dense SwiGLU of 11776, the rest 64 routed SwiGLU
+    # experts of 1536, 4 a token, by sigmoid scores with a selection bias;
+    # tied head (assumed: the family ties it)
+    "lfm2-24b-a2b": ModelConfig(
+        vocab_size=65536, hidden_size=2048, num_layers=40, num_heads=32,
+        num_kv_heads=8, intermediate_size=1536, max_seq_len=128000,
+        position_embedding="rope", rope_theta=1e6, norm="rmsnorm",
+        norm_eps=1e-5, activation="silu_glu", qk_norm="head",
+        layer_kinds=("conv", "conv", "full", "conv"), conv_taps=3,
+        tie_embeddings=True,
+        moe=MoEConfig(num_experts=64, top_k=4, normalize_gates=True,
+                      router_score="sigmoid_bias", dropless=True,
+                      moe_layer_pattern=(False,) * 2 + (True,) * 38,
+                      dense_ffn_intermediate=11776)),
+    # one leading conv layer with the dense feed-forward, then one whole
+    # period (attention, conv, conv, conv) of expert layers: published
+    # layers 1-5 in small
+    "tiny-lfm2-moe": ModelConfig(
+        vocab_size=256, hidden_size=64, num_layers=5, num_heads=4,
+        num_kv_heads=2, intermediate_size=32, max_seq_len=256,
+        position_embedding="rope", rope_theta=1e4, norm="rmsnorm",
+        norm_eps=1e-5, activation="silu_glu", qk_norm="head",
+        leading_kinds=("conv",),
+        layer_kinds=("full", "conv", "conv", "conv"), conv_taps=3,
+        tie_embeddings=True,
+        moe=MoEConfig(num_experts=8, top_k=2, min_capacity=4,
+                      normalize_gates=True, router_score="sigmoid_bias",
+                      dropless=True, dropless_block_m=16,
+                      moe_layer_pattern=(False, True, True, True, True),
+                      dense_ffn_intermediate=96)),
 }
 
 
+def _frozen(v):
+    """A JSON value as a dataclass field holds it: lists become tuples."""
+    return tuple(_frozen(x) for x in v) if isinstance(v, list) else v
+
+
 def get_model_config(name: str, **overrides) -> ModelConfig:
+    """The preset ``name`` with ``overrides`` laid over it. Overrides may
+    come from a JSON file (``benchmark/configs/*.json``): a list becomes a
+    tuple, and a dict under ``moe`` is laid over the preset's
+    ``MoEConfig``."""
     import dataclasses
 
     if name not in PRESETS:
         raise ValueError(f"unknown model preset '{name}'; known: {sorted(PRESETS)}")
     cfg = PRESETS[name]
+    overrides = {k: _frozen(v) for k, v in overrides.items()}
+    if isinstance(overrides.get("moe"), dict):
+        overrides["moe"] = dataclasses.replace(
+            cfg.moe, **{k: _frozen(v) for k, v in overrides["moe"].items()})
     return dataclasses.replace(cfg, **overrides) if overrides else cfg
 
 

@@ -429,7 +429,63 @@ def _qwen2_moe_tree(sd: dict, cfg: ModelConfig) -> dict:
     return t
 
 
-_CONVERTERS = {"gpt2": _gpt2_tree, "llama": _llama_tree,
+def _lfm2_moe_tree(sd: dict, cfg: ModelConfig) -> dict:
+    """lfm2_moe layout (HF ``Lfm2Moe*``): ``operator_norm`` / ``ffn_norm``
+    a layer and ``embedding_norm`` at the end; a conv layer's
+    ``conv.in_proj`` [3E, E] (rows B, C, x), ``conv.conv`` [E, 1, L]
+    (depthwise taps) and ``conv.out_proj``; an attention layer's
+    ``self_attn.{q,k,v,out}_proj`` with per-head ``q_layernorm`` /
+    ``k_layernorm`` [D] (elementwise scales: they follow the same
+    half→interleaved permutation as ``wq``/``wk``'s head lanes); a dense
+    layer's ``feed_forward.{w1,w3,w2}`` (gate, up, down); an expert layer's
+    ``feed_forward.gate`` [n, E], ``feed_forward.expert_bias`` [n] and
+    ``feed_forward.experts.K.{w1,w3,w2}``. The head is tied."""
+    from .transformer import CONV, is_moe_layer
+
+    E, H, KV, D = (cfg.hidden_size, cfg.num_heads, cfg.kv_heads,
+                   cfg.head_dim)
+    perm = _interleave_perm(D)
+    t = {"embed": sd["model.embed_tokens.weight"],
+         "ln_final": {"scale": sd["model.embedding_norm.weight"]}}
+    if not cfg.tie_embeddings:
+        t["unembed"] = sd["lm_head.weight"].T
+    for i in range(cfg.num_layers):
+        p = f"model.layers.{i}."
+        layer = {"ln_attn": {"scale": sd[p + "operator_norm.weight"]},
+                 "ln_ffn": {"scale": sd[p + "ffn_norm.weight"]}}
+        if cfg.layer_kind(i) == CONV:
+            layer["conv"] = {
+                "w_in": sd[p + "conv.in_proj.weight"].T.reshape(E, 3, E),
+                "w_conv": sd[p + "conv.conv.weight"][:, 0, :].T,   # [L, E]
+                "w_out": sd[p + "conv.out_proj.weight"].T}
+        else:
+            a = p + "self_attn."
+            layer["attn"] = {
+                "wq": sd[a + "q_proj.weight"].T.reshape(E, H, D)[:, :, perm],
+                "wk": sd[a + "k_proj.weight"].T.reshape(E, KV, D)[:, :, perm],
+                "wv": sd[a + "v_proj.weight"].T.reshape(E, KV, D),
+                "wo": sd[a + "out_proj.weight"].T.reshape(H, D, E),
+                "q_norm": sd[a + "q_layernorm.weight"][perm],
+                "k_norm": sd[a + "k_layernorm.weight"][perm]}
+        f = p + "feed_forward."
+        if is_moe_layer(cfg, i):
+            stack = lambda name: np.stack(
+                [sd[f + f"experts.{k}.{name}.weight"].T
+                 for k in range(cfg.moe.num_experts)])
+            layer["moe"] = {"moe_layer": {
+                "gate": {"wg": sd[f + "gate.weight"].T,          # [E, n]
+                         "bias": sd[f + "expert_bias"]},
+                "experts": {"w_gate": stack("w1"), "w_up": stack("w3"),
+                            "w_down": stack("w2")}}}
+        else:
+            layer["ffn"] = {"w_gate": sd[f + "w1.weight"].T,
+                            "w_up": sd[f + "w3.weight"].T,
+                            "w_down": sd[f + "w2.weight"].T}
+        t[f"layer_{i}"] = layer
+    return t
+
+
+_CONVERTERS = {"lfm2_moe": _lfm2_moe_tree, "gpt2": _gpt2_tree, "llama": _llama_tree,
                "mistral": _llama_tree, "qwen2": _qwen2_tree,
                "mixtral": _mixtral_tree, "falcon": _falcon_tree,
                "bloom": _bloom_tree, "opt": _opt_tree, "phi": _phi_tree,
@@ -723,6 +779,48 @@ def config_from_hf(hf_config) -> ModelConfig:
                                              False)),
                 aux_loss_weight=float(getattr(
                     hf_config, "router_aux_loss_coef", 0.01))))
+    if mt == "lfm2_moe":
+        from .transformer import MoEConfig
+
+        _reject_rope_scaling(hf_config)
+        if getattr(hf_config, "conv_bias", False):
+            raise NotImplementedError("lfm2_moe conv_bias=True is not "
+                                      "converted")
+        if not getattr(hf_config, "use_expert_bias", True) \
+                or float(getattr(hf_config, "routed_scaling_factor", 1)) != 1:
+            raise NotImplementedError(
+                "lfm2_moe without an expert bias, or with a "
+                "routed_scaling_factor other than 1, is not converted")
+        # every layer's kind, as ONE period (its length divides the depth
+        # trivially): the published pattern need not repeat evenly
+        names = {"conv": "conv", "full_attention": "full"}
+        kinds = tuple(names[t] for t in hf_config.layer_types)
+        rope = getattr(hf_config, "rope_parameters", None) or {}
+        return dataclasses.replace(
+            PRESETS["lfm2-24b-a2b"],
+            vocab_size=hf_config.vocab_size,
+            hidden_size=hf_config.hidden_size,
+            num_layers=hf_config.num_hidden_layers,
+            num_heads=hf_config.num_attention_heads,
+            num_kv_heads=hf_config.num_key_value_heads,
+            intermediate_size=hf_config.moe_intermediate_size,
+            max_seq_len=hf_config.max_position_embeddings,
+            rope_theta=float(rope.get("rope_theta") or getattr(
+                hf_config, "rope_theta", 1e6)),
+            norm_eps=hf_config.norm_eps, layer_kinds=kinds,
+            leading_kinds=(), conv_taps=hf_config.conv_L_cache,
+            tie_embeddings=bool(getattr(hf_config, "tie_word_embeddings",
+                                        True)),
+            moe=MoEConfig(
+                num_experts=hf_config.num_experts,
+                top_k=hf_config.num_experts_per_tok,
+                normalize_gates=bool(getattr(hf_config, "norm_topk_prob",
+                                             True)),
+                router_score="sigmoid_bias", dropless=True,
+                moe_layer_pattern=tuple(
+                    i >= hf_config.num_dense_layers
+                    for i in range(hf_config.num_hidden_layers)),
+                dense_ffn_intermediate=hf_config.intermediate_size))
     raise NotImplementedError(
         f"no converter for HF model_type '{mt}' (have: "
         f"{sorted(_CONVERTERS)})")

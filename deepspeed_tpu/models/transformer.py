@@ -110,15 +110,28 @@ class MoEConfig:
     # "attn": the layer's INPUT norm output, before attention
     # (SmallThinker: "router placed before attention")
     router_input: str = "ffn"
+    # how the router scores experts (read in ONE place,
+    # ``moe/sharded_moe.py:topk_dropless_gating``): "softmax" — top k of
+    # the softmax over all experts, every preset before LFM2 — or
+    # "sigmoid_bias": ``s = sigmoid(logits)``, the k experts are the top k
+    # of ``s + b`` (``gate/bias``, a learned vector that moves the
+    # SELECTION only) and the weights are ``s`` at the chosen k, divided
+    # by their sum + 1e-6 where ``normalize_gates`` (LFM2's
+    # ``use_expert_bias`` / ``norm_topk_prob``). Dropless routing only.
+    router_score: str = "softmax"
 
 
 #: what a kind of layer fixes: (sliding-window mask, position embedding).
 #: "full" and "window" carry the model's ``position_embedding``;
 #: "full_nope" is full causal attention with NO position embedding
-#: (SmallThinker's global layers). The serving engine keeps one KV
-#: allocator a MASK ("full" / "window"): see ``cache_kind``.
+#: (SmallThinker's global layers); "conv" is no attention at all — a gated
+#: short convolution mixes the sequence (LFM2: no mask, no position
+#: embedding, no keys and values). The serving engine keeps one cache a
+#: kind of STATE ("full" / "window" pages, "conv" records): ``cache_kind``.
 LAYER_KINDS = {"full": (False, True), "window": (True, True),
-               "full_nope": (False, False)}
+               "full_nope": (False, False), "conv": (False, False)}
+#: the operator that is not attention
+CONV = "conv"
 
 
 @dataclass(frozen=True)
@@ -142,9 +155,12 @@ class ModelConfig:
     qkv_bias: bool = False                   # qwen-style projection biases
     qk_norm: str | None = None               # None | "full": RMSNorm of the
                                              # WHOLE projected q and k (all
-                                             # heads as one vector, OLMoE),
-                                             # before rope. (A per-head form
-                                             # would be another value here.)
+                                             # heads as one vector, OLMoE) |
+                                             # "head": RMSNorm of each head
+                                             # over its own width, one
+                                             # learned scale of head_dim
+                                             # shared by the heads (LFM2,
+                                             # qwen3); both before rope
     attn_out_bias: bool = False              # gpt2/bert-style out-proj bias
     parallel_block: bool = False             # falcon/gpt-j/phi: attn ∥ ffn
     parallel_block_norms: int = 1            # 2 = separate ln for ffn branch
@@ -160,6 +176,14 @@ class ModelConfig:
                                              # "window" where
                                              # sliding_window is set, else
                                              # "full"
+    leading_kinds: tuple[str, ...] = ()      # kinds of the layers BEFORE the
+                                             # periods: a stack is these
+                                             # leading layers, then whole
+                                             # periods of ``layer_kinds``
+    conv_taps: int = 3                       # taps a channel of a "conv"
+                                             # layer's depthwise causal
+                                             # convolution (LFM2
+                                             # ``conv_L_cache``)
     pre_norm: bool = True                    # False → post-norm residuals
                                              # (original BERT layout)
     embed_norm: bool = False                 # bloom: LayerNorm right after
@@ -193,21 +217,30 @@ class ModelConfig:
     def kinds_period(self) -> tuple[str, ...]:
         """One period of layer kinds (``layer_kinds``, or the one kind
         every layer has)."""
-        period = self.layer_kinds or (
-            ("window",) if self.sliding_window else ("full",))
-        bad = [k for k in period if k not in LAYER_KINDS]
-        if bad or self.num_layers % len(period):
+        period = tuple(self.layer_kinds or (
+            ("window",) if self.sliding_window else ("full",)))
+        lead = tuple(self.leading_kinds)
+        bad = [k for k in lead + period if k not in LAYER_KINDS]
+        if bad or (self.num_layers - len(lead)) % len(period) \
+                or len(lead) > self.num_layers:
             raise ValueError(
-                f"layer_kinds {period!r}: names must be of "
-                f"{sorted(LAYER_KINDS)} and the period must divide "
-                f"num_layers {self.num_layers}")
-        if "window" in period and not self.sliding_window:
+                f"layer_kinds {period!r} after {len(lead)} leading "
+                f"layer(s): names must be of {sorted(LAYER_KINDS)} and the "
+                f"period must divide the layers after the leading ones "
+                f"(num_layers {self.num_layers})")
+        if "window" in lead + period and not self.sliding_window:
             raise ValueError("a 'window' layer kind needs sliding_window")
         return period
 
     def layer_kind(self, i: int) -> str:
-        period = self.kinds_period
-        return period[i % len(period)]
+        period, lead = self.kinds_period, self.leading_kinds
+        return lead[i] if i < len(lead) \
+            else period[(i - len(lead)) % len(period)]
+
+    @property
+    def kinds(self) -> tuple[str, ...]:
+        """Every layer's kind, in order."""
+        return tuple(self.layer_kind(i) for i in range(self.num_layers))
 
     @property
     def ffn_size(self) -> int:
@@ -218,28 +251,44 @@ class ModelConfig:
         return 4 * self.hidden_size
 
     def num_params(self) -> int:
-        """Analytic parameter count (used by the flops profiler and bench)."""
+        """Analytic parameter count (used by the flops profiler and bench):
+        each layer with the operator (attention or a short convolution) and
+        the feed-forward (dense, of its own width in a mixed stack, or
+        routed experts) it has."""
         h, v, L = self.hidden_size, self.vocab_size, self.num_layers
         f = self.ffn_size
         attn = h * self.num_heads * self.head_dim + 2 * h * self.kv_heads * self.head_dim \
             + self.num_heads * self.head_dim * h
-        if self.activation in GLU_ACTS:
-            ffn_dense = 3 * h * f
-        else:
-            ffn_dense = 2 * h * f + f + h  # + biases
+        glu = self.activation in GLU_ACTS
+
+        def dense(width):
+            return 3 * h * width if glu else 2 * h * width + width + h
+
+        ffn_moe = 0
         if self.moe:
-            ffn = self.moe.num_experts * 3 * h * f + h * self.moe.num_experts
+            ffn_moe = self.moe.num_experts * (3 if glu else 2) * h * f \
+                + h * self.moe.num_experts
+            if self.moe.router_score == "sigmoid_bias":
+                ffn_moe += self.moe.num_experts
             if self.moe.shared_expert_intermediate:
-                ffn += 3 * h * self.moe.shared_expert_intermediate + h
-        else:
-            ffn = ffn_dense
+                ffn_moe += 3 * h * self.moe.shared_expert_intermediate + h
         if self.qkv_bias:
             attn += self.num_heads * self.head_dim \
                 + 2 * self.kv_heads * self.head_dim
         if self.attn_out_bias:
             attn += h
-        if self.qk_norm:
+        if self.qk_norm == "head":
+            attn += 2 * self.head_dim
+        elif self.qk_norm:
             attn += (self.num_heads + self.kv_heads) * self.head_dim
+        # a conv layer: in-projection to (B, C, u), the taps, out-projection
+        conv = 3 * h * h + self.conv_taps * h + h * h
+        n_conv = sum(k == CONV for k in self.kinds)
+        n_moe = sum(is_moe_layer(self, i) for i in range(L))
+        dense_width = (self.moe.dense_ffn_intermediate or f) if self.moe \
+            else f
+        layers = n_conv * conv + (L - n_conv) * attn \
+            + n_moe * ffn_moe + (L - n_moe) * dense(dense_width)
         per_norm = h if self.norm == "rmsnorm" else 2 * h
         # pre-norm: 2 per layer + ln_final; post-norm: 2 per layer + ln_embed
         norms = (2 * L + 1) * per_norm
@@ -252,7 +301,7 @@ class ModelConfig:
         if self.unembed_bias:
             emb += v
         pos = self.max_seq_len * h if self.position_embedding == "learned" else 0
-        return emb + pos + L * (attn + ffn) + norms
+        return emb + pos + layers + norms
 
 
 def _dense_init(scale: float = 1.0):
@@ -339,19 +388,26 @@ def apply_rope(q: jax.Array, k: jax.Array, positions: jax.Array,
             jnp.concatenate([kr, k[..., d_rot:]], axis=-1))
 
 
-QK_NORMS = ("full",)
+QK_NORMS = ("full", "head")
+
+
+def qk_norm_shape(cfg: "ModelConfig", heads: int) -> tuple[int, ...]:
+    """Shape of a ``qk_norm`` scale: one a head and lane ("full"), or one
+    vector of ``head_dim`` shared by the heads ("head")."""
+    return (cfg.head_dim,) if cfg.qk_norm == "head" else (heads, cfg.head_dim)
 
 
 def qk_norm(cfg: "ModelConfig", x: jax.Array, scale: jax.Array) -> jax.Array:
     """``cfg.qk_norm`` on a projected q or k ``[..., heads, head_dim]`` with
-    its scale ``[heads, head_dim]`` — the one implementation shared by
+    its scale (:func:`qk_norm_shape`) — the one implementation shared by
     training attention and the ragged inference forward. "full": RMS over
     heads AND head_dim together (OLMoE normalises the projection before it
-    is split into heads). Statistics in float32, affine in the input dtype,
-    as :class:`Norm`."""
+    is split into heads); "head": RMS over each head's own width (LFM2).
+    Statistics in float32, affine in the input dtype, as :class:`Norm`."""
     if cfg.qk_norm not in QK_NORMS:
         raise ValueError(f"qk_norm {cfg.qk_norm!r} is not one of {QK_NORMS}")
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=(-2, -1),
+    var = jnp.mean(jnp.square(x.astype(jnp.float32)),
+                   axis=-1 if cfg.qk_norm == "head" else (-2, -1),
                    keepdims=True)
     inv = jax.lax.rsqrt(var + cfg.norm_eps)
     return x * inv.astype(x.dtype) * scale.astype(x.dtype)
@@ -368,9 +424,41 @@ def kind_ropes(cfg: "ModelConfig", kind: str) -> bool:
 
 
 def cache_kind(kind: str) -> str:
-    """The KV cache a layer of ``kind`` keeps: "window" (a bounded ring)
-    or "full" (a table that grows with the context)."""
+    """The cache a layer of ``kind`` keeps: "window" (a bounded ring of
+    pages), "full" (a table of pages that grows with the context) or
+    "conv" (no pages: one record of the last ``conv_taps - 1`` inputs of
+    the convolution a sequence)."""
+    if kind == CONV:
+        return CONV
     return "window" if LAYER_KINDS[kind][0] else "full"
+
+
+def conv_mix(cfg: "ModelConfig", p, h: jax.Array, prev: jax.Array,
+             n_valid: jax.Array | None = None
+             ) -> tuple[jax.Array, jax.Array]:
+    """THE gated short convolution (LFM2's operator), shared by the flax
+    block and the ragged serving forward: ``[B, C, u] = split3(h W_in)``,
+    ``z = B * u``, ``c_t = sum_j w[j] * z_{t-(taps-1)+j}`` (depthwise,
+    causal), ``out = (C * c) W_out``. ``h`` ``[S, T, E]``; ``prev``
+    ``[S, taps-1, E]``: the ``z`` of the ``taps - 1`` positions before this
+    call's first (zeros at a sequence's start). Returns ``(out, new)``
+    with ``new`` the ``z`` of the last ``taps - 1`` of each row's
+    ``n_valid`` tokens (None: all ``T``), older ones taken from ``prev``
+    where the row has fewer: what the next call needs as its ``prev``."""
+    S, T, E = h.shape
+    R = cfg.conv_taps - 1
+    dt = h.dtype
+    bcu = jnp.einsum("ste,ekf->stkf", h, p["w_in"].astype(dt))
+    z = bcu[:, :, 0] * bcu[:, :, 2]                        # B * u  [S,T,E]
+    zfull = jnp.concatenate([prev.astype(dt), z], axis=1)  # [S, R+T, E]
+    w = p["w_conv"].astype(dt)                             # [taps, E]
+    c = sum(w[j] * zfull[:, j:j + T] for j in range(cfg.conv_taps))
+    out = jnp.einsum("ste,ef->stf", bcu[:, :, 1] * c, p["w_out"].astype(dt))
+    if n_valid is None:
+        return out, zfull[:, T:]
+    # row s keeps zfull[s, n : n + R]: its last R valid inputs
+    idx = n_valid[:, None].astype(jnp.int32) + jnp.arange(R)[None, :]
+    return out, jnp.take_along_axis(zfull, idx[:, :, None], axis=1)
 
 
 def _attn_impl(cfg: "ModelConfig", kind: str) -> str:
@@ -480,12 +568,15 @@ class Attention(nn.Module):
             k = k + bk.astype(cfg.dtype)
             v = v + bv.astype(cfg.dtype)
         if cfg.qk_norm:
+            per_head = cfg.qk_norm == "head"
             q = qk_norm(cfg, q, self.param("q_norm", nn.with_partitioning(
-                nn.initializers.ones, ("heads", "head_dim")), (H, D),
-                jnp.float32))
+                nn.initializers.ones,
+                ("head_dim",) if per_head else ("heads", "head_dim")),
+                qk_norm_shape(cfg, H), jnp.float32))
             k = qk_norm(cfg, k, self.param("k_norm", nn.with_partitioning(
-                nn.initializers.ones, ("kv_heads", "head_dim")), (KV, D),
-                jnp.float32))
+                nn.initializers.ones,
+                ("head_dim",) if per_head else ("kv_heads", "head_dim")),
+                qk_norm_shape(cfg, KV), jnp.float32))
 
         if kind_ropes(cfg, kind):
             q, k = apply_rope(q, k, positions, cfg.rope_theta, cfg.rotary_pct)
@@ -552,6 +643,33 @@ class Attention(nn.Module):
         if new_cache is not None:
             return out, new_cache
         return out
+
+
+class ShortConv(nn.Module):
+    """A "conv" layer's operator over a whole sequence (training, v1
+    prefill): :func:`conv_mix` from zeros. No bias anywhere (LFM2
+    ``conv_bias`` false)."""
+    config: ModelConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        E = cfg.hidden_size
+        p = {
+            "w_in": self.param("w_in", nn.with_partitioning(
+                _dense_init(), ("embed", None, "mlp")), (E, 3, E),
+                jnp.float32),
+            "w_conv": self.param("w_conv", nn.with_partitioning(
+                nn.initializers.normal(cfg.conv_taps ** -0.5),
+                (None, "mlp")), (cfg.conv_taps, E), jnp.float32),
+            "w_out": self.param("w_out", nn.with_partitioning(
+                _dense_init(), ("mlp", "embed")), (E, E), jnp.float32),
+        }
+        with device_scope("conv_mix"):
+            out, _ = conv_mix(
+                cfg, p, x,
+                jnp.zeros((x.shape[0], cfg.conv_taps - 1, E), x.dtype))
+        return constrain(out, BATCH, SEQ, EMBED)
 
 
 #: two-matrix FFN activations; torch's nn.GELU() is the erf form while
@@ -626,8 +744,8 @@ def dense_ffn_config(cfg: ModelConfig) -> ModelConfig:
 
 def is_moe_layer(cfg: ModelConfig, i: int) -> bool:
     """Whether layer ``i`` carries the MoE FFN: the explicit per-layer
-    pattern when set (qwen2-moe sparse-step phase / mlp_only_layers),
-    else the every-Nth ``moe_layer_freq`` rule."""
+    pattern when set (qwen2-moe sparse-step phase / mlp_only_layers, LFM2's
+    leading dense layers), else the every-Nth ``moe_layer_freq`` rule."""
     if cfg.moe is None:
         return False
     pat = cfg.moe.moe_layer_pattern
@@ -659,6 +777,7 @@ def moe_layer_kwargs(cfg: ModelConfig, **overrides) -> dict:
         dropless=moe.dropless,
         dropless_block_m=moe.dropless_block_m,
         normalize_gates=moe.normalize_gates,
+        router_score=moe.router_score,
     )
     kw.update(overrides)
     return kw
@@ -698,6 +817,21 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, positions, kv_cache=None, attn_mask=None, deterministic=True):
         cfg = self.config
+        if self.kind == CONV:
+            # x = x + conv(norm(x)); x = x + ff(norm(x)): LFM2's block.
+            # No v1 decode cache: a conv layer's state is served by
+            # InferenceEngineV2 (a record a slot), not by kv_caches
+            if kv_cache is not None or cfg.parallel_block \
+                    or not cfg.pre_norm:
+                raise ValueError(
+                    "a 'conv' layer runs pre-norm, sequential, and without "
+                    "a v1 kv_cache (serve it through InferenceEngineV2)")
+            x = x + ShortConv(cfg, name="conv")(Norm(cfg, name="ln_attn")(x))
+            h = Norm(cfg, name="ln_ffn")(x)
+            if self.use_moe:
+                return x + MoEFFN(cfg, name="moe")(
+                    h, deterministic=deterministic)
+            return x + DenseFFN(dense_ffn_config(cfg), name="ffn")(h)
         if cfg.parallel_block:
             # falcon-7b/gpt-j/phi: ONE pre-norm feeds attention and ffn;
             # gpt-neox/falcon-40b keep separate norms per branch

@@ -41,6 +41,10 @@ class TopKGate(nn.Module):
     #: renormalize top-k gates to sum to 1 (False = raw softmax probs,
     #: qwen2-moe norm_topk_prob=False semantics)
     normalize_gates: bool = True
+    #: ``MoEConfig.router_score``; "sigmoid_bias" adds the parameter
+    #: ``bias`` [n] (seeded small and NON-zero, so that selection by
+    #: ``s + b`` and weights from ``s`` differ under seeded weights)
+    router_score: str = "softmax"
 
     @nn.compact
     def __call__(self, x: jax.Array, deterministic: bool = True):
@@ -53,9 +57,20 @@ class TopKGate(nn.Module):
         rng = None
         if self.noisy_gate_policy == "RSample" and not deterministic:
             rng = self.make_rng("gating")
+        bias = None
+        if self.router_score != "softmax":
+            if not self.dropless:
+                raise ValueError(
+                    f"router_score {self.router_score!r} routes dropless "
+                    f"only (MoEConfig.dropless=True): capacity gating is "
+                    f"softmax's")
+            bias = self.param("bias", nn.with_partitioning(
+                nn.initializers.normal(0.02), ("expert",)),
+                (self.num_experts,), jnp.float32)
         if self.dropless:
             return topk_dropless_gating(logits, self.k, noise_rng=rng,
-                                        normalize_gates=self.normalize_gates)
+                                        normalize_gates=self.normalize_gates,
+                                        score=self.router_score, bias=bias)
         return topkgating(
             logits, self.k,
             self.eval_capacity_factor if deterministic else self.capacity_factor,
@@ -175,6 +190,7 @@ class MoE(nn.Module):
     dropless: bool = False
     dropless_block_m: int = 128
     normalize_gates: bool = True
+    router_score: str = "softmax"
 
     @nn.compact
     def __call__(self, x: jax.Array, deterministic: bool = True,
@@ -191,6 +207,7 @@ class MoE(nn.Module):
             noisy_gate_policy=self.noisy_gate_policy,
             drop_tokens=self.drop_tokens, dropless=self.dropless,
             normalize_gates=self.normalize_gates,
+            router_score=self.router_score,
             name="gate")(x if router_x is None else router_x, deterministic)
 
         self.sow("losses", "moe_aux_loss",

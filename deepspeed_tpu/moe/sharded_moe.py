@@ -112,22 +112,47 @@ class DroplessGateOutput(NamedTuple):
     exp_counts: jax.Array      # [n]
 
 
+ROUTER_SCORES = ("softmax", "sigmoid_bias")
+
+
 def topk_dropless_gating(logits: jax.Array, k: int, *,
                          noise_rng: jax.Array | None = None,
                          noise_eps: float = 1e-2,
-                         normalize_gates: bool = True) -> DroplessGateOutput:
+                         normalize_gates: bool = True,
+                         score: str = "softmax",
+                         bias: jax.Array | None = None
+                         ) -> DroplessGateOutput:
     """Top-k routing with NO capacity and NO drops — every token reaches
     all k chosen experts (the megablocks contract; tokens are instead
-    block-aligned per expert by ``sort_tokens_by_expert``)."""
+    block-aligned per expert by ``sort_tokens_by_expert``).
+
+    ``score`` (``MoEConfig.router_score``, read HERE and nowhere else, at
+    trace time): "softmax" — the k largest of the softmax over all experts,
+    renormalised where ``normalize_gates``; "sigmoid_bias" — ``s =
+    sigmoid(logits)``, the experts are the k largest of ``s + bias`` (the
+    bias ``[n]`` moves the SELECTION only), the weights are ``s`` at the
+    chosen k, divided by their sum + 1e-6 where ``normalize_gates``."""
     G, S, n = logits.shape
     logits = logits.astype(jnp.float32)
     if noise_rng is not None:
         logits = logits + jax.random.normal(noise_rng, logits.shape) * noise_eps
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_idx = jax.lax.top_k(probs, k)                # [G,S,k]
-    if normalize_gates:
-        gate_vals = gate_vals / jnp.maximum(
-            jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+    if score == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, expert_idx = jax.lax.top_k(probs, k)            # [G,S,k]
+        if normalize_gates:
+            gate_vals = gate_vals / jnp.maximum(
+                jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+    elif score == "sigmoid_bias":
+        probs = jax.nn.sigmoid(logits)
+        _, expert_idx = jax.lax.top_k(
+            probs + bias.astype(jnp.float32), k)
+        gate_vals = jnp.take_along_axis(probs, expert_idx, axis=-1)
+        if normalize_gates:
+            gate_vals = gate_vals / (
+                jnp.sum(gate_vals, axis=-1, keepdims=True) + 1e-6)
+    else:
+        raise ValueError(f"router score {score!r} is not one of "
+                         f"{ROUTER_SCORES}")
 
     onehot = jax.nn.one_hot(expert_idx, n, dtype=jnp.float32)      # [G,S,k,n]
     me = jnp.mean(probs, axis=(0, 1))

@@ -341,13 +341,27 @@ class LineChannel:
     def send(self, msg: dict, timeout: float) -> None:
         """Write one message, waiting up to ``timeout`` for pipe space.
         Raises :class:`ChannelTimeout` when the peer stops reading and
-        :class:`ChannelClosed` on EPIPE."""
+        :class:`ChannelClosed` on EPIPE.
+
+        While it waits for space it keeps READING: what the peer has sent
+        is drained into the message buffer (``recv`` returns it later, in
+        order). Two peers that each write more than a pipe holds, each
+        from the one thread that also reads, would otherwise wait on each
+        other until a deadline takes one of them for dead (the shape of
+        it: a router sending a 12k-token prompt, 70 KB, to a worker that
+        is streaming thousands of tokens a second back;
+        tests/test_serving.py holds the two-way case)."""
         data = json.dumps(msg, separators=(",", ":")).encode() + b"\n"
         deadline = time.perf_counter() + max(timeout, 0.0)
         while data:
             wait = max(deadline - time.perf_counter(), 0.0)
-            _, w, _ = select.select([], [self.wfd], [], wait)
+            rd = [] if self.closed or self.rfd is None else [self.rfd]
+            r, w, _ = select.select(rd, [self.wfd], [], wait)
+            if r:
+                self._pump()
             if not w:
+                if time.perf_counter() < deadline:
+                    continue
                 raise ChannelTimeout(
                     f"send timed out after {timeout}s ({len(data)} bytes "
                     f"unwritten) — peer stopped reading")

@@ -29,6 +29,11 @@ DEVICE_SCOPES = (
     # top-k; sort and gather into the tile-aligned buffer; the grouped
     # GEMMs and the activation; gather back and the gate-weighted sum
     "moe_router", "moe_dispatch", "moe_experts", "moe_combine",
+    # a "conv" layer's operator (models/transformer.py ``conv_mix``: the
+    # projections, the gates and the taps) in place of the five attention
+    # scopes, and the one write of a program's records (per-slot state that
+    # is not pages: inference/forward.py ``merge_records``)
+    "conv_mix", "state_commit",
     # training (models/transformer.py, models/loss.py, runtime/engine.py)
     "head_loss", "optimizer", "grad_check", "zero_gather", "zero_reduce",
 )

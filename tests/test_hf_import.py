@@ -1,6 +1,9 @@
 """HF checkpoint import: converted weights reproduce the transformers
 forward numerically (the correctness contract module_inject's policies
 carry in the reference — here proven against torch directly)."""
+import dataclasses
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -555,3 +558,143 @@ def test_qwen2_moe_mixed_stack_import_matches_torch_forward():
         ref = hf(torch.from_numpy(ids).long()).logits.numpy()
     got = _logits_ours(model, params, ids)
     np.testing.assert_allclose(got, ref, atol=3e-4)
+
+
+# ---------------------------------------------------------------------------
+# lfm2_moe: the name map, on a synthetic state dict at the tiny size
+# ---------------------------------------------------------------------------
+
+LFM2 = dict(vocab_size=128, hidden_size=64, intermediate_size=96,
+            moe_intermediate_size=32, num_hidden_layers=5,
+            num_attention_heads=4, num_key_value_heads=2,
+            max_position_embeddings=64, norm_eps=1e-5, num_experts=8,
+            num_experts_per_tok=2, norm_topk_prob=True, use_expert_bias=True,
+            routed_scaling_factor=1, num_dense_layers=1, conv_L_cache=3,
+            conv_bias=False, rope_parameters={"rope_theta": 10000.0},
+            layer_types=["conv", "full_attention", "conv", "conv", "conv"])
+
+
+def _lfm2_state_dict(rng):
+    """A synthetic state dict under HF ``Lfm2Moe``'s own key names."""
+    E, F, Fe, n, V, D = 64, 96, 32, 8, 128, 16
+    r = lambda *shape: rng.standard_normal(shape).astype(np.float32) * 0.1
+    sd = {"model.embed_tokens.weight": r(V, E),
+          "model.embedding_norm.weight": 1 + r(E)}
+    for i, kind in enumerate(LFM2["layer_types"]):
+        p = f"model.layers.{i}."
+        sd.update({p + "operator_norm.weight": 1 + r(E),
+                   p + "ffn_norm.weight": 1 + r(E)})
+        if kind == "conv":
+            sd.update({p + "conv.in_proj.weight": r(3 * E, E),
+                       p + "conv.conv.weight": 5 * r(E, 1, 3),
+                       p + "conv.out_proj.weight": r(E, E)})
+        else:
+            sd.update({p + "self_attn.q_proj.weight": r(E, E),
+                       p + "self_attn.k_proj.weight": r(2 * D, E),
+                       p + "self_attn.v_proj.weight": r(2 * D, E),
+                       p + "self_attn.out_proj.weight": r(E, E),
+                       p + "self_attn.q_layernorm.weight": 1 + 3 * r(D),
+                       p + "self_attn.k_layernorm.weight": 1 + 3 * r(D)})
+        f = p + "feed_forward."
+        if i < LFM2["num_dense_layers"]:
+            sd.update({f + "w1.weight": r(F, E), f + "w3.weight": r(F, E),
+                       f + "w2.weight": r(E, F)})
+        else:
+            sd.update({f + "gate.weight": r(n, E),
+                       f + "expert_bias": 0.2 * r(n)})
+            for k in range(n):
+                sd.update({f + f"experts.{k}.w1.weight": r(Fe, E),
+                           f + f"experts.{k}.w3.weight": r(Fe, E),
+                           f + f"experts.{k}.w2.weight": r(E, Fe)})
+    return sd
+
+
+def test_lfm2_moe_tree_matches_the_reference_in_the_checkpoints_rotation():
+    """Name map and permutation together: the converted tree through the
+    program's forward (rope on interleaved pairs) against the plain
+    reference run on the checkpoint's OWN layout — unpermuted q/k
+    projections and norm scales, rope rotating the two halves as HF does.
+    Every tensor of the state dict is consumed."""
+    import importlib.util
+    from types import SimpleNamespace
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models.hf import (_lfm2_moe_tree, _TrackedSD,
+                                         config_from_hf)
+    from deepspeed_tpu.models.transformer import TransformerLM
+
+    cfg = dataclasses.replace(
+        config_from_hf(SimpleNamespace(model_type="lfm2_moe",
+                                       rope_scaling=None, **LFM2)),
+        dtype=jnp.float32, attn_impl="xla")
+    assert cfg.kinds == ("conv", "full", "conv", "conv", "conv")
+    assert (cfg.qk_norm, cfg.moe.router_score, cfg.moe.moe_layer_pattern,
+            cfg.ffn_size, cfg.moe.dense_ffn_intermediate, cfg.conv_taps,
+            cfg.tie_embeddings) == (
+        "head", "sigmoid_bias", (False, True, True, True, True), 32, 96, 3,
+        True)
+    sd = _TrackedSD(_lfm2_state_dict(np.random.default_rng(0)))
+    tree = _lfm2_moe_tree(sd, cfg)
+    assert set(sd) == sd.used
+    assert tree["layer_0"]["conv"]["w_in"].shape == (64, 3, 64)
+    assert tree["layer_0"]["conv"]["w_conv"].shape == (3, 64)
+    # rows [E:2E] of in_proj are C: the gate on the convolution's output
+    np.testing.assert_array_equal(
+        tree["layer_0"]["conv"]["w_in"][:, 1],
+        sd["model.layers.0.conv.in_proj.weight"][64:128].T)
+
+    spec = importlib.util.spec_from_file_location(
+        "lfm2_reference", os.path.join(os.path.dirname(
+            os.path.abspath(__file__)), "reference", "lfm2_moe_decoder.py"))
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+
+    def rotate_halves(x, positions, theta):         # HF's apply_rotary
+        d = x.shape[-1]
+        inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+        ang = positions.astype(jnp.float32)[:, None] * inv[None, :]
+        cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[:, None, :]
+        sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[:, None, :]
+        x1, x2 = x[..., :d // 2], x[..., d // 2:]
+        return x * cos + jnp.concatenate([-x2, x1], -1) * sin
+
+    def hf_layer(i):
+        """Layer ``i`` straight from the state dict, nothing permuted."""
+        p, t = f"model.layers.{i}.", tree[f"layer_{i}"]
+        w = ref.program_layer(tree, i)
+        if "attn" in t:
+            w.update(
+                wq=sd[p + "self_attn.q_proj.weight"].T.reshape(64, 4, 16),
+                wk=sd[p + "self_attn.k_proj.weight"].T.reshape(64, 2, 16),
+                q_norm=sd[p + "self_attn.q_layernorm.weight"],
+                k_norm=sd[p + "self_attn.k_layernorm.weight"])
+        return w
+
+    tokens = np.random.default_rng(1).integers(0, 128, (1, 32)).astype(
+        np.int32)
+    ops, experts = ref.program_ops(cfg)
+    ref.rotary, interleaved = rotate_halves, ref.rotary
+    ref._layer_step = jax.jit(ref.layer_forward, static_argnames=(
+        "op", "experts", "theta", "eps", "top_k", "q_block"))
+    want = np.asarray(ref.forward_logits(
+        tokens[0], embed=tree["embed"], layer=hf_layer, ops=ops,
+        experts=experts, ln_final=tree["ln_final"]["scale"],
+        theta=cfg.rope_theta, eps=cfg.norm_eps, top_k=2, q_block=16))
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(TransformerLM(cfg).apply(
+            {"params": jax.tree.map(jnp.asarray, tree)}, tokens))[0]
+    np.testing.assert_allclose(got, want, atol=2e-4, rtol=0)
+    # ...and the permutation is needed: unpermuted q/k leave the reference
+    flat = dict(tree, layer_1=dict(tree["layer_1"], attn=dict(
+        tree["layer_1"]["attn"],
+        wq=hf_layer(1)["wq"], wk=hf_layer(1)["wk"])))
+    with jax.default_matmul_precision("highest"):
+        off = np.asarray(TransformerLM(cfg).apply(
+            {"params": jax.tree.map(jnp.asarray, flat)}, tokens))[0]
+    assert np.abs(off - want).max() > 1e-3
+    with pytest.raises(NotImplementedError, match="conv_bias"):
+        config_from_hf(SimpleNamespace(model_type="lfm2_moe",
+                                       rope_scaling=None,
+                                       **dict(LFM2, conv_bias=True)))

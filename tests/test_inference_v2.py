@@ -710,17 +710,28 @@ def test_v2_prefill_pack_generates_same_tokens():
     assert got_p == got_u
 
 
-def test_program_shape_menu_covers_scheduler_emissions():
+@pytest.mark.parametrize("grow_chunk,max_rows", [
+    (True, 0), (False, 0), (False, 3), (True, 2), (False, 9)])
+def test_program_shape_menu_covers_scheduler_emissions(grow_chunk, max_rows):
     """The scheduler's program_shape_menu is THE warm list: every prefill
     plan shape emitted under randomized admission/commit churn must be in
     it (a hand-kept copy in the bench drifted once and cost a 4.5s
     recompile inside an SLA-scored serve). Non-pow2 max_seqs + small
-    pages exercise the page-aligned halving-chain edge."""
+    pages exercise the page-aligned halving-chain edge. ``max_rows`` caps
+    the sequences a packed plan carries (a cap of max_seqs and more is no
+    cap): the menu ends at the cap and holds no full-width plan."""
     rng = np.random.default_rng(0)
     st = StateManager(num_blocks=256, block_size=4, max_seqs=5,
                       max_blocks_per_seq=16)
-    sched = SplitFuseScheduler(st, chunk=8, pack=True)
+    sched = SplitFuseScheduler(st, chunk=8, pack=True, grow_chunk=grow_chunk,
+                               max_rows=max_rows)
     menu = set(sched.program_shape_menu())
+    if 0 < max_rows < 5:
+        assert {S for _, S in menu} == set(range(1, max_rows + 1))
+    else:
+        assert {S for _, S in menu} == {1, 2, 3, 4, 5}
+    if not grow_chunk:
+        assert {T for T, _ in menu} == {8}
     uid = 0
     for _ in range(300):
         while st.can_admit(30, 4) and rng.random() < 0.6:
@@ -748,6 +759,24 @@ def test_program_shape_menu_covers_scheduler_emissions():
         sched.commit(plan, sampled)
         for u in [u for u, s in st.seqs.items() if s.done]:
             st.release(u)
+
+
+def test_v2_prefill_max_rows_serves_the_same_tokens():
+    """A cap on the sequences a prefill plan carries changes WHEN a prompt
+    is prefilled, never what is served."""
+    model = build_model("tiny-gpt2")
+    cfg = {"max_seqs": 4, "chunk": 8, "block_size": 4, "num_blocks": 128,
+           "max_seq_len": 128}
+    free = InferenceEngineV2(model, config=cfg, rng=jax.random.PRNGKey(0))
+    capped = InferenceEngineV2(model, config={**cfg, "prefill_max_rows": 2},
+                               rng=jax.random.PRNGKey(0))
+    assert [S for _, S in capped.scheduler.program_shape_menu()
+            if S > 2] == []
+    rngnp = np.random.default_rng(7)
+    prompts = [list(map(int, rngnp.integers(0, 256, (L,))))
+               for L in [23, 3, 11, 17]]
+    assert capped.generate(prompts, max_new_tokens=5) \
+        == free.generate(prompts, max_new_tokens=5)
 
 
 def test_v2_fp8_kv_with_rolling_window_ring():

@@ -156,8 +156,12 @@ def test_qk_norm_is_over_the_whole_vector():
                            + cfg.norm_eps) * scale
     np.testing.assert_allclose(got, whole, atol=1e-5)
     assert np.abs(got - per_head).max() > 0.5
+    # "head" (PR 50: LFM2) IS that per-head form; an unknown name is refused
+    head = np.asarray(qk_norm(dataclasses.replace(cfg, qk_norm="head"),
+                              jnp.asarray(x), jnp.asarray(scale)))
+    np.testing.assert_allclose(head, per_head, atol=1e-5)
     with pytest.raises(ValueError, match="qk_norm"):
-        qk_norm(dataclasses.replace(cfg, qk_norm="head"), jnp.asarray(x),
+        qk_norm(dataclasses.replace(cfg, qk_norm="group"), jnp.asarray(x),
                 jnp.asarray(scale))
 
 

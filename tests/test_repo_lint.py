@@ -898,3 +898,30 @@ def test_state_invariant_detector_pins_two_phase_extract(tmp_path):
         "    def helper(self):\n"
         "        self.kv_tier.extract_begin(None, 16)\n")
     assert state_lint.check_file(str(impl)) == []
+
+
+# --- a record kind's state is written once a program -------------------------
+
+def test_record_writes_are_pinned_to_the_programs_one_write(tmp_path):
+    """``merge_records`` (the write of a "conv" kind's record a slot) is
+    legal in ``forward.merge_step`` and in ``engine_v2._window_program``
+    and nowhere else: a second write inside a program, or one from the
+    host, could land a decode window's record over a half-prefilled
+    sequence's."""
+    bad = tmp_path / "engine_v2.py"
+    bad.write_text(
+        "def _window_program(self, W):\n"
+        "    def run(pool, new):\n"
+        "        return merge_records(pool, slots, new)\n"      # allowed
+        "    return run\n"
+        "def _program(self, T):\n"
+        "    return forward.merge_records(pool, slots, new)\n")  # flagged
+    out = state_lint.check_file(str(bad))
+    assert len(out) == 1 and ":6:" in out[0] and "merge_records" in out[0]
+    ok = tmp_path / "forward.py"
+    ok.write_text(
+        "def merge_records(records, write_slots, new):\n"
+        "    return records\n"
+        "def merge_step(pools, slots, k_ys, v_ys, T):\n"
+        "    return merge_records(pools[0], slots[0], k_ys[0])\n")
+    assert state_lint.check_file(str(ok)) == []

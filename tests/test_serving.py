@@ -199,6 +199,49 @@ def test_line_channel_roundtrip_and_deadlines():
     b.close()
 
 
+def test_line_channel_send_keeps_reading_while_it_waits_for_space():
+    """Two peers that each write more than a pipe holds, each from the one
+    thread that also reads (a router sending a long prompt to a worker that
+    is streaming tokens back), must not wait on each other until a
+    deadline takes one for dead: ``send`` drains the peer's messages into
+    the buffer while it waits, and ``recv`` returns them in order."""
+    import threading
+
+    a2b_r, a2b_w = os.pipe()
+    b2a_r, b2a_w = os.pipe()
+    a = LineChannel(b2a_r, a2b_w)
+    b = LineChannel(a2b_r, b2a_w)
+    big = list(range(60000))                # ~350 KB a message: 5 pipes' worth
+    errs, got = [], {"a": [], "b": []}
+
+    def talk(ch, tag):
+        try:
+            for i in range(3):
+                ch.send({"t": tag, "i": i, "x": big}, timeout=20.0)
+            got[tag] = [ch.recv(20.0) for _ in range(3)]
+        except Exception as e:              # noqa: BLE001 (reported below)
+            errs.append(repr(e))
+
+    threads = [threading.Thread(target=talk, args=(a, "a")),
+               threading.Thread(target=talk, args=(b, "b"))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(60.0)
+    assert not errs and not any(t.is_alive() for t in threads)
+    assert [(m["t"], m["i"], len(m["x"])) for m in got["a"]] \
+        == [("b", i, 60000) for i in range(3)]
+    assert [(m["t"], m["i"]) for m in got["b"]] \
+        == [("a", i) for i in range(3)]
+    # a peer that truly stops reading is still found out, on time
+    t0 = time.perf_counter()
+    with pytest.raises(Exception, match="peer stopped reading"):
+        a.send({"t": "a", "x": big}, timeout=0.3)
+    assert time.perf_counter() - t0 < 2.0
+    a.close()
+    b.close()
+
+
 def test_request_record_wire_roundtrip():
     rec = RequestRecord(trace_id="x-1", prompt=[1, 2, 3],
                         max_new_tokens=5, eos_token_id=9, tenant="acme")

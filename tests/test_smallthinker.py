@@ -451,7 +451,8 @@ def test_a_model_that_keeps_a_ring_packs_rows_only():
     model = build_model("tiny-smallthinker")
     eng = InferenceEngineV2(model, rng=jax.random.PRNGKey(0),
                             config={**ENGINE, "max_seqs": 3})
-    assert eng.state.has_ring and eng._prefix_cache is None
+    assert "rolling ring" in eng.state.not_a_page_chain
+    assert eng._prefix_cache is None
     assert eng.scheduler.program_shape_menu() == [(16, 1), (16, 2), (16, 3)]
     eng.put(1, list(range(1, 40)), max_new_tokens=4)
     plan = eng.scheduler.next_step()
