@@ -399,10 +399,12 @@ the spans of one entry share it.
 | `state_records_live`, `state_records_peak` | a RECORD kind's state (`conv`: the last `conv_taps - 1` inputs of a short convolution, one record a layer and slot, no pages): records live — one a sequence in a slot — sampled after every dispatch, and the run's peak; present only where the model has such layers |
 | `conv_chunks`, `conv_chunks_carried` | prefill rows dispatched, and those of them whose first position is past 0: they started from the record their sequence's last chunk left, not from zeros (booked on the host at dispatch) |
 | `moe_routed_rows`, `moe_padded_rows`, `attn_steps_*` | booked with the layers that HAVE the mechanism — the expert layers, the layers of each paged kind — not `num_layers` (a stack of unlike layers) |
+| `moe_masked_rows` | (token, choice) entries of dispatched programs whose row carried no request — an empty slot of a window, a chunk's padding — and which the expert sort's liveness mask therefore left out: `(rows x iterations - live tokens) x top_k x expert layers`, host arithmetic at dispatch beside `moe_routed_rows` (a slot that meets its EOS inside a window is masked on the device from there on and still counted as routed) |
 
 A replica worker that leaves logs one line from them: `pipeline: depth ...
 residence ... ms over ... entries (prefill ... ms over ...); replica step
-... % outside the engine`.
+... % outside the engine`, and with routed experts `; experts: ... entries
+routed, ... masked out of the sort, ... buffer rows`.
 """
 
 

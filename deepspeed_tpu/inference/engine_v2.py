@@ -771,8 +771,11 @@ class InferenceEngineV2:
                       # live tokens of a step route (tokens x top_k x MoE
                       # layers) against the rows of the tile-aligned
                       # buffers the grouped GEMMs walk — host arithmetic
-                      # from each dispatched plan's shape (``_count_moe``)
+                      # from each dispatched plan's shape (``_count_moe``);
+                      # and the rows of those programs that carried no
+                      # request, which the sort's liveness mask left out
                       "moe_routed_rows": 0, "moe_padded_rows": 0,
+                      "moe_masked_rows": 0,
                       # the paged kernel's grid steps that read a page
                       # against the slots x table-width rectangle around
                       # them (``_count_attn_steps``, host arithmetic too)
@@ -1227,7 +1230,7 @@ class InferenceEngineV2:
                             params, kv_pool, tok[:, None], pos[:, None],
                             block_tables, lens, jnp.zeros_like(pos),
                             kv_stage=(kbuf, vbuf), stage_fill=i,
-                            stage_starts=base)
+                            stage_starts=base, live=active[:, None])
                     # a row that is not live in this iteration keeps the
                     # record it has
                     kbuf = tuple(
@@ -1451,13 +1454,16 @@ class InferenceEngineV2:
             cfg = self.config
 
             def run(params, kv_pool, token_ids, positions, block_tables,
-                    seq_lens, tree_mask, rng):
+                    seq_lens, tree_mask, n_nodes, rng):
+                # a row's nodes past its tree's, and a row with no tree,
+                # are padding: they route to no expert
+                live = jnp.arange(T)[None] < n_nodes[:, None]
                 with nn.logical_axis_rules(self._rules):
                     (k_ys, v_ys), logits = self._forward(
                         params, kv_pool, token_ids, positions,
                         block_tables, seq_lens,
                         jnp.zeros(token_ids.shape[0], jnp.int32),
-                        tree_mask=tree_mask)
+                        tree_mask=tree_mask, live=live)
                 with device_scope("sample"):
                     toks = sample_tree_logits(
                         logits.astype(jnp.float32), rng,
@@ -1470,7 +1476,7 @@ class InferenceEngineV2:
             # pool NOT donated: it stays live (unchanged) for the merge
             # program that runs after the host-side acceptance walk
             self._programs[key] = register_program(jax.jit(
-                run, in_shardings=(None, self._pool_formats) + (None,) * 6,
+                run, in_shardings=(None, self._pool_formats) + (None,) * 7,
                 out_shardings=(repl, repl, repl)))
         return self._programs[key]
 
@@ -1578,6 +1584,7 @@ class InferenceEngineV2:
         pos = np.zeros((S, T), np.int32)
         tables = np.zeros((S, mb), np.int32)
         lens = np.zeros(S, np.int32)
+        n_nodes = np.zeros(S, np.int32)
         mask = np.zeros((S, T, T), np.uint8)
         # every row starts as self-bits only: empty slots and padding
         # nodes must never see an all-masked softmax row (NaN)
@@ -1595,6 +1602,7 @@ class InferenceEngineV2:
                 pos[sl, :n] = [root + d for d in depths]
                 tables[sl, :len(s.blocks)] = s.blocks
                 lens[sl] = root + 1 + max(depths)
+                n_nodes[sl] = n
                 mask[sl] = tree.ancestor_mask(T)
                 mask[sl, np.arange(n, T), np.arange(n, T)] = 1
                 meta[s.uid] = (sl, tree)
@@ -1608,7 +1616,7 @@ class InferenceEngineV2:
                 fn = self._spec_program(T)
                 self._rng, sub = jax.random.split(self._rng)
                 k_ys, v_ys, toks = fn(self.params, self.kv_pool, tok, pos,
-                                      (tables,), lens, mask, sub)
+                                      (tables,), lens, mask, n_nodes, sub)
                 toks_h = np.asarray(toks)
 
             # exact acceptance on the host, then ONE merge of exactly the
@@ -1698,7 +1706,10 @@ class InferenceEngineV2:
         """Book one dispatch of a program whose forward runs ``iters``
         times over ``rows`` token rows, ``live_tokens`` of them real (step
         plans and decode windows; speculative verify rounds are not
-        booked)."""
+        booked). The rest are the (token, choice) entries the liveness mask
+        keeps out of the expert sort — as the host knows them at dispatch:
+        a slot that meets its EOS inside a window is masked from there on
+        and still counted as routed."""
         if not self._moe_layers:
             return
         mo = self.mcfg.moe
@@ -1706,6 +1717,8 @@ class InferenceEngineV2:
                            bool(self.config.quant_bits))
         self.stats["moe_routed_rows"] += \
             live_tokens * mo.top_k * self._moe_layers
+        self.stats["moe_masked_rows"] += \
+            (rows * iters - live_tokens) * mo.top_k * self._moe_layers
         self.stats["moe_padded_rows"] += iters * self._moe_layers * \
             moe_padded_rows(rows, mo.top_k, mo.num_experts, bm)
 

@@ -437,7 +437,7 @@ class RaggedForward:
 
     def __call__(self, params, kv_pools, token_ids, positions, block_tables,
                  seq_lens, sample_idx, kv_stage=None, stage_fill=None,
-                 stage_starts=None, tree_mask=None):
+                 stage_starts=None, tree_mask=None, live=None):
         """One ragged forward over READ-ONLY pools; returns ``((k_ys, v_ys),
         logits)`` and never writes a pool.
 
@@ -475,6 +475,13 @@ class RaggedForward:
         fresh KV is ancestors-only (siblings share a POSITION, which
         positional-causal masking cannot tell apart, hence the explicit
         mask; the paged pool below the root stays position-causal).
+        ``live`` ([S, T] bool) says which tokens carry a request;
+        the rest reach no routed expert (``routed_experts``). A step plan
+        need not pass it: its row's valid tokens are ``seq_lens`` less the
+        row's first position, so a chunk's padding and an empty row are not
+        live. A window's rows and a tree's nodes look alike to the forward
+        (None there: every token live): the program that calls it knows
+        (the window's ``active``, the round's node counts).
         Logits are ``[S, T, V]`` — ALL nodes; the caller merges only the
         ACCEPTED path's staged rows, so rejected candidates never reach
         the pool. The Pallas kernel serves tree mode too (per-node stage
@@ -505,12 +512,15 @@ class RaggedForward:
             Ts = kbufs[0].shape[3]
         else:
             Ts = stage_rows(T, bs)
+        #: tokens of each row that count in this call
+        n_valid = jnp.ones_like(seq_lens) if window_mode \
+            else seq_lens - q_starts
         if CONV in cache_of:
-            #: a record kind's rows: tokens of this call that count, and
-            #: whether the row starts its sequence (no past: zeros)
-            n_valid = jnp.ones_like(seq_lens) if window_mode \
-                else seq_lens - q_starts
+            #: whether a record kind's row starts its sequence (no past:
+            #: zeros)
             fresh_row = (q_starts == 0)[:, None, None]
+        if live is None and not (window_mode or tree_mode):
+            live = jnp.arange(T)[None] < n_valid[:, None]
 
         # ring collective-matmul TP: static per program — the token-sharded
         # residual stream needs the row dim to divide the tensor axis
@@ -604,7 +614,9 @@ class RaggedForward:
             sort, and gather the rows into a tile-aligned buffer (no
             scatter: a one-hot matmul at a step's few rows, a row gather
             at many) -> grouped GEMMs -> gather back and gate-weighted
-            sum. Only the GEMM differs: the bf16 Pallas grouped matmul, or
+            sum. A token that carries no request (``live``) reaches no
+            expert and reads zero. Only the GEMM differs: the bf16 Pallas
+            grouped matmul, or
             its in-tile-dequant twin over QuantGrouped slabs (reference
             cutlass_ops/moe_gemm with mixed_gemm). The
             dispatch/combine algebra is shared with the training dropless
@@ -658,7 +670,8 @@ class RaggedForward:
 
             out = dropless_dispatch_combine(
                 flat, gate.gates[0], gate.experts[0], mo.num_experts,
-                mo.top_k, bm, gemm)
+                mo.top_k, bm, gemm,
+                live=None if live is None else live.reshape(Tt))
             return out.reshape(S, T, E).astype(cfg.dtype)
 
         def ffn(p, h, use_moe: bool, li=None, h_router=None):

@@ -80,7 +80,8 @@ class TopKGate(nn.Module):
 
 def dropless_dispatch_combine(x2d: jax.Array, gates: jax.Array,
                               experts: jax.Array, num_experts: int, k: int,
-                              block_m: int, gemm: Callable) -> jax.Array:
+                              block_m: int, gemm: Callable,
+                              live: jax.Array | None = None) -> jax.Array:
     """Shared megablocks-style dispatch/combine (used by the dropless
     training path below AND every routed-expert layer of the v2 serving
     forward — inference/engine_v2.py ``routed_experts``, quantised or not
@@ -93,6 +94,14 @@ def dropless_dispatch_combine(x2d: jax.Array, gates: jax.Array,
     part that differs between callers: bf16 grouped GEMM vs quantized
     grouped GEMM), gather each token's k rows back and combine with its
     gates (as the router gave them: renormalised or not).
+
+    ``live`` ([T] bool; None: every token, as training passes) says which
+    tokens exist: a serving program's rows that carry no request reach no
+    expert (the sort counts them for none, so no tile and no weight block
+    is theirs) and their output is exactly zero. A select, not a zero
+    gate: a masked entry's index, clamped into the buffer, fetches a row
+    the kernel may never have written (a tile at or past ``n_tiles``), and
+    NaN times 0 is NaN.
     """
     from ..ops.pallas.grouped_matmul import (gather_expert_rows,
                                              gather_token_rows,
@@ -101,13 +110,18 @@ def dropless_dispatch_combine(x2d: jax.Array, gates: jax.Array,
     T = x2d.shape[0]
     with device_scope("moe_dispatch"):
         srt = sort_tokens_by_expert(experts.reshape(T, k), num_experts,
-                                    block_m)
+                                    block_m, live)
         buf = gather_expert_rows(x2d, srt.src, srt.dst)    # [Tp, E]
     with device_scope("moe_experts"):
         out_buf = gemm(buf, srt)
     with device_scope("moe_combine"):
+        # (a masked entry's ``dst`` is one past the buffer: held inside it
+        # for the gather, and what it fetched thrown away)
+        dst = srt.dst if live is None else jnp.minimum(srt.dst, srt.Tp - 1)
         rows_out = gather_token_rows(out_buf, srt.src,
-                                     srt.dst).reshape(T, k, -1)
+                                     dst).reshape(T, k, -1)
+        if live is not None:
+            rows_out = jnp.where(live[:, None, None], rows_out, 0)
         return jnp.einsum("tk,tke->te",
                           gates.reshape(T, k).astype(x2d.dtype), rows_out)
 

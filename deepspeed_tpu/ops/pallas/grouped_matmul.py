@@ -361,7 +361,8 @@ class ExpertSort(NamedTuple):
     into the buffer is a GATHER, not a scatter: ``src`` names, for every
     buffer row, what it holds; ``dst`` names, for every (token, choice),
     its row — the way back, and what the dense fill compares against."""
-    dst: jax.Array          # [T*k] buffer row of each (token, choice)
+    dst: jax.Array          # [T*k] buffer row of each (token, choice);
+                            # Tp (no row) for a masked token's
     tile_expert: jax.Array  # [Tp // block_m] expert owning each token tile
     Tp: int                 # static padded buffer length
     n_tiles: jax.Array      # scalar: tiles that hold a token (the rest of
@@ -380,10 +381,21 @@ def _lookup(table: jax.Array, idx: jax.Array) -> jax.Array:
 
 
 def sort_tokens_by_expert(expert_idx: jax.Array, num_experts: int,
-                          block_m: int = 128) -> ExpertSort:
+                          block_m: int = 128,
+                          live: jax.Array | None = None) -> ExpertSort:
     """Compute the expert-sorted, block-aligned buffer row of every
     (token, choice) pair, and for every buffer row the entry to gather into
     it. ``expert_idx``: [T, k] int32 from top-k routing.
+
+    ``live`` ([T] bool; None: every token) says which tokens exist. A
+    masked token's k entries take the expert id ``num_experts``, which no
+    comparison against ``arange(n)`` matches: they count for no expert,
+    sort behind every live entry, and ``counts``, the tiles, ``n_tiles``
+    and ``src`` are those of the live entries alone. Their ``dst`` is
+    ``Tp``, one past the buffer: the fill puts them nowhere, and the way
+    back (``dropless_dispatch_combine``) holds the index inside the buffer
+    and discards what it fetched — a tile at or past ``n_tiles`` is one
+    the kernel never wrote.
 
     No scatter anywhere (a TPU walks a scatter one update at a time, ~0.17
     us a row: ``PERF.md`` PR 46): the counts are a comparison against
@@ -399,6 +411,8 @@ def sort_tokens_by_expert(expert_idx: jax.Array, num_experts: int,
     T, k = expert_idx.shape
     Tk = T * k
     e_flat = expert_idx.reshape(-1).astype(jnp.int32)
+    if live is not None:
+        e_flat = jnp.where(jnp.repeat(live, k), e_flat, num_experts)
     counts = jnp.sum(
         e_flat[:, None] == jnp.arange(num_experts, dtype=jnp.int32)[None],
         axis=0, dtype=jnp.int32)                                   # [n]
@@ -411,10 +425,12 @@ def sort_tokens_by_expert(expert_idx: jax.Array, num_experts: int,
     iota = jnp.arange(Tk, dtype=jnp.int32)
     sorted_e, order = jax.lax.sort((e_flat, iota), num_keys=1,
                                    is_stable=True)                 # [Tk]
+    Tp = ((Tk + block_m - 1) // block_m) * block_m + num_experts * block_m
     dst_sorted = _lookup(starts - cum_counts, sorted_e) + iota
+    if live is not None:
+        dst_sorted = jnp.where(sorted_e < num_experts, dst_sorted, Tp)
     _, dst = jax.lax.sort((order, dst_sorted), num_keys=1, is_stable=False)
 
-    Tp = ((Tk + block_m - 1) // block_m) * block_m + num_experts * block_m
     tile_starts = jnp.arange(Tp // block_m, dtype=jnp.int32) * block_m
     # tiles past the last used one belong to the last used tile's expert:
     # still nondecreasing (the dw kernel's invariant), and a kernel told
@@ -427,12 +443,13 @@ def sort_tokens_by_expert(expert_idx: jax.Array, num_experts: int,
         jnp.sum(starts[None] <= clamped[:, None], axis=1, dtype=jnp.int32) - 1,
         0, num_experts - 1)
 
-    # a tail tile lies past its (clamped) expert's aligned rows: not live
+    # a tail tile lies past its (clamped) expert's aligned rows: it holds
+    # no entry
     p = tile_starts[:, None] + jnp.arange(block_m, dtype=jnp.int32)[None]
-    live = p < _lookup(starts + counts, tile_expert)[:, None]      # [tiles, bm]
+    held = p < _lookup(starts + counts, tile_expert)[:, None]      # [tiles, bm]
     entry = order[jnp.clip(
         p + _lookup(cum_counts - starts, tile_expert)[:, None], 0, Tk - 1)]
-    src = jnp.where(live, entry, Tk).reshape(Tp)
+    src = jnp.where(held, entry, Tk).reshape(Tp)
     return ExpertSort(dst=dst, tile_expert=tile_expert, Tp=Tp,
                       n_tiles=n_tiles, src=src)
 

@@ -1053,7 +1053,8 @@ class EngineBackend:
 
     def pipeline_line(self) -> str:
         """The engine's pipeline over this backend's life, from its
-        counters: what a worker logs when it leaves."""
+        counters: what a worker logs when it leaves (an engine with routed
+        experts adds what their sorts were handed)."""
         st = self.eng.stats
         n_in, n_out = st["entries_dispatched"], st["entries_committed"]
         n_pre, step_s = st["prefill_entries_committed"], st["replica_step_s"]
@@ -1065,7 +1066,11 @@ class EngineBackend:
             f"{1e3 * st['prefill_residence_s'] / max(n_pre, 1):.1f} ms over "
             f"{n_pre}); replica step "
             f"{100 * (step_s - st['engine_step_s']) / max(step_s, 1e-9):.2f}"
-            f" % outside the engine")
+            f" % outside the engine" + (
+                f"; experts: {st['moe_routed_rows']} entries routed, "
+                f"{st['moe_masked_rows']} masked out of the sort, "
+                f"{st['moe_padded_rows']} buffer rows"
+                if st["moe_padded_rows"] else ""))
 
     # -- KV-page migration (disaggregated serving) -----------------------
     def request_handoff(self, rid: str) -> bool:

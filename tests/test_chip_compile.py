@@ -260,10 +260,11 @@ def _grouped_serving(tokens, down=False, k=8, E=2048, F=1024):
     return build
 
 
-def _routed_layer(tokens, k=8, E=2048, F=1024):
+def _routed_layer(tokens, k=8, E=2048, F=1024, masked=False):
     """One routed-expert layer of serving as ``moe/layer.py`` builds it
     (sort, fill, three grouped GEMMs, combine) at OLMoE's or SmallThinker's
-    widths and the tile height of a step of ``tokens`` rows."""
+    widths and the tile height of a step of ``tokens`` rows; ``masked``:
+    with the rows' liveness, as every serving program hands it (PR 51)."""
     def build(devs):
         from deepspeed_tpu.inference.engine_v2 import moe_tile_rows
         from deepspeed_tpu.moe.layer import dropless_dispatch_combine
@@ -273,18 +274,19 @@ def _routed_layer(tokens, k=8, E=2048, F=1024):
         n = 64
         bm = moe_tile_rows(tokens, k, n)
 
-        def fn(x, gates, experts, wg, wu, wd):
+        def fn(x, gates, experts, wg, wu, wd, live=None):
             def gemm(buf, srt):
                 mm = lambda a, w: grouped_matmul_layer(
                     a, w, srt.tile_expert, srt.n_tiles, bm)
                 return mm(jax.nn.silu(mm(buf, wg)) * mm(buf, wu), wd)
             return dropless_dispatch_combine(x, gates, experts, n, k, bm,
-                                             gemm)
+                                             gemm, live=live)
         return fn, (_sds(one, (tokens, E), BF16),
                     _sds(one, (tokens, k), jnp.float32),
                     _sds(one, (tokens, k), jnp.int32),
                     _sds(one, (n, E, F), BF16), _sds(one, (n, E, F), BF16),
-                    _sds(one, (n, F, E), BF16)), True
+                    _sds(one, (n, F, E), BF16)) + (
+                        (_sds(one, (tokens,), jnp.bool_),) * masked), True
     return build
 
 
@@ -388,6 +390,14 @@ ROUTED_LAYERS = {
     "routed_layer_olmoe_decode": ((48, {}), "dense"),
     "routed_layer_olmoe_chunk128": ((128, {}), "dense"),
     "routed_layer_olmoe_rows2048": ((2048, {}), "gather"),
+    # the same with the liveness mask: the decode window's 48 rows at 6 of
+    # 64 and tile 16, a prefill chunk, and the gather form
+    "routed_layer_thinker_decode_masked": (
+        (48, {**THINKER_MOE, "masked": True}), "dense"),
+    "routed_layer_thinker_chunk512_masked": (
+        (512, {**THINKER_MOE, "masked": True}), "dense"),
+    "routed_layer_olmoe_rows2048_masked": ((2048, {"masked": True}),
+                                           "gather"),
 }
 
 
@@ -397,7 +407,8 @@ def test_routed_layer_compiles_with_no_scatter(name, topo):
     held three: the fill, the destinations, bincount's add; a TPU walks
     each one update at a time), its fill is the form the step's shape
     picks — a convolution under ``moe_dispatch`` for few tokens, a gather
-    for many — and the three grouped GEMMs are still the kernel."""
+    for many — and the three grouped GEMMs are still the kernel; with the
+    liveness mask as without."""
     import re
 
     import deepspeed_tpu.ops.pallas.grouped_matmul as gm

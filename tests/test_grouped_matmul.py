@@ -384,3 +384,112 @@ def test_dispatch_output_and_grads_equal_the_scatter_forms(
         np.testing.assert_array_equal(np.asarray(got, np.float32),
                                       np.asarray(want, np.float32))
     assert float(jnp.abs(outs[0][1]).sum()) > 0
+
+
+# ---------------------------------------------------------------------------
+# the sort takes a liveness mask (PR 51): a token with no request counts for
+# no expert, its rows reach no buffer row, and nothing of a buffer row the
+# kernel never wrote reaches the output
+# ---------------------------------------------------------------------------
+
+#: which of the T tokens carry a request
+MASKS = {
+    "none_live": lambda T: np.zeros(T, bool),
+    "one_live": lambda T: np.arange(T) == T // 3,
+    "all_but_one": lambda T: np.arange(T) != T // 3,
+    "all_live": lambda T: np.ones(T, bool),
+}
+
+
+@pytest.mark.parametrize("form", list(FORMS))
+@pytest.mark.parametrize("block_m", [16, 128])
+@pytest.mark.parametrize("mask", list(MASKS))
+def test_masked_sort_is_the_sort_of_the_live_tokens_alone(monkeypatch, mask,
+                                                          block_m, form):
+    """A decode step's 48 rows, 6 of 64, dead rows ALL on the same experts
+    (token 0's, as an empty slot's are). (a) The tiles, ``n_tiles`` and
+    ``src`` are those of the sort of the live tokens alone, a masked
+    entry's ``dst`` is ``Tp``, and with every token live the masked sort is
+    ``live=None``'s to the element. (b) The buffer's live rows are the
+    unmasked buffer's rows of the same entries bit for bit, every other row
+    zero. (c) With the rows of ``out_buf`` at and past ``n_tiles * block_m``
+    — what the kernel never writes — set to NaN, the layer's output is
+    finite, exactly zero for a masked token and bitwise the unmasked
+    layer's for a live one."""
+    import deepspeed_tpu.ops.pallas.grouped_matmul as gm
+    from deepspeed_tpu.moe.layer import dropless_dispatch_combine
+
+    monkeypatch.setattr(gm, "DENSE_FILL_MAX_ELEMS", FORMS[form])
+    T, k, n, bm = 48, 6, 64, block_m
+    Tk = T * k
+    live = MASKS[mask](T)
+    idx = np.flatnonzero(live)
+    eidx = np.array(_route("random", T, k, n, seed=7))
+    eidx[~live] = eidx[0] if not live[0] else eidx[T // 3]
+    eidx = jnp.asarray(eidx)
+    sort = jax.jit(lambda e, lv=None: gm.sort_tokens_by_expert(e, n, bm, lv))
+    got, plain = sort(eidx, jnp.asarray(live)), sort(eidx)
+    Tp, nt = int(got.Tp), int(got.n_tiles)
+    dst, src = np.asarray(got.dst).reshape(T, k), np.asarray(got.src)
+
+    # (a)
+    if mask == "all_live":
+        for a, b in zip(got, plain):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert (dst[~live] == Tp).all() and (src[nt * bm:] == Tk).all()
+    te = np.asarray(got.tile_expert)
+    assert (np.diff(te) >= 0).all() and (te[nt:] == te[max(nt - 1, 0)]).all()
+    if idx.size:
+        sub = sort(eidx[idx])
+        assert nt == int(sub.n_tiles) > 0
+        np.testing.assert_array_equal(te[:nt],
+                                      np.asarray(sub.tile_expert)[:nt])
+        np.testing.assert_array_equal(dst[live],
+                                      np.asarray(sub.dst).reshape(-1, k))
+        s = np.asarray(sub.src)[:nt * bm]       # entries of the SUBSET
+        held = s < idx.size * k
+        want = np.where(held, idx[np.minimum(s // k, idx.size - 1)] * k
+                        + s % k, Tk)
+        np.testing.assert_array_equal(src[:nt * bm], want)
+    else:
+        assert nt == 0 and (te == 0).all()
+    counts = np.bincount(te[np.arange(Tp) // bm][src < Tk], minlength=n)
+    np.testing.assert_array_equal(
+        counts, np.bincount(np.asarray(eidx)[live].reshape(-1), minlength=n))
+
+    # (b)
+    E, F = 128, 48
+    rng = np.random.default_rng(8)
+    x = jnp.asarray(rng.standard_normal((T, E)), jnp.bfloat16)
+    fill = jax.jit(gm.gather_expert_rows)
+    buf, buf_plain = fill(x, got.src, got.dst), fill(x, plain.src, plain.dst)
+    assert buf.shape == buf_plain.shape == (Tp, E)
+    np.testing.assert_array_equal(
+        _bits(buf)[dst[live]],
+        _bits(buf_plain)[np.asarray(plain.dst).reshape(T, k)[live]])
+    assert not _bits(buf)[np.setdiff1d(np.arange(Tp), dst[live])].any()
+
+    # (c)
+    gates = jnp.asarray(rng.random((T, k)), jnp.float32)
+    wu = jnp.asarray(rng.standard_normal((n, E, F)) / E ** 0.5, jnp.bfloat16)
+    wd = jnp.asarray(rng.standard_normal((n, F, E)) / F ** 0.5, jnp.bfloat16)
+
+    def layer(lv, poison):
+        def gemm(b, srt):
+            mm = lambda a, w: gm.grouped_matmul_layer(
+                a, w, srt.tile_expert, srt.n_tiles, bm)
+            out = mm(jax.nn.silu(mm(b, wu)), wd)
+            unwritten = jnp.arange(out.shape[0]) >= srt.n_tiles * bm
+            return jnp.where(unwritten[:, None] & poison, jnp.nan, out)
+        return dropless_dispatch_combine(x, gates, eidx, n, k, bm, gemm,
+                                         live=lv)
+
+    out = np.asarray(jax.jit(lambda: layer(jnp.asarray(live), True))()
+                     .astype(jnp.float32))
+    ref = np.asarray(jax.jit(lambda: layer(None, False))()
+                     .astype(jnp.float32))
+    assert np.isfinite(out).all()
+    assert not out[~live].any() and (np.signbit(out[~live]) == 0).all()
+    np.testing.assert_array_equal(out[live], ref[live])
+    if idx.size:
+        assert np.abs(out[live]).sum() > 0
