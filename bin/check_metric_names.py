@@ -390,7 +390,8 @@ the spans of one entry share it.
 | `entries_dispatched`, `inflight_depth_sum` | entries appended, and the sum over them of the entries already in flight: the mean is how far the host runs ahead of the device |
 | `entries_committed`, `inflight_residence_s` | entries committed, and the sum of (end of commit - append): what a dispatched step spends in the pipeline |
 | `prefill_entries_committed`, `prefill_residence_s` | the same two for prefill plans alone |
-| `decode_tokens` | tokens generated by decode steps (booked at dispatch) and decode windows (booked at commit) |
+| `decode_tokens` | tokens generated by decode steps and by prefill steps' decode blocks (booked at dispatch) and decode windows (booked at commit) |
+| `fused_steps`, `fused_decode_tokens`, `fused_empty_steps` | the decode block of a prefill step (the decode-ready rows as a `[max_seqs, 1]` segment of the same program): steps whose block carried a decode token, the tokens that left that way — in `decode_tokens` too, in NO `decode_steps` / `window_iters`: their device time is `jit_step_prefill`'s — and steps whose block had no live row; booked at dispatch beside `prefill_steps` |
 | `replica_step_s`, `engine_step_s` | booked by `EngineBackend.step`: its own wall time, and `engine.step()`'s inside it |
 | `kv_blocks_live_<kind>`, `kv_blocks_peak_<kind>` | by PAGED kind of layer (`full`: a table that grows; `window`: a bounded ring): KV blocks live sequences hold, sampled after every dispatch (`StateManager.sample`), and the run's peak |
 | `ring_blocks_reused` | ring slots a page past the window overwrote in place (`StateManager.note_written`, booked at dispatch) |
@@ -403,8 +404,9 @@ the spans of one entry share it.
 
 A replica worker that leaves logs one line from them: `pipeline: depth ...
 residence ... ms over ... entries (prefill ... ms over ...); replica step
-... % outside the engine`, and with routed experts `; experts: ... entries
-routed, ... masked out of the sort, ... buffer rows`.
+... % outside the engine`, where prefill steps ran `; fused: ... steps
+carried ... decode tokens, ... carried none`, and with routed experts
+`; experts: ... entries routed, ... masked out of the sort, ... buffer rows`.
 """
 
 

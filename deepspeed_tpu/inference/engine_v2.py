@@ -20,8 +20,11 @@ Architecture (TPU-first, round-4 async design):
   forward and inside the same ``jit``. Interleaving pool writes with the
   attention custom call makes XLA materialize pool-sized copies — the
   measured difference is ~280ms vs ~8.5ms per decode token-step.
-- Steps are cached jitted programs — a SplitFuse plan ([S, chunk] prompt
-  chunks with decode rows fused in) or a multi-iteration decode window
+- Steps are cached jitted programs — a SplitFuse plan ([rows, chunk]
+  prompt chunks, and beside them the DECODE BLOCK: the decode-ready
+  sequences as a [max_seqs, 1] segment of the same forward, one token each
+  for the price of their attention, the weights read once for both), a
+  pure [max_seqs, 1] decode plan, or a multi-iteration decode window
   (a fixed-trip ``lax.scan``) — built by inference/scheduler.py
   from a SPECULATIVE view of each sequence (dispatched-but-uncommitted).
 - Dispatch never waits: decode chains through a device-resident
@@ -55,9 +58,9 @@ from ..ops.pallas.paged_attention import (paged_attention_usable, paged_plan,
                                           paged_step_counts)
 # (``cache_kinds``, ``moe_tile_rows`` and ``moe_padded_rows`` are imported
 # from here by the benchmark's tools too)
-from .forward import (KIND_SPEC_2D, KIND_SPEC_3D, RaggedForward, cache_kinds,
-                      kv_pack, merge_records, merge_rows, merge_step, moe_padded_rows,
-                      moe_tile_rows, stage_rows)
+from .forward import (KIND_SPEC_2D, KIND_SPEC_3D, DecodeBlock, RaggedForward,
+                      cache_kinds, kv_pack, merge_records, merge_rows,
+                      merge_step, moe_padded_rows, moe_tile_rows, stage_rows)
 from .ragged import StateManager, StepPlan
 from .sampling import sample_logits, sample_tree_logits
 from .scheduler import SpecAcceptTracker, SplitFuseScheduler
@@ -115,11 +118,13 @@ class RaggedInferenceConfig:
     decode_window: int = 8
     #: cap on the decode window while prefill chunks are PENDING (advisor
     #: r05: a new request's first chunk could wait out a full
-    #: decode_window, inflating TTFT). The engine alternates pure
-    #: prefill/decode dispatches; this bounds how long a pending chunk
-    #: waits behind the decode side of the alternation without giving up
-    #: windowing entirely. Pow2-floored like the window itself, so the
-    #: compiled-program menu stays bounded. 0 disables the cap.
+    #: decode_window, inflating TTFT). The engine alternates prefill steps
+    #: (each carries the decode block: one token for every decoder) with
+    #: decode windows; this bounds how long a pending chunk waits behind
+    #: the decode side of the alternation without giving up windowing
+    #: entirely — a capped cycle hands a decoder cap + 1 tokens. Pow2-
+    #: floored like the window itself, so the compiled-program menu stays
+    #: bounded. 0 disables the cap.
     decode_window_mixed_cap: int = 4
     #: async pipeline depth: how many dispatched steps may await host
     #: readback before the engine blocks on the oldest. Dispatch never
@@ -633,6 +638,9 @@ class InferenceEngineV2:
             self.scheduler.row_multiple = self._tp_ring_n
 
         self._programs: dict[int, Any] = {}
+        #: the decode block of a prefill plan that carries none
+        #: (``_plan_args``): a ``[max_seqs, 1]`` plan of no live row
+        self._empty_block: StepPlan | None = None
         #: the grouped GEMM's weight blocks, one entry a distinct expert
         #: shape and block (``RaggedForward.gmm``; the ``gmm:`` log lines)
         self.gmm_plans: dict[tuple, Any] = {}
@@ -723,6 +731,13 @@ class InferenceEngineV2:
                       "decode_steps": 0, "windows": 0, "window_iters": 0,
                       "forced_drains": 0, "opportunistic_drains": 0,
                       "prefill_tokens": 0, "decode_tokens": 0,
+                      # the decode block of a prefill step: steps whose
+                      # block carried a decode token, the tokens that left
+                      # that way (booked in ``decode_tokens`` too, in no
+                      # ``decode_steps`` / ``window_iters``), and steps
+                      # whose block had no live row
+                      "fused_steps": 0, "fused_decode_tokens": 0,
+                      "fused_empty_steps": 0,
                       # the pipeline, entry by entry (``_enqueue`` /
                       # ``_drain``): entries appended and the depth each
                       # joined; entries committed and the time from their
@@ -835,6 +850,9 @@ class InferenceEngineV2:
         self._spec_emit: dict[int, list[int]] = {}
         if cfg.spec_decode:
             self._init_speculative(draft_model, draft_params, draft_rng)
+            # the decode side belongs to the verify rounds: prefill steps
+            # stay pure
+            self.scheduler.decode_rides = False
             # draft-mirror rewinds show up on the TARGET request's
             # timeline (the mirror engine runs with telemetry off)
             self._spec.reqtrace = self._rt
@@ -1115,35 +1133,89 @@ class InferenceEngineV2:
         (S_rows < max_seqs) carry fewer, wider rows — the token-budget
         menu VERDICT r04 weak #2 asked for — and map each row to its
         physical slot through ``row_slots`` (all-distinct, so the
-        last-token scatter is race-free)."""
+        last-token scatter is race-free).
+
+        A PREFILL program (T > 1) always takes one more argument, the
+        DECODE BLOCK (``_plan_args``: a ``[max_seqs, 1]`` decode plan's
+        arrays, full static width, whoever is live): the forward walks the
+        layers once over both segments, the block's one token a row is
+        merged, sampled and chained through ``last_tok`` as a ``[S, 1]``
+        step's, and ``toks`` holds the plan's rows then the block's. The
+        key, and so the compiled menu, does not know the block."""
         key = (T, S_rows)
         if key not in self._programs:
-            def step(params, kv_pool, last_tok, token_ids, positions,
-                     slot_map, block_tables, seq_lens, sample_idx,
-                     do_sample, use_last, row_slots, rng):
+            cfg = self.config
+
+            def from_last(last_tok, token_ids, use_last, row_slots):
                 # decode rows whose previous token is still in flight read
                 # the device-resident last sample instead of the host
                 # placeholder (only col 0 can be such a row: 1-token rows)
                 row_last = last_tok[row_slots]
-                token_ids = token_ids.at[:, 0].set(
+                return row_last, token_ids.at[:, 0].set(
                     jnp.where(use_last.astype(bool), row_last,
                               token_ids[:, 0]))
+
+            def sample(logits, rng):
+                return sample_logits(logits.astype(jnp.float32), rng,
+                                     temperature=cfg.temperature,
+                                     top_k=cfg.top_k, top_p=cfg.top_p,
+                                     greedy=cfg.greedy)
+
+            def step_plain(params, kv_pool, last_tok, token_ids, positions,
+                           slot_map, block_tables, seq_lens, sample_idx,
+                           do_sample, use_last, row_slots, rng):
+                row_last, token_ids = from_last(last_tok, token_ids,
+                                                use_last, row_slots)
                 with nn.logical_axis_rules(self._rules):
                     (k_ys, v_ys), logits = self._forward(
                         params, kv_pool, token_ids, positions,
                         block_tables, seq_lens, sample_idx)
                     # the ONE pool write of this program
                     kv_pool = merge_step(kv_pool, slot_map, k_ys, v_ys, T)
-                cfg = self.config
                 with device_scope("sample"):
-                    toks = sample_logits(logits.astype(jnp.float32), rng,
-                                         temperature=cfg.temperature,
-                                         top_k=cfg.top_k, top_p=cfg.top_p,
-                                         greedy=cfg.greedy)
+                    toks = sample(logits, rng)
                     last_tok = last_tok.at[row_slots].set(
                         jnp.where(do_sample.astype(bool), toks, row_last))
                 return kv_pool, last_tok, toks
 
+            def step_fused(params, kv_pool, last_tok, token_ids, positions,
+                           slot_map, block_tables, seq_lens, sample_idx,
+                           do_sample, use_last, row_slots, block, rng):
+                (b_tok, b_pos, b_slot_map, b_tables, b_lens, b_do_sample,
+                 b_use_last, b_slots) = block
+                row_last, token_ids = from_last(last_tok, token_ids,
+                                                use_last, row_slots)
+                _, b_tok = from_last(last_tok, b_tok, b_use_last, b_slots)
+                with nn.logical_axis_rules(self._rules):
+                    ((k_ys, v_ys), logits), ((bk_ys, bv_ys), b_logits) = \
+                        self._forward(
+                            params, kv_pool, token_ids, positions,
+                            block_tables, seq_lens, sample_idx,
+                            block=DecodeBlock(b_tok, b_pos, b_tables, b_lens))
+                    # the ONE pool write of this program, a segment: the
+                    # chunks by pages, the block's one token a row by rows
+                    # (a sequence is in one segment or the other; a row
+                    # with no request writes the trash block / record)
+                    kv_pool = merge_step(kv_pool, slot_map, k_ys, v_ys, T)
+                    kv_pool = merge_step(kv_pool, b_slot_map, bk_ys, bv_ys,
+                                         1)
+                rows = logits.shape[0]
+                with device_scope("sample"):
+                    toks = sample(jnp.concatenate([logits, b_logits]), rng)
+                    # (the plan's empty rows hold unused slots — a block
+                    # row's among them — and write back what they read: the
+                    # block's rows write after them, and only those that
+                    # sampled, for a block row with no request may sit in
+                    # the slot of a sequence the plan has just sampled)
+                    last_tok = last_tok.at[row_slots].set(
+                        jnp.where(do_sample.astype(bool), toks[:rows],
+                                  row_last))
+                    last_tok = last_tok.at[jnp.where(
+                        b_do_sample.astype(bool), b_slots,
+                        last_tok.shape[0])].set(toks[rows:], mode="drop")
+                return kv_pool, last_tok, toks
+
+            step = step_fused if T > 1 else step_plain
             # distinct module names per kind: device traces attribute
             # jit_step_prefill vs jit_step_decode busy time separately
             # (a T=1 decode plan in "prefill" seconds would corrupt the
@@ -1155,9 +1227,36 @@ class InferenceEngineV2:
             repl = NamedSharding(self.topology.mesh, P())
             self._programs[key] = register_program(jax.jit(
                 step, donate_argnums=(1, 2),
-                in_shardings=(None, self._pool_formats) + (None,) * 11,
+                in_shardings=(None, self._pool_formats)
+                + (None,) * (12 if T > 1 else 11),
                 out_shardings=(self._pool_formats, repl, repl)))
         return self._programs[key]
+
+    def _plan_args(self, plan: StepPlan) -> tuple:
+        """THE site that turns a plan into a step program's arguments
+        (between ``last_tok`` and ``rng``), a tuple a kind of layer
+        (``self._kinds``' order) where the program takes one: the plan
+        holds the primary's slot map and table itself and every further
+        kind's in ``more``. A prefill plan's last argument is its decode
+        block's arrays — those of a block with no live row where the plan
+        carries none."""
+        of_kind = {self._kinds[0].name: (plan.slot_map, plan.block_tables),
+                   **plan.more}
+        slot_maps, tables = zip(*(of_kind[k.name] for k in self._kinds))
+        args = (plan.token_ids, plan.positions, slot_maps, tables,
+                plan.seq_lens, plan.sample_idx, plan.do_sample,
+                plan.use_last, plan.row_slots)
+        if plan.token_ids.shape[1] == 1:
+            return args
+        block = plan.block
+        if block is None:
+            if self._empty_block is None:
+                self._empty_block = self.scheduler._desc("decode", 1, [])
+            block = self._empty_block
+        (tok, pos, slot_maps, tables, lens, _, do_sample, use_last,
+         slots) = self._plan_args(block)    # (it samples at column 0)
+        return args + ((tok, pos, slot_maps, tables, lens, do_sample,
+                        use_last, slots),)
 
     def _window_program(self, W: int):
         """Up to W chained decode steps in one jitted program: per step,
@@ -1350,8 +1449,8 @@ class InferenceEngineV2:
         readback. Runs over the decode-READY subset — slots still
         prefilling (or empty) ride along inactive (rem=0, masked last-
         token update), so mixed states window too; the caller alternates
-        windows with pure prefill steps (round-5: fused decode rows cost
-        a full prefill-row budget each). With ``prefill_pending`` the
+        windows with prefill steps (which carry the decoders one token
+        each, as their decode block). With ``prefill_pending`` the
         window is capped at ``decode_window_mixed_cap`` so a waiting
         chunk (TTFT) is never stuck behind a full-length window — the
         alternation still hands decoders a window every other dispatch,
@@ -1758,9 +1857,13 @@ class InferenceEngineV2:
     def _dispatch_next(self) -> bool:
         """Dispatch the next scheduled step without blocking. Returns True
         if something was dispatched. Mixed prefill/decode load alternates
-        pure prefill steps with decode windows (or [S,1] decode plans when
-        windowing is off) — each kind runs at full useful occupancy.
-        With ``spec_decode`` configured, the decode side of the
+        prefill steps with decode windows (or [S,1] decode plans when
+        windowing is off); a prefill step's program also runs the
+        decode-ready rows, one token each, as its decode block
+        (``StepPlan.block``: what the scheduler saw in its queue — the
+        program is the same whoever is live), so the decoders do not wait
+        out the weights a chunk streams anyway.
+        With ``spec_decode`` configured (prefill steps then stay pure), the decode side of the
         alternation first offers the step to the speculative path — a
         verify round replaces up to depth+1 serial decode steps; when no
         proposer finds candidates the window/plain path runs as before."""
@@ -1803,17 +1906,9 @@ class InferenceEngineV2:
                               seq=self._entry_seq):
             fn = self._program(T, plan.token_ids.shape[0])
             self._rng, sub = jax.random.split(self._rng)
-            # THE site that turns a plan into a tuple a kind of layer
-            # (``self._kinds``' order): the plan holds the primary's slot
-            # map and table itself and every further kind's in ``more``
-            of_kind = {self._kinds[0].name: (plan.slot_map,
-                                             plan.block_tables), **plan.more}
-            slot_maps, tables = zip(*(of_kind[k.name] for k in self._kinds))
             self.kv_pool, self._last_tok, toks = fn(
                 self.params, self.kv_pool, self._last_tok,
-                plan.token_ids, plan.positions, slot_maps, tables,
-                plan.seq_lens, plan.sample_idx,
-                plan.do_sample, plan.use_last, plan.row_slots, sub)
+                *self._plan_args(plan), sub)
         self.scheduler.mark_dispatched(plan)
         toks.copy_to_host_async()
         self._enqueue({"kind": "plan", "plan": plan, "toks": toks,
@@ -1821,12 +1916,26 @@ class InferenceEngineV2:
         self.stats["dispatch_s"] += time.perf_counter() - t0
         self.stats["dispatches"] += 1
         n_tok = int(plan.active.sum())
-        self._count_moe(n_tok, plan.token_ids.size)
+        # the decode block: its tokens are decode tokens that no decode
+        # step or window iteration made (their device time is the prefill
+        # program's), and its rows are rows of the program's routed layers
+        # and steps of its paged kernel's decode form
+        n_block = 0 if plan.block is None else int(plan.block.active.sum())
+        self._count_moe(n_tok + n_block, plan.token_ids.size
+                        + (self.state.max_seqs if T > 1 else 0))
         self._count_attn_steps(plan.seq_lens, plan.positions[:, 0],
                                stage_rows(T, bs))
+        if plan.block is not None:
+            self._count_attn_steps(plan.block.seq_lens,
+                                   plan.block.positions[:, 0],
+                                   stage_rows(1, bs))
         if plan.kind == "prefill":
             self.stats["prefill_steps"] += 1
             self.stats["prefill_tokens"] += n_tok
+            self.stats["fused_steps" if n_block
+                       else "fused_empty_steps"] += 1
+            self.stats["fused_decode_tokens"] += n_block
+            self.stats["decode_tokens"] += n_block
             if "conv_chunks" in self.stats:
                 live = np.asarray(plan.uids) >= 0
                 self.stats["conv_chunks"] += int(live.sum())
@@ -1936,8 +2045,7 @@ class InferenceEngineV2:
                                        window=True)
             return
         plan = entry["plan"]
-        sampled = {uid: int(toks_h[s]) for s, uid in enumerate(plan.uids)
-                   if uid >= 0 and plan.do_sample[s]}
+        sampled = {uid: int(toks_h[r]) for r, uid in plan.sampled_rows()}
         accepted = self.scheduler.commit(plan, sampled)
         for uid, new in accepted.items():   # stop criteria may drop tokens
             if new:
@@ -2034,7 +2142,7 @@ class InferenceEngineV2:
     def _uid_inflight(self, uid: int) -> bool:
         for entry in self._inflight:
             uids = entry["sched"] if entry["kind"] == "window" \
-                else entry["plan"].uids
+                else entry["plan"].all_uids
             if uid in uids:
                 return True
         return False

@@ -32,10 +32,11 @@ import dataclasses
 from contextlib import nullcontext
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Mapping
+from typing import Any, Mapping, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -282,6 +283,41 @@ def stage_rows(n: int, block_size: int) -> int:
     return rows if rows <= block_size else -(-rows // block_size) * block_size
 
 
+class DecodeBlock(NamedTuple):
+    """The decode-ready rows that ride a prefill step: a second, ``[B, 1]``
+    segment of the same forward (``B`` = ``max_seqs``: row ``b`` is slot
+    ``b``, whoever is live), one token a row at the row's own position
+    against its own tables — what a ``[max_seqs, 1]`` decode plan holds."""
+    token_ids: Any                 # [B, 1]
+    positions: Any                 # [B, 1]
+    block_tables: tuple            # a kind: [B, width] (a record kind: [B])
+    seq_lens: Any                  # [B], this token included (0: no
+    #                                request in the row: it reaches no
+    #                                routed expert, as a plan's empty row)
+
+
+@dataclass(frozen=True)
+class _Segment:
+    """What is per SEQUENCE in one ``[S, T]`` rectangle of a forward's
+    tokens: attention, a conv layer's record and the staged K/V run a
+    segment with these; everything per token runs on the segments' tokens
+    together."""
+    S: int
+    T: int
+    Ts: int                        # rows of its staged K/V
+    positions: Any                 # [S, T]
+    seq_lens: Any                  # [S]
+    q_starts: Any                  # [S] each row's first position
+    stage_starts: Any              # [S]
+    block_tables: tuple
+    n_valid: Any                   # [S] tokens of each row that count
+    fresh_row: Any                 # [S, 1, 1] the row starts its sequence
+    #                                (a record kind's row has no past: zeros)
+    sample_idx: Any                # [S]
+    attn_works: tuple              # the paged kernel's work list a kind
+    empty_stage: tuple
+
+
 @dataclass(frozen=True, eq=False)
 class RaggedForward:
     """The serving forward of one engine (reads the TransformerLM param tree
@@ -437,7 +473,7 @@ class RaggedForward:
 
     def __call__(self, params, kv_pools, token_ids, positions, block_tables,
                  seq_lens, sample_idx, kv_stage=None, stage_fill=None,
-                 stage_starts=None, tree_mask=None, live=None):
+                 stage_starts=None, tree_mask=None, live=None, block=None):
         """One ragged forward over READ-ONLY pools; returns ``((k_ys, v_ys),
         logits)`` and never writes a pool.
 
@@ -490,6 +526,21 @@ class RaggedForward:
         — geometry gates on top of the decode gate); the XLA gather
         formulation is the counted fallback. Tree mode never rings
         (all-position logits need the full residual stream).
+
+        ``block`` (a :class:`DecodeBlock`; default mode only): the
+        decode-ready rows ride this call as a SECOND segment, ``[B, 1]``
+        beside the ``[S, T]`` one, and the layers are walked once over
+        both. What is per TOKEN (norms, the q/k/v and output projections,
+        the feed-forward, router + dispatch + grouped GEMM + combine, the
+        head) runs on the two segments' tokens concatenated, so each weight
+        is read once; what is per SEQUENCE (rope's positions aside: the
+        staged K/V, the paged attention — the prefill form for one segment,
+        the decode form for the other — a conv layer's record) runs a
+        segment, with that segment's own positions, lengths, tables and
+        work list. The return is then a pair of the form above, one a
+        segment: ``(((k_ys, v_ys), logits), ((k_ys, v_ys), logits))`` —
+        the block's as a ``[B, 1]`` step's, for the caller to merge by
+        :func:`merge_step` with ``T`` 1.
         """
         m, cfg, kinds = self.mcfg, self.config, self.kinds
         S, T = token_ids.shape
@@ -504,23 +555,78 @@ class RaggedForward:
                     for name in set(m.kinds)}
         period = m.kinds_period
         tree_mode = tree_mask is not None
-        q_starts = positions[:, 0]
-        if stage_starts is None:
-            stage_starts = q_starts
+        fused = block is not None
+        if fused and (window_mode or tree_mode):
+            raise ValueError("a decode block rides a step plan's forward: "
+                             "not a window's, not a tree's")
         if window_mode:
             kbufs, vbufs = kv_stage
-            Ts = kbufs[0].shape[3]
-        else:
-            Ts = stage_rows(T, bs)
-        #: tokens of each row that count in this call
-        n_valid = jnp.ones_like(seq_lens) if window_mode \
-            else seq_lens - q_starts
-        if CONV in cache_of:
-            #: whether a record kind's row starts its sequence (no past:
-            #: zeros)
-            fresh_row = (q_starts == 0)[:, None, None]
+        # kernel-vs-gather comes from the attention registry's static
+        # per-mode selection (attn_registry.py) — the ONLY dispatch
+        # decision point, pinned by check_attn_registry in
+        # bin/check_state_invariants.py
+        sel = self.attn_tree_sel if tree_mode else self.attn_decode_sel
+
+        def segment(positions, block_tables, seq_lens, sample_idx,
+                    stage_starts=None):
+            S, T = positions.shape
+            q_starts = positions[:, 0]
+            if stage_starts is None:
+                stage_starts = q_starts
+            Ts = kbufs[0].shape[3] if window_mode else stage_rows(T, bs)
+            # the paged kernel's steps, the same for every layer: built
+            # here, outside the layer loop (one list a kind of layer: a
+            # window kind's is bounded by its window, over its own table)
+            works = [()] * len(kinds)
+            if sel.is_pallas:
+                with device_scope("attn_core"):
+                    works = [() if k.is_record else paged_work_list(
+                        seq_lens, q_starts, stage_starts, block_size=bs,
+                        max_pages=block_tables[c].shape[1], stage_rows=Ts,
+                        window=k.window, ring_tokens=k.ring_tokens,
+                        tree=tree_mode) for c, k in enumerate(kinds)]
+            return _Segment(
+                S, T, Ts, positions, seq_lens, q_starts, stage_starts,
+                block_tables,
+                jnp.ones_like(seq_lens) if window_mode
+                else seq_lens - q_starts, (q_starts == 0)[:, None, None],
+                sample_idx, tuple(works),
+                (jnp.zeros((S, KVp, Ts, Dp), cfg.dtype),) * 2)
+
+        seg0 = segment(positions, block_tables, seq_lens, sample_idx,
+                       stage_starts)
         if live is None and not (window_mode or tree_mode):
-            live = jnp.arange(T)[None] < n_valid[:, None]
+            live = jnp.arange(T)[None] < seg0.n_valid[:, None]
+        segs = [seg0]
+        if fused:
+            segs.append(segment(block.positions, block.block_tables,
+                                block.seq_lens,
+                                jnp.zeros_like(block.seq_lens)))
+            # the token stream of everything per token: ``[1, N]``, the
+            # segments' tokens one after the other
+            cat = lambda a, b: jnp.concatenate(
+                [a.reshape(1, -1), b.reshape(1, -1)], axis=1)
+            token_ids = cat(token_ids, block.token_ids)
+            positions = cat(positions, block.positions)
+            live = cat(live, segs[1].n_valid[:, None] > 0)
+        ends = [0]
+        for g in segs:
+            ends.append(ends[-1] + g.S * g.T)
+        #: tokens in this call, and the rows its stream is split by
+        N = ends[-1]
+
+        def to_segs(y):
+            """A per-token array as its segments' ``[S, T, ...]``."""
+            if not fused:
+                return [y]
+            return [y[0, a:b].reshape(g.S, g.T, *y.shape[2:])
+                    for g, a, b in zip(segs, ends, ends[1:])]
+
+        def from_segs(parts):
+            if not fused:
+                return parts[0]
+            return jnp.concatenate(
+                [y.reshape(1, -1, *y.shape[2:]) for y in parts], axis=1)
 
         # ring collective-matmul TP: static per program — the token-sharded
         # residual stream needs the row dim to divide the tensor axis
@@ -531,9 +637,9 @@ class RaggedForward:
         # weight re-reads for a tiny hidden collective; tp_overlap=True
         # overrides for measurement)
         rn = self.tp_ring_n
-        if rn and (tree_mode or S % rn or not (
+        if rn and (tree_mode or (N if fused else S) % rn or not (
                 self.tp_ring_force
-                or (S * T) // rn >= TP_OVERLAP_MIN_ROWS)):
+                or N // rn >= TP_OVERLAP_MIN_ROWS)):
             overlap_counters.fallback()
             rn = 0
         mesh_t = self.topology.mesh
@@ -583,15 +689,15 @@ class RaggedForward:
                 w = qstack[f"attn/{name}"]
             if isinstance(w, QuantLinear):
                 y = self.qmm(h.reshape(-1, h.shape[-1]), w, name, li=li)
-                return y.reshape(S, T, nh, -1).astype(cfg.dtype)
+                return y.reshape(*h.shape[:2], nh, -1).astype(cfg.dtype)
             return jnp.einsum("ste,ehd->sthd", h, w.astype(cfg.dtype))
 
         def proj_out(o, w, li=None):
             if w is None:
                 w = qstack["attn/wo"]
             if isinstance(w, QuantLinear):
-                y = self.qmm(o.reshape(S * T, -1), w, "wo", li=li)
-                return y.reshape(S, T, -1).astype(cfg.dtype)
+                y = self.qmm(o.reshape(N, -1), w, "wo", li=li)
+                return y.reshape(*o.shape[:2], -1).astype(cfg.dtype)
             return jnp.einsum("sthd,hde->ste", o, w.astype(cfg.dtype))
 
         with device_scope("embed"):
@@ -605,7 +711,8 @@ class RaggedForward:
             # residual adds run 1/tp-sized per chip; the projections put
             # the gather/scatter back via overlapped ring primitives
             x = jax.lax.with_sharding_constraint(
-                x, NamedSharding(mesh_t, P("tensor", None, None)))
+                x, NamedSharding(mesh_t, P(None, "tensor", None) if fused
+                                 else P("tensor", None, None)))
 
         def routed_experts(ml, h, li, h_router=None):
             """THE routed-expert layer of serving, quantised or not: router
@@ -626,7 +733,7 @@ class RaggedForward:
             doesn't (tests/test_moe.py::
             test_capacity_divergence_v1_drops_v2_routes_all)."""
             mo = m.moe
-            Tt, E = S * T, h.shape[-1]
+            Tt, E = N, h.shape[-1]
             flat = h.reshape(Tt, E).astype(cfg.dtype)
             # what the router reads, where that is not what the experts
             # read (``MoEConfig.router_input``)
@@ -672,7 +779,7 @@ class RaggedForward:
                 flat, gate.gates[0], gate.experts[0], mo.num_experts,
                 mo.top_k, bm, gemm,
                 live=None if live is None else live.reshape(Tt))
-            return out.reshape(S, T, E).astype(cfg.dtype)
+            return out.reshape(h.shape).astype(cfg.dtype)
 
         def ffn(p, h, use_moe: bool, li=None, h_router=None):
             if use_moe and rn:
@@ -722,7 +829,7 @@ class RaggedForward:
                 # dense layers of a mixed MoE stack may carry their own
                 # intermediate size — ring only when it divides the axis
                 if isinstance(wu, QuantLinear) or wu.shape[1] % rn == 0:
-                    h2 = h.reshape(S * T, -1)
+                    h2 = h.reshape(N, -1)
                     if m.activation in GLU_ACTS:
                         g2, u2 = allgather_matmul(
                             h2, (fwr("w_gate"), wu), mesh_t, layer_index=li)
@@ -734,7 +841,7 @@ class RaggedForward:
                     y2 = matmul_reduce_scatter(
                         z.astype(cfg.dtype), fwr("w_down"), mesh_t,
                         layer_index=li)
-                    out = y2.reshape(S, T, -1).astype(cfg.dtype)
+                    out = y2.reshape(*h.shape[:2], -1).astype(cfg.dtype)
                     if m.activation not in GLU_ACTS:
                         out = out + f["b_down"].astype(cfg.dtype)
                     return out
@@ -762,30 +869,36 @@ class RaggedForward:
 
         def attention(p, li, h, stage_l, kind, c, lk):
             """QKV → write into the STAGED buffer → ragged attention over
-            the read-only pool pages + the stage. Returns (o, stage_l').
-            ``kind``: the layer's kind (static); ``c`` its cache among
-            ``kinds``; ``lk`` the layer's index inside that cache's pool."""
+            the read-only pool pages + the stage, a segment. Returns (o,
+            stage_l'). ``kind``: the layer's kind (static); ``c`` its cache
+            among ``kinds``; ``lk`` the layer's index inside that cache's
+            pool."""
             a = p["attn"]
             qli = li if qstack else None
             with device_scope("attn_qkv"):
                 q, k, v = qkv(a, qli, h, kind)
                 if pk > 1:
                     q, k, v = pack_heads(q, k, v, pk)
-            with device_scope("kv_stage"):
-                stage_l = stage(k, v, stage_l)
-            # window and global layers told apart, inside ``attn_core``,
-            # where a model has both (a model of one kind keeps the scope
-            # table it always had)
-            sub = nullcontext()
-            if len(kinds) > 1:
-                sub = device_scope("attn_window") if kinds[c].window \
-                    else device_scope("attn_full")
-            with device_scope("attn_core"), sub:
-                o = core(c, lk, q, stage_l)
+            outs, stages = [], []
+            for g, q_g, k_g, v_g, stage_g in zip(segs, to_segs(q), to_segs(k),
+                                                 to_segs(v), stage_l):
+                with device_scope("kv_stage"):
+                    stage_g = stage(g, k_g, v_g, stage_g)
+                # window and global layers told apart, inside
+                # ``attn_core``, where a model has both (a model of one
+                # kind keeps the scope table it always had)
+                sub = nullcontext()
+                if len(kinds) > 1:
+                    sub = device_scope("attn_window") if kinds[c].window \
+                        else device_scope("attn_full")
+                with device_scope("attn_core"), sub:
+                    outs.append(core(g, c, lk, q_g, stage_g))
+                stages.append(stage_g)
             with device_scope("attn_out"):
+                o = from_segs(outs)
                 if pk > 1:
                     o = unpack_heads(o, KV, pk)
-                return out_proj(a, qli, o), stage_l
+                return out_proj(a, qli, o), tuple(stages)
 
         def qkv(a, qli, h, kind):
             if rn:
@@ -802,11 +915,11 @@ class RaggedForward:
                     w2 = wv.astype(cfg.dtype)
                     return w2.reshape(w2.shape[0], -1)
                 q2, k2, v2 = allgather_matmul(
-                    h.reshape(S * T, -1), (aw("wq"), aw("wk"), aw("wv")),
+                    h.reshape(N, -1), (aw("wq"), aw("wk"), aw("wv")),
                     mesh_t, layer_index=qli)
-                q = q2.reshape(S, T, H, -1).astype(cfg.dtype)
-                k = k2.reshape(S, T, KV, -1).astype(cfg.dtype)
-                v = v2.reshape(S, T, KV, -1).astype(cfg.dtype)
+                q = q2.reshape(*h.shape[:2], H, -1).astype(cfg.dtype)
+                k = k2.reshape(*h.shape[:2], KV, -1).astype(cfg.dtype)
+                v = v2.reshape(*h.shape[:2], KV, -1).astype(cfg.dtype)
             else:
                 q = proj_in(h, a["wq"], H, "wq", li=qli)
                 k = proj_in(h, a["wk"], KV, "wk", li=qli)
@@ -822,8 +935,8 @@ class RaggedForward:
                 q, k = apply_rope(q, k, positions, m.rope_theta, m.rotary_pct)
             return q, k, v
 
-        def stage(k, v, stage_l):
-            """This step's K/V into the staged buffers."""
+        def stage(g, k, v, stage_l):
+            """This step's K/V of segment ``g`` into its staged buffers."""
             k_t = k.transpose(0, 2, 1, 3).astype(cfg.dtype)  # [S,KV,T,D]
             v_t = v.transpose(0, 2, 1, 3).astype(cfg.dtype)
             if window_mode:
@@ -833,23 +946,27 @@ class RaggedForward:
                 v_st = jax.lax.dynamic_update_slice(
                     v_st, v_t, (0, 0, stage_fill, 0))
             else:
-                pad = [(0, 0), (0, 0), (0, Ts - T), (0, 0)]
+                pad = [(0, 0), (0, 0), (0, g.Ts - g.T), (0, 0)]
                 k_st = jnp.pad(k_t, pad)
                 v_st = jnp.pad(v_t, pad)
             return k_st, v_st
 
-        def core(c, lk, q, stage_l):
-            """Ragged attention over the pool pages + the stage: the Pallas
-            kernel, or the XLA gather fallback — over cache ``c``'s pool
-            and block table, at layer ``lk`` of that pool."""
+        def core(g, c, lk, q, stage_l):
+            """Ragged attention of segment ``g`` over the pool pages + the
+            stage: the Pallas kernel, or the XLA gather fallback — over
+            cache ``c``'s pool and block table, at layer ``lk`` of that
+            pool."""
             k_st, v_st = stage_l
+            S, T, Ts, positions = g.S, g.T, g.Ts, g.positions
+            seq_lens, q_starts = g.seq_lens, g.q_starts
+            stage_starts = g.stage_starts
             # Sliding windows mask on every path; a window kind also serves
             # from a ROLLING block table (ring_tokens > 0) so out-of-window
             # KV blocks are reused instead of pinned.
             win = kinds[c].window
             ring = kinds[c].ring_tokens
-            ro_pool, table = kv_pools[c], block_tables[c]
-            attn_work = attn_works[c]
+            ro_pool, table = kv_pools[c], g.block_tables[c]
+            attn_work = g.attn_works[c]
             ctx = table.shape[1] * bs
             li_dev = jnp.asarray(lk, jnp.int32)
             if sel.is_pallas:
@@ -973,8 +1090,8 @@ class RaggedForward:
                 if not isinstance(wo, QuantLinear):
                     wo = wo.astype(cfg.dtype).reshape(-1, wo.shape[-1])
                 o2 = matmul_reduce_scatter(
-                    o.reshape(S * T, -1), wo, mesh_t, layer_index=qli)
-                o = o2.reshape(S, T, -1).astype(cfg.dtype)
+                    o.reshape(N, -1), wo, mesh_t, layer_index=qli)
+                o = o2.reshape(*o.shape[:2], -1).astype(cfg.dtype)
             else:
                 o = proj_out(o, a["wo"], li=qli)
             if m.attn_out_bias:
@@ -986,16 +1103,20 @@ class RaggedForward:
                 return Norm(m).apply({"params": p_ln}, x)
 
         def layer(x, p, li, use_moe, stage_l, kind, lk):
-            """``stage_l``: the layer's staged K/V — or, for a "conv"
-            layer, the record it starts from; returned advanced."""
+            """``stage_l``: a segment, the layer's staged K/V — or, for a
+            "conv" layer, the record each row starts from (zeros for a row
+            that starts its sequence: no past); returned advanced."""
             qli = li if qstack else None
             h_attn = norm(p["ln_attn"], x)
             if kind == CONV:
                 with device_scope("conv_mix"):
-                    o, stage_l = conv_mix(
-                        m, p["conv"], h_attn,
-                        jnp.where(fresh_row, 0, stage_l).astype(cfg.dtype),
-                        n_valid)
+                    mixed = [conv_mix(
+                        m, p["conv"], h_g,
+                        jnp.where(g.fresh_row, 0, rec).astype(cfg.dtype),
+                        g.n_valid)
+                        for g, h_g, rec in zip(segs, to_segs(h_attn), stage_l)]
+                    o = from_segs([o_g for o_g, _ in mixed])
+                    stage_l = tuple(rec for _, rec in mixed)
             else:
                 o, stage_l = attention(p, li, h_attn, stage_l, kind,
                                        cache_of[kind], lk)
@@ -1011,24 +1132,7 @@ class RaggedForward:
                     f = ffn(p, h_ffn, False, qli)
             return (x + o + f if m.parallel_block else x + f), stage_l
 
-        # kernel-vs-gather comes from the attention registry's static
-        # per-mode selection (attn_registry.py) — the ONLY dispatch
-        # decision point, pinned by check_attn_registry in
-        # bin/check_state_invariants.py
-        sel = self.attn_tree_sel if tree_mode else self.attn_decode_sel
-        # the paged kernel's steps, the same for every layer: built here,
-        # outside the layer loop (`core` closes over both)
-        # (one list a kind of layer: a window kind's is bounded by its
-        # window, over its own table)
-        attn_works = [()] * len(kinds)
-        if sel.is_pallas:
-            with device_scope("attn_core"):
-                attn_works = [() if k.is_record else paged_work_list(
-                    seq_lens, q_starts, stage_starts, block_size=bs,
-                    max_pages=block_tables[c].shape[1], stage_rows=Ts,
-                    window=k.window, ring_tokens=k.ring_tokens,
-                    tree=tree_mode) for c, k in enumerate(kinds)]
-        empty_stage = (jnp.zeros((S, KVp, Ts, Dp), cfg.dtype),) * 2
+        empty_stages = tuple(g.empty_stage for g in segs)
         P_ = len(period)
         if "layers_stacked" in params:
             if CONV in cache_of:
@@ -1056,39 +1160,46 @@ class RaggedForward:
             def body(xc, p, li, stage_l, j):
                 c, r = place[j]
                 return layer(xc, p, li, is_moe_layer(m, 0),
-                             stage_l if window_mode else empty_stage,
+                             (stage_l,) if window_mode else empty_stages,
                              period[j], (li // P_) * n_in[c] + r)
 
             x, ys = scan_layers(scanned_layers, x, body, P_, xs)
-            k_ys, v_ys = [], []
-            for c in range(len(kinds)):
-                for out, half in ((k_ys, 0), (v_ys, 1)):
-                    y = jnp.stack([ys[j][half] for j in range(P_)
-                                   if place[j][0] == c], axis=1)
-                    out.append(y.reshape(-1, *y.shape[2:]))
+            # ``fresh[gi]``: segment gi's (k_ys, v_ys), a kind each
+            fresh = []
+            for gi in range(len(segs)):
+                k_ys, v_ys = [], []
+                for c in range(len(kinds)):
+                    for out, half in ((k_ys, 0), (v_ys, 1)):
+                        y = jnp.stack([ys[j][gi][half] for j in range(P_)
+                                       if place[j][0] == c], axis=1)
+                        out.append(y.reshape(-1, *y.shape[2:]))
+                fresh.append((tuple(k_ys), tuple(v_ys)))
         else:
-            lists = [([], []) for _ in kinds]
+            lists = [[([], []) for _ in kinds] for _ in segs]
             for i in range(m.num_layers):
                 use_moe = is_moe_layer(m, i)
                 c = cache_of[m.layer_kind(i)]
                 lk = kinds[c].layers.index(i)
                 if kinds[c].is_record:
-                    # the record the row starts from: the running one of a
+                    # the record each row starts from: the running one of a
                     # window, else its slot's (``block_tables[c]``: slots)
-                    rec = kbufs[c][lk] if window_mode \
-                        else kv_pools[c][lk][block_tables[c]]
-                    x, rec = layer(x, params[f"layer_{i}"], i, use_moe,
-                                   rec, CONV, lk)
-                    lists[c][0].append(rec)
+                    recs = (kbufs[c][lk],) if window_mode else tuple(
+                        kv_pools[c][lk][g.block_tables[c]] for g in segs)
+                    x, recs = layer(x, params[f"layer_{i}"], i, use_moe,
+                                    recs, CONV, lk)
+                    for of_seg, rec in zip(lists, recs):
+                        of_seg[c][0].append(rec)
                     continue
-                stage_l = (kbufs[c][lk], vbufs[c][lk]) if window_mode \
-                    else empty_stage
+                stage_l = ((kbufs[c][lk], vbufs[c][lk]),) if window_mode \
+                    else empty_stages
                 x, stage_l = layer(x, params[f"layer_{i}"], i, use_moe,
                                    stage_l, m.layer_kind(i), lk)
-                lists[c][0].append(stage_l[0])
-                lists[c][1].append(stage_l[1])
-            k_ys = [jnp.stack(ks) for ks, _ in lists]
-            v_ys = [jnp.stack(vs) if vs else None for _, vs in lists]
+                for of_seg, (k_st, v_st) in zip(lists, stage_l):
+                    of_seg[c][0].append(k_st)
+                    of_seg[c][1].append(v_st)
+            fresh = [(tuple(jnp.stack(ks) for ks, _ in of_seg),
+                      tuple(jnp.stack(vs) if vs else None
+                            for _, vs in of_seg)) for of_seg in lists]
 
         def head(x):
             x = Norm(m).apply({"params": params["ln_final"]}, x)
@@ -1097,9 +1208,11 @@ class RaggedForward:
                 # logits ([S*T, E] rows through the same projection paths)
                 last = x.reshape(S * T, -1)
             else:
-                last = jnp.take_along_axis(
-                    x, sample_idx[:, None, None].astype(jnp.int32),
-                    axis=1)[:, 0]                                      # [S,E]
+                # each segment's sampled rows, one after the other: [rows, E]
+                last = [jnp.take_along_axis(
+                    x_g, g.sample_idx[:, None, None].astype(jnp.int32),
+                    axis=1)[:, 0] for g, x_g in zip(segs, to_segs(x))]
+                last = jnp.concatenate(last) if fused else last[0]
             if rn:
                 # leave the token-sharded stream: the logits projection reads
                 # S rows total — replicating them is noise next to the weight
@@ -1133,8 +1246,9 @@ class RaggedForward:
         with device_scope("head"):
             logits = head(x)
         # NO pool write here: the caller merges, once a program
-        return (tuple(k_ys), tuple(v_ys)), \
-            (logits.reshape(S, T, -1) if tree_mode else logits)
+        if fused:
+            return (fresh[0], logits[:S]), (fresh[1], logits[S:])
+        return fresh[0], (logits.reshape(S, T, -1) if tree_mode else logits)
 
 
 def merge_records(records, write_slots, new):
@@ -1221,13 +1335,25 @@ def merge_rows(kv_pool, flat_slots, k_rows, v_rows):
     (decode plans: S; windows: W*S)."""
     with device_scope("kv_commit"):
         bs = kv_pool.shape[4]
-        kv_rows = jnp.stack([k_rows, v_rows], axis=1).astype(kv_pool.dtype)
-        z = jnp.int32(0)
+        # [L,2,KV,N,1,D]: row n's update is ONE static slice of it, and its
+        # block and offset one element each of two vectors worked out once.
+        # The loop is unrolled and a window or a decode block merges
+        # hundreds of rows: with the division and the indexing inside it a
+        # row was ~30 equations to trace, lower and hash at every start,
+        # warm ones too (``PERF.md`` section 6, PR 52: +19 s of a 97 s
+        # set-up until this form)
+        kv_rows = jnp.stack([k_rows, v_rows], axis=1).astype(
+            kv_pool.dtype).transpose(0, 1, 3, 2, 4)[:, :, :, :, None, :]
+        blk, off = flat_slots // bs, flat_slots % bs
+        z = np.int32(0)
         for n in range(flat_slots.shape[0]):
-            upd = kv_rows[:, :, n][:, :, :, None, None, :]  # [L,2,KV,1,1,D]
-            kv_pool = jax.lax.dynamic_update_slice(
-                kv_pool, upd,
-                (z, z, z, flat_slots[n] // bs, flat_slots[n] % bs, z))
+            # (the primitive itself: ``lax.dynamic_update_slice`` wraps
+            # every index round that might be negative, three equations
+            # an index; a flat slot is not)
+            kv_pool = jax.lax.dynamic_update_slice_p.bind(
+                kv_pool, jax.lax.slice_in_dim(kv_rows, n, n + 1, axis=3),
+                z, z, z, jax.lax.index_in_dim(blk, n, keepdims=False),
+                jax.lax.index_in_dim(off, n, keepdims=False), z)
         return kv_pool
 
 

@@ -1103,3 +1103,23 @@ class StepPlan:
     #: (slot_map [S, T], block_tables [S, that kind's width])); empty for
     #: a model of one kind
     more: dict = field(default_factory=dict)
+    #: a PREFILL plan's decode block: the decode-ready sequences as a
+    #: ``[max_seqs, 1]`` decode plan that rides the same program as a
+    #: second segment (None: the program's block has no live row)
+    block: "StepPlan | None" = None
+
+    @property
+    def all_uids(self) -> list[int]:
+        """The uids of the plan's rows and of its block's."""
+        return self.uids if self.block is None \
+            else self.uids + self.block.uids
+
+    def sampled_rows(self) -> list[tuple[int, int]]:
+        """``(row, uid)`` of every row that samples, ``row`` its place in
+        the program's ``toks``: the plan's rows, then its block's."""
+        rows = [(r, uid) for r, uid in enumerate(self.uids)
+                if uid >= 0 and self.do_sample[r]]
+        if self.block is not None:
+            rows += [(len(self.uids) + r, uid)
+                     for r, uid in self.block.sampled_rows()]
+        return rows

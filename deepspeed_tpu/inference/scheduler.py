@@ -5,14 +5,17 @@ engine_v2.py:107, ``can_schedule`` :184) and the Dynamic SplitFuse policy
 from the FastGen blog — long prompts are decomposed into fixed-size chunks
 so every forward step has near-constant token count.
 
-TPU deviation (by design): FastGen packs prompt chunks and decode tokens
-into ONE ragged batch; under XLA's static shapes that would force a mixed
-layout padded to worst case. Instead the scheduler emits alternating
-fixed-shape steps — a prefill step ([max_seqs, chunk] prompt chunks) or a
-decode step ([max_seqs, 1]) — which hits the same goal (constant per-step
-work, no long-prompt head-of-line blocking) with exactly two compiled
-programs. Prefill is prioritized when chunks are pending; decodes for
-already-running sequences batch together.
+TPU formulation: FastGen packs prompt chunks and decode tokens into ONE
+ragged batch; under XLA's static shapes a decode token in a ``[rows,
+chunk]`` rectangle would occupy a whole padded row. So a step is two
+fixed-shape SEGMENTS of one forward: the prompt chunks (``[rows, chunk]``)
+and, riding every prefill step, the DECODE BLOCK — the decode-ready
+sequences as a ``[max_seqs, 1]`` plan of its own (``StepPlan.block``), at
+its full static width whoever is live, so the compiled menu does not know
+it. The weights are read once for both (``inference/forward.py``). With no
+chunk pending the engine runs pure decode steps and windows; with chunks
+pending it alternates prefill steps (each worth one token to every
+decoder) with capped decode windows.
 """
 from __future__ import annotations
 
@@ -62,6 +65,11 @@ class SplitFuseScheduler:
         #: same convention full-width plans already use for idle rows.
         #: 1 = exact-k, no padding.
         self.row_multiple = 1
+        #: whether a prefill plan carries the decode-ready sequences as its
+        #: ``block``. The engine clears it where the decode side belongs
+        #: to speculative verify rounds; a block-less prefill plan runs
+        #: the same program with a block of no live row.
+        self.decode_rides = True
 
     def _desc(self, kind: str, T: int, entries,
               use_last_slots=(), n_rows: int | None = None) -> StepPlan:
@@ -305,15 +313,16 @@ class SplitFuseScheduler:
         still in flight carries a placeholder with ``use_last`` set — the
         program substitutes the device-resident last sampled token.
 
-        Mixed prefill/decode load ALTERNATES pure steps instead of fusing
-        decode rows into prefill plans (round-5 redesign: a fused decode
-        row occupied a full T-token row, holding long-mix prefill
-        occupancy to ~55%; the engine interleaves decode windows/steps so
-        decoders still see a token at least every other dispatch —
-        Dynamic SplitFuse's constant-work goal with PURE steps).
-        ``prefer="decode"`` emits the decode plan when both kinds of work
-        exist (the engine's alternation hint when the multi-iteration
-        window path is unavailable)."""
+        A PREFILL plan carries the decode-ready sequences as its
+        ``block``: the ``[max_seqs, 1]`` decode plan the branch below
+        would have built, run by the same program as a second segment
+        (round 5 had fused a decode row INTO the ``[S, T]`` rectangle,
+        where it occupied a full T-token row, and took that out; a
+        segment of its own costs no padded row). The engine still
+        interleaves capped decode windows between prefill steps.
+        ``prefer="decode"`` emits the pure decode plan when both kinds of
+        work exist (the engine's alternation hint when the
+        multi-iteration window path is unavailable)."""
         st = self.state
         prefill: list[SequenceDescriptor] = []
         decode: list[SequenceDescriptor] = []
@@ -322,11 +331,15 @@ class SplitFuseScheduler:
                 continue
             (prefill if seq.pending_sched > 1 else decode).append(seq)
 
-        def decode_entry(seq):
-            if seq.n_inflight:
-                # value lives only on device → placeholder + use_last
-                return (seq, [0], seq.kv_next, True)
-            return (seq, seq.tokens[-1:], seq.kv_next, True)
+        def decode_plan():
+            # a row whose last token is still in flight: the value lives
+            # only on device → placeholder + use_last
+            rows = decode[:st.max_seqs]
+            return self._desc(
+                "decode", 1,
+                [(seq, [0] if seq.n_inflight else seq.tokens[-1:],
+                  seq.kv_next, True) for seq in rows],
+                [seq.slot for seq in rows if seq.n_inflight])
 
         # blocks were reserved for prompt + max_new_tokens at admit time,
         # so neither branch can exhaust the pool here
@@ -362,13 +375,13 @@ class SplitFuseScheduler:
                 # sample only when this chunk consumes the last pending token
                 finishes = n == seq.pending_sched
                 entries.append((seq, toks, seq.kv_next, finishes))
-            return self._desc("prefill", T, entries, (), n_rows=n_rows)
+            plan = self._desc("prefill", T, entries, (), n_rows=n_rows)
+            if self.decode_rides:
+                plan.block = decode_plan()
+            return plan
 
         if decode:
-            entries = [decode_entry(seq) for seq in decode[:st.max_seqs]]
-            use_last = [seq.slot for seq in decode[:st.max_seqs]
-                        if seq.n_inflight]
-            return self._desc("decode", 1, entries, use_last)
+            return decode_plan()
         return None
 
     def mark_dispatched(self, plan: StepPlan) -> None:
@@ -377,6 +390,8 @@ class SplitFuseScheduler:
         readback-time half). Each real row lands one lifecycle event on
         its request timeline (reqtrace): the prefill chunk's token count
         and plan width, or the decode step."""
+        if plan.block is not None:
+            self.mark_dispatched(plan.block)
         rt = self._reqtrace
         trace = rt.enabled
         T = plan.token_ids.shape[1]
@@ -402,10 +417,12 @@ class SplitFuseScheduler:
         """Advance sequence state after a step ran. ``sampled``: uid → token
         for every slot that had do_sample. Returns uid → tokens actually
         ACCEPTED by each sequence's stop criteria (callers surface these,
-        never the raw samples)."""
+        never the raw samples). A plan's ``block`` commits with it (a
+        sequence is in one of the two or the other)."""
         st = self.state
         rt = self._reqtrace
-        accepted: dict[int, list[int]] = {}
+        accepted: dict[int, list[int]] = {} if plan.block is None \
+            else self.commit(plan.block, sampled)
         for s, uid in enumerate(plan.uids):
             if uid < 0:
                 continue

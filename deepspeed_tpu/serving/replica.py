@@ -1053,7 +1053,8 @@ class EngineBackend:
 
     def pipeline_line(self) -> str:
         """The engine's pipeline over this backend's life, from its
-        counters: what a worker logs when it leaves (an engine with routed
+        counters: what a worker logs when it leaves (where prefill steps
+        ran, what their decode blocks carried; an engine with routed
         experts adds what their sorts were handed)."""
         st = self.eng.stats
         n_in, n_out = st["entries_dispatched"], st["entries_committed"]
@@ -1067,6 +1068,10 @@ class EngineBackend:
             f"{n_pre}); replica step "
             f"{100 * (step_s - st['engine_step_s']) / max(step_s, 1e-9):.2f}"
             f" % outside the engine" + (
+                f"; fused: {st['fused_steps']} steps carried "
+                f"{st['fused_decode_tokens']} decode tokens, "
+                f"{st['fused_empty_steps']} carried none"
+                if st["prefill_steps"] else "") + (
                 f"; experts: {st['moe_routed_rows']} entries routed, "
                 f"{st['moe_masked_rows']} masked out of the sort, "
                 f"{st['moe_padded_rows']} buffer rows"

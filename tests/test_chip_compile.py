@@ -398,6 +398,10 @@ ROUTED_LAYERS = {
         (512, {**THINKER_MOE, "masked": True}), "dense"),
     "routed_layer_olmoe_rows2048_masked": ((2048, {"masked": True}),
                                            "gather"),
+    # a (512, 1) prefill program's stream since PR 52: the chunk's tokens
+    # and the decode block's 48 rows together, past the one-hot fill
+    "routed_layer_thinker_chunk512_block48_masked": (
+        (560, {**THINKER_MOE, "masked": True}), "gather"),
 }
 
 

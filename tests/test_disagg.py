@@ -132,8 +132,7 @@ def _decode_ready(st, sched, uid, prompt, gen_budget=6, first_tok=7):
     seq = st.seqs[uid]
     while seq.pending_tokens > 1 or seq.n_generated < 1:
         p = sched.next_step()
-        sampled = {u: first_tok for s, u in enumerate(p.uids)
-                   if u >= 0 and p.do_sample[s]}
+        sampled = {u: first_tok for _, u in p.sampled_rows()}
         sched.commit(p, sampled)
     return seq
 
@@ -246,8 +245,7 @@ def test_migration_refusals():
     # done -> refused
     while not seq.done:
         p = sched.next_step()
-        sched.commit(p, {u: 7 for s, u in enumerate(p.uids)
-                         if u >= 0 and p.do_sample[s]})
+        sched.commit(p, {u: 7 for _, u in p.sampled_rows()})
     with pytest.raises(RuntimeError, match="done"):
         st.migrate_out(1)
     st.release(1)

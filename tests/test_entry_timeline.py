@@ -140,6 +140,10 @@ def test_replica_step_books_its_own_time_and_says_so():
     st = backend.eng.stats
     assert done == 2 and st["replica_step_s"] >= st["engine_step_s"] > 0.0
     line = backend.pipeline_line()
+    # (prefill steps ran: what their decode blocks carried follows)
     assert line.startswith("pipeline: depth ") and line.endswith(
-        " % outside the engine")
+        f" % outside the engine; fused: {st['fused_steps']} steps carried "
+        f"{st['fused_decode_tokens']} decode tokens, "
+        f"{st['fused_empty_steps']} carried none")
+    assert st["fused_steps"] + st["fused_empty_steps"] == st["prefill_steps"]
     assert f"over {st['entries_committed']} entries (prefill " in line

@@ -526,8 +526,7 @@ def test_native_atom_builder_matches_python(monkeypatch):
             if p is None:
                 break
             out.append(p)
-            sampled = {uid: 42 + len(out) for s, uid in enumerate(p.uids)
-                       if uid >= 0 and p.do_sample[s]}
+            sampled = {uid: 42 + len(out) for _, uid in p.sampled_rows()}
             sched.commit(p, sampled)
         monkeypatch.undo()
         return out
@@ -754,8 +753,7 @@ def test_program_shape_menu_covers_scheduler_emissions(grow_chunk, max_rows):
                 bad = (n_real > 1) & (plan.slot_map[:, 0]
                                       % st.block_size != 0)
                 assert not bad.any()
-        sampled = {u: 7 for s_i, u in enumerate(plan.uids)
-                   if u >= 0 and plan.do_sample[s_i]}
+        sampled = {u: 7 for _, u in plan.sampled_rows()}
         sched.commit(plan, sampled)
         for u in [u for u, s in st.seqs.items() if s.done]:
             st.release(u)

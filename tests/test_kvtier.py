@@ -492,7 +492,7 @@ def test_eviction_under_pressure_demotes_and_adopt_promotes(tmp_path):
         if plan is None:
             break
         sched.mark_dispatched(plan)
-        sched.commit(plan, {u: 900 for u in plan.uids if u >= 0})
+        sched.commit(plan, {u: 900 for u in plan.all_uids if u >= 0})
         if st.seqs.get(1) is None or st.seqs[1].done:
             break
     st.release(1)                             # publishes 4 pages

@@ -242,10 +242,9 @@ class Tap:
                         c[slot] for c in calls[:n])
             else:
                 call, self.calls = self.calls[0], self.calls[1:]
-                plan = entry["plan"]
-                for r, uid in enumerate(plan.uids):
-                    if uid >= 0 and plan.do_sample[r]:
-                        self.rows.setdefault(uid, []).append(call[r])
+                # (a prefill program's rows, then its decode block's)
+                for r, uid in entry["plan"].sampled_rows():
+                    self.rows.setdefault(uid, []).append(call[r])
             return commit(entry, toks_h, emitted)
 
         eng._commit_entry = tapped

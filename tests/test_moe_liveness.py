@@ -71,8 +71,10 @@ class Record:
             else:
                 call, self.calls = self.calls[0], self.calls[1:]
                 plan = entry["plan"]
-                self.logits.append((call, (np.asarray(plan.uids) >= 0)
-                                    & plan.do_sample.astype(bool)))
+                # (a prefill program's rows, then its decode block's)
+                live = np.zeros(len(call), bool)
+                live[[r for r, _ in plan.sampled_rows()]] = True
+                self.logits.append((call, live))
                 self.kinds.append(plan.kind)
             return commit(entry, toks_h, emitted)
 

@@ -93,8 +93,7 @@ def _finish(st, sched, uid, toks=()):
         p = sched.next_step()
         assert p is not None, f"uid {uid} stuck (nothing schedulable)"
         sampled = {u: toks[min(st.seqs[u].n_generated, len(toks) - 1)]
-                   for s, u in enumerate(p.uids)
-                   if u >= 0 and p.do_sample[s]}
+                   for _, u in p.sampled_rows()}
         sched.commit(p, sampled)
 
 
@@ -342,8 +341,8 @@ def _run_trace(ops):
 
     def commit_oldest(P, tok):
         plan = P["inflight"].pop(0)
-        sampled = {u: tok for s, u in enumerate(plan.uids)
-                   if u >= 0 and plan.do_sample[s] and u in P["st"].seqs}
+        sampled = {u: tok for _, u in plan.sampled_rows()
+                   if u in P["st"].seqs}
         P["sched"].commit(plan, sampled)
 
     def apply(P, op):
@@ -366,7 +365,7 @@ def _run_trace(ops):
             live = sorted(st.seqs)
             if live:
                 uid = live[op[1] % len(live)]
-                while any(uid in p.uids for p in inflight):
+                while any(uid in p.all_uids for p in inflight):
                     commit_oldest(P, 0)
                 st.release(uid)
         elif kind == "spec":
@@ -379,7 +378,7 @@ def _run_trace(ops):
                      if not s.done and not s.frozen
                      and s.pending_tokens == 1
                      and s.max_new_tokens - s.n_generated > 1
-                     and not any(u in p.uids for p in inflight)]
+                     and not any(u in p.all_uids for p in inflight)]
             if cands:
                 uid = cands[pick % len(cands)]
                 seq = st.seqs[uid]
@@ -415,7 +414,7 @@ def _run_trace(ops):
             return
         uid = cands[op[1] % len(cands)]
         # the engine contract: drain in-flight plans referencing the uid
-        while any(uid in p.uids for p in A["inflight"]):
+        while any(uid in p.all_uids for p in A["inflight"]):
             commit_oldest(A, 0)
         seq = stA.seqs.get(uid)
         if seq is None or seq.done or seq.frozen \

@@ -139,8 +139,7 @@ def _decode_ready(st, sched, uid, first_tok=7):
     while st.seqs[uid].pending_tokens > 1 or not st.seqs[uid].n_generated:
         p = sched.next_step()
         assert p is not None
-        sampled = {u: first_tok for s, u in enumerate(p.uids)
-                   if u >= 0 and p.do_sample[s]}
+        sampled = {u: first_tok for _, u in p.sampled_rows()}
         sched.commit(p, sampled)
 
 
@@ -234,8 +233,7 @@ def test_rewind_longer_history_caps_budget_to_reservation():
     while not seq.done:                            # decode to exhaustion
         p = sched.next_step()
         assert p is not None
-        sched.commit(p, {u: 7 for s, u in enumerate(p.uids)
-                         if u >= 0 and p.do_sample[s]})
+        sched.commit(p, {u: 7 for _, u in p.sampled_rows()})
     assert len(seq.tokens) <= cap                  # never past the pages
     st.audit()
     st.release(1)
@@ -250,8 +248,7 @@ def test_rewind_never_rewrites_shared_prefix_pages():
     st.admit(1, list(range(8)), max_new_tokens=2)
     while not st.seqs[1].done:
         p = sched.next_step()
-        sched.commit(p, {u: 7 for s, u in enumerate(p.uids)
-                         if u >= 0 and p.do_sample[s]})
+        sched.commit(p, {u: 7 for _, u in p.sampled_rows()})
     st.release(1)                              # publishes pages [0:8]
     st.admit(2, list(range(8)) + [100, 101], max_new_tokens=4)
     assert st.seqs[2].n_shared_blocks == 2
