@@ -393,11 +393,12 @@ the spans of one entry share it.
 | `decode_tokens` | tokens generated by decode steps and by prefill steps' decode blocks (booked at dispatch) and decode windows (booked at commit) |
 | `fused_steps`, `fused_decode_tokens`, `fused_empty_steps` | the decode block of a prefill step (the decode-ready rows as a `[max_seqs, 1]` segment of the same program): steps whose block carried a decode token, the tokens that left that way — in `decode_tokens` too, in NO `decode_steps` / `window_iters`: their device time is `jit_step_prefill`'s — and steps whose block had no live row; booked at dispatch beside `prefill_steps` |
 | `replica_step_s`, `engine_step_s` | booked by `EngineBackend.step`: its own wall time, and `engine.step()`'s inside it |
-| `kv_blocks_live_<kind>`, `kv_blocks_peak_<kind>` | by PAGED kind of layer (`full`: a table that grows; `window`: a bounded ring): KV blocks live sequences hold, sampled after every dispatch (`StateManager.sample`), and the run's peak |
+| `kv_blocks_live_<kind>`, `kv_blocks_peak_<kind>` | by PAGED kind of layer (`full`: a table that grows; `window`: a bounded ring; `latent`: latent attention's ONE row `[c, k_r]` a token, a table that grows): blocks live sequences hold, sampled after every dispatch (`StateManager.sample`), and the run's peak |
 | `ring_blocks_reused` | ring slots a page past the window overwrote in place (`StateManager.note_written`, booked at dispatch) |
 | `attn_steps_live_<kind>`, `attn_steps_rect_<kind>` | `attn_steps_live` / `attn_steps_rect` split by kind of layer (each kind walks its own table) |
 | `attn_pages_unclipped`, `attn_pages_clipped` | pool pages a table that grew with the context would have walked in the window layers, and those of them the window kind did not walk |
 | `state_records_live`, `state_records_peak` | a RECORD kind's state (`conv`: the last `conv_taps - 1` inputs of a short convolution, one record a layer and slot, no pages): records live — one a sequence in a slot — sampled after every dispatch, and the run's peak; present only where the model has such layers |
+| `latent_rows_written` | latent attention (`kv_lora_rank`): rows `[c, k_r]` the dispatched programs were to write, a token a row whatever the layers — a plan's live tokens and its decode block's, a window's scheduled iterations a slot (host arithmetic at dispatch); present only where the model's paged kind is `latent`. The kernel's steps are booked in `attn_steps_*` (and `attn_steps_*_latent`) as the K/V kernel's are; its device time and the absorb's are the scopes `attn_core` and `latent_absorb` (`W_dkv`, the latent's norm, the rope key, `W_uk` folded into the query, `W_uv` after the weighted sum) |
 | `conv_chunks`, `conv_chunks_carried` | prefill rows dispatched, and those of them whose first position is past 0: they started from the record their sequence's last chunk left, not from zeros (booked on the host at dispatch) |
 | `moe_routed_rows`, `moe_padded_rows`, `attn_steps_*` | booked with the layers that HAVE the mechanism — the expert layers, the layers of each paged kind — not `num_layers` (a stack of unlike layers) |
 | `moe_masked_rows` | (token, choice) entries of dispatched programs whose row carried no request — an empty slot of a window, a chunk's padding — and which the expert sort's liveness mask therefore left out: `(rows x iterations - live tokens) x top_k x expert layers`, host arithmetic at dispatch beside `moe_routed_rows` (a slot that meets its EOS inside a window is masked on the device from there on and still counted as routed) |

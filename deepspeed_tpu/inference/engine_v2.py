@@ -161,6 +161,14 @@ class RaggedInferenceConfig:
     #: tokens, rows x ``chunk`` — at what a deployment warms. Sequences
     #: past the cap keep their place and ride the next prefill plan.
     prefill_max_rows: int = 0
+    #: False: a packed plan packs ROWS only and its chunk stays ``chunk``,
+    #: also where the state is a chain of pages (where it is not, the chunk
+    #: never grows). For a model whose prefill step costs grow with the
+    #: CONTEXT — latent attention over a 28k-token table: a lone prompt's
+    #: grown chunk of ``chunk`` x ``max_seqs`` tokens is one step of most of
+    #: a second, in which every decoding row gets one token — and whose
+    #: programs compile slowly (the menu is then one program a row count).
+    prefill_grow_chunk: bool = True
     #: content-addressed shared-prefix KV cache over the paged pool
     #: (vLLM PagedAttention block sharing + SGLang RadixAttention, TPU
     #: formulation — inference/prefix_cache.py): full KV pages are keyed
@@ -318,8 +326,20 @@ class InferenceEngineV2:
         # a bounded ring for window layers (``cache_kinds``)
         # — and one RECORD a slot for layers whose state is no pages at all
         # (a "conv" kind: no allocator, no table)
-        self._kinds = cache_kinds(model.config, cfg)
+        self._kinds = cache_kinds(model.config, cfg,
+                                  max(topology.size("tensor"), 1))
         k0 = self._kinds[0]
+        if k0.is_latent:
+            # what the latent page does not do yet, refused by its name
+            for on, what in ((topology.mesh.size > 1, "a device mesh"),
+                             (cfg.kv_cache_dtype, "kv_cache_dtype"),
+                             (cfg.spec_decode, "spec_decode"),
+                             (cfg.kv_tier, "kv_tier")):
+                if on:
+                    raise ValueError(
+                        f"kind {k0.name!r} (latent attention: one row a "
+                        f"token shared by every head, no K/V halves) does "
+                        f"not serve under {what} yet")
         self.state = StateManager(
             k0.num_blocks, cfg.block_size, cfg.max_seqs, k0.max_blocks,
             kind=k0.name, ring=bool(k0.ring_tokens),
@@ -335,7 +355,8 @@ class InferenceEngineV2:
         # hands over at chunk boundaries the warmed programs know
         self.scheduler = SplitFuseScheduler(
             self.state, cfg.chunk, pack=cfg.prefill_pack,
-            grow_chunk=not not_pages, max_rows=cfg.prefill_max_rows)
+            grow_chunk=cfg.prefill_grow_chunk and not not_pages,
+            max_rows=cfg.prefill_max_rows)
 
         # --- shared-prefix KV cache (radix reuse over the pool) ----------
         use_pc = cfg.prefix_cache
@@ -378,8 +399,9 @@ class InferenceEngineV2:
                 m0 = self.mcfg
                 kv_bytes = 1 if cfg.kv_cache_dtype == "fp8" \
                     else jnp.dtype(cfg.dtype).itemsize
-                page_bytes = int(2 * m0.num_layers * m0.kv_heads *
-                                 cfg.block_size * m0.head_dim * kv_bytes)
+                page_bytes = int(np.prod(
+                    k0.pool_shape(cfg.block_size)) // k0.num_blocks
+                    * kv_bytes)
                 min_pages = auto_min_pages(
                     measure_tier_rates(nvme_dir=cfg.kv_tier_nvme_dir),
                     page_bytes=page_bytes, block_size=cfg.block_size,
@@ -426,18 +448,29 @@ class InferenceEngineV2:
         moe_flags = [is_moe_layer(m, i) for i in range(m.num_layers)]
         # ... and so does a stack whose layers differ in OPERATOR (a
         # "conv" kind among attention layers)
-        self._scan_layers = (m.num_layers > 1 and
-                             (all(moe_flags) or not any(moe_flags))
+        uniform = all(moe_flags) or not any(moe_flags)
+        #: ... or whose LEADING layers carry a dense feed-forward and the
+        #: rest, all alike, routed experts (one kind of layer, no record:
+        #: kanana-2): the leading layers keep their own trees and are
+        #: walked unrolled, the tail is stacked and scanned
+        lead = moe_flags.index(True) if any(moe_flags) else 0
+        self._scan_lead = lead if (
+            not uniform and all(moe_flags[lead:])
+            and m.num_layers - lead > 1 and len(self._kinds) == 1
+            and len(m.kinds_period) == 1) else 0
+        self._scan_layers = (m.num_layers > 1
+                             and (uniform or bool(self._scan_lead))
                              and not any(k.is_record for k in self._kinds))
         if self._scan_layers:
             layers = [self.params.pop(f"layer_{i}")
-                      for i in range(m.num_layers)]
+                      for i in range(self._scan_lead, m.num_layers)]
             stack_kw = {}
             if not cfg.quant_bits:
                 is_p = lambda x: isinstance(x, P)
                 stack_kw["out_shardings"] = jax.tree.map(
                     lambda p: NamedSharding(topology.mesh, P(None, *p)),
-                    plan.param_specs["layer_0"], is_leaf=is_p)
+                    plan.param_specs[f"layer_{self._scan_lead}"],
+                    is_leaf=is_p)
                 # donate: each per-layer buffer frees as it is copied, so
                 # init never holds 2x the layer weights in HBM
                 stack_kw["donate_argnums"] = (0,)
@@ -449,7 +482,7 @@ class InferenceEngineV2:
                 # stack.)
                 from jax.tree_util import DictKey, tree_map_with_path
 
-                spec0 = plan.param_specs["layer_0"]
+                spec0 = plan.param_specs[f"layer_{self._scan_lead}"]
 
                 def stacked_sharding(path, leaf):
                     names = [p.key for p in path if isinstance(p, DictKey)]
@@ -473,6 +506,13 @@ class InferenceEngineV2:
             self.params["layers_stacked"] = jax.jit(
                 lambda ls: jax.tree.map(lambda *xs: jnp.stack(xs), *ls),
                 **stack_kw)(layers)
+            # the per-layer buffers the stack could not take over (the
+            # donation is refused for most of them) live as long as this
+            # list: without this a stack and its layers are BOTH there
+            # when the pool below is allocated (kanana-2's 1 + 4 layers:
+            # 13.6 GiB in use and no room for a 3.1 GiB pool; my chip run,
+            # PR 54, call D)
+            del layers
 
         # --- the paged KV pool -------------------------------------------
         # [L, 2, KV, num_blocks, block_size, D], block-granular so the
@@ -487,8 +527,9 @@ class InferenceEngineV2:
         #: in a page row (2 where heads are 64 wide: ``forward.kv_pack``),
         #: so ``[.., KV / pack, nb, block, pack * head_dim]``
         self._kv_pack = kv_pack(m, tp)
-        self._kv_geom = (m.kv_heads // self._kv_pack,
-                         m.head_dim * self._kv_pack)
+        #: (heads, lanes) of a page row: the paged kinds' own (one geometry
+        #: for them all: ``CacheKind.heads`` / ``lanes``)
+        self._kv_geom = (k0.heads, k0.lanes)
         kv_spec = P(None, None, "tensor", None, None, None) \
             if self._kv_geom[0] % tp == 0 else \
             P(None, None, None, None, None, None)
@@ -513,13 +554,11 @@ class InferenceEngineV2:
         # replicated, in the compute dtype: donated and returned like a pool
         repl = NamedSharding(topology.mesh, P())
         self.kv_pool = tuple(
-            jax.device_put(jnp.zeros((len(k.layers), k.num_blocks, k.rows,
-                                      k.width), cfg.dtype), repl)
+            jax.device_put(jnp.zeros(k.pool_shape(cfg.block_size),
+                                     cfg.dtype), repl)
             if k.is_record else
-            jax.device_put(
-                jnp.zeros((len(k.layers), 2, self._kv_geom[0], k.num_blocks,
-                           cfg.block_size, self._kv_geom[1]),
-                          self._kv_dtype), self._pool_format)
+            jax.device_put(jnp.zeros(k.pool_shape(cfg.block_size),
+                                     self._kv_dtype), self._pool_format)
             for k in self._kinds)
         #: a jitted program's sharding of its ``kv_pool`` argument
         self._pool_formats = tuple(repl if k.is_record else self._pool_format
@@ -533,6 +572,9 @@ class InferenceEngineV2:
             f"blocks of {cfg.block_size}, table {k.max_blocks} a sequence"
             + (f" (a ring of {k.ring_tokens} tokens, window {k.window})"
                if k.ring_tokens else " (grows with the context)")
+            + (f", ONE row of {k.row_values} values a token in {k.lanes} "
+               f"lanes (no K/V halves), {self.kv_pool[c].nbytes} bytes"
+               if k.is_latent else "")
             for c, k in enumerate(self._kinds)))
 
         # alibi needs a positional bias inside the kernel — XLA path only.
@@ -597,7 +639,7 @@ class InferenceEngineV2:
         if self._attn_paged:
             plan = paged_plan(cfg.chunk * (m.num_heads // self._kv_geom[0]),
                               self._kv_geom[0] // tp, cfg.block_size,
-                              cfg.dtype)
+                              cfg.dtype, lanes=self._kv_geom[1])
             self.paged_plans = {k.name: plan for k in self._kinds
                                 if not k.is_record}
             for k in self.paged_plans:
@@ -807,6 +849,12 @@ class InferenceEngineV2:
             self.stats.update({"state_records_live": 0,
                                "state_records_peak": 0,
                                "conv_chunks": 0, "conv_chunks_carried": 0})
+        if k0.is_latent:
+            # rows ``[c | k_r]`` the dispatched programs were to write, a
+            # token a row whatever the layers (host arithmetic, at
+            # dispatch: a plan's live tokens and its decode block's, a
+            # window's scheduled iterations a slot)
+            self.stats["latent_rows_written"] = 0
         for k in self._kinds:
             if k.is_record:
                 continue
@@ -897,9 +945,7 @@ class InferenceEngineV2:
         cfg = self.config
         if not jax.config.jax_enable_compilation_cache:
             return
-        k0 = self._kinds[0]
-        shape = (len(k0.layers), 2, self._kv_geom[0], k0.num_blocks,
-                 cfg.block_size, self._kv_geom[1])
+        shape = self._kinds[0].pool_shape(cfg.block_size)
         default = jax.jit(lambda x: x).lower(jax.ShapeDtypeStruct(
             shape, self._kv_dtype, sharding=self._pool_sharding)).compile(
             ).input_formats[0][0].layout.major_to_minor
@@ -1089,10 +1135,14 @@ class InferenceEngineV2:
             if "attn" in layer:
                 a = layer["attn"]
                 sa = spec0.get("attn", {})
+                # (latent attention has no wk / wv: its small down- and
+                # up-projections ``w_dkv`` / ``w_uk`` / ``w_uv`` — 20 % of
+                # the attention's bytes — stay exact, as the router does)
                 for k in ("wq", "wk", "wv"):
-                    a[k] = q2d(a[k], E, k, sa.get(k))     # [E, (H|KV)*D]
-                a["wo"] = q2d(a["wo"], m.num_heads * m.head_dim, "wo",
-                              sa.get("wo"))
+                    if k in a:
+                        a[k] = q2d(a[k], E, k, sa.get(k))  # [E, (H|KV)*D]
+                a["wo"] = q2d(a["wo"], a["wo"].shape[0] * a["wo"].shape[1],
+                              "wo", sa.get("wo"))
             if "ffn" in layer:
                 f = layer["ffn"]
                 sf = spec0.get("ffn", {})
@@ -1302,6 +1352,8 @@ class InferenceEngineV2:
                 # — a window's row IS its slot — and has no V half)
                 stage0 = tuple(
                     (pool[:, :S], None) if k.is_record else
+                    (jnp.zeros((len(k.layers), S, KV, Ws, D), cfg.dtype),
+                     None) if k.is_latent else
                     (jnp.zeros((len(k.layers), S, KV, Ws, D), cfg.dtype),) * 2
                     for k, pool in zip(kinds, kv_pool))
                 stage0 = tuple(zip(*stage0))     # (k halves, v halves)
@@ -1393,8 +1445,9 @@ class InferenceEngineV2:
                     with device_scope("kv_commit"):
                         ks = (kb[:, :, :, :W, :].transpose(0, 3, 1, 2, 4)
                               .reshape(L, W * S, KV, D))
-                        vs = (vb[:, :, :, :W, :].transpose(0, 3, 1, 2, 4)
-                              .reshape(L, W * S, KV, D))
+                        vs = None if vb is None else (
+                            vb[:, :, :, :W, :].transpose(0, 3, 1, 2, 4)
+                            .reshape(L, W * S, KV, D))
                         merged.append(merge_rows(
                             pool, sl.reshape(-1), ks, vs))
                 # toks [W, S], iters run
@@ -1527,6 +1580,8 @@ class InferenceEngineV2:
         self.stats["dispatches"] += 1
         self.stats["windows"] += 1
         self._count_moe(int(rem.sum()), S, iters=W)
+        if "latent_rows_written" in self.stats:
+            self.stats["latent_rows_written"] += int(rem.sum())
         self._count_attn_steps(lens0, pos0,
                                stage_rows(W, self.config.block_size),
                                iters=W)
@@ -1923,6 +1978,8 @@ class InferenceEngineV2:
         n_block = 0 if plan.block is None else int(plan.block.active.sum())
         self._count_moe(n_tok + n_block, plan.token_ids.size
                         + (self.state.max_seqs if T > 1 else 0))
+        if "latent_rows_written" in self.stats:
+            self.stats["latent_rows_written"] += n_tok + n_block
         self._count_attn_steps(plan.seq_lens, plan.positions[:, 0],
                                stage_rows(T, bs))
         if plan.block is not None:
@@ -2327,9 +2384,9 @@ class InferenceEngineV2:
     # ring-free model — one kind of layer: every caller refuses a ring
     @property
     def _page_shape(self) -> tuple[int, ...]:
-        m = self.mcfg
-        return (len(self._kinds[0].layers), 2, self._kv_geom[0],
-                self.config.block_size, self._kv_geom[1])
+        k0 = self._kinds[0]
+        return (len(k0.layers), k0.halves, k0.heads,
+                self.config.block_size, k0.lanes)
 
     @property
     def _page_bytes(self) -> int:

@@ -12,8 +12,11 @@ model:
   slot, its fresh state each row's new record; a model without such layers
   carries no such entry.
 - **One walk over a stacked model** (:func:`scan_layers`: a scan over periods
-  of layer kinds; a period of one is the plain scan over depth). Layers that
-  cannot be stacked (MoE on some layers only) take the unrolled loop.
+  of layer kinds; a period of one is the plain scan over depth). Leading
+  dense layers before a uniform tail of expert layers are walked from their
+  own trees first, then the tail is scanned; layers that cannot be stacked
+  at all (MoE on some layers in the middle, a record kind) take the
+  unrolled loop.
 - **One return form**: :class:`RaggedForward` never writes a pool. It returns
   ``((k_ys, v_ys), logits)`` — this call's fresh K/V a kind — and the
   program that called it merges them inside the same ``jit``
@@ -54,6 +57,7 @@ from ..models.transformer import (
     dense_ffn_config,
     is_moe_layer,
     kind_ropes,
+    latent_row,
     qk_norm,
 )
 from ..moe.layer import dropless_dispatch_combine
@@ -160,8 +164,17 @@ class CacheKind:
     reused in place (a window kind narrower than a whole context). A RECORD
     kind ("conv", ``rows`` > 0): ``rows`` x ``width`` values a layer and
     SLOT, addressed by the slot a live sequence already holds — no
-    allocator, no table, nothing to reserve."""
-    name: str                      # "full" | "window" | "conv"
+    allocator, no table, nothing to reserve.
+
+    A paged kind's PAGE is ``[halves, heads, block, lanes]`` a layer:
+    ``halves`` 2 — keys and values, ``heads`` KV heads (:func:`kv_pack` of
+    them side by side in a row of ``lanes``) — or 1: the LATENT kind
+    ("latent": latent attention's ``[c | k_r]``, ONE row a token shared by
+    every head, no K/V halves, no KV-head dim; ``row_values`` of its
+    ``lanes`` are the model's, the rest lane padding). The pool's
+    allocation, its merges, the log line and what moves pages read the
+    geometry here."""
+    name: str                      # "full" | "window" | "latent" | "conv"
     layers: tuple[int, ...]        # the model's layers of this kind
     window: int | None             # sliding-window mask (None: full)
     max_blocks: int                # block-table width of a sequence
@@ -169,14 +182,36 @@ class CacheKind:
     num_blocks: int                # blocks of its pool (records: slots + 1)
     rows: int = 0                  # > 0: a record kind, rows of a record
     width: int = 0                 # values a row of a record
+    halves: int = 2                # a page's K and V halves; 1: one row
+    heads: int = 0                 # heads a page holds
+    lanes: int = 0                 # width of a page row as stored
+    row_values: int = 0            # of them the model's (0: all of them)
 
     @property
     def is_record(self) -> bool:
         return self.rows > 0
 
+    @property
+    def is_latent(self) -> bool:
+        return self.halves == 1
 
-def cache_kinds(m: ModelConfig, cfg: "RaggedInferenceConfig"
-                ) -> tuple[CacheKind, ...]:
+    def pool_shape(self, block_size: int) -> tuple[int, ...]:
+        """The kind's pool: ``[layers, halves, heads, blocks, block,
+        lanes]``, or a record kind's ``[layers, slots + 1, rows, width]``."""
+        if self.is_record:
+            return (len(self.layers), self.num_blocks, self.rows, self.width)
+        return (len(self.layers), self.halves, self.heads, self.num_blocks,
+                block_size, self.lanes)
+
+
+#: the latent kind's name, and the lanes a stored row is padded to a
+#: multiple of (a v5e vector register's)
+LATENT = "latent"
+LANES = 128
+
+
+def cache_kinds(m: ModelConfig, cfg: "RaggedInferenceConfig",
+                tp: int = 1) -> tuple[CacheKind, ...]:
     """The caches a model's layers need: the PAGED kinds first, each with
     the layers that HAVE keys and values only, the PRIMARY of them first
     ("full" where the model has full layers), then the record kind where
@@ -188,9 +223,29 @@ def cache_kinds(m: ModelConfig, cfg: "RaggedInferenceConfig"
     (``max_seqs`` x ring + the trash block), so it never refuses. The
     record kind "conv" holds ``conv_taps - 1`` rows of ``hidden_size`` a
     layer and slot, ``max_seqs`` records and one more for rows that are not
-    live (the trash record, as a pool's trash block)."""
+    live (the trash record, as a pool's trash block).
+
+    A model of latent attention (``kv_lora_rank``) has ONE paged kind,
+    "latent", primary, every layer, a table that grows: a linear chain of
+    pages like "full" (chunk growth, the prefix trie and rewind work on
+    it), whose page row is ``[c | k_r]`` — ``latent_width`` values (576)
+    padded to a multiple of 128 lanes (640): 4.5 lanes' worth is neither
+    a tile nor, pinned row-major, the device's default layout. ``tp``: the
+    tensor axis' size (it decides :func:`kv_pack`, so a page's ``heads`` and
+    ``lanes``; the engine hands its topology's)."""
     bs = cfg.block_size
     whole = -(-cfg.max_seq_len // bs)
+    if m.kv_lora_rank:
+        if set(m.kinds) != {"full"}:
+            raise ValueError("latent attention serves full causal rope "
+                             "layers only (no window, no conv)")
+        return (CacheKind(
+            LATENT, tuple(range(m.num_layers)), None, whole, 0,
+            cfg.num_blocks, halves=1, heads=1,
+            lanes=-(-m.latent_width // LANES) * LANES,
+            row_values=m.latent_width),)
+    pack = kv_pack(m, tp)
+    geom = dict(heads=m.kv_heads // pack, lanes=m.head_dim * pack)
     of = [cache_kind(k) for k in m.kinds]
     names = sorted(set(of) - {CONV})            # "full" < "window"
     if not names:
@@ -208,7 +263,8 @@ def cache_kinds(m: ModelConfig, cfg: "RaggedInferenceConfig"
                 ring = width * bs
         out.append(CacheKind(
             name, tuple(i for i, k in enumerate(of) if k == name), W, width,
-            ring, cfg.num_blocks if not out else cfg.max_seqs * width + 1))
+            ring, cfg.num_blocks if not out else cfg.max_seqs * width + 1,
+            **geom))
     if CONV in of:
         out.append(CacheKind(
             CONV, tuple(i for i, k in enumerate(of) if k == CONV), None, 0,
@@ -549,10 +605,14 @@ class RaggedForward:
         #: the pool's own head geometry (``kv_pack`` heads a page row)
         pk = self.kv_pack
         KVp, Dp = KV // pk, D * pk
+        #: latent attention: ONE paged kind whose page is a row a token
+        latent = kinds[0].is_latent
+        if latent:
+            KVp, Dp = kinds[0].heads, kinds[0].lanes
         window_mode = kv_stage is not None
         #: the cache (an index into ``kinds``) of a layer kind
-        cache_of = {name: [k.name for k in kinds].index(cache_kind(name))
-                    for name in set(m.kinds)}
+        cache_of = {name: [k.name for k in kinds].index(
+            LATENT if latent else cache_kind(name)) for name in set(m.kinds)}
         period = m.kinds_period
         tree_mode = tree_mask is not None
         fused = block is not None
@@ -585,13 +645,14 @@ class RaggedForward:
                         max_pages=block_tables[c].shape[1], stage_rows=Ts,
                         window=k.window, ring_tokens=k.ring_tokens,
                         tree=tree_mode) for c, k in enumerate(kinds)]
+            empty = jnp.zeros((S, KVp, Ts, Dp), cfg.dtype)
             return _Segment(
                 S, T, Ts, positions, seq_lens, q_starts, stage_starts,
                 block_tables,
                 jnp.ones_like(seq_lens) if window_mode
                 else seq_lens - q_starts, (q_starts == 0)[:, None, None],
                 sample_idx, tuple(works),
-                (jnp.zeros((S, KVp, Ts, Dp), cfg.dtype),) * 2)
+                (empty, None if latent else empty))
 
         seg0 = segment(positions, block_tables, seq_lens, sample_idx,
                        stage_starts)
@@ -745,7 +806,8 @@ class RaggedForward:
                 gate = topk_dropless_gating(
                     logits[None], mo.top_k,
                     normalize_gates=mo.normalize_gates,
-                    score=mo.router_score, bias=ml["gate"].get("bias"))
+                    score=mo.router_score, bias=ml["gate"].get("bias"),
+                    scale=mo.routed_scaling_factor)
 
             def exw(k):      # stripped (stacked) slabs are closed over
                 w = ml["experts"].get(k)
@@ -797,12 +859,15 @@ class RaggedForward:
             if use_moe:
                 out = routed_experts(p["moe"]["moe_layer"], h, li, h_router)
                 se = m.moe.shared_expert_intermediate
-                if se:   # qwen2-moe sigmoid-gated shared expert
+                if se:   # the always-on shared expert: behind a sigmoid
+                    #      gate (qwen2-moe) or as it is (deepseek-v3)
                     with device_scope("ffn"):
                         shared_cfg = dataclasses.replace(
                             m, intermediate_size=se)
                         shared = DenseFFN(shared_cfg).apply(
                             {"params": p["moe"]["shared_expert"]}, h)
+                        if not m.moe.shared_expert_gated:
+                            return out + shared
                         g = jax.nn.sigmoid(jnp.einsum(
                             "ste,eo->sto", h.astype(jnp.float32),
                             p["moe"]["shared_gate"].astype(jnp.float32)))
@@ -867,21 +932,24 @@ class RaggedForward:
                 return out.reshape(h.shape).astype(cfg.dtype)
             return DenseFFN(dense_ffn_config(m)).apply({"params": f}, h)
 
-        def attention(p, li, h, stage_l, kind, c, lk):
+        def attention(p, qli, h, stage_l, kind, c, lk):
             """QKV → write into the STAGED buffer → ragged attention over
             the read-only pool pages + the stage, a segment. Returns (o,
             stage_l'). ``kind``: the layer's kind (static); ``c`` its cache
             among ``kinds``; ``lk`` the layer's index inside that cache's
             pool."""
             a = p["attn"]
-            qli = li if qstack else None
-            with device_scope("attn_qkv"):
-                q, k, v = qkv(a, qli, h, kind)
-                if pk > 1:
-                    q, k, v = pack_heads(q, k, v, pk)
+            if latent:
+                q, k, v = latent_qkv(a, qli, h)
+            else:
+                with device_scope("attn_qkv"):
+                    q, k, v = qkv(a, qli, h, kind)
+                    if pk > 1:
+                        q, k, v = pack_heads(q, k, v, pk)
             outs, stages = [], []
-            for g, q_g, k_g, v_g, stage_g in zip(segs, to_segs(q), to_segs(k),
-                                                 to_segs(v), stage_l):
+            for g, q_g, k_g, v_g, stage_g in zip(
+                    segs, to_segs(q), to_segs(k),
+                    [None] * len(segs) if latent else to_segs(v), stage_l):
                 with device_scope("kv_stage"):
                     stage_g = stage(g, k_g, v_g, stage_g)
                 # window and global layers told apart, inside
@@ -894,11 +962,44 @@ class RaggedForward:
                 with device_scope("attn_core"), sub:
                     outs.append(core(g, c, lk, q_g, stage_g))
                 stages.append(stage_g)
+            if latent:
+                # the weighted sum of latents back to a head's value:
+                # ``o_h = W_uv,h o_lat_h``
+                with device_scope("latent_absorb"):
+                    o = jnp.einsum("sthr,rhd->sthd", from_segs(outs),
+                                   a["w_uv"].astype(cfg.dtype))
+                with device_scope("attn_out"):
+                    return out_proj(a, qli, o), tuple(stages)
             with device_scope("attn_out"):
                 o = from_segs(outs)
                 if pk > 1:
                     o = unpack_heads(o, KV, pk)
                 return out_proj(a, qli, o), tuple(stages)
+
+        def latent_qkv(a, qli, h):
+            """Latent attention ABSORBED: what attends is the latent row
+            itself. Returns the query ``[S, T, H, lanes]`` — ``W_uk,h^T
+            q_nope_h`` (the up-projection of the keys folded into the
+            query) beside ``q_rope_h``, zeros in the row's padding — the
+            row ``[c | k_r]`` of this call's tokens ``[S, T, 1, lanes]``
+            (what the pool keeps, ONCE: the kernel takes its first
+            ``kv_lora_rank`` lanes as the value), and no V."""
+            R, dn = m.kv_lora_rank, m.qk_nope_head_dim
+            with device_scope("attn_qkv"):
+                q = proj_in(h, a["wq"], H, "wq", li=qli)
+            with device_scope("latent_absorb"):
+                c, k_r = latent_row(m, h, a["w_dkv"], a["kv_norm"])
+                q_r, k_r = apply_rope(q[..., dn:], k_r[:, :, None, :],
+                                      positions, m.rope_theta)
+                q_abs = jnp.einsum("sthd,rhd->sthr", q[..., :dn],
+                                   a["w_uk"].astype(cfg.dtype))
+                pad = Dp - m.latent_width
+                q = jnp.pad(jnp.concatenate([q_abs, q_r], axis=-1),
+                            [(0, 0)] * 3 + [(0, pad)])
+                row = jnp.pad(jnp.concatenate([c[:, :, None, :], k_r],
+                                              axis=-1),
+                              [(0, 0)] * 3 + [(0, pad)])
+            return q, row.astype(cfg.dtype), None
 
         def qkv(a, qli, h, kind):
             if rn:
@@ -938,17 +1039,19 @@ class RaggedForward:
         def stage(g, k, v, stage_l):
             """This step's K/V of segment ``g`` into its staged buffers."""
             k_t = k.transpose(0, 2, 1, 3).astype(cfg.dtype)  # [S,KV,T,D]
-            v_t = v.transpose(0, 2, 1, 3).astype(cfg.dtype)
+            v_t = None if v is None \
+                else v.transpose(0, 2, 1, 3).astype(cfg.dtype)
             if window_mode:
                 k_st, v_st = stage_l
                 k_st = jax.lax.dynamic_update_slice(
                     k_st, k_t, (0, 0, stage_fill, 0))
-                v_st = jax.lax.dynamic_update_slice(
-                    v_st, v_t, (0, 0, stage_fill, 0))
+                if v_t is not None:
+                    v_st = jax.lax.dynamic_update_slice(
+                        v_st, v_t, (0, 0, stage_fill, 0))
             else:
                 pad = [(0, 0), (0, 0), (0, g.Ts - g.T), (0, 0)]
                 k_st = jnp.pad(k_t, pad)
-                v_st = jnp.pad(v_t, pad)
+                v_st = None if v_t is None else jnp.pad(v_t, pad)
             return k_st, v_st
 
         def core(g, c, lk, q, stage_l):
@@ -957,6 +1060,10 @@ class RaggedForward:
             cache ``c``'s pool and block table, at layer ``lk`` of that
             pool."""
             k_st, v_st = stage_l
+            #: the score scale: the model's head width's — a packed or
+            #: latent row's lanes are not it
+            qk_scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5 \
+                if latent else D ** -0.5
             S, T, Ts, positions = g.S, g.T, g.Ts, g.positions
             seq_lens, q_starts = g.seq_lens, g.q_starts
             stage_starts = g.stage_starts
@@ -982,7 +1089,8 @@ class RaggedForward:
                         qq, pp, ks, vs, bt, sl, qs, ss,
                         block_size=bs, layer_index=lr, window=win,
                         ring_tokens=ring, work=(wl, nw),
-                        scale=None if pk == 1 else D ** -0.5,
+                        scale=qk_scale if latent or pk > 1 else None,
+                        value_lanes=m.kv_lora_rank if latent else None,
                         tree_positions=t[0] if t else None,
                         tree_mask=t[1] if t else None)
 
@@ -1013,17 +1121,23 @@ class RaggedForward:
                 blocks = jnp.repeat(table, bs, axis=1)           # [S,ctx]
                 offs = jnp.tile(jnp.arange(bs), table.shape[1])
                 K = ro_pool[li_dev, 0, :, blocks, offs[None, :]]  # [S,ctx,KV,D]
-                V = ro_pool[li_dev, 1, :, blocks, offs[None, :]]
                 K = jnp.concatenate([K.astype(cfg.dtype),
                                      k_st.transpose(0, 2, 1, 3)], axis=1)
-                V = jnp.concatenate([V.astype(cfg.dtype),
-                                     v_st.transpose(0, 2, 1, 3)], axis=1)
+                if latent:
+                    # the same form as the kernel's: the value is the
+                    # first lanes of the one row every head reads
+                    V = K[..., :m.kv_lora_rank]
+                else:
+                    V = ro_pool[li_dev, 1, :, blocks, offs[None, :]]
+                    V = jnp.concatenate([V.astype(cfg.dtype),
+                                         v_st.transpose(0, 2, 1, 3)], axis=1)
                 if KVp != H:
                     K = jnp.repeat(K, H // KVp, axis=2)
                     V = jnp.repeat(V, H // KVp, axis=2)
 
                 scores = jnp.einsum("sthd,schd->shtc", q, K).astype(jnp.float32)
-                scores = scores / (D ** 0.5)
+                scores = scores * qk_scale if latent \
+                    else scores / (D ** 0.5)
                 sstart = stage_starts[:, None]
                 if ring:
                     # rolling buffer: recover each gathered offset's
@@ -1102,11 +1216,14 @@ class RaggedForward:
             with device_scope("norm"):
                 return Norm(m).apply({"params": p_ln}, x)
 
-        def layer(x, p, li, use_moe, stage_l, kind, lk):
+        def layer(x, p, li, use_moe, stage_l, kind, lk, stacked=True):
             """``stage_l``: a segment, the layer's staged K/V — or, for a
             "conv" layer, the record each row starts from (zeros for a row
-            that starts its sequence: no past); returned advanced."""
-            qli = li if qstack else None
+            that starts its sequence: no past); returned advanced.
+            ``stacked``: ``p`` is a slice of the depth-stacked tree (``li``
+            then picks the layer of a weight closed over whole), not a
+            layer's own tree."""
+            qli = li if qstack and stacked else None
             h_attn = norm(p["ln_attn"], x)
             if kind == CONV:
                 with device_scope("conv_mix"):
@@ -1118,7 +1235,7 @@ class RaggedForward:
                     o = from_segs([o_g for o_g, _ in mixed])
                     stage_l = tuple(rec for _, rec in mixed)
             else:
-                o, stage_l = attention(p, li, h_attn, stage_l, kind,
+                o, stage_l = attention(p, qli, h_attn, stage_l, kind,
                                        cache_of[kind], lk)
             if not m.parallel_block:
                 x = x + o
@@ -1134,49 +1251,13 @@ class RaggedForward:
 
         empty_stages = tuple(g.empty_stage for g in segs)
         P_ = len(period)
-        if "layers_stacked" in params:
-            if CONV in cache_of:
-                raise ValueError("a model with 'conv' layers is walked "
-                                 "unrolled, not stacked")
-            # a scan over PERIODS (of one layer, for a model of one kind):
-            # one traced body a place, whatever the depth; the pools never
-            # enter the carry — only the small staged KV does. Place j of a
-            # period fixes the layer's kind, its cache c and its rank r
-            # among that cache's layers of the period — layer pi * P + j is
-            # layer pi * n_c + r of pool c
-            place = []
-            for j, kind in enumerate(period):
-                c = cache_of[kind]
-                place.append((c, sum(cache_of[kk] == c
-                                     for kk in period[:j])))
-            n_in = [sum(cc == c for cc, _ in place)
-                    for c in range(len(kinds))]
-            xs = None
-            if window_mode:
-                split = lambda b, c: b.reshape(-1, n_in[c], *b.shape[1:])
-                xs = [(split(kbufs[c], c)[:, r], split(vbufs[c], c)[:, r])
-                      for c, r in place]
 
-            def body(xc, p, li, stage_l, j):
-                c, r = place[j]
-                return layer(xc, p, li, is_moe_layer(m, 0),
-                             (stage_l,) if window_mode else empty_stages,
-                             period[j], (li // P_) * n_in[c] + r)
-
-            x, ys = scan_layers(scanned_layers, x, body, P_, xs)
-            # ``fresh[gi]``: segment gi's (k_ys, v_ys), a kind each
-            fresh = []
-            for gi in range(len(segs)):
-                k_ys, v_ys = [], []
-                for c in range(len(kinds)):
-                    for out, half in ((k_ys, 0), (v_ys, 1)):
-                        y = jnp.stack([ys[j][gi][half] for j in range(P_)
-                                       if place[j][0] == c], axis=1)
-                        out.append(y.reshape(-1, *y.shape[2:]))
-                fresh.append((tuple(k_ys), tuple(v_ys)))
-        else:
+        def unrolled(x, layer_ids):
+            """Layers ``layer_ids`` one after the other, each from its own
+            tree ``layer_<i>``. Returns ``x`` and, a segment and kind, the
+            layers' staged K/V (or new records) as lists."""
             lists = [[([], []) for _ in kinds] for _ in segs]
-            for i in range(m.num_layers):
+            for i in layer_ids:
                 use_moe = is_moe_layer(m, i)
                 c = cache_of[m.layer_kind(i)]
                 lk = kinds[c].layers.index(i)
@@ -1186,17 +1267,80 @@ class RaggedForward:
                     recs = (kbufs[c][lk],) if window_mode else tuple(
                         kv_pools[c][lk][g.block_tables[c]] for g in segs)
                     x, recs = layer(x, params[f"layer_{i}"], i, use_moe,
-                                    recs, CONV, lk)
+                                    recs, CONV, lk, stacked=False)
                     for of_seg, rec in zip(lists, recs):
                         of_seg[c][0].append(rec)
                     continue
-                stage_l = ((kbufs[c][lk], vbufs[c][lk]),) if window_mode \
+                stage_l = ((kbufs[c][lk], None if latent
+                            else vbufs[c][lk]),) if window_mode \
                     else empty_stages
                 x, stage_l = layer(x, params[f"layer_{i}"], i, use_moe,
-                                   stage_l, m.layer_kind(i), lk)
+                                   stage_l, m.layer_kind(i), lk,
+                                   stacked=False)
                 for of_seg, (k_st, v_st) in zip(lists, stage_l):
                     of_seg[c][0].append(k_st)
-                    of_seg[c][1].append(v_st)
+                    if v_st is not None:
+                        of_seg[c][1].append(v_st)
+            return x, lists
+
+        if "layers_stacked" in params:
+            if CONV in cache_of:
+                raise ValueError("a model with 'conv' layers is walked "
+                                 "unrolled, not stacked")
+            # the LEADING layers that are not in the stack (a model of
+            # leading dense layers, then identical expert layers: none for
+            # a uniform model) are walked from their own trees first
+            lead = m.num_layers - jax.tree.leaves(
+                params["layers_stacked"])[0].shape[0]
+            x, lead_lists = unrolled(x, range(lead))
+            # a scan over PERIODS (of one layer, for a model of one kind):
+            # one traced body a place, whatever the depth; the pools never
+            # enter the carry — only the small staged KV does. Place j of a
+            # period fixes the layer's kind, its cache c and its rank r
+            # among that cache's layers of the period — stack layer
+            # pi * P + j is layer lead + pi * n_c + r of pool c
+            place = []
+            for j, kind in enumerate(period):
+                c = cache_of[kind]
+                place.append((c, sum(cache_of[kk] == c
+                                     for kk in period[:j])))
+            n_in = [sum(cc == c for cc, _ in place)
+                    for c in range(len(kinds))]
+            xs = None
+            if window_mode:
+                split = lambda b, c: b[lead:].reshape(-1, n_in[c],
+                                                      *b.shape[1:])
+                xs = [(split(kbufs[c], c)[:, r],
+                       None if latent else split(vbufs[c], c)[:, r])
+                      for c, r in place]
+
+            def body(xc, p, li, stage_l, j):
+                c, r = place[j]
+                return layer(xc, p, li, is_moe_layer(m, lead),
+                             (stage_l,) if window_mode else empty_stages,
+                             period[j], lead + (li // P_) * n_in[c] + r)
+
+            x, ys = scan_layers(scanned_layers, x, body, P_, xs)
+            # ``fresh[gi]``: segment gi's (k_ys, v_ys), a kind each: the
+            # leading layers' (if any), then the stack's
+            fresh = []
+            for gi in range(len(segs)):
+                k_ys, v_ys = [], []
+                for c in range(len(kinds)):
+                    for out, half in ((k_ys, 0), (v_ys, 1)):
+                        if latent and half:      # one row a token: no V
+                            out.append(None)
+                            continue
+                        y = jnp.stack([ys[j][gi][half] for j in range(P_)
+                                       if place[j][0] == c], axis=1)
+                        y = y.reshape(-1, *y.shape[2:])
+                        if lead:
+                            y = jnp.concatenate(
+                                [jnp.stack(lead_lists[gi][c][half]), y])
+                        out.append(y)
+                fresh.append((tuple(k_ys), tuple(v_ys)))
+        else:
+            x, lists = unrolled(x, range(m.num_layers))
             fresh = [(tuple(jnp.stack(ks) for ks, _ in of_seg),
                       tuple(jnp.stack(vs) if vs else None
                             for _, vs in of_seg)) for of_seg in lists]
@@ -1277,13 +1421,13 @@ def merge_step(kv_pools, slot_maps, k_ys, v_ys, T: int):
     ``[S]``) is written by :func:`merge_records`."""
     merged = []
     for pool, slots, kc, vc in zip(kv_pools, slot_maps, k_ys, v_ys):
-        if vc is None:
+        if pool.ndim == 4:
             merged.append(merge_records(pool, slots, kc))
             continue
         L, _, KV, _, bs, D = pool.shape
         if T == 1:
-            pool = merge_rows(pool, slots[:, 0],
-                              kc[:, :, :, 0, :], vc[:, :, :, 0, :])
+            pool = merge_rows(pool, slots[:, 0], kc[:, :, :, 0, :],
+                              None if vc is None else vc[:, :, :, 0, :])
         elif T % bs == 0:
             # (a ring too: the slot a whole page lands in held a page
             # more than a window + a step older, dead to every query
@@ -1294,8 +1438,9 @@ def merge_step(kv_pools, slot_maps, k_ys, v_ys, T: int):
             with device_scope("kv_commit"):
                 ks = (kc[:, :, :, :T, :].transpose(0, 1, 3, 2, 4)
                       .reshape(L, -1, KV, D))
-                vs = (vc[:, :, :, :T, :].transpose(0, 1, 3, 2, 4)
-                      .reshape(L, -1, KV, D))
+                vs = None if vc is None else (
+                    vc[:, :, :, :T, :].transpose(0, 1, 3, 2, 4)
+                    .reshape(L, -1, KV, D))
             pool = merge_stage(pool, slots.reshape(-1), ks, vs)
         merged.append(pool)
     return tuple(merged)
@@ -1321,8 +1466,9 @@ def merge_stage(kv_pool, flat_slots, ks, vs):
         liL = jnp.arange(kv_pool.shape[0])
         kv_pool = kv_pool.at[liL[:, None], 0, :, blk[None, :],
                              off[None, :]].set(ks.astype(kv_pool.dtype))
-        kv_pool = kv_pool.at[liL[:, None], 1, :, blk[None, :],
-                             off[None, :]].set(vs.astype(kv_pool.dtype))
+        if vs is not None:
+            kv_pool = kv_pool.at[liL[:, None], 1, :, blk[None, :],
+                                 off[None, :]].set(vs.astype(kv_pool.dtype))
         return kv_pool
 
 
@@ -1342,7 +1488,8 @@ def merge_rows(kv_pool, flat_slots, k_rows, v_rows):
         # row was ~30 equations to trace, lower and hash at every start,
         # warm ones too (``PERF.md`` section 6, PR 52: +19 s of a 97 s
         # set-up until this form)
-        kv_rows = jnp.stack([k_rows, v_rows], axis=1).astype(
+        kv_rows = jnp.stack([k_rows] if v_rows is None
+                            else [k_rows, v_rows], axis=1).astype(
             kv_pool.dtype).transpose(0, 1, 3, 2, 4)[:, :, :, :, None, :]
         blk, off = flat_slots // bs, flat_slots % bs
         z = np.int32(0)
@@ -1369,7 +1516,7 @@ def merge_pages(kv_pool, slot_map, k_ys, v_ys, T):
     the page update degrades to a read-back of the current page, and
     a per-row token DUS writes the one real token."""
     with device_scope("kv_commit"):
-        L, _, KV, nb, bs, D = kv_pool.shape
+        L, halves, KV, nb, bs, D = kv_pool.shape
         S = slot_map.shape[0]
         z = jnp.int32(0)
         n_real = (slot_map >= bs).sum(axis=1)          # trash slots < bs
@@ -1382,15 +1529,16 @@ def merge_pages(kv_pool, slot_map, k_ys, v_ys, T):
             for pg in range(T // bs):
                 sl = pg * bs
                 page = jnp.stack(
-                    [k_ys[:, s, :, sl:sl + bs, :],
-                     v_ys[:, s, :, sl:sl + bs, :]],
+                    [y[:, s, :, sl:sl + bs, :]
+                     for y in (k_ys, v_ys) if y is not None],
                     axis=1)[:, :, :, None].astype(kv_pool.dtype)
                 blk = slot_map[s, sl] // bs
                 if pg == 0:
                     # read-modify-write: a single-token/misaligned row's
                     # first page holds live earlier KV
                     cur = jax.lax.dynamic_slice(
-                        kv_pool, (z, z, z, blk, z, z), (L, 2, KV, 1, bs, D))
+                        kv_pool, (z, z, z, blk, z, z),
+                        (L, halves, KV, 1, bs, D))
                     page = jnp.where(no_page, cur, page)
                 else:
                     # later pages of degraded rows carry trash slots
@@ -1401,5 +1549,5 @@ def merge_pages(kv_pool, slot_map, k_ys, v_ys, T):
                     kv_pool, page, (z, z, z, blk, z, z))
         # every row's first token (covers degraded rows; for full chunks
         # this rewrites the value the page already wrote)
-        return merge_rows(kv_pool, slot_map[:, 0],
-                          k_ys[:, :, :, 0, :], v_ys[:, :, :, 0, :])
+        return merge_rows(kv_pool, slot_map[:, 0], k_ys[:, :, :, 0, :],
+                          None if v_ys is None else v_ys[:, :, :, 0, :])

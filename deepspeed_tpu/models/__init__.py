@@ -305,6 +305,45 @@ PRESETS: dict[str, ModelConfig] = {
                       dropless=True, dropless_block_m=16,
                       moe_layer_pattern=(False, True, True, True, True),
                       dense_ffn_intermediate=96)),
+    # kanana-2-30b-a3b-instruct-2601 (kakaocorp, ``deepseek_v3``): latent
+    # attention (MLA, no low-rank query step) on every layer — 32 heads of
+    # 128 nope + 64 rope over ONE latent of 512 and ONE rope key of 64 a
+    # token, values of 128 —; layer 0 a dense SwiGLU of 6144, the other 47
+    # 128 routed SwiGLU experts of 768, 6 a token (sigmoid scores, a
+    # selection bias, ONE group: group-limited selection is the identity),
+    # the weights normalised and times 2.448, beside ONE ungated shared
+    # expert of 2 x 768; untied head
+    "kanana-2-30b-a3b": ModelConfig(
+        vocab_size=128256, hidden_size=2048, num_layers=48, num_heads=32,
+        intermediate_size=768, max_seq_len=32768,
+        position_embedding="rope", rope_theta=1e6, norm="rmsnorm",
+        norm_eps=1e-6, activation="silu_glu", tie_embeddings=False,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128,
+        moe=MoEConfig(num_experts=128, top_k=6, normalize_gates=True,
+                      router_score="sigmoid_bias", dropless=True,
+                      moe_layer_pattern=(False,) + (True,) * 47,
+                      dense_ffn_intermediate=6144,
+                      shared_expert_intermediate=1536,
+                      shared_expert_gated=False,
+                      routed_scaling_factor=2.448)),
+    # one leading dense layer and two expert layers; the four MLA widths
+    # all differ (a transposed or mis-sliced axis cannot pass)
+    "tiny-kanana2": ModelConfig(
+        vocab_size=256, hidden_size=64, num_layers=3, num_heads=4,
+        intermediate_size=32, max_seq_len=256,
+        position_embedding="rope", rope_theta=1e4, norm="rmsnorm",
+        norm_eps=1e-6, activation="silu_glu", tie_embeddings=False,
+        kv_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=12,
+        moe=MoEConfig(num_experts=8, top_k=2, min_capacity=4,
+                      normalize_gates=True, router_score="sigmoid_bias",
+                      dropless=True, dropless_block_m=16,
+                      moe_layer_pattern=(False, True, True),
+                      dense_ffn_intermediate=96,
+                      shared_expert_intermediate=64,
+                      shared_expert_gated=False,
+                      routed_scaling_factor=2.448)),
 }
 
 

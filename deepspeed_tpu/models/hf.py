@@ -485,7 +485,64 @@ def _lfm2_moe_tree(sd: dict, cfg: ModelConfig) -> dict:
     return t
 
 
-_CONVERTERS = {"lfm2_moe": _lfm2_moe_tree, "gpt2": _gpt2_tree, "llama": _llama_tree,
+def _deepseek_v3_tree(sd: dict, cfg: ModelConfig) -> dict:
+    """deepseek_v3 layout without a low-rank query step (HF
+    ``DeepseekV3*``, ``q_lora_rank`` null: kanana-2): ``self_attn.q_proj``
+    [H (nope + rope), E]; ``kv_a_proj_with_mqa`` [r + rope, E], whose rows
+    are ``[c | k_r]`` in the order the cache keeps them; ``kv_a_layernorm``
+    [r]; ``kv_b_proj`` [H (nope + v), r], a head's rows ``k_nope | v``,
+    held as its two halves ``w_uk`` / ``w_uv`` (the absorbed serving form
+    folds them on different sides of the attention); ``o_proj`` [E, H v].
+    ``rope_interleave`` true: the checkpoint keeps rope pairs ``(x[2i],
+    x[2i+1])``, the form this program rotates — nothing is permuted. A
+    dense layer's ``mlp.{gate,up,down}_proj``; an expert layer's
+    ``mlp.gate.weight`` [n, E], ``mlp.gate.e_score_correction_bias`` [n],
+    ``mlp.experts.K.*`` and the ONE ungated ``mlp.shared_experts.*``."""
+    from .transformer import is_moe_layer
+
+    E, H, R = cfg.hidden_size, cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    t = {"embed": sd["model.embed_tokens.weight"],
+         "ln_final": {"scale": sd["model.norm.weight"]}}
+    if not cfg.tie_embeddings:
+        t["unembed"] = sd["lm_head.weight"].T
+    ffn = lambda base: {"w_gate": sd[base + "gate_proj.weight"].T,
+                        "w_up": sd[base + "up_proj.weight"].T,
+                        "w_down": sd[base + "down_proj.weight"].T}
+    for i in range(cfg.num_layers):
+        p = f"model.layers.{i}."
+        a = p + "self_attn."
+        ukv = sd[a + "kv_b_proj.weight"].T.reshape(R, H, dn + dv)
+        layer = {
+            "ln_attn": {"scale": sd[p + "input_layernorm.weight"]},
+            "ln_ffn": {"scale": sd[p + "post_attention_layernorm.weight"]},
+            "attn": {
+                "wq": sd[a + "q_proj.weight"].T.reshape(E, H, dn + dr),
+                "w_dkv": sd[a + "kv_a_proj_with_mqa.weight"].T,
+                "kv_norm": sd[a + "kv_a_layernorm.weight"],
+                "w_uk": ukv[:, :, :dn], "w_uv": ukv[:, :, dn:],
+                "wo": sd[a + "o_proj.weight"].T.reshape(H, dv, E)}}
+        f = p + "mlp."
+        if is_moe_layer(cfg, i):
+            stack = lambda name: np.stack(
+                [sd[f + f"experts.{k}.{name}.weight"].T
+                 for k in range(cfg.moe.num_experts)])
+            layer["moe"] = {
+                "moe_layer": {
+                    "gate": {"wg": sd[f + "gate.weight"].T,      # [E, n]
+                             "bias": sd[f + "gate.e_score_correction_bias"]},
+                    "experts": {"w_gate": stack("gate_proj"),
+                                "w_up": stack("up_proj"),
+                                "w_down": stack("down_proj")}},
+                "shared_expert": ffn(f + "shared_experts.")}
+        else:
+            layer["ffn"] = ffn(f)
+        t[f"layer_{i}"] = layer
+    return t
+
+
+_CONVERTERS = {"lfm2_moe": _lfm2_moe_tree, "deepseek_v3": _deepseek_v3_tree,
+               "gpt2": _gpt2_tree, "llama": _llama_tree,
                "mistral": _llama_tree, "qwen2": _qwen2_tree,
                "mixtral": _mixtral_tree, "falcon": _falcon_tree,
                "bloom": _bloom_tree, "opt": _opt_tree, "phi": _phi_tree,
@@ -821,6 +878,57 @@ def config_from_hf(hf_config) -> ModelConfig:
                     i >= hf_config.num_dense_layers
                     for i in range(hf_config.num_hidden_layers)),
                 dense_ffn_intermediate=hf_config.intermediate_size))
+    if mt == "deepseek_v3":
+        from .transformer import MoEConfig
+
+        _reject_rope_scaling(hf_config)
+        refused = {
+            "q_lora_rank": getattr(hf_config, "q_lora_rank", None),
+            "n_group > 1": int(getattr(hf_config, "n_group", 1) or 1) > 1,
+            "topk_group > 1": int(getattr(hf_config, "topk_group", 1)
+                                  or 1) > 1,
+            "attention_bias": getattr(hf_config, "attention_bias", False),
+            "a scoring_func other than sigmoid": getattr(
+                hf_config, "scoring_func", "sigmoid") != "sigmoid",
+            "moe_layer_freq > 1": int(getattr(hf_config, "moe_layer_freq",
+                                              1) or 1) > 1}
+        bad = [k for k, v in refused.items() if v]
+        if bad:
+            raise NotImplementedError(
+                f"deepseek_v3 with {', '.join(bad)} is not converted: the "
+                f"program has no low-rank query step and ONE group of "
+                f"experts (group-limited selection is then the identity)")
+        L, dense = hf_config.num_hidden_layers, int(getattr(
+            hf_config, "first_k_dense_replace", 0))
+        shared = int(getattr(hf_config, "n_shared_experts", 0) or 0)
+        return dataclasses.replace(
+            PRESETS["kanana-2-30b-a3b"],
+            vocab_size=hf_config.vocab_size,
+            hidden_size=hf_config.hidden_size, num_layers=L,
+            num_heads=hf_config.num_attention_heads,
+            intermediate_size=hf_config.moe_intermediate_size,
+            max_seq_len=hf_config.max_position_embeddings,
+            rope_theta=float(getattr(hf_config, "rope_theta", 1e4)),
+            norm_eps=hf_config.rms_norm_eps,
+            kv_lora_rank=hf_config.kv_lora_rank,
+            qk_nope_head_dim=hf_config.qk_nope_head_dim,
+            qk_rope_head_dim=hf_config.qk_rope_head_dim,
+            v_head_dim=hf_config.v_head_dim,
+            tie_embeddings=bool(getattr(hf_config, "tie_word_embeddings",
+                                        False)),
+            moe=MoEConfig(
+                num_experts=hf_config.n_routed_experts,
+                top_k=hf_config.num_experts_per_tok,
+                normalize_gates=bool(getattr(hf_config, "norm_topk_prob",
+                                             True)),
+                router_score="sigmoid_bias", dropless=True,
+                moe_layer_pattern=tuple(i >= dense for i in range(L)),
+                dense_ffn_intermediate=hf_config.intermediate_size,
+                shared_expert_intermediate=(
+                    shared * hf_config.moe_intermediate_size or None),
+                shared_expert_gated=False,
+                routed_scaling_factor=float(getattr(
+                    hf_config, "routed_scaling_factor", 1.0))))
     raise NotImplementedError(
         f"no converter for HF model_type '{mt}' (have: "
         f"{sorted(_CONVERTERS)})")

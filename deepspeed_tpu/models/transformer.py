@@ -101,6 +101,13 @@ class MoEConfig:
     # intermediate size added to the routed output through a sigmoid gate
     # (reference inference/v2 qwen_v2_moe shared expert). None = no shared.
     shared_expert_intermediate: int | None = None
+    # False: the shared expert is added as it is, with NO gate (deepseek-v3
+    # ``n_shared_experts``: ONE always-on FFN of n x the experts' width)
+    shared_expert_gated: bool = True
+    # the routed experts' summed output times this constant (deepseek-v3
+    # ``routed_scaling_factor``), applied to the gate weights in ONE place,
+    # ``moe/sharded_moe.py:topk_dropless_gating``; 1.0 = every other preset
+    routed_scaling_factor: float = 1.0
     # renormalize the top-k gate values to sum to 1 (mixtral semantics);
     # False = use the raw softmax probabilities (qwen2-moe's
     # norm_topk_prob=False default)
@@ -180,6 +187,21 @@ class ModelConfig:
                                              # periods: a stack is these
                                              # leading layers, then whole
                                              # periods of ``layer_kinds``
+    kv_lora_rank: int | None = None          # latent attention (MLA,
+                                             # deepseek-v3): keys and values
+                                             # are up-projections of ONE
+                                             # normed latent ``c`` of this
+                                             # width a token, beside ONE
+                                             # rope key of
+                                             # ``qk_rope_head_dim`` shared by
+                                             # the heads; None → per-head
+                                             # keys and values (every other
+                                             # preset)
+    qk_nope_head_dim: int = 0                # MLA: a head's query/key width
+                                             # without position ...
+    qk_rope_head_dim: int = 0                # ... and with rope; the score
+                                             # scale is (nope + rope)^-0.5
+    v_head_dim: int = 0                      # MLA: a head's value width
     conv_taps: int = 3                       # taps a channel of a "conv"
                                              # layer's depthwise causal
                                              # convolution (LFM2
@@ -212,6 +234,13 @@ class ModelConfig:
     @property
     def head_dim(self) -> int:
         return self.head_size or self.hidden_size // self.num_heads
+
+    @property
+    def latent_width(self) -> int:
+        """Values a token a layer that latent attention keeps of the past:
+        the latent and the shared rope key (0: not a latent model)."""
+        return (self.kv_lora_rank + self.qk_rope_head_dim) \
+            if self.kv_lora_rank else 0
 
     @property
     def kinds_period(self) -> tuple[str, ...]:
@@ -259,6 +288,13 @@ class ModelConfig:
         f = self.ffn_size
         attn = h * self.num_heads * self.head_dim + 2 * h * self.kv_heads * self.head_dim \
             + self.num_heads * self.head_dim * h
+        if self.kv_lora_rank:
+            # W_q, W_dkv and its norm, W_uk | W_uv, W_o
+            R, H = self.kv_lora_rank, self.num_heads
+            qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+            attn = h * H * qk + h * self.latent_width + R \
+                + R * H * (self.qk_nope_head_dim + self.v_head_dim) \
+                + H * self.v_head_dim * h
         glu = self.activation in GLU_ACTS
 
         def dense(width):
@@ -271,7 +307,8 @@ class ModelConfig:
             if self.moe.router_score == "sigmoid_bias":
                 ffn_moe += self.moe.num_experts
             if self.moe.shared_expert_intermediate:
-                ffn_moe += 3 * h * self.moe.shared_expert_intermediate + h
+                ffn_moe += 3 * h * self.moe.shared_expert_intermediate \
+                    + (h if self.moe.shared_expert_gated else 0)
         if self.qkv_bias:
             attn += self.num_heads * self.head_dim \
                 + 2 * self.kv_heads * self.head_dim
@@ -406,10 +443,16 @@ def qk_norm(cfg: "ModelConfig", x: jax.Array, scale: jax.Array) -> jax.Array:
     Statistics in float32, affine in the input dtype, as :class:`Norm`."""
     if cfg.qk_norm not in QK_NORMS:
         raise ValueError(f"qk_norm {cfg.qk_norm!r} is not one of {QK_NORMS}")
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)),
-                   axis=-1 if cfg.qk_norm == "head" else (-2, -1),
+    return _rms_scale(x, scale, cfg.norm_eps,
+                      -1 if cfg.qk_norm == "head" else (-2, -1))
+
+
+def _rms_scale(x: jax.Array, scale: jax.Array, eps: float, axis=-1):
+    """``x`` RMS-normalised over ``axis`` times ``scale``: statistics in
+    float32, affine in the input dtype, as :class:`Norm`."""
+    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=axis,
                    keepdims=True)
-    inv = jax.lax.rsqrt(var + cfg.norm_eps)
+    inv = jax.lax.rsqrt(var + eps)
     return x * inv.astype(x.dtype) * scale.astype(x.dtype)
 
 
@@ -645,6 +688,95 @@ class Attention(nn.Module):
         return out
 
 
+class LatentAttention(nn.Module):
+    """Multi-head LATENT attention (MLA, deepseek-v3 with ``q_lora_rank``
+    null), the EXPANDED form: training, v1 and the reference of the serving
+    forward, which runs the ABSORBED form of the same function over the
+    cached latent (``inference/forward.py``).
+
+    ``q = x W_q`` (a head's ``nope | rope`` columns); ``[c | k_r] = x
+    W_dkv``; ``c = RMSNorm(c)``; ``k_r`` is ONE rope key shared by the
+    heads; ``k_nope_h = c W_uk,h``, ``v_h = c W_uv,h`` (``kv_b_proj`` kept
+    as its two halves: the absorbed form folds them on different sides of
+    the attention); rope on ``q_rope`` and ``k_r`` only, interleaved pairs
+    (HF ``rope_interleave``); scores over ``nope + rope`` scaled by
+    ``(nope + rope)^-0.5``. No bias. What a later token needs of the past
+    is ``c`` and ``k_r``: ``ModelConfig.latent_width`` values a token."""
+    config: ModelConfig
+    kind: str = ""
+
+    @nn.compact
+    def __call__(self, x, positions, kv_cache=None, attn_mask=None):
+        cfg = self.config
+        if kv_cache is not None:
+            raise ValueError(
+                "latent attention (kv_lora_rank) has no v1 kv_cache: serve "
+                "it through InferenceEngineV2 (a latent page a token)")
+        if cfg.qkv_bias or cfg.qk_norm or cfg.attn_out_bias \
+                or not kind_ropes(cfg, self.kind or cfg.layer_kind(0)):
+            raise ValueError("latent attention runs with rope, without "
+                             "biases and without a q/k norm")
+        H, R = cfg.num_heads, cfg.kv_lora_rank
+        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, \
+            cfg.v_head_dim
+        E, dt = cfg.hidden_size, cfg.dtype
+        part = lambda init, names: nn.with_partitioning(init, names)
+        wq = self.param("wq", part(_dense_init(), ("embed", "heads",
+                                                   "head_dim")),
+                        (E, H, dn + dr), jnp.float32)
+        w_dkv = self.param("w_dkv", part(_dense_init(), ("embed", None)),
+                           (E, R + dr), jnp.float32)
+        kv_norm = self.param("kv_norm", part(nn.initializers.ones, (None,)),
+                             (R,), jnp.float32)
+        w_uk = self.param("w_uk", part(_dense_init(), (None, "heads",
+                                                       "head_dim")),
+                          (R, H, dn), jnp.float32)
+        w_uv = self.param("w_uv", part(_dense_init(), (None, "heads",
+                                                       "head_dim")),
+                          (R, H, dv), jnp.float32)
+        wo = self.param("wo", part(_dense_init(), ("heads", "head_dim",
+                                                   "embed")),
+                        (H, dv, E), jnp.float32)
+        q = jnp.einsum("bse,ehd->bshd", x, wq.astype(dt))
+        c, k_r = latent_row(cfg, x, w_dkv, kv_norm)
+        q_r, k_r = apply_rope(q[..., dn:], k_r[:, :, None, :], positions,
+                              cfg.rope_theta)
+        q = jnp.concatenate([q[..., :dn], q_r], axis=-1)
+        k = jnp.concatenate(
+            [jnp.einsum("bsr,rhd->bshd", c, w_uk.astype(dt)),
+             jnp.broadcast_to(k_r, (*k_r.shape[:2], H, dr))], axis=-1)
+        v = jnp.einsum("bsr,rhd->bshd", c, w_uv.astype(dt))
+        q = checkpoint_name(constrain(q, BATCH, None, HEADS, None), "attn_q")
+        k = checkpoint_name(constrain(k, BATCH, None, HEADS, None), "attn_k")
+        v = checkpoint_name(constrain(v, BATCH, None, HEADS, None), "attn_v")
+        # (query and value widths differ: XLA attention, scaled by the
+        # query's width, nope + rope)
+        out = dot_product_attention(q, k, v, causal=cfg.causal,
+                                    mask=attn_mask, impl="xla")
+        out = constrain(out, BATCH, SEQ, None, None)
+        out = jnp.einsum("bshd,hde->bse", out, wo.astype(dt))
+        return checkpoint_name(constrain(out, BATCH, SEQ, EMBED),
+                               "attn_proj")
+
+
+def latent_row(cfg: "ModelConfig", x: jax.Array, w_dkv: jax.Array,
+               kv_norm: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """``[c | k_r] = x W_dkv`` with ``c`` RMS-normalised over its own width
+    (``kv_a_layernorm``; statistics in float32, as :class:`Norm`): the
+    latent ``[..., kv_lora_rank]`` and the rope key BEFORE rope ``[...,
+    qk_rope_head_dim]`` — shared by the flax block and the serving
+    forward."""
+    ckr = jnp.einsum("...e,er->...r", x, w_dkv.astype(x.dtype))
+    return (_rms_scale(ckr[..., :cfg.kv_lora_rank], kv_norm, cfg.norm_eps),
+            ckr[..., cfg.kv_lora_rank:])
+
+
+def attention_cls(cfg: "ModelConfig"):
+    """The flax module of a layer's attention: latent (MLA) where the model
+    has ``kv_lora_rank``, else per-head keys and values."""
+    return LatentAttention if cfg.kv_lora_rank else Attention
+
+
 class ShortConv(nn.Module):
     """A "conv" layer's operator over a whole sequence (training, v1
     prefill): :func:`conv_mix` from zeros. No bias anywhere (LFM2
@@ -778,6 +910,7 @@ def moe_layer_kwargs(cfg: ModelConfig, **overrides) -> dict:
         dropless_block_m=moe.dropless_block_m,
         normalize_gates=moe.normalize_gates,
         router_score=moe.router_score,
+        routed_scaling_factor=moe.routed_scaling_factor,
     )
     kw.update(overrides)
     return kw
@@ -800,6 +933,8 @@ class MoEFFN(nn.Module):
         if se:
             shared_cfg = dataclasses.replace(cfg, intermediate_size=se)
             shared = DenseFFN(shared_cfg, name="shared_expert")(x)
+            if not cfg.moe.shared_expert_gated:
+                return out + shared
             gate = self.param("shared_gate", nn.with_partitioning(
                 _dense_init(), ("embed", None)),
                 (cfg.hidden_size, 1), jnp.float32)
@@ -837,7 +972,7 @@ class Block(nn.Module):
             # gpt-neox/falcon-40b keep separate norms per branch
             # (parallel_block_norms=2) — reference falcon/gptneox containers
             h = Norm(cfg, name="ln_attn")(x)
-            attn_out = Attention(cfg, self.kind, name="attn")(h, positions,
+            attn_out = attention_cls(cfg)(cfg, self.kind, name="attn")(h, positions,
                                                    kv_cache=kv_cache,
                                                    attn_mask=attn_mask)
             if kv_cache is not None:
@@ -860,7 +995,7 @@ class Block(nn.Module):
         if not cfg.pre_norm:
             # post-norm residuals (original BERT layout; the reference's
             # DeepSpeedTransformerConfig pre_layer_norm=False mode)
-            attn_out = Attention(cfg, self.kind, name="attn")(x, positions,
+            attn_out = attention_cls(cfg)(cfg, self.kind, name="attn")(x, positions,
                                                    kv_cache=kv_cache,
                                                    attn_mask=attn_mask)
             if kv_cache is not None:
@@ -878,7 +1013,7 @@ class Block(nn.Module):
             return x
 
         h_in = Norm(cfg, name="ln_attn")(x)
-        attn_out = Attention(cfg, self.kind, name="attn")(
+        attn_out = attention_cls(cfg)(cfg, self.kind, name="attn")(
             h_in, positions, kv_cache=kv_cache, attn_mask=attn_mask)
         if kv_cache is not None:
             attn_out, new_cache = attn_out

@@ -45,6 +45,8 @@ class TopKGate(nn.Module):
     #: ``bias`` [n] (seeded small and NON-zero, so that selection by
     #: ``s + b`` and weights from ``s`` differ under seeded weights)
     router_score: str = "softmax"
+    #: ``MoEConfig.routed_scaling_factor`` (dropless routing only)
+    routed_scaling_factor: float = 1.0
 
     @nn.compact
     def __call__(self, x: jax.Array, deterministic: bool = True):
@@ -57,6 +59,9 @@ class TopKGate(nn.Module):
         rng = None
         if self.noisy_gate_policy == "RSample" and not deterministic:
             rng = self.make_rng("gating")
+        if self.routed_scaling_factor != 1.0 and not self.dropless:
+            raise ValueError("routed_scaling_factor routes dropless only "
+                             "(MoEConfig.dropless=True)")
         bias = None
         if self.router_score != "softmax":
             if not self.dropless:
@@ -70,7 +75,8 @@ class TopKGate(nn.Module):
         if self.dropless:
             return topk_dropless_gating(logits, self.k, noise_rng=rng,
                                         normalize_gates=self.normalize_gates,
-                                        score=self.router_score, bias=bias)
+                                        score=self.router_score, bias=bias,
+                                        scale=self.routed_scaling_factor)
         return topkgating(
             logits, self.k,
             self.eval_capacity_factor if deterministic else self.capacity_factor,
@@ -205,6 +211,7 @@ class MoE(nn.Module):
     dropless_block_m: int = 128
     normalize_gates: bool = True
     router_score: str = "softmax"
+    routed_scaling_factor: float = 1.0
 
     @nn.compact
     def __call__(self, x: jax.Array, deterministic: bool = True,
@@ -222,6 +229,7 @@ class MoE(nn.Module):
             drop_tokens=self.drop_tokens, dropless=self.dropless,
             normalize_gates=self.normalize_gates,
             router_score=self.router_score,
+            routed_scaling_factor=self.routed_scaling_factor,
             name="gate")(x if router_x is None else router_x, deterministic)
 
         self.sow("losses", "moe_aux_loss",

@@ -120,8 +120,8 @@ def topk_dropless_gating(logits: jax.Array, k: int, *,
                          noise_eps: float = 1e-2,
                          normalize_gates: bool = True,
                          score: str = "softmax",
-                         bias: jax.Array | None = None
-                         ) -> DroplessGateOutput:
+                         bias: jax.Array | None = None,
+                         scale: float = 1.0) -> DroplessGateOutput:
     """Top-k routing with NO capacity and NO drops — every token reaches
     all k chosen experts (the megablocks contract; tokens are instead
     block-aligned per expert by ``sort_tokens_by_expert``).
@@ -131,7 +131,11 @@ def topk_dropless_gating(logits: jax.Array, k: int, *,
     renormalised where ``normalize_gates``; "sigmoid_bias" — ``s =
     sigmoid(logits)``, the experts are the k largest of ``s + bias`` (the
     bias ``[n]`` moves the SELECTION only), the weights are ``s`` at the
-    chosen k, divided by their sum + 1e-6 where ``normalize_gates``."""
+    chosen k, divided by their sum + 1e-6 where ``normalize_gates``.
+    ``scale`` (``MoEConfig.routed_scaling_factor``, applied HERE and nowhere
+    else): the gate weights times a constant, after the normalisation —
+    the routed experts' summed output scaled (deepseek-v3: 2.448 where six
+    normalised weights would sum to 1)."""
     G, S, n = logits.shape
     logits = logits.astype(jnp.float32)
     if noise_rng is not None:
@@ -153,6 +157,8 @@ def topk_dropless_gating(logits: jax.Array, k: int, *,
     else:
         raise ValueError(f"router score {score!r} is not one of "
                          f"{ROUTER_SCORES}")
+    if scale != 1.0:
+        gate_vals = gate_vals * scale
 
     onehot = jax.nn.one_hot(expert_idx, n, dtype=jnp.float32)      # [G,S,k,n]
     me = jnp.mean(probs, axis=(0, 1))

@@ -59,12 +59,13 @@ NEG_INF = float(jnp.finfo(jnp.float32).min)
 
 def paged_attention_usable(num_heads: int, kv_heads: int, head_dim: int,
                            block_size: int) -> bool:
-    """Gate: MXU-friendly head_dim, sublane-aligned pages, even GQA groups."""
+    """Gate: MXU-friendly head_dim (or a row of whole 128-lane registers:
+    the latent form's 640), sublane-aligned pages, even GQA groups."""
     if num_heads % kv_heads:
         return False
     if block_size % 8:
         return False
-    return head_dim in (64, 128, 256)
+    return head_dim in (64, 128, 256) or head_dim % 128 == 0
 
 
 def _paged_attn_kernel(tables_ref, lens_ref, starts_ref, q_ref, k_ref, v_ref,
@@ -237,7 +238,7 @@ class PagedPlan(NamedTuple):
 
 
 def paged_plan(TG: int, KV: int, block_size: int, dtype,
-               tree: bool = False) -> PagedPlan:
+               tree: bool = False, lanes: int = 128) -> PagedPlan:
     """The query tile for ``TG`` rows a KV head: the TALLEST divisor of
     ``TG`` that is a multiple of ``dtype``'s sublane tile and keeps the f32
     score tile within :data:`SCORE_TILE_BYTES` — the grid is (query tiles,
@@ -247,9 +248,15 @@ def paged_plan(TG: int, KV: int, block_size: int, dtype,
     ``KV`` are the shard's. Where no such divisor is taller than
     :data:`ONE_TILE_ROWS` (always for ``TG`` within it, and for the tree
     form, whose per-row operands tile by 128 lanes) the tile is that many
-    rows, halved until it fits — never under 8 — and divides ``TG``."""
+    rows, halved until it fits — never under 8 — and divides ``TG``.
+    ``lanes``: the width of a query row. Beside the score tile a step holds
+    the query and output blocks and the accumulator, which grow with it: a
+    row wider than 256 lanes (the latent form's 640) cuts the tile in
+    proportion, so that the whole stays inside :data:`VMEM_LIMIT_BYTES`
+    (1,024 rows at 640 lanes: 27 MiB by the compiler's own count)."""
     plan = lambda t: PagedPlan(TG, KV, block_size, t)
-    fits = lambda t: plan(t).score_tile_bytes <= SCORE_TILE_BYTES
+    budget = SCORE_TILE_BYTES * 256 // max(lanes, 256)
+    fits = lambda t: plan(t).score_tile_bytes <= budget
     if TG > ONE_TILE_ROWS and not tree:
         sub = 32 // jnp.dtype(dtype).itemsize
         tall = max((t for t in range(sub, TG + 1, sub)
@@ -357,7 +364,7 @@ def _ragged_attn_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
                         *refs, block_size: int, scale: float, G: int,
                         window: int, ring_tokens: int, srows: int,
                         jbits: int, n_pool: int, p_scale: float = 1.0,
-                        tree: bool = False):
+                        tree: bool = False, value_lanes: int = 0):
     """Read-only-pool ragged attention, ALL kv heads per grid step.
 
     What the measured costs on real hardware made of
@@ -401,6 +408,11 @@ def _ragged_attn_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
     the ramp); stage columns take the tree mask VERBATIM, replacing the
     positional mask — exactly the gather formulation's split in
     inference/forward.py (`RaggedForward`).
+
+    ``value_lanes`` > 0 (the LATENT form: one row a token shared by every
+    query head, the page ``[1, bs, lanes]`` with no V half): ``vp_ref`` and
+    ``vs_ref`` are None and the value is the first ``value_lanes`` lanes of
+    the key row — a page is read ONCE for scores and values.
 
     Grid (q-tiles, n_items).
     ``refs`` = ([tpos, tmask when tree,] o, m_scr, l_scr, acc_scr).
@@ -480,7 +492,7 @@ def _ragged_attn_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
     def _pool_step():
         q = q_ref[0]                                       # [KV, TQB, D]
         k = kp_ref[0, 0, :, 0]                             # [KV, bs, D]
-        v = vp_ref[0, 0, :, 0]
+        v = k[..., :value_lanes] if value_lanes else vp_ref[0, 0, :, 0]
         if k.dtype != q.dtype:
             # fp8 KV pool: converting the PAGE up costs ~10us/page in
             # Mosaic (element-wise + sublane relayout); converting the
@@ -522,7 +534,7 @@ def _ragged_attn_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
     def _stage_step():
         q = q_ref[0]                                       # [KV, TQB, D]
         k = ks_ref[0]                                      # [KV, srows, D]
-        v = vs_ref[0]
+        v = k[..., :value_lanes] if value_lanes else vs_ref[0]
         scores = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale
@@ -545,6 +557,15 @@ def _ragged_attn_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
         o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
 
 
+def _latent_attn_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
+                        work_ref, q_ref, kp_ref, ks_ref, *refs, **kw):
+    """:func:`_ragged_attn_kernel` without the V operands (its latent
+    form)."""
+    _ragged_attn_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
+                        work_ref, q_ref, kp_ref, None, ks_ref, None, *refs,
+                        **kw)
+
+
 def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
                            seq_lens, q_starts, stage_starts, *,
                            block_size: int, layer_index,
@@ -552,6 +573,7 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
                            window: int | None = None,
                            ring_tokens: int | None = None,
                            tree_positions=None, tree_mask=None, work=None,
+                           value_lanes: int | None = None,
                            interpret: bool | None = None):
     """Ragged attention over a READ-ONLY paged pool plus a staged tail.
 
@@ -579,9 +601,29 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
     pages keep the positional-causal walk using the per-node positions,
     stage columns take the mask verbatim. Both args come together.
     Returns [S, T, H, D].
+
+    The LATENT form (``value_lanes``; latent attention absorbed, a page
+    kind of its own): ``pool`` ``[L, 1, 1, nb, bs, D]`` — ONE row a token,
+    no K/V halves, shared by all ``H`` query heads (``G = H``) — ``k_stage``
+    ``[S, 1, Ts, D]``, ``v_stage`` None; the value of a key row is its first
+    ``value_lanes`` lanes, so a page is read once for scores and values.
+    ``D`` is the row as stored (lane-padded; the query carries zeros in the
+    padding) and ``scale`` must be given (the model's, not ``D``'s).
+    Returns ``[S, T, H, value_lanes]``. Same plan, same work list.
     """
     S, T, H, D = q.shape
-    L, _, KV, nb, bs, _ = pool.shape
+    L, halves, KV, nb, bs, _ = pool.shape
+    Dv = int(value_lanes or D)
+    if (halves == 1) != bool(value_lanes) or (v_stage is None) != bool(
+            value_lanes):
+        raise ValueError(
+            f"a pool of {halves} half(s) a page with value_lanes "
+            f"{value_lanes!r}: the latent form takes a pool without K/V "
+            f"halves, no v_stage and the value's width, together")
+    if value_lanes and (scale is None or tree_positions is not None
+                        or KV != 1):
+        raise ValueError("the latent form: one shared row (KV 1), the "
+                         "model's own scale, no tree")
     if bs != block_size:
         raise ValueError(f"pool block dim {bs} != block_size {block_size}")
     if H % KV:
@@ -615,7 +657,7 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
     TG = T * G
     # query-row tiles bound VMEM for long prefill chunks; stage pages
     # bound it on the key side (uniform page-sized score tiles)
-    TQB = paged_plan(TG, KV, bs, q.dtype, tree).tqb
+    TQB = paged_plan(TG, KV, bs, q.dtype, tree, lanes=D).tqb
     n_pool = max_pages
     nsp, srows = _ragged_geometry(Ts, bs)
     jbits = _item_bits(n_pool + nsp)
@@ -692,33 +734,33 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
         grid=(TG // TQB, n_items),
         in_specs=[
             pl.BlockSpec((1, KV, TQB, D), q_index),
-            pool_spec(0),
-            pool_spec(1),
-            stage_spec(),
-            stage_spec(),
+            *([pool_spec(0), stage_spec()] if value_lanes else
+              [pool_spec(0), pool_spec(1), stage_spec(), stage_spec()]),
             *tree_specs,
         ],
-        out_specs=pl.BlockSpec((1, KV, TQB, D), q_index),
+        out_specs=pl.BlockSpec((1, KV, TQB, Dv), q_index),
         scratch_shapes=[
             pltpu.VMEM((KV, TQB, 1), jnp.float32),
             pltpu.VMEM((KV, TQB, 1), jnp.float32),
-            pltpu.VMEM((KV, TQB, D), jnp.float32),
+            pltpu.VMEM((KV, TQB, Dv), jnp.float32),
         ],
     )
     # fp8 pools scale p into e4m3's normal range (the e4m3 max, 448) so
     # long-context attention weights survive the fp8 PV-dot cast; the
     # matching l accumulation cancels the scale exactly at finalize
     p_scale = 448.0 if pool.dtype == jnp.float8_e4m3fn else 1.0
+    kw = dict(block_size=block_size, scale=float(scale), G=G,
+              window=int(window or 0), ring_tokens=int(ring_tokens or 0),
+              srows=srows, jbits=jbits, n_pool=n_pool, p_scale=p_scale,
+              tree=tree)
+    form = "decode" if T == 1 else "prefill"
     out = pl.pallas_call(
-        functools.partial(_ragged_attn_kernel, block_size=block_size,
-                          scale=float(scale), G=G, window=int(window or 0),
-                          ring_tokens=int(ring_tokens or 0), srows=srows,
-                          jbits=jbits, n_pool=n_pool, p_scale=p_scale,
-                          tree=tree),
+        functools.partial(_latent_attn_kernel, value_lanes=Dv, **kw)
+        if value_lanes else functools.partial(_ragged_attn_kernel, **kw),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, KV, TG, D), q.dtype),
-        name=("paged_attn_tree" if tree else "paged_attn_decode" if T == 1
-              else "paged_attn_prefill"),
+        out_shape=jax.ShapeDtypeStruct((S, KV, TG, Dv), q.dtype),
+        name=("paged_attn_tree" if tree else f"paged_latent_{form}"
+              if value_lanes else f"paged_attn_{form}"),
         # a call of one tile (every decode and tree program) is compiled as
         # it always was; a taller tile states what it may use
         compiler_params=(pltpu.CompilerParams(
@@ -728,9 +770,10 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
       q_starts.astype(jnp.int32), stage_starts.astype(jnp.int32),
       jnp.asarray(layer_index, jnp.int32).reshape(1), items,
-      qg, pool, pool, k_stage, v_stage, *tree_ops)
-    return (out.reshape(S, KV, T, G, D).transpose(0, 2, 1, 3, 4)
-            .reshape(S, T, H, D))
+      qg, *((pool, k_stage) if value_lanes
+            else (pool, pool, k_stage, v_stage)), *tree_ops)
+    return (out.reshape(S, KV, T, G, Dv).transpose(0, 2, 1, 3, 4)
+            .reshape(S, T, H, Dv))
 
 
 def paged_prefill_attention(q, k_pool, v_pool, block_tables, seq_lens,

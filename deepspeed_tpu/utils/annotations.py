@@ -34,6 +34,11 @@ DEVICE_SCOPES = (
     # scopes, and the one write of a program's records (per-slot state that
     # is not pages: inference/forward.py ``merge_records``)
     "conv_mix", "state_commit",
+    # latent attention absorbed (inference/forward.py ``latent_qkv``): the
+    # down-projection to ``[c | k_r]``, its norm and rope, the keys'
+    # up-projection folded into the query, and the values' up-projection of
+    # the attended latents — what the latent page costs beside the kernel
+    "latent_absorb",
     # training (models/transformer.py, models/loss.py, runtime/engine.py)
     "head_loss", "optimizer", "grad_check", "zero_gather", "zero_reduce",
 )

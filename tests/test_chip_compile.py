@@ -116,6 +116,29 @@ def _ragged(S, T, pool_dtype, tree=False, H=16, KV=16, D=64, bs=128,
     return build
 
 
+def _latent(S, T, H=32, lanes=640, value=512, bs=128, max_pages=256):
+    """The LATENT form of the ragged kernel at kanana-2's widths: ONE row
+    of 576 values in 640 lanes a token, no K/V halves, 32 query heads over
+    it, the value its first 512 lanes."""
+    def build(devs):
+        from deepspeed_tpu.ops.pallas.paged_attention import \
+            paged_ragged_attention
+        mk = lambda shape, dt: _sds(_one(devs), shape, dt)
+        Ts = max(8, T)
+        args = (mk((S, T, H, lanes), BF16),
+                mk((2, 1, 1, 64, bs, lanes), BF16),
+                mk((S, 1, Ts, lanes), BF16),
+                mk((S, max_pages), jnp.int32), mk((S,), jnp.int32),
+                mk((S,), jnp.int32), mk((S,), jnp.int32))
+
+        def fn(q, pool, ks, bt, sl, qs, ss):
+            return paged_ragged_attention(
+                q, pool, ks, None, bt, sl, qs, ss, block_size=bs,
+                layer_index=1, scale=192 ** -0.5, value_lanes=value)
+        return fn, args, True
+    return build
+
+
 def _ragged_tp4(devs):
     """The ``tensor: 4`` serving layout (``inference/forward.py``):
     the kernel per shard under shard_map, heads split four ways over a
@@ -235,7 +258,7 @@ def _grouped(backward):
     return build
 
 
-def _grouped_serving(tokens, down=False, k=8, E=2048, F=1024):
+def _grouped_serving(tokens, down=False, k=8, E=2048, F=1024, n=64):
     """The serving form at OLMoE-1B-7B's widths (64 experts, 8 a token,
     hidden 2048, expert width 1024): the depth-stacked slab with the layer
     picked inside the kernel, the sort's ``n_tiles`` skipping the buffer's
@@ -247,7 +270,7 @@ def _grouped_serving(tokens, down=False, k=8, E=2048, F=1024):
         from deepspeed_tpu.ops.pallas.grouped_matmul import \
             grouped_matmul_layer
         one = _one(devs)
-        L, n = 2, 64
+        L = 2
         bm = moe_tile_rows(tokens, k, n)
         Tp = moe_padded_rows(tokens, k, n, bm)
         K, N = (F, E) if down else (E, F)
@@ -314,6 +337,8 @@ MISTRAL = dict(H=32, KV=8, D=128, max_pages=128)
 THINKER = dict(H=28, KV=4, D=128)
 THINKER_RING = dict(max_pages=37, window=4096, ring=True, **THINKER)
 THINKER_MOE = dict(k=6, E=2560, F=768)
+#: kanana-2-30b-a3b: 128 experts of 768 over hidden 2048, 6 a token
+KANANA_MOE = dict(k=6, E=2048, F=768, n=128)
 OLMOE = dict(H=16, KV=16, D=128, max_pages=32)
 
 CASES = {
@@ -328,6 +353,11 @@ CASES = {
     "ragged_tree_s8_fp8": _ragged(8, 8, FP8, tree=True),
     # 24 nodes at page 16: the stage (and the ancestors mask) spans 2 pages
     "ragged_tree_s8_t24_page16": _ragged(8, 24, BF16, tree=True, bs=16),
+    # kanana-2's latent page: 48 decode rows, and a 512-token chunk (16,384
+    # query rows: 16 tiles of 1,024) over a table of 256 pages
+    "latent_decode_s48_p256": _latent(48, 1),
+    "latent_chunk512_s1_p256": _latent(1, 512),
+    "latent_chunk512_s12_p256": _latent(12, 512),
     "grouped_gemm_fwd": _grouped(False),
     "grouped_gemm_bwd": _grouped(True),
     "grouped_gemm_olmoe_decode_up": _grouped_serving(48),
@@ -370,6 +400,14 @@ CASES = {
     "grouped_gemm_thinker_prefill_up": _grouped_serving(512, **THINKER_MOE),
     "grouped_gemm_thinker_prefill_down": _grouped_serving(
         512, down=True, **THINKER_MOE),
+    # a decode step of 48 rows (16 rows a tile) and a prefill step of 12
+    # rows x 512 tokens with its 48 riding rows (128)
+    "grouped_gemm_kanana_decode_up": _grouped_serving(48, **KANANA_MOE),
+    "grouped_gemm_kanana_decode_down": _grouped_serving(
+        48, down=True, **KANANA_MOE),
+    "grouped_gemm_kanana_prefill_up": _grouped_serving(6192, **KANANA_MOE),
+    "grouped_gemm_kanana_prefill_down": _grouped_serving(
+        6192, down=True, **KANANA_MOE),
     "quant_int8_m8": _quant(8, 8),
     "quant_int8_m512": _quant(8, 512),
     "quant_int4_m8": _quant(4, 8),
@@ -438,7 +476,8 @@ def test_kernel_compiles_for_v5e(name, topo):
 
 
 @pytest.mark.parametrize("name", [n for n in CASES if n.startswith(
-    ("grouped_gemm_olmoe_", "grouped_gemm_thinker_"))])
+    ("grouped_gemm_olmoe_", "grouped_gemm_thinker_",
+     "grouped_gemm_kanana_"))])
 def test_grouped_gemm_block_fits_the_plans_budget(name, topo, monkeypatch):
     """The decode form (16 rows a tile) and the 128-row prefill form at the
     published OLMoE and SmallThinker widths take their expert matrix in ONE
@@ -462,6 +501,8 @@ def test_grouped_gemm_block_fits_the_plans_budget(name, topo, monkeypatch):
     ("ragged_decode_bf16", "paged_attn_decode"),
     ("ragged_chunk512_bf16", "paged_attn_prefill"),
     ("ragged_tree_s8_bf16", "paged_attn_tree"),
+    ("latent_decode_s48_p256", "paged_latent_decode"),
+    ("latent_chunk512_s1_p256", "paged_latent_prefill"),
     ("ragged_decode_h16_kv16_d128", "paged_attn_decode"),
     ("ragged_chunk128_h16_kv16_d128", "paged_attn_prefill"),
     ("ragged_decode_mistral_s48_p128", "paged_attn_decode"),

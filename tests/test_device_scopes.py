@@ -40,6 +40,10 @@ TRAINING = ("embed", "head_loss", "optimizer", "grad_check", "zero_gather",
 #: to their compiled programs, and to no other model's, by
 #: tests/test_lfm2_moe.py)
 RECORDS = ("conv_mix", "state_commit")
+#: latent attention absorbed: what the latent page costs beside the kernel
+#: (held to its compiled programs, and to no other model's, by
+#: tests/test_kanana2_pages.py)
+LATENT = ("latent_absorb",)
 
 #: lowerings and backend compiles seen by this process, in order
 _EVENTS: list[str] = []
@@ -123,7 +127,7 @@ def test_every_use_is_declared_and_every_declaration_used():
         {k: v for k, v in used.items() if k not in DEVICE_SCOPES}
     assert not set(DEVICE_SCOPES) - set(used)
     assert set(SERVING) | set(MOE) | set(TRAINING) | set(KINDS) \
-        | set(RECORDS) == set(DEVICE_SCOPES)
+        | set(RECORDS) | set(LATENT) == set(DEVICE_SCOPES)
 
 
 @pytest.mark.parametrize("name", SERVING)

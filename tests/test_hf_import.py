@@ -698,3 +698,119 @@ def test_lfm2_moe_tree_matches_the_reference_in_the_checkpoints_rotation():
         config_from_hf(SimpleNamespace(model_type="lfm2_moe",
                                        rope_scaling=None,
                                        **dict(LFM2, conv_bias=True)))
+
+
+# ---------------------------------------------------------------------------
+# deepseek_v3 without a low-rank query step (kanana-2): the name map, on a
+# synthetic state dict at tiny widths that all differ
+# ---------------------------------------------------------------------------
+
+DSV3 = dict(vocab_size=128, hidden_size=64, intermediate_size=96,
+            moe_intermediate_size=32, num_hidden_layers=3,
+            num_attention_heads=4, num_key_value_heads=4,
+            max_position_embeddings=64, rms_norm_eps=1e-6,
+            n_routed_experts=8, num_experts_per_tok=2, n_shared_experts=2,
+            norm_topk_prob=True, routed_scaling_factor=2.448,
+            scoring_func="sigmoid", topk_method="noaux_tc", n_group=1,
+            topk_group=1, first_k_dense_replace=1, moe_layer_freq=1,
+            kv_lora_rank=24, q_lora_rank=None, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=12, rope_theta=10000.0,
+            rope_interleave=True, attention_bias=False,
+            tie_word_embeddings=False)
+
+
+def _dsv3_state_dict(rng):
+    """A synthetic state dict under HF ``DeepseekV3``'s own key names."""
+    E, F, Fe, n, V, H = 64, 96, 32, 8, 128, 4
+    R, dn, dr, dv = 24, 16, 8, 12
+    r = lambda *shape: rng.standard_normal(shape).astype(np.float32) * 0.1
+    sd = {"model.embed_tokens.weight": r(V, E),
+          "model.norm.weight": 1 + r(E), "lm_head.weight": r(V, E)}
+    mlp = lambda base, width: {base + "gate_proj.weight": r(width, E),
+                               base + "up_proj.weight": r(width, E),
+                               base + "down_proj.weight": r(E, width)}
+    for i in range(3):
+        p = f"model.layers.{i}."
+        a = p + "self_attn."
+        sd.update({p + "input_layernorm.weight": 1 + r(E),
+                   p + "post_attention_layernorm.weight": 1 + r(E),
+                   a + "q_proj.weight": r(H * (dn + dr), E),
+                   a + "kv_a_proj_with_mqa.weight": r(R + dr, E),
+                   a + "kv_a_layernorm.weight": 1 + 3 * r(R),
+                   a + "kv_b_proj.weight": r(H * (dn + dv), R),
+                   a + "o_proj.weight": r(E, H * dv)})
+        f = p + "mlp."
+        if i < 1:
+            sd.update(mlp(f, F))
+            continue
+        sd.update({f + "gate.weight": r(n, E),
+                   f + "gate.e_score_correction_bias": 0.2 * r(n),
+                   **mlp(f + "shared_experts.", 2 * Fe)})
+        for k in range(n):
+            sd.update(mlp(f + f"experts.{k}.", Fe))
+    return sd
+
+
+def test_deepseek_v3_tree_places_every_tensor_and_matches_the_reference():
+    """Every tensor placed; ``kv_a_proj_with_mqa`` split into ``c`` and
+    ``k_r`` in the stored order; ``kv_b_proj`` into a head's ``k_nope | v``;
+    nothing permuted (``rope_interleave``); the converted tree through the
+    program's EXPANDED forward equals the plain reference."""
+    import importlib.util
+    from types import SimpleNamespace
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models.hf import (_deepseek_v3_tree, _TrackedSD,
+                                         config_from_hf)
+    from deepspeed_tpu.models.transformer import TransformerLM
+
+    hf = lambda **over: SimpleNamespace(
+        model_type="deepseek_v3", **{**DSV3, "rope_scaling": None, **over})
+    cfg = dataclasses.replace(config_from_hf(hf()), dtype=jnp.float32)
+    assert (cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+            cfg.v_head_dim) == (24, 16, 8, 12)
+    assert (cfg.moe.router_score, cfg.moe.moe_layer_pattern, cfg.ffn_size,
+            cfg.moe.dense_ffn_intermediate,
+            cfg.moe.shared_expert_intermediate, cfg.moe.shared_expert_gated,
+            cfg.moe.routed_scaling_factor, cfg.tie_embeddings) == (
+        "sigmoid_bias", (False, True, True), 32, 96, 64, False, 2.448, False)
+    sd = _TrackedSD(_dsv3_state_dict(np.random.default_rng(0)))
+    tree = _deepseek_v3_tree(sd, cfg)
+    assert set(sd) == sd.used
+    a0, key = tree["layer_0"]["attn"], "model.layers.0.self_attn."
+    assert a0["w_dkv"].shape == (64, 32)
+    # columns [:24] are the latent c, [24:] the shared rope key: the order
+    # of the checkpoint's rows, and of the cached row
+    np.testing.assert_array_equal(
+        a0["w_dkv"][:, 24:], sd[key + "kv_a_proj_with_mqa.weight"][24:].T)
+    # head 1's rows of kv_b_proj: 16 of k_nope, then 12 of v
+    kvb = sd[key + "kv_b_proj.weight"]
+    np.testing.assert_array_equal(a0["w_uk"][:, 1], kvb[28:44].T)
+    np.testing.assert_array_equal(a0["w_uv"][:, 1], kvb[44:56].T)
+    assert "shared_gate" not in tree["layer_1"]["moe"]
+
+    spec = importlib.util.spec_from_file_location(
+        "kanana2_reference", os.path.join(os.path.dirname(
+            os.path.abspath(__file__)), "reference", "kanana2_decoder.py"))
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    tokens = np.random.default_rng(1).integers(0, 128, (1, 32)).astype(
+        np.int32)
+    want = np.asarray(ref.forward_logits(
+        tokens[0], embed=tree["embed"], unembed=tree["unembed"],
+        layer=lambda i: ref.program_layer(tree, i),
+        experts=ref.program_experts(cfg),
+        ln_final=tree["ln_final"]["scale"], theta=cfg.rope_theta,
+        eps=cfg.norm_eps, top_k=2, scaling=2.448, q_block=16))
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(TransformerLM(cfg).apply(
+            {"params": jax.tree.map(jnp.asarray, tree)}, tokens))[0]
+    np.testing.assert_allclose(got, want, atol=2e-4, rtol=0)
+    for over, text in (({"q_lora_rank": 16}, "q_lora_rank"),
+                       ({"n_group": 4, "topk_group": 2}, "n_group > 1"),
+                       ({"rope_scaling": {"type": "yarn", "factor": 4}},
+                        "rope_scaling")):
+        with pytest.raises(NotImplementedError, match=text):
+            config_from_hf(hf(**over))
