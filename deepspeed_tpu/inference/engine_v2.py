@@ -535,9 +535,9 @@ class InferenceEngineV2:
             P(None, None, None, None, None, None)
         self._pool_sharding = NamedSharding(topology.mesh, kv_spec)
         # pin the pool's jit entry/exit layout to row-major: with the
-        # layout-neutral DUS merges the whole program then runs in one
-        # layout, killing the last full-pool permute copy the donation
-        # chain otherwise negotiates (~8ms/step on a 1.6GB pool)
+        # layout-neutral DUS merges (pure writes: ``forward.merge_pages``)
+        # the whole program then runs in one layout, and no step program
+        # copies a pool (``profiling.trace.pool_sized_copies``)
         from jax.experimental.layout import Format, Layout
         self._pool_format = Format(
             Layout(major_to_minor=(0, 1, 2, 3, 4, 5)), self._pool_sharding)

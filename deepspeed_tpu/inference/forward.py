@@ -1413,12 +1413,17 @@ def merge_step(kv_pools, slot_maps, k_ys, v_ys, T: int):
     ``[S, T]`` plan (:class:`RaggedForward`'s ``k_ys`` / ``v_ys``) lands at
     its (block, offset) slot of that kind's pool (``slot_maps``: a tuple a
     kind of ``[S, T]`` flat slots); padded tokens carry trash-block slots
-    (block 0) by construction. DUS merges avoid the scatter layout war (see
-    :func:`merge_stage`: at SmallThinker's cell the scatter held a copy of
-    the window layers' whole 1.3 GiB pool as a temporary of every prefill
-    step); page-misaligned chunks keep the scatter. A record kind (its
-    ``v_ys`` entry is None, its ``slot_maps`` entry each row's write slot
-    ``[S]``) is written by :func:`merge_records`."""
+    (block 0) by construction. One token a row is a DUS a row
+    (:func:`merge_rows`), chunks of whole pages a DUS a page
+    (:func:`merge_pages`): PURE writes of a static slice at a dynamic
+    block, which take the pool in the row-major layout it is pinned to
+    (``engine._pool_formats``) and write it in place — no compiled step
+    program holds a copy of a pool's size
+    (``profiling.trace.pool_sized_copies``). Page-misaligned chunks, which
+    no serving configuration plans, keep the scatter
+    (:func:`merge_stage`). A record kind (its ``v_ys`` entry is None, its
+    ``slot_maps`` entry each row's write slot ``[S]``) is written by
+    :func:`merge_records`."""
     merged = []
     for pool, slots, kc, vc in zip(kv_pools, slot_maps, k_ys, v_ys):
         if pool.ndim == 4:
@@ -1452,14 +1457,15 @@ def merge_stage(kv_pool, flat_slots, ks, vs):
     ``[L, 2, KV, nb, bs, D]`` pool.
 
     NB on layout: an XLA scatter layout-assigns the pool to a
-    scatter-friendly permutation while the pallas reads need
-    row-major, costing full-pool layout-permute copies per compiled
-    step (~23ms/window on a 1.6GB pool; a flat [rows, D] scatter is
-    WORSE — column-major preference; layout_constraint pins don't
-    override scatter's mandatory layout). Callers therefore prefer
-    the layout-NEUTRAL dynamic-update-slice merges (``merge_rows``,
-    ``merge_pages``) and fall back here only for configurations
-    those can't express."""
+    scatter-friendly permutation while the pallas reads need row-major,
+    which costs a copy of the whole pool out and one back in every
+    compiled step (a flat [rows, D] scatter is WORSE — column-major
+    preference; layout_constraint pins don't override scatter's mandatory
+    layout; at SmallThinker's cell the scatter held a copy of the window
+    layers' whole pool as a temporary of every prefill step). Callers
+    therefore prefer the layout-NEUTRAL dynamic-update-slice merges
+    (``merge_rows``, ``merge_pages``) and fall back here only for what
+    those can't express: a chunk that is no whole number of pages."""
     with device_scope("kv_commit"):
         bs = kv_pool.shape[4]
         blk, off = flat_slots // bs, flat_slots % bs
@@ -1508,45 +1514,51 @@ def merge_pages(kv_pool, slot_map, k_ys, v_ys, T):
     """Page-granular pool merge for SplitFuse chunk steps
     (``k_ys/v_ys`` [L, S, KV, Ts, D], token t of row s ↔
     ``slot_map[s, t]``). Chunk starts are page-aligned whenever
-    chunk %% block_size == 0, so each page of a prefill row is one
+    chunk % block_size == 0, so each page of a prefill row is one
     whole-page DUS (rows past the chunk's real tokens land in the
-    not-yet-valid region — harmless). Rows carrying a single token
-    (fused decode rows, 1-token final chunks, inactive padding) must
-    NOT page-write (their page holds live earlier rows): for those
-    the page update degrades to a read-back of the current page, and
-    a per-row token DUS writes the one real token."""
+    not-yet-valid region — harmless). A row that carries a single token
+    (a 1-token final chunk, inactive padding) or starts off a page
+    boundary must NOT page-write (its page holds live earlier rows): ALL
+    its pages go to the trash block (block 0, which a degraded row's later
+    pages name anyway), and the per-row token DUS that ends the merge
+    writes its one real token.
+
+    Every update must stay a PURE write, which is why a degraded row's
+    first page is redirected and not read back. A read-modify-write of the
+    pool (``dynamic_slice``, ``where``, DUS back) the compiler fuses into
+    one loop fusion that reads and writes the pool, lays the pool out for
+    THAT (the blocks dimension major-most, a page of every layer
+    contiguous), and wraps the whole chain of merges in a copy out of the
+    pinned row-major layout and a copy back: two copies of every pool a
+    prefill step, 9-10 ms each on a 3.1 GiB latent pool (``PERF.md``
+    section 6, PR 55). A plain DUS takes its operand's layout as it is;
+    ``profiling.trace.pool_sized_copies`` reads a compiled program for
+    such copies."""
     with device_scope("kv_commit"):
-        L, halves, KV, nb, bs, D = kv_pool.shape
-        S = slot_map.shape[0]
-        z = jnp.int32(0)
+        bs = kv_pool.shape[4]
+        S, pages = slot_map.shape[0], T // bs
         n_real = (slot_map >= bs).sum(axis=1)          # trash slots < bs
+        # page-write only rows that really carry a chunk AND start on
+        # a page boundary (the scheduler advances kv_next in whole
+        # chunks so this holds today; the traced check pins the
+        # invariant rather than assuming it)
+        no_page = (n_real <= 1) | (slot_map[:, 0] % bs != 0)
+        # every page's block, worked out once (the loop below is unrolled
+        # and traced at every start: ``merge_rows``); a real row's pages
+        # past its tokens carry trash slots, block 0, by construction
+        blks = jnp.where(no_page[:, None], 0,
+                         slot_map[:, ::bs] // bs).reshape(-1)
+        z = np.int32(0)
         for s in range(S):
-            # page-write only rows that really carry a chunk AND start on
-            # a page boundary (the scheduler advances kv_next in whole
-            # chunks so this holds today; the traced check pins the
-            # invariant rather than assuming it)
-            no_page = (n_real[s] <= 1) | (slot_map[s, 0] % bs != 0)
-            for pg in range(T // bs):
+            for pg in range(pages):
                 sl = pg * bs
                 page = jnp.stack(
                     [y[:, s, :, sl:sl + bs, :]
                      for y in (k_ys, v_ys) if y is not None],
                     axis=1)[:, :, :, None].astype(kv_pool.dtype)
-                blk = slot_map[s, sl] // bs
-                if pg == 0:
-                    # read-modify-write: a single-token/misaligned row's
-                    # first page holds live earlier KV
-                    cur = jax.lax.dynamic_slice(
-                        kv_pool, (z, z, z, blk, z, z),
-                        (L, halves, KV, 1, bs, D))
-                    page = jnp.where(no_page, cur, page)
-                else:
-                    # later pages of degraded rows carry trash slots
-                    # (block 0) — writing garbage there is the existing
-                    # trash-block convention, no read-back needed
-                    blk = jnp.where(no_page, 0, blk)
-                kv_pool = jax.lax.dynamic_update_slice(
-                    kv_pool, page, (z, z, z, blk, z, z))
+                kv_pool = jax.lax.dynamic_update_slice_p.bind(
+                    kv_pool, page, z, z, z, jax.lax.index_in_dim(
+                        blks, s * pages + pg, keepdims=False), z, z)
         # every row's first token (covers degraded rows; for full chunks
         # this rewrites the value the page already wrote)
         return merge_rows(kv_pool, slot_map[:, 0], k_ys[:, :, :, 0, :],

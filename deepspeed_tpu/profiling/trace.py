@@ -178,13 +178,18 @@ class RegisteredProgram:
     def module_name(self) -> str:
         return "jit_" + re.sub(r"[^\w.\-]", "_", self.fn.__name__)
 
+    def compiled_text(self) -> str:
+        """The COMPILED module's text: lowered and compiled again from the
+        first call's abstract arguments (or read from the compile cache)."""
+        args, kwargs = self.avals
+        return self.fn.lower(*args, **kwargs).compile().as_text()
+
     def scopes(self) -> dict:
         """``{"module", "ops": {instruction: op_name}, "hlo_bytes"}`` —
         lowered, compiled (or read from the compile cache) and parsed on
         the first request, kept after."""
         if self._parsed is None:
-            args, kwargs = self.avals
-            text = self.fn.lower(*args, **kwargs).compile().as_text()
+            text = self.compiled_text()
             module, ops = parse_hlo_scopes(text)
             self._parsed = {"module": module, "ops": ops,
                             "hlo_bytes": len(text)}
@@ -249,6 +254,67 @@ def program_scope_maps(names=None) -> dict[str, dict]:
                   "programs": len(ps),
                   "hlo_bytes": max(p["hlo_bytes"] for p in ps)}
             for mod, ps in per.items()}
+
+
+_COPY = re.compile(
+    r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*\w+\[([\d,]*)\](\{[^}]*\})?\s+"
+    r"copy(?:-start)?\((.*)$")
+_OPERAND = re.compile(r"(?:\w+\[[\d,]*\](\{[^}]*\})?\s+)?%?([\w.\-]+)")
+_RESULT = re.compile(r"=\s*\(?\w+\[[\d,]*\](\{[^}]*\})?")
+
+
+def pool_sized_copies(program, pool_shapes) -> list[dict]:
+    """Every ``copy`` of a compiled program whose result has the shape of
+    one of ``pool_shapes`` (an engine's ``kv_pool`` arrays): ``{"instruction",
+    "shape", "operand", "operand_layout", "result_layout", "users"}``. A
+    step program reads its donated pool, writes it once in place and
+    returns it; a copy of a pool's size inside it is a pool read and
+    written again for nothing — a LAYOUT copy where the two layouts differ
+    (an update form that draws another layout than the pinned one), a
+    HAZARD copy where they are equal (a write the compiler could not order
+    after every read of the old value). ``program`` is a
+    :class:`RegisteredProgram` that has run, or a compiled module's
+    text."""
+    text = program if isinstance(program, str) else program.compiled_text()
+    want = {tuple(int(d) for d in s) for s in pool_shapes}
+    lines = text.splitlines()
+    found = []
+    for line in lines:
+        m = _COPY.match(line)
+        if m is None:
+            continue
+        name, dims, layout, rest = m.groups()
+        shape = [int(d) for d in dims.split(",") if d]
+        if tuple(shape) not in want:
+            continue
+        op_layout, operand = _OPERAND.match(rest).groups()
+        found.append({"instruction": name, "shape": shape,
+                      "operand": operand, "operand_layout": op_layout,
+                      "result_layout": layout, "users": []})
+    if not found:
+        return found
+    # (a second pass only where there is something to say: the operand's
+    # layout where the text prints operands bare, and who reads the copy)
+    by_name = {f["instruction"]: f for f in found}
+    bare = collections.defaultdict(list)
+    for f in found:
+        if f["operand_layout"] is None:
+            bare[f["operand"]].append(f)
+    refs = re.compile(r"(?<![\w.\-])(" + "|".join(map(re.escape, by_name))
+                      + r")(?![\w.\-])")
+    for line in lines:
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            continue
+        name = m.group(2)
+        if name in bare:
+            r = _RESULT.search(line)
+            for f in bare[name]:
+                f["operand_layout"] = r.group(1) if r else None
+        for used in set(refs.findall(line.split("=", 1)[1])):
+            if used != name:
+                by_name[used]["users"].append(name)
+    return found
 
 
 # ---- reading a trace ------------------------------------------------------

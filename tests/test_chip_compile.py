@@ -908,3 +908,71 @@ def test_layer_walk_reads_the_stack_in_place(S, T, period, topo):
     ffn_shapes = {s for s in leaves if len(s) == 2}
     assert not [w for w in walked if w[0] in ffn_shapes], walked
     assert sum(n for _, n in walked) <= period * layer_bytes // 8, walked
+
+
+def _prefill_step_with_its_pool_write(devs, L, halves, KV, nb, D, S=3,
+                                      T=512, bs=128, H=8, block=8):
+    """What a ``step_prefill`` program does to ONE pool, at a pool's real
+    page geometry: the paged kernel (a custom call: it takes the pool
+    row-major) reads the donated, row-major-pinned pool in every layer,
+    then ``merge_step`` writes the plan's chunks by pages and the decode
+    block's one token a row by rows. Returns (jitted step, abstract args,
+    the pool's shape)."""
+    from jax.experimental.layout import Format, Layout
+
+    from deepspeed_tpu.inference.forward import merge_step
+    from deepspeed_tpu.ops.pallas.paged_attention import \
+        paged_ragged_attention
+
+    one = _one(devs)
+    pinned = Format(Layout(major_to_minor=(0, 1, 2, 3, 4, 5)), one)
+    latent = halves == 1
+    kw = {"scale": 192 ** -0.5, "value_lanes": 512} if latent else {}
+
+    def step(pool, q, ks, bt, sl, qs, ss, slot_map, b_slots, bk):
+        outs, fresh = [], []
+        for li in range(L):
+            o = paged_ragged_attention(
+                q, pool, ks, None if latent else ks, bt, sl, qs, ss,
+                block_size=bs, layer_index=li, **kw)
+            outs.append(o.astype(jnp.float32).sum())
+            fresh.append(ks * (li + 1))
+        k = jnp.stack(fresh)                          # [L, S, KV, T, D]
+        (pool,) = merge_step((pool,), (slot_map,), (k,),
+                             (None if latent else k,), T)
+        (pool,) = merge_step((pool,), (b_slots,), (bk,),
+                             (None if latent else bk,), 1)
+        return pool, sum(outs)
+
+    vec = _sds(one, (S,), jnp.int32)
+    args = (_sds(pinned, (L, halves, KV, nb, bs, D), BF16),
+            _sds(one, (S, T, H, D), BF16), _sds(one, (S, KV, T, D), BF16),
+            _sds(one, (S, 8), jnp.int32), vec, vec, vec,
+            _sds(one, (S, T), jnp.int32), _sds(one, (block, 1), jnp.int32),
+            _sds(one, (L, block, KV, 1, D), BF16))
+    fn = jax.jit(step, donate_argnums=(0,),
+                 in_shardings=(pinned,) + (None,) * 9,
+                 out_shardings=(pinned, None))
+    return fn, args, args[0].shape
+
+
+@pytest.mark.parametrize("L, halves, KV, nb, D", [
+    (2, 1, 1, 64, 640), (2, 2, 4, 96, 128)],
+    ids=["latent_page_640_lanes", "kv_halves_4_heads"])
+def test_pool_write_compiles_with_no_pool_sized_copy(L, halves, KV, nb, D,
+                                                     topo):
+    """A prefill step's pool write is in place: the compiled program holds
+    no ``copy`` of the pool's size. (With a read-modify-write among the
+    merges — PR 55's parent: a degraded row's first page read back — the
+    compiler laid the pool out for that fusion, blocks major-most, and
+    copied the whole pool out of the pinned row-major layout and back:
+    two copies a pool in every such program, 17 ms of a 71 ms step of the
+    3.1 GiB latent pool.)"""
+    from deepspeed_tpu.profiling.trace import pool_sized_copies
+
+    fn, args, pool_shape = _prefill_step_with_its_pool_write(
+        topo.devices, L, halves, KV, nb, D)
+    text = fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert " dynamic-update-slice(" in text
+    assert pool_sized_copies(text, [pool_shape]) == []

@@ -349,6 +349,60 @@ def test_merged_maps_count_a_disagreement_as_ambiguous():
     assert merged["copy.3"] == ""
 
 
+#: a compiled step program as the v5e's compiler prints one (cut to what
+#: the reader parses): the pool copied OUT of its pinned layout for a fused
+#: read-modify-write and BACK after the chain of merges (a layout copy each
+#: way), a second pool protected by a same-layout copy, and a small copy
+_HLO_WITH_POOL_COPIES = """HloModule jit_step_prefill, is_scheduled=true
+
+%fused_computation.273 (param_0: bf16[3,1,1,64,128,640]) -> bf16[3,1,1,64,128,640] {
+  %param_0 = bf16[3,1,1,64,128,640]{5,4,0,3,2,1:T(8,128)(2,1)} parameter(0)
+  ROOT %copy.9 = bf16[3,1,1,64,128,640]{5,4,0,3,2,1:T(8,128)(2,1)} copy(%param_0)
+}
+
+ENTRY %main.1 (kv_pool_0_.1: bf16[3,1,1,64,128,640], kv_pool_1_.1: bf16[1,2,4,96,128,128]) -> (bf16[3,1,1,64,128,640], bf16[1,2,4,96,128,128]) {
+  %kv_pool_0_.1 = bf16[3,1,1,64,128,640]{5,4,3,2,1,0:T(8,128)(2,1)} parameter(0), metadata={op_name="kv_pool[0]"}
+  %kv_pool_1_.1 = bf16[1,2,4,96,128,128]{5,4,3,2,1,0:T(8,128)(2,1)} parameter(1)
+  %copy.267 = bf16[3,1,1,64,128,640]{5,4,0,3,2,1:T(8,128)(2,1)} copy(%kv_pool_0_.1), sharding={replicated}
+  %fusion.164 = bf16[3,1,1,64,128,640]{5,4,0,3,2,1:T(8,128)(2,1)} fusion(%copy.267, %select_n.123), kind=kLoop, calls=%fused_computation.273
+  %dynamic_update_slice.50 = bf16[3,1,1,64,128,640]{5,4,0,3,2,1:T(8,128)(2,1)} dynamic-update-slice(%fusion.164, %get-tuple-element.1210, %constant.206)
+  %copy.284 = bf16[3,1,1,64,128,640]{5,4,3,2,1,0:T(8,128)(2,1)} copy(bf16[3,1,1,64,128,640]{5,4,0,3,2,1:T(8,128)(2,1)} %dynamic_update_slice.50)
+  %copy.301 = bf16[1,2,4,96,128,128]{5,4,3,2,1,0:T(8,128)(2,1)} copy(%kv_pool_1_.1), metadata={op_name="jit(step_prefill)/kv_commit/dynamic_update_slice"}
+  %dynamic_update_slice.60 = bf16[1,2,4,96,128,128]{5,4,3,2,1,0:T(8,128)(2,1)} dynamic-update-slice(%copy.301, %get-tuple-element.1211, %constant.206)
+  %copy.12 = bf16[48,1,640]{2,1,0:T(8,128)(2,1)} copy(%get-tuple-element.7)
+  ROOT %tuple.145 = (bf16[3,1,1,64,128,640]{5,4,3,2,1,0:T(8,128)(2,1)}, bf16[1,2,4,96,128,128]{5,4,3,2,1,0:T(8,128)(2,1)}) tuple(%copy.284, %dynamic_update_slice.60)
+}
+"""
+
+
+def test_pool_sized_copies_names_layout_and_hazard_copies():
+    """``pool_sized_copies`` over a compiled text: the copies of a POOL's
+    shape with both layouts (a layout copy's differ, a hazard copy's are
+    equal), their operand and who reads them; a small copy, and a copy
+    inside a fused computation of another result than the entry's, are
+    the reader's business only by their shape."""
+    pools = [(3, 1, 1, 64, 128, 640), (1, 2, 4, 96, 128, 128)]
+    got = {c["instruction"]: c for c in ptrace.pool_sized_copies(
+        _HLO_WITH_POOL_COPIES, pools)}
+    assert sorted(got) == ["copy.267", "copy.284", "copy.301", "copy.9"]
+    out, back, hazard = got["copy.267"], got["copy.284"], got["copy.301"]
+    assert out["operand"] == "kv_pool_0_.1" and out["users"] == ["fusion.164"]
+    # (an operand printed bare takes the layout of the line that defines it)
+    assert out["operand_layout"] == "{5,4,3,2,1,0:T(8,128)(2,1)}"
+    assert out["result_layout"] == "{5,4,0,3,2,1:T(8,128)(2,1)}"
+    assert back["operand"] == "dynamic_update_slice.50"
+    assert (back["operand_layout"], back["result_layout"]) == (
+        out["result_layout"], out["operand_layout"])
+    assert back["users"] == ["tuple.145"]
+    assert hazard["operand_layout"] == hazard["result_layout"]
+    assert hazard["shape"] == [1, 2, 4, 96, 128, 128]
+    assert hazard["users"] == ["dynamic_update_slice.60"]
+    # one pool's shape only: the other's copies are not named
+    assert [c["instruction"] for c in ptrace.pool_sized_copies(
+        _HLO_WITH_POOL_COPIES, pools[1:])] == ["copy.301"]
+    assert ptrace.pool_sized_copies(_HLO_WITH_POOL_COPIES, [(48, 1, 64)]) == []
+
+
 #: a small scoped device trace recorded on a v5e
 #: (benchmark/tests/record_scope_fixture.py) with what the chip run wrote
 #: beside it: the program's scope maps, the benchmark reader's numbers

@@ -210,3 +210,106 @@ def test_a_forward_builds_without_an_engine(engines, layer_kinds):
     np.testing.assert_allclose(np.asarray(l1), np.asarray(l2), atol=1e-5)
     np.testing.assert_allclose(np.asarray(k1[0]), np.asarray(k2[0]),
                                atol=1e-5)
+
+
+# ---- the pool write against a plain scatter (PR 55) ----------------------
+
+_BS, _NB, _D, _L = 8, 7, 16, 2
+
+
+def _slots(block, first=0, n=_BS):
+    """``n`` flat slots of ``block`` from offset ``first`` on."""
+    return [block * _BS + first + i for i in range(n)]
+
+
+def _row(*real, T=2 * _BS):
+    """A plan row's slot map: its real slots, then trash slots (block 0)."""
+    real = [s for part in real for s in part]
+    return real + [0] * (T - len(real))
+
+
+#: name -> (a V half?, KV heads, the plan's [S, T] slot map, the decode
+#: block's [S'] write slots). Trash slots are < ``_BS`` (block 0).
+_MERGE_CASES = {
+    # whole chunks from page boundaries, one of them a page and a half
+    "kv_halves": (True, 2, [_row(_slots(2), _slots(5)),
+                            _row(_slots(3), _slots(1, n=4))], [4 * _BS + 3]),
+    # the latent page: one half, no V
+    "latent_no_v": (False, 1, [_row(_slots(2), _slots(5)),
+                               _row(_slots(3), _slots(1, n=4))],
+                    [4 * _BS + 3]),
+    # a ring at its wrap: the chunk's second page lands in the ring's FIRST
+    # slot, and another row's window sits in the block between
+    "ring_wrap": (True, 2, [_row(_slots(6), _slots(1)),
+                            _row(_slots(3), _slots(4, n=2))],
+                  [2 * _BS + 7, 5 * _BS]),
+    # rows that may NOT page-write: one token on a page boundary, one token
+    # in the middle of a page of live rows, a chunk that starts off a
+    # boundary (only its first token lands: the scheduler never plans one)
+    "one_token_and_off_boundary": (True, 2, [
+        _row(_slots(2, n=1)), _row(_slots(4, first=5, n=1)),
+        _row(_slots(5, first=3, n=4))], [3 * _BS + 1]),
+    # a plan of nothing but padding beside one real row
+    "empty_rows": (False, 1, [_row(), _row(_slots(3), _slots(6)), _row()],
+                   [0]),
+    # a decode block whose rows carry no request (trash slots), one of them
+    # beside a row that shares its page with nothing live
+    "block_rows_with_no_request": (True, 1, [_row(_slots(1), _slots(2))],
+                                   [0, 3, 6 * _BS + 2, 0]),
+}
+
+
+@pytest.mark.parametrize("case", list(_MERGE_CASES))
+def test_pool_write_is_a_plain_scatter_on_every_live_slot(case):
+    """``merge_step`` of a plan's chunks (pages) and then of its decode
+    block's one token a row (rows), as ``step_fused`` chains them, leaves
+    in every slot but the trash block's what a numpy scatter of the same
+    ``(slot_map, k_ys, v_ys)`` leaves — and what the pool held before
+    everywhere a token did not land (less the rows of a written page past
+    its chunk's real tokens: the sequence's not-yet-valid region)."""
+    has_v, KV, slot_map, block_slots = _MERGE_CASES[case]
+    halves = 2 if has_v else 1
+    slot_map = np.asarray(slot_map, np.int32)
+    block_slots = np.asarray(block_slots, np.int32)
+    S, T = slot_map.shape
+    rng = np.random.default_rng(len(case))
+    pool0 = rng.standard_normal((_L, halves, KV, _NB, _BS, _D)).astype(
+        np.float32)
+    fresh = [rng.standard_normal((_L, S, KV, T, _D)).astype(np.float32)
+             for _ in range(halves)]
+    b_fresh = [rng.standard_normal(
+        (_L, len(block_slots), KV, 1, _D)).astype(np.float32)
+        for _ in range(halves)]
+
+    def write(pool, slot_map, k, v, T):
+        return merge_step((pool,), (slot_map,), (k,), (v,), T)[0]
+
+    @jax.jit
+    def step(pool, slot_map, block_slots, fresh, b_fresh):
+        pool = write(pool, slot_map, fresh[0],
+                     fresh[1] if has_v else None, T)
+        return write(pool, block_slots[:, None], b_fresh[0],
+                     b_fresh[1] if has_v else None, 1)
+
+    got = np.asarray(step(pool0, slot_map, block_slots, fresh, b_fresh))
+
+    want, dont_care = pool0.copy(), np.zeros((_NB, _BS), bool)
+    dont_care[0] = True                                   # the trash block
+    for s in range(S):
+        n_real = int((slot_map[s] >= _BS).sum())
+        paged = n_real > 1 and slot_map[s, 0] % _BS == 0
+        for t in range(T if paged else min(T, 1)):
+            slot = slot_map[s, t]
+            if slot >= _BS:
+                for h in range(halves):
+                    want[:, h, :, slot // _BS, slot % _BS] = \
+                        fresh[h][:, s, :, t]
+            elif paged and slot_map[s, t - t % _BS] >= _BS:
+                dont_care[slot_map[s, t - t % _BS] // _BS, t % _BS] = True
+    for n, slot in enumerate(block_slots):
+        if slot >= _BS:
+            for h in range(halves):
+                want[:, h, :, slot // _BS, slot % _BS] = b_fresh[h][:, n, :, 0]
+    keep = ~dont_care
+    np.testing.assert_array_equal(got[:, :, :, keep], want[:, :, :, keep])
+    assert not np.array_equal(got[:, :, :, 1:], pool0[:, :, :, 1:])
