@@ -51,7 +51,8 @@ from ..models.transformer import (ModelConfig, TransformerLM,
                                   default_activation_rules, is_moe_layer)
 from ..parallel.tensor import overlap_counters
 from ..parallel.topology import MeshConfig, MeshTopology
-from ..profiling.trace import register_program
+from ..profiling.trace import (books_its_build, engine_build,
+                               register_program)
 from ..utils.annotations import device_scope
 from ..utils.logging import logger
 from ..ops.pallas.paged_attention import (paged_attention_usable, paged_plan,
@@ -303,6 +304,7 @@ class RaggedInferenceConfig:
 
 
 class InferenceEngineV2:
+    @books_its_build
     def __init__(self, model: TransformerLM, params: Pytree | None = None,
                  config: RaggedInferenceConfig | dict | None = None,
                  topology: MeshTopology | None = None,
@@ -310,6 +312,10 @@ class InferenceEngineV2:
                  draft_model: TransformerLM | None = None,
                  draft_params: Pytree | None = None,
                  draft_rng: jax.Array | None = None):
+        # the constructor by phase, into the build ledger: the phases
+        # partition its wall time (``books_its_build`` opened the block,
+        # in phase ``rest``, and ends it with one ``build:`` line)
+        build = engine_build(type(self).__name__)
         if isinstance(config, dict):
             config = RaggedInferenceConfig(**config)
         self.config = config or RaggedInferenceConfig()
@@ -429,6 +435,7 @@ class InferenceEngineV2:
         self._weight_version: dict = {"id": 0, "digest": "init"}
 
         # --- weights: same tree as the trainer, TP-sharded ---------------
+        build.phase("weights")
         self.params, plan = load_tp_params(model, params, rng, topology,
                                            cfg.dtype)
         #: a quantised weight's TP kind, by weight name
@@ -444,6 +451,7 @@ class InferenceEngineV2:
         # kernel dispatch; under jit an unrolled loop is per-layer
         # RECOMPILATION). Heterogeneous moe patterns (freq > 1) keep the
         # unrolled loop.
+        build.phase("stack")
         m = self.mcfg
         moe_flags = [is_moe_layer(m, i) for i in range(m.num_layers)]
         # ... and so does a stack whose layers differ in OPERATOR (a
@@ -522,6 +530,7 @@ class InferenceEngineV2:
         # pool is READ-ONLY inside the forward (inference/forward.py) —
         # fresh KV rides a small staged buffer and is merged exactly once
         # per dispatch.
+        build.phase("pools")
         tp = max(topology.size("tensor"), 1)
         #: the pool's own head geometry: ``kv_pack`` KV heads side by side
         #: in a page row (2 where heads are 64 wide: ``forward.kv_pack``),
@@ -546,7 +555,9 @@ class InferenceEngineV2:
                              f"{cfg.kv_cache_dtype!r}")
         self._kv_dtype = jnp.float8_e4m3fn \
             if cfg.kv_cache_dtype == "fp8" else cfg.dtype
+        build.phase("probes")
         self._guard_pinned_layout_against_cache()
+        build.phase("pools")
         #: one pool a kind of layer: a tuple in ``self._kinds``' order, for
         #: every model
         # every model; a record kind's entry is its records ``[layers,
@@ -577,6 +588,7 @@ class InferenceEngineV2:
                if k.is_latent else "")
             for c, k in enumerate(self._kinds)))
 
+        build.phase("probes")
         # alibi needs a positional bias inside the kernel — XLA path only.
         # pallas_call has no GSPMD rule, so multi-device meshes run the
         # kernel per-shard through shard_map over ALL live axes: q sharded
@@ -679,6 +691,7 @@ class InferenceEngineV2:
             # path; no-op when packing is off
             self.scheduler.row_multiple = self._tp_ring_n
 
+        build.phase("rest")
         self._programs: dict[int, Any] = {}
         #: the decode block of a prefill plan that carries none
         #: (``_plan_args``): a ``[max_seqs, 1]`` plan of no live row
@@ -771,7 +784,7 @@ class InferenceEngineV2:
         self.stats = {"plan_s": 0.0, "dispatch_s": 0.0, "drain_block_s": 0.0,
                       "commit_s": 0.0, "dispatches": 0, "prefill_steps": 0,
                       "decode_steps": 0, "windows": 0, "window_iters": 0,
-                      "forced_drains": 0, "opportunistic_drains": 0,
+                      "forced_drains": 0,
                       "prefill_tokens": 0, "decode_tokens": 0,
                       # the decode block of a prefill step: steps whose
                       # block carried a decode token, the tokens that left
@@ -795,11 +808,10 @@ class InferenceEngineV2:
                       "prefix_hit_tokens": 0, "prefix_lookup_tokens": 0,
                       "prefix_hit_rate": 0.0,
                       # KV tiering (kvtier.py): pages demoted on
-                      # eviction, chains promoted on admission misses,
-                      # prompt tokens the tier saved from recompute
+                      # eviction, chains promoted on admission misses
+                      # (a promote that fell back to recompute is the
+                      # tier's own count, by reason: ``KVTier.stats``)
                       "kv_tier_demoted_pages": 0, "kv_tier_promotes": 0,
-                      "kv_tier_promoted_tokens": 0,
-                      "kv_tier_fallbacks": 0,
                       # ring collective-matmul overlap (trace-time deltas
                       # from parallel/tensor.py — see _refresh_tp_stats)
                       "tp_ring_matmuls": 0, "tp_ring_steps": 0,
@@ -821,9 +833,10 @@ class InferenceEngineV2:
                       "attn_pallas_tree": 0, "attn_gather_tree": 0,
                       # KV-page migration (inference/migration.py):
                       # disaggregated prefill/decode handoffs through
-                      # this engine's pool, both directions + payload
+                      # this engine's pool, both directions, and the
+                      # payload taken in
                       "migrations_out": 0, "migrations_in": 0,
-                      "migration_bytes_out": 0, "migration_bytes_in": 0,
+                      "migration_bytes_in": 0,
                       # routed-expert layers (``routed_experts``): rows the
                       # live tokens of a step route (tokens x top_k x MoE
                       # layers) against the rows of the tile-aligned
@@ -872,6 +885,7 @@ class InferenceEngineV2:
         # the opportunistic commit path never fired — every drain
         # blocked): opportunistic drains trust is_ready() only after a
         # d2h copy has had ~2x the probed latency to land
+        build.phase("probes")
         probe = jnp.arange(max(cfg.decode_window, 1) * cfg.max_seqs,
                            dtype=jnp.int32)
         lat = []
@@ -888,6 +902,7 @@ class InferenceEngineV2:
         self._d2h_latency = float(np.median(lat))
         self._drain_age = min(2.0 * self._d2h_latency, 0.5)
         self.stats["d2h_latency_s"] = round(self._d2h_latency, 4)
+        build.phase("rest")
 
         # --- speculative decoding (inference/speculative.py) -------------
         self._spec = None
@@ -1279,7 +1294,8 @@ class InferenceEngineV2:
                 step, donate_argnums=(1, 2),
                 in_shardings=(None, self._pool_formats)
                 + (None,) * (12 if T > 1 else 11),
-                out_shardings=(self._pool_formats, repl, repl)))
+                out_shardings=(self._pool_formats, repl, repl)),
+                key=key, cause=("dispatch", self._entry_seq))
         return self._programs[key]
 
     def _plan_args(self, plan: StepPlan) -> tuple:
@@ -1308,7 +1324,7 @@ class InferenceEngineV2:
         return args + ((tok, pos, slot_maps, tables, lens, do_sample,
                         use_last, slots),)
 
-    def _window_program(self, W: int):
+    def _window_program(self, W: int, cause: tuple | None = None):
         """Up to W chained decode steps in one jitted program: per step,
         each slot's write slot comes from its block table at the current
         position, the forward runs with T=1, and the sampled token feeds
@@ -1332,7 +1348,10 @@ class InferenceEngineV2:
         weight reads overlap iteration i's tail), which a data-dependent
         exit test forbids. Work is wasted only when EVERY slot exits early
         (eos): the scheduler already sizes W to the largest remaining
-        budget."""
+        budget.
+
+        ``cause``: who asks, for the build ledger's record of a program
+        made here (the dispatch of the entry under way, unless told)."""
         key = ("win", W)
         if key not in self._programs:
             cfg = self.config
@@ -1458,7 +1477,8 @@ class InferenceEngineV2:
             self._programs[key] = register_program(jax.jit(
                 run, donate_argnums=(1, 2),
                 in_shardings=(None, self._pool_formats) + (None,) * 9,
-                out_shardings=(self._pool_formats, repl, repl, repl)))
+                out_shardings=(self._pool_formats, repl, repl, repl)),
+                key=key, cause=cause or ("dispatch", self._entry_seq))
         return self._programs[key]
 
     def warm_decode_windows(self, sizes: list[int] | None = None,
@@ -1488,7 +1508,7 @@ class InferenceEngineV2:
         for W in sizes:
             if W <= 1 or (skip_existing and ("win", W) in self._programs):
                 continue
-            fn = self._window_program(W)
+            fn = self._window_program(W, cause=("warm", None))
             self._rng, sub = jax.random.split(self._rng)
             self.kv_pool, self._last_tok, _, _ = fn(
                 self.params, self.kv_pool, self._last_tok, z(S),
@@ -1631,7 +1651,8 @@ class InferenceEngineV2:
             # program that runs after the host-side acceptance walk
             self._programs[key] = register_program(jax.jit(
                 run, in_shardings=(None, self._pool_formats) + (None,) * 7,
-                out_shardings=(repl, repl, repl)))
+                out_shardings=(repl, repl, repl)),
+                key=key, cause=("dispatch", self._entry_seq))
         return self._programs[key]
 
     def _spec_merge_program(self, T: int):
@@ -1659,7 +1680,8 @@ class InferenceEngineV2:
             self._programs[key] = register_program(jax.jit(
                 run, donate_argnums=(0,),
                 in_shardings=(self._pool_format, None, None, None),
-                out_shardings=self._pool_format))
+                out_shardings=self._pool_format),
+                key=key, cause=("dispatch", self._entry_seq))
         return self._programs[key]
 
     def _try_dispatch_spec(self, prefill_pending: bool = False) -> bool:
@@ -2056,7 +2078,6 @@ class InferenceEngineV2:
                     toks_h = np.asarray(entry["toks"])
                 self.stats["drain_block_s"] += time.perf_counter() - t0
             else:
-                self.stats["opportunistic_drains"] += 1
                 toks_h = np.asarray(entry["toks"])
             self._inflight.popleft()
             force = False
@@ -2364,7 +2385,6 @@ class InferenceEngineV2:
             pages=page_blobs, tail=tail)
         bundle.validate()
         self.stats["migrations_out"] += 1
-        self.stats["migration_bytes_out"] += bundle.payload_bytes
         return bundle
 
     def export_commit(self, uid: int) -> list[int]:
@@ -2542,8 +2562,6 @@ class InferenceEngineV2:
             np.dtype(self._kv_dtype).name, self._page_bytes, blobs,
             weight_version=dict(self._weight_version))
         bundle.validate()
-        self.stats["kv_pull_bytes_out"] = self.stats.get(
-            "kv_pull_bytes_out", 0) + bundle.payload_bytes
         return bundle
 
     def import_prefix(self, bundle: "PageBundle",
@@ -2713,7 +2731,6 @@ class InferenceEngineV2:
             # capacity / skew / geometry: structured refusal — the
             # admission below recomputes, always safe
             tier._fallback("adopt")
-            self.stats["kv_tier_fallbacks"] += 1
             logger.warning(f"engine_v2: tier promote refused ({e}); "
                            f"recomputing")
             return 0
@@ -2726,7 +2743,6 @@ class InferenceEngineV2:
         gained = max((len(handle["tok"]) // bs
                       - int(handle.get("have", 0))) * bs, 0)
         self.stats["kv_tier_promotes"] += 1
-        self.stats["kv_tier_promoted_tokens"] += gained
         if self._rt.enabled:
             self._rt.event(-1, "kv_tier", dir="promote", pages=pages,
                            tokens=gained)

@@ -43,7 +43,8 @@ from ..models.transformer import (ModelConfig, default_activation_rules,
 from ..ops.optimizers import OptState, Optimizer, build_optimizer
 from ..ops.remat import AUTO as REMAT_AUTO, REMAT_LADDER
 from ..parallel.topology import BATCH_AXES, MeshTopology
-from ..profiling.trace import _abstract, register_program
+from ..profiling.trace import (_abstract, books_its_build, engine_build,
+                               register_program)
 from ..utils.annotations import device_scope
 from ..utils.logging import log_dist, logger
 from ..utils.timer import (
@@ -92,6 +93,7 @@ def _global_norm(tree: Pytree) -> jax.Array:
 
 
 class DeepSpeedEngine:
+    @books_its_build
     def __init__(self,
                  config: Config,
                  model: nn.Module | None = None,
@@ -307,6 +309,7 @@ class DeepSpeedEngine:
 
         # ---- state bring-up (reference _configure_distributed_model :1137)
         self._init_state(params, sample_batch, rng)
+        engine_build(type(self).__name__).phase("programs")
         self.remat_plan = self._build_judged_programs()
         self.attention_formulation = self._announce_attention()
 
@@ -1045,7 +1048,7 @@ class DeepSpeedEngine:
             train_step,
             out_shardings=(ss, (repl, repl)),
             donate_argnums=(0,),
-        ))
+        ), key=("train_step", "gspmd"))
 
     def _safe_manual_rules(self, manual_axes: tuple[str, ...]):
         """Logical-axis constraints on manual (shard_map) axes are illegal —
@@ -1190,7 +1193,7 @@ class DeepSpeedEngine:
 
         self._train_step = register_program(jax.jit(
             train_step, out_shardings=(ss, (repl, repl)),
-            donate_argnums=(0,)))
+            donate_argnums=(0,)), key=("train_step", "zeropp"))
 
     def _use_onebit_comm(self) -> bool:
         """1-bit compressed gradient comm applies when the optimizer is a
@@ -1303,7 +1306,7 @@ class DeepSpeedEngine:
 
         self._train_step = register_program(jax.jit(
             train_step, out_shardings=(self._state_shardings, (repl, repl)),
-            donate_argnums=(0,)))
+            donate_argnums=(0,)), key=("train_step", "onebit"))
 
     def _offload_apply(self, grads: Pytree) -> None:
         """Host optimizer step + device param refresh."""

@@ -872,34 +872,44 @@ class EngineBackend:
         import jax                               # deferred: toy mode never
         from ..models import build_model         # pays the jax/flax import
         from ..inference.engine_v2 import InferenceEngineV2
+        from ..profiling import trace as _builds
 
-        model = build_model(cfg.get("model", "tiny-gpt2"),
-                            **(cfg.get("overrides") or {}))
-        ecfg = dict(cfg.get("engine") or {})
-        ecfg.setdefault("block_size", 16)
-        ecfg.setdefault("num_blocks", 128)
-        ecfg.setdefault("max_seqs", 4)
-        ecfg.setdefault("max_seq_len", 512)
-        tier_cfg = _slot_tier_cfg(cfg) if cfg.get("kv_tier") else None
-        if tier_cfg:
-            # KV tiering rides the engine's own config surface (the
-            # tier lives under the engine's prefix cache)
-            ecfg.setdefault("kv_tier", True)
-            ecfg.setdefault("prefix_cache", True)
-            for src, dst in (("ram_bytes", "kv_tier_ram_bytes"),
-                             ("nvme_dir", "kv_tier_nvme_dir"),
-                             ("nvme_bytes", "kv_tier_nvme_bytes"),
-                             ("min_pages", "kv_tier_min_pages")):
-                if src in tier_cfg:
-                    ecfg.setdefault(dst, tier_cfg[src])
-        if str(cfg.get("role", "mixed")) == "prefill":
-            # a prefill-role replica hands each sequence off right after
-            # its first sampled token: a multi-token decode window would
-            # only generate tokens the decode pool exists to own
-            ecfg.setdefault("decode_window", 1)
-        self.eng = InferenceEngineV2(
-            model, rng=jax.random.PRNGKey(int(cfg.get("seed", 0))),
-            config=ecfg)
+        #: the build ledger (``profiling/trace.py``): what ``setup_line``,
+        #: ``new_builds`` and ``builds_lines`` read
+        self._builds = _builds
+        # the engine's constructor joins this block: ``model`` and its
+        # phases are ONE build, said in one ``build:`` line
+        with _builds.engine_build(type(self).__name__) as build:
+            build.phase("model")
+            model = build_model(cfg.get("model", "tiny-gpt2"),
+                                **(cfg.get("overrides") or {}))
+            ecfg = dict(cfg.get("engine") or {})
+            ecfg.setdefault("block_size", 16)
+            ecfg.setdefault("num_blocks", 128)
+            ecfg.setdefault("max_seqs", 4)
+            ecfg.setdefault("max_seq_len", 512)
+            tier_cfg = _slot_tier_cfg(cfg) if cfg.get("kv_tier") else None
+            if tier_cfg:
+                # KV tiering rides the engine's own config surface (the
+                # tier lives under the engine's prefix cache)
+                ecfg.setdefault("kv_tier", True)
+                ecfg.setdefault("prefix_cache", True)
+                for src, dst in (("ram_bytes", "kv_tier_ram_bytes"),
+                                 ("nvme_dir", "kv_tier_nvme_dir"),
+                                 ("nvme_bytes", "kv_tier_nvme_bytes"),
+                                 ("min_pages", "kv_tier_min_pages")):
+                    if src in tier_cfg:
+                        ecfg.setdefault(dst, tier_cfg[src])
+            if str(cfg.get("role", "mixed")) == "prefill":
+                # a prefill-role replica hands each sequence off right
+                # after its first sampled token: a multi-token decode
+                # window would only generate tokens the decode pool exists
+                # to own
+                ecfg.setdefault("decode_window", 1)
+            self.eng = InferenceEngineV2(
+                model, rng=jax.random.PRNGKey(int(cfg.get("seed", 0))),
+                config=ecfg)
+        self.note_ready()
         # ``step`` books its own wall time and the engine's inside it
         # beside the engine's counters
         self.eng.stats.update(replica_step_s=0.0, engine_step_s=0.0)
@@ -1076,6 +1086,43 @@ class EngineBackend:
                 f"{st['moe_masked_rows']} masked out of the sort, "
                 f"{st['moe_padded_rows']} buffer rows"
                 if st["moe_padded_rows"] else ""))
+
+    # -- the build ledger (profiling/trace.py), as a worker says it -------
+    def setup_line(self) -> str:
+        """What the worker holds at ``ready``: the newest engine build by
+        phase and the programs first called so far."""
+        s = self._builds.build_summary()
+        return (f"setup: {self._builds.phases_line(s['phases'])}; "
+                f"{s['programs']} programs first-called in "
+                f"{s['first_call_s']:.2f} s")
+
+    def note_ready(self) -> None:
+        """The ledger's count and the clock that ``new_builds`` starts
+        from: the constructor's end, then the worker's ``ready``."""
+        self._builds_seen = self._builds.build_count()
+        self._ready_t = time.perf_counter()
+
+    def new_builds(self) -> list[str]:
+        """One line for every ``program`` or ``outside`` record with a
+        backend event booked since the last call (or ``ready``): a build
+        under load, with when and what the engine held. A steady call is
+        one compare."""
+        n = self._builds.build_count()
+        if n == self._builds_seen:
+            return []
+        recs = [r for r in self._builds.build_records()[self._builds_seen - n:]
+                if r["kind"] != "phase" and r["backend_events"]]
+        self._builds_seen = n
+        if not recs:
+            return []
+        load = self.eng.scheduler.load_summary()
+        return [f"{self._builds.build_line(r)} at "
+                f"+{r['t0'] - self._ready_t:.1f} s since ready; "
+                f"{load['live']} live, {load['queued']} pending"
+                for r in recs]
+
+    def builds_lines(self) -> list[str]:
+        return self._builds.builds_lines()
 
     # -- KV-page migration (disaggregated serving) -----------------------
     def request_handoff(self, rid: str) -> bool:
@@ -1697,11 +1744,14 @@ class DaemonState:
 
 
 def _log_pipeline(backend) -> None:
-    """A leaving worker's one line on its engine's pipeline (the toy
-    backend has none)."""
+    """A leaving worker's one line on its engine's pipeline, then its
+    build ledger's: the sums, each rebuild, each build outside the table
+    (the toy backend has neither)."""
     line = getattr(backend, "pipeline_line", None)
     if line is not None:
         logger.info(line())
+    for line in getattr(backend, "builds_lines", lambda: ())():
+        logger.info(line)
 
 
 def _drain_flush(backend, inj) -> int:
@@ -1752,6 +1802,12 @@ def serve(cfg: dict, chan: LineChannel,
     role = getattr(backend, "role", "mixed")
     from .shm import attach_ring
     ring = st.ring
+    # an engine backend says what its build was made of, and from here on
+    # every build under load (``new_builds``: one compare an iteration)
+    new_builds = getattr(backend, "new_builds", None)
+    if new_builds is not None:
+        logger.info(backend.setup_line())
+        backend.note_ready()
     chan.send({"t": "ready", "pid": os.getpid(),
                "block_size": backend.block_size,
                "max_live": backend.max_live, "role": role,
@@ -2622,6 +2678,10 @@ def serve(cfg: dict, chan: LineChannel,
                 _stream({"t": "failed", "id": rid, "a": a,
                          "reason": str(toks)})
                 _trace_ship(rid)
+
+        if new_builds is not None:
+            for line in new_builds():
+                logger.warning(line)
 
         # sequences frozen for transfer — a prefill role's boundary
         # crossings plus any router-requested rebalance victims: bundle
