@@ -398,7 +398,7 @@ the spans of one entry share it.
 | `attn_steps_live_<kind>`, `attn_steps_rect_<kind>` | `attn_steps_live` / `attn_steps_rect` split by kind of layer (each kind walks its own table) |
 | `attn_pages_unclipped`, `attn_pages_clipped` | pool pages a table that grew with the context would have walked in the window layers, and those of them the window kind did not walk |
 | `state_records_live`, `state_records_peak` | a RECORD kind's state (`conv`: the last `conv_taps - 1` inputs of a short convolution, one record a layer and slot, no pages): records live — one a sequence in a slot — sampled after every dispatch, and the run's peak; present only where the model has such layers |
-| `latent_rows_written` | latent attention (`kv_lora_rank`): rows `[c, k_r]` the dispatched programs were to write, a token a row whatever the layers — a plan's live tokens and its decode block's, a window's scheduled iterations a slot (host arithmetic at dispatch); present only where the model's paged kind is `latent`. The kernel's steps are booked in `attn_steps_*` (and `attn_steps_*_latent`) as the K/V kernel's are; its device time and the absorb's are the scopes `attn_core` and `latent_absorb` (`W_dkv`, the latent's norm, the rope key, `W_uk` folded into the query, `W_uv` after the weighted sum) |
+| `latent_rows_written` | latent attention (`kv_lora_rank`): rows `[c, k_r]` the dispatched programs were to write, a token a row whatever the layers — a plan's live tokens and its decode block's, a window's scheduled iterations a slot (host arithmetic at dispatch); present only where the model's paged kind is `latent`. The kernel's steps are booked in `attn_steps_*` (and `attn_steps_*_latent`) as the K/V kernel's are; its device time and the absorb's are the scopes `attn_core` and `latent_absorb` (`W_dkv`, the latent's norm, the rope key, `W_uk` folded into the query, `W_uv` after the weighted sum). Since PR 60 a prefill CHUNK past the forms' break-even (`latent_prefill_breakeven`: 158 tokens at kanana-2's widths) runs EXPANDED — kernel `paged_latent_prefill` as before, under `attn_core`: each page up-projected in VMEM a head, no fold and no `W_uv` einsum round it — and every decode row stays absorbed; the engine's `paged:` line says which, with the head group. The benchmark's `prefill_attn_core_share` reads `attn_core` + `kv_stage` over the prefill step programs' device self time (`benchmark/layers/prefill_attn_core_share.py`, the five serving cells; `decode_attn_core_share` is its twin over the decode programs) |
 | `conv_chunks`, `conv_chunks_carried` | prefill rows dispatched, and those of them whose first position is past 0: they started from the record their sequence's last chunk left, not from zeros (booked on the host at dispatch) |
 | `moe_routed_rows`, `moe_padded_rows`, `attn_steps_*` | booked with the layers that HAVE the mechanism — the expert layers, the layers of each paged kind — not `num_layers` (a stack of unlike layers) |
 | `moe_masked_rows` | (token, choice) entries of dispatched programs whose row carried no request — an empty slot of a window, a chunk's padding — and which the expert sort's liveness mask therefore left out: `(rows x iterations - live tokens) x top_k x expert layers`, host arithmetic at dispatch beside `moe_routed_rows` (a slot that meets its EOS inside a window is masked on the device from there on and still counted as routed) |

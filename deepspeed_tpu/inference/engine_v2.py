@@ -55,7 +55,8 @@ from ..profiling.trace import (books_its_build, engine_build,
                                register_program)
 from ..utils.annotations import device_scope
 from ..utils.logging import logger
-from ..ops.pallas.paged_attention import (paged_attention_usable, paged_plan,
+from ..ops.pallas.paged_attention import (latent_prefill_plan,
+                                          paged_attention_usable, paged_plan,
                                           paged_step_counts)
 # (``cache_kinds``, ``moe_tile_rows`` and ``moe_padded_rows`` are imported
 # from here by the benchmark's tools too)
@@ -652,6 +653,13 @@ class InferenceEngineV2:
             plan = paged_plan(cfg.chunk * (m.num_heads // self._kv_geom[0]),
                               self._kv_geom[0] // tp, cfg.block_size,
                               cfg.dtype, lanes=self._kv_geom[1])
+            if k0.is_latent:
+                # a chunk past the forms' break-even runs the EXPANDED
+                # kernel, planned by heads (None: it stays absorbed)
+                plan = latent_prefill_plan(
+                    cfg.chunk, m.num_heads, m.kv_lora_rank,
+                    m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim,
+                    k0.lanes, cfg.block_size, cfg.dtype) or plan
             self.paged_plans = {k.name: plan for k in self._kinds
                                 if not k.is_record}
             for k in self.paged_plans:

@@ -63,7 +63,9 @@ from ..models.transformer import (
 from ..moe.layer import dropless_dispatch_combine
 from ..moe.sharded_moe import topk_dropless_gating
 from ..ops.pallas.grouped_matmul import gmm_plan, grouped_matmul_layer
-from ..ops.pallas.paged_attention import (paged_ragged_attention,
+from ..ops.pallas.paged_attention import (latent_prefill_plan,
+                                          paged_latent_prefill,
+                                          paged_ragged_attention,
                                           paged_work_list)
 from ..ops.pallas.quant_matmul import (QuantGrouped, QuantLinear,
                                        quant_grouped_matmul, quant_matmul)
@@ -606,9 +608,10 @@ class RaggedForward:
         pk = self.kv_pack
         KVp, Dp = KV // pk, D * pk
         #: latent attention: ONE paged kind whose page is a row a token
+        #: (no KV heads to pack, whatever ``kv_pack`` makes of the widths)
         latent = kinds[0].is_latent
         if latent:
-            KVp, Dp = kinds[0].heads, kinds[0].lanes
+            pk, KVp, Dp = 1, kinds[0].heads, kinds[0].lanes
         window_mode = kv_stage is not None
         #: the cache (an index into ``kinds``) of a layer kind
         cache_of = {name: [k.name for k in kinds].index(
@@ -939,19 +942,29 @@ class RaggedForward:
             among ``kinds``; ``lk`` the layer's index inside that cache's
             pool."""
             a = p["attn"]
+            #: a latent segment's up-projections, where its chunk takes
+            #: the EXPANDED form (``latent_expands``); None: absorbed
+            ups = [None] * len(segs)
             if latent:
                 q, k, v = latent_qkv(a, qli, h)
+                ups = [(a["w_uk"].astype(cfg.dtype),
+                        a["w_uv"].astype(cfg.dtype))
+                       if latent_expands(g.T) else None for g in segs]
             else:
                 with device_scope("attn_qkv"):
                     q, k, v = qkv(a, qli, h, kind)
                     if pk > 1:
                         q, k, v = pack_heads(q, k, v, pk)
             outs, stages = [], []
-            for g, q_g, k_g, v_g, stage_g in zip(
+            for g, q_g, k_g, v_g, stage_g, up in zip(
                     segs, to_segs(q), to_segs(k),
-                    [None] * len(segs) if latent else to_segs(v), stage_l):
+                    [None] * len(segs) if latent else to_segs(v), stage_l,
+                    ups):
                 with device_scope("kv_stage"):
                     stage_g = stage(g, k_g, v_g, stage_g)
+                if latent and up is None:
+                    with device_scope("latent_absorb"):
+                        q_g = latent_absorb_query(a, q_g)
                 # window and global layers told apart, inside
                 # ``attn_core``, where a model has both (a model of one
                 # kind keeps the scope table it always had)
@@ -960,46 +973,63 @@ class RaggedForward:
                     sub = device_scope("attn_window") if kinds[c].window \
                         else device_scope("attn_full")
                 with device_scope("attn_core"), sub:
-                    outs.append(core(g, c, lk, q_g, stage_g))
+                    o_g = core(g, c, lk, q_g, stage_g, up)
+                if latent and up is None:
+                    # the weighted sum of latents back to a head's value:
+                    # ``o_h = W_uv,h o_lat_h``
+                    with device_scope("latent_absorb"):
+                        o_g = jnp.einsum("sthr,rhd->sthd", o_g,
+                                         a["w_uv"].astype(cfg.dtype))
+                outs.append(o_g)
                 stages.append(stage_g)
-            if latent:
-                # the weighted sum of latents back to a head's value:
-                # ``o_h = W_uv,h o_lat_h``
-                with device_scope("latent_absorb"):
-                    o = jnp.einsum("sthr,rhd->sthd", from_segs(outs),
-                                   a["w_uv"].astype(cfg.dtype))
-                with device_scope("attn_out"):
-                    return out_proj(a, qli, o), tuple(stages)
             with device_scope("attn_out"):
                 o = from_segs(outs)
                 if pk > 1:
                     o = unpack_heads(o, KV, pk)
                 return out_proj(a, qli, o), tuple(stages)
 
+        def latent_expands(T):
+            """Whether a latent segment of ``T`` tokens a row takes the
+            kernel's EXPANDED form (:func:`paged_latent_prefill`: the
+            prefill chunks) or the absorbed one (the decode programs, the
+            rows that ride a prefill step, a chunk too short to pay for a
+            page's up-projection) — by its static ``T`` against the
+            break-even the model's own widths give."""
+            return sel.is_pallas and latent_prefill_plan(
+                T, H, m.kv_lora_rank, m.qk_nope_head_dim,
+                m.qk_rope_head_dim, m.v_head_dim, Dp, bs,
+                cfg.dtype) is not None
+
         def latent_qkv(a, qli, h):
-            """Latent attention ABSORBED: what attends is the latent row
-            itself. Returns the query ``[S, T, H, lanes]`` — ``W_uk,h^T
-            q_nope_h`` (the up-projection of the keys folded into the
-            query) beside ``q_rope_h``, zeros in the row's padding — the
-            row ``[c | k_r]`` of this call's tokens ``[S, T, 1, lanes]``
-            (what the pool keeps, ONCE: the kernel takes its first
-            ``kv_lora_rank`` lanes as the value), and no V."""
-            R, dn = m.kv_lora_rank, m.qk_nope_head_dim
+            """Latent attention's operands: the query AS PROJECTED ``[S,
+            T, H, dn + dr]``, rope applied to its last ``dr``; the row ``[c
+            | k_r]`` of this call's tokens ``[S, T, 1, lanes]`` (what the
+            pool keeps, ONCE: the absorbed kernel takes its first
+            ``kv_lora_rank`` lanes as the value, the expanded one
+            up-projects them a head); and no V."""
+            dn = m.qk_nope_head_dim
             with device_scope("attn_qkv"):
                 q = proj_in(h, a["wq"], H, "wq", li=qli)
             with device_scope("latent_absorb"):
                 c, k_r = latent_row(m, h, a["w_dkv"], a["kv_norm"])
                 q_r, k_r = apply_rope(q[..., dn:], k_r[:, :, None, :],
                                       positions, m.rope_theta)
-                q_abs = jnp.einsum("sthd,rhd->sthr", q[..., :dn],
-                                   a["w_uk"].astype(cfg.dtype))
-                pad = Dp - m.latent_width
-                q = jnp.pad(jnp.concatenate([q_abs, q_r], axis=-1),
-                            [(0, 0)] * 3 + [(0, pad)])
+                q = jnp.concatenate([q[..., :dn], q_r], axis=-1)
                 row = jnp.pad(jnp.concatenate([c[:, :, None, :], k_r],
                                               axis=-1),
-                              [(0, 0)] * 3 + [(0, pad)])
+                              [(0, 0)] * 3 + [(0, Dp - m.latent_width)])
             return q, row.astype(cfg.dtype), None
+
+        def latent_absorb_query(a, q):
+            """Latent attention ABSORBED: what attends is the latent row
+            itself. The query ``[S, T, H, lanes]`` — ``W_uk,h^T q_nope_h``
+            (the up-projection of the keys folded into the query) beside
+            ``q_rope_h``, zeros in the row's padding."""
+            dn = m.qk_nope_head_dim
+            q_abs = jnp.einsum("sthd,rhd->sthr", q[..., :dn],
+                               a["w_uk"].astype(cfg.dtype))
+            return jnp.pad(jnp.concatenate([q_abs, q[..., dn:]], axis=-1),
+                           [(0, 0)] * 3 + [(0, Dp - m.latent_width)])
 
         def qkv(a, qli, h, kind):
             if rn:
@@ -1054,11 +1084,12 @@ class RaggedForward:
                 v_st = None if v_t is None else jnp.pad(v_t, pad)
             return k_st, v_st
 
-        def core(g, c, lk, q, stage_l):
+        def core(g, c, lk, q, stage_l, up=None):
             """Ragged attention of segment ``g`` over the pool pages + the
             stage: the Pallas kernel, or the XLA gather fallback — over
             cache ``c``'s pool and block table, at layer ``lk`` of that
-            pool."""
+            pool. ``up`` (``w_uk``, ``w_uv``): a latent chunk in the
+            EXPANDED form — ``q`` as projected in, a head's value out."""
             k_st, v_st = stage_l
             #: the score scale: the model's head width's — a packed or
             #: latent row's lanes are not it
@@ -1076,6 +1107,11 @@ class RaggedForward:
             attn_work = g.attn_works[c]
             ctx = table.shape[1] * bs
             li_dev = jnp.asarray(lk, jnp.int32)
+            if up is not None:
+                return paged_latent_prefill(
+                    q, *up, ro_pool, k_st, table, seq_lens, q_starts,
+                    stage_starts, block_size=bs, layer_index=li_dev,
+                    scale=qk_scale, work=attn_work)
             if sel.is_pallas:
                 # tree-verify stages ride two extra replicated operands:
                 # per-node absolute positions (root+depth) and the

@@ -45,6 +45,7 @@ scattered into the pool; ``seq_lens`` counts valid context tokens
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple
 
 import jax
@@ -774,6 +775,355 @@ def paged_ragged_attention(q, pool, k_stage, v_stage, block_tables,
             else (pool, pool, k_stage, v_stage)), *tree_ops)
     return (out.reshape(S, KV, T, G, Dv).transpose(0, 2, 1, 3, 4)
             .reshape(S, T, H, Dv))
+
+
+#: heads one pass of the expanded latent kernel's inner loop takes, side by
+#: side in one loop body so that one head's exponentials overlap another's
+#: products: 23.5 us a page at one, 21.6 at two, 20.7 at four (v5e, kanana-2's
+#: widths, a 512-token chunk over 28k tokens) — and every head of the body
+#: is traced and lowered again in each of a replica's prefill programs, at
+#: every start: four cost the cell 5 s of set-up (``PERF.md`` section 6,
+#: PR 60)
+LATENT_HEADS_A_PASS = 2
+
+
+def _lanes(n: int) -> int:
+    """``n`` values as VMEM holds a row of them: whole 128-lane registers."""
+    return -(-n // 128) * 128
+
+
+class LatentPrefillPlan(NamedTuple):
+    """The head group (and, where ONE head's chunk does not fit, the token
+    tile) of one :func:`paged_latent_prefill` call —
+    :func:`latent_prefill_plan` makes it, the kernel's entry reads it and
+    the serving engine logs it (``paged:``)."""
+    T: int                  # tokens a chunk
+    H: int
+    block_size: int
+    hg: int                 # heads a group: a page is up-projected once each
+    tqb: int                # tokens a tile (T but where one head overflows)
+    vmem_bytes: int         # what a grid step holds, by :func:`_latent_vmem`
+    breakeven: float        # tokens a chunk from which this form is cheaper
+
+    @property
+    def n_groups(self) -> int:
+        """Head groups a call: EACH walks every live page of a slot."""
+        return self.H // self.hg
+
+    def describe(self) -> str:
+        tiles = self.T // self.tqb
+        return (f"{self.T} tokens x {self.H} heads EXPANDED (cheaper than "
+                f"absorbed from {self.breakeven:.0f} tokens a chunk): "
+                f"{self.n_groups} group{'s' * (self.n_groups > 1)} of "
+                f"{self.hg} heads"
+                + (f" x {tiles} tiles of {self.tqb} tokens" * (tiles > 1))
+                + f" a call, each walks the slot's pages once (page "
+                f"{self.block_size}) and up-projects a page once a head; "
+                f"{self.vmem_bytes / 2**20:.1f} of "
+                f"{VMEM_LIMIT_BYTES / 2**20:.0f} MiB")
+
+
+def latent_prefill_breakeven(R: int, dn: int, dr: int, dv: int,
+                             lanes: int) -> float:
+    """Tokens a chunk at which latent attention EXPANDED costs what it
+    costs ABSORBED, a page: absorbed, every head's row contracts the stored
+    row (``lanes``) for its score and the latent (``R``) for its value;
+    expanded, a page is up-projected once a head (``R (dn + dv)`` a key,
+    whatever the chunk) and a row contracts ``dn + dr`` and ``dv``. 158 at
+    kanana-2's widths; infinite where expanding never pays."""
+    saved = (lanes + R) - (dn + dr + dv)
+    return R * (dn + dv) / saved if saved > 0 else float("inf")
+
+
+def _latent_vmem(hg: int, tqb: int, R: int, dn: int, dv: int, lanes: int,
+                 rows: int, itemsize: int) -> int:
+    """Bytes of VMEM one grid step of the expanded latent kernel holds:
+    the blocks the pipeline keeps twice (the group's two query parts and
+    its output, tokens in the lanes; its slices of ``w_uk`` / ``w_uv``; a
+    pool page and a stage tile), the f32 accumulator, m and l (a sublane
+    each, padded to 8), and a pass's temporaries (~6 score tiles a head
+    and the up-projected page)."""
+    t = _lanes(tqb)
+    blocks = 2 * itemsize * (hg * t * (dn + (lanes - R) + dv)
+                             + hg * R * (_lanes(dn) + dv) + 2 * rows * lanes)
+    scratch = 4 * hg * t * (dv + 2 * 8)
+    temps = math.gcd(hg, LATENT_HEADS_A_PASS) * (
+        6 * 4 * rows * t + (4 + itemsize) * rows * (_lanes(dn) + dv))
+    return blocks + scratch + temps
+
+
+def latent_prefill_plan(T: int, H: int, R: int, dn: int, dr: int, dv: int,
+                        lanes: int, block_size: int,
+                        dtype) -> LatentPrefillPlan | None:
+    """The EXPANDED form's plan for a chunk of ``T`` tokens, or None where
+    the absorbed form is the cheaper one (``T`` under
+    :func:`latent_prefill_breakeven`: every decode program, the rows that
+    ride a prefill step). From the call's static shape alone, as
+    :func:`paged_plan`: the grid is (head groups, token tiles, work list),
+    and a group is the LARGEST divisor of ``H`` whose step stays inside
+    :data:`VMEM_LIMIT_BYTES` — tiling by heads, because a page's
+    up-projection is per head and a tile of tokens would repeat it. The
+    tokens are tiled too (the widest divisor of ``T`` in whole 128-lane
+    registers that fits) only where one head's chunk does not fit."""
+    even = latent_prefill_breakeven(R, dn, dr, dv, lanes)
+    if T < even:
+        return None
+    isz = jnp.dtype(dtype).itemsize
+    # a stage tile is never taller than a page (``_ragged_geometry``)
+    vmem = lambda hg, t: _latent_vmem(hg, t, R, dn, dv, lanes, block_size,
+                                      isz)
+    groups = [g for g in range(H, 0, -1) if H % g == 0]
+    hg = next((g for g in groups if vmem(g, T) <= VMEM_LIMIT_BYTES), 1)
+    tqb = next((t for t in range(T, 0, -1) if T % t == 0
+                and (t == T or t % 128 == 0)
+                and vmem(hg, t) <= VMEM_LIMIT_BYTES), None)
+    if tqb is None:
+        raise ValueError(f"no tile of a {T}-token chunk of one head fits "
+                         f"{VMEM_LIMIT_BYTES} bytes of VMEM")
+    return LatentPrefillPlan(T, H, block_size, hg, tqb, vmem(hg, tqb), even)
+
+
+def _latent_prefill_kernel(tables_ref, lens_ref, qst_ref, sst_ref, layer_ref,
+                           work_ref, qn_ref, qr_ref, wuk_ref, wuv_ref,
+                           kp_ref, ks_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                           block_size: int, scale: float, srows: int,
+                           jbits: int, n_pool: int, rank: int, hb: int):
+    """Latent attention EXPANDED over the read-only latent pool: the form
+    of a prefill chunk (:func:`paged_latent_prefill`).
+
+    The absorbed form (:func:`_ragged_attn_kernel`, ``value_lanes``)
+    contracts every head's query row with the stored row — ``lanes`` for
+    the score, ``rank`` for the value: right at one row a head, twice the
+    FLOPs a chunk needs at 512. Here a grid step takes ONE latent page
+    ``[bs, lanes]`` (or one stage tile of this chunk's own rows), and for
+    each head of its group up-projects it in VMEM — ``k_nope = c W_uk,h``
+    (times the score scale) and ``v^T = W_uv,h^T c^T``, in the operands'
+    dtype, f32 sums — and runs the online softmax of
+    :func:`_ragged_attn_kernel` over ``k_nope · q_nope + k_r · q_rope``
+    (the rope lanes of the row, shared by the heads). The group's slices of
+    ``W_uk`` / ``W_uv`` stay resident along the work list; the page is read
+    once a group.
+
+    TRANSPOSED against the absorbed kernel: a score tile is ``[keys,
+    tokens]`` — the page's rows down the sublanes, the chunk's tokens along
+    the lanes — so that the max and the sum over keys run DOWN a tile
+    (register-wise, then one 8-sublane fold) and m, l and alpha are ``[1,
+    tokens]``, lane-dense. With a page's 128 keys along the lanes every
+    register of the score tile needs a cross-lane reduction of its own,
+    twice, and m / l are a lane wide: measured on a v5e at kanana-2's
+    widths that form took 74 us a page and chunk (52 with two heads a
+    pass) against the absorbed kernel's 63, this one 21 (``PERF.md``
+    section 6, PR 60). The query parts arrive ``[d, tokens]``, the
+    accumulator and the output are ``[dv, tokens]``.
+
+    Grid (head groups, token tiles, n_items); the same work list, block
+    table, stage, and masks — causal by position, the pool's
+    ``stage_starts``, the slot's ``seq_len``, an empty slot's finalize-only
+    item — as the absorbed form. ``qr_ref`` carries the query's rope part
+    padded with zeros to the row's lanes past ``rank`` (what the pool's
+    padding lanes meet). ``hb`` heads a pass of the inner loop.
+    """
+    del tables_ref, layer_ref
+    tq = pl.program_id(1)
+    s, j, first, last = _unpack_item(work_ref[pl.program_id(2)], jbits)
+    hg, tqb = m_scr.shape[0], m_scr.shape[2]
+
+    @pl.when(first)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    seq_len = lens_ref[s]
+    qstart = qst_ref[s]
+    sstart = sst_ref[s]            # pool holds positions < sstart
+
+    def attend(rows, ctx0, limit):
+        """The group's heads over latent rows ``[W, lanes]`` at positions
+        ``ctx0 + r``, those before ``limit`` and not after the query."""
+        W = rows.shape[0]
+        c = rows[:, :rank]
+        k_r = (rows[:, rank:].astype(jnp.float32) * scale).astype(rows.dtype)
+        ctx = ctx0 + jax.lax.broadcasted_iota(jnp.int32, (W, tqb), 0)
+        qpos = qstart + tq * tqb + jax.lax.broadcasted_iota(
+            jnp.int32, (W, tqb), 1)
+        mask = (ctx < limit) & (ctx <= qpos)
+
+        def head(h):
+            k_n = (jax.lax.dot_general(
+                c, wuk_ref[h], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale).astype(
+                    rows.dtype)                                # [W, dn]
+            v_t = jax.lax.dot_general(
+                wuv_ref[h], c, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32).astype(rows.dtype)
+            scores = jax.lax.dot_general(
+                k_n, qn_ref[0, h], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)            # [W, tqb]
+            scores += jax.lax.dot_general(
+                k_r, qr_ref[0, h], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            scores = jnp.where(mask, scores, NEG_INF)
+            m_prev = m_scr[h]                                  # [1, tqb]
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(scores, axis=0, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(scores - m_new)
+            l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=0, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                v_t, p.astype(rows.dtype), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)            # [dv, tqb]
+            m_scr[h] = m_new
+
+        def heads(i, carry):
+            for b in range(hb):
+                head(i * hb + b)
+            return carry
+
+        jax.lax.fori_loop(0, hg // hb, heads, 0)
+
+    # every item of the list is a live step by construction, but for the
+    # finalize-only item of an empty slot: there both are false
+    run_pool, run_stage = _live_steps(
+        j, seq_len, qstart, sstart, block_size=block_size, window=0,
+        ring_tokens=0, n_pool=n_pool, srows=srows, tree=False)
+
+    @pl.when(run_pool)
+    def _pool_step():
+        attend(kp_ref[0, 0, 0, 0], j * block_size, sstart)
+
+    @pl.when(run_stage)
+    def _stage_step():
+        attend(ks_ref[0, 0], sstart + jnp.maximum(j - n_pool, 0) * srows,
+               seq_len)
+
+    @pl.when(last)
+    def _finalize():
+        l = l_scr[:]
+        l_safe = jnp.where(l == 0.0, 1.0, l)               # empty slot → 0s
+        o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+
+
+def paged_latent_prefill(q, w_uk, w_uv, pool, k_stage, block_tables,
+                         seq_lens, q_starts, stage_starts, *,
+                         block_size: int, layer_index, scale: float,
+                         work=None, interpret: bool | None = None):
+    """Latent attention EXPANDED over the paged latent pool plus the staged
+    tail: the prefill form of the latent kind (kernel
+    ``paged_latent_prefill``; the decode form, and a chunk under
+    :func:`latent_prefill_breakeven`, is :func:`paged_ragged_attention`
+    with ``value_lanes``). Reads the cache the absorbed form reads — the
+    same pool, stage, block table and work list — and writes nothing.
+
+    q:        [S, T, H, dn + dr] — the queries AS PROJECTED (rope applied
+              to the last ``dr``; NOT folded through ``w_uk``) at positions
+              q_starts[s]..q_starts[s]+T-1
+    w_uk:     [R, H, dn], w_uv: [R, H, dv] — the latent's up-projections to
+              a head's keys and values, in the dtype of ``q``
+    pool:     [L, 1, 1, nb, bs, lanes] — rows ``[c | k_r | padding]``,
+              positions < stage_starts[s] a slot
+    k_stage:  [S, 1, Ts, lanes] — this step's rows
+    scale:    the model's (``(dn + dr) ** -0.5``)
+    Returns [S, T, H, dv]: what ``W_o`` takes.
+    """
+    S, T, H, dq = q.shape
+    R, _, dn = w_uk.shape
+    dv = w_uv.shape[2]
+    L, halves, KV, nb, bs, lanes = pool.shape
+    dr = dq - dn
+    if (halves, KV) != (1, 1) or bs != block_size or w_uk.shape[1] != H \
+            or w_uv.shape[:2] != (R, H) or not 0 < dr <= lanes - R:
+        raise ValueError(
+            f"the expanded latent form takes q [S, T, H, dn + dr], w_uk "
+            f"[R, H, dn], w_uv [R, H, dv] and a pool [L, 1, 1, nb, "
+            f"{block_size}, lanes >= R + dr]: got {q.shape}, {w_uk.shape}, "
+            f"{w_uv.shape}, {pool.shape}")
+    plan = latent_prefill_plan(T, H, R, dn, dr, dv, lanes, bs, q.dtype)
+    if plan is None:
+        raise ValueError(
+            f"a chunk of {T} tokens is cheaper absorbed (the forms cross at "
+            f"{latent_prefill_breakeven(R, dn, dr, dv, lanes):.1f}): "
+            f"paged_ragged_attention(value_lanes=) serves it")
+    if interpret is None:
+        from . import interpret_mode
+        interpret = interpret_mode()
+    hg, tqb = plan.hg, plan.tqb
+    Ts = k_stage.shape[2]
+    n_pool = block_tables.shape[1]
+    nsp, srows = _ragged_geometry(Ts, bs)
+    jbits = _item_bits(n_pool + nsp)
+    if work is None:
+        work = paged_work_list(seq_lens, q_starts, stage_starts,
+                               block_size=bs, max_pages=n_pool,
+                               stage_rows=Ts)
+    items, n_items = work
+    if items.shape != (S * (n_pool + nsp) + 1,):
+        raise ValueError(f"work list {items.shape} was not built for {S} "
+                         f"slots x {n_pool + nsp} columns")
+
+    # heads lead and the tokens lie along the lanes: a group's blocks are
+    # [hg, d, tokens], a head's weights [R, dn] and [dv, R] whole
+    qt = q.transpose(0, 2, 3, 1)                           # [S, H, dq, T]
+    qn = qt[:, :, :dn]
+    qr = jnp.pad(qt[:, :, dn:], [(0, 0)] * 2 + [(0, lanes - R - dr), (0, 0)])
+    wk, wv = w_uk.transpose(1, 0, 2), w_uv.transpose(1, 2, 0)
+
+    def item(wl, i):
+        s, j, _, _ = _unpack_item(wl[i], jbits)
+        return s, j
+
+    def pool_index(g, tq, i, t, ln, qs, ss, lr, wl):
+        s, j = item(wl, i)
+        # a stage step still needs a legal page index: the trash block
+        return (lr[0], 0, 0,
+                jnp.where(j < n_pool, t[s, jnp.minimum(j, n_pool - 1)], 0),
+                0, 0)
+
+    def stage_index(g, tq, i, t, ln, qs, ss, lr, wl):
+        s, j = item(wl, i)
+        return s, 0, jnp.maximum(j - n_pool, 0), 0
+
+    def q_index(g, tq, i, t, ln, qs, ss, lr, wl):
+        return item(wl, i)[0], g, 0, tq
+
+    def w_index(g, tq, i, t, ln, qs, ss, lr, wl):
+        return g, 0, 0
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=6,
+        grid=(H // hg, T // tqb, n_items),
+        in_specs=[
+            pl.BlockSpec((1, hg, dn, tqb), q_index),
+            pl.BlockSpec((1, hg, lanes - R, tqb), q_index),
+            pl.BlockSpec((hg, R, dn), w_index),
+            pl.BlockSpec((hg, dv, R), w_index),
+            pl.BlockSpec((1, 1, 1, 1, bs, lanes), pool_index),
+            pl.BlockSpec((1, 1, srows, lanes), stage_index),
+        ],
+        out_specs=pl.BlockSpec((1, hg, dv, tqb), q_index),
+        scratch_shapes=[
+            pltpu.VMEM((hg, 1, tqb), jnp.float32),
+            pltpu.VMEM((hg, 1, tqb), jnp.float32),
+            pltpu.VMEM((hg, dv, tqb), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _latent_prefill_kernel, block_size=bs, scale=float(scale),
+            srows=srows, jbits=jbits, n_pool=n_pool, rank=R,
+            hb=math.gcd(hg, LATENT_HEADS_A_PASS)),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, H, dv, T), q.dtype),
+        name="paged_latent_prefill",
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
+      q_starts.astype(jnp.int32), stage_starts.astype(jnp.int32),
+      jnp.asarray(layer_index, jnp.int32).reshape(1), items,
+      qn, qr, wk, wv, pool, k_stage)
+    return out.transpose(0, 3, 1, 2)
+
 
 
 def paged_prefill_attention(q, k_pool, v_pool, block_tables, seq_lens,

@@ -337,6 +337,30 @@ MISTRAL = dict(H=32, KV=8, D=128, max_pages=128)
 THINKER = dict(H=28, KV=4, D=128)
 THINKER_RING = dict(max_pages=37, window=4096, ring=True, **THINKER)
 THINKER_MOE = dict(k=6, E=2560, F=768)
+def _latent_expanded(S, T, H=32, R=512, dn=128, dr=64, dv=128, lanes=640,
+                     bs=128, max_pages=256):
+    """The EXPANDED form of the latent kind's prefill chunk at kanana-2's
+    widths (``paged_latent_prefill``, PR 60): the query as projected, the
+    two up-projections whole, the same pool, stage and table."""
+    def build(devs):
+        from deepspeed_tpu.ops.pallas.paged_attention import \
+            paged_latent_prefill
+        mk = lambda shape, dt: _sds(_one(devs), shape, dt)
+        Ts = -(-max(8, T) // bs) * bs if T > bs else max(8, T)
+        args = (mk((S, T, H, dn + dr), BF16), mk((R, H, dn), BF16),
+                mk((R, H, dv), BF16), mk((2, 1, 1, 64, bs, lanes), BF16),
+                mk((S, 1, Ts, lanes), BF16),
+                mk((S, max_pages), jnp.int32), mk((S,), jnp.int32),
+                mk((S,), jnp.int32), mk((S,), jnp.int32))
+
+        def fn(q, w_uk, w_uv, pool, ks, bt, sl, qs, ss):
+            return paged_latent_prefill(
+                q, w_uk, w_uv, pool, ks, bt, sl, qs, ss, block_size=bs,
+                layer_index=1, scale=(dn + dr) ** -0.5)
+        return fn, args, True
+    return build
+
+
 #: kanana-2-30b-a3b: 128 experts of 768 over hidden 2048, 6 a token
 KANANA_MOE = dict(k=6, E=2048, F=768, n=128)
 OLMOE = dict(H=16, KV=16, D=128, max_pages=32)
@@ -358,6 +382,15 @@ CASES = {
     "latent_decode_s48_p256": _latent(48, 1),
     "latent_chunk512_s1_p256": _latent(1, 512),
     "latent_chunk512_s12_p256": _latent(12, 512),
+    # ... and the chunk's EXPANDED form (what the engine's prefill steps
+    # launch since PR 60): 16 heads a group by the plan; a chunk of 2,048
+    # (4 heads a group) and one of 1,536 (8: the plan's count within 3 % of
+    # the limit); ONE head a group with the tokens tiled too
+    "latent_expanded_chunk512_s1_p256": _latent_expanded(1, 512),
+    "latent_expanded_chunk512_s12_p256": _latent_expanded(12, 512),
+    "latent_expanded_chunk2048_s1_p256": _latent_expanded(1, 2048),
+    "latent_expanded_chunk1536_s2_p256": _latent_expanded(2, 1536),
+    "latent_expanded_chunk8192_h2_s1_p256": _latent_expanded(1, 8192, H=2),
     "grouped_gemm_fwd": _grouped(False),
     "grouped_gemm_bwd": _grouped(True),
     "grouped_gemm_olmoe_decode_up": _grouped_serving(48),
@@ -503,6 +536,8 @@ def test_grouped_gemm_block_fits_the_plans_budget(name, topo, monkeypatch):
     ("ragged_tree_s8_bf16", "paged_attn_tree"),
     ("latent_decode_s48_p256", "paged_latent_decode"),
     ("latent_chunk512_s1_p256", "paged_latent_prefill"),
+    # the expanded form keeps the name the ledger's breakdown compares by
+    ("latent_expanded_chunk512_s1_p256", "paged_latent_prefill"),
     ("ragged_decode_h16_kv16_d128", "paged_attn_decode"),
     ("ragged_chunk128_h16_kv16_d128", "paged_attn_prefill"),
     ("ragged_decode_mistral_s48_p128", "paged_attn_decode"),

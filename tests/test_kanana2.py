@@ -252,35 +252,156 @@ def _latent_oracle(q, pool, stage, tables, lens, starts, R, scale, layer,
     return out
 
 
+#: the tiny model's latent widths (``_latent_case``'s R, rope and lanes) and
+#: the per-head widths of the EXPANDED form: the forms cross at 5.8 tokens
+TINY_WIDTHS = dict(R=24, dn=16, dr=8, dv=12, lanes=128)
+
+
+def _expanded_case(rng, q_lat, H=4, R=24, dn=16, dr=8, dv=12, lanes=128):
+    """The expanded form's operands for a latent case: queries AS
+    PROJECTED and the two up-projections — and the absorbed query they
+    fold to, in float64, for the oracle."""
+    S, T = q_lat.shape[:2]
+    q = rng.normal(size=(S, T, H, dn + dr)).astype(np.float32)
+    w_uk = (rng.normal(size=(R, H, dn)) / R ** 0.5).astype(np.float32)
+    w_uv = (rng.normal(size=(R, H, dv)) / R ** 0.5).astype(np.float32)
+    absorbed = np.zeros((S, T, H, lanes))
+    absorbed[..., :R] = np.einsum("sthd,rhd->sthr", q[..., :dn].astype(
+        np.float64), w_uk.astype(np.float64))
+    absorbed[..., R:R + dr] = q[..., dn:]
+    return jnp.asarray(q), jnp.asarray(w_uk), jnp.asarray(w_uv), absorbed
+
+
 @pytest.mark.parametrize("name, T, lens, starts", [
     # decode: contexts that end on, before and after page edges; one empty
     ("decode", 1, [17, 8, 1, 0, 40], [16, 7, 0, 0, 39]),
     # a chunk of 16 over 0, 8 and 24 cached tokens; a partial last chunk
+    # (ABSORBED, as every chunk was until PR 60 and the gather path still is)
     ("chunk16", 16, [16, 24, 33, 0], [0, 8, 24, 0]),
+    # a chunk of 4: under the break-even (5.8 at these widths), absorbed
+    ("chunk4-under-the-break-even", 4, [4, 13, 0], [0, 9, 0]),
+    # ---- the EXPANDED form (``paged_latent_prefill``) ----
+    # contexts that end INSIDE a page (of 8): 3, 11 and 19 cached tokens
+    ("expanded-ends-inside-a-page", 16, [19, 27, 30], [3, 11, 19]),
+    # every key in the stage: first chunks, one full, one of 5 tokens
+    ("expanded-all-keys-staged", 16, [16, 5], [0, 0]),
+    # deep in a long context: 29 pages before the chunk, a partial chunk
+    ("expanded-deep", 16, [248, 243], [232, 232]),
+    # several rows, an empty slot between and one at the end
+    ("expanded-empty-slots", 16, [24, 0, 33, 0], [8, 0, 24, 0]),
+    # just over the break-even: 8 tokens a chunk; 32: the stage spans pages
+    ("expanded-chunk8-over-the-break-even", 8, [8, 21, 47], [0, 16, 40]),
+    ("expanded-chunk32", 32, [40, 70, 0], [8, 40, 0]),
 ])
 def test_the_latent_kernel_form_matches_the_plain_oracle(name, T, lens,
                                                          starts):
-    """``paged_ragged_attention``'s latent form in interpret mode over
-    ragged lengths that cross page and tile edges: one row a token shared
-    by the heads, the value the row's first ``R`` lanes, padding unread."""
-    from deepspeed_tpu.ops.pallas.paged_attention import \
-        paged_ragged_attention
+    """The latent kernel's two forms in interpret mode over ragged lengths
+    that cross page and tile edges. ABSORBED (``paged_ragged_attention``,
+    ``value_lanes``): one row a token shared by the heads, the value the
+    row's first ``R`` lanes, padding unread. EXPANDED
+    (``paged_latent_prefill``): the same cache, the page up-projected a
+    head in the kernel — held to the same oracle through ``W_uk`` folded
+    into the query and ``W_uv`` after the weighted sum, in float64. Which
+    form a chunk takes is its ``T`` against the break-even."""
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        latent_prefill_plan, paged_latent_prefill, paged_ragged_attention)
 
     rng = np.random.default_rng(7)
     R, scale = 24, 24 ** -0.5
-    case = _latent_case(rng, len(lens), T, lens, starts)
+    case = _latent_case(rng, len(lens), T, lens, starts, pages=32, nb=200)
     q, pool, stage, tables, ln, st = case
-    got = paged_ragged_attention(q, pool, stage, None, tables, ln, st, st,
-                                 block_size=8, layer_index=1, scale=scale,
-                                 value_lanes=R, interpret=True)
-    assert got.shape == (len(lens), T, 4, R)
-    want = _latent_oracle(q, pool, stage, tables, lens, starts, R, scale, 1)
+    plan = latent_prefill_plan(T, 4, block_size=8, dtype=jnp.float32,
+                               **TINY_WIDTHS)
+    assert (plan is not None) == (T >= 6)
+    if not name.startswith("expanded"):
+        # (the absorbed form serves any chunk: "chunk16" holds it to that)
+        got = paged_ragged_attention(q, pool, stage, None, tables, ln, st,
+                                     st, block_size=8, layer_index=1,
+                                     scale=scale, value_lanes=R,
+                                     interpret=True)
+        assert got.shape == (len(lens), T, 4, R)
+        want = _latent_oracle(q, pool, stage, tables, lens, starts, R,
+                              scale, 1)
+    else:
+        qp, w_uk, w_uv, q_abs = _expanded_case(rng, q)
+        got = paged_latent_prefill(qp, w_uk, w_uv, pool, stage, tables, ln,
+                                   st, st, block_size=8, layer_index=1,
+                                   scale=scale, interpret=True)
+        assert got.shape == (len(lens), T, 4, 12)
+        want = np.einsum("sthr,rhd->sthd", _latent_oracle(
+            q_abs, pool, stage, tables, lens, starts, R, scale, 1),
+            np.asarray(w_uv, np.float64))
     live = np.asarray(lens) > 0
     valid = (np.asarray(starts)[:, None] + np.arange(T)[None]
              < np.asarray(lens)[:, None])
     np.testing.assert_allclose(np.asarray(got)[valid], want[valid],
                                atol=2e-5)
     assert not np.asarray(got)[~live].any()
+
+
+def test_the_expanded_form_refuses_what_it_does_not_serve():
+    """A chunk under the break-even is the absorbed form's (the entry says
+    so, by the numbers), and so is a pool with K/V halves."""
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        latent_prefill_breakeven, paged_latent_prefill)
+
+    assert latent_prefill_breakeven(512, 128, 64, 128, 640) \
+        == pytest.approx(157.54, abs=0.01)          # kanana-2's widths
+    assert latent_prefill_breakeven(**TINY_WIDTHS) == pytest.approx(5.79,
+                                                                    abs=0.01)
+    # widths at which expanding never pays: a row narrower than a head
+    assert latent_prefill_breakeven(64, 128, 64, 128, 128) == float("inf")
+    rng = np.random.default_rng(2)
+    q, pool, stage, tables, ln, st = _latent_case(rng, 2, 4, [4, 12],
+                                                  [0, 8])
+    qp, w_uk, w_uv, _ = _expanded_case(rng, q)
+    kw = dict(block_size=8, layer_index=0, scale=1.0, interpret=True)
+    with pytest.raises(ValueError, match="cheaper absorbed .*cross at 5.8"):
+        paged_latent_prefill(qp, w_uk, w_uv, pool, stage, tables, ln, st,
+                             st, **kw)
+    q, pool, stage, tables, ln, st = _latent_case(rng, 2, 8, [8, 12], [0, 8])
+    qp, w_uk, w_uv, _ = _expanded_case(rng, q)
+    with pytest.raises(ValueError, match=r"a pool \[L, 1, 1, nb, 8, lanes"):
+        paged_latent_prefill(qp, w_uk, w_uv, jnp.concatenate([pool, pool], 1),
+                             stage, tables, ln, st, st, **kw)
+
+
+@pytest.mark.parametrize("T, H, hg, tqb", [
+    # kanana-2's chunk: 16 of its 32 heads a group, the tokens whole
+    (512, 32, 16, 512),
+    # a chunk four times as long: 4 heads a group, still by heads alone
+    (2048, 32, 4, 2048),
+    # ONE head's rows past the limit: one head a group, the tokens cut too
+    (16384, 32, 1, 4096),
+    (4096, 7, 1, 4096)],
+    ids=["published", "chunk2048", "one-head-tokens-cut", "one-head"])
+def test_the_head_group_plan_fits_the_vmem_limit(T, H, hg, tqb):
+    """``latent_prefill_plan`` at kanana-2's published widths: the LARGEST
+    divisor of the heads whose step — by the plan's own count of blocks,
+    scratch and temporaries — stays inside ``VMEM_LIMIT_BYTES``, the
+    tokens whole; a tile of tokens only where one head does not fit. (That
+    Mosaic agrees with the count is ``tests/test_chip_compile.py``'s.)"""
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        VMEM_LIMIT_BYTES, _latent_vmem, latent_prefill_plan)
+
+    plan = latent_prefill_plan(T, H, 512, 128, 64, 128, 640, 128,
+                               jnp.bfloat16)
+    assert (plan.hg, plan.tqb, plan.n_groups) == (hg, tqb, H // hg)
+    assert plan.vmem_bytes <= VMEM_LIMIT_BYTES
+    assert plan.vmem_bytes == _latent_vmem(hg, tqb, 512, 128, 128, 640, 128,
+                                           2)
+    # the next larger group (or the whole chunk) would not have fitted
+    larger = [g for g in range(hg + 1, H + 1) if H % g == 0]
+    if larger:
+        assert _latent_vmem(larger[0], T, 512, 128, 128, 640, 128, 2) \
+            > VMEM_LIMIT_BYTES
+    if tqb < T:
+        assert _latent_vmem(1, 2 * tqb, 512, 128, 128, 640, 128, 2) \
+            > VMEM_LIMIT_BYTES
+    assert "EXPANDED" in plan.describe() and f"{hg} heads" in plan.describe()
+    # a decode program's row, and the rows that ride a prefill step
+    assert latent_prefill_plan(1, H, 512, 128, 64, 128, 640, 128,
+                               jnp.bfloat16) is None
 
 
 def test_the_latent_form_is_refused_half_said():
@@ -336,6 +457,87 @@ def test_absorbed_over_the_cache_equals_expanded_on_one_layer(tiny,
     np.testing.assert_allclose(flat[hits, :width], want_rows, atol=1e-5)
     assert not flat[:, width:].any()                      # padding: zeros
     assert len(out[0]) == 1
+    # the third side: the two KERNEL forms over that cache — the chunk of
+    # tokens 16..31 of layer 0 over the 16 rows before it — against plain
+    # attention over per-head keys and values made from the rows above
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        paged_latent_prefill, paged_ragged_attention)
+    R, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    w_uk, w_uv = a["w_uk"], a["w_uv"]
+    scale = (dn + cfg.qk_rope_head_dim) ** -0.5
+    q = jnp.einsum("ste,ehd->sthd", h[:, 16:32], a["wq"])
+    q_r, _ = apply_rope(q[..., dn:], q[..., dn:], pos[:, 16:32],
+                        cfg.rope_theta)
+    q = jnp.concatenate([q[..., :dn], q_r], -1)
+    ops = (jnp.asarray(flat[hits[16:32]])[None, None],     # the stage
+           jnp.asarray([[hits[i] // 8 for i in range(0, 40, 8)]], jnp.int32),
+           jnp.asarray([32], jnp.int32), jnp.asarray([16], jnp.int32),
+           jnp.asarray([16], jnp.int32))
+    kw = dict(block_size=8, layer_index=0, scale=scale, interpret=True)
+    expanded = paged_latent_prefill(q, w_uk, w_uv, eng.kv_pool[0], *ops, **kw)
+    q_abs = jnp.pad(jnp.concatenate([jnp.einsum(
+        "sthd,rhd->sthr", q[..., :dn], w_uk), q_r], -1),
+        [(0, 0)] * 3 + [(0, pool.shape[-1] - width)])
+    absorbed = jnp.einsum("sthr,rhd->sthd", paged_ragged_attention(
+        q_abs, eng.kv_pool[0], ops[0], None, *ops[1:], value_lanes=R, **kw),
+        w_uv)
+    c_all, kr_all = want_rows[:32, :R], want_rows[:32, R:]
+    keys = np.concatenate([
+        np.einsum("cr,rhd->chd", c_all, np.asarray(w_uk)),
+        np.repeat(kr_all[:, None], cfg.num_heads, 1)], -1)
+    scores = np.einsum("thd,chd->htc", np.asarray(q[0]), keys) * scale
+    scores[:, 16 + np.arange(16)[:, None] < np.arange(32)[None]] = -np.inf
+    w = np.exp(scores - scores.max(-1, keepdims=True))
+    plain = np.einsum("htc,chd->thd", w / w.sum(-1, keepdims=True),
+                      np.einsum("cr,rhd->chd", c_all, np.asarray(w_uv)))
+    np.testing.assert_allclose(np.asarray(expanded[0]), plain, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(absorbed[0]), plain, atol=1e-5)
+
+
+def test_the_pool_after_a_prefill_is_the_same_whichever_form_ran(
+        tiny, monkeypatch):
+    """What a prefill step WRITES is the row ``[c | k_r]``, whichever form
+    its chunks attended by: layer 0's rows (made from the embeddings) are
+    the same BYTES after an expanded prefill and after an absorbed one;
+    the layers above read the attention's output, which the two forms
+    round differently, and agree to float32's last places. The expanded
+    entry is what the engine's chunks call; a chunk under the break-even
+    and every decode row never do."""
+    import deepspeed_tpu.inference.forward as fwd
+    from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
+
+    model, params, tokens = tiny
+    calls = []
+    real = fwd.paged_latent_prefill
+    monkeypatch.setattr(fwd, "paged_latent_prefill", lambda q, *a, **k: (
+        calls.append(q.shape[1]), real(q, *a, **k))[1])
+
+    def pool_after(chunk):
+        eng = InferenceEngineV2(
+            model, params=jax.tree.map(jnp.copy, params),
+            config={"block_size": 8, "num_blocks": 32, "max_seqs": 2,
+                    "chunk": chunk, "max_seq_len": 64,
+                    "dtype": jnp.float32}, rng=jax.random.PRNGKey(0))
+        out = eng.generate([tokens[0, :40].tolist()], max_new_tokens=3)
+        return np.asarray(eng.kv_pool[0]), out
+
+    expanded, out_e = pool_after(16)
+    # (traced once a program: the chunks' widths, never a decode row's 1)
+    assert calls and min(calls) >= 16, calls
+    n = len(calls)
+    monkeypatch.setattr(fwd, "latent_prefill_plan", lambda *a, **k: None)
+    absorbed, out_a = pool_after(16)
+    assert len(calls) == n and out_a == out_e
+    assert expanded[0].tobytes() == absorbed[0].tobytes()
+    assert expanded[0].any()
+    np.testing.assert_allclose(expanded[1:], absorbed[1:], atol=1e-5)
+    monkeypatch.undo()
+    # a chunk of 4 is under the break-even: absorbed, by its T alone
+    calls.clear()
+    monkeypatch.setattr(fwd, "paged_latent_prefill", lambda *a, **k: (
+        calls.append(1), real(*a, **k))[1])
+    pool_after(4)
+    assert not calls
 
 
 # ---------------------------------------------------------------------------
@@ -427,3 +629,22 @@ def test_a_prefill_step_carries_riding_decode_rows(tiny, monkeypatch):
         e["plan"].token_ids.shape[1] == CHUNK for e in chunks)
     hold_to_the_reference(model, params, tap, requests, out)
     eng.state.audit()
+
+
+def test_a_latent_model_whose_heads_come_out_64_wide_serves(monkeypatch):
+    """kanana-2's ``hidden_size / num_heads`` is 64 over an even head
+    count, for which ``kv_pack`` says two KV heads a page row — and the
+    latent kind has no KV heads to pack: its attention's output goes to
+    ``W_o`` as it is, whichever form made it. (The tiny preset's quotient
+    is 16; at 64 the published model's prefill step did not trace.)"""
+    from deepspeed_tpu.inference.forward import kv_pack
+
+    model, params, _ = build(hidden_size=256)
+    assert model.config.head_dim == 64 and kv_pack(model.config) == 2
+    tap = Tap(monkeypatch)
+    rng = np.random.default_rng(11)
+    requests = {1: (_prompt(rng, 5), 12), 2: (_prompt(rng, 2 * CHUNK + 3), 4)}
+    eng, out = serve(model, params, tap, requests, arrivals={2: 3},
+                     decode_window=4, prefill_grow_chunk=False)
+    assert eng.stats["fused_steps"] >= 1
+    hold_to_the_reference(model, params, tap, requests, out)

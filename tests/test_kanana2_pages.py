@@ -218,3 +218,69 @@ def test_the_decode_programs_launch_the_latent_kernel_once_a_layer(tiny):
     # the kernel's steps are booked as the paged kernel's are
     assert eng.stats["attn_steps_live_latent"] == eng.stats[
         "attn_steps_live"] > 0
+
+
+#: the four other served families: a tiny preset of each, and at the
+#: PUBLISHED shape of its benchmark cell — (chunk, query heads, KV heads a
+#: page row, lanes of a row), pages of 128, bf16 — what ``paged_plan``
+#: returned on the parent of PR 60 (``5ed1c74``) for a decode row and for a
+#: chunk, as ``(TG, KV, block_size, tqb)``
+OTHER_KINDS = {
+    "mistral": ("tiny-llama", {"sliding_window": 64}, (128, 32, 8, 128),
+                (4, 8, 128, 4), (512, 8, 128, 512)),
+    "olmoe": ("tiny-olmoe", {}, (128, 16, 16, 128),
+              (1, 16, 128, 1), (128, 16, 128, 128)),
+    "smallthinker": ("tiny-smallthinker", {}, (512, 28, 4, 128),
+                     (7, 4, 128, 7), (3584, 4, 128, 896)),
+    "lfm2": ("tiny-lfm2-moe", {}, (512, 32, 4, 128),
+             (8, 4, 128, 8), (4096, 4, 128, 1024)),
+}
+
+
+@pytest.mark.parametrize("family", list(OTHER_KINDS))
+def test_the_expanded_latent_form_is_invisible_to_the_other_kinds(
+        family, monkeypatch):
+    """The latent kind's prefill form (PR 60) is a kernel and a branch of
+    its OWN: an engine of another kind never calls its plan or its entry
+    (both raise here), its prefill step launches ``paged_attn_prefill`` for
+    the chunks and ``paged_attn_decode`` for the rows that ride it (and the
+    grouped GEMM where it has experts) and nothing else, and ``paged_plan``
+    returns for its published shape what it returned on the parent — so a
+    ``correct: false`` in one of their cells is not this change's."""
+    import deepspeed_tpu.inference.engine_v2 as ev
+    import deepspeed_tpu.inference.forward as fwd
+    from deepspeed_tpu.models import build_model
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_plan
+
+    preset, over, (chunk, H, KV, lanes), decode_plan, chunk_plan = \
+        OTHER_KINDS[family]
+
+    def never(*a, **k):
+        raise AssertionError("the latent kind's code, reached from a "
+                             f"{family} engine")
+
+    monkeypatch.setattr(ev, "latent_prefill_plan", never)
+    monkeypatch.setattr(fwd, "latent_prefill_plan", never)
+    monkeypatch.setattr(fwd, "paged_latent_prefill", never)
+    # (heads of 64, two a page row: a width the paged kernel serves)
+    eng = ev.InferenceEngineV2(
+        build_model(preset, head_size=64, **over), rng=jax.random.PRNGKey(6),
+        config={"block_size": 8, "num_blocks": 64, "max_seqs": 2, "chunk": 8,
+                "max_seq_len": 128})
+    eng.generate([list(range(5, 24)), [3, 4]], max_new_tokens=6)
+    assert eng._attn_paged and not any(k.is_latent for k in eng._kinds)
+    steps = 0
+    for key, prog in eng._programs.items():
+        names = _kernels_of(prog) - {"grouped_matmul_fwd"}
+        if key[0] == "win" or key[0] == 1:
+            assert names == {"paged_attn_decode"}, (key, names)
+        else:
+            assert names == {"paged_attn_prefill", "paged_attn_decode"}, \
+                (key, names)
+            steps += 1
+    assert steps
+    G = H // KV
+    assert tuple(paged_plan(G, KV, 128, jnp.bfloat16, lanes=lanes)) \
+        == decode_plan
+    assert tuple(paged_plan(chunk * G, KV, 128, jnp.bfloat16, lanes=lanes)) \
+        == chunk_plan
